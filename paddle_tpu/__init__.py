@@ -7,6 +7,9 @@ jax.sharding meshes (see parallel/).
 """
 from __future__ import annotations
 
+from .core.compile_cache import configure_compile_cache as _cfg_cache
+_cfg_cache()
+
 # ops must register before any program building
 from . import ops as _ops  # noqa: F401
 

@@ -1,10 +1,10 @@
 """Programmable operator scheduler: concurrent island dispatch +
 micro-batch pipelining (docs/SCHEDULING.md).
 
-BENCH_r05 measured the transformer sync 1-step latency at 178.9 ms
-against a 59.1 ms device-pipeline bound: ~120 ms of every synchronous
-step is host dispatch + fetch serialization behind ONE monolithic
-whole-block executable. DynaFlow's observation (PAPERS.md) is that a
+A synchronous step is host dispatch + fetch serialization behind ONE
+monolithic whole-block executable (July 2026, previous installation:
+178.9 ms sync vs a 59.1 ms device bound; git history — not measured on
+the present machine). DynaFlow's observation (PAPERS.md) is that a
 block is rarely one dependence chain — forward, backward, and the
 per-parameter optimizer updates are data-independent subgraphs that a
 programmable scheduler can dispatch on separate lanes. This module

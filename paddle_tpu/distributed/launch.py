@@ -13,6 +13,12 @@ TPU-native notes:
   gloo-style host bootstrap the collective fleet uses is selected with
   PADDLE_TPU_MULTIHOST=1 — the same contract the subprocess cluster
   tests exercise (tests/test_dist_fleet.py).
+* A chip belongs to one process. With the TPU backend every child
+  opens every local chip, so `--nproc > 1` on one TPU host is refused
+  at launch (no gang of children fighting over the chips): drive the
+  host's chips from ONE process over a mesh, or pass `--backend cpu`.
+  The launcher itself never initialises a JAX backend, or it would
+  hold the chips its children need; this is asserted before the spawn.
 * `--backend cpu` forces JAX_PLATFORMS=cpu in the children (virtual
   multi-process clusters on one machine — CI, dry runs).
 
@@ -38,6 +44,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import socket
@@ -51,6 +58,18 @@ __all__ = ["launch", "supervise", "main"]
 # long enough for a SIGTERM-hooked final checkpoint of a small model,
 # short enough that a wedged worker cannot stall CI
 DEFAULT_GRACE_S = 10.0
+
+
+def _children_open_tpu(backend, env) -> bool:
+    """Will a child started with *env* bring up the TPU backend? Decided
+    without JAX (this process must never open the chips): the platform
+    request that reaches the child, then the host's TPU device nodes."""
+    if backend == "cpu":
+        return False
+    platforms = env.get("JAX_PLATFORMS", "").lower()
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
 
 
 def _free_ports(n, start=None):
@@ -127,9 +146,24 @@ def _run_once(script_args, nproc=1, ips=None, started_port=None,
         endpoints = [f"{h}:{port0}" for h in hosts]
         ranks = local_ranks
     else:
+        if nproc > 1 and _children_open_tpu(
+                backend, {**os.environ, **(extra_env or {})}):
+            raise SystemExit(
+                f"paddle_tpu.distributed.launch: --nproc {nproc} on one "
+                f"TPU host is refused: a chip belongs to one process "
+                f"and every child would open every local chip. Run ONE "
+                f"process over all local chips (a mesh / "
+                f"with_data_parallel(places=fluid.tpu_places())), or "
+                f"pass --backend cpu for a virtual cluster.")
         ports = _free_ports(nproc, started_port)
         endpoints = [f"127.0.0.1:{p}" for p in ports]
         ranks = list(range(nproc))
+
+    # a parent that has brought the TPU backend up holds the chips its
+    # children need (in-process callers with a CPU backend are fine)
+    xb = sys.modules.get("jax._src.xla_bridge")
+    assert xb is None or "tpu" not in xb._backends, \
+        "launcher process initialised the TPU backend before spawning"
 
     eps = ",".join(endpoints)
     nranks = len(endpoints)

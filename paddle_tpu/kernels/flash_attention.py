@@ -45,15 +45,17 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..core.jaxcompat import out_struct as _out_struct
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across jax releases: TPUCompilerParams (0.4.x) -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 _NEG_INF = -1e30
+
+
+def _out_struct(shape, dtype, like):
+    """pallas_call out_shape carrying ``like``'s varying-mesh-axes set:
+    under shard_map outputs inherit the inputs' vma, and JAX checks it
+    on pallas_call out_shapes."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 # test hook: run pallas_call in interpreter mode (CPU correctness tests)
 _INTERPRET = False
@@ -600,8 +602,6 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
     assert Sq % bq == 0 and Sk % bk == 0, (Sq, Sk, bq, bk)
     n_kv = Sk // bk
     plan = _Plan(layout, B, H, Sq, Sk, D, bq, bk)
-    # under shard_map, outputs inherit the inputs' varying-mesh-axes
-    # set (JAX >= 0.9 checks vma on pallas_call out_shapes)
     def _sds(shape, dtype):
         return _out_struct(shape, dtype, like=q)
 
@@ -671,7 +671,7 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
             pltpu.VMEM((plan.hpb, bq, 128), jnp.float32),
             pltpu.VMEM((plan.hpb, bq, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * kv_axis
             + ("arbitrary",)),
         interpret=_INTERPRET,
@@ -826,7 +826,7 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((plan.hpb, bq, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * kv_axis
             + ("arbitrary",)),
         interpret=_INTERPRET,
@@ -903,7 +903,7 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
                    _sds(out_rows(Sk), v.dtype)],
         scratch_shapes=[pltpu.VMEM((plan.hpb, bk, D), jnp.float32),
                         pltpu.VMEM((plan.hpb, bk, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * q_axis
             + ("arbitrary",)),
         interpret=_INTERPRET,
@@ -969,7 +969,8 @@ def use_kernel_path(q, k, block_q=128, block_k=128, layout="bhsd"):
     if not _kreg.allowed("flash_attention"):
         _kreg.count("flash_attention", "denied")
         return False
-    ok = _kernel_ok(q, k, block_q, block_k, layout)
+    ok = _kernel_ok(q, k, block_q, block_k, layout) \
+        and not _kreg.in_auto_partitioned_trace()
     if ok and not _INTERPRET \
             and not os.environ.get("PT_FORCE_KERNEL"):
         ok = (_seq_len(q, layout) * _seq_len(k, layout)

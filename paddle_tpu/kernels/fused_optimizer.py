@@ -56,10 +56,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
 
-# renamed across jax releases: TPUCompilerParams (0.4.x) -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 _LANES = 128
 _BLOCK_ROWS = 256  # 256x128 f32 = 128 KiB per operand block in VMEM
 
@@ -159,7 +155,7 @@ def _call(body, hyper, bounds, bufs, n_out, block_rows):
                    * n_out if n_out > 1
                    else jax.ShapeDtypeStruct(bufs[0].shape,
                                              bufs[0].dtype)),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=registry.interpret(),
     )(hyper, bounds, *bufs)

@@ -8,23 +8,31 @@ baseline it replaces.  The harness
 * runs each case's BASELINE through the real op lowering
   (``core.registry.OPS``) with the kernel registry force-disabled, so
   the reference really is the path users get with kernels off;
-* runs the kernel directly (kernels execute under the Pallas
-  interpreter on CPU — see ``registry.interpret()`` — so this gates in
-  tier-1 CI under ``JAX_PLATFORMS=cpu``);
+* runs the kernel directly: compiled by Mosaic on a TPU
+  (``chip_smoke.py`` leg C), under the Pallas interpreter on CPU — see
+  ``registry.interpret()`` — so this also gates in tier-1 CI under
+  ``JAX_PLATFORMS=cpu``;
 * compares under a per-dtype tolerance: **ulp** bounds for
   value-preserving kernels (fused optimizer: same math, same
   operation order, tolerance a handful of ulp), **relative-error**
   bounds for value-approximating kernels (quantized matmul, flash
-  attention's online softmax).
+  attention's online softmax). Where two correct programs legitimately
+  differ (FMA contraction on a cancelling sum; the MXU's bf16 passes at
+  default precision) kernel and baseline are each measured against a
+  float64 evaluation and the kernel may not be worse — the bound
+  follows what the backend does, it is not widened to cover it.
 
 ``tools/lint_program.py --check-kernels`` fails the build when a
 registered kernel has no parity case (:func:`missing_parity`);
 ``tests/test_kernels.py`` runs :func:`run_all` case by case.
 
-Tolerance policy (docs/KERNELS.md): f32 value-preserving <= 4 ulp;
-rel-error kernels get per-mode bounds (int8 5e-2, bf16 1e-2, flash
-attention 2e-3 on f32 data) measured on unit-scale random data with a
-fixed seed — loosening a bound is a reviewed change, not a test edit.
+Tolerance policy (docs/KERNELS.md): f32 value-preserving <= 4 ulp
+(fused_sgd), or at most 1 ulp further from float64 than the lowering is
+(fused_adam); rel-error kernels get per-mode bounds (int8 5e-2, bf16
+1e-2; flash attention 1e-5 from float64 at precision "highest", and at
+the backend's default precision no further from float64 than the
+composed path) measured on unit-scale random data with a fixed seed —
+loosening a bound is a reviewed change, not a test edit.
 """
 
 from __future__ import annotations
@@ -39,7 +47,7 @@ import jax.numpy as jnp
 from . import registry
 
 __all__ = ["cases", "run_case", "run_all", "missing_parity",
-           "max_ulp", "rel_err"]
+           "max_ulp", "rel_err", "dropout_mask_identity"]
 
 
 # ---------------------------------------------------------------------------
@@ -159,10 +167,32 @@ def _adam_case(shape):
                                 jnp.asarray(m), jnp.asarray(v),
                                 jnp.asarray(lr_t), beta1=0.9,
                                 beta2=0.999, epsilon=1e-8)
-        return {"metric": "ulp", "tol": 4.0,
-                "value": max(max_ulp(env["po"], po),
-                             max_ulp(env["mo"], mo),
-                             max_ulp(env["vo"], vo))}
+        # m' = b1*m + (1-b1)*g and p' = p - upd cancel on some
+        # elements, and there two correct f32 programs that differ in
+        # FMA contraction sit hundreds of ulp apart (XLA:CPU vs the
+        # Pallas interpreter: 204; compiled on the v5e: 0). So both are
+        # measured against the same recurrence in float64 (the f32
+        # constants both use), and the kernel may be at most 1 ulp
+        # further from it than the lowering is.
+        f64, f32 = np.float64, np.float32
+        c = {"b1": f64(f32(0.9)), "1-b1": f64(f32(1.0 - 0.9)),
+             "b2": f64(f32(0.999)), "1-b2": f64(f32(1.0 - 0.999)),
+             "eps": f64(f32(1e-8))}
+        m64 = c["b1"] * m.astype(f64) + c["1-b1"] * g.astype(f64)
+        v64 = (c["b2"] * v.astype(f64)
+               + c["1-b2"] * g.astype(f64) * g.astype(f64))
+        p64 = p.astype(f64) - f64(lr_t) * m64 / (np.sqrt(v64)
+                                                 + c["eps"])
+        trios = [(p64, env["po"], po), (m64, env["mo"], mo),
+                 (v64, env["vo"], vo)]
+        kern = [max_ulp(r.astype(f32), k) for r, _, k in trios]
+        low = [max_ulp(r.astype(f32), lo) for r, lo, _ in trios]
+        direct = max(max_ulp(lo, k) for _, lo, k in trios)
+        return {"metric": "ulp_past_lowering", "tol": 1.0,
+                "value": max(0.0, *(k - lo for k, lo in zip(kern, low))),
+                "note": "kernel vs lowering %g ulp; from float64: "
+                        "kernel %g, lowering %g ulp"
+                        % (direct, max(kern), max(low))}
     return Case("fused_adam", "fused_adam/f32/%s" % (shape,), run)
 
 
@@ -204,32 +234,140 @@ def _qmm_case(mode, tol):
                 "quantized_matmul/%s/256x384x128" % mode, run)
 
 
-def _fa_case():
+def _fa_module():
+    # the package re-exports the flash_attention FUNCTION under the
+    # module's name; go through importlib for the module itself
+    import importlib
+    return importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+
+@contextlib.contextmanager
+def _fa_kernels_live(fa):
+    """Flash kernels run as the backend runs them: compiled by Mosaic
+    on a TPU, under the Pallas interpreter on a CPU host."""
+    prev = fa._INTERPRET
+    fa._INTERPRET = registry.interpret()
+    try:
+        yield
+    finally:
+        fa._INTERPRET = prev
+
+
+# flash attention, f32 data, against a float64 evaluation. At precision
+# "highest" the kernel is held to an f32 bound on every backend
+# (measured: 3.6e-7 interpreted, 1.3e-6 compiled on the v5e). At the
+# backend's DEFAULT precision a TPU feeds the MXU bf16 passes in the
+# kernel and in the composed path alike (3.18e-3 and 3.34e-3 from
+# float64 on the v5e), so no flat bound separates a correct kernel from
+# a broken one: the kernel may be at most _FA_SLACK further from
+# float64 than the composed path it replaces, measured in the same run.
+_FA_HIGHEST_TOL = 1e-5
+_FA_SLACK = 1.25
+
+
+def _fa_case(precision):
     def run():
-        import importlib
-        # the package re-exports the flash_attention FUNCTION under the
-        # module's name; go through importlib for the module itself
-        fa = importlib.import_module(
-            "paddle_tpu.kernels.flash_attention")
+        import jax
+        fa = _fa_module()
         r = _rng(17)
         q = r.standard_normal((1, 2, 256, 64), dtype=np.float32)
         k = r.standard_normal((1, 2, 256, 64), dtype=np.float32)
         v = r.standard_normal((1, 2, 256, 64), dtype=np.float32)
         scale = 0.125
-        ref = fa._attn_reference(jnp.asarray(q), jnp.asarray(k),
-                                 jnp.asarray(v), None, scale)
-        prev = fa._INTERPRET
-        fa._INTERPRET = True
-        try:
+        s64 = np.einsum("bhqd,bhkd->bhqk", q.astype(np.float64),
+                        k.astype(np.float64)) * scale
+        p64 = np.exp(s64 - s64.max(-1, keepdims=True))
+        p64 /= p64.sum(-1, keepdims=True)
+        ref = np.einsum("bhqk,bhkd->bhqd", p64, v.astype(np.float64))
+        scope = (jax.default_matmul_precision("highest")
+                 if precision == "highest" else contextlib.nullcontext())
+        with scope, _fa_kernels_live(fa):
             got = fa.flash_attention(jnp.asarray(q), jnp.asarray(k),
                                      jnp.asarray(v), None, scale,
                                      128, 128)
-        finally:
-            fa._INTERPRET = prev
-        return {"metric": "rel", "tol": 2e-3,
-                "value": rel_err(ref, got)}
-    return Case("flash_attention", "flash_attention/f32/1x2x256x64",
-                run)
+            composed = fa._attn_reference(
+                jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), None,
+                scale)
+        k_err, c_err = rel_err(ref, got), rel_err(ref, composed)
+        tol = _FA_HIGHEST_TOL
+        if precision != "highest":
+            tol = max(tol, _FA_SLACK * c_err)
+        return {"metric": "rel_vs_f64", "tol": tol, "value": k_err,
+                "note": "composed path %.3g from float64" % c_err}
+    return Case("flash_attention",
+                "flash_attention/f32/%s/1x2x256x64" % precision, run)
+
+
+def dropout_mask_identity() -> Dict[str, Any]:
+    """Do the forward, dq and dkv flash kernels realize the SAME
+    attention-dropout mask? Exact-extraction probe: q = k = 0 makes p
+    uniform, so one-hot v / dO read the keep mask out elementwise from
+    the forward output and from dv, and a per-head zero bias reads it
+    from ds. Compiled kernels draw the mask from the TPU hardware PRNG
+    (``_tile_keep``), which no CPU run reaches; under the interpreter
+    the same probe checks the hash path. Returns ``value`` = mask
+    positions where a backward kernel disagrees with the forward
+    (``tol`` 0) and ``keep_frac``, the realized keep rate."""
+    import jax
+    fa = _fa_module()
+    B, H, S, D = 1, 4, 256, 64
+    bq = bk = 128
+    key = jax.random.PRNGKey(9)
+    t = 205
+    c = 256.0 / t
+    z = jnp.zeros((B, S, H, D), jnp.float32)
+    drop = dict(layout="bshd", dropout=(key, t))
+
+    # jitted once each: the one-hot sweeps reuse one compiled kernel
+    @jax.jit
+    def fwd(v, bias):
+        return fa._fa_forward(z, z, v, bias, 1.0, bq, bk,
+                              return_lse=True, raw_lse=True, **drop)
+
+    @jax.jit
+    def dv_of(out, lse, do):
+        return fa._fa_backward(z, z, z, None, out, lse, do, 1.0, bq,
+                               bk, lse_wide=True, **drop)[2]
+
+    with _fa_kernels_live(fa):
+        m_fwd = np.zeros((H, S, S))
+        for r in range(S // 64):
+            v = np.zeros((B, S, H, D), np.float32)
+            for j in range(64):
+                v[0, r * 64 + j, :, j] = 1.0
+            out, _ = fwd(jnp.asarray(v), None)
+            m_fwd[:, :, r * 64:(r + 1) * 64] = \
+                np.moveaxis(np.asarray(out)[0], 1, 0) * (S / c)
+        m_fwd = m_fwd > 0.5
+
+        out, lse = fwd(z, None)
+        m_dkv = np.zeros((H, S, S))
+        for r in range(S // 64):
+            do = np.zeros((B, S, H, D), np.float32)
+            for i in range(64):
+                do[0, r * 64 + i, :, i] = 1.0
+            dv = dv_of(out, lse, jnp.asarray(do))
+            m_dkv[:, r * 64:(r + 1) * 64, :] = \
+                np.transpose(np.asarray(dv)[0], (1, 2, 0)) * (S / c)
+
+        v = jnp.asarray(
+            _rng(0).standard_normal((B, S, H, D)) * 0.3 + 1.0,
+            jnp.float32)
+        bias_h = jnp.zeros((B, H, S, S), jnp.float32)
+        out, lse = fwd(v, bias_h)
+        _, _, _, dbias = fa._fa_backward(
+            z, z, v, bias_h, out, lse, jnp.ones_like(v), 1.0, bq, bk,
+            lse_wide=True, want_dbias=True, **drop)
+    ds = np.asarray(dbias)[0]
+    w = np.asarray(v.sum(-1))[0]
+    di = np.asarray(out.sum(-1))[0]
+    m_dq = np.zeros((H, S, S))
+    for h in range(H):
+        m_dq[h] = (S * ds[h] + di[:, h:h + 1]) / (c * w[:, h][None, :])
+    return {"metric": "mask_mismatch", "tol": 0,
+            "value": int((m_fwd != (m_dkv > 0.5)).sum()
+                         + (m_fwd != (m_dq > 0.5)).sum()),
+            "keep_frac": float(m_fwd.mean())}
 
 
 def cases() -> List[Case]:
@@ -246,7 +384,8 @@ def cases() -> List[Case]:
         _sgd_case((129, 5)),
         _qmm_case("int8", 5e-2),
         _qmm_case("bf16", 1e-2),
-        _fa_case(),
+        _fa_case("highest"),
+        _fa_case("default"),
     ]
 
 

@@ -40,9 +40,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 _TILE = 128
 
 __all__ = ["quantized_matmul", "quant_mode"]
@@ -115,7 +112,7 @@ def quantized_matmul(x, y, *, mode=None, out_dtype=None):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((_TILE, _TILE), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=registry.interpret(),
     )(x, y)

@@ -16,6 +16,11 @@ Gating, outermost first:
   ``_INTERPRET`` test hook is armed, so tier-1 CI never routes hot paths
   through Pallas interpret mode by accident; tests monkeypatch
   ``_INTERPRET = True`` to exercise kernels on the host.
+* partitioning — inside :func:`auto_partitioned` (the engine tracing a
+  step XLA will partition over a multi-device mesh) every decision is
+  ``lowered`` and counted as such: JAX refuses to lower a Mosaic kernel
+  there ("cannot be automatically partitioned"), so the lowered op,
+  which XLA can partition, stays.
 * per-kernel ``eligible(sig)`` — dtype/shape/layout checks, including
   the ``PT_KERNEL_MIN_NUMEL`` floor where size matters.
 
@@ -31,6 +36,7 @@ See docs/KERNELS.md for the registry model and how to add a kernel.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -41,7 +47,8 @@ __all__ = [
     "Signature", "Kernel", "register_kernel", "select", "signature",
     "routable", "allowed", "count", "kernels", "kernel_names", "get",
     "dispatch_stats", "reset_stats", "min_numel", "interpret",
-    "abstract_select", "candidate_op_types",
+    "abstract_select", "candidate_op_types", "auto_partitioned",
+    "in_auto_partitioned_trace",
 ]
 
 # Test hook: arm to let the registry (and the kernels it selects) run in
@@ -179,13 +186,34 @@ def _platform() -> Optional[str]:
     keeps the lowered path, whose output shapes the kernels match by
     the parity contract, so shape inference is unaffected.
     """
-    try:
-        from jax._src import xla_bridge as xb
-        if not xb._backends:
-            return None
-    except Exception:
-        pass  # private layout changed: fall through and ask jax
+    from jax._src import xla_bridge as xb
+    if not xb._backends:
+        return None
     return jax.default_backend()
+
+
+_TRACE = threading.local()
+
+
+@contextlib.contextmanager
+def auto_partitioned():
+    """Trace-time scope: the step being traced will be partitioned by
+    XLA over a multi-device mesh (core/engine.py enters it for meshes
+    of more than one device). A Mosaic kernel cannot be partitioned
+    automatically — JAX raises NotImplementedError when it lowers one
+    outside a fully-manual shard_map — so inside this scope the
+    dispatchers (:func:`select`; flash attention's use_kernel_path)
+    count every decision ``lowered``."""
+    prev = in_auto_partitioned_trace()
+    _TRACE.auto_partitioned = True
+    try:
+        yield
+    finally:
+        _TRACE.auto_partitioned = prev
+
+
+def in_auto_partitioned_trace() -> bool:
+    return getattr(_TRACE, "auto_partitioned", False)
 
 
 def _deny() -> Tuple[str, ...]:
@@ -272,15 +300,12 @@ def select(op_type: str, sig: Signature) -> Optional[Kernel]:
     from ..core.flags import FLAGS
     flag_on = bool(FLAGS.use_custom_kernels)
     deny = _deny()
+    partitioned = in_auto_partitioned_trace()
     for kern in cands:
         if not flag_on or kern.name in deny:
             count(kern.name, "denied")
             continue
-        try:
-            ok = bool(kern.eligible(sig))
-        except Exception:
-            ok = False
-        if ok:
+        if not partitioned and kern.eligible(sig):
             count(kern.name, "custom")
             return kern
         count(kern.name, "lowered")
@@ -317,11 +342,8 @@ def abstract_select(op_type: str, sig: Signature,
     for kern in cands:
         if kern.name in deny:
             continue
-        try:
-            if bool(kern.eligible(sig)):
-                return kern.name
-        except Exception:
-            continue
+        if kern.eligible(sig):
+            return kern.name
     return None
 
 

@@ -85,7 +85,8 @@ def resnet(input, class_dim=1000, depth=50, is_test=False,
     """input: [B, 3, H, W] (NCHW) or [B, H, W, 3] (NHWC). The two
     layouts are PERFORMANCE-EQUIVALENT in a compiled model (measured
     2,445 vs 2,443 img/s — XLA's layout assignment normalizes conv
-    layouts inside one program; BASELINE.md r5); weights are OIHW in
+    layouts inside one program; July 2026, previous installation);
+    weights are OIHW in
     BOTH layouts so a trained scope serves either graph. Returns
     logits [B, class_dim]."""
     if layout not in ("NCHW", "NHWC"):

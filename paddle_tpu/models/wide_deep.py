@@ -28,8 +28,8 @@ def wide_deep(slot_ids, dense_feat, vocab_size=1000001, embed_dim=16,
     # r4 A/B at B=4096/1M vocab: dense grads 243.6k examples/s vs
     # SelectedRows 154.5k. The dense [vocab, dim] grad + full-table
     # Adagrad pass is ~0.5 GB of clean streaming traffic (measured
-    # 3.5 ms per 64 MB read+write pass on this chip — BASELINE.md's
-    # scatter-bound table), while the sparse path's scatter-add
+    # 3.5 ms per 64 MB read+write pass; July 2026, previous
+    # installation, git history), while the sparse path's scatter-add
     # serializes on TPU (~15M rows/s). Set is_sparse=True when the
     # table cannot afford a dense optimizer pass (multi-GB vocabs).
     emb = layers.embedding(

@@ -32,7 +32,7 @@ def _conv_nd(ctx, nd, depthwise=False):
     dilations = _pair(ctx.attr("dilations", [1] * nd), nd)
     groups = ctx.attr("groups", 1) or 1
     # "NHWC"/"NDHWC" puts channels last (TPU-friendly at small channel
-    # counts — measured 1.5x on ResNet's early stages, BASELINE r5);
+    # counts — measured 1.5x on ResNet's early stages, July 2026);
     # the FILTER stays OI-major either way so both layouts share
     # parameters
     data_format = ctx.attr("data_format", None) or f"NC{'DHW'[-nd:]}"

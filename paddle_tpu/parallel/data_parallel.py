@@ -35,15 +35,25 @@ class DataParallelEngine:
                  data_axis: str = "dp"):
         self._program = program
         devices = None
-        if places:
+        if places is not None:
+            if not len(places):
+                # e.g. places=fluid.tpu_places() on a host where JAX
+                # found no TPU: the caller's error, never a quiet mesh
+                # over whatever the default backend has
+                raise ValueError(
+                    "with_data_parallel: places is empty — no device "
+                    "to run on (fluid.tpu_places() is [] when this "
+                    "process has no local TPU chip); pass places=None "
+                    "for every device of the executor's platform")
             # honor the executor's device platform: an Executor(CPUPlace)
             # with_data_parallel must mesh over CPU devices even when the
             # process default backend is TPU (mixing platforms between
             # feed placement and mesh shardings is a hard error in jax)
             devices = [p.jax_device() if hasattr(p, "jax_device") else p
                        for p in places]
-        self.mesh = make_mesh({data_axis: len(devices)} if devices
-                              else None, devices=devices)
+        self.mesh = make_mesh({data_axis: len(devices)}
+                              if devices is not None else None,
+                              devices=devices)
         self._engine = Engine(mesh=self.mesh, data_axis=data_axis)
 
     @property

@@ -49,7 +49,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..core.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.registry import OPS, ExecContext, _RngCtx

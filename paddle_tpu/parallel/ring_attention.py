@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.jaxcompat import axis_size as _axis_size
 
 
 def _block_attn(q, k, v, bias, scale):
@@ -79,7 +78,7 @@ def _block_bwd(q, k, v, bias, out, lse, di, g, scale):
 
 
 def _ring_forward(q, k, v, bias, axis_name, scale):
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_local = k.shape[2]
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -124,7 +123,7 @@ def _ring_fwd(q, k, v, bias, axis_name, scale):
 
 def _ring_bwd(axis_name, scale, res, g):
     q, k, v, bias, out, lse = res
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_local = k.shape[2]
     perm = [(i, (i + 1) % n) for i in range(n)]

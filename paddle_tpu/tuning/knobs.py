@@ -185,7 +185,7 @@ _def("compiler_options", "env", "PT_COMPILER_OPTIONS", str, "", ("",),
 _def("recompute", "env", "PT_RECOMPUTE", str, "", ("",),
      trace_affecting=True,
      help="op types re-derived at the fwd/bwd boundary (core/engine.py "
-          "_recompute_types); measured loss on ResNet (BASELINE r5) so "
+          "_recompute_types); measured loss on ResNet (July 2026) so "
           "not searched, but trace-affecting and key-audited")
 _def("mesh_axes", "env", "PT_MESH_AXES", str, "", ("",),
      trace_affecting=True,

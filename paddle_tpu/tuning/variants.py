@@ -139,8 +139,6 @@ def tuned_matmul(x, y, *, variant: Variant, gamma=None, beta=None,
     from jax.experimental.pallas import tpu as pltpu
     from ..kernels import registry as kreg
 
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
     bm, bn, bk = variant.bm, variant.bn, variant.bk
     M, K = x.shape
     K2, N = y.shape
@@ -164,7 +162,7 @@ def tuned_matmul(x, y, *, variant: Variant, gamma=None, beta=None,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=kreg.interpret(),
     )

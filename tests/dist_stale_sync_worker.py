@@ -94,7 +94,7 @@ def main():
         state[n] = arr
 
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from paddle_tpu.core.jaxcompat import shard_map
+    from jax import shard_map
     mesh = Mesh(np.array(jax.devices()), ("dp",))
 
     def to_global(local):

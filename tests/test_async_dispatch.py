@@ -48,6 +48,23 @@ def _delta(before, after):
 # fast-path step cache
 # ---------------------------------------------------------------------------
 
+def test_step_compiles_once_across_dispatches():
+    """Startup leaves the params uncommitted and a step's outputs are
+    committed: unless the engine commits what the step donates before
+    the FIRST dispatch, the second one has other argument shardings and
+    XLA compiles the whole step again (invisible to counters["traces"];
+    on the chip it doubled the cold set-up)."""
+    main, startup, loss = _sgd_model()
+    scope = Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        for _ in range(3):
+            exe.run(main, feed=_feeds(), fetch_list=[loss.name])
+    counts = exe._engine.step_executables()
+    assert counts and set(counts) == {1}, counts
+
+
 def test_steady_state_counters_zero_redundant_work():
     """After warmup, device-resident feeds hit the fast path: no
     signature rebuild, no re-trace, no device_put — per run."""

@@ -21,7 +21,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu.core.engine import Engine
-from paddle_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 from paddle_tpu.core.scope import Scope
 from paddle_tpu.parallel import DistributedStrategy
 from paddle_tpu.parallel import comm_scheduler as cs

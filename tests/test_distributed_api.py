@@ -8,7 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from paddle_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 
 import paddle_tpu as fluid
 from paddle_tpu.core.scope import Scope

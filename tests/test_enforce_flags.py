@@ -300,8 +300,7 @@ def test_num_iteration_per_run_on_island_fallback():
 def test_pt_recompute_trajectory_parity(monkeypatch):
     """PT_RECOMPUTE re-derives the fwd stash behind optimization
     barriers; without AMP the trajectory must be EXACT (the pass only
-    changes buffer lifetimes, not math). Measured perf story in
-    BASELINE.md ('remat attempt')."""
+    changes buffer lifetimes, not math)."""
     import numpy as np
     import paddle_tpu as fluid
     from paddle_tpu import layers

@@ -124,6 +124,50 @@ def test_unknown_op_selects_nothing(interp):
                                               (256,))) is None
 
 
+def test_mesh_step_counts_every_decision_lowered(interp):
+    """A step XLA partitions over a mesh cannot hold a Mosaic kernel
+    (JAX refuses to lower it): the engine traces it inside
+    registry.auto_partitioned(), the product lowerings still reach
+    select(), and every decision is counted ``lowered`` — the same
+    Adam step without a mesh routes to the kernel."""
+    from paddle_tpu.parallel import DistributedStrategy
+
+    def adam_counts(strategy):
+        fluid.framework.unique_name.reset()
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            loss = _mlp_adam()
+        scope = Scope()
+        kreg.reset_stats()
+        with fluid.scope_guard(scope):
+            fluid.Executor().run(startup)
+            Engine(strategy=strategy).run(main, scope, None, _feed(),
+                                          [loss.name])
+        return kreg.dispatch_stats()["per_kernel"]["fused_adam"]
+
+    meshed = adam_counts(DistributedStrategy(axes={"dp": 2}))
+    assert set(meshed) == {"lowered"} and meshed["lowered"] > 0, meshed
+    assert adam_counts(None).get("custom", 0) > 0
+
+
+def test_select_propagates_eligible_error(interp):
+    """A kernel whose eligibility check raises is a bug to see, not a
+    quiet "not eligible" that leaves a slower path running."""
+    def boom(sig):
+        raise ValueError("eligibility bug")
+
+    kreg.register_kernel("boom", op_types=("boom_op",), eligible=boom,
+                         run=lambda *a: None)
+    try:
+        with pytest.raises(ValueError, match="eligibility bug"):
+            kreg.select("boom_op", _sig_f32("boom_op", (256,)))
+        with pytest.raises(ValueError, match="eligibility bug"):
+            kreg.abstract_select("boom_op", _sig_f32("boom_op", (256,)))
+    finally:
+        kreg._KERNELS.pop("boom")
+        kreg._BY_OP.pop("boom_op")
+
+
 # ---------------------------------------------------------------------------
 # numerics parity (the tier-1 gate for every registered kernel)
 # ---------------------------------------------------------------------------

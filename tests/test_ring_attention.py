@@ -5,7 +5,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from paddle_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 
 from paddle_tpu.kernels.flash_attention import _attn_reference
 from paddle_tpu.parallel.ring_attention import ring_attention

@@ -294,9 +294,15 @@ class TestAttribution(unittest.TestCase):
             self.assertGreater(
                 metrics.gauge("pt_hbm_peak_bytes").get(), 0)
 
-    def test_mfu_estimate_requires_known_peak(self):
-        # CPU hosts have no PEAK_TFLOPS entry: None, never a bogus MFU
+    def test_mfu_estimate_needs_a_tpu(self):
+        # a host backend has no MXU peak: None, never a bogus MFU
         self.assertIsNone(attribution.mfu_estimate(1e12, 0.1))
+
+    def test_unknown_device_kind_raises(self):
+        # the single peak table: an unlisted chip is an error
+        self.assertEqual(attribution.peak_tflops("TPU v5 lite"), 197.0)
+        with self.assertRaisesRegex(KeyError, "TPU v99"):
+            attribution.peak_tflops("TPU v99")
 
 
 # ---------------------------------------------------------------------------

@@ -48,20 +48,6 @@ def _build(collective, n_elems, mesh):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-    import inspect
-    if "check_vma" not in inspect.signature(shard_map).parameters:
-        # older jax spells the kwarg check_rep
-        _inner = shard_map
-
-        def shard_map(f, **kw):
-            if "check_vma" in kw:
-                kw["check_rep"] = kw.pop("check_vma")
-            return _inner(f, **kw)
-
     n_dev = mesh.shape["x"]
     if collective == "all_reduce":
         in_spec, out_spec = P(None), P(None)
@@ -94,7 +80,7 @@ def _build(collective, n_elems, mesh):
         raise SystemExit(f"unknown collective {collective!r}")
 
     @jax.jit
-    @functools.partial(shard_map, mesh=mesh, in_specs=(in_spec,),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(in_spec,),
                        out_specs=out_spec, check_vma=False)
     # check_vma=False: collectives flip values between replicated and
     # device-varying types across scan iterations; the chain is a

@@ -3,8 +3,8 @@ HBM-traffic-by-source table (paddle_tpu.tools.hbm_breakdown).
 
 Usage: python tools/traffic_report.py [transformer|resnet50] [--dump FILE]
 
-This is the auditable input behind BASELINE.md's traffic-by-category
-table (VERDICT r3 #1): it compiles the exact step bench.py times, asks
+This is the auditable input behind a traffic-by-category table
+(VERDICT r3 #1): it compiles the exact step bench.py times, asks
 XLA for cost/memory analysis, and attributes the optimized HLO's bytes
 to framework source lines.
 """
