@@ -21,6 +21,7 @@ import numpy as np
 import jax
 
 from . import framework
+from . import profiler as _profiler
 from .core.flags import FLAGS
 from .core.scope import LoDTensor
 
@@ -186,8 +187,41 @@ class CompiledProgram:
         self._inference_config = config
         return self
 
-    def _run(self, executor, feed, fetch_names, scope, return_numpy):
+    def _step_engine(self, executor):
+        """The engine whose run counter numbers this program's steps:
+        the data-parallel engine once it is built, else the
+        executor's."""
+        if self._dp_engine is not None:
+            return self._dp_engine._engine
+        return executor._engine
+
+    def _run(self, executor, feed, fetch_list, scope, return_numpy):
+        with _profiler.step_clock().phase(_profiler.P_EXECUTOR_FEED):
+            feed, fetch_names, scope, iters = self._prepare(
+                executor, feed, fetch_list, scope)
+        if not self._is_data_parallel:
+            # K iterations compile into ONE lax.scan executable on the
+            # jit path (host-looped on the eager/islands fallbacks)
+            return executor._engine.run(
+                self._program, scope, executor.place, feed, fetch_names,
+                return_numpy=return_numpy, iterations=iters)
+        # num_iteration_per_run routes INTO the engine: K chained steps
+        # compile into one lax.scan executable (fetches from the last
+        # iteration), instead of the old host loop that fully synced
+        # every iteration — see DataParallelEngine.run for the remaining
+        # gap vs the single-device path
+        return self._dp_engine.run(feed, fetch_names, scope,
+                                   return_numpy, self._loss_name,
+                                   iterations=iters)
+
+    def _prepare(self, executor, feed, fetch_list, scope):
+        """What a step needs before an engine takes it: names, scope,
+        validation, the canonical feed, and (once) the data-parallel
+        engine."""
+        from .executor import _to_name_str, global_scope
         from .parallel.data_parallel import DataParallelEngine
+        scope = scope or global_scope()
+        fetch_names = [_to_name_str(f) for f in fetch_list or ()]
         if FLAGS.validate_program and isinstance(
                 self._program, framework.Program):
             from .analysis import validate_cached
@@ -211,23 +245,11 @@ class CompiledProgram:
                     or 1) if self._exec_strategy is not None else 1
         if not self._is_data_parallel:
             feed = executor._canonical_feed(feed, self._program)
-            # K iterations compile into ONE lax.scan executable on the
-            # jit path (host-looped on the eager/islands fallbacks)
-            return executor._engine.run(
-                self._program, scope, executor.place, feed, fetch_names,
-                return_numpy=return_numpy, iterations=iters)
-        if self._dp_engine is None:
+        elif self._dp_engine is None:
             places = self._places
             if places is None and executor.place is not None:
                 # default to every device of the executor's platform
                 places = _platform_devices(executor.place)
             self._dp_engine = DataParallelEngine(
                 self._program, self._build_strategy, places)
-        # num_iteration_per_run routes INTO the engine: K chained steps
-        # compile into one lax.scan executable (fetches from the last
-        # iteration), instead of the old host loop that fully synced
-        # every iteration — see DataParallelEngine.run for the remaining
-        # gap vs the single-device path
-        return self._dp_engine.run(feed, fetch_names, scope,
-                                   return_numpy, self._loss_name,
-                                   iterations=iters)
+        return feed, fetch_names, scope, iters

@@ -13,6 +13,7 @@ liveness replaces the eager-deletion GC.
 """
 from __future__ import annotations
 
+import gc
 import os
 
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -34,6 +35,7 @@ from ..observability import metrics as _obs
 from ..observability import recorder as _obs_recorder
 from ..observability import tracing as _obs_tracing
 from ..observability import memory as _obs_memory
+from .. import profiler as _profiler
 
 RNG_STATE_VAR = "@RNG_STATE@"
 
@@ -83,6 +85,9 @@ class TracedStep:
         self.updated_names = updated_names
         self.fetch_lods = fetch_lods  # name -> lod (host metadata)
         self.uses_rng = uses_rng
+        # the first call of fn lowers and compiles (or loads) the
+        # executable: Engine._first_dispatch spans it, once
+        self.dispatched = False
         # PT_MULTI_STEP: K > 1 means fn scans K stacked batches through
         # one executable and returns (stacked_fetches, updated,
         # nan_flags, ms_info) instead of the 3-tuple contract
@@ -216,6 +221,16 @@ def _recompute_types():
     return frozenset(t for t in spec.split(",") if t) if spec else None
 
 
+def _op_scope(op):
+    """The op's name in the compiled step's HLO metadata
+    (`op_name="jit(step1)/forward/layer_norm/..."`): the name scope it
+    was built under (`fluid.name_scope`, the `op_namescope` attribute),
+    its role and its type. Metadata only: fusion decisions and
+    instruction names stay as they are."""
+    return (f"{op.attr('op_namescope', '')}"
+            f"{op.attr('op_role', 'forward')}/{op.type}")
+
+
 def _recompute_stash(fwd_ops, bwd_ops, env, types, rng_ctx, lod_env,
                      block_runner):
     bwd_reads = set()
@@ -236,7 +251,8 @@ def _recompute_stash(fwd_ops, bwd_ops, env, types, rng_ctx, lod_env,
                 if v is not None and hasattr(v, "dtype"):
                     sub[n] = jax.lax.optimization_barrier(v)
         ctx = ExecContext(op, sub, rng_ctx, block_runner, lod_env)
-        OPS.get(op.type).lowering(ctx)
+        with jax.named_scope(_op_scope(op)):
+            OPS.get(op.type).lowering(ctx)
         for n in outs:
             # rebind ONLY bwd-consumed, non-persistable outputs; a
             # persistable output (bn running stats) must not apply its
@@ -287,7 +303,8 @@ def run_block_ops(block, env, rng_ctx, lod_env, block_runner, ops=None,
         try:
             info = OPS.get(op.type)
             ctx = ExecContext(op, env, rng_ctx, block_runner, lod_env)
-            info.lowering(ctx)
+            with jax.named_scope(_op_scope(op)):
+                info.lowering(ctx)
         except (NotImplementedError, jax.errors.JAXTypeError) as exc:
             # handled by the island partitioner; overwrite so the
             # OUTERMOST frame's index wins (a dynamic op inside a
@@ -499,6 +516,23 @@ def trace_step(program, block_idx: int, feed_sig: Dict[str, Any],
                scope: Scope, mesh=None, data_axis: str = "dp",
                strategy=None, iterations: int = 1,
                multi_step: int = 1) -> TracedStep:
+    """:func:`_trace_step` under its set-up span (`trace_step`, with
+    the child `trace_step.op_walk`: the abstract walk over every op's
+    lowering that finds the updated persistables; the rest, building
+    the jitted callable, is the parent's own time;
+    observability/tracing.py): what a process spends tracing is kept
+    whether or not telemetry is on."""
+    with _obs_tracing.setup_span(
+            "trace_step", program=program.fingerprint[0],
+            ops=len(program.block(block_idx).ops)) as span:
+        return _trace_step(program, block_idx, feed_sig, feed_lods,
+                           fetch_names, scope, mesh, data_axis,
+                           strategy, iterations, multi_step, span)
+
+
+def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
+                scope, mesh, data_axis, strategy, iterations,
+                multi_step, span) -> TracedStep:
     """Build + jit the step function for one (program, feed-sig) pair.
 
     With `mesh`, the step is compiled SPMD: feeds sharded on their batch
@@ -821,7 +855,8 @@ def trace_step(program, block_idx: int, feed_sig: Dict[str, Any],
         if opaque_state:
             raise NotImplementedError(
                 f"persistable {n!r} holds a host-side state object")
-        jax.eval_shape(step, params_sig, feed_sig, key_sig)
+        with _obs_tracing.setup_span("trace_step.op_walk", parent=span):
+            jax.eval_shape(step, params_sig, feed_sig, key_sig)
     except (NotImplementedError, jax.errors.JAXTypeError) as reason:
         # Block contains value-dependent-shape ops (edit_distance,
         # sequence_erase, save, ...) or host-state persistables: compile
@@ -1155,6 +1190,63 @@ class _FastPathEntry:
         self.sig_hash: Optional[str] = None
 
 
+def _sig_hash(sig):
+    """Short feed-signature identifier of a flight record."""
+    return sig if isinstance(sig, str) \
+        else f"{hash(sig) & 0xffffffff:08x}"
+
+
+class _SlowSteps:
+    """An engine's running view of its own step times, from the
+    always-on StepClock: a step is slow when it takes more than
+    `FACTOR` times the median of the last `WINDOW` steps, judged only
+    once `MIN_HISTORY` exist (so a first, compiling step never is).
+    The median is refreshed every `REFRESH` steps, not every step; the
+    interpreter's collection counts are read at each refresh and at
+    each slow step, so a slow step's `gc` delta covers the steps since
+    the last of those (at most `REFRESH`)."""
+
+    WINDOW, MIN_HISTORY, REFRESH, FACTOR = 64, 16, 16, 3.0
+
+    __slots__ = ("_ns", "_i", "median_ns", "_limit_ns", "_gc", "_gc_i")
+
+    def __init__(self):
+        self._ns: List[int] = []
+        self._i = 0
+        self.median_ns = 0
+        self._limit_ns = None
+        self._gc = None
+        self._gc_i = 0
+
+    def is_slow(self, total_ns) -> bool:
+        limit = self._limit_ns
+        slow = limit is not None and total_ns > limit
+        ns = self._ns
+        if len(ns) < self.WINDOW:
+            ns.append(total_ns)
+        else:
+            ns[self._i % self.WINDOW] = total_ns
+        self._i += 1
+        if self._i % self.REFRESH == 0 and self._i >= self.MIN_HISTORY:
+            self.median_ns = sorted(ns)[len(ns) // 2]
+            self._limit_ns = self.FACTOR * self.median_ns
+            if not slow:
+                self._read_gc()
+        return slow
+
+    def _read_gc(self):
+        seen = [g["collections"] for g in gc.get_stats()]
+        before, self._gc = self._gc, seen
+        steps, self._gc_i = self._i - self._gc_i, self._i
+        return before, seen, steps
+
+    def gc_delta(self):
+        """Collections of each generation since the last reading."""
+        before, seen, steps = self._read_gc()
+        return {"collections": [b - a for a, b in zip(before, seen)],
+                "over_steps": steps}
+
+
 # deferred-check records kept in flight before the oldest is forced to
 # materialize — the pipeline-depth backstop that keeps an un-materialized
 # async training loop from accumulating unchecked device flags forever
@@ -1270,6 +1362,9 @@ class Engine:
         # (docs/ASYNC_DISPATCH.md "Multi-step dispatch")
         self._last_multi = None
         self.last_multi_fetches = None
+        # step totals off the always-on StepClock: a slow step reaches
+        # the flight recorder without the telemetry switch
+        self._slow = _SlowSteps()
 
     def _step_watchdog(self):
         """The armed-per-dispatch hang detector (FLAGS_step_timeout_s);
@@ -1775,73 +1870,82 @@ class Engine:
             # recovery path is exercised end to end in chaos runs
             if feed:
                 feed = plan.corrupt_feed(self.counters["runs"], feed)
-        # ONE boolean gates all per-step telemetry (phase spans, flight
-        # recorder); obs stays None on the cold path
-        obs = None
+        # the step's phases stamp this thread's StepClock (profiler.py):
+        # spans in any open profiler session and two clock reads each,
+        # whatever the telemetry switch says. The flight/telemetry
+        # record is built from the stamps at the end of the step, and
+        # only while metrics._HOT is set or the step was slow
+        clock = _profiler.step_clock()
+        if clock.opened:
+            clock.opened = False    # Executor.run began this step
+        else:
+            clock.begin_step()
         if _obs._HOT[0]:
-            obs = {"step": self.counters["runs"], "t_host": time.time(),
-                   "_t0": time.perf_counter(), "phases": {},
-                   "fast_path": False, "traced": False}
             # deterministic trace id for this step: RPCs, deferred
             # fetches and checkpoint saves issued below inherit it
             # (docs/TRACING.md)
-            _obs_tracing.begin_step(obs["step"])
+            _obs_tracing.begin_step(self.counters["runs"])
         iterations = int(iterations or 1)
-        fast_key = None
-        if use_program_cache:
-            fast_key = self._fast_key(program, block_idx, fetch_names,
-                                      iterations, multi_step)
-            # one entry per live feed signature (entries disagree on
-            # shapes, so at most one converts the feed); small list —
-            # a training loop sees 1-2 signatures (train + eval tail)
-            for entry in self._fast.get(fast_key, ()):
-                if entry.scope is scope and (
-                        entry.place is place or entry.dev == (
-                            place.jax_device()
-                            if place is not None and self.mesh is None
-                            else None)):
-                    arrays = self._fast_feed_arrays(entry, feed)
-                    if arrays is not None:
-                        self.counters["fast_path_hits"] += 1
-                        if obs is not None:
-                            obs["fast_path"] = True
-                            obs["sig"] = entry.sig_hash
-                            obs["phases"]["feed_ms"] = (
-                                time.perf_counter() - obs["_t0"]) * 1e3
-                        donated = {n: _var_array(v)
-                                   for n, v in entry.donated_vars}
-                        const = {n: _var_array(v)
-                                 for n, v in entry.const_vars}
-                        outs = self._dispatch(
-                            program, scope, entry.traced, arrays,
-                            donated, const, return_numpy,
-                            updated_vars=entry.updated_vars, obs=obs)
-                        if multi_step > 1:
-                            return self._finish_multi(
-                                outs, program, scope, place, feed,
-                                fetch_names, block_idx,
-                                return_numpy, multi_step)
-                        return outs
-        arrays, lods, feed_sig_key = self._normalize_feed(
-            feed, None if self.mesh is not None else place)
-        multihost = self._is_multihost()
-        if multihost:
-            if lods:
-                # Ragged feeds are supported when every process's batch
-                # has the SAME LoD signature (what length-bucketing
-                # produces): the single global program then sees the
-                # k-fold replicated offsets, and row blocks concatenate
-                # uniformly. Divergent per-process lods would need
-                # per-process programs — SPMD cannot express that.
-                self._verify_uniform_lods(lods)
-                lods = {n: self._replicate_lod(lod)
-                        for n, lod in lods.items()}
-            feed_sig_key = self._global_sig_key(arrays, lods)
-            arrays = self._globalize(arrays)
-        if obs is not None:
-            obs["sig"] = f"{hash(feed_sig_key) & 0xffffffff:08x}"
-            obs["phases"]["feed_ms"] = (time.perf_counter()
-                                        - obs["_t0"]) * 1e3
+        fast_key = entry = None
+        with clock.phase(_profiler.P_FEED):
+            if use_program_cache:
+                fast_key = self._fast_key(program, block_idx,
+                                          fetch_names, iterations,
+                                          multi_step)
+                # one entry per live feed signature (entries disagree
+                # on shapes, so at most one converts the feed); small
+                # list — a training loop sees 1-2 signatures (train +
+                # eval tail)
+                for e in self._fast.get(fast_key, ()):
+                    if e.scope is scope and (
+                            e.place is place or e.dev == (
+                                place.jax_device()
+                                if place is not None
+                                and self.mesh is None else None)):
+                        arrays = self._fast_feed_arrays(e, feed)
+                        if arrays is not None:
+                            entry = e
+                            break
+            if entry is None:
+                arrays, lods, feed_sig_key = self._normalize_feed(
+                    feed, None if self.mesh is not None else place)
+                multihost = self._is_multihost()
+                if multihost:
+                    if lods:
+                        # Ragged feeds are supported when every
+                        # process's batch has the SAME LoD signature
+                        # (what length-bucketing produces): the single
+                        # global program then sees the k-fold
+                        # replicated offsets, and row blocks
+                        # concatenate uniformly. Divergent per-process
+                        # lods would need per-process programs — SPMD
+                        # cannot express that.
+                        self._verify_uniform_lods(lods)
+                        lods = {n: self._replicate_lod(lod)
+                                for n, lod in lods.items()}
+                    feed_sig_key = self._global_sig_key(arrays, lods)
+                    arrays = self._globalize(arrays)
+        if entry is not None:
+            self.counters["fast_path_hits"] += 1
+            clock.fast_path = True
+            clock.sig = entry.sig_hash
+            with clock.phase(_profiler.P_ARGS):
+                donated = {n: _var_array(v)
+                           for n, v in entry.donated_vars}
+                const = {n: _var_array(v) for n, v in entry.const_vars}
+            outs = self._dispatch(
+                program, scope, entry.traced, arrays, donated, const,
+                return_numpy, updated_vars=entry.updated_vars)
+            with clock.phase(_profiler.P_RELEASE):
+                # the donated arrays' wrappers die with this dict
+                del donated, const
+            self._finish_step(clock, entry.traced, arrays)
+            if multi_step > 1:
+                return self._finish_multi(
+                    outs, program, scope, place, feed, fetch_names,
+                    block_idx, return_numpy, multi_step)
+            return outs
+        clock.sig = feed_sig_key    # hashed only if a record is built
         if iterations > 1 and lods:
             raise NotImplementedError(
                 "num_iteration_per_run > 1 cannot scan over LoD "
@@ -1855,100 +1959,110 @@ class Engine:
         traced = self._cache.get(key) if use_program_cache else None
         if traced is None:
             self.counters["traces"] += 1
-            _tt0 = time.perf_counter() if obs is not None else 0.0
-            feed_sig = {n: jax.ShapeDtypeStruct(a.shape, a.dtype)
-                        for n, a in arrays.items()}
-            traced = trace_step(program, block_idx, feed_sig, lods,
-                                fetch_names, scope, mesh=self.mesh,
-                                data_axis=self.data_axis,
-                                strategy=self.strategy,
-                                iterations=iterations,
-                                multi_step=multi_step)
-            if FLAGS.validate_program and \
-                    int(FLAGS.validate_tier) >= 2:
-                # tier 2: re-verify the step we ACTUALLY traced — the
-                # partition the scheduler would dispatch, proven
-                # conflict-free under the ground-truth updated/donated
-                # sets phase 1 discovered (vs tier 1's static
-                # inference at the executor boundary). Runs once per
-                # trace build; raises before anything compiles.
-                from ..analysis.validate import validate_traced
-                validate_traced(program, block_idx,
-                                traced.updated_names,
-                                traced.donated_names, fetch_names)
-                # ... and cross-check the step's lowering decisions
-                # (guard gate, collective plan, island-gate choice)
-                # against the static conformance trace — same tier,
-                # same once-per-trace-build cost
-                # (analysis/conformance.py).
-                from ..analysis.conformance import crosscheck_traced
-                crosscheck_traced(program, block_idx, traced,
-                                  mesh=self.mesh,
-                                  data_axis=self.data_axis,
-                                  strategy=self.strategy)
+            clock.traced = True
+            with clock.phase(_profiler.P_TRACE):
+                traced = self._trace(program, block_idx, arrays, lods,
+                                     fetch_names, scope, iterations,
+                                     multi_step)
             if use_program_cache:
                 self._cache[key] = traced
-            if obs is not None:
-                obs["traced"] = True
-                obs["phases"]["trace_ms"] = (time.perf_counter()
-                                             - _tt0) * 1e3
 
-        donated_params = {}
-        const_params = {}
-        for n in traced.donated_names:
-            donated_params[n] = _scope_array(scope, n)
-        for n in traced.const_names:
-            const_params[n] = _scope_array(scope, n)
-        if place is not None and self.mesh is None:
-            # a startup program has no committed input, so it leaves
-            # the params UNCOMMITTED; this step's outputs are committed
-            # (the feeds are). Commit what the step donates now, or the
-            # second dispatch sees other argument shardings than the
-            # first and XLA compiles the whole step a second time
-            donated_params = jax.device_put(donated_params,
-                                            place.jax_device())
-        if multihost:
-            # params already produced by a previous multihost step are
-            # global arrays; only host-local values need assembling —
-            # and globalized const params are written back to the scope
-            # so the transfer happens once, not per step
-            def _as_global(n, v, write_back):
-                if isinstance(v, jax.Array) and \
-                        not v.is_fully_addressable:
-                    return v
-                g = self._globalize_replicated({n: v})[n]
-                if write_back:
-                    scope.var(n).set_value(g)
-                return g
+        with clock.phase(_profiler.P_ARGS):
+            donated_params = {}
+            const_params = {}
+            for n in traced.donated_names:
+                donated_params[n] = _scope_array(scope, n)
+            for n in traced.const_names:
+                const_params[n] = _scope_array(scope, n)
+            if place is not None and self.mesh is None:
+                # a startup program has no committed input, so it
+                # leaves the params UNCOMMITTED; this step's outputs
+                # are committed (the feeds are). Commit what the step
+                # donates now, or the second dispatch sees other
+                # argument shardings than the first and XLA compiles
+                # the whole step a second time
+                donated_params = jax.device_put(donated_params,
+                                                place.jax_device())
+            if multihost:
+                # params already produced by a previous multihost step
+                # are global arrays; only host-local values need
+                # assembling — and globalized const params are written
+                # back to the scope so the transfer happens once, not
+                # per step
+                def _as_global(n, v, write_back):
+                    if isinstance(v, jax.Array) and \
+                            not v.is_fully_addressable:
+                        return v
+                    g = self._globalize_replicated({n: v})[n]
+                    if write_back:
+                        scope.var(n).set_value(g)
+                    return g
 
-            donated_params = {n: _as_global(n, v, False)
-                              for n, v in donated_params.items()}
-            const_params = {n: _as_global(n, v, True)
-                            for n, v in const_params.items()}
-        elif fast_key is not None:
-            # steady-state record: subsequent runs of this (program,
-            # feed-sig, fetch) tuple skip signature reconstruction,
-            # persistable re-walks, and no-op device_puts
-            entries = self._fast.setdefault(fast_key, [])
-            entry = _FastPathEntry(
-                scope, place, place.jax_device()
-                if place is not None and self.mesh is None else None,
-                arrays, lods, traced)
-            entry.sig_hash = f"{hash(feed_sig_key) & 0xffffffff:08x}"
-            entries.append(entry)
-            if len(entries) > _MAX_FAST_ENTRIES:
-                entries.pop(0)
-        # cold path only: register the scope with the memory census
-        # (one weak-set add per trace, nothing per steady-state step)
-        _obs_memory.track_scope(scope)
+                donated_params = {n: _as_global(n, v, False)
+                                  for n, v in donated_params.items()}
+                const_params = {n: _as_global(n, v, True)
+                                for n, v in const_params.items()}
+            elif fast_key is not None:
+                # steady-state record: subsequent runs of this
+                # (program, feed-sig, fetch) tuple skip signature
+                # reconstruction, persistable re-walks, and no-op
+                # device_puts
+                entries = self._fast.setdefault(fast_key, [])
+                entry = _FastPathEntry(
+                    scope, place, place.jax_device()
+                    if place is not None and self.mesh is None
+                    else None, arrays, lods, traced)
+                entry.sig_hash = _sig_hash(feed_sig_key)
+                entries.append(entry)
+                if len(entries) > _MAX_FAST_ENTRIES:
+                    entries.pop(0)
+            # cold path only: register the scope with the memory census
+            # (one weak-set add per trace, nothing per steady-state
+            # step)
+            _obs_memory.track_scope(scope)
         outs = self._dispatch(program, scope, traced, arrays,
                               donated_params, const_params,
-                              return_numpy, obs=obs)
+                              return_numpy)
+        with clock.phase(_profiler.P_RELEASE):
+            del donated_params, const_params
+        self._finish_step(clock, traced, arrays)
         if multi_step > 1:
             return self._finish_multi(outs, program, scope, place,
                                       feed, fetch_names, block_idx,
                                       return_numpy, multi_step)
         return outs
+
+    def _trace(self, program, block_idx, arrays, lods, fetch_names,
+               scope, iterations, multi_step):
+        """The cold path of :meth:`run`: trace the step, then tier-2
+        validation of what was traced."""
+        feed_sig = {n: jax.ShapeDtypeStruct(a.shape, a.dtype)
+                    for n, a in arrays.items()}
+        traced = trace_step(program, block_idx, feed_sig, lods,
+                            fetch_names, scope, mesh=self.mesh,
+                            data_axis=self.data_axis,
+                            strategy=self.strategy,
+                            iterations=iterations,
+                            multi_step=multi_step)
+        if FLAGS.validate_program and int(FLAGS.validate_tier) >= 2:
+            # tier 2: re-verify the step we ACTUALLY traced — the
+            # partition the scheduler would dispatch, proven
+            # conflict-free under the ground-truth updated/donated
+            # sets phase 1 discovered (vs tier 1's static inference at
+            # the executor boundary). Runs once per trace build;
+            # raises before anything compiles.
+            from ..analysis.validate import validate_traced
+            validate_traced(program, block_idx, traced.updated_names,
+                            traced.donated_names, fetch_names)
+            # ... and cross-check the step's lowering decisions (guard
+            # gate, collective plan, island-gate choice) against the
+            # static conformance trace — same tier, same
+            # once-per-trace-build cost (analysis/conformance.py).
+            from ..analysis.conformance import crosscheck_traced
+            crosscheck_traced(program, block_idx, traced,
+                              mesh=self.mesh, data_axis=self.data_axis,
+                              strategy=self.strategy)
+        return traced
 
     def _finish_multi(self, outs, program, scope, place, feed,
                       fetch_names, block_idx, return_numpy, k):
@@ -2007,8 +2121,7 @@ class Engine:
         return self.last_multi_fetches
 
     def _dispatch(self, program, scope, traced, arrays, donated_params,
-                  const_params, return_numpy, updated_vars=None,
-                  obs=None):
+                  const_params, return_numpy, updated_vars=None):
         """Watchdog wrapper over :meth:`_dispatch_inner`: with
         FLAGS_step_timeout_s > 0 the step runs armed, and a hang is
         converted into the watchdog's diagnosable EnforceNotMet (the
@@ -2018,13 +2131,13 @@ class Engine:
         if wd is None:
             return self._dispatch_inner(
                 program, scope, traced, arrays, donated_params,
-                const_params, return_numpy, updated_vars, obs)
+                const_params, return_numpy, updated_vars)
         try:
             try:
                 wd.arm()
                 return self._dispatch_inner(
                     program, scope, traced, arrays, donated_params,
-                    const_params, return_numpy, updated_vars, obs)
+                    const_params, return_numpy, updated_vars)
             finally:
                 wd.disarm()
         except KeyboardInterrupt:
@@ -2032,20 +2145,53 @@ class Engine:
                 raise wd.error from None
             raise
 
-    def _obs_finish(self, obs, feed_arrays=None):
-        """Close out one step's flight/telemetry record: total span,
-        then hand it to the recorder (histogram observes + ring
-        append), derive the step's trace spans from the same timings,
-        and tick the deep-profile trigger — all behind the one _HOT
-        boolean that built obs."""
-        obs["phases"]["total_ms"] = (time.perf_counter()
-                                     - obs.pop("_t0")) * 1e3
-        # census attribution for the step's device-side feed batch:
-        # held until the next step replaces it (owner "feed"), cleared
-        # when the census is off so the batch is not kept alive
-        self._census_feed = (feed_arrays
-                             if _obs_memory.census_active() else None)
+    def _finish_step(self, clock, traced, feed_arrays):
+        """Close out one step from the clock's stamps. Always: the
+        step's total joins the slow-step history. While metrics._HOT is
+        set, or when the step was slow, the flight/telemetry record is
+        built — the one place that is — and handed to the recorder
+        (histogram observes + ring append); while _HOT, the step's
+        trace spans are derived from the same record and the
+        deep-profile and memory ticks run."""
+        total_ns = clock.end_step()
+        slow = self._slow.is_slow(total_ns)
+        hot = _obs._HOT[0]
+        if not (hot or slow):
+            return
+        dur, off = clock.phases()
+        obs = {"step": self.counters["runs"],
+               "t_host": time.time() - total_ns / 1e9,
+               "phases": dur, "phase_t0_ms": off,
+               "fast_path": clock.fast_path, "traced": clock.traced,
+               "pending_fetches": len(self._pending)}
+        if clock.sig is not None:
+            obs["sig"] = _sig_hash(clock.sig)
+        comm_stats = getattr(traced, "comm_stats", None)
+        if comm_stats:
+            obs["comm_plan"] = comm_stats.get("plan_id",
+                                              comm_stats["buckets"])
+        sched = getattr(traced, "op_sched", None)
+        if sched is not None and sched.last_stats:
+            obs["lanes"] = sched.last_stats.get("spans")
+            obs["phases"]["lane_idle_ms"] = sched.last_stats.get(
+                "lane_idle_ms", 0.0)
+        last = getattr(self._stability, "last", None)
+        if last and last.get("step") == obs["step"]:
+            obs["anomaly"] = dict(last)
+        if slow:
+            obs["slow"] = True
+            obs["median_ms"] = self._slow.median_ns / 1e6
+            obs["gc"] = self._slow.gc_delta()
+        if hot:
+            # census attribution for the step's device-side feed batch:
+            # held until the next step replaces it (owner "feed"),
+            # cleared when the census is off so the batch is not kept
+            # alive
+            self._census_feed = (feed_arrays
+                                 if _obs_memory.census_active() else None)
         _obs_recorder.record_step(obs)
+        if not hot:
+            return
         _obs_tracing.finish_step(obs)
         try:
             from ..observability import attribution as _obs_attr
@@ -2059,126 +2205,177 @@ class Engine:
 
     def _dispatch_inner(self, program, scope, traced, arrays,
                         donated_params, const_params, return_numpy,
-                        updated_vars=None, obs=None,
-                        _guard_reexec=False):
+                        updated_vars=None, _guard_reexec=False):
         """Shared dispatch tail of fast and slow paths: RNG split,
         executable call, device-resident scope writeback, NaN-check
         surfacing (inline or deferred), fetch wrapping. Under
         FLAGS.async_dispatch nothing here forces a device sync — the
         RNG split and persistable writebacks stay jax.Array futures and
         the nan-flag host sync moves to the materialization point."""
-        rng_key = _get_rng_state(scope, program)
+        clock = _profiler.step_clock()
         multi_k = int(getattr(traced, "multi_step", 1) or 1)
-        if multi_k > 1:
-            # multi-step (PT_MULTI_STEP): the scanned executable splits
-            # the rng PER SUBSTEP on device — bit-identical to K
-            # sequential host splits — so it takes the RAW state and
-            # returns the carried state in ms_info["rng_state"]
-            step_key, next_state = rng_key, None
-        else:
-            step_key, next_state = jax.random.split(rng_key)
-        t0 = time.perf_counter() if FLAGS.benchmark else None
-        _d0 = time.perf_counter() if obs is not None else None
-        from .. import profiler as _profiler
-        try:
-            if _profiler.profiling_active():
-                with _profiler.RecordEvent(
-                        f"engine_step(program={program.fingerprint[0]})"):
-                    res = traced.fn(
-                        donated_params, const_params, arrays, step_key)
+        with clock.phase(_profiler.P_RNG):
+            rng_key = _get_rng_state(scope, program)
+            if multi_k > 1:
+                # multi-step (PT_MULTI_STEP): the scanned executable
+                # splits the rng PER SUBSTEP on device — bit-identical
+                # to K sequential host splits — so it takes the RAW
+                # state and returns the carried state in
+                # ms_info["rng_state"]
+                step_key, next_state = rng_key, None
             else:
-                res = traced.fn(
-                    donated_params, const_params, arrays, step_key)
+                step_key, next_state = jax.random.split(rng_key)
+        first = not traced.dispatched
+        try:
+            if first:
+                res = self._first_dispatch(
+                    clock, program, traced, donated_params,
+                    const_params, arrays, step_key)
+            else:
+                with clock.phase(_profiler.P_DISPATCH):
+                    # async dispatch: this is the enqueue span; device
+                    # time lands in the fetch phase (sync) or at the
+                    # materialization point
+                    res = traced.fn(donated_params, const_params,
+                                    arrays, step_key)
         except Exception as exc:
             # RESOURCE_EXHAUSTED here = compile/alloc OOM: capture who
             # owns the HBM before unwinding (one dump per exception)
             _obs_memory.oom_postmortem(exc, where="engine_dispatch")
             raise
-        if obs is not None:
-            # async dispatch: this is the enqueue span; device time
-            # lands in fetch_ms (sync) or the materialization point
-            obs["phases"]["dispatch_ms"] = (time.perf_counter()
-                                            - _d0) * 1e3
-        if multi_k > 1:
-            fetches, updated, nan_flags, ms_info = res
-            _set_rng_state(scope, ms_info["rng_state"])
-        else:
-            fetches, updated, nan_flags = res
-            ms_info = None
-            _set_rng_state(scope, next_state)
-        comm_stats = getattr(traced, "comm_stats", None)
-        if comm_stats:
-            c = self.counters
-            c["collective_bytes"] += comm_stats["bytes"]
-            c["collective_buckets"] += comm_stats["buckets"]
-            c["collective_quantized"] += comm_stats["quantized"]
-            c["grad_collectives_per_step"] = comm_stats["buckets"]
-            c["comm_overlap_frac"] = comm_stats["overlap_frac"]
-            if obs is not None:
-                obs["comm_plan"] = comm_stats.get(
-                    "plan_id", comm_stats["buckets"])
-        sched = getattr(traced, "op_sched", None)
-        if sched is not None and sched.last_stats:
-            st = sched.last_stats
-            c = self.counters
-            c["scheduled_steps"] += 1
-            if "islands_concurrent" in st:
-                c["islands_concurrent"] = st["islands_concurrent"]
-            if "pipeline_fill_frac" in st:
-                c["pipeline_fill_frac"] = st["pipeline_fill_frac"]
-            c["lane_idle_ms"] += st.get("lane_idle_ms", 0.0)
-            if obs is not None:
-                obs["lanes"] = st.get("spans")
-                obs["phases"]["lane_idle_ms"] = st.get(
-                    "lane_idle_ms", 0.0)
-        for n, v in updated.items():
-            var = updated_vars.get(n) if updated_vars is not None \
-                else None
-            if var is None:
-                var = scope.var(n)
-                if updated_vars is not None:
-                    updated_vars[n] = var
-            var.set_value(v)
-        # the synchronize() barrier target: the updated persistables
-        # are the step's full dependency cone (same arrays the scope
-        # holds — no extra live buffers)
-        self._last_updated = tuple(updated.values())
-        async_defer = (bool(FLAGS.async_dispatch) and not return_numpy
-                       and t0 is None)
+        async_defer = bool(FLAGS.async_dispatch) and not return_numpy
         guard_plan = getattr(traced, "guard_plan", None)
-        if guard_plan is not None:
-            _g0 = time.perf_counter()
-            ctl = self._stability
-            if ctl is None:
-                from ..stability import StabilityGuard
-                ctl = self._stability = StabilityGuard()
-            action = ctl.after_step(
-                self, program, scope, traced, arrays, fetches,
-                updated, rng_key, async_defer and multi_k == 1,
-                obs=obs, reexec=_guard_reexec)
-            self.counters["guard_overhead_ms"] += (
-                time.perf_counter() - _g0) * 1e3
-            if _obs.telemetry_active():
-                _obs.histogram(
-                    "pt_guard_overhead_seconds",
-                    "host-side stability-guard controller time per "
-                    "step (verdict read + policy + ghost capture)"
-                ).observe(time.perf_counter() - _g0)
-            if action == "reexecute":
-                # the scope now holds the restored ghost (params,
-                # optimizer state, loss scale, RNG); re-run THIS step
-                # from it — recursion depth is bounded to one by the
-                # controller's reexec handling
-                donated2 = {n: _scope_array(scope, n)
-                            for n in traced.donated_names}
-                const2 = {n: _scope_array(scope, n)
-                          for n in traced.const_names}
-                return self._dispatch_inner(
-                    program, scope, traced, arrays, donated2, const2,
-                    return_numpy, updated_vars, obs,
-                    _guard_reexec=True)
-        integrity_plan = getattr(traced, "integrity_plan", None)
-        if integrity_plan is not None:
+        reexec = False
+        with clock.phase(_profiler.P_WRITEBACK):
+            if multi_k > 1:
+                fetches, updated, nan_flags, ms_info = res
+                _set_rng_state(scope, ms_info["rng_state"])
+            else:
+                fetches, updated, nan_flags = res
+                ms_info = None
+                _set_rng_state(scope, next_state)
+            comm_stats = getattr(traced, "comm_stats", None)
+            if comm_stats:
+                c = self.counters
+                c["collective_bytes"] += comm_stats["bytes"]
+                c["collective_buckets"] += comm_stats["buckets"]
+                c["collective_quantized"] += comm_stats["quantized"]
+                c["grad_collectives_per_step"] = comm_stats["buckets"]
+                c["comm_overlap_frac"] = comm_stats["overlap_frac"]
+            sched = getattr(traced, "op_sched", None)
+            if sched is not None and sched.last_stats:
+                st = sched.last_stats
+                c = self.counters
+                c["scheduled_steps"] += 1
+                if "islands_concurrent" in st:
+                    c["islands_concurrent"] = st["islands_concurrent"]
+                if "pipeline_fill_frac" in st:
+                    c["pipeline_fill_frac"] = st["pipeline_fill_frac"]
+                c["lane_idle_ms"] += st.get("lane_idle_ms", 0.0)
+            for n, v in updated.items():
+                var = updated_vars.get(n) if updated_vars is not None \
+                    else None
+                if var is None:
+                    var = scope.var(n)
+                    if updated_vars is not None:
+                        updated_vars[n] = var
+                var.set_value(v)
+            # the synchronize() barrier target: the updated
+            # persistables are the step's full dependency cone (same
+            # arrays the scope holds — no extra live buffers)
+            self._last_updated = tuple(updated.values())
+            if guard_plan is not None:
+                _g0 = time.perf_counter()
+                ctl = self._stability
+                if ctl is None:
+                    from ..stability import StabilityGuard
+                    ctl = self._stability = StabilityGuard()
+                action = ctl.after_step(
+                    self, program, scope, traced, arrays, fetches,
+                    updated, rng_key, async_defer and multi_k == 1,
+                    reexec=_guard_reexec)
+                self.counters["guard_overhead_ms"] += (
+                    time.perf_counter() - _g0) * 1e3
+                if _obs.telemetry_active():
+                    _obs.histogram(
+                        "pt_guard_overhead_seconds",
+                        "host-side stability-guard controller time per "
+                        "step (verdict read + policy + ghost capture)"
+                    ).observe(time.perf_counter() - _g0)
+                reexec = action == "reexecute"
+            if not reexec:
+                self._after_writeback(program, scope, traced, updated,
+                                      guard_plan, ms_info, multi_k)
+        if reexec:
+            # the scope now holds the restored ghost (params, optimizer
+            # state, loss scale, RNG); re-run THIS step from it —
+            # recursion depth is bounded to one by the controller's
+            # reexec handling
+            donated2 = {n: _scope_array(scope, n)
+                        for n in traced.donated_names}
+            const2 = {n: _scope_array(scope, n)
+                      for n in traced.const_names}
+            return self._dispatch_inner(
+                program, scope, traced, arrays, donated2, const2,
+                return_numpy, updated_vars, _guard_reexec=True)
+        with clock.phase(_profiler.P_FETCH):
+            rec = None
+            if traced.nan_check_labels:
+                if async_defer:
+                    from .async_dispatch import PendingStep
+                    rec = PendingStep(nan_flags,
+                                      traced.nan_check_labels,
+                                      program.fingerprint)
+                    self._pending.append(rec)
+                    if len(self._pending) > _MAX_PENDING_STEPS:
+                        self._pending.pop(0).check()
+                else:
+                    flags_host = np.asarray(nan_flags)
+                    if not flags_host.all():
+                        bad = int(np.argmin(flags_host))
+                        op_type, var = traced.nan_check_labels[bad]
+                        raise EnforceNotMet(
+                            f"Operator {op_type!r} output {var!r} "
+                            f"contains NaN or Inf (FLAGS_check_nan_inf; "
+                            f"reference operator.cc:953-983)",
+                            op_type=op_type)
+            try:
+                out = self._package(traced, fetches, rec, program,
+                                    async_defer, return_numpy, multi_k)
+            except Exception as exc:
+                # deferred XLA OOM surfaces at the sync D2H
+                _obs_memory.oom_postmortem(exc, where="fetch")
+                raise
+        return out
+
+    def _first_dispatch(self, clock, program, traced, donated_params,
+                        const_params, arrays, step_key):
+        """The first call of an executable: jit lowering plus XLA
+        compile or persistent-cache load, once. A set-up span
+        (`first_dispatch`, observability/tracing.py) beside the
+        profiler's, annotated with what the compilation cache said
+        where `jax.monitoring` tells."""
+        traced.dispatched = True
+        hits0, misses0 = _obs_tracing.compile_cache_events()
+        with _obs_tracing.setup_span(
+                "first_dispatch",
+                program=program.fingerprint[0]) as span, \
+                clock.phase(_profiler.P_DISPATCH,
+                            _profiler.FIRST_DISPATCH):
+            res = traced.fn(donated_params, const_params, arrays,
+                            step_key)
+            hits, misses = _obs_tracing.compile_cache_events()
+            if misses > misses0:
+                span.ann["cache"] = "miss"
+            elif hits > hits0:
+                span.ann["cache"] = "hit"
+        return res
+
+    def _after_writeback(self, program, scope, traced, updated,
+                         guard_plan, ms_info, multi_k):
+        """Tail of the writeback phase: the integrity controller and
+        the multi-step slab's accounting."""
+        if getattr(traced, "integrity_plan", None) is not None:
             ctl = self._integrity
             if ctl is None:
                 from ..stability import IntegritySentinel
@@ -2189,8 +2386,7 @@ class Engine:
             # picks the rewound params up from the scope; nothing to
             # re-execute here (the corruption happened outside the
             # step, not inside it)
-            ctl.after_step(self, program, scope, traced, updated,
-                           obs=obs)
+            ctl.after_step(self, program, scope, traced, updated)
         if multi_k > 1:
             # executed-substep count: guard-off slabs run all K by
             # construction (no sync); guard-on pays ONE scalar sync per
@@ -2222,120 +2418,51 @@ class Engine:
                         "pt_multistep_early_exits_total",
                         "slabs cut short by a guard verdict "
                         "(carry freeze)").inc(1)
-        rec = None
-        if traced.nan_check_labels:
-            if async_defer:
-                from .async_dispatch import PendingStep
-                rec = PendingStep(nan_flags, traced.nan_check_labels,
-                                  program.fingerprint)
-                self._pending.append(rec)
-                if len(self._pending) > _MAX_PENDING_STEPS:
-                    self._pending.pop(0).check()
-            else:
-                flags_host = np.asarray(nan_flags)
-                if not flags_host.all():
-                    bad = int(np.argmin(flags_host))
-                    op_type, var = traced.nan_check_labels[bad]
-                    raise EnforceNotMet(
-                        f"Operator {op_type!r} output {var!r} contains "
-                        f"NaN or Inf (FLAGS_check_nan_inf; reference "
-                        f"operator.cc:953-983)", op_type=op_type)
-        if t0 is not None:
-            jax.block_until_ready(fetches)
-            print(f"[FLAGS_benchmark] step {time.perf_counter() - t0:.6f}s "
-                  f"program={program.fingerprint}")
-        if multi_k > 1:
-            return self._package_multi(traced, fetches, rec, program,
-                                       async_defer, return_numpy,
-                                       obs, arrays, multi_k)
 
-        out = []
+    def _package(self, traced, fetches, rec, program, async_defer,
+                 return_numpy, k):
+        """The fetch phase's result: the step's fetches as the caller
+        asked for them. One step gives one row; a multi-step dispatch
+        (k > 1) gives K per-substep rows out of the stacked fetches.
+        Async: lazy FetchHandles (over device-side row slices when
+        k > 1, so per-substep losses materialize individually without
+        a slab-wide sync); sync: one host transfer per fetch."""
+        lods = traced.fetch_lods
         if async_defer:
             from .async_dispatch import FetchHandle
+            hot = _obs._HOT[0]
             # capture the step's trace context NOW — materialization
             # happens on a later step (or another thread), after this
             # thread's context has moved on
-            tctx = _obs_tracing.current_context() \
-                if obs is not None else None
-            for n, v in zip(traced.fetch_names, fetches):
-                h = FetchHandle(v, traced.fetch_lods.get(n), rec,
-                                n, program.fingerprint, tctx=tctx)
-                if obs is not None:
-                    _obs_memory.track_fetch_handle(h)
-                out.append(h)
-            if obs is not None:
-                obs["pending_fetches"] = len(self._pending)
-                obs["phases"]["fetch_ms"] = 0.0  # deferred to handles
-                self._obs_finish(obs, arrays)
-            return out
-        _f0 = time.perf_counter() if obs is not None else None
-        try:
-            for n, v in zip(traced.fetch_names, fetches):
-                lod = traced.fetch_lods.get(n)
-                if return_numpy and not lod:
-                    out.append(np.asarray(v))
-                else:
-                    t = LoDTensor(v, lod or [])
-                    out.append(t)
-        except Exception as exc:
-            # deferred XLA OOM surfaces at the sync D2H
-            _obs_memory.oom_postmortem(exc, where="fetch")
-            raise
-        if obs is not None:
-            obs["pending_fetches"] = len(self._pending)
-            obs["phases"]["fetch_ms"] = (time.perf_counter()
-                                         - _f0) * 1e3
-            self._obs_finish(obs, arrays)
-        return out
+            tctx = _obs_tracing.current_context() if hot else None
 
-    def _package_multi(self, traced, fetches, rec, program,
-                       async_defer, return_numpy, obs, arrays, k):
-        """Package one multi-step dispatch's stacked fetches into K
-        per-substep rows. Async: each row holds lazy FetchHandles over
-        device-side row slices, so per-substep losses materialize
-        individually without a slab-wide sync; sync: one host
-        transfer per stacked fetch, then row views."""
-        rows = []
-        if async_defer:
-            from .async_dispatch import FetchHandle
-            tctx = _obs_tracing.current_context() \
-                if obs is not None else None
-            for j in range(k):
-                row = []
-                for n, v in zip(traced.fetch_names, fetches):
-                    h = FetchHandle(v[j], traced.fetch_lods.get(n),
-                                    rec, f"{n}[{j}]",
-                                    program.fingerprint, tctx=tctx)
-                    if obs is not None:
-                        _obs_memory.track_fetch_handle(h)
-                    row.append(h)
-                rows.append(row)
-            if obs is not None:
-                obs["pending_fetches"] = len(self._pending)
-                obs["phases"]["fetch_ms"] = 0.0  # deferred to handles
-                self._obs_finish(obs, arrays)
-            return rows
-        _f0 = time.perf_counter() if obs is not None else None
-        try:
-            hosts = [np.asarray(v) for v in fetches]
-        except Exception as exc:
-            _obs_memory.oom_postmortem(exc, where="fetch")
-            raise
-        for j in range(k):
-            row = []
-            for n, v, hv in zip(traced.fetch_names, fetches, hosts):
-                lod = traced.fetch_lods.get(n)
-                if return_numpy and not lod:
-                    row.append(hv[j])
-                else:
-                    row.append(LoDTensor(v[j], lod or []))
-            rows.append(row)
-        if obs is not None:
-            obs["pending_fetches"] = len(self._pending)
-            obs["phases"]["fetch_ms"] = (time.perf_counter()
-                                         - _f0) * 1e3
-            self._obs_finish(obs, arrays)
-        return rows
+            def handle(v, n, label):
+                h = FetchHandle(v, lods.get(n), rec, label,
+                                program.fingerprint, tctx=tctx)
+                if hot:
+                    _obs_memory.track_fetch_handle(h)
+                return h
+
+            if k == 1:
+                return [handle(v, n, n)
+                        for n, v in zip(traced.fetch_names, fetches)]
+            return [[handle(v[j], n, f"{n}[{j}]")
+                     for n, v in zip(traced.fetch_names, fetches)]
+                    for j in range(k)]
+
+        def host(n, v, hv):
+            lod = lods.get(n)
+            return hv if return_numpy and not lod \
+                else LoDTensor(v, lod or [])
+
+        if k == 1:
+            return [host(n, v, np.asarray(v)
+                         if return_numpy and not lods.get(n) else None)
+                    for n, v in zip(traced.fetch_names, fetches)]
+        hosts = [np.asarray(v) for v in fetches]
+        return [[host(n, v[j], hv[j])
+                 for n, v, hv in zip(traced.fetch_names, fetches, hosts)]
+                for j in range(k)]
 
     def synchronize(self):
         """Materialization barrier for FLAGS.async_dispatch: drain every

@@ -9,8 +9,7 @@ import time, and ``get_flags``/``set_flags`` read/write at runtime — but
 the flag *set* is honest about what the XLA runtime subsumes:
 
 * flags with live behavior in this framework are marked ``live=True``
-  (e.g. ``check_nan_inf`` instruments every traced op,
-  ``benchmark`` forces per-step device sync + timing logs);
+  (e.g. ``check_nan_inf`` instruments every traced op);
 * reference flags whose job XLA/PJRT performs automatically (allocator
   tuning, eager deletion, cudnn knobs …) are registered ``live=False`` so
   user programs that set them keep working, and ``flag_info()`` reports
@@ -52,17 +51,13 @@ _define("check_nan_inf", False, True,
         "after every traced op, verify float outputs are finite and raise "
         "EnforceNotMet naming the first offending op/var (reference "
         "operator.cc:953-983)")
-_define("benchmark", False, True,
-        "block until device ready after every executor step and log step "
-        "latency (reference FLAGS_benchmark per-op sync, operator.cc:949)")
 _define("async_dispatch", False, True,
         "pipelined step dispatch: run(..., return_numpy=False) returns "
         "fetch handles backed by live jax.Arrays instead of synced host "
         "copies, and NaN/Inf checks (FLAGS_check_nan_inf) are deferred to "
         "handle materialization / Executor.synchronize() so step N+1's "
-        "host work overlaps step N's device compute and D2H; ignored "
-        "while FLAGS_benchmark forces per-step sync (docs/ASYNC_DISPATCH"
-        ".md)")
+        "host work overlaps step N's device compute and D2H "
+        "(docs/ASYNC_DISPATCH.md)")
 _define("async_checkpoint", False, True,
         "route io.save_persistables/load_persistables (and the fleet "
         "save paths) through the async sharded checkpoint subsystem "

@@ -10,9 +10,12 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
+from . import compiler as _compiler
 from . import framework
+from . import profiler as _profiler
 from .core.engine import Engine
 from .core.flags import FLAGS
 from .core.place import CPUPlace, TPUPlace, Place, default_place
@@ -77,23 +80,34 @@ class Executor:
             raise RuntimeError("Executor is closed")
         if program is None:
             program = framework.default_main_program()
-        scope = scope or global_scope()
-        fetch_list = fetch_list or []
-        fetch_names = [_to_name_str(f) for f in fetch_list]
-
-        # CompiledProgram path (data-parallel / distributed)
-        from . import compiler as _compiler
-        if isinstance(program, _compiler.CompiledProgram):
-            return program._run(self, feed, fetch_names, scope, return_numpy)
-
-        feed = self._canonical_feed(feed, program)
-        if FLAGS.validate_program:
-            from .analysis import validate_cached
-            validate_cached(program, feed_names=list(feed),
-                            fetch_names=fetch_names)
-        return self._engine.run(program, scope, self.place, feed,
-                                fetch_names, return_numpy=return_numpy,
-                                use_program_cache=use_program_cache)
+        compiled = isinstance(program, _compiler.CompiledProgram)
+        engine = program._step_engine(self) if compiled else self._engine
+        # one `pt.step` per call, in whatever profiler session is open
+        # (docs/TRACING.md); the phases inside stamp the thread's clock
+        clock = _profiler.step_clock()
+        with jax.profiler.StepTraceAnnotation(
+                _profiler.STEP_SPAN,
+                step_num=engine.counters["runs"] + 1):
+            clock.begin_step(opened=True)
+            try:
+                if compiled:
+                    # data-parallel / distributed
+                    return program._run(self, feed, fetch_list, scope,
+                                        return_numpy)
+                with clock.phase(_profiler.P_EXECUTOR_FEED):
+                    scope = scope or global_scope()
+                    fetch_names = [_to_name_str(f)
+                                   for f in fetch_list or ()]
+                    feed = self._canonical_feed(feed, program)
+                    if FLAGS.validate_program:
+                        from .analysis import validate_cached
+                        validate_cached(program, feed_names=list(feed),
+                                        fetch_names=fetch_names)
+                return engine.run(program, scope, self.place, feed,
+                                  fetch_names, return_numpy=return_numpy,
+                                  use_program_cache=use_program_cache)
+            finally:
+                clock.opened = False
 
     def synchronize(self):
         """Block until every step dispatched by this executor has
@@ -115,7 +129,6 @@ class Executor:
                 raise TypeError(
                     "list feed is only valid for CompiledProgram "
                     "with_data_parallel")
-        import jax
         out = {}
         for k, v in feed.items():
             if isinstance(v, LoDTensor):

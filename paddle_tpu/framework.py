@@ -222,6 +222,8 @@ class Parameter(Variable):
         self.initializer = kwargs.pop("initializer", None)
         super().__init__(block, shape=shape, dtype=dtype, **kwargs)
         self.stop_gradient = not self.trainable
+        # the name scope it was created under: its update op's too
+        self._name_scope = _NAME_SCOPES[-1]
 
 
 # ---------------------------------------------------------------------------
@@ -253,6 +255,8 @@ class Operator:
         self._outputs: Dict[str, List[str]] = {}
         self._attrs: Dict[str, Any] = dict(attrs or {})
         self._attrs.setdefault(OP_UID_ATTR, _next_uid())
+        if _NAME_SCOPES[-1]:
+            self._attrs.setdefault(OP_NAMESCOPE_ATTR, _NAME_SCOPES[-1])
 
         def _names(v):
             if v is None:
@@ -765,7 +769,30 @@ def program_guard(main_program: Program,
             switch_startup_program(old_startup)
 
 
+# full paths of the open name scopes, innermost last ("enc_0/ffn/")
+_NAME_SCOPES: List[str] = [""]
+OP_NAMESCOPE_ATTR = "op_namescope"
+
+
 @contextlib.contextmanager
 def name_scope(prefix: str):
-    # cosmetic in this build (reference uses it for op naming in graphs)
-    yield
+    """Ops appended inside carry the scope's path as their
+    `op_namescope` attribute (reference framework.py name_scope); so do
+    the grad ops made from them (they copy the forward op's attributes)
+    and the update op of a parameter created inside. The compiled
+    step's HLO metadata names each op
+    `<op_namescope><op_role>/<op type>` (core/engine.py)."""
+    with _name_scope_path(f"{_NAME_SCOPES[-1]}{prefix}/" if prefix
+                          else ""):
+        yield
+
+
+@contextlib.contextmanager
+def _name_scope_path(path: str):
+    """Open `path` whole (a parameter's own scope, for its update op);
+    an empty path leaves the current scope open."""
+    _NAME_SCOPES.append(path or _NAME_SCOPES[-1])
+    try:
+        yield
+    finally:
+        _NAME_SCOPES.pop()

@@ -662,6 +662,7 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
 
     res = pl.pallas_call(
         kern,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs if return_lse else out_specs[0],
@@ -821,6 +822,7 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
 
     res = pl.pallas_call(
         kern_dq,
+        name="flash_attention_dq",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -895,6 +897,7 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
 
     dk, dv = pl.pallas_call(
         kern_dkv,
+        name="flash_attention_dkv",
         grid=grid,
         in_specs=in_specs,
         out_specs=[plan.row_spec(bk, D, ka),

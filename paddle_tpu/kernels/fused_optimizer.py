@@ -140,7 +140,7 @@ def _sgd_block(hyper_ref, bounds_ref, p_ref, g_ref, po_ref, *, wd,
     po_ref[:] = jnp.where(inside, p_new, p)
 
 
-def _call(body, hyper, bounds, bufs, n_out, block_rows):
+def _call(name, body, hyper, bounds, bufs, n_out, block_rows):
     rows = bufs[0].shape[0]
     grid = (rows // block_rows,)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -148,6 +148,7 @@ def _call(body, hyper, bounds, bufs, n_out, block_rows):
                         memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         body,
+        name=name,
         grid=grid,
         in_specs=[smem, smem] + [tile] * len(bufs),
         out_specs=[tile] * n_out if n_out > 1 else tile,
@@ -210,7 +211,7 @@ def fused_adam(p, g, m, v, lr_t, *, beta1=0.9, beta2=0.999,
                              b2=float(beta2), eps=float(epsilon),
                              wd=float(weight_decay),
                              block_rows=_BLOCK_ROWS, gated=False)
-    po, mo, vo = _call(body, _hyper(lr_t, None),
+    po, mo, vo = _call("fused_adam", body, _hyper(lr_t, None),
                        _bounds(bufs[0].shape[0], None), bufs, 3,
                        _BLOCK_ROWS)
     return (_from2d(po, n).reshape(shape),
@@ -225,7 +226,7 @@ def fused_sgd(p, g, lr, *, weight_decay=0.0):
     bufs = [_to2d(x.reshape(-1)) for x in (p, g)]
     body = functools.partial(_sgd_block, wd=float(weight_decay),
                              block_rows=_BLOCK_ROWS, gated=False)
-    (po,) = _call(body, _hyper(lr, None),
+    (po,) = _call("fused_sgd", body, _hyper(lr, None),
                   _bounds(bufs[0].shape[0], None), bufs, 1,
                   _BLOCK_ROWS)
     return _from2d(po, n).reshape(shape)
@@ -267,7 +268,7 @@ def bucket_sweep(kind, flat_param, flat_grad, flat_m=None, flat_v=None,
                                  b2=float(beta2), eps=float(epsilon),
                                  wd=float(weight_decay),
                                  block_rows=_BLOCK_ROWS, gated=gated)
-        po, mo, vo = _call(body, _hyper(lr_t, guard),
+        po, mo, vo = _call("fused_adam", body, _hyper(lr_t, guard),
                            _bounds(bufs[0].shape[0], shard), bufs, 3,
                            _BLOCK_ROWS)
         return _from2d(po, n), _from2d(mo, n), _from2d(vo, n)
@@ -275,7 +276,7 @@ def bucket_sweep(kind, flat_param, flat_grad, flat_m=None, flat_v=None,
         bufs = [_to2d(x) for x in (flat_param, flat_grad)]
         body = functools.partial(_sgd_block, wd=float(weight_decay),
                                  block_rows=_BLOCK_ROWS, gated=gated)
-        (po,) = _call(body, _hyper(lr, guard),
+        (po,) = _call("fused_sgd", body, _hyper(lr, guard),
                       _bounds(bufs[0].shape[0], shard), bufs, 1,
                       _BLOCK_ROWS)
         return _from2d(po, n)

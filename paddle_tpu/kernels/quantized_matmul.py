@@ -100,6 +100,7 @@ def quantized_matmul(x, y, *, mode=None, out_dtype=None):
     n_k = K // _TILE
     out = pl.pallas_call(
         functools.partial(_qmm_block, n_k=n_k, mode=mode),
+        name="quantized_matmul",
         grid=(M // _TILE, N // _TILE, n_k),
         in_specs=[
             pl.BlockSpec((_TILE, _TILE), lambda i, j, k: (i, k),

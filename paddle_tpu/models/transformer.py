@@ -21,6 +21,7 @@ from __future__ import annotations
 import numpy as np
 
 from .. import layers
+from ..framework import name_scope
 from ..param_attr import ParamAttr
 from ..initializer import Normal, Constant
 
@@ -173,46 +174,65 @@ def _embed(ids, vocab_size, cfg, name, pos=True):
     return emb
 
 
+def _embed_in(ids, vocab_size, cfg, name, is_test):
+    # name scopes (embed, enc_<i>/self_attn, dec_<i>/cross_attn, ...)
+    # reach the compiled step's HLO metadata: core/engine.py _op_scope
+    with name_scope("embed"):
+        x = _embed(ids, vocab_size, cfg, name)
+        if cfg.dropout and not is_test:
+            x = layers.dropout(x, cfg.dropout, is_test=is_test,
+                               dropout_implementation="upscale_in_train")
+    return x
+
+
+def _ffn_block(x, cfg, name, is_test):
+    with name_scope("ffn"):
+        ffn = _ffn(x, cfg, name, is_test)
+        return _pre_post(ffn, x, cfg, name, is_test)
+
+
 def encoder(src_ids, src_bias, cfg: TransformerConfig, is_test=False):
-    x = _embed(src_ids, cfg.src_vocab_size, cfg, "src_word_emb.w_0")
-    if cfg.dropout and not is_test:
-        x = layers.dropout(x, cfg.dropout, is_test=is_test,
-                           dropout_implementation="upscale_in_train")
+    x = _embed_in(src_ids, cfg.src_vocab_size, cfg, "src_word_emb.w_0",
+                  is_test)
     for i in range(cfg.n_layer):
         p = f"enc_{i}"
-        attn = multi_head_attention(x, x, src_bias, cfg, p + "_attn",
-                                    is_test)
-        x = _pre_post(attn, x, cfg, p + "_attn", is_test)
-        ffn = _ffn(x, cfg, p + "_ffn", is_test)
-        x = _pre_post(ffn, x, cfg, p + "_ffn", is_test)
+        with name_scope(p):
+            with name_scope("self_attn"):
+                attn = multi_head_attention(x, x, src_bias, cfg,
+                                            p + "_attn", is_test)
+                x = _pre_post(attn, x, cfg, p + "_attn", is_test)
+            x = _ffn_block(x, cfg, p + "_ffn", is_test)
     return x
 
 
 def decoder(trg_ids, trg_bias, enc_out, cross_bias, cfg, is_test=False,
             caches=None):
-    x = _embed(trg_ids, cfg.trg_vocab_size, cfg, "trg_word_emb.w_0")
-    if cfg.dropout and not is_test:
-        x = layers.dropout(x, cfg.dropout, is_test=is_test,
-                           dropout_implementation="upscale_in_train")
+    x = _embed_in(trg_ids, cfg.trg_vocab_size, cfg, "trg_word_emb.w_0",
+                  is_test)
     for i in range(cfg.n_layer):
         p = f"dec_{i}"
         cache = caches[i] if caches is not None else None
-        self_attn = multi_head_attention(x, x, trg_bias, cfg,
-                                         p + "_self_attn", is_test,
-                                         cache,
-                                         causal=cfg.fuse_attention)
-        x = _pre_post(self_attn, x, cfg, p + "_self_attn", is_test)
-        cross = multi_head_attention(x, enc_out, cross_bias, cfg,
-                                     p + "_cross_attn", is_test)
-        x = _pre_post(cross, x, cfg, p + "_cross_attn", is_test)
-        ffn = _ffn(x, cfg, p + "_ffn", is_test)
-        x = _pre_post(ffn, x, cfg, p + "_ffn", is_test)
+        with name_scope(p):
+            with name_scope("self_attn"):
+                self_attn = multi_head_attention(
+                    x, x, trg_bias, cfg, p + "_self_attn", is_test,
+                    cache, causal=cfg.fuse_attention)
+                x = _pre_post(self_attn, x, cfg, p + "_self_attn",
+                              is_test)
+            with name_scope("cross_attn"):
+                cross = multi_head_attention(x, enc_out, cross_bias,
+                                             cfg, p + "_cross_attn",
+                                             is_test)
+                x = _pre_post(cross, x, cfg, p + "_cross_attn", is_test)
+            x = _ffn_block(x, cfg, p + "_ffn", is_test)
     return x
 
 
 def _project_logits(dec_out, cfg):
-    return layers.fc(dec_out, cfg.trg_vocab_size, num_flatten_dims=2,
-                     param_attr=_w("trg_proj.w_0"), bias_attr=False)
+    with name_scope("logits"):
+        return layers.fc(dec_out, cfg.trg_vocab_size,
+                         num_flatten_dims=2,
+                         param_attr=_w("trg_proj.w_0"), bias_attr=False)
 
 
 def transformer_train(cfg: TransformerConfig, is_test=False):
@@ -247,6 +267,14 @@ def transformer_train(cfg: TransformerConfig, is_test=False):
     dec_out = decoder(trg_ids, trg_bias, enc_out, src_bias, cfg, is_test)
     logits = _project_logits(dec_out, cfg)
 
+    with name_scope("loss"):
+        avg_cost = _loss(logits, lbl_ids, lbl_w, cfg)
+    feeds = ["src_ids", "trg_ids", "lbl_ids", "src_bias", "trg_bias",
+             "lbl_w"]
+    return avg_cost, logits, feeds
+
+
+def _loss(logits, lbl_ids, lbl_w, cfg):
     if cfg.label_smooth_eps and cfg.fuse_loss:
         cost = layers.label_smoothed_softmax_xent(
             logits, lbl_ids, epsilon=cfg.label_smooth_eps)
@@ -265,10 +293,7 @@ def transformer_train(cfg: TransformerConfig, is_test=False):
     weighted = layers.elementwise_mul(cost, lbl_w)
     sum_cost = layers.reduce_sum(weighted)
     token_count = layers.reduce_sum(lbl_w)
-    avg_cost = layers.elementwise_div(sum_cost, token_count)
-    feeds = ["src_ids", "trg_ids", "lbl_ids", "src_bias", "trg_bias",
-             "lbl_w"]
-    return avg_cost, logits, feeds
+    return layers.elementwise_div(sum_cost, token_count)
 
 
 def make_batch(cfg, batch, s_src, s_trg, rng=None, src_lens=None,

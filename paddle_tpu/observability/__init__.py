@@ -21,8 +21,11 @@ Three layers (docs/OBSERVABILITY.md):
   OOM/pressure postmortem dumps, and the leak sentinel
   (docs/MEMORY.md).
 
-Hot-path contract: one boolean (``metrics._HOT[0]``, folded into
-``profiler.profiling_active()``) gates all per-step work.
+Hot-path contract: one boolean (``metrics._HOT[0]``) gates all
+per-step telemetry work. The step's own profiler spans and clock stamps
+(``profiler.StepClock``) are not behind it: they cost microseconds and
+feed a profiler session, the slow-step detector and, while ``_HOT``,
+this layer's record.
 """
 from . import metrics, recorder, export, tracing, attribution, \
     memory  # noqa: F401

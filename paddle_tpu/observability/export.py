@@ -279,13 +279,14 @@ def maybe_start_from_env() -> Optional[MetricsServer]:
 # chrome-trace merge (tools/timeline.py)
 # ---------------------------------------------------------------------------
 
-_PHASE_LANES = ("feed_ms", "trace_ms", "dispatch_ms", "fetch_ms")
 
 
 def flight_to_chrome_trace(path: str) -> List[dict]:
     """Convert one flight-recorder dump into chrome trace events: each
-    step's phases render as back-to-back complete ('X') events, one
-    lane (tid) per phase, anchored at the step's host wall time."""
+    step's phases render as complete ('X') events, one lane (tid) per
+    phase, anchored at the step's host wall time and placed where the
+    record's ``phase_t0_ms`` stamps say (back to back where a record
+    carries none)."""
     d = _recorder.read_dump(path)
     pid = d["header"].get("pid", 0)
     events: List[dict] = []
@@ -293,11 +294,14 @@ def flight_to_chrome_trace(path: str) -> List[dict]:
         t0 = float(rec.get("t_host") or 0.0) * 1e6  # seconds -> us
         step = rec.get("step")
         phases = rec.get("phases") or {}
+        starts = rec.get("phase_t0_ms")
         off = 0.0
-        for lane, key in enumerate(_PHASE_LANES):
+        for lane, key in enumerate(_recorder.PHASE_KEYS):
             v = phases.get(key)
             if not v:
                 continue
+            if starts is not None:
+                off = float(starts.get(key) or 0.0) * 1e3
             dur = float(v) * 1e3                    # ms -> us
             args = {"step": step}
             for k in ("sig", "fast_path", "traced", "comm_plan",

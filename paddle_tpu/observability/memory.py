@@ -19,7 +19,7 @@ Design (same shape as recorder.py / tracing.py):
   object pays nothing per step and dies naturally. Engines are
   enumerated through ``metrics._ENGINES`` (already weakly tracked for
   the counter collector) — no new engine-side registration.
-- **One-boolean hot gate.** ``Engine._obs_finish`` calls
+- **One-boolean hot gate.** ``Engine._finish_step`` calls
   ``step_tick()`` only while ``metrics._HOT[0]`` is already true, and
   the tick itself re-checks ``census_active()``; with observability
   off the engine performs ZERO census work (``stats()['censuses']``
@@ -98,9 +98,9 @@ def enable(on: bool = True) -> None:
     _ENABLED[0] = bool(on)
     _metrics._recompute_hot()
     if not on and not census_active():
-        # engines only clear their tagged feed batch inside
-        # _obs_finish, which no longer runs — release it here so a
-        # disarmed census never pins the last step's batch in HBM
+        # engines only replace their tagged feed batch in
+        # _finish_step while _HOT, which is now off — release it here
+        # so a disarmed census never pins the last step's batch in HBM
         for eng in list(getattr(_metrics, "_ENGINES", ()) or ()):
             if getattr(eng, "_census_feed", None) is not None:
                 eng._census_feed = None
@@ -772,7 +772,7 @@ def oom_postmortem(exc: BaseException,
 
 
 # ---------------------------------------------------------------------------
-# per-step tick (called from Engine._obs_finish while _HOT)
+# per-step tick (called from Engine._finish_step while _HOT)
 # ---------------------------------------------------------------------------
 
 _TICK = [0]

@@ -40,6 +40,12 @@ __all__ = ["FlightRecorder", "flight_recorder", "record_step", "dump",
            "set_watchdog_active", "default_dir", "read_dump",
            "find_dumps", "summarize_dumps", "install_sigterm_hook"]
 
+# a step record's phase keys, in the order the phases run (the slots
+# of profiler.StepClock); `total_ms` and `lane_idle_ms` ride beside them
+PHASE_KEYS = ("executor_feed_ms", "feed_ms", "trace_ms", "args_ms",
+              "rng_ms", "dispatch_ms", "writeback_ms", "fetch_ms",
+              "release_ms")
+
 _ENABLED = [False]
 _FAULT = [False]
 _WATCHDOG = [False]
@@ -177,10 +183,12 @@ def flight_recorder() -> FlightRecorder:
 
 
 def record_step(rec: dict) -> None:
-    """Engine-side sink for one step record (already gated by
-    ``metrics._HOT`` — the caller only builds ``rec`` while armed).
-    Observes the phase histograms when telemetry is on and appends to
-    the ring when the recorder is armed."""
+    """Engine-side sink for one step record. The caller builds ``rec``
+    while ``metrics._HOT`` is set, or — armed or not — for a step it
+    found slow against its own running median (``"slow": True``; such a
+    record always reaches the ring, so a postmortem of an ordinary run
+    shows its stalls). Observes the phase histograms when telemetry is
+    on and appends to the ring when the recorder is armed."""
     if _metrics.telemetry_active():
         reg = _metrics.default_registry()
         phases = rec.get("phases") or {}
@@ -196,7 +204,7 @@ def record_step(rec: dict) -> None:
                 h = reg.get(name)
                 if h is not None:
                     h.observe(v / 1e3)
-    if recording_active():
+    if recording_active() or rec.get("slow"):
         flight_recorder().append(rec)
 
 
@@ -286,8 +294,7 @@ def summarize_dumps(directory: Optional[str] = None,
         steps = [r.get("step") for r in recs
                  if r.get("step") is not None]
         phases: Dict[str, float] = {}
-        for key in ("feed_ms", "trace_ms", "dispatch_ms", "fetch_ms",
-                    "total_ms", "lane_idle_ms"):
+        for key in PHASE_KEYS + ("total_ms", "lane_idle_ms"):
             vals = [r["phases"][key] for r in recs
                     if r.get("phases", {}).get(key) is not None]
             if vals:
@@ -301,5 +308,10 @@ def summarize_dumps(directory: Optional[str] = None,
             "last_step": max(steps) if steps else None,
             "first_step": min(steps) if steps else None,
             "mean_phase_ms": phases,
+            # steps the engine found slow, over the whole dump
+            "slow_steps": [
+                {"step": r.get("step"), "phases": r.get("phases"),
+                 "gc": r.get("gc")}
+                for r in d["records"] if r.get("slow")],
         })
     return out

@@ -55,6 +55,7 @@ from . import recorder as _recorder
 __all__ = ["worker_id", "set_worker", "default_worker", "new_span_id",
            "begin_step", "current_context", "span", "server_span",
            "record_span", "finish_step", "span_buffer",
+           "setup_span", "setup_spans", "clear_setup_spans",
            "spans_snapshot", "clear_spans", "dump_spans",
            "read_span_dump", "find_span_dumps", "note_step_duration",
            "step_summary", "update_skew", "skew_snapshot",
@@ -221,15 +222,24 @@ def record_span(name: str, t0: float, dur_ms: float, kind: str = "host",
         trace = ctx["trace"] if ctx else f"{worker_id()}-detached"
     if parent is None and ctx is not None:
         parent = ctx["stack"][-1] if ctx["stack"] else ctx["root"]
-    rec = {"trace": trace, "span": span_id or new_span_id(),
-           "parent": parent, "name": name, "kind": kind,
-           "worker": worker_id(), "t0": round(float(t0), 6),
-           "dur_ms": round(float(dur_ms), 3)}
+    return _ring_append(_span_record(name, t0, dur_ms, kind, trace,
+                                     span_id or new_span_id(), parent,
+                                     ann))
+
+
+def _span_record(name, t0, dur_ms, kind, trace, span_id, parent, ann):
+    rec = {"trace": trace, "span": span_id, "parent": parent,
+           "name": name, "kind": kind, "worker": worker_id(),
+           "t0": round(float(t0), 6), "dur_ms": round(float(dur_ms), 3)}
     if ann:
         rec["ann"] = {k: v for k, v in ann.items() if v is not None}
+    return rec
+
+
+def _ring_append(rec):
     span_buffer().append(rec)
     try:
-        _metrics.counter("pt_spans_recorded_total").inc(kind=kind)
+        _metrics.counter("pt_spans_recorded_total").inc(kind=rec["kind"])
     except Exception:
         pass
     return rec
@@ -347,18 +357,103 @@ def server_span(tctx: Optional[dict], name: str, kind: str = "rpc.server",
 
 
 # ---------------------------------------------------------------------------
+# set-up spans: once per executable, recorded whether or not _HOT
+# ---------------------------------------------------------------------------
+
+# What a process spends before its first steady step — `trace_step` per
+# program (child `trace_step.op_walk`, the abstract walk over the ops'
+# lowerings that finds the updated persistables) and `first_dispatch`
+# per executable (jit lowering plus XLA compile or persistent-cache
+# load). They occur once per executable, never per
+# step, so they are kept without the telemetry switch, and apart from
+# the span ring: 4,096 step spans would push them out of it. With _HOT
+# set they are mirrored into the ring, so the dumps tools/timeline.py
+# reads show them.
+_SETUP_MAX = 512
+_SETUP: List[dict] = []
+
+
+def setup_spans() -> List[dict]:
+    """The process's set-up spans, oldest first (record shape of
+    :func:`record_span`, ``kind="setup"``)."""
+    return list(_SETUP)
+
+
+def clear_setup_spans() -> None:
+    del _SETUP[:]
+
+
+class _SetupSpan:
+    __slots__ = ("name", "ann", "sid", "parent", "t0", "_p0")
+
+    def __init__(self, name, parent, ann):
+        self.name, self.ann = name, ann
+        self.parent = parent.sid if parent is not None else None
+        self.sid = new_span_id()
+
+    def __enter__(self):
+        self.t0 = time.time()
+        self._p0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if exc_type is not None:
+            self.ann.setdefault("error", exc_type.__name__)
+        rec = _span_record(
+            self.name, self.t0, (time.perf_counter() - self._p0) * 1e3,
+            "setup", f"{worker_id()}-setup", self.sid, self.parent,
+            self.ann)
+        _SETUP.append(rec)
+        if len(_SETUP) > _SETUP_MAX:
+            del _SETUP[0]
+        if _metrics._HOT[0]:
+            _ring_append(rec)
+        return False
+
+
+def setup_span(name: str, parent: Optional[_SetupSpan] = None, **ann):
+    """``with setup_span("trace_step", program=fp) as sp: ...``; a child
+    passes ``parent=sp``. Annotations may be added to ``sp.ann`` until
+    the block ends."""
+    return _SetupSpan(name, parent, ann)
+
+
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": 0,
+                 "/jax/compilation_cache/cache_misses": 0}
+_CACHE_LISTENING = [False]
+
+
+def compile_cache_events():
+    """(hits, misses) of JAX's persistent compilation cache so far, as
+    ``jax.monitoring`` reports them; counted from the first call on."""
+    if not _CACHE_LISTENING[0]:
+        _CACHE_LISTENING[0] = True
+        import jax.monitoring
+
+        def _count(event, **kw):
+            if event in _CACHE_EVENTS:
+                _CACHE_EVENTS[event] += 1
+
+        jax.monitoring.register_event_listener(_count)
+    return tuple(_CACHE_EVENTS.values())
+
+
+# ---------------------------------------------------------------------------
 # engine hook: derive step/phase/lane spans from the obs record
 # ---------------------------------------------------------------------------
 
-_PHASE_KEYS = ("feed_ms", "trace_ms", "dispatch_ms", "fetch_ms")
 
 
 def finish_step(obs: dict) -> None:
     """Close out one step's trace: emit the root step span, one child
     per measured phase, and one grandchild per scheduler-lane island
-    span — all derived from timings the engine already took for the
-    flight record, so tracing adds no clocks to the hot path. Also
-    feeds the step-duration window the heartbeat summaries read."""
+    span — all derived from the stamps the engine's StepClock took
+    (``profiler.py``), so tracing adds no clocks to the hot path. A
+    phase span starts where its stamp says: ``obs["phase_t0_ms"]``
+    holds each phase's offset from the step's start (a record without
+    it, hand-built or from an older dump, has its phases laid end to
+    end). Also feeds the step-duration window the heartbeat summaries
+    read."""
     ctx = _ctx()
     _TLS.ctx = None
     if not _metrics._HOT[0]:
@@ -376,12 +471,15 @@ def finish_step(obs: dict) -> None:
     ann["step"] = step
     record_span("step", t0, total_ms, kind="step", trace=trace,
                 span_id=root, parent=None, ann=ann)
+    starts = obs.get("phase_t0_ms")
     off = 0.0
     dispatch_t0, dispatch_sid = t0, root
-    for key in _PHASE_KEYS:
+    for key in _recorder.PHASE_KEYS:
         v = phases.get(key)
         if not v:
             continue
+        if starts is not None:
+            off = float(starts.get(key) or 0.0)
         rec = record_span(key[:-3], t0 + off / 1e3, float(v),
                           kind="phase", trace=trace, parent=root,
                           ann={"step": step})

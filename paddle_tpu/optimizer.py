@@ -17,7 +17,8 @@ import numpy as np
 
 from . import framework
 from .framework import Variable, default_main_program, \
-    default_startup_program, program_guard, unique_name, in_dygraph_mode
+    default_startup_program, program_guard, unique_name, \
+    in_dygraph_mode, _name_scope_path
 from .backward import append_backward
 from .initializer import Constant
 from .layer_helper import LayerHelper
@@ -249,7 +250,9 @@ class Optimizer:
             if param_and_grad[1] is None:
                 continue
             if param_and_grad[0].trainable:
-                op = self._append_optimize_op(block, param_and_grad)
+                with _name_scope_path(getattr(param_and_grad[0],
+                                              "_name_scope", "")):
+                    op = self._append_optimize_op(block, param_and_grad)
                 optimize_ops.append(op)
         self._finish_update(block, parameters_and_grads)
         return optimize_ops

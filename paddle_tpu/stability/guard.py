@@ -561,7 +561,7 @@ class StabilityGuard:
 
     # -- the per-step decision ------------------------------------------
     def after_step(self, engine, program, scope, traced, arrays,
-                   fetches, updated, rng_key, async_defer, obs=None,
+                   fetches, updated, rng_key, async_defer,
                    reexec: bool = False) -> str:
         """Returns "ok" (continue) or "reexecute" (state was rolled
         back to a ghost; the engine must re-dispatch the step)."""
@@ -610,8 +610,6 @@ class StabilityGuard:
                      "classes": classes, "policy": policy,
                      "norm": norm, "ema": ema,
                      "escalated": escalated, "reexec": reexec}
-        if obs is not None:
-            obs["anomaly"] = dict(self.last)
         warnings.warn(
             f"stability guard: step {step_no} anomaly "
             f"{'+'.join(classes)} (grad_norm={norm:.4g} "

@@ -368,8 +368,8 @@ class IntegritySentinel:
         from .guard import policy_map
         return policy_map().get("integrity", "rollback")
 
-    def after_step(self, engine, program, scope, traced, updated,
-                   obs=None) -> str:
+    def after_step(self, engine, program, scope, traced,
+                   updated) -> str:
         """Called from the engine after writeback. Cheap on non-window
         steps (one int increment); on window steps reads the small
         accumulator arrays (device->host sync of O(nbuckets) values).

@@ -165,6 +165,7 @@ def tuned_matmul(x, y, *, variant: Variant, gamma=None, beta=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=kreg.interpret(),
+        name=f"tuned_matmul_{variant.epilogue}_{bm}x{bn}x{bk}",
     )
     if variant.epilogue == "none":
         return pl.pallas_call(

@@ -1,0 +1,211 @@
+"""The names the program puts into the device trace.
+
+The profiler's event name for a Pallas kernel is its HLO instruction's
+text, so a `pallas_call(..., name="flash_attention_fwd")` is what lets a
+trace tell the three flash kernels apart and `fused_adam` from them by
+name (benchmark/families/transformer_encdec.classify_kernel reads the
+instruction head). The kernels are compiled here for a described
+v5e:2x2 — nothing runs, no chip needed (on-chip-measurement guide §2):
+the topology is described inside a module-scoped fixture, never at
+import, and all such compiles stay in this one file. The op scopes of
+the compiled step (`forward/layer_norm`, `optimize/adam`, under the
+model's `fluid.name_scope`s) are HLO metadata and are read off a step
+lowered on the CPU.
+"""
+import os
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+import paddle_tpu as fluid
+
+# transformer_base at the benchmark's long cell: B=4 S=4096, 8 heads of 64
+B, S, H, D = 4, 4096, 8, 64
+BLOCK_Q, BLOCK_K = 512, 1024
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to JAX's persistent
+    cache but cannot be read back without the chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _custom_call_heads(text):
+    """Instruction names of the TPU custom calls in optimized HLO."""
+    return re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                      r'"tpu_custom_call"', text)
+
+
+def _flash_module():
+    # `paddle_tpu.kernels.flash_attention` the attribute is the function
+    import importlib
+    return importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+
+QKV = ((B, S, H, D), jnp.bfloat16)
+BIAS = ((B, 1, 1, S), jnp.float32)
+
+
+def test_flash_forward_is_named(one_chip, no_compile_cache):
+    fa = _flash_module()
+
+    def fwd(q, k, v, bias):
+        return fa._fa_forward(q, k, v, bias, D ** -0.5, BLOCK_Q, BLOCK_K,
+                              return_lse=True, layout="bshd",
+                              raw_lse=True, causal=True)
+
+    heads = _custom_call_heads(
+        _compiled_text(fwd, one_chip, QKV, QKV, QKV, BIAS))
+    assert heads and all(h.startswith("flash_attention_fwd")
+                         for h in heads), heads
+
+
+def test_flash_backward_kernels_are_named(one_chip, no_compile_cache):
+    fa = _flash_module()
+
+    def fwd_bwd(q, k, v, bias, g):
+        out, lse = fa._fa_forward(q, k, v, bias, D ** -0.5, BLOCK_Q,
+                                  BLOCK_K, return_lse=True,
+                                  layout="bshd", raw_lse=True,
+                                  causal=True)
+        return fa._fa_backward(q, k, v, bias, out, lse, g, D ** -0.5,
+                               BLOCK_Q, BLOCK_K, layout="bshd",
+                               lse_wide=True, want_dbias=False,
+                               causal=True)[:3]
+
+    heads = _custom_call_heads(
+        _compiled_text(fwd_bwd, one_chip, QKV, QKV, QKV, BIAS, QKV))
+    stems = {h.rsplit(".", 1)[0] if h.rsplit(".", 1)[-1].isdigit() else h
+             for h in heads}
+    assert stems == {"flash_attention_fwd", "flash_attention_dq",
+                     "flash_attention_dkv"}, heads
+
+
+@pytest.mark.parametrize("kernel", ["fused_adam", "fused_sgd"])
+def test_fused_optimizer_kernels_are_named(one_chip, no_compile_cache,
+                                           kernel, monkeypatch):
+    from paddle_tpu.kernels import fused_optimizer as fo
+    from paddle_tpu.kernels import registry
+    # the kernels ask the default backend, here the CPU, whether to run
+    # under the Pallas interpreter: this compile is for the chip
+    monkeypatch.setattr(registry, "interpret", lambda: False)
+    # the base model's widest routed parameter: a 32,000 x 512 table
+    table = ((32000, 512), jnp.float32)
+    scalar = ((), jnp.float32)
+    if kernel == "fused_adam":
+        fn = lambda p, g, m, v, lr: fo.fused_adam(p, g, m, v, lr)
+        shapes = (table, table, table, table, scalar)
+    else:
+        fn = lambda p, g, lr: fo.fused_sgd(p, g, lr)
+        shapes = (table, table, scalar)
+    heads = _custom_call_heads(_compiled_text(fn, one_chip, *shapes))
+    assert heads and all(h.startswith(kernel) for h in heads), heads
+
+
+def test_no_other_kernel_reads_as_flash_or_adam():
+    """The accepted classifier maps a head holding `adam` to fused_adam
+    and one holding `flash` or `kern` to flash attention: no other
+    kernel's name may hold any of them."""
+    from paddle_tpu.tuning import variants
+    names = ["fused_sgd", "quantized_matmul"] + [
+        f"tuned_matmul_{v.epilogue}_{v.bm}x{v.bn}x{v.bk}"
+        for v in variants.enumerate_variants()]
+    for n in names:
+        assert not any(h in n for h in ("adam", "flash", "kern")), n
+    src = open(variants.__file__).read()
+    assert 'name=f"tuned_matmul_{variant.epilogue}_{bm}x{bn}x{bk}"' in src
+
+
+def _lowered_step_text():
+    """Un-optimized HLO text (with metadata) of a small Transformer
+    training step, lowered on the CPU through the engine's own
+    callable."""
+    from paddle_tpu import models
+    from paddle_tpu.core.scope import Scope
+    cfg = models.transformer.TransformerConfig(
+        src_vocab_size=64, trg_vocab_size=64, d_model=32, d_inner=64,
+        n_head=2, n_layer=1, dropout=0.0, fuse_attention=True)
+    fluid.framework.unique_name.reset()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        cost, _, _ = models.transformer_train(cfg)
+        fluid.optimizer.AdamOptimizer(learning_rate=1e-3).minimize(cost)
+    scope = Scope()
+    feed = models.transformer.make_batch(cfg, 2, 8, 8)
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        exe.run(main, feed=feed, fetch_list=[cost])
+        compiled = exe._engine.compiled_step(main, scope, feed,
+                                             [cost.name])
+    return main, compiled.as_text()
+
+
+def test_compiled_step_carries_op_scopes():
+    main, text = _lowered_step_text()
+    names = set(re.findall(r'op_name="([^"]+)"', text))
+    assert names, "the compiled step's HLO carries no op_name metadata"
+
+    def has(fragment):
+        return any(fragment in n for n in names)
+
+    assert has("forward/layer_norm")
+    assert has("backward/")
+    assert has("optimize/adam")
+    # fluid.name_scope: the model's scopes lead the role and the type,
+    # on forward ops, on the grad ops made from them and on the update
+    # op of a parameter created inside
+    assert has("enc_0/self_attn/forward/fused_attention")
+    assert has("dec_0/cross_attn/backward/")
+    assert has("enc_0/ffn/optimize/adam")
+    assert has("embed/forward/lookup_table")
+    assert has("loss/forward/")
+    ops = main.global_block().ops
+    scoped = [op for op in ops if op.attr("op_namescope", "")]
+    assert len(scoped) > len(ops) // 2
+    assert all(op.attr("op_namescope").endswith("/") for op in scoped)
+
+
+def test_name_scope_nests_and_closes():
+    fluid.framework.unique_name.reset()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[4], dtype="float32")
+        with fluid.name_scope("a"):
+            with fluid.name_scope("b"):
+                y = fluid.layers.fc(x, size=2)
+            z = fluid.layers.relu(y)
+        out = fluid.layers.mean(z)
+    by_type = {op.type: op.attr("op_namescope", "")
+               for op in main.global_block().ops}
+    assert by_type["mul"] == "a/b/"
+    assert by_type["relu"] == "a/"
+    assert by_type["mean"] == ""

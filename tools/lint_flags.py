@@ -76,7 +76,6 @@ ALLOWLIST: Dict[str, str] = {
     "FLAGS.async_dispatch": "host-side: picks sync vs async dispatch "
                             "of the SAME compiled step",
     "FLAGS.autotune": "host-side: arms the tuning driver between steps",
-    "FLAGS.benchmark": "host-side: timing/printing around the step",
     "FLAGS.seed": "runtime state: seeds the RNG key that is a traced "
                   "ARGUMENT, not trace content",
     "FLAGS.step_timeout_s": "host-side: watchdog on the dispatch future",
