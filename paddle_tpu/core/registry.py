@@ -323,8 +323,13 @@ def _make_generic_grad_lowering(fwd_type: str):
       attrs:   copy of the forward attrs (incl. the forward op uid so rng
                replays identically).
     The lowering reconstructs the pure forward function of the
-    differentiated inputs and applies jax.vjp. XLA CSE dedupes the forward
-    recomputation against the forward pass inside the same compiled step.
+    differentiated inputs and applies jax.vjp. For a forward made of plain
+    XLA ops, XLA CSE dedupes the recomputation against the forward pass
+    inside the same compiled step. It does NOT merge custom calls (Pallas /
+    Mosaic kernels): a kernel forward recomputed here runs twice. An op
+    whose forward is a custom call declares an intermediate output that
+    carries what its backward needs and overrides this lowering to read it
+    (fused_attention's SoftmaxLse, ops/fused.py).
     """
 
     def grad_lowering(ctx: ExecContext):

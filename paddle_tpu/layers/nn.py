@@ -952,11 +952,15 @@ def fused_attention(q, k, v, bias=None, scale=None, block_q=None,
     baking an O(S^2) causal bias feed."""
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
+    # softmax log-sum-exp [B, H, Sq], kept for the grad op (as dropout
+    # keeps Mask): the backward kernels read it instead of running the
+    # forward kernel a second time
+    lse = helper.create_variable_for_type_inference("float32", True)
     inputs = {"Q": q, "K": k, "V": v}
     if bias is not None:
         inputs["BiasQK"] = bias
     helper.append_op("fused_attention", inputs=inputs,
-                     outputs={"Out": out},
+                     outputs={"Out": out, "SoftmaxLse": lse},
                      attrs={"scale": -1.0 if scale is None else
                             float(scale),
                             "block_q": int(block_q or 0),

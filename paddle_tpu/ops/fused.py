@@ -37,8 +37,9 @@ def _attn_args(ctx):
         scale = float(q.shape[-1]) ** -0.5
     # bias included: the FORWARD context white-casts every float input
     # (ExecContext), but the grad op is policy-unlisted — casting here
-    # keeps the recomputed forward bit-identical (CSE) and the backward
-    # differentiating exactly the function the forward executed
+    # keeps the backward differentiating exactly the function the
+    # forward executed (and the fallback's recomputed forward
+    # bit-identical to it)
     q, k, v, bias = amp_cast("fused_attention", q, k, v, bias)
     # Block-size policy: user-set attrs win; otherwise scale with the
     # sequence — r4 A/B at B=4 H=8 S=4096 D=64: bq=512/bk=1024 runs
@@ -65,7 +66,15 @@ def _attn_args(ctx):
     return q, k, v, bias, layout, scale, bq, bk, drop, causal
 
 
-@register_op("fused_attention")
+def _rows(x, layout):
+    """[B, S, H, D] / [B, H, S, D] as the kernels' row form (a free
+    reshape): [B, S, H*D] / [B*H, S, D]."""
+    if layout == "bshd":
+        return x.reshape(x.shape[0], x.shape[1], -1)
+    return x.reshape(-1, *x.shape[2:])
+
+
+@register_op("fused_attention", intermediate_outputs=("SoftmaxLse",))
 def fused_attention(ctx):
     """Q/K/V: [B, H, S, D] (layout "bhsd") or [B, S, H, D] ("bshd");
     optional BiasQK [B, 1|H, Sq|1, Sk] additive.
@@ -74,31 +83,48 @@ def fused_attention(ctx):
     dist_transformer.py:1043-1044 — applied in BOTH regimes; the Pallas
     kernels regenerate the mask from the hardware PRNG per block),
     causal (mask rows >= cols; the kernels SKIP fully-masked KV
-    blocks and elide their DMA)."""
+    blocks and elide their DMA).
+    SoftmaxLse (intermediate, float32 [B, H, Sq]): the softmax
+    log-sum-exp the forward kernel wrote, carried to the grad op so the
+    backward kernels need no second forward. Narrow on purpose: the
+    kernel's lane-broadcast carrier is 128x this (64 MiB a site at B=4
+    S=4096) and would live from forward to backward. Only the kernel
+    path in training writes a real value; everywhere else it is zeros
+    nothing reads."""
     from ..kernels.flash_attention import (
-        _fa_forward, _attn_reference, use_kernel_path)
+        _fa_forward, _attn_reference, use_kernel_path, _dims)
     res_t = jnp.result_type(ctx.input("Q"))
     q, k, v, bias, layout, scale, bq, bk, drop, causal = \
         _attn_args(ctx)
+    lse = None
     if drop is not None and drop[1] == 0:
         # dropout_prob ~ 1.0: everything dropped
-        ctx.set_output("Out", jnp.zeros(q.shape, res_t))
-        return
-    if use_kernel_path(q, k, bq, bk, layout):
-        # long-context regime: Pallas flash kernels, O(S) HBM. The
-        # forward requests (out, lse) even though only out is consumed:
-        # the grad lowering issues the IDENTICAL call, so XLA CSE runs
-        # the forward kernel once per step, not twice
+        out = jnp.zeros(q.shape, res_t)
+    elif use_kernel_path(q, k, bq, bk, layout):
+        # long-context regime: Pallas flash kernels, O(S) HBM
         if ctx.attr("is_test", False):
             # inference: no grad op will consume lse — skip the
             # un-DCE-able wide-lse output entirely
             out = _fa_forward(q, k, v, bias, scale, bq, bk,
                               layout=layout, causal=causal)
         else:
-            out, _ = _fa_forward(q, k, v, bias, scale, bq, bk,
-                                 return_lse=True, layout=layout,
-                                 raw_lse=True, causal=causal,
-                                 dropout=drop)
+            # XLA does not merge two Mosaic custom calls, so the grad
+            # op cannot get (out, lse) by repeating this call for free:
+            # it reads Out and the narrow lse stored here
+            out, lse = _fa_forward(q, k, v, bias, scale, bq, bk,
+                                   return_lse=True, layout=layout,
+                                   causal=causal, dropout=drop)
+            # Left alone, XLA fuses the narrowing slice into the
+            # backward's widening broadcast and keeps the kernel's wide
+            # carrier alive from forward to backward (+0.36 GiB peak at
+            # B=4 S=4096, 18 sites; PERF.md PR 27). Tied to out, the
+            # narrow value must exist before anything reads out, so the
+            # carrier dies here. out passes the barrier in the kernel's
+            # own row form: as [B, S, H, D] XLA gave it another layout
+            # and kept a second copy of out per site.
+            rows, lse = jax.lax.optimization_barrier(
+                (_rows(out, layout), lse))
+            out = rows.reshape(out.shape)
     else:
         # shape-bounded regime / CPU / odd shapes: XLA's fully-fused
         # composed formulation is faster while [Sq,Sk] fits (see the
@@ -106,6 +132,11 @@ def fused_attention(ctx):
         out = _attn_reference(q, k, v, bias, scale, layout=layout,
                               dropout=drop, causal=causal)
     ctx.set_output("Out", out.astype(res_t))
+    if lse is None:
+        # never read (the grad op takes this same branch), never
+        # fetched: XLA removes it from the compiled step
+        lse = jnp.zeros(_dims(q, layout)[:3], jnp.float32)
+    ctx.set_output("SoftmaxLse", lse)
 
 
 @override_grad_lowering("fused_attention")
@@ -116,9 +147,10 @@ def fused_attention_grad(ctx):
     output, so an attention MASK (additive bias built from feeds, never
     differentiated) would pay an O(B*H*Sq*Sk) f32 buffer per site
     (measured 2.1 GB at B=4 S=4096). Here dbias work happens only when
-    BiasQK@GRAD is actually bound. The forward (out, lse) is recomputed
-    and CSE-merged with the forward pass, like the generic vjp's
-    recompute."""
+    BiasQK@GRAD is actually bound. On the kernel path (out, lse) are
+    the forward op's own Out and SoftmaxLse: a recomputed forward is a
+    second Mosaic custom call, which XLA does not merge with the first
+    (36 forward calls for 18 attentions, PERF.md PR 26)."""
     from ..kernels.flash_attention import (
         _fa_forward, _fa_backward, _attn_reference, use_kernel_path)
     op = ctx.op
@@ -137,14 +169,26 @@ def fused_attention_grad(ctx):
         dq, dk, dv = (jnp.zeros_like(x) for x in (q, k, v))
         dbias = None if bias is None else jnp.zeros_like(bias)
     elif use_kernel_path(q, k, bq, bk, layout):
-        # identical call to the forward lowering's -> CSE-merged
-        out, lse = _fa_forward(q, k, v, bias, scale, bq, bk,
-                               return_lse=True, layout=layout,
-                               raw_lse=True, causal=causal,
-                               dropout=drop)
+        if ctx.has_input("SoftmaxLse") and \
+                not ctx.attr("is_test", False):
+            # read past ctx.input(): lse stays float32 under any amp
+            # list; Out is the kernel's own result cast to the primal
+            # dtype, so casting back is exact
+            out = ctx.env[op.input("Out")[0]].astype(q.dtype)
+            lse = ctx.env[op.input("SoftmaxLse")[0]]
+            lse_wide = False
+        else:
+            # the one remaining recompute: SoftmaxLse unbound (a
+            # program serialised before the slot existed, a hand-built
+            # op desc) or a forward at is_test, which wrote no lse
+            out, lse = _fa_forward(q, k, v, bias, scale, bq, bk,
+                                   return_lse=True, layout=layout,
+                                   raw_lse=True, causal=causal,
+                                   dropout=drop)
+            lse_wide = True
         dq, dk, dv, dbias = _fa_backward(
             q, k, v, bias, out, lse, dout.astype(q.dtype), scale, bq,
-            bk, layout=layout, lse_wide=True,
+            bk, layout=layout, lse_wide=lse_wide,
             want_dbias=_bound("BiasQK"), causal=causal, dropout=drop)
     else:
         def f(q, k, v, bias):

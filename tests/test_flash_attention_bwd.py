@@ -423,3 +423,264 @@ def test_dispatch_is_sequence_keyed(monkeypatch):
     assert fa.use_kernel_path(*qk(4, 1024), 512, 1024, "bshd")
     assert fa.use_kernel_path(*qk(2, 2048), 512, 1024, "bshd")
     assert fa.use_kernel_path(*qk(4, 4096), 512, 1024, "bshd")
+
+
+# ---------------------------------------------------------------------------
+# PR 27: fused_attention hands Out and a narrow SoftmaxLse to its grad op;
+# the backward runs no second forward kernel
+# ---------------------------------------------------------------------------
+
+_B, _H, _S, _D = 1, 2, 128, 16
+
+
+def _attn_shape(layout, S=_S):
+    return (_B, S, _H, _D) if layout == "bshd" else (_B, _H, S, _D)
+
+
+def _attn_program(layout, mode, n_sites=1, bias_grad=False):
+    """layers.fused_attention + append_backward over `n_sites` chained
+    attentions; returns (main, feed names, grad names)."""
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+
+    fluid.framework.unique_name.reset()
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        def data(name, shape):
+            var = layers.data(name=name, shape=list(shape),
+                              dtype="float32", append_batch_size=False)
+            var.stop_gradient = False
+            return var
+
+        # a leaf shared by two ops would need its grads summed, which
+        # append_backward does only for parameters: K/V per site
+        names = ["q"] + [f"{n}{i}" for i in range(n_sites) for n in "kv"]
+        q, *kv = (data(n, _attn_shape(layout)) for n in names)
+        bias = None
+        if mode == "padding":
+            bias = data("bias", (_B, 1, 1, _S))
+            bias.stop_gradient = not bias_grad
+            names.append("bias")
+        x = q
+        for i in range(n_sites):
+            x = layers.fused_attention(
+                x, kv[2 * i], kv[2 * i + 1], bias, block_q=128,
+                block_k=128, layout=layout, causal=(mode == "causal"))
+        loss = layers.reduce_sum(layers.square(x))
+        fluid.backward.append_backward(loss)
+    grads = [n + "@GRAD" for n in names
+             if n != "bias" or bias_grad]
+    return main, names, grads
+
+
+def _attn_feed(names, layout, seed=0):
+    rng = np.random.default_rng(seed)
+    feed = {n: rng.standard_normal(_attn_shape(layout)).astype("float32")
+            for n in names if n != "bias"}
+    if "bias" in names:
+        pad = np.zeros((_B, 1, 1, _S), "float32")
+        pad[..., _S - 32:] = -1e9
+        feed["bias"] = pad
+    return feed
+
+
+def _pallas_calls(jaxpr, acc=None):
+    """name -> count of pallas_call equations, sub-jaxprs included."""
+    acc = {} if acc is None else acc
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            acc[name] = acc.get(name, 0) + 1
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else (p,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_calls(sub, acc)
+    return acc
+
+
+def _engine_step(main, feed, fetch):
+    """Run one step through Executor.run; (fetched values, the step's
+    Pallas calls by name)."""
+    import paddle_tpu as fluid
+    from paddle_tpu.core.engine import _scope_array
+    from paddle_tpu.core.scope import Scope
+
+    def sig(a):
+        return jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a))
+
+    scope = Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        vals = exe.run(main, feed=feed, fetch_list=fetch)
+        _, traced = exe._engine._compiled_entry(main, scope, feed, fetch)
+        assert exe._engine.step_executables() == [1]
+        closed = traced.fn.trace(
+            {n: sig(_scope_array(scope, n)) for n in traced.donated_names},
+            {n: sig(_scope_array(scope, n)) for n in traced.const_names},
+            {n: sig(a) for n, a in feed.items()},
+            jax.ShapeDtypeStruct((2,), jnp.uint32)).jaxpr
+    return vals, _pallas_calls(closed.jaxpr)
+
+
+def _reference_grads(feed, layout, mode, n_sites, names):
+    scale = float(_D) ** -0.5
+
+    def loss(*args):
+        a = dict(zip(names, args))
+        x = a["q"]
+        for i in range(n_sites):
+            x = fa._attn_reference(x, a[f"k{i}"], a[f"v{i}"],
+                                   a.get("bias"), scale, layout=layout,
+                                   causal=(mode == "causal"))
+        return (x ** 2).sum()
+
+    return jax.grad(loss, tuple(range(len(names))))(
+        *(jnp.asarray(feed[n]) for n in names))
+
+
+@pytest.mark.parametrize("mode", ["plain", "causal", "padding"])
+@pytest.mark.parametrize("layout", ["bhsd", "bshd"])
+def test_engine_step_runs_each_flash_kernel_once_per_site(layout, mode):
+    """(a) The lowered step holds one forward, one dq and one dkv call
+    per attention: the grad op reads Out and SoftmaxLse."""
+    n_sites = 2
+    main, names, grads = _attn_program(layout, mode, n_sites)
+    feed = _attn_feed(names, layout)
+    vals, calls = _engine_step(main, feed, grads)
+    assert calls == {"flash_attention_fwd": n_sites,
+                     "flash_attention_dq": n_sites,
+                     "flash_attention_dkv": n_sites}, calls
+    ref = _reference_grads(feed, layout, mode, n_sites, names)
+    for got, want in zip(vals, ref):
+        np.testing.assert_allclose(got, np.asarray(want),
+                                   atol=5e-4, rtol=5e-4)
+
+
+def _lower_ops(block, env, skip_slot=None):
+    """Lower the block's ops in order into env (what the engine's trace
+    does), optionally hiding one input slot from the grad op."""
+    from paddle_tpu.core.registry import (OPS, ExecContext, _RngCtx,
+                                          _SlotView)
+    for op in block.ops:
+        view = op
+        if skip_slot and op.type == "fused_attention_grad":
+            view = _SlotView(
+                op.type,
+                {s: op.input(s) for s in op.input_slots()
+                 if not s.startswith(skip_slot)},
+                {s: op.output(s) for s in op.output_slots()},
+                dict(op._all_attrs()))
+        OPS.get(op.type).lowering(
+            ExecContext(view, env, _RngCtx(jax.random.PRNGKey(0))))
+    return env
+
+
+@pytest.mark.parametrize("mode,bias_grad", [
+    ("plain", False), ("causal", False), ("padding", False),
+    ("padding", True)])
+@pytest.mark.parametrize("layout", ["bhsd", "bshd"])
+def test_carried_lse_grads_bit_identical_to_recompute(layout, mode,
+                                                      bias_grad):
+    """(b) Lane 0 of the carrier -> narrow -> _widen is exact: the grads
+    from the carried pair equal the unbound-slot fallback's bit for bit.
+    (c) What is carried is float32 [B, H, Sq]; nothing of the carrier's
+    wide shape enters the grad op."""
+    main, names, grads = _attn_program(layout, mode,
+                                       bias_grad=bias_grad)
+    block = main.global_block()
+    feed = {n: jnp.asarray(a)
+            for n, a in _attn_feed(names, layout, seed=3).items()}
+    carried = _lower_ops(block, dict(feed))
+    fallback = _lower_ops(block, dict(feed), skip_slot="SoftmaxLse")
+    assert ("bias@GRAD" in grads) == bias_grad
+    for g in grads:
+        np.testing.assert_array_equal(np.asarray(carried[g]),
+                                      np.asarray(fallback[g]))
+
+    fwd = next(op for op in block.ops if op.type == "fused_attention")
+    gop = next(op for op in block.ops
+               if op.type == "fused_attention_grad")
+    lse_name, = fwd.output("SoftmaxLse")
+    assert gop.input("SoftmaxLse") == [lse_name]
+    lse = carried[lse_name]
+    assert lse.dtype == jnp.float32 and lse.shape == (_B, _H, _S)
+    assert tuple(block.var(lse_name).shape) == (_B, _H, _S)
+    assert block.var(lse_name).stop_gradient
+    plan = fa._Plan(layout, _B, _H, _S, _S, _D, 128, 128)
+    wide = tuple(plan.wide_shape(_S))
+    for slot in gop.input_slots():
+        for n in gop.input(slot):
+            if n:
+                assert tuple(carried[n].shape) != wide, (slot, n)
+    # the real thing, not the placeholder: matches the composed lse
+    ref_q, ref_k = feed["q"], feed["k0"]
+    if layout == "bshd":
+        ref_q, ref_k = (jnp.moveaxis(x, 1, 2) for x in (ref_q, ref_k))
+    s = jnp.einsum("bhqd,bhkd->bhqk", ref_q, ref_k) * float(_D) ** -0.5
+    if "bias" in feed:
+        s = s + feed["bias"]
+    if mode == "causal":
+        s = jnp.where(jnp.tril(jnp.ones((_S, _S), bool)), s, -jnp.inf)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(jax.nn.logsumexp(s, axis=-1)),
+        atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("layout", ["bhsd", "bshd"])
+def test_composed_path_lowers_no_pallas_call(layout, monkeypatch):
+    """(d) Off the kernel path nothing is carried and nothing is a
+    kernel: the step holds no Pallas call, the grads are jax.grad of the
+    composed formulation, and the slot holds a placeholder."""
+    monkeypatch.setattr(fa, "_INTERPRET", False)
+    main, names, grads = _attn_program(layout, "padding")
+    feed = _attn_feed(names, layout, seed=4)
+    fwd = next(op for op in main.global_block().ops
+               if op.type == "fused_attention")
+    lse_name, = fwd.output("SoftmaxLse")
+    vals, calls = _engine_step(main, feed, grads + [lse_name])
+    assert calls == {}
+    ref = _reference_grads(feed, layout, "padding", 1, names)
+    for got, want in zip(vals[:len(grads)], ref):
+        np.testing.assert_allclose(got, np.asarray(want),
+                                   atol=2e-4, rtol=2e-4)
+    assert vals[-1].shape == (_B, _H, _S) and not vals[-1].any()
+
+
+def test_serialised_program_keeps_lse_slot():
+    """(e) ProgramDesc round trip: the slot survives on both ops and the
+    reloaded step still runs one forward call per site."""
+    import paddle_tpu as fluid
+    main, names, grads = _attn_program("bshd", "causal", n_sites=2)
+    loaded = fluid.Program.parse_from_string(main.serialize_to_string())
+    ops = loaded.global_block().ops
+    fwd = [op for op in ops if op.type == "fused_attention"]
+    gops = [op for op in ops if op.type == "fused_attention_grad"]
+    assert len(fwd) == len(gops) == 2
+    assert sorted(op.output("SoftmaxLse")[0] for op in fwd) == \
+        sorted(op.input("SoftmaxLse")[0] for op in gops)
+    feed = _attn_feed(names, "bshd", seed=5)
+    vals, calls = _engine_step(loaded, feed, grads)
+    assert calls == {"flash_attention_fwd": 2, "flash_attention_dq": 2,
+                     "flash_attention_dkv": 2}, calls
+    want, _ = _engine_step(main, feed, grads)
+    for a, b in zip(vals, want):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_is_test_forward_with_grad_op_recomputes():
+    """A forward at is_test writes no lse; a grad op bound to it must
+    not read the placeholder."""
+    import paddle_tpu as fluid
+    main, names, grads = _attn_program("bhsd", "plain")
+    block = main.global_block()
+    for op in block.ops:
+        if op.type.startswith("fused_attention"):
+            op.set_attr("is_test", True)
+    feed = {n: jnp.asarray(a)
+            for n, a in _attn_feed(names, "bhsd", seed=6).items()}
+    env = _lower_ops(block, dict(feed))
+    ref = _reference_grads(feed, "bhsd", "plain", 1, names)
+    for g, want in zip(grads, ref):
+        np.testing.assert_allclose(np.asarray(env[g]), np.asarray(want),
+                                   atol=5e-4, rtol=5e-4)
