@@ -24,7 +24,7 @@ contrib/mixed_precision/fp16_utils.py:103 find_true_prev_op/insert_cast):
 * BLACK (loss/softmax reductions): reduced-precision float inputs are
   cast UP to f32. The cast fuses into the consuming reduction, so this
   costs registers, not HBM.
-* NORM ops (layer_norm/batch_norm/group_norm/data_norm) opt out of
+* NORM ops (layer_norm/rms_norm/batch_norm/group_norm/data_norm) opt out of
   input casting entirely: their lowerings read bf16 activations, compute
   statistics in f32 internally (see ops/nn.py), emit Y in the input's
   dtype, and keep f32 running-stat persistables f32 — context casting
@@ -60,7 +60,7 @@ GRAY_OPS = frozenset({
     "pool2d", "pad", "pad2d", "concat", "split", "stack", "slice",
     "reshape2", "reshape", "transpose2", "transpose", "squeeze2",
     "squeeze", "unsqueeze2", "unsqueeze", "expand", "flatten2",
-    "flatten", "add_position_encoding",
+    "flatten", "add_position_encoding", "rotary_embedding", "swiglu",
 })
 
 # numerically sensitive: always f32 compute (extended per-config via the
@@ -76,10 +76,13 @@ BLACK_OPS = frozenset({
     "sigmoid_cross_entropy_with_logits",
     "mean", "reduce_mean", "reduce_sum", "exp", "log", "square",
     "cos_sim",
+    # the router's logits are float32 in the published modelling code: in
+    # bf16 near-tied scores flip the top-k choice
+    "moe_router",
 })
 
 NORM_OPS = frozenset({
-    "layer_norm", "batch_norm", "group_norm", "data_norm",
+    "layer_norm", "batch_norm", "group_norm", "data_norm", "rms_norm",
 })
 
 OUT_CAST_OPS = frozenset({"lookup_table", "lookup_table_v2"})

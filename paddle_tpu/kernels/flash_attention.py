@@ -41,6 +41,7 @@ Grad identities (standard flash attention backward):
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -155,11 +156,18 @@ def _seq_len(x, layout):
     return x.shape[1] if layout == "bshd" else x.shape[2]
 
 
-def _heads_per_block(H, D):
+def _heads_per_block(H, D, Dv=None):
     """bshd lane packing: how many heads share one lane block. Aims for
     128 lanes (the Mosaic minimum for a strict lane block); interpret
-    mode and _kernel_ok tolerate smaller when H is small."""
-    hpb = max(1, 128 // D) if D < 128 else 1
+    mode and _kernel_ok tolerate smaller when H is small. Where q/k and
+    v differ in width (Dv), or a width over 128 is no multiple of it,
+    the least number of heads that makes BOTH blocks whole multiples of
+    128 lanes: 2 for 192/128 (blocks of 384 and 256)."""
+    if Dv in (None, D) and (D < 128 or D % 128 == 0):
+        hpb = max(1, 128 // D) if D < 128 else 1
+    else:
+        a, b = (128 // math.gcd(w, 128) for w in (D, Dv or D))
+        hpb = a * b // math.gcd(a, b)
     hpb = min(hpb, H)
     while H % hpb:
         hpb -= 1
@@ -175,12 +183,14 @@ class _Plan:
     `order` maps the q/k sequence grid axes for the active kernel
     (dq-style grids put q before kv; dkv-style grids swap them)."""
 
-    def __init__(self, layout, B, H, Sq, Sk, D, bq, bk):
+    def __init__(self, layout, B, H, Sq, Sk, D, bq, bk, Dv=None):
         self.layout = layout
         self.B, self.H, self.Sq, self.Sk, self.D = B, H, Sq, Sk, D
+        # q and k are D wide, v (and so out, do, dv) Dv wide
+        self.Dv = D if Dv is None else Dv
         self.bq, self.bk = bq, bk
         if layout == "bshd":
-            self.hpb = _heads_per_block(H, D)
+            self.hpb = _heads_per_block(H, D, self.Dv)
             self.Hg = H // self.hpb
         else:
             self.hpb = 1
@@ -190,7 +200,7 @@ class _Plan:
         """HBM view handed to pallas_call."""
         if self.layout == "bshd":
             B, S = x.shape[0], x.shape[1]
-            return x.reshape(B, S, self.H * self.D)
+            return x.reshape(B, S, self.H * x.shape[3])
         B, H, S, D = x.shape
         return x.reshape(B * H, S, D)
 
@@ -357,7 +367,7 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
                q_axis, kv_axis, causal, drop_t):
     kv_idx = pl.program_id(kv_axis)
     q_idx = pl.program_id(q_axis)
-    D, bq, bk = plan.D, plan.bq, plan.bk
+    D, Dv, bq, bk = plan.D, plan.Dv, plan.bq, plan.bk
     bhs = [plan.bh(i) for i in range(plan.hpb)] \
         if drop_t is not None else None
 
@@ -396,7 +406,7 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
                                   drop_t)
                 p_v = jnp.where(keep, p * (256.0 / drop_t), 0.0)
             acc_scr[i] = acc_scr[i] * corr + jax.lax.dot_general(
-                p_v.astype(v_ref.dtype), plan.lanes(v_ref, i, D),
+                p_v.astype(v_ref.dtype), plan.lanes(v_ref, i, Dv),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_scr[i] = jnp.broadcast_to(m_next, m_scr[i].shape)
@@ -416,7 +426,7 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
     def _finish():
         for i in range(plan.hpb):
             denom = jnp.maximum(l_scr[i][:, :1], 1e-30)
-            plan.store_lanes(o_ref, i, D,
+            plan.store_lanes(o_ref, i, Dv,
                              (acc_scr[i] / denom).astype(o_ref.dtype))
             if lse_ref is not None:
                 plan.store_lanes(
@@ -431,7 +441,7 @@ def _fa_bwd_dq_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
                       causal, drop_t):
     kv_idx = pl.program_id(kv_axis)
     q_idx = pl.program_id(q_axis)
-    D, bq, bk = plan.D, plan.bq, plan.bk
+    D, Dv, bq, bk = plan.D, plan.Dv, plan.bq, plan.bk
     bhs = [plan.bh(i) for i in range(plan.hpb)] \
         if drop_t is not None else None
 
@@ -443,10 +453,10 @@ def _fa_bwd_dq_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
         for i in range(plan.hpb):
             q = plan.lanes(q_ref, i, D)                 # [bq, D]
             k = plan.lanes(k_ref, i, D)                 # [bk, D]
-            v = plan.lanes(v_ref, i, D)
-            do = plan.lanes(do_ref, i, D).astype(jnp.float32)
+            v = plan.lanes(v_ref, i, Dv)
+            do = plan.lanes(do_ref, i, Dv).astype(jnp.float32)
             lse = plan.lanes(lse_ref, i, 128)[:, :1]    # [bq, 1]
-            di = jnp.sum(plan.lanes(out_ref, i, D).astype(jnp.float32)
+            di = jnp.sum(plan.lanes(out_ref, i, Dv).astype(jnp.float32)
                          * do, axis=-1, keepdims=True)
             if glse_ref is not None:
                 di = di - plan.lanes(glse_ref, i, 128)[:, :1]
@@ -507,7 +517,7 @@ def _fa_bwd_dkv_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
                        kv_axis, causal, drop_t):
     q_idx = pl.program_id(q_axis)
     kv_idx = pl.program_id(kv_axis)
-    D, bq, bk = plan.D, plan.bq, plan.bk
+    D, Dv, bq, bk = plan.D, plan.Dv, plan.bq, plan.bk
     bhs = [plan.bh(i) for i in range(plan.hpb)] \
         if drop_t is not None else None
 
@@ -520,10 +530,10 @@ def _fa_bwd_dkv_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
         for i in range(plan.hpb):
             q = plan.lanes(q_ref, i, D)
             k = plan.lanes(k_ref, i, D)
-            v = plan.lanes(v_ref, i, D)
-            do = plan.lanes(do_ref, i, D).astype(jnp.float32)
+            v = plan.lanes(v_ref, i, Dv)
+            do = plan.lanes(do_ref, i, Dv).astype(jnp.float32)
             lse = plan.lanes(lse_ref, i, 128)[:, :1]
-            di = jnp.sum(plan.lanes(out_ref, i, D).astype(jnp.float32)
+            di = jnp.sum(plan.lanes(out_ref, i, Dv).astype(jnp.float32)
                          * do, axis=-1, keepdims=True)
             if glse_ref is not None:
                 di = di - plan.lanes(glse_ref, i, 128)[:, :1]
@@ -544,7 +554,7 @@ def _fa_bwd_dkv_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
             p_v = p if keep is None else \
                 jnp.where(keep, p * (256.0 / drop_t), 0.0)
             dv_scr[i] += jax.lax.dot_general(
-                p_v.astype(do_ref.dtype), plan.lanes(do_ref, i, D),
+                p_v.astype(do_ref.dtype), plan.lanes(do_ref, i, Dv),
                 (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dp = jax.lax.dot_general(
@@ -569,7 +579,7 @@ def _fa_bwd_dkv_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
         for i in range(plan.hpb):
             plan.store_lanes(dk_ref, i, D,
                              dk_scr[i].astype(dk_ref.dtype))
-            plan.store_lanes(dv_ref, i, D,
+            plan.store_lanes(dv_ref, i, Dv,
                              dv_scr[i].astype(dv_ref.dtype))
 
 
@@ -596,12 +606,13 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
                 return_lse=False, layout="bhsd", raw_lse=False,
                 causal=False, dropout=None):
     B, H, Sq, D = _dims(q, layout)
+    Dv = v.shape[3]
     Sk = _seq_len(k, layout)
     bq = min(block_q, Sq)
     bk = min(block_k, Sk)
     assert Sq % bq == 0 and Sk % bk == 0, (Sq, Sk, bq, bk)
     n_kv = Sk // bk
-    plan = _Plan(layout, B, H, Sq, Sk, D, bq, bk)
+    plan = _Plan(layout, B, H, Sq, Sk, D, bq, bk, Dv)
     def _sds(shape, dtype):
         return _out_struct(shape, dtype, like=q)
 
@@ -622,7 +633,7 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
     in_specs = [
         plan.row_spec(bq, D, qa),
         plan.row_spec(bk, D, ka, idx=k_idx),
-        plan.row_spec(bk, D, ka, idx=k_idx),
+        plan.row_spec(bk, Dv, ka, idx=k_idx),
     ]
     args = [plan.rows(q), plan.rows(k), plan.rows(v)]
     if bias is not None:
@@ -636,9 +647,9 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         args.append(seed)
 
-    out_rows = ((B, Sq, H * D) if layout == "bshd"
-                else (B * H, Sq, D))
-    out_specs = [plan.row_spec(bq, D, qa)]
+    out_rows = ((B, Sq, H * Dv) if layout == "bshd"
+                else (B * H, Sq, Dv))
+    out_specs = [plan.row_spec(bq, Dv, qa)]
     out_shape = [_sds(out_rows, q.dtype)]
     if return_lse:
         out_specs.append(plan.wide_spec(bq, qa))
@@ -670,7 +681,7 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
         scratch_shapes=[
             pltpu.VMEM((plan.hpb, bq, 128), jnp.float32),
             pltpu.VMEM((plan.hpb, bq, 128), jnp.float32),
-            pltpu.VMEM((plan.hpb, bq, D), jnp.float32),
+            pltpu.VMEM((plan.hpb, bq, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * kv_axis
@@ -680,8 +691,8 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
 
     def _out(o):
         if layout == "bshd":
-            return o.reshape(B, Sq, H, D)
-        return o.reshape(B, H, Sq, D)
+            return o.reshape(B, Sq, H, Dv)
+        return o.reshape(B, H, Sq, Dv)
 
     if return_lse:
         out, lse_w = res
@@ -727,12 +738,13 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     multi-output custom call cannot DCE (measured 2.1 GB/site at B=4
     S=4096), and a padding/causal-mask bias never needs a gradient."""
     B, H, Sq, D = _dims(q, layout)
+    Dv = v.shape[3]
     Sk = _seq_len(k, layout)
     bq = min(block_q, Sq)
     bk = min(block_k, Sk)
     n_q = Sq // bq
     n_kv = Sk // bk
-    plan = _Plan(layout, B, H, Sq, Sk, D, bq, bk)
+    plan = _Plan(layout, B, H, Sq, Sk, D, bq, bk, Dv)
     qr, kr, vr = plan.rows(q), plan.rows(k), plan.rows(v)
     dor, outr = plan.rows(g), plan.rows(out)
     lse_w = lse if lse_wide else _widen(lse.astype(jnp.float32), plan)
@@ -743,13 +755,13 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     def _sds(shape, dtype):
         return _out_struct(shape, dtype, like=q)
 
-    def out_rows(S):
-        return ((B, S, H * D) if layout == "bshd" else (B * H, S, D))
+    def out_rows(S, W=D):
+        return ((B, S, H * W) if layout == "bshd" else (B * H, S, W))
 
-    def _unrows(o, S):
+    def _unrows(o, S, W=D):
         if layout == "bshd":
-            return o.reshape(B, S, H, D)
-        return o.reshape(B, H, S, D)
+            return o.reshape(B, S, H, W)
+        return o.reshape(B, H, S, W)
 
     if want_dbias is None:
         want_dbias = bias is not None
@@ -772,10 +784,10 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     in_specs = [
         plan.row_spec(bq, D, qa),
         plan.row_spec(bk, D, ka, idx=k_idx),
-        plan.row_spec(bk, D, ka, idx=k_idx),
+        plan.row_spec(bk, Dv, ka, idx=k_idx),
         plan.wide_spec(bq, qa),
-        plan.row_spec(bq, D, qa),
-        plan.row_spec(bq, D, qa),
+        plan.row_spec(bq, Dv, qa),
+        plan.row_spec(bq, Dv, qa),
     ]
     args = [qr, kr, vr, lse_w, outr, dor]
     if has_glse:
@@ -862,10 +874,10 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     in_specs = [
         plan.row_spec(bq, D, qa, idx=q_idx_f),
         plan.row_spec(bk, D, ka),
-        plan.row_spec(bk, D, ka),
+        plan.row_spec(bk, Dv, ka),
         plan.wide_spec(bq, qa, idx=q_idx_f),
-        plan.row_spec(bq, D, qa, idx=q_idx_f),
-        plan.row_spec(bq, D, qa, idx=q_idx_f),
+        plan.row_spec(bq, Dv, qa, idx=q_idx_f),
+        plan.row_spec(bq, Dv, qa, idx=q_idx_f),
     ]
     args = [qr, kr, vr, lse_w, outr, dor]
     if has_glse:
@@ -901,35 +913,36 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
         grid=grid,
         in_specs=in_specs,
         out_specs=[plan.row_spec(bk, D, ka),
-                   plan.row_spec(bk, D, ka)],
+                   plan.row_spec(bk, Dv, ka)],
         out_shape=[_sds(out_rows(Sk), k.dtype),
-                   _sds(out_rows(Sk), v.dtype)],
+                   _sds(out_rows(Sk, Dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((plan.hpb, bk, D), jnp.float32),
-                        pltpu.VMEM((plan.hpb, bk, D), jnp.float32)],
+                        pltpu.VMEM((plan.hpb, bk, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * q_axis
             + ("arbitrary",)),
         interpret=_INTERPRET,
     )(*args)
-    return dq, _unrows(dk, Sk), _unrows(dv, Sk), dbias
+    return dq, _unrows(dk, Sk), _unrows(dv, Sk, Dv), dbias
 
 
-def _kernel_ok(q, k, block_q, block_k, layout="bhsd"):
+def _kernel_ok(q, k, block_q, block_k, layout="bhsd", v=None):
     import os
     if os.environ.get("PT_FORCE_COMPOSED"):   # A/B-measurement knob
         return False
     Sq, Sk = _seq_len(q, layout), _seq_len(k, layout)
     D = q.shape[3]
+    Dv = D if v is None else v.shape[3]
     if layout == "bshd":
         H = q.shape[2]
-        hpb = _heads_per_block(H, D)
+        hpb = _heads_per_block(H, D, Dv)
         # real Mosaic requires strict 128-lane (or full-minor) blocks;
         # the interpreter does not care, which lets CPU tests cover
         # small shapes
-        if not _INTERPRET and (hpb * D) % 128 != 0:
+        if not _INTERPRET and ((hpb * D) % 128 or (hpb * Dv) % 128):
             return False
     return (Sq % min(block_q, Sq) == 0 and Sk % min(block_k, Sk) == 0
-            and D % 8 == 0
+            and D % 8 == 0 and Dv % 8 == 0
             and (_INTERPRET or jax.default_backend() != "cpu"))
 
 
@@ -959,9 +972,11 @@ def _kernel_ok(q, k, block_q, block_k, layout="bhsd"):
 _KERNEL_MIN_SEQ_PRODUCT = 1024 * 1024      # Sq * Sk
 
 
-def use_kernel_path(q, k, block_q=128, block_k=128, layout="bhsd"):
+def use_kernel_path(q, k, block_q=128, block_k=128, layout="bhsd",
+                    v=None):
     """True when the fused-attention op should route through the Pallas
-    kernels rather than the composed einsum formulation.
+    kernels rather than the composed einsum formulation. `v` is given
+    where its head width may differ from q's and k's.
 
     Registry-governed: FLAGS_use_custom_kernels off (or
     "flash_attention" in PT_KERNEL_DENY) forces the composed path, and
@@ -972,7 +987,7 @@ def use_kernel_path(q, k, block_q=128, block_k=128, layout="bhsd"):
     if not _kreg.allowed("flash_attention"):
         _kreg.count("flash_attention", "denied")
         return False
-    ok = _kernel_ok(q, k, block_q, block_k, layout) \
+    ok = _kernel_ok(q, k, block_q, block_k, layout, v) \
         and not _kreg.in_auto_partitioned_trace()
     if ok and not _INTERPRET \
             and not os.environ.get("PT_FORCE_KERNEL"):
@@ -1038,7 +1053,7 @@ def flash_attention(q, k, v, bias=None, scale=1.0, block_q=128,
     entirely — a multi-output Pallas call cannot DCE the ds tile, so
     callers that never read the bias gradient must say so here; None
     (default) keeps the historical behavior (dbias iff bias given)."""
-    if _kernel_ok(q, k, block_q, block_k, layout):
+    if _kernel_ok(q, k, block_q, block_k, layout, v):
         return _fa_forward(q, k, v, bias, scale, block_q, block_k,
                            layout=layout, causal=causal)
     qb, kb, vb = q, k, v
@@ -1050,7 +1065,7 @@ def flash_attention(q, k, v, bias=None, scale=1.0, block_q=128,
 
 def _fa_fwd(q, k, v, bias, scale, block_q, block_k, layout, causal,
             need_dbias):
-    if _kernel_ok(q, k, block_q, block_k, layout):
+    if _kernel_ok(q, k, block_q, block_k, layout, v):
         # lse residual stays in the kernel's wide carrier layout;
         # _kernel_ok is static, so _fa_bwd re-derives the same branch
         out, lse = _fa_forward(q, k, v, bias, scale, block_q, block_k,
@@ -1072,11 +1087,11 @@ def _fa_bwd(scale, block_q, block_k, layout, causal, need_dbias, res,
     q, k, v, bias, out, lse = res
     want_dbias = (bias is not None) if need_dbias is None \
         else bool(need_dbias)
-    if use_kernel_path(q, k, block_q, block_k, layout):
+    if use_kernel_path(q, k, block_q, block_k, layout, v):
         dq, dk, dv, dbias = _fa_backward(
             q, k, v, bias, out, lse, g, scale, block_q, block_k,
             layout=layout, causal=causal, want_dbias=want_dbias,
-            lse_wide=_kernel_ok(q, k, block_q, block_k, layout))
+            lse_wide=_kernel_ok(q, k, block_q, block_k, layout, v))
         return dq, dk, dv, dbias if want_dbias else None
 
     def f(q, k, v, bias):

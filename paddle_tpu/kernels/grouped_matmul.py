@@ -1,0 +1,294 @@
+"""Grouped matmul over the experts a chip holds (Pallas), dropless.
+
+A mixture-of-experts layer sends each token to `top_k` of `num_experts`
+experts; this chip holds `experts_held` of them. The rows routed to held
+experts are gathered into ONE row buffer, expert by expert, and three
+kernels do every matrix product of the expert feed-forward over it:
+
+  moe_grouped_matmul_fwd   out[r] = lhs[r] @ rhs[expert(r)]
+  moe_grouped_matmul_dx    dlhs[r] = dout[r] @ rhs[expert(r)]^T
+  moe_grouped_matmul_dw    drhs[e] = sum over rows r of e: lhs[r]^T dout[r]
+
+The row buffer (`plan_rows`). Shapes are static, token counts are not,
+and no token is ever dropped: the buffer has room for the worst case,
+every choice of every token held here (`top_k * T` rows), plus one tile
+a held expert so that every group can start on a tile boundary. A
+group's rows are padded up to whole tiles of `TILE_ROWS`; an expert no
+token chose keeps one (all-padding) tile, so the dw kernel visits, and
+so writes, every expert's block. Each tile therefore belongs to exactly
+one expert (`tile_expert`, scalar-prefetched), and the tiles in use are
+the first `n_active` of the buffer. Work is done only for those: the
+index maps clamp a later grid step to the last tile in use, so Mosaic
+sees a repeated block index and elides its DMA, and `pl.when` skips its
+body. Rows past the last tile in use are never written: whatever reads
+the buffer masks them (`valid`), it does not multiply them by zero.
+
+Routing. `select()`-governed like fused_adam (kernels/registry.py): off
+the CPU and when not denied the three kernels run; otherwise the
+`lowered` path computes the same three products over the same buffer
+with `lax.ragged_dot_general`, which XLA can partition and a CPU can
+run. Decisions land in `dispatch_stats()` under `moe_grouped_matmul`.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import registry
+
+TILE_ROWS = 128
+# the dw kernel's output block [bk, n] f32 is held to this many bytes
+_DW_BLOCK_BYTES = 2 * 1024 * 1024
+
+__all__ = ["TILE_ROWS", "plan_rows", "buffer_rows", "gmm", "gmm_dx",
+           "gmm_dw", "use_kernels"]
+
+
+# ---------------------------------------------------------------------------
+# the row buffer
+# ---------------------------------------------------------------------------
+
+def buffer_rows(n_choices: int, experts_held: int,
+                tile: int = TILE_ROWS) -> int:
+    """Rows of the worst-case buffer: every choice held here, each group
+    padded to whole tiles (at most one tile of padding a group)."""
+    return (-(-n_choices // tile) + experts_held) * tile
+
+
+def plan_rows(local_expert, experts_held: int, tile: int = TILE_ROWS):
+    """Lay the choices routed to held experts out in the row buffer.
+
+    local_expert  int32 [n_choices]: the held expert's index in
+                  [0, experts_held) or anything else for a choice that
+                  is routed to an expert another chip holds.
+    Returns a dict of int32/bool arrays:
+      row_of_choice [n_choices]  the choice's row (only where `held`)
+      held          [n_choices]  the choice is routed to a held expert
+      choice_of_row [rows]       the choice a row carries (0 for padding)
+      valid         [rows]       the row carries a choice
+      tile_expert   [rows/tile]  the expert each tile belongs to
+      n_active      [1]          tiles in use: the first n_active
+      sizes         [experts_held]  rows routed to each held expert
+    """
+    n = local_expert.shape[0]
+    e = experts_held
+    rows = buffer_rows(n, e, tile)
+    n_tiles = rows // tile
+    held = (local_expert >= 0) & (local_expert < e)
+    key = jnp.where(held, local_expert, e).astype(jnp.int32)
+    sizes = jnp.sum(key[:, None] == jnp.arange(e, dtype=jnp.int32)[None],
+                    axis=0, dtype=jnp.int32)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    first_sorted = jnp.cumsum(sizes) - sizes        # group's first, sorted
+    tiles = jnp.maximum(1, -(-sizes // tile))
+    tile_end = jnp.cumsum(tiles)
+    first_row = (tile_end - tiles) * tile           # group's first row
+    n_active = tile_end[-1:]
+
+    # rows -> choices, by gathers alone
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(n_tiles, dtype=jnp.int32),
+                         side="right").astype(jnp.int32), e - 1)
+    row = jnp.arange(rows, dtype=jnp.int32)
+    row_expert = tile_expert[row // tile]
+    offset = row - first_row[row_expert]
+    valid = (offset < sizes[row_expert]) & (row // tile < n_active[0])
+    src = jnp.clip(first_sorted[row_expert] + offset, 0, n - 1)
+    choice_of_row = jnp.where(valid, order[src], 0)
+
+    # choices -> rows: a choice's rank in the sorted order
+    rank = jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32))
+    safe = jnp.minimum(key, e - 1)
+    row_of_choice = jnp.where(
+        held, first_row[safe] + rank - first_sorted[safe], 0)
+    return {"row_of_choice": row_of_choice, "held": held,
+            "choice_of_row": choice_of_row, "valid": valid,
+            "tile_expert": tile_expert, "n_active": n_active,
+            "sizes": sizes}
+
+
+# ---------------------------------------------------------------------------
+# kernels
+# ---------------------------------------------------------------------------
+
+def _in_use(t, n_active):
+    """Clamp a grid step past the tiles in use to the last of them: a
+    repeated block index, so no DMA."""
+    return jnp.minimum(t, n_active[0] - 1)
+
+
+def _gmm_kernel(te_ref, na_ref, lhs_ref, rhs_ref, out_ref, *, transpose):
+    @pl.when(pl.program_id(0) < na_ref[0])
+    def _run():
+        dims = (((1,), (1,)), ((), ())) if transpose \
+            else (((1,), (0,)), ((), ()))
+        out_ref[...] = lax.dot_general(
+            lhs_ref[...], rhs_ref[...], dims,
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+
+def _gmm_call(name, lhs, rhs, plan, transpose, tile):
+    rows, k = lhs.shape
+    n_out = rhs.shape[1] if transpose else rhs.shape[2]
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(rows // tile,),
+        in_specs=[
+            pl.BlockSpec((tile, k), lambda t, te, na: (_in_use(t, na), 0)),
+            pl.BlockSpec((None,) + rhs.shape[1:],
+                         lambda t, te, na: (te[_in_use(t, na)], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((tile, n_out),
+                               lambda t, te, na: (_in_use(t, na), 0)))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose=transpose),
+        name=name, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, n_out), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=registry.interpret(),
+    )(plan["tile_expert"], plan["n_active"], lhs, rhs)
+
+
+def _dw_kernel(te_ref, na_ref, lhs_ref, dout_ref, out_ref):
+    t = pl.program_id(1)
+    live = t < na_ref[0]
+    prev = te_ref[jnp.maximum(t, 1) - 1]
+    first = jnp.logical_or(t == 0, te_ref[t] != prev)
+
+    @pl.when(jnp.logical_and(live, first))
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(live)
+    def _run():
+        out_ref[...] += lax.dot_general(
+            lhs_ref[...], dout_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def _dw_block(k, n):
+    """Largest 128-multiple divisor of k whose [bk, n] f32 block stays
+    within _DW_BLOCK_BYTES (k itself when k is no multiple of 128)."""
+    if k % 128:
+        return k
+    best = 128
+    for bk in range(128, k + 1, 128):
+        if k % bk == 0 and bk * n * 4 <= _DW_BLOCK_BYTES:
+            best = bk
+    return best
+
+
+def _dw_call(lhs, dout, plan, experts_held, tile):
+    rows, k = lhs.shape
+    n = dout.shape[1]
+    bk = _dw_block(k, n)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(k // bk, rows // tile),
+        in_specs=[
+            pl.BlockSpec((tile, bk),
+                         lambda j, t, te, na: (_in_use(t, na), j)),
+            pl.BlockSpec((tile, n), lambda j, t, te, na: (_in_use(t, na), 0)),
+        ],
+        out_specs=pl.BlockSpec(
+            (None, bk, n), lambda j, t, te, na: (te[_in_use(t, na)], j, 0)))
+    return pl.pallas_call(
+        _dw_kernel, name="moe_grouped_matmul_dw", grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((experts_held, k, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=registry.interpret(),
+    )(plan["tile_expert"], plan["n_active"], lhs, dout)
+
+
+# ---------------------------------------------------------------------------
+# lowered path: the same products over the same buffer, by XLA
+# ---------------------------------------------------------------------------
+
+def _padded_sizes(plan, tile):
+    """Rows each group takes in the buffer (whole tiles), as the ragged
+    dot's group sizes: their sum is the rows in use, the rest is left
+    zero."""
+    return jnp.maximum(1, -(-plan["sizes"] // tile)) * tile
+
+
+def _lowered_gmm(lhs, rhs, plan, transpose, tile):
+    if transpose:
+        rhs = jnp.swapaxes(rhs, 1, 2)
+    return lax.ragged_dot(lhs, rhs, _padded_sizes(plan, tile),
+                          preferred_element_type=jnp.float32
+                          ).astype(lhs.dtype)
+
+
+def _lowered_dw(lhs, dout, plan, experts_held, tile):
+    dims = lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    return lax.ragged_dot_general(
+        lhs, dout, _padded_sizes(plan, tile), dims,
+        preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# entry points
+# ---------------------------------------------------------------------------
+
+def use_kernels(lhs, rhs) -> bool:
+    """One decision a layer, counted under `moe_grouped_matmul`: the
+    three Pallas kernels (`custom`) or the ragged dots (`lowered`)."""
+    if not registry.routable("moe_experts"):
+        return False
+    return registry.select(
+        "moe_experts", registry.signature("moe_experts", lhs, rhs)) \
+        is not None
+
+
+def gmm(lhs, rhs, plan, kernels, tile=TILE_ROWS):
+    """[rows, k] x [experts, k, n] -> [rows, n], each row with its own
+    tile's expert. Rows past the tiles in use are not written on the
+    kernel path and zero on the lowered one."""
+    if kernels:
+        return _gmm_call("moe_grouped_matmul_fwd", lhs, rhs, plan, False,
+                         tile)
+    return _lowered_gmm(lhs, rhs, plan, False, tile)
+
+
+def gmm_dx(dout, rhs, plan, kernels, tile=TILE_ROWS):
+    """[rows, n] x [experts, k, n]^T -> [rows, k]: gmm's gradient for
+    its rows."""
+    if kernels:
+        return _gmm_call("moe_grouped_matmul_dx", dout, rhs, plan, True,
+                         tile)
+    return _lowered_gmm(dout, rhs, plan, True, tile)
+
+
+def gmm_dw(lhs, dout, plan, experts_held, kernels, tile=TILE_ROWS):
+    """[rows, k]^T x [rows, n] summed within each group -> float32
+    [experts, k, n]: gmm's gradient for its weights. Padding rows must
+    be zero in `lhs` or in `dout`."""
+    if kernels:
+        return _dw_call(lhs, dout, plan, experts_held, tile)
+    return _lowered_dw(lhs, dout, plan, experts_held, tile)
+
+
+def _eligible(sig: registry.Signature) -> bool:
+    """bf16 or f32 rows whose widths Mosaic can tile: both matrix
+    dimensions multiples of 128 (the interpreter takes any)."""
+    (rows, k), (_, k2, n) = sig.shapes[0], sig.shapes[1]
+    return (sig.dtypes[0] in ("bfloat16", "float32")
+            and sig.dtypes[0] == sig.dtypes[1] and k == k2
+            and (registry._INTERPRET or (k % 128 == 0 and n % 128 == 0)))
+
+
+registry.register_kernel(
+    "moe_grouped_matmul", op_types=("moe_experts",), eligible=_eligible,
+    run=gmm, source_tag="grouped_matmul.py",
+    doc="dropless grouped matmul over the held experts' row buffer "
+        "(fwd, dx, dw); tiles past the last routed row are skipped")

@@ -370,12 +370,52 @@ def dropout_mask_identity() -> Dict[str, Any]:
             "keep_frac": float(m_fwd.mean())}
 
 
+def _gmm_case(which):
+    """The grouped matmul over a row buffer with uneven groups (one
+    expert without a row, one over two tiles), f32 at precision
+    "highest": each kernel against the lowered ragged dot over the same
+    buffer."""
+    def run():
+        import jax
+        from . import grouped_matmul as gm
+        r = _rng(23)
+        experts, k, n = 4, 128, 256
+        local = np.concatenate([np.full(300, 2), np.full(40, 0),
+                                np.full(90, 3), np.full(50, 7)])
+        plan = gm.plan_rows(jnp.asarray(r.permutation(local), jnp.int32),
+                            experts)
+        rows = plan["valid"].shape[0]
+        valid = np.asarray(plan["valid"])[:, None]
+        lhs = jnp.asarray(r.standard_normal((rows, k), dtype=np.float32)
+                          * valid)
+        dout = jnp.asarray(r.standard_normal((rows, n), dtype=np.float32)
+                           * valid)
+        rhs = jnp.asarray(r.standard_normal((experts, k, n),
+                                            dtype=np.float32))
+        used = int(plan["n_active"][0]) * gm.TILE_ROWS
+        with jax.default_matmul_precision("highest"):
+            if which == "fwd":
+                got, ref = (gm.gmm(lhs, rhs, plan, kern)[:used]
+                            for kern in (True, False))
+            elif which == "dx":
+                got, ref = (gm.gmm_dx(dout, rhs, plan, kern)[:used]
+                            for kern in (True, False))
+            else:
+                got, ref = (gm.gmm_dw(lhs, dout, plan, experts, kern)
+                            for kern in (True, False))
+        return {"metric": "rel_vs_lowered", "tol": 1e-5,
+                "value": rel_err(ref, got)}
+    return Case("moe_grouped_matmul",
+                "moe_grouped_matmul/%s/f32/4x128x256" % which, run)
+
+
 def cases() -> List[Case]:
     """Every parity case; keyed to registered kernel names."""
     # import for side effect: ensure all kernels are registered before
     # completeness is judged
     import importlib
-    from . import fused_optimizer, quantized_matmul  # noqa: F401
+    from . import fused_optimizer, grouped_matmul  # noqa: F401
+    from . import quantized_matmul  # noqa: F401
     importlib.import_module("paddle_tpu.kernels.flash_attention")
     return [
         _adam_case((4096,)),
@@ -386,6 +426,9 @@ def cases() -> List[Case]:
         _qmm_case("bf16", 1e-2),
         _fa_case("highest"),
         _fa_case("default"),
+        _gmm_case("fwd"),
+        _gmm_case("dx"),
+        _gmm_case("dw"),
     ]
 
 

@@ -11,6 +11,7 @@ from . import learning_rate_scheduler
 from . import loss
 from . import sequence  # noqa: F401
 from . import collective  # noqa: F401
+from . import decoder  # noqa: F401
 
 from .nn import *  # noqa: F401,F403
 from .tensor import *  # noqa: F401,F403
@@ -25,6 +26,7 @@ from .sequence import *  # noqa: F401,F403
 from .ops import *  # noqa: F401,F403
 from .detection import *  # noqa: F401,F403
 from .collective import *  # noqa: F401,F403
+from .decoder import *  # noqa: F401,F403
 from .distributions import (  # noqa: F401
     Normal, Uniform, Categorical, MultivariateNormalDiag)
 

@@ -1,0 +1,114 @@
+"""Layers of a current decoder-only language model block (ops in
+ops/decoder.py): RMS normalisation, rotary positions, the gated
+feed-forward's activation, the sigmoid top-k router, and the expert
+layer that is told which experts this chip holds."""
+from __future__ import annotations
+
+from ..initializer import Constant, Normal
+from ..layer_helper import LayerHelper
+from ..param_attr import ParamAttr
+
+__all__ = ["rms_norm", "rotary_embedding", "swiglu", "moe_router",
+           "moe_experts"]
+
+
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """x / sqrt(mean(x^2, last axis) + epsilon) * w, w [D] from ones."""
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(param_attr, [int(input.shape[-1])],
+                                    input.dtype,
+                                    default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("rms_norm", inputs={"X": input, "Scale": scale},
+                     outputs={"Y": out}, attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def rotary_embedding(input, theta=10000.0, rotary_dim=None,
+                     position_offset=0, name=None):
+    """Rotary positions on input [B, S, H, D]: the trailing `rotary_dim`
+    channels of every head (all of them by default), adjacent pairs
+    rotated by pos * theta^(-2i / rotary_dim)."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("rotary_embedding", inputs={"X": input},
+                     outputs={"Out": out},
+                     attrs={"theta": float(theta),
+                            "rotary_dim": int(rotary_dim or 0),
+                            "position_offset": int(position_offset)})
+    return out
+
+
+def swiglu(gate, up, name=None):
+    """silu(gate) * up."""
+    helper = LayerHelper("swiglu", name=name)
+    out = helper.create_variable_for_type_inference(gate.dtype)
+    helper.append_op("swiglu", inputs={"X": gate, "Y": up},
+                     outputs={"Out": out})
+    return out
+
+
+def moe_router(input, num_experts, top_k, experts_held=None, first_expert=0,
+               scoring_func="sigmoid", norm_topk_prob=True,
+               routed_scaling_factor=1.0, n_group=1, topk_group=1,
+               param_attr=None, bias_attr=None, name=None):
+    """Sigmoid top-k router over `num_experts` (float32 under AMP).
+    Returns (choice int32 [T, top_k], weight float32 [T, top_k], count
+    of tokens for each of the `experts_held` experts from
+    `first_expert`). `bias_attr` names the selection-only score
+    correction, a parameter that takes no gradient (False: none)."""
+    helper = LayerHelper("moe_router", name=name)
+    d = int(input.shape[-1])
+    weight = helper.create_parameter(param_attr, [num_experts, d],
+                                     "float32",
+                                     default_initializer=Normal(0.0, 0.02))
+    inputs = {"X": input, "Weight": weight}
+    if bias_attr is not False:
+        attr = ParamAttr._to_attr(bias_attr)
+        attr.trainable = False
+        inputs["Bias"] = helper.create_parameter(
+            attr, [num_experts], "float32", is_bias=True)
+    choice = helper.create_variable_for_type_inference("int32", True)
+    probs = helper.create_variable_for_type_inference("float32")
+    counts = helper.create_variable_for_type_inference("int32", True)
+    helper.append_op(
+        "moe_router", inputs=inputs,
+        outputs={"TopkIdx": choice, "TopkWeight": probs, "Counts": counts},
+        attrs={"top_k": int(top_k), "scoring_func": scoring_func,
+               "norm_topk_prob": bool(norm_topk_prob),
+               "routed_scaling_factor": float(routed_scaling_factor),
+               "n_group": int(n_group), "topk_group": int(topk_group),
+               "experts_held": int(experts_held or num_experts),
+               "first_expert": int(first_expert)})
+    return choice, probs, counts
+
+
+def moe_experts(input, choice, weight, num_experts, expert_width,
+                experts_held=None, first_expert=0, gate_attr=None,
+                up_attr=None, down_attr=None, name=None):
+    """The routed SwiGLU experts `first_expert .. first_expert +
+    experts_held - 1` of a layer of `num_experts`, dropless: three
+    stacked parameters [experts_held, D, F], [experts_held, D, F],
+    [experts_held, F, D]. A choice of an expert held elsewhere adds
+    nothing here."""
+    helper = LayerHelper("moe_experts", name=name)
+    held = int(experts_held or num_experts)
+    d, f = int(input.shape[-1]), int(expert_width)
+    init = Normal(0.0, 0.02)
+    w_gate = helper.create_parameter(gate_attr, [held, d, f], "float32",
+                                     default_initializer=init)
+    w_up = helper.create_parameter(up_attr, [held, d, f], "float32",
+                                   default_initializer=init)
+    w_down = helper.create_parameter(down_attr, [held, f, d], "float32",
+                                     default_initializer=init)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    gate = helper.create_variable_for_type_inference(input.dtype, True)
+    up = helper.create_variable_for_type_inference(input.dtype, True)
+    helper.append_op(
+        "moe_experts",
+        inputs={"X": input, "TopkIdx": choice, "TopkWeight": weight,
+                "WGate": w_gate, "WUp": w_up, "WDown": w_down},
+        outputs={"Out": out, "GateAct": gate, "UpAct": up},
+        attrs={"num_experts": int(num_experts), "experts_held": held,
+               "first_expert": int(first_expert)})
+    return out

@@ -19,7 +19,9 @@ Three layers (docs/OBSERVABILITY.md):
 * :mod:`.memory` — HBM memory observatory: owner-attributed
   live-buffer census reconciled against ``jax.live_arrays()``,
   OOM/pressure postmortem dumps, and the leak sentinel
-  (docs/MEMORY.md).
+  (docs/MEMORY.md);
+* :mod:`.moe` — reader of the `moe_expert_load` counter a
+  mixture-of-experts step keeps (docs/TRACING.md).
 
 Hot-path contract: one boolean (``metrics._HOT[0]``) gates all
 per-step telemetry work. The step's own profiler spans and clock stamps
@@ -28,7 +30,7 @@ feed a profiler session, the slow-step detector and, while ``_HOT``,
 this layer's record.
 """
 from . import metrics, recorder, export, tracing, attribution, \
-    memory  # noqa: F401
+    memory, moe  # noqa: F401
 from .metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, EngineCounters,
     default_registry, counter, gauge, histogram,

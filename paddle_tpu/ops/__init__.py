@@ -18,6 +18,7 @@ from . import metrics  # noqa: F401
 from . import control_flow  # noqa: F401
 from . import sequence  # noqa: F401
 from . import fused  # noqa: F401
+from . import decoder  # noqa: F401
 from . import collective  # noqa: F401
 from . import distributed_ops  # noqa: F401
 from . import rnn  # noqa: F401

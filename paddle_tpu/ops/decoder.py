@@ -1,0 +1,285 @@
+"""Ops of a current decoder-only language model block: RMS
+normalisation, rotary positions, the gated (SwiGLU) feed-forward's
+activation, the sigmoid top-k router and the expert layer of a mixture
+of experts of which this chip holds a share.
+
+The expert layer is dropless and knows which experts it holds:
+`moe_experts` gathers the rows routed to experts `first_expert ..
+first_expert + experts_held - 1` into a worst-case row buffer and runs
+the three grouped matmuls of kernels/grouped_matmul.py over it; what the
+experts held elsewhere would add is left out (on one chip there is no
+exchange, and nothing stands in for the absent chips).
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ..core.amp import amp_cast
+from ..core.registry import register_op, override_grad_lowering
+
+_F32 = jnp.float32
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@register_op("rms_norm")
+def rms_norm(ctx):
+    """Y = X / sqrt(mean(X^2, last axis) + epsilon) * Scale. Statistics
+    in float32 whatever X's type (a NORM op of core/amp.py: it reads
+    bf16 activations as they are and answers in their type)."""
+    x, scale = ctx.input("X"), ctx.input("Scale")
+    eps = ctx.attr("epsilon", 1e-6)
+    xf = x.astype(_F32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    if scale is not None:
+        y = y * scale.astype(_F32)
+    ctx.set_output("Y", y.astype(x.dtype))
+
+
+def _rotate_pairs(x, theta, offset):
+    """Rotate the adjacent pairs (x[2i], x[2i+1]) of the last axis of
+    x [B, S, H, D] by the angle pos * theta^(-2i/D), pos = offset + s."""
+    d = x.shape[-1]
+    pos = jnp.arange(x.shape[1], dtype=_F32) + offset
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
+    angle = pos[:, None] * freq[None, :]                    # [S, D/2]
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+    pairs = x.astype(_F32).reshape(x.shape[:-1] + (d // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+@register_op("rotary_embedding")
+def rotary_embedding(ctx):
+    """Rotary positions on the trailing `rotary_dim` channels of
+    X [B, S, H, D] (all of D when 0), interleaved: the pairs are adjacent
+    channels. attrs: theta, rotary_dim, position_offset."""
+    x = ctx.input("X")
+    theta = float(ctx.attr("theta", 10000.0))
+    n = int(ctx.attr("rotary_dim", 0) or 0) or x.shape[-1]
+    offset = float(ctx.attr("position_offset", 0))
+    if n % 2 or n > x.shape[-1]:
+        raise ValueError(f"rotary_dim {n} of a head of {x.shape[-1]}")
+    keep = x.shape[-1] - n
+    if keep == 0:
+        ctx.set_output("Out", _rotate_pairs(x, theta, offset))
+    else:
+        ctx.set_output("Out", jnp.concatenate(
+            [x[..., :keep], _rotate_pairs(x[..., keep:], theta, offset)],
+            axis=-1))
+
+
+@register_op("swiglu")
+def swiglu(ctx):
+    """Out = silu(X) * Y, the gated feed-forward's activation (X the
+    gate projection, Y the up projection)."""
+    x, y = ctx.input("X"), ctx.input("Y")
+    xf = x.astype(_F32)
+    ctx.set_output("Out", (xf * jax.nn.sigmoid(xf) * y.astype(_F32)
+                           ).astype(jnp.result_type(x, y)))
+
+
+# ---------------------------------------------------------------- router
+
+def _router_scores(x, w):
+    """sigmoid(float32(x) . w^T), the matrix product in float32 on
+    every backend."""
+    logits = jnp.einsum("td,ed->te", x.astype(_F32), w.astype(_F32),
+                        precision=_HIGHEST)
+    return jax.nn.sigmoid(logits)
+
+
+def _router(ctx, x, w, bias):
+    k = int(ctx.attr("top_k", 1))
+    if ctx.attr("scoring_func", "sigmoid") != "sigmoid":
+        raise NotImplementedError("moe_router scores by sigmoid")
+    if int(ctx.attr("n_group", 1)) != 1 or \
+            int(ctx.attr("topk_group", 1)) != 1:
+        raise NotImplementedError("moe_router selects within one group")
+    s = _router_scores(x, w)
+    pick = s if bias is None else s + bias.astype(_F32)[None, :]
+    _, choice = jax.lax.top_k(jax.lax.stop_gradient(pick), k)
+    weight = jnp.take_along_axis(s, choice, axis=-1)
+    if ctx.attr("norm_topk_prob", True):
+        weight = weight / (jnp.sum(weight, -1, keepdims=True) + 1e-20)
+    return choice.astype(jnp.int32), \
+        weight * float(ctx.attr("routed_scaling_factor", 1.0))
+
+
+@register_op("moe_router", no_grad_slots=("Bias",))
+def moe_router(ctx):
+    """X [T, D], Weight [num_experts, D], Bias [num_experts] (the
+    selection-only score correction; takes no gradient).
+    TopkIdx int32 [T, top_k]: the top_k of sigmoid(x.w^T) + bias over
+    all experts; TopkWeight float32 [T, top_k]: the chosen scores
+    WITHOUT the bias, normalised to sum 1 (norm_topk_prob) and scaled
+    by routed_scaling_factor; Counts int32 [experts_held]: tokens routed
+    to each expert held here (first_expert ..). float32 whatever AMP
+    says (a BLACK op of core/amp.py)."""
+    x = ctx.input("X")
+    choice, weight = _router(ctx, x.reshape(-1, x.shape[-1]),
+                             ctx.input("Weight"), ctx.input("Bias"))
+    held = int(ctx.attr("experts_held", 0)) or ctx.input("Weight").shape[0]
+    local = choice - int(ctx.attr("first_expert", 0))
+    counts = jnp.sum(
+        local.reshape(-1, 1) == jnp.arange(held, dtype=jnp.int32)[None],
+        axis=0, dtype=jnp.int32)
+    ctx.set_output("TopkIdx", choice)
+    ctx.set_output("TopkWeight", weight)
+    ctx.set_output("Counts", counts)
+
+
+@override_grad_lowering("moe_router")
+def moe_router_grad(ctx):
+    """Gradients reach X and Weight through TopkWeight only: the choice
+    is piecewise constant."""
+    op = ctx.op
+    x, w = ctx.input("X"), ctx.input("Weight")
+    bias = ctx.input("Bias")
+    g = ctx.env.get((op.input("TopkWeight@GRAD") or [""])[0])
+    if g is None:
+        return
+    x2 = x.reshape(-1, x.shape[-1])
+    _, vjp = jax.vjp(lambda a, b: _router(ctx, a, b, bias)[1], x2, w)
+    dx, dw = vjp(g.astype(_F32))
+    for slot, grad, like in (("X", dx.reshape(x.shape), x),
+                             ("Weight", dw, w)):
+        names = op.output(slot + "@GRAD")
+        if names and names[0]:
+            ctx.env[names[0]] = grad.astype(like.dtype)
+
+
+# --------------------------------------------------------------- experts
+
+def _silu(x):
+    return x * jax.nn.sigmoid(x)
+
+
+def _gather_rows(table, plan, top_k):
+    """The token each row carries: table [T, D] -> [rows, D], padding
+    rows zero."""
+    rows = table[plan["choice_of_row"] // top_k]
+    return jnp.where(plan["valid"][:, None], rows, 0)
+
+
+def _combine(buf, plan, t, top_k):
+    """Sum each token's held choices out of the buffer: [rows, D] ->
+    [T, D]. Rows the kernels never wrote are masked, not multiplied."""
+    picked = buf[plan["row_of_choice"]]                  # [T*k, D]
+    picked = jnp.where(plan["held"][:, None], picked, 0)
+    return jnp.sum(picked.reshape(t, top_k, -1).astype(_F32), axis=1)
+
+
+class _Experts:
+    """What the forward and the grad op of `moe_experts` share: the
+    operands as the kernels take them, the row buffer's plan, the rows
+    gathered into it and each row's routing weight."""
+
+    def __init__(self, ctx):
+        from ..kernels import grouped_matmul as gm
+        self.gm = gm
+        x, choice = ctx.input("X"), ctx.input("TopkIdx")
+        self.weight = ctx.input("TopkWeight").astype(_F32)
+        self.shape = x.shape
+        x = x.reshape(-1, x.shape[-1])
+        # neither op is on an AMP list: cast here, so that both
+        # differentiate the same function and the weights stay float32
+        x, wg, wu, wd = amp_cast("moe_experts", x, ctx.input("WGate"),
+                                 ctx.input("WUp"), ctx.input("WDown"))
+        self.x = x
+        self.wg, self.wu, self.wd = (w.astype(x.dtype) for w in (wg, wu, wd))
+        self.held = int(ctx.attr("experts_held"))
+        first = int(ctx.attr("first_expert", 0))
+        if first < 0 or first + self.held > int(ctx.attr("num_experts")):
+            raise ValueError("experts held lie outside the layer's experts")
+        self.top_k = choice.shape[-1]
+        self.plan = gm.plan_rows(choice.reshape(-1) - first, self.held)
+        self.kernels = gm.use_kernels(x, self.wg)
+        self.valid = self.plan["valid"][:, None]
+        self.xs = _gather_rows(x, self.plan, self.top_k)
+        self.w_row = jnp.where(
+            self.plan["valid"],
+            self.weight.reshape(-1)[self.plan["choice_of_row"]], 0.0)
+
+    def gmm(self, lhs, rhs):
+        return self.gm.gmm(lhs, rhs, self.plan, self.kernels)
+
+    def gmm_dx(self, dout, rhs):
+        return self.gm.gmm_dx(dout, rhs, self.plan, self.kernels)
+
+    def gmm_dw(self, lhs, dout):
+        return self.gm.gmm_dw(lhs, dout, self.plan, self.held, self.kernels)
+
+
+@register_op("moe_experts", no_grad_slots=("TopkIdx",),
+             intermediate_outputs=("GateAct", "UpAct"))
+def moe_experts(ctx):
+    """The routed experts held here, dropless. X [.., D]; TopkIdx int32
+    [T, k] over all `num_experts`; TopkWeight [T, k]; WGate, WUp
+    [experts_held, D, F]; WDown [experts_held, F, D].
+    Out[t] = sum over k with TopkIdx[t, k] held here of TopkWeight[t, k]
+    * WDown_e(silu(x_t WGate_e) * (x_t WUp_e)). GateAct and UpAct
+    (intermediate, [buffer rows, F]) carry the two projections to the
+    grad op, so the backward runs no forward kernel again."""
+    res_t = jnp.result_type(ctx.input("X"))
+    e = _Experts(ctx)
+    gate, up = e.gmm(e.xs, e.wg), e.gmm(e.xs, e.wu)
+    hidden = _silu(gate.astype(_F32)) * up.astype(_F32)
+    # the routing weight goes in before the down projection (it is
+    # linear), so the backward needs no expert output kept or recomputed
+    hw = jnp.where(e.valid, hidden * e.w_row[:, None], 0)
+    out = _combine(e.gmm(hw.astype(e.x.dtype), e.wd), e.plan,
+                   e.x.shape[0], e.top_k)
+    ctx.set_output("Out", out.astype(res_t).reshape(e.shape))
+    ctx.set_output("GateAct", gate)
+    ctx.set_output("UpAct", up)
+
+
+@override_grad_lowering("moe_experts")
+def moe_experts_grad(ctx):
+    """Hand-written: three dx and three dw grouped matmuls over the
+    forward's row buffer, reading the forward's GateAct and UpAct. With
+    hw = silu(gate) * up * w_row and y = hw WDown:
+      d hw = dy WDown^T;  dWDown = hw^T dy;  dw_row = sum(d hw * hidden)
+      d gate = d hw * w_row * up * silu'(gate);  d up = d hw * w_row *
+      silu(gate);  dx = d gate WGate^T + d up WUp^T;  dWGate = x^T
+      d gate;  dWUp = x^T d up."""
+    op = ctx.op
+    e = _Experts(ctx)
+    dtype, valid = e.x.dtype, e.valid
+    if ctx.has_input("GateAct") and ctx.has_input("UpAct"):
+        gate = ctx.env[op.input("GateAct")[0]]
+        up = ctx.env[op.input("UpAct")[0]]
+    else:       # a hand-built op desc without the two slots
+        gate, up = e.gmm(e.xs, e.wg), e.gmm(e.xs, e.wu)
+    dout = ctx.env[op.input("Out@GRAD")[0]]
+    dy = _gather_rows(dout.reshape(e.x.shape[0], -1).astype(dtype), e.plan,
+                      e.top_k)
+
+    gate32 = jnp.where(valid, gate.astype(_F32), 0)
+    up32 = jnp.where(valid, up.astype(_F32), 0)
+    sig = jax.nn.sigmoid(gate32)
+    act = gate32 * sig
+    hidden = act * up32
+    hw = (hidden * e.w_row[:, None]).astype(dtype)
+    dhw = jnp.where(valid, e.gmm_dx(dy, e.wd).astype(_F32), 0)
+    d_wd = e.gmm_dw(hw, dy)
+    dw_row = jnp.sum(dhw * hidden, axis=-1)
+    dh = dhw * e.w_row[:, None]
+    dgate = (dh * up32 * (sig + act * (1.0 - sig))).astype(dtype)
+    dup = (dh * act).astype(dtype)
+    dxs = e.gmm_dx(dgate, e.wg).astype(_F32) \
+        + e.gmm_dx(dup, e.wu).astype(_F32)
+    d_wg, d_wu = e.gmm_dw(e.xs, dgate), e.gmm_dw(e.xs, dup)
+    dx = _combine(dxs, e.plan, e.x.shape[0], e.top_k).reshape(e.shape)
+    dweight = jnp.where(e.plan["held"], dw_row[e.plan["row_of_choice"]],
+                        0.0).reshape(e.weight.shape)
+
+    for slot, grad in (("X", dx), ("TopkWeight", dweight),
+                       ("WGate", d_wg), ("WUp", d_wu), ("WDown", d_wd)):
+        names = op.output(slot + "@GRAD")
+        if names and names[0]:
+            primal = ctx.env[op.input(slot)[0]]
+            ctx.env[names[0]] = grad.astype(primal.dtype)

@@ -99,8 +99,8 @@ def fused_attention(ctx):
     lse = None
     if drop is not None and drop[1] == 0:
         # dropout_prob ~ 1.0: everything dropped
-        out = jnp.zeros(q.shape, res_t)
-    elif use_kernel_path(q, k, bq, bk, layout):
+        out = jnp.zeros(q.shape[:-1] + v.shape[-1:], res_t)
+    elif use_kernel_path(q, k, bq, bk, layout, v):
         # long-context regime: Pallas flash kernels, O(S) HBM
         if ctx.attr("is_test", False):
             # inference: no grad op will consume lse — skip the
@@ -168,7 +168,7 @@ def fused_attention_grad(ctx):
         # forward emitted constant zeros: nothing flows back
         dq, dk, dv = (jnp.zeros_like(x) for x in (q, k, v))
         dbias = None if bias is None else jnp.zeros_like(bias)
-    elif use_kernel_path(q, k, bq, bk, layout):
+    elif use_kernel_path(q, k, bq, bk, layout, v):
         if ctx.has_input("SoftmaxLse") and \
                 not ctx.attr("is_test", False):
             # read past ctx.input(): lse stays float32 under any amp

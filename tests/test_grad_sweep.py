@@ -336,6 +336,15 @@ RECIPES = {
                    "Bias": _f((6,), seed=2)},
         "attrs": {"begin_norm_axis": 1, "epsilon": 1e-5}, "out": "Y",
         "check": ["x", "scale", "bias"], "tol": 0.02},
+    "rms_norm": {
+        "inputs": {"X": _f((3, 6)), "Scale": _pos((6,), seed=1)},
+        "attrs": {"epsilon": 1e-6}, "out": "Y",
+        "check": ["x", "scale"], "tol": 0.02},
+    "rotary_embedding": {
+        "inputs": {"X": _f((2, 3, 2, 8))},
+        "attrs": {"theta": 100.0, "rotary_dim": 4, "position_offset": 0},
+        "out": "Out", "check": ["x"], "tol": 0.01},
+    "swiglu": _binary(),
     "batch_norm": {
         "inputs": {"X": _f((3, 4, 2, 2)), "Scale": _pos((4,), seed=1),
                    "Bias": _f((4,), seed=2),
@@ -512,6 +521,8 @@ RECIPES = {
 # the structured inputs (LoD offsets, RNN state, anchors, ...) the
 # generic one-op builder here cannot: entry -> where the coverage lives.
 COVERED = {
+    "moe_experts": "tests/test_decoder_lm.py (forward and every gradient against the plain reference, lowered and through the grouped-matmul kernels; the share test; dropless under imbalance)",
+    "moe_router": "tests/test_decoder_lm.py (choices, weights, counts and gradients against the plain reference; the top-k choice is piecewise constant, so central differences straddle its jumps)",
     "add_position_encoding": "tests/test_nlp_ops.py (position encoding parity incl. grad via transformer training)",
     "array_to_lod_tensor": "tests/test_rnn_control_flow.py (dynamic RNN beam pipeline differentiates through the array ops)",
     "attention_lstm": "tests/test_rnn_control_flow.py TestAttentionLSTM",

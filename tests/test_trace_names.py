@@ -130,12 +130,73 @@ def test_fused_optimizer_kernels_are_named(one_chip, no_compile_cache,
     assert heads and all(h.startswith(kernel) for h in heads), heads
 
 
+def test_flash_kernels_compile_at_latent_attention_widths(one_chip,
+                                                         no_compile_cache):
+    """q/k 192 wide, v 128 (32 heads, B=1 S=4096, the kanana2_s4096
+    cell's attention): two heads share a 384- and a 256-lane block, and
+    Mosaic takes the three kernels under their names."""
+    fa = _flash_module()
+    qk = ((1, S, 32, 192), jnp.bfloat16)
+    v = ((1, S, 32, 128), jnp.bfloat16)
+
+    def fwd_bwd(q, k, v, g):
+        out, lse = fa._fa_forward(q, k, v, None, 192 ** -0.5, BLOCK_Q,
+                                  BLOCK_K, return_lse=True,
+                                  layout="bshd", causal=True)
+        return fa._fa_backward(q, k, v, None, out, lse, g, 192 ** -0.5,
+                               BLOCK_Q, BLOCK_K, layout="bshd",
+                               causal=True)[:3]
+
+    text = _compiled_text(fwd_bwd, one_chip, qk, qk, v, v)
+    stems = {h.rsplit(".", 1)[0] if h.rsplit(".", 1)[-1].isdigit() else h
+             for h in _custom_call_heads(text)}
+    assert stems == {"flash_attention_fwd", "flash_attention_dq",
+                     "flash_attention_dkv"}, stems
+    # v is not padded to the q/k width: no 32 x 192 = 6144-wide v, out
+    # or dv anywhere
+    assert "bf16[1,4096,4096]" in text
+
+
+@pytest.mark.parametrize("which", ["fwd", "dx", "dw"])
+def test_grouped_matmul_kernels_are_named(one_chip, no_compile_cache,
+                                          which, monkeypatch):
+    """The expert layer's three kernels at the kanana2_s4096 cell's
+    widths: 4,096 tokens, top-6, 16 experts held of 2048 x 768."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+    from paddle_tpu.kernels import registry
+    monkeypatch.setattr(registry, "interpret", lambda: False)
+    held, d, f = 16, 2048, 768
+    rows = gm.buffer_rows(4096 * 6, held)
+    assert rows == 26624
+    choice = ((4096 * 6,), jnp.int32)
+    wide, narrow = ((rows, d), jnp.bfloat16), ((rows, f), jnp.bfloat16)
+    up, down = ((held, d, f), jnp.bfloat16), ((held, f, d), jnp.bfloat16)
+
+    def both(fn):
+        return lambda c, a, b, a2, b2: (
+            fn(a, b, gm.plan_rows(c, held)), fn(a2, b2,
+                                                gm.plan_rows(c, held)))
+    if which == "fwd":
+        fn = both(lambda x, w, plan: gm.gmm(x, w, plan, True))
+        shapes = (choice, wide, up, narrow, down)
+    elif which == "dx":
+        fn = both(lambda dy, w, plan: gm.gmm_dx(dy, w, plan, True))
+        shapes = (choice, narrow, up, wide, down)
+    else:
+        fn = both(lambda x, dy, plan: gm.gmm_dw(x, dy, plan, held, True))
+        shapes = (choice, wide, narrow, narrow, wide)
+    heads = _custom_call_heads(_compiled_text(fn, one_chip, *shapes))
+    assert len(heads) == 2 and all(
+        h.startswith("moe_grouped_matmul_" + which) for h in heads), heads
+
+
 def test_no_other_kernel_reads_as_flash_or_adam():
     """The accepted classifier maps a head holding `adam` to fused_adam
     and one holding `flash` or `kern` to flash attention: no other
     kernel's name may hold any of them."""
     from paddle_tpu.tuning import variants
-    names = ["fused_sgd", "quantized_matmul"] + [
+    names = ["fused_sgd", "quantized_matmul", "moe_grouped_matmul_fwd",
+             "moe_grouped_matmul_dx", "moe_grouped_matmul_dw"] + [
         f"tuned_matmul_{v.epilogue}_{v.bm}x{v.bn}x{v.bk}"
         for v in variants.enumerate_variants()]
     for n in names:
