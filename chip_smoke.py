@@ -215,7 +215,10 @@ def leg_a(sm):
              and adam["custom"] % n_big == 0
              and adam.get("lowered", 0)
              == adam["custom"] // n_big * (len(sizes) - n_big)
-             and set(adam) <= {"custom", "lowered"},
+             and set(adam) <= {"custom", "lowered", "native_view",
+                               "flat_view"}
+             and adam.get("native_view", 0) + adam.get("flat_view", 0)
+             == adam["custom"],
              f"leg A: fused_adam dispatch {adam} does not match "
              f"{n_big} params over / {len(sizes) - n_big} under the "
              f"{registry.min_numel()}-element floor")

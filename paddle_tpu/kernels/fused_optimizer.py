@@ -6,7 +6,21 @@ most of it, but each parameter still costs one loop over HBM per fusion
 root and the moments round-trip at f32.  This kernel does the whole
 update — moment EMAs, bias-corrected step, decoupled weight decay, the
 stability-guard gate, and the ZeRO-1 shard mask — in a single VMEM pass
-per (block_rows, 128) tile: read p/g/m/v once, write p'/m'/v' once.
+per block: read p/g/m/v once, write p'/m'/v' once, the outputs aliased
+onto p/m/v so a donated parameter is updated in place.
+
+Two views of an operand, chosen from its shape (docs/KERNELS.md):
+
+* native — a ``[.., K, N]`` operand whose two minor dimensions fill an
+  (8, 128) tile is blocked as it lies: viewed ``[L, K, N]`` (collapsing
+  leading dimensions leaves the tiled layout alone, so the view is a
+  bitcast) under a grid ``(L, K blocks, N blocks)``; Pallas masks the
+  partial edge blocks. Nothing is padded, sliced or relaid out.
+* flat — ``[rows, 128]``, padded to whole blocks. Free for rank 1 and
+  for the bucket surface, which are flat already; for a tiled
+  ``[K, N]`` it is a physical permutation that XLA runs as a standalone
+  ``reshape`` of every operand and result (seven a parameter), so only
+  shapes too narrow to block natively still take it.
 
 Two entry surfaces:
 
@@ -40,14 +54,16 @@ surface.)  Guard gate (must match stability/guard.py:_gate_value):
   gated = where(nonfinite, old,
           where(spike, old + (new - old)*damp, new))
 
-Padding tail (flat size -> rows of 128 lanes) runs the same math on
-zeros — finite, and masked rows always rewrite old values — so no
-NaN/garbage ever lands in the output.
+The flat view's padding tail runs the same math on zeros — finite, and
+masked rows always rewrite old values; what a native edge block reads
+past the array is never written back (the update is elementwise) — so
+no NaN/garbage ever lands in the output.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -57,7 +73,12 @@ from jax.experimental.pallas import tpu as pltpu
 from . import registry
 
 _LANES = 128
-_BLOCK_ROWS = 256  # 256x128 f32 = 128 KiB per operand block in VMEM
+_SUBLANES = 8
+_BLOCK_ROWS = 256  # flat view: 256x128 f32 = 128 KiB per operand block
+# native view: 512 KiB per operand block; Adam's 7 blocks x 2 buffers
+# are 7 MiB, under the default scoped VMEM limit
+_BLOCK_ELEMS = 256 * 512
+_WHOLE_ROW_COLS = 1024
 
 __all__ = ["fused_adam", "fused_sgd", "bucket_sweep"]
 
@@ -85,6 +106,40 @@ def _from2d(x2d, n: int):
 
 
 # ---------------------------------------------------------------------------
+# native view: blocks over the operand's own [.., K, N] layout
+# ---------------------------------------------------------------------------
+
+def _fills_a_tile(shape) -> bool:
+    """Whether the two minor dimensions hold at least one (8, 128)
+    tile. Under that (a conv filter ``[O, I, 3, 3]``, a ``[K, 7]``) a
+    native block would be a few elements and the grid one step each."""
+    return (len(shape) >= 2 and shape[-2] >= _SUBLANES
+            and shape[-1] >= _LANES)
+
+
+def _lies_k_minor(k: int, n: int) -> bool:
+    """Whether XLA keeps a ``[.., K, N]`` f32 array K-minor on the TPU:
+    it does where N is off the 128 lanes and K is on them (no lane is
+    padded that way; read off the compiled steps: ``[2048, 576]`` and
+    ``[2048, 16032]`` lie ``{0,1}``, ``[16032, 2048]`` ``{1,0}``). The
+    kernel then blocks the transpose, which is a bitcast of such an
+    array; where the guess is wrong XLA transposes, what the flat view
+    cost every operand."""
+    return n % _LANES != 0 and k % _LANES == 0
+
+
+def _native_block(k: int, n: int):
+    """(block_rows, block_cols) for a ``[.., K, N]`` operand: columns
+    the whole of N while a block of whole rows stays contiguous and
+    small, else 512; rows the power of two that keeps the block within
+    ``_BLOCK_ELEMS`` (most K divide by it), or the whole of K."""
+    cols = n if n <= _WHOLE_ROW_COLS else 512
+    lanes = -(-cols // _LANES) * _LANES
+    rows = 1 << ((_BLOCK_ELEMS // lanes).bit_length() - 1)
+    return (k if k <= rows else rows), cols
+
+
+# ---------------------------------------------------------------------------
 # kernel bodies
 # ---------------------------------------------------------------------------
 
@@ -94,16 +149,18 @@ def _gate(new, old, nf, sp, damp):
     return jnp.where(nf, old, jnp.where(sp, damped, new))
 
 
-def _row_mask(bounds_ref, block_rows):
+def _row_mask(bounds_ref):
     i = pl.program_id(0)
-    rows = i * block_rows + jax.lax.broadcasted_iota(
-        jnp.int32, (block_rows, _LANES), 0)
+    rows = i * _BLOCK_ROWS + jax.lax.broadcasted_iota(
+        jnp.int32, (_BLOCK_ROWS, _LANES), 0)
     return (rows >= bounds_ref[0, 0]) & (rows < bounds_ref[0, 1])
 
 
-def _adam_block(hyper_ref, bounds_ref, p_ref, g_ref, m_ref, v_ref,
-                po_ref, mo_ref, vo_ref, *, b1, b2, eps, wd,
-                block_rows, gated):
+def _adam_block(hyper_ref, *refs, b1, b2, eps, wd, gated, sharded):
+    if sharded:
+        inside = _row_mask(refs[0])
+        refs = refs[1:]
+    p_ref, g_ref, m_ref, v_ref, po_ref, mo_ref, vo_ref = refs
     p, g, m, v = p_ref[:], g_ref[:], m_ref[:], v_ref[:]
     lr_t = hyper_ref[0, 0]
     m_new = b1 * m + (1.0 - b1) * g
@@ -120,14 +177,20 @@ def _adam_block(hyper_ref, bounds_ref, p_ref, g_ref, m_ref, v_ref,
         p_new = _gate(p_new, p, nf, sp, damp)
         m_new = _gate(m_new, m, nf, sp, damp)
         v_new = _gate(v_new, v, nf, sp, damp)
-    inside = _row_mask(bounds_ref, block_rows)
-    po_ref[:] = jnp.where(inside, p_new, p)
-    mo_ref[:] = jnp.where(inside, m_new, m)
-    vo_ref[:] = jnp.where(inside, v_new, v)
+    if sharded:
+        p_new = jnp.where(inside, p_new, p)
+        m_new = jnp.where(inside, m_new, m)
+        v_new = jnp.where(inside, v_new, v)
+    po_ref[:] = p_new
+    mo_ref[:] = m_new
+    vo_ref[:] = v_new
 
 
-def _sgd_block(hyper_ref, bounds_ref, p_ref, g_ref, po_ref, *, wd,
-               block_rows, gated):
+def _sgd_block(hyper_ref, *refs, wd, gated, sharded):
+    if sharded:
+        inside = _row_mask(refs[0])
+        refs = refs[1:]
+    p_ref, g_ref, po_ref = refs
     p, g = p_ref[:], g_ref[:]
     lr = hyper_ref[0, 0]
     if wd:
@@ -136,31 +199,69 @@ def _sgd_block(hyper_ref, bounds_ref, p_ref, g_ref, po_ref, *, wd,
     if gated:
         p_new = _gate(p_new, p, hyper_ref[0, 1] > 0.0,
                       hyper_ref[0, 2] > 0.0, hyper_ref[0, 3])
-    inside = _row_mask(bounds_ref, block_rows)
-    po_ref[:] = jnp.where(inside, p_new, p)
+    if sharded:
+        p_new = jnp.where(inside, p_new, p)
+    po_ref[:] = p_new
 
 
-def _call(name, body, hyper, bounds, bufs, n_out, block_rows):
-    rows = bufs[0].shape[0]
-    grid = (rows // block_rows,)
+def _call(name, body, scalars, bufs, updated, grid, block, index_map):
+    """One pallas_call over *bufs* (all one shape), blocked as *block*.
+    ``updated`` lists the bufs the kernel rewrites, in output order;
+    each output aliases its input, so XLA hands a donated buffer to the
+    kernel to update in place and copies only an input that stays live
+    (eager callers, tests)."""
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    tile = pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0),
-                        memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
+    tile = pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
+    out = jax.ShapeDtypeStruct(bufs[0].shape, bufs[0].dtype)
+    return pl.pallas_call(
         body,
         name=name,
         grid=grid,
-        in_specs=[smem, smem] + [tile] * len(bufs),
-        out_specs=[tile] * n_out if n_out > 1 else tile,
-        out_shape=([jax.ShapeDtypeStruct(bufs[0].shape, bufs[0].dtype)]
-                   * n_out if n_out > 1
-                   else jax.ShapeDtypeStruct(bufs[0].shape,
-                                             bufs[0].dtype)),
+        in_specs=[smem] * len(scalars) + [tile] * len(bufs),
+        out_specs=[tile] * len(updated),
+        out_shape=[out] * len(updated),
+        input_output_aliases={len(scalars) + i: o
+                              for o, i in enumerate(updated)},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",) * len(grid)),
         interpret=registry.interpret(),
-    )(hyper, bounds, *bufs)
-    return out if n_out > 1 else (out,)
+    )(*scalars, *bufs)
+
+
+def _flat_call(name, body, scalars, flats, updated):
+    """The kernel over 1-D *flats* through the ``[rows, 128]`` view."""
+    n = flats[0].shape[0]
+    bufs = [_to2d(x) for x in flats]
+    outs = _call(name, body, scalars, bufs, updated,
+                 (bufs[0].shape[0] // _BLOCK_ROWS,),
+                 (_BLOCK_ROWS, _LANES), lambda i: (i, 0))
+    return [_from2d(o, n) for o in outs]
+
+
+def _per_op(name, body, hyper, operands, updated):
+    """One parameter's update in the view its shape allows; which one
+    each call site took is counted beside the registry's decision."""
+    shape = operands[0].shape
+    if not _fills_a_tile(shape):
+        registry.count(name, "flat_view")
+        outs = _flat_call(name, body, [hyper],
+                          [x.reshape(-1) for x in operands], updated)
+        return [o.reshape(shape) for o in outs]
+    registry.count(name, "native_view")
+    k, n = shape[-2:]
+    swapped = _lies_k_minor(k, n)
+    if swapped:
+        operands = [jnp.swapaxes(x, -1, -2) for x in operands]
+        k, n = n, k
+    lead = math.prod(shape[:-2])
+    rows, cols = _native_block(k, n)
+    outs = _call(name, body, [hyper],
+                 [x.reshape(lead, k, n) for x in operands], updated,
+                 (lead, pl.cdiv(k, rows), pl.cdiv(n, cols)),
+                 (None, rows, cols), lambda l, i, j: (l, i, j))
+    if swapped:
+        outs = [jnp.swapaxes(o, -1, -2) for o in outs]
+    return [o.reshape(shape) for o in outs]
 
 
 def _hyper(lr_t, guard):
@@ -177,19 +278,14 @@ def _hyper(lr_t, guard):
 
 
 def _bounds(rows: int, shard):
-    if shard is None:
-        lo = jnp.int32(0)
-        hi = jnp.int32(rows)
-    else:
-        idx, num = shard
-        if rows % num:
-            raise ValueError(
-                "bucket rows (%d) not divisible by num_shards (%d); pad "
-                "the bucket to num_shards*128 elements" % (rows, num))
-        per = rows // num
-        lo = (jnp.asarray(idx, jnp.int32) * per).reshape(())
-        hi = lo + per
-    return jnp.stack([lo, hi]).reshape(1, 2)
+    idx, num = shard
+    if rows % num:
+        raise ValueError(
+            "bucket rows (%d) not divisible by num_shards (%d); pad "
+            "the bucket to num_shards*128 elements" % (rows, num))
+    per = rows // num
+    lo = (jnp.asarray(idx, jnp.int32) * per).reshape(())
+    return jnp.stack([lo, lo + per]).reshape(1, 2)
 
 
 # ---------------------------------------------------------------------------
@@ -204,32 +300,19 @@ def fused_adam(p, g, m, v, lr_t, *, beta1=0.9, beta2=0.999,
     lr*sqrt(1-b2^t)/(1-b1^t) fold so the beta-pow recurrence stays in
     the lowering).  Returns (p', m', v').
     """
-    shape = p.shape
-    n = p.size
-    bufs = [_to2d(x.reshape(-1)) for x in (p, g, m, v)]
     body = functools.partial(_adam_block, b1=float(beta1),
                              b2=float(beta2), eps=float(epsilon),
-                             wd=float(weight_decay),
-                             block_rows=_BLOCK_ROWS, gated=False)
-    po, mo, vo = _call("fused_adam", body, _hyper(lr_t, None),
-                       _bounds(bufs[0].shape[0], None), bufs, 3,
-                       _BLOCK_ROWS)
-    return (_from2d(po, n).reshape(shape),
-            _from2d(mo, n).reshape(shape),
-            _from2d(vo, n).reshape(shape))
+                             wd=float(weight_decay), gated=False,
+                             sharded=False)
+    return tuple(_per_op("fused_adam", body, _hyper(lr_t, None),
+                         (p, g, m, v), (0, 2, 3)))
 
 
 def fused_sgd(p, g, lr, *, weight_decay=0.0):
     """One-shot SGD update on one parameter; shape/dtype preserved."""
-    shape = p.shape
-    n = p.size
-    bufs = [_to2d(x.reshape(-1)) for x in (p, g)]
     body = functools.partial(_sgd_block, wd=float(weight_decay),
-                             block_rows=_BLOCK_ROWS, gated=False)
-    (po,) = _call("fused_sgd", body, _hyper(lr, None),
-                  _bounds(bufs[0].shape[0], None), bufs, 1,
-                  _BLOCK_ROWS)
-    return _from2d(po, n).reshape(shape)
+                             gated=False, sharded=False)
+    return _per_op("fused_sgd", body, _hyper(lr, None), (p, g), (0,))[0]
 
 
 def bucket_sweep(kind, flat_param, flat_grad, flat_m=None, flat_v=None,
@@ -254,34 +337,28 @@ def bucket_sweep(kind, flat_param, flat_grad, flat_m=None, flat_v=None,
 
     Returns p' for sgd, (p', m', v') for adam.
     """
-    gated = guard is not None
-    n = flat_param.shape[0]
+    if kind not in ("adam", "sgd"):
+        raise ValueError("bucket_sweep kind must be adam|sgd, got %r"
+                         % (kind,))
+    opts = dict(gated=guard is not None, sharded=shard is not None)
+    if kind == "adam" and beta1_pow is not None and beta2_pow is not None:
+        b1p = jnp.asarray(beta1_pow, jnp.float32).reshape(())
+        b2p = jnp.asarray(beta2_pow, jnp.float32).reshape(())
+        lr = lr * jnp.sqrt(1.0 - b2p) / (1.0 - b1p)
+    scalars = [_hyper(lr, guard)]
+    if shard is not None:
+        scalars.append(
+            _bounds(_rows_padded(flat_param.shape[0]), shard))
     if kind == "adam":
-        lr_t = lr
-        if beta1_pow is not None and beta2_pow is not None:
-            b1p = jnp.asarray(beta1_pow, jnp.float32).reshape(())
-            b2p = jnp.asarray(beta2_pow, jnp.float32).reshape(())
-            lr_t = lr * jnp.sqrt(1.0 - b2p) / (1.0 - b1p)
-        bufs = [_to2d(x) for x in (flat_param, flat_grad, flat_m,
-                                   flat_v)]
         body = functools.partial(_adam_block, b1=float(beta1),
                                  b2=float(beta2), eps=float(epsilon),
-                                 wd=float(weight_decay),
-                                 block_rows=_BLOCK_ROWS, gated=gated)
-        po, mo, vo = _call("fused_adam", body, _hyper(lr_t, guard),
-                           _bounds(bufs[0].shape[0], shard), bufs, 3,
-                           _BLOCK_ROWS)
-        return _from2d(po, n), _from2d(mo, n), _from2d(vo, n)
-    if kind == "sgd":
-        bufs = [_to2d(x) for x in (flat_param, flat_grad)]
-        body = functools.partial(_sgd_block, wd=float(weight_decay),
-                                 block_rows=_BLOCK_ROWS, gated=gated)
-        (po,) = _call("fused_sgd", body, _hyper(lr, guard),
-                      _bounds(bufs[0].shape[0], shard), bufs, 1,
-                      _BLOCK_ROWS)
-        return _from2d(po, n)
-    raise ValueError("bucket_sweep kind must be adam|sgd, got %r"
-                     % (kind,))
+                                 wd=float(weight_decay), **opts)
+        return tuple(_flat_call(
+            "fused_adam", body, scalars,
+            [flat_param, flat_grad, flat_m, flat_v], (0, 2, 3)))
+    body = functools.partial(_sgd_block, wd=float(weight_decay), **opts)
+    return _flat_call("fused_sgd", body, scalars,
+                      [flat_param, flat_grad], (0,))[0]
 
 
 # ---------------------------------------------------------------------------

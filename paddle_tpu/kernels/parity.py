@@ -27,9 +27,9 @@ registered kernel has no parity case (:func:`missing_parity`);
 ``tests/test_kernels.py`` runs :func:`run_all` case by case.
 
 Tolerance policy (docs/KERNELS.md): f32 value-preserving <= 4 ulp
-(fused_sgd), or at most 1 ulp further from float64 than the lowering is
-(fused_adam); rel-error kernels get per-mode bounds (int8 5e-2, bf16
-1e-2; flash attention 1e-5 from float64 at precision "highest", and at
+(fused_sgd), or <= 8 ulp of each sum's largest addend from a float64
+evaluation (fused_adam, whose sums cancel); rel-error kernels get
+per-mode bounds (int8 5e-2, bf16 1e-2; flash attention 1e-5 from float64 at precision "highest", and at
 the backend's default precision no further from float64 than the
 composed path) measured on unit-scale random data with a fixed seed —
 loosening a bound is a reviewed change, not a test edit.
@@ -47,25 +47,51 @@ import jax.numpy as jnp
 from . import registry
 
 __all__ = ["cases", "run_case", "run_all", "missing_parity",
-           "max_ulp", "rel_err", "dropout_mask_identity"]
+           "max_ulp", "adam_f64", "ADAM_TOL", "rel_err",
+           "dropout_mask_identity"]
 
 
 # ---------------------------------------------------------------------------
 # metrics
 # ---------------------------------------------------------------------------
 
-def max_ulp(ref, got) -> float:
-    """Largest elementwise |got - ref| in units of ref's last place."""
+def max_ulp(ref, got, scale=None) -> float:
+    """Largest elementwise |got - ref| in units of ref's last place —
+    or of *scale*'s, where the caller knows the largest addend of the
+    sum that made ref: a rounded sum is good to the last place of its
+    largest addend, not of a result that cancelled, so on cancelling
+    elements two correct f32 programs sit hundreds of ulp of the RESULT
+    apart and within one or two of the addend's."""
     ref = np.asarray(ref)
     got = np.asarray(got)
     dt = ref.dtype if ref.dtype.kind == "f" else np.dtype(np.float32)
     if ref.size == 0:
         return 0.0
+    unit = np.abs(ref if scale is None else scale)
     spacing = np.spacing(
-        np.maximum(np.abs(ref), np.finfo(dt).tiny).astype(dt)
+        np.maximum(unit, np.finfo(dt).tiny).astype(dt)
     ).astype(np.float64)
     diff = np.abs(ref.astype(np.float64) - got.astype(np.float64))
     return float(np.max(diff / spacing))
+
+
+def adam_f64(p, g, m, v, lr_t, b1=0.9, b2=0.999, eps=1e-8):
+    """The Adam recurrence in float64 over f32 inputs, with the f32
+    constants the programs use: ``[(ref, scale)]`` for p', m', v', each
+    ref rounded to f32 beside the largest addend of its last sum (for
+    p' the update's own addends carried through the quotient), the
+    unit :func:`max_ulp` measures a correct f32 program by."""
+    f64, f32 = np.float64, np.float32
+    p, g, m, v = (np.asarray(x).astype(f64) for x in (p, g, m, v))
+    m_old, m_grad = f64(f32(b1)) * m, f64(f32(1.0 - b1)) * g
+    v64 = f64(f32(b2)) * v + f64(f32(1.0 - b2)) * g * g
+    step = f64(f32(lr_t)) / (np.sqrt(v64) + f64(f32(eps)))
+    m64 = m_old + m_grad
+    m_scale = np.maximum(np.abs(m_old), np.abs(m_grad))
+    return [((p - step * m64).astype(f32),
+             np.maximum(np.abs(p), step * m_scale).astype(f32)),
+            (m64.astype(f32), m_scale.astype(f32)),
+            (v64.astype(f32), v64.astype(f32))]
 
 
 def rel_err(ref, got) -> float:
@@ -138,6 +164,12 @@ def _rng(seed=0):
     return np.random.default_rng(seed)
 
 
+# a correct f32 Adam reads up to 4.4 addend-ulp from float64 on a million
+# elements (the quotient's roundings ride on p'); a wrong block, operand
+# or constant reads thousands
+ADAM_TOL = 8.0
+
+
 def _adam_case(shape):
     def run():
         r = _rng(7)
@@ -169,30 +201,23 @@ def _adam_case(shape):
                                 beta2=0.999, epsilon=1e-8)
         # m' = b1*m + (1-b1)*g and p' = p - upd cancel on some
         # elements, and there two correct f32 programs that differ in
-        # FMA contraction sit hundreds of ulp apart (XLA:CPU vs the
-        # Pallas interpreter: 204; compiled on the v5e: 0). So both are
-        # measured against the same recurrence in float64 (the f32
-        # constants both use), and the kernel may be at most 1 ulp
-        # further from it than the lowering is.
-        f64, f32 = np.float64, np.float32
-        c = {"b1": f64(f32(0.9)), "1-b1": f64(f32(1.0 - 0.9)),
-             "b2": f64(f32(0.999)), "1-b2": f64(f32(1.0 - 0.999)),
-             "eps": f64(f32(1e-8))}
-        m64 = c["b1"] * m.astype(f64) + c["1-b1"] * g.astype(f64)
-        v64 = (c["b2"] * v.astype(f64)
-               + c["1-b2"] * g.astype(f64) * g.astype(f64))
-        p64 = p.astype(f64) - f64(lr_t) * m64 / (np.sqrt(v64)
-                                                 + c["eps"])
-        trios = [(p64, env["po"], po), (m64, env["mo"], mo),
-                 (v64, env["vo"], vo)]
-        kern = [max_ulp(r.astype(f32), k) for r, _, k in trios]
-        low = [max_ulp(r.astype(f32), lo) for r, lo, _ in trios]
-        direct = max(max_ulp(lo, k) for _, lo, k in trios)
-        return {"metric": "ulp_past_lowering", "tol": 1.0,
-                "value": max(0.0, *(k - lo for k, lo in zip(kern, low))),
-                "note": "kernel vs lowering %g ulp; from float64: "
-                        "kernel %g, lowering %g ulp"
-                        % (direct, max(kern), max(low))}
+        # FMA contraction sit hundreds of ulp of the result apart
+        # (XLA:CPU vs the Pallas interpreter, whose contraction follows
+        # the block's shape: 32,733 at (16, 128); compiled on the v5e:
+        # 0). So both are measured against the same recurrence in
+        # float64, in units of the largest addend's last place, where a
+        # correct program reads 1 to 5 (adam_f64).
+        refs = adam_f64(p, g, m, v, lr_t)
+        outs = (po, mo, vo)
+        lows = (env["po"], env["mo"], env["vo"])
+        return {"metric": "addend_ulp_from_f64", "tol": ADAM_TOL,
+                "value": max(max_ulp(r, k, s)
+                             for (r, s), k in zip(refs, outs)),
+                "note": "lowering %.3g from float64; kernel vs lowering "
+                        "%g ulp of the result"
+                        % (max(max_ulp(r, lo, s)
+                               for (r, s), lo in zip(refs, lows)),
+                           max(map(max_ulp, lows, outs)))}
     return Case("fused_adam", "fused_adam/f32/%s" % (shape,), run)
 
 
@@ -409,6 +434,16 @@ def _gmm_case(which):
                 "moe_grouped_matmul/%s/f32/4x128x256" % which, run)
 
 
+# shapes the fused optimizer blocks over their own layout
+# (fused_optimizer._native_block): one whole block; N off the 128 lanes
+# in a whole-row block; N over whole-row width and off the 512-column
+# block (partial lane edge); K off the row block too (partial edges both
+# ways); N off the lanes and K on them (blocked as its transpose);
+# leading dimensions collapsed into the grid
+_NATIVE_VIEW_SHAPES = ((64, 256), (40, 200), (16, 1100), (300, 1536),
+                       (256, 200), (4, 16, 256))
+
+
 def cases() -> List[Case]:
     """Every parity case; keyed to registered kernel names."""
     # import for side effect: ensure all kernels are registered before
@@ -418,10 +453,12 @@ def cases() -> List[Case]:
     from . import quantized_matmul  # noqa: F401
     importlib.import_module("paddle_tpu.kernels.flash_attention")
     return [
-        _adam_case((4096,)),
-        _adam_case((513, 7)),       # padding tail exercised
+        _adam_case((4096,)),        # rank 1: the flat view
+        _adam_case((513, 7)),       # under a tile: flat, padding tail
         _sgd_case((2048,)),
         _sgd_case((129, 5)),
+        *(case(shape) for case in (_adam_case, _sgd_case)
+          for shape in _NATIVE_VIEW_SHAPES),
         _qmm_case("int8", 5e-2),
         _qmm_case("bf16", 1e-2),
         _fa_case("highest"),

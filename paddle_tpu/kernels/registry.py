@@ -246,11 +246,18 @@ def _metric_inc(name: str, outcome: str) -> None:
         pass
 
 
-def count(name: str, outcome: str) -> None:
-    """Record one dispatch decision for *name*.
+_DECISIONS = ("custom", "lowered", "denied")
 
-    outcome: ``custom`` (kernel chosen), ``lowered`` (eligibility or
-    backend said no), ``denied`` (flag/deny list said no).
+
+def count(name: str, outcome: str) -> None:
+    """Record one dispatch outcome for *name*.
+
+    outcome: a decision — ``custom`` (kernel chosen), ``lowered``
+    (eligibility or backend said no), ``denied`` (flag/deny list said
+    no) — or what a kernel says of the call it then made (the fused
+    optimizer's ``native_view`` / ``flat_view``), which
+    :func:`dispatch_stats` lists per kernel and leaves out of
+    ``decisions`` and ``hit_rate``.
     """
     with _STATS_LOCK:
         d = _STATS.setdefault(name, {})
@@ -351,7 +358,7 @@ def dispatch_stats() -> Dict[str, Any]:
     """Process-local dispatch counters, bench-consumable shape."""
     with _STATS_LOCK:
         per = {k: dict(v) for k, v in _STATS.items()}
-    total = sum(sum(v.values()) for v in per.values())
+    total = sum(v.get(d, 0) for v in per.values() for d in _DECISIONS)
     custom = sum(v.get("custom", 0) for v in per.values())
     return {
         "per_kernel": per,

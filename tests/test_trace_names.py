@@ -12,6 +12,7 @@ the compiled step (`forward/layer_norm`, `optimize/adam`, under the
 model's `fluid.name_scope`s) are HLO metadata and are read off a step
 lowered on the CPU.
 """
+import math
 import os
 import re
 
@@ -128,6 +129,36 @@ def test_fused_optimizer_kernels_are_named(one_chip, no_compile_cache,
         shapes = (table, table, scalar)
     heads = _custom_call_heads(_compiled_text(fn, one_chip, *shapes))
     assert heads and all(h.startswith(kernel) for h in heads), heads
+
+
+@pytest.mark.parametrize("shape", [(2048, 6144), (16, 2048, 768),
+                                   (16032, 2048), (2048, 576)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_fused_adam_updates_a_donated_parameter_in_place(
+        one_chip, no_compile_cache, shape, monkeypatch):
+    """kanana2_s4096's parameters at their published widths, p, m and v
+    donated as the engine donates them: the kernel stands alone in the
+    compiled program. No `reshape`, `copy` or `transpose` of a whole
+    operand beside it and no temporary, where the flat `[rows, 128]`
+    view cost seven relayouts a parameter (PR 30). (16032, 2048) ends
+    in a partial row block; (2048, 576) lies K-minor (`{0,1}`) and is
+    blocked as its transpose."""
+    from paddle_tpu.kernels import fused_optimizer as fo
+    from paddle_tpu.kernels import registry
+    monkeypatch.setattr(registry, "interpret", lambda: False)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda p, g, m, v, lr: fo.fused_adam(p, g, m, v, lr),
+                       donate_argnums=(0, 2, 3)).lower(x, x, x, x,
+                                                      lr).compile()
+    text = compiled.as_text()
+    assert [h for h in _custom_call_heads(text)
+            if h.startswith("fused_adam")]
+    relaid = [(op, dims) for dims, op in re.findall(
+        r" = f32\[([\d,]+)\]\S* (reshape|copy|transpose)\(", text)
+        if math.prod(map(int, dims.split(","))) == math.prod(shape)]
+    assert not relaid, relaid
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 def test_flash_kernels_compile_at_latent_attention_widths(one_chip,
