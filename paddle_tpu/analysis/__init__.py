@@ -27,7 +27,7 @@ from . import conformance, support_matrix
 from .races import verify_partition, donation_plan
 from .memplan import MemoryPlan, plan_memory, reconcile
 from .cost_model import (OpCost, ProgramCost, program_cost,
-                         island_cost_rows, correlation)
+                         island_cost_rows)
 from . import cost_model as cost
 from .placement import (PlacementPlan, plan_for_program,
                         search_placement, strategy_for_plan)
@@ -50,7 +50,7 @@ __all__ = [
     "verify_partition", "donation_plan",
     "MemoryPlan", "plan_memory", "reconcile",
     "OpCost", "ProgramCost", "program_cost", "island_cost_rows",
-    "correlation", "cost",
+    "cost",
     "PlacementPlan", "plan_for_program", "search_placement",
     "strategy_for_plan", "placement",
 ]

@@ -7,10 +7,8 @@ This is the substrate ROADMAP item 1's SPMD placement search consumes
 on Hierarchical Systems" needs a per-op cost to score candidate
 placements without compiling each one), and the per-island aggregation
 lines up index-for-index with the scheduler partition so the model can
-be **calibrated** against measured per-island device time
-(``observability/attribution.island_rows``) and against XLA's own
-analysis (``Engine.compiled_stats``'s flops) — ``bench.py``'s
-``analysis`` tail reports both.
+be **calibrated** against measured per-island device time and against
+XLA's own analysis (``Engine.compiled_stats``'s flops).
 
 Cost formulas are deliberately simple closed forms (dense GEMM/conv
 arithmetic, element-wise/reduction byte counts, ring-allreduce 2N
@@ -26,14 +24,13 @@ the budget — the "accidentally quadratic batch dim" class of defect.
 from __future__ import annotations
 
 import os
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from .diagnostics import Diagnostic, Severity
 
-__all__ = ["OpCost", "ProgramCost", "program_cost", "island_cost_rows",
-           "correlation"]
+__all__ = ["OpCost", "ProgramCost", "program_cost", "island_cost_rows"]
 
 
 def _shape_of(block, name: str, dynamic_dim: int
@@ -247,9 +244,9 @@ def program_cost(program, block_idx: int = 0,
 
 def island_cost_rows(program, cost: ProgramCost,
                      info=None) -> List[Dict[str, Any]]:
-    """Aggregate per-op costs onto the scheduler partition — the same
-    global island indices ``attribution.island_rows`` uses, so a
-    zip-by-index comparison against measured device time is valid."""
+    """Aggregate per-op costs onto the scheduler partition's global
+    island indices, so a zip-by-index comparison against measured
+    per-island rows is valid."""
     from ..core.scheduler import partition_metadata
     if info is None:
         try:
@@ -268,22 +265,6 @@ def island_cost_rows(program, cost: ProgramCost,
                      "ops": len(isl.indices), "flops": flops,
                      "bytes": byt})
     return rows
-
-
-def correlation(xs: Sequence[float], ys: Sequence[float]
-                ) -> Optional[float]:
-    """Pearson correlation; None when undefined (n < 2 or a constant
-    series). The calibration number: static island cost share vs
-    measured island device-time share."""
-    n = min(len(xs), len(ys))
-    if n < 2:
-        return None
-    x = np.asarray(xs[:n], dtype=np.float64)
-    y = np.asarray(ys[:n], dtype=np.float64)
-    sx, sy = x.std(), y.std()
-    if sx == 0 or sy == 0:
-        return None
-    return float(np.corrcoef(x, y)[0, 1])
 
 
 # -- the registered pass ----------------------------------------------------

@@ -23,8 +23,7 @@ reads from each island executable's ``memory_analysis()``.
 
 The plan is **calibrated**, not trusted: ``reconcile`` compares it
 against the measured owner census (``observability/memory.census``)
-and the compiled per-island attribution, and reports the error ratio —
-``bench.py``'s ``analysis`` tail records that ratio per bench model.
+and the compiled per-island attribution, and reports the error ratio.
 A static plan cannot see XLA's fusion/rematerialization choices or
 allocator padding; the reconciliation quantifies exactly how much that
 costs in accuracy instead of letting the estimate drift silently.
@@ -71,7 +70,7 @@ def _var_bytes(var, dynamic_dim: int) -> int:
 
 class MemoryPlan:
     """Static per-step HBM budget for one block. All byte fields are
-    plain ints so ``to_dict`` is JSON-ready for the bench tail."""
+    plain ints so ``to_dict`` is JSON-ready."""
 
     __slots__ = ("resident_bytes", "feed_bytes", "transient_peak_bytes",
                  "overheads", "islands", "top_vars", "assumptions",
@@ -288,9 +287,7 @@ def reconcile(plan: MemoryPlan, census: Optional[Dict] = None,
       (``argument_bytes``/``temp_bytes``): temp is compared against
       the plan's transient peak.
 
-    ``*_error_ratio`` fields are ``|static - measured| / measured`` —
-    the number the acceptance bar (< 0.25 on the bench models) and the
-    bench ``analysis`` tail track.
+    ``*_error_ratio`` fields are ``|static - measured| / measured``.
     """
     out: Dict[str, Any] = {"static": plan.to_dict()}
     if census:

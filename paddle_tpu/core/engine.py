@@ -1568,8 +1568,7 @@ class Engine:
         once and cached on the traced entry). Returns None on the
         eager-interpreter fallback. The single source for everything
         that inspects the compiled artifact — cost analysis
-        (compiled_stats), HLO text (tools/traffic_report.py,
-        tools/time_report.py)."""
+        (compiled_stats), HLO text."""
         compiled, _ = self._compiled_entry(program, scope, feed,
                                            fetch_names, block_idx,
                                            iterations, multi_step)
@@ -1638,8 +1637,8 @@ class Engine:
         """XLA analytical cost of the already-compiled step: flops,
         bytes accessed, and temp (scratch) memory per step. Returns None
         on the eager-interpreter fallback (nothing is compiled there).
-        This powers bench.py's MFU/roofline accounting — the TPU-native
-        analog of the reference's per-op benchmark bookkeeping
+        The TPU-native analog of the reference's per-op benchmark
+        bookkeeping
         (/root/reference/paddle/fluid/operators/benchmark/op_tester.cc).
         """
         compiled, traced = self._compiled_entry(
@@ -1658,8 +1657,8 @@ class Engine:
         # costs even for scanned executables. `trip_count` carries the
         # steps-per-DISPATCH multiplier (num_iteration_per_run x
         # PT_MULTI_STEP): anything dividing by per-dispatch device time
-        # (pt_mfu_estimate, the bench roofline) must multiply body
-        # FLOPs by it or the scanned path reports impossibly low MFU.
+        # must multiply body FLOPs by it or the scanned path reports
+        # impossibly low MFU.
         out = {"flops": float(ca.get("flops", 0.0)),
                "bytes_accessed":
                    float(ca.get("bytes accessed", 0.0)),

@@ -156,9 +156,8 @@ class ServingEngine:
         self._running: List[Request] = []    # decoding
         self._draining = False
         self._decode_dispatches = 0
-        # bounded: the stats RPC reads a short tail and serve_bench a
-        # whole run's worth; unbounded growth would leak on a
-        # long-running server
+        # bounded: the stats RPC reads a short tail; unbounded growth
+        # would leak on a long-running server
         self.occupancy_history: Deque[int] = deque(maxlen=4096)
         self._win_tokens = 0
         self._win_t0 = clock()

@@ -1173,7 +1173,6 @@ def _fa_eligible(sig):
 _kreg.register_kernel(
     "flash_attention", op_types=("fused_attention",),
     eligible=_fa_eligible, run=flash_attention,
-    source_tag="flash_attention.py",
     doc="online-softmax attention fwd + dq/dkv bwd (O(S) memory); "
         "sequence-keyed crossover vs the composed path in "
         "use_kernel_path")

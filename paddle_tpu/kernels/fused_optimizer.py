@@ -372,12 +372,12 @@ def _dense_f32(sig: registry.Signature) -> bool:
 
 registry.register_kernel(
     "fused_adam", op_types=("adam",), eligible=_dense_f32,
-    run=fused_adam, source_tag="fused_optimizer.py",
+    run=fused_adam,
     doc="single-pass Adam update (m/v EMAs + bias-corrected step) per "
         "VMEM tile; dense f32, >= PT_KERNEL_MIN_NUMEL elements")
 
 registry.register_kernel(
     "fused_sgd", op_types=("sgd",), eligible=_dense_f32,
-    run=fused_sgd, source_tag="fused_optimizer.py",
+    run=fused_sgd,
     doc="single-pass SGD update per VMEM tile; dense f32, >= "
         "PT_KERNEL_MIN_NUMEL elements")

@@ -289,6 +289,6 @@ def _eligible(sig: registry.Signature) -> bool:
 
 registry.register_kernel(
     "moe_grouped_matmul", op_types=("moe_experts",), eligible=_eligible,
-    run=gmm, source_tag="grouped_matmul.py",
+    run=gmm,
     doc="dropless grouped matmul over the held experts' row buffer "
         "(fwd, dx, dw); tiles past the last routed row are skipped")

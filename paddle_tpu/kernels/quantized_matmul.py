@@ -138,7 +138,6 @@ def _qmm_eligible(sig: registry.Signature) -> bool:
 registry.register_kernel(
     "quantized_matmul", op_types=("mul", "matmul"),
     eligible=_qmm_eligible, run=quantized_matmul,
-    source_tag="quantized_matmul.py",
     doc="per-tile int8/bf16 GEMM for inference-shaped programs; "
         "opt-in via PT_KERNEL_QUANT_MATMUL=int8|bf16, 2-D operands "
         "with 128-multiple dims")

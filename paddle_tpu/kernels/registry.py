@@ -25,8 +25,8 @@ Gating, outermost first:
   the ``PT_KERNEL_MIN_NUMEL`` floor where size matters.
 
 Every decision increments ``pt_kernel_dispatch_total`` (labels:
-``kernel``, ``outcome``) and a process-local stats dict consumed by
-``bench.py`` / ``tools/kernel_bench.py``.  All four knobs that change
+``kernel``, ``outcome``) and a process-local stats dict
+(:func:`dispatch_stats`).  All four knobs that change
 trace content (the flag plus the three ``PT_KERNEL_*`` env vars) are
 part of the engine ``_cache_key``/``_fast_key``, so toggling them can
 never serve a stale compiled artifact.
@@ -105,17 +105,15 @@ def signature(op_type: str, *arrays) -> Signature:
 class Kernel:
     """One registered custom kernel."""
 
-    __slots__ = ("name", "op_types", "run", "eligible", "source_tag",
-                 "doc")
+    __slots__ = ("name", "op_types", "run", "eligible", "doc")
 
     def __init__(self, name: str, op_types: Tuple[str, ...],
                  run: Callable, eligible: Callable[[Signature], bool],
-                 source_tag: str = "", doc: str = ""):
+                 doc: str = ""):
         self.name = name
         self.op_types = op_types
         self.run = run
         self.eligible = eligible
-        self.source_tag = source_tag
         self.doc = doc
 
 
@@ -128,10 +126,9 @@ _STATS: Dict[str, Dict[str, int]] = {}  # kernel name -> outcome counts
 
 def register_kernel(name: str, *, op_types: Sequence[str],
                     eligible: Callable[[Signature], bool],
-                    run: Callable, source_tag: str = "",
-                    doc: str = "") -> Kernel:
+                    run: Callable, doc: str = "") -> Kernel:
     """Register (or re-register, e.g. on module reload) a kernel."""
-    kern = Kernel(name, tuple(op_types), run, eligible, source_tag, doc)
+    kern = Kernel(name, tuple(op_types), run, eligible, doc)
     if name in _KERNELS:
         for lst in _BY_OP.values():
             lst[:] = [k for k in lst if k.name != name]
@@ -355,7 +352,7 @@ def abstract_select(op_type: str, sig: Signature,
 
 
 def dispatch_stats() -> Dict[str, Any]:
-    """Process-local dispatch counters, bench-consumable shape."""
+    """Process-local dispatch counters."""
     with _STATS_LOCK:
         per = {k: dict(v) for k, v in _STATS.items()}
     total = sum(v.get(d, 0) for v in per.values() for d in _DECISIONS)
@@ -372,16 +369,3 @@ def dispatch_stats() -> Dict[str, Any]:
 def reset_stats() -> None:
     with _STATS_LOCK:
         _STATS.clear()
-
-
-def source_tags() -> List[Tuple[str, str]]:
-    """(source-file tag, kernel names) pairs for HLO attribution.
-
-    Kernels sharing a source file are folded into one label so
-    hbm_breakdown's first-hit-wins categorizer stays truthful.
-    """
-    by_tag: Dict[str, List[str]] = {}
-    for k in _KERNELS.values():
-        if k.source_tag:
-            by_tag.setdefault(k.source_tag, []).append(k.name)
-    return [(tag, "+".join(names)) for tag, names in by_tag.items()]

@@ -475,7 +475,7 @@ def _install_standard_families(reg: MetricsRegistry) -> None:
                 "{kernel, outcome} with outcome one of custom "
                 "(kernel selected), lowered (eligibility/backend kept "
                 "the lowered path), denied (flag or PT_KERNEL_DENY)")
-    # distributed tracing + attribution (docs/TRACING.md)
+    # distributed tracing (docs/TRACING.md)
     reg.counter("pt_spans_recorded_total",
                 "trace spans recorded, labeled {kind} (step, phase, "
                 "lane, rpc.client, rpc.server, fetch, ckpt)")
@@ -488,18 +488,6 @@ def _install_standard_families(reg: MetricsRegistry) -> None:
     reg.gauge("pt_step_slowest_worker_seconds",
               "mean step duration of the currently slowest worker, "
               "labeled {worker}")
-    reg.gauge("pt_island_device_seconds",
-              "estimated device time per scheduler island, labeled "
-              "{island} (measured device total apportioned by each "
-              "island's host dispatch-span share)")
-    reg.gauge("pt_hbm_peak_bytes",
-              "compiled-step HBM footprint: memory_analysis temp + "
-              "argument bytes (max over scheduler islands when "
-              "FLAGS_op_scheduler splits the step)")
-    reg.gauge("pt_mfu_estimate",
-              "measured MFU: analytic FLOPs/step over measured device "
-              "(or host-wall) seconds per step against the chip's "
-              "dense bf16 peak")
     reg.counter("pt_deep_profiles_total",
                 "deep-profile captures that emitted a merged timeline "
                 "(PT_DEEP_PROFILE_EVERY / request_deep_profile)")

@@ -82,8 +82,7 @@ class MPMDPipelineEngine:
     ``schedule`` picks the micro-batch dispatch order
     (core/scheduler.pipeline_schedule): "1f1b" (default) drains each
     backward as soon as it is ready, capping the activation stash at
-    the pipeline depth; "gpipe" is the legacy fill/drain, kept for the
-    A/B in tools/step_overhead_bench.py --compare-pipeline. Both
+    the pipeline depth; "gpipe" is the legacy fill/drain. Both
     execute the same F/B events with the same fold_in keys, so the
     loss is schedule-invariant; ``last_stats`` reports the measured
     bubble fraction of whichever schedule ran."""

@@ -39,8 +39,7 @@ attributed, recoverable* anomaly:
 
 Sentinel OFF is the default and does literally nothing: no plan is
 built, no state vars exist, the traced step is bit-identical to a
-build without this module (proved by
-``tools/step_overhead_bench.py --compare-integrity``).
+build without this module.
 """
 from __future__ import annotations
 

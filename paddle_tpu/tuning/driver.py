@@ -77,25 +77,12 @@ def _objective_mode() -> str:
     return mode if mode in ("wall", "attribution") else "wall"
 
 
-def _gauge_sum(name: str) -> Optional[float]:
-    """Sum of a gauge family's sample values, None when never set."""
-    try:
-        from ..observability import metrics
-        fam = metrics.default_registry().get(name)
-        samples = fam.collect().samples
-        if not samples:
-            return None
-        return float(sum(v for _labels, v in samples))
-    except Exception:
-        return None
-
-
 def _attr_signals(engine, c0: Dict[str, float], steps: int
                   ) -> Dict[str, float]:
     """Per-knob credit signals measured over one trial: engine-counter
-    deltas (vs the pre-trial snapshot ``c0``) normalized per step, plus
-    attribution gauges. A knob with no live signal contributes nothing
-    — the attribution objective then degrades to pure wall time."""
+    deltas (vs the pre-trial snapshot ``c0``) normalized per step. A
+    knob with no live signal contributes nothing — the attribution
+    objective then degrades to pure wall time."""
     c = engine.counters
     steps = max(1, int(steps))
     sig: Dict[str, float] = {}
@@ -111,10 +98,6 @@ def _attr_signals(engine, c0: Dict[str, float], steps: int
             float(c0.get("collective_bytes", 0.0)):
         sig["comm_overlap_frac"] = float(
             c.get("comm_overlap_frac", 0.0))
-    # GEMM/kernel knobs <- measured per-island device seconds
-    isl = _gauge_sum("pt_island_device_seconds")
-    if isl:
-        sig["island_device_ms"] = isl * 1e3
     # multi_step_k <- host-phase share: the fraction of substeps that
     # paid a host dispatch round-trip (1.0 at K=1, 1/K in slab mode)
     sub = float(c.get("multistep_substeps", 0.0)) - \
@@ -136,7 +119,6 @@ def _attr_score(wall_ms: float, sig: Dict[str, float]) -> float:
     if "comm_overlap_frac" in sig:
         s += min((1.0 - sig["comm_overlap_frac"]) * wall_ms * 0.25,
                  cap)
-    s += min(sig.get("island_device_ms", 0.0) * 0.25, cap)
     s += min(sig.get("host_share", 0.0) * wall_ms * 0.25, cap)
     return s
 

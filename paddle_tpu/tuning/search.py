@@ -18,8 +18,8 @@ memoized — a deterministic objective is measured exactly once per
 budget.
 
 The objective is "lower is better", typically measured step
-milliseconds (driver.py wires per-island device ms / MFU-derived
-objectives from the PR 10 attribution when available).
+milliseconds (driver.py adds per-knob penalties from the engine's
+counters under PT_TUNE_OBJECTIVE=attribution).
 """
 from __future__ import annotations
 

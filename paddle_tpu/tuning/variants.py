@@ -7,8 +7,7 @@ This extends the ``kernels/parity.py`` generate-and-verify loop from
 Pallas GEMM variants — tile shapes (bm, bn, bk) crossed with fused
 epilogues (none, layer_norm, dropout+residual) — admit ONLY variants
 whose parity case passes against the composed XLA baseline, then rank
-the admitted set with the ``tools/kernel_bench.py`` median-of-reps
-timing discipline. Winners persist in the tuning cache next to the
+the admitted set by the median of repeated timed calls. Winners persist in the tuning cache next to the
 knob config and are re-registered on later runs by the driver.
 
 The variant kernel follows quantized_matmul's structure: a
@@ -22,7 +21,7 @@ composed baseline is exact modulo f32 reassociation).
 
 On CPU the kernels run under the Pallas interpreter: parity gating is
 real (tier-1 proves the loop), timings are marked ``interpret_mode``
-and not treated as hardware truth — same policy as kernel_bench.
+and not treated as hardware truth.
 """
 from __future__ import annotations
 
@@ -364,7 +363,7 @@ def register_winner(winners: Dict[str, Any]) -> Optional[str]:
 
     kreg.register_kernel(
         "tuned_matmul", op_types=("mul", "matmul"),
-        eligible=eligible, run=run, source_tag="tuning/variants.py",
+        eligible=eligible, run=run,
         doc=f"autotuned f32 GEMM, blocks {v.bm}x{v.bn}x{v.bk} "
             f"(winner from the tuning-cache variant search)")
     return "tuned_matmul"

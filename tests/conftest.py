@@ -1,6 +1,6 @@
 """Test config: force an 8-device virtual CPU mesh so multi-chip sharding
-paths are exercised without TPU hardware (chip_smoke.py and bench.py run
-on the real chip). The suite never reaches a TPU: jax_platforms is
+paths are exercised without TPU hardware (chip_smoke.py and benchmark/
+run on the real chip). The suite never reaches a TPU: jax_platforms is
 pinned to cpu before any backend is initialized."""
 import os
 

@@ -383,7 +383,8 @@ def test_fused_lowering_canonicalizes_int64_operands():
 
 def test_transpiled_bucketed_program_runs_single_process(flag_guard):
     """world_size-1: c_allreduce_fused is identity (no axis guard);
-    a bucketed transpiled program still trains."""
+    a bucketed transpiled program trains exactly as the plain one."""
+    plain, _ = _run_steps(*_build_adam_mlp(), _batches(4))
     main, startup, cost = _build_adam_mlp()
     cfg = fluid.DistributeTranspilerConfig()
     cfg.mode = "collective"
@@ -394,7 +395,7 @@ def test_transpiled_bucketed_program_runs_single_process(flag_guard):
     ops = [op.type for op in trainer.global_block().ops]
     assert "c_allreduce_fused" in ops
     losses, eng = _run_steps(trainer, startup, cost, _batches(4))
-    assert losses[-1] < losses[0]
+    np.testing.assert_array_equal(losses, plain)
     assert all(np.isfinite(losses))
     # no mesh -> the identity collective moves no bytes; honest zero
     assert eng.counters["grad_collectives_per_step"] == 0

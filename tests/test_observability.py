@@ -459,22 +459,6 @@ class TestMetricsReport(unittest.TestCase):
                                   "--check-families"])
         self.assertEqual(rc, 0)
 
-    def test_overhead_gate_from_json(self):
-        import metrics_report
-        d = tempfile.mkdtemp()
-        oj = os.path.join(d, "overhead.json")
-        with open(oj, "w") as f:
-            json.dump({"sync_ms": 10.0, "pipelined_ms": 2.0,
-                       "host_overhead_ms": 8.0}, f)
-        rc = metrics_report.main(["--flight-dir", d, "--no-local",
-                                  "--threshold-ms", "5",
-                                  "--overhead-json", oj])
-        self.assertEqual(rc, 1)
-        rc = metrics_report.main(["--flight-dir", d, "--no-local",
-                                  "--threshold-ms", "9",
-                                  "--overhead-json", oj])
-        self.assertEqual(rc, 0)
-
     def test_metrics_jsonl_dump_feeds_fleet_report(self):
         import metrics_report
         d = tempfile.mkdtemp()

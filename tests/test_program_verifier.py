@@ -378,15 +378,6 @@ def test_cost_scales_with_batch():
     assert c64.total_flops > 30 * c1.total_flops
 
 
-def test_correlation():
-    assert cost_model.correlation([1, 2, 3], [2, 4, 6]) == \
-        pytest.approx(1.0)
-    assert cost_model.correlation([1, 2, 3], [3, 2, 1]) == \
-        pytest.approx(-1.0)
-    assert cost_model.correlation([1], [1]) is None
-    assert cost_model.correlation([1, 1, 1], [1, 2, 3]) is None
-
-
 def test_cost_model_pass_opt_in(monkeypatch):
     main, _, loss = _mlp_program()
     diags = analyze_program(main, feed_names=["img", "label"],

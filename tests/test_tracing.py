@@ -1,7 +1,7 @@
-"""Distributed tracing + device-time attribution (docs/TRACING.md):
+"""Distributed tracing (docs/TRACING.md):
 span parent/child integrity across a 2-process trainer<->pserver RPC
-exchange, fleet-skew gauges from heartbeat summaries, attribution of a
-CPU-compiled step (cost_analysis keys), the disabled-path no-op, the
+exchange, fleet-skew gauges from heartbeat summaries, the cost keys of a
+CPU-compiled step (Engine.compiled_stats), the disabled-path no-op, the
 deep-profile merged timeline, and the timeline tool's directory
 expansion."""
 import json
@@ -273,7 +273,7 @@ class TestSkew(unittest.TestCase):
 
 
 # ---------------------------------------------------------------------------
-# attribution of a CPU-compiled step
+# cost analysis of a CPU-compiled step
 # ---------------------------------------------------------------------------
 
 class TestAttribution(unittest.TestCase):
@@ -282,24 +282,18 @@ class TestAttribution(unittest.TestCase):
         fluid, eng, prog, scope, feed, fetch = _tiny_engine()
         with fluid.scope_guard(scope):
             eng.run(prog, scope, None, feed, fetch)
-            rep = attribution.attribute(eng, prog, scope, feed, fetch)
-        self.assertNotIn("error", rep)
-        self.assertIn("cost", rep)
+            stats = eng.compiled_stats(prog, scope, feed, fetch)
+        self.assertIsNotNone(stats)
         self.assertTrue(
-            set(rep["cost"]) & {"flops", "bytes_accessed",
-                                "temp_bytes", "argument_bytes"})
-        self.assertIn("program_ops", rep)
-        self.assertGreaterEqual(rep["program_ops"].get("mean", 0), 1)
-        if rep.get("hbm_peak_bytes"):
-            self.assertGreater(
-                metrics.gauge("pt_hbm_peak_bytes").get(), 0)
+            {"flops", "bytes_accessed", "temp_bytes",
+             "argument_bytes"} <= set(stats))
 
     def test_mfu_estimate_needs_a_tpu(self):
         # a host backend has no MXU peak: None, never a bogus MFU
         self.assertIsNone(attribution.mfu_estimate(1e12, 0.1))
 
     def test_unknown_device_kind_raises(self):
-        # the single peak table: an unlisted chip is an error
+        # an unlisted chip is an error, never a default
         self.assertEqual(attribution.peak_tflops("TPU v5 lite"), 197.0)
         with self.assertRaisesRegex(KeyError, "TPU v99"):
             attribution.peak_tflops("TPU v99")

@@ -4,8 +4,8 @@ Runs the same cache-or-search loop ``FLAGS_autotune`` runs at a
 program's first training step — but ahead of time, so production jobs
 start from a warm tuning cache and pay ZERO trials::
 
-    # search on the built-in training-step model (the MLP
-    # step_overhead_bench measures), persist the winner
+    # search on the built-in training-step model (lint_program's
+    # book MLP), persist the winner
     python tools/autotune.py --cache-dir /ckpt/tuning
 
     # tune a serialized inference model (save_inference_model dir)
@@ -118,8 +118,14 @@ def main(argv=None):
         fetch = [v.name for v in fetch_vars]
         eng = Engine()
     else:
-        from tools.step_overhead_bench import _build_model
-        eng, program, scope, feed, fetch = _build_model(args.batch)
+        from tools.lint_program import build_model
+        program, startup, _, loss = build_model("mlp")
+        scope = Scope()
+        with fluid.scope_guard(scope):
+            fluid.Executor().run(startup)
+        feed = _synth_feed(program, args.batch)
+        fetch = [loss.name]
+        eng = Engine()
 
     if args.force:
         path = cache.path_for(

@@ -34,7 +34,6 @@ Usage:
       --fault "seed=7,nan=0.2"                      # stability guard
   python tools/chaos_report.py --steps 16 \
       --fault "seed=7,bitflip_step=6"               # integrity sentinel
-  PT_BENCH_CHAOS=1 python bench.py                  # bench tail line
 
 ``nan`` / ``grad_spike`` fault plans automatically arm
 ``FLAGS_stability_guard`` in every trainer of both runs and add an
@@ -887,29 +886,6 @@ def chaos_report(steps=DEFAULT_STEPS, fault_spec=DEFAULT_FAULT,
             and eprobe["resumed_elastic"]
             and eprobe["bit_identical_vs_fresh"])
     return rep
-
-
-def chaos_report_line(steps=DEFAULT_STEPS, fault_spec=DEFAULT_FAULT,
-                      max_restarts=1):
-    """(dict, '# chaos: ...' stderr line) for bench.py's report tail."""
-    rep = chaos_report(steps=steps, fault_spec=fault_spec,
-                       max_restarts=max_restarts)
-    f = rep["faulted"]
-    line = (f"# chaos: survived={rep['survived']} "
-            f"restarts={f['restarts']} "
-            f"faults={sum(f['faults_injected'].values())} "
-            f"retries={f['retries_consumed']} "
-            f"loss_delta={rep['loss_delta']}")
-    if "integrity" in rep:
-        i = rep["integrity"]
-        line += (f" integrity={i['detected']}/{i['injected']} "
-                 f"recovered={i['recovered']} missed={i['missed']}")
-    if "elastic" in rep:
-        e = rep["elastic"]
-        line += (f" elastic={e['detected']}/{e['injected']} "
-                 f"worlds={e['world_sizes']} "
-                 f"bit_identical={e['bit_identical_vs_fresh']}")
-    return rep, line
 
 
 def main(argv=None):

@@ -11,29 +11,23 @@ Inputs (any combination; all three default on):
   ``{"t": "metrics_json"}`` endpoint every trainer serves when
   ``PT_METRICS_PORT`` is set (and every pserver serves natively).
 * **the local registry** — so running the tool inside a trainer
-  process (or bench.py) reports without any files.
+  process reports without any files.
 
 Fleet merge: counters sum across sources, gauges keep per-source
 samples (labeled by origin), histograms sum bucket counts / sums — so
 ``pt_step_total_seconds`` becomes the cluster-wide step latency
 distribution.
 
-CI gates (exit 1 on failure):
-
-* ``--check-families``: every REQUIRED_FAMILIES name must be present —
-  a refactor silently dropping ``pt_step_dispatch_seconds`` (the
-  ROADMAP item 4 attribution metric) fails here, not in a dashboard
-  three weeks later.
-* ``--threshold-ms X``: disabled-telemetry host overhead per step must
-  stay under X (proves the one-boolean hot-path gate). Reads
-  ``--overhead-json`` (a ``step_overhead_bench --json`` output) when
-  given, else measures in-process.
+CI gate (exit 1 on failure): ``--check-families`` — every
+REQUIRED_FAMILIES name must be present; a refactor silently dropping
+``pt_step_dispatch_seconds`` fails here, not in a dashboard three
+weeks later.
 
 Usage::
 
     python tools/metrics_report.py --flight-dir /tmp/flight --json
     python tools/metrics_report.py --scrape 127.0.0.1:9460
-    python tools/metrics_report.py --threshold-ms 6 --check-families
+    python tools/metrics_report.py --check-families
 """
 from __future__ import annotations
 
@@ -57,11 +51,10 @@ REQUIRED_FAMILIES = (
     "pt_ckpt_save_seconds", "pt_ckpt_restore_seconds",
     "pt_heartbeats_sent_total", "pt_heartbeats_failed_total",
     "pt_trainers_evicted_total", "pt_flight_dumps_total",
-    # distributed tracing + device-time attribution (docs/TRACING.md)
+    # distributed tracing (docs/TRACING.md)
     "pt_spans_recorded_total", "pt_span_dumps_total",
     "pt_step_skew_seconds", "pt_step_slowest_worker_seconds",
-    "pt_island_device_seconds", "pt_hbm_peak_bytes",
-    "pt_mfu_estimate", "pt_deep_profiles_total",
+    "pt_deep_profiles_total",
     # feedback-directed autotuner (FLAGS_autotune, docs/TUNING.md)
     "pt_tuning_searches_total", "pt_tuning_trials_total",
     "pt_tuning_cache_hits_total", "pt_tuning_best_ms",
@@ -227,25 +220,6 @@ def missing_families(merged: Dict[str, dict]) -> List[str]:
     return [n for n in REQUIRED_FAMILIES if n not in merged]
 
 
-def measure_disabled_overhead(batch: int = 256, steps: int = 20) -> dict:
-    """Disabled-telemetry host overhead, measured in-process with
-    ``step_overhead_bench``'s method. Every observability gate is
-    explicitly forced off first — this is the number the one-boolean
-    contract is judged by."""
-    from paddle_tpu.observability import metrics, recorder
-    from paddle_tpu.distributed import faults
-    import paddle_tpu as fluid
-    import step_overhead_bench as sob
-    faults.uninstall()
-    metrics.enable_telemetry(False)
-    recorder.enable(False)
-    recorder.set_watchdog_active(False)
-    eng, prog, scope, feed, fetch = sob._build_model(batch)
-    with fluid.scope_guard(scope):
-        return sob.measure_step_overhead(eng, prog, scope, feed, fetch,
-                                         steps=steps)
-
-
 # ---------------------------------------------------------------------------
 # report
 # ---------------------------------------------------------------------------
@@ -282,12 +256,6 @@ def main(argv=None) -> int:
     p.add_argument("--check-families", action="store_true",
                    help="exit 1 if any required metric family is "
                         "missing from the merged view")
-    p.add_argument("--threshold-ms", type=float, default=None,
-                   help="exit 1 if disabled-telemetry host overhead "
-                        "per step exceeds this")
-    p.add_argument("--overhead-json", default=None,
-                   help="step_overhead_bench --json output to gate on "
-                        "instead of measuring in-process")
     p.add_argument("--last-n", type=int, default=8,
                    help="steps summarized per flight dump")
     p.add_argument("--json", action="store_true",
@@ -307,24 +275,6 @@ def main(argv=None) -> int:
             failures.append(f"required metric families missing: "
                             f"{missing}")
 
-    if args.threshold_ms is not None:
-        if args.overhead_json:
-            with open(args.overhead_json) as f:
-                overhead = json.load(f)
-        else:
-            overhead = measure_disabled_overhead()
-        rep["disabled_overhead"] = {
-            "host_overhead_ms": overhead["host_overhead_ms"],
-            "sync_ms": overhead["sync_ms"],
-            "threshold_ms": args.threshold_ms,
-        }
-        if overhead["host_overhead_ms"] > args.threshold_ms:
-            failures.append(
-                f"disabled-telemetry host overhead "
-                f"{overhead['host_overhead_ms']:.2f} ms/step exceeds "
-                f"threshold {args.threshold_ms:.2f} ms (one-boolean "
-                f"hot-path gate regressed?)")
-
     if args.json:
         print(json.dumps(rep, indent=2, default=str))
     else:
@@ -338,11 +288,6 @@ def main(argv=None) -> int:
             print(f"  flight {fl['file']}: reason={fl['reason']} "
                   f"steps {fl['first_step']}..{fl['last_step']} "
                   f"mean_phase_ms={fl['mean_phase_ms']}")
-        if "disabled_overhead" in rep:
-            d = rep["disabled_overhead"]
-            print(f"disabled-path overhead: "
-                  f"{d['host_overhead_ms']:.2f} ms/step "
-                  f"(threshold {d['threshold_ms']:.2f})")
     if failures:
         for f in failures:
             print("GATE FAILURE: " + f, file=sys.stderr)
