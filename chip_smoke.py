@@ -24,8 +24,8 @@ its mode):
      from the same init with FLAGS_use_custom_kernels=0 must give the
      same losses.
   B  the same model at B=8 S=1024 and B=4 S=4096 (dropout 0.1): the
-     flash-attention forward, dq and dkv kernels with the in-kernel
-     hardware-PRNG dropout, compiled by Mosaic; no lowered decision.
+     flash-attention forward and fused backward kernels with the
+     in-kernel hardware-PRNG dropout, compiled by Mosaic; no lowered decision.
   C  every registered kernel compiled and held to its parity
      tolerance (kernels/parity.py), the dropout mask-identity probe,
      quantized_matmul and the tuning/variants.py GEMM variants.

@@ -323,14 +323,71 @@ def _fa_case(precision):
                 "flash_attention/f32/%s/1x2x256x64" % precision, run)
 
 
+# The flash backward as the long-sequence cells run it: bf16 streams in
+# the bshd layout, causal, d_head 64 (tbase_s4096) and q/k 192 against
+# v 128 (kanana2_s4096), compiled at S=4096 in blocks of 512 x 1024 on a
+# chip and interpreted at S=256 in blocks of 128 on a CPU host. The
+# fused kernel's dq, dk and dv are held to the composed formulation's
+# float32 vjp at precision "highest" (2.5e-3 compiled on the v5e: the
+# bf16 rounding of p, ds and the results) and, bit for bit, to the split
+# pair's, whose accumulation order the fused kernel keeps.
+_FA_BWD_BF16_TOL = 1e-2
+
+
+def _fa_bwd_case(heads, d, dv):
+    def run():
+        import jax
+        fa = _fa_module()
+        seq, bq, bk = (256, 128, 128) if registry.interpret() \
+            else (4096, 512, 1024)
+        r = _rng(31)
+        q, k, v, g = (jnp.asarray(r.standard_normal((1, seq, heads, w),
+                                                    dtype=np.float32),
+                                  jnp.bfloat16)
+                      for w in (d, d, dv, dv))
+        scale = d ** -0.5
+
+        def backward(q, k, v, g):
+            out, lse = fa._fa_forward(q, k, v, None, scale, bq, bk,
+                                      return_lse=True, layout="bshd",
+                                      causal=True)
+            return fa._fa_backward(q, k, v, None, out, lse, g, scale, bq,
+                                   bk, layout="bshd", causal=True)[:3]
+
+        with _fa_kernels_live(fa):
+            fused = jax.jit(backward)(q, k, v, g)
+            budget, fa._FUSED_DQ_VMEM_BUDGET = fa._FUSED_DQ_VMEM_BUDGET, 0
+            try:
+                split = jax.jit(backward)(q, k, v, g)
+            finally:
+                fa._FUSED_DQ_VMEM_BUDGET = budget
+        with jax.default_matmul_precision("highest"):
+            _, vjp = jax.vjp(
+                lambda q, k, v: fa._attn_reference(
+                    q, k, v, None, scale, layout="bshd", causal=True),
+                *(x.astype(jnp.float32) for x in (q, k, v)))
+            want = vjp(g.astype(jnp.float32))
+        apart = sum(int((np.asarray(a, np.float32)
+                         != np.asarray(b, np.float32)).sum())
+                    for a, b in zip(fused, split))
+        err = max(rel_err(w, np.asarray(f, np.float32))
+                  for f, w in zip(fused, want))
+        return {"metric": "rel_vs_composed_vjp", "tol": _FA_BWD_BF16_TOL,
+                "value": float("inf") if apart else err,
+                "note": "%d elements apart from the split pair's" % apart}
+    return Case("flash_attention",
+                "flash_attention/bwd_fused/bf16/%dx%d" % (d, dv), run)
+
+
 def dropout_mask_identity() -> Dict[str, Any]:
-    """Do the forward, dq and dkv flash kernels realize the SAME
+    """Do the forward and the backward flash kernels realize the SAME
     attention-dropout mask? Exact-extraction probe: q = k = 0 makes p
     uniform, so one-hot v / dO read the keep mask out elementwise from
-    the forward output and from dv, and a per-head zero bias reads it
-    from ds. Compiled kernels draw the mask from the TPU hardware PRNG
-    (``_tile_keep``), which no CPU run reaches; under the interpreter
-    the same probe checks the hash path. Returns ``value`` = mask
+    the forward output and from dv (the fused backward kernel), and a
+    per-head zero bias whose gradient is demanded reads it from ds (the
+    split pair's dq kernel). Compiled kernels draw the mask from the
+    TPU hardware PRNG (``_tile_keep``), which no CPU run reaches; under
+    the interpreter the same probe checks the hash path. Returns ``value`` = mask
     positions where a backward kernel disagrees with the forward
     (``tol`` 0) and ``keep_frac``, the realized keep rate."""
     import jax
@@ -463,6 +520,8 @@ def cases() -> List[Case]:
         _qmm_case("bf16", 1e-2),
         _fa_case("highest"),
         _fa_case("default"),
+        _fa_bwd_case(4, 64, 64),
+        _fa_bwd_case(4, 192, 128),
         _gmm_case("fwd"),
         _gmm_case("dx"),
         _gmm_case("dw"),

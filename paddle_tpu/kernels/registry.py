@@ -252,7 +252,8 @@ def count(name: str, outcome: str) -> None:
     outcome: a decision — ``custom`` (kernel chosen), ``lowered``
     (eligibility or backend said no), ``denied`` (flag/deny list said
     no) — or what a kernel says of the call it then made (the fused
-    optimizer's ``native_view`` / ``flat_view``), which
+    optimizer's ``native_view`` / ``flat_view``, the flash backward's
+    ``fused_bwd`` / ``split_bwd``), which
     :func:`dispatch_stats` lists per kernel and leaves out of
     ``decisions`` and ``hit_rate``.
     """

@@ -542,14 +542,14 @@ def _reference_grads(feed, layout, mode, n_sites, names):
 @pytest.mark.parametrize("mode", ["plain", "causal", "padding"])
 @pytest.mark.parametrize("layout", ["bhsd", "bshd"])
 def test_engine_step_runs_each_flash_kernel_once_per_site(layout, mode):
-    """(a) The lowered step holds one forward, one dq and one dkv call
-    per attention: the grad op reads Out and SoftmaxLse."""
+    """(a) The lowered step holds one forward call and one backward
+    call (the fused dq/dk/dv kernel, named flash_attention_dkv) per
+    attention: the grad op reads Out and SoftmaxLse."""
     n_sites = 2
     main, names, grads = _attn_program(layout, mode, n_sites)
     feed = _attn_feed(names, layout)
     vals, calls = _engine_step(main, feed, grads)
     assert calls == {"flash_attention_fwd": n_sites,
-                     "flash_attention_dq": n_sites,
                      "flash_attention_dkv": n_sites}, calls
     ref = _reference_grads(feed, layout, mode, n_sites, names)
     for got, want in zip(vals, ref):
@@ -661,7 +661,7 @@ def test_serialised_program_keeps_lse_slot():
         sorted(op.input("SoftmaxLse")[0] for op in gops)
     feed = _attn_feed(names, "bshd", seed=5)
     vals, calls = _engine_step(loaded, feed, grads)
-    assert calls == {"flash_attention_fwd": 2, "flash_attention_dq": 2,
+    assert calls == {"flash_attention_fwd": 2,
                      "flash_attention_dkv": 2}, calls
     want, _ = _engine_step(main, feed, grads)
     for a, b in zip(vals, want):
@@ -684,3 +684,151 @@ def test_is_test_forward_with_grad_op_recomputes():
     for g, want in zip(grads, ref):
         np.testing.assert_allclose(np.asarray(env[g]), np.asarray(want),
                                    atol=5e-4, rtol=5e-4)
+
+
+# ---------------------------------------------------------------------------
+# the fused backward: dq, dk and dv from one pass over the score tiles
+# ---------------------------------------------------------------------------
+
+def _flash_stats():
+    from paddle_tpu.kernels import registry
+    return registry.dispatch_stats()["per_kernel"].get(
+        "flash_attention", {})
+
+
+def _composed_with_lse(q, k, v, scale, causal, keep, t):
+    """[B,H,S,D] attention returning (out, lse), the weights dropped by
+    the mask the interpret-mode kernels realize."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    if causal:
+        s = fa._causal_mask_dense(s)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.exp(s - lse[..., None])
+    if keep is not None:
+        p = jnp.where(keep, p * (256.0 / t), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v), lse
+
+
+@pytest.mark.parametrize("with_glse", [False, True],
+                         ids=["out_only", "g_lse"])
+@pytest.mark.parametrize("drop", [False, True], ids=["keep_all", "dropout"])
+@pytest.mark.parametrize("widths", [(64, 64), (192, 128)],
+                         ids=["64x64", "192x128"])
+@pytest.mark.parametrize("seqs", [(256, 256), (256, 384)],
+                         ids=["Sq_eq_Sk", "Sq_ne_Sk"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("layout", ["bhsd", "bshd"])
+def test_fused_backward_bit_equal_to_split(layout, causal, seqs, widths,
+                                           drop, with_glse, monkeypatch):
+    """One kernel builds each tile's s, p, dp and ds once and writes dq,
+    dk and dv; the accumulation orders are the split pair's (dq over kv
+    blocks ascending, dk / dv over q blocks ascending), so the results
+    are the same bits, and within the composed vjp's tolerance."""
+    from paddle_tpu.kernels import registry
+    (Sq, Sk), (D, Dv) = seqs, widths
+    B, H, t = 2, 4, 205
+    rng = np.random.default_rng(12)
+    qb, kb, vb, gb = (_rand(rng, B, H, S, W) for S, W in
+                      ((Sq, D), (Sk, D), (Sk, Dv), (Sq, Dv)))
+    g_lse = _rand(rng, B, H, Sq) if with_glse else None
+    key = jax.random.PRNGKey(7)
+    dropout = (key, t) if drop else None
+    scale = float(D) ** -0.5
+
+    def to_layout(x):
+        return jnp.moveaxis(x, 1, 2) if layout == "bshd" else x
+
+    q, k, v, g = (to_layout(x) for x in (qb, kb, vb, gb))
+    out, lse = fa._fa_forward(q, k, v, None, scale, 128, 128,
+                              return_lse=True, raw_lse=True,
+                              layout=layout, causal=causal,
+                              dropout=dropout)
+
+    def backward():
+        return fa._fa_backward(q, k, v, None, out, lse, g, scale, 128,
+                               128, g_lse=g_lse, layout=layout,
+                               lse_wide=True, causal=causal,
+                               dropout=dropout)[:3]
+
+    registry.reset_stats()
+    fused = backward()
+    assert _flash_stats() == {"fused_bwd": 1}
+    # nothing fits a budget of nothing: the split pair
+    monkeypatch.setattr(fa, "_FUSED_DQ_VMEM_BUDGET", 0)
+    split = backward()
+    assert _flash_stats() == {"fused_bwd": 1, "split_bwd": 1}
+    for a, b in zip(fused, split):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    keep = None
+    if drop:
+        keep = fa.dropout_keep_mask(
+            jax.lax.bitcast_convert_type(key, jnp.int32).reshape(2),
+            B, H, Sq, Sk, t)
+    _, vjp = jax.vjp(lambda q, k, v: _composed_with_lse(
+        q, k, v, scale, causal, keep, t), qb, kb, vb)
+    want = vjp((gb, jnp.zeros((B, H, Sq)) if g_lse is None else g_lse))
+    for a, b in zip(fused, want):
+        np.testing.assert_allclose(
+            np.asarray(a if layout == "bhsd" else jnp.moveaxis(a, 1, 2)),
+            np.asarray(b), atol=3e-4, rtol=3e-4)
+
+
+def _traced_backward_route(shape_qk, shape_v, dtype, bias=None,
+                           want_dbias=None):
+    """Trace (never run) one _fa_backward at a bshd shape; the route it
+    counted."""
+    from paddle_tpu.kernels import registry
+    B, S, H, _ = shape_qk
+    qk = jax.ShapeDtypeStruct(shape_qk, dtype)
+    v = jax.ShapeDtypeStruct(shape_v, dtype)
+    lse = jax.ShapeDtypeStruct((B, H, S), jnp.float32)
+    registry.reset_stats()
+    jax.eval_shape(
+        lambda q, k, v, out, lse, g: fa._fa_backward(
+            q, k, v, bias, out, lse, g, 0.125, 512, 1024, layout="bshd",
+            want_dbias=want_dbias, causal=True),
+        qk, qk, v, v, lse, v)
+    return _flash_stats()
+
+
+@pytest.mark.parametrize("shape_qk,shape_v,dtype,resident,route", [
+    # tbase_s4096's attention: 4 MiB of resident dq
+    ((4, 4096, 8, 64), (4, 4096, 8, 64), jnp.bfloat16, 4 << 20,
+     "fused_bwd"),
+    # kanana2_s4096's: two heads of 192 a block, 12 MiB
+    ((1, 4096, 32, 192), (1, 4096, 32, 128), jnp.bfloat16, 12 << 20,
+     "fused_bwd"),
+    # the same heads in float32 at S=8192: 36 MiB, the last that fits
+    ((1, 8192, 32, 192), (1, 8192, 32, 128), jnp.float32, 36 << 20,
+     "fused_bwd"),
+    # S=65,536 at 64/64: 64 MiB of dq cannot stay in VMEM
+    ((1, 65536, 8, 64), (1, 65536, 8, 64), jnp.bfloat16, 64 << 20,
+     "split_bwd"),
+], ids=["tbase_s4096", "kanana2_s4096", "f32_s8192", "s65536"])
+def test_backward_route_follows_the_resident_dq(shape_qk, shape_v, dtype,
+                                                resident, route):
+    B, S, H, D = shape_qk
+    plan = fa._Plan("bshd", B, H, S, S, D, 512, 1024, shape_v[3])
+    assert fa._resident_dq_bytes(plan, dtype) == resident
+    assert (resident <= fa._FUSED_DQ_VMEM_BUDGET) == (route == "fused_bwd")
+    assert _traced_backward_route(shape_qk, shape_v, dtype) == {route: 1}
+
+
+def test_demanded_dbias_takes_the_split_pair():
+    """The ds output follows the dq-style grid: a bias gradient that is
+    asked for runs the dq and dk/dv kernels, and says so; the same bias
+    with no gradient asked runs the fused kernel."""
+    shape = (2, 1024, 8, 64)
+    bias = jnp.zeros((2, 1, 1024, 1024), jnp.float32)
+    assert _traced_backward_route(shape, shape, jnp.bfloat16, bias,
+                                  want_dbias=True) == {"split_bwd": 1}
+    assert _traced_backward_route(shape, shape, jnp.bfloat16,
+                                  bias) == {"split_bwd": 1}
+    assert _traced_backward_route(shape, shape, jnp.bfloat16, bias,
+                                  want_dbias=False) == {"fused_bwd": 1}
+    main, names, grads = _attn_program("bshd", "padding", bias_grad=True)
+    _, calls = _engine_step(main, _attn_feed(names, "bshd"), grads)
+    assert calls == {"flash_attention_fwd": 1, "flash_attention_dq": 1,
+                     "flash_attention_dkv": 1}, calls

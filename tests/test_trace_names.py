@@ -89,7 +89,22 @@ def test_flash_forward_is_named(one_chip, no_compile_cache):
                          for h in heads), heads
 
 
-def test_flash_backward_kernels_are_named(one_chip, no_compile_cache):
+def _stems(heads):
+    return {h.rsplit(".", 1)[0] if h.rsplit(".", 1)[-1].isdigit() else h
+            for h in heads}
+
+
+@pytest.mark.parametrize("want_dbias,names", [
+    # the cell's attention: the fused backward, one kernel under the
+    # dk/dv kernel's name that holds the [4096, 128] dq of a (batch,
+    # head group) in VMEM; Mosaic takes it with its raised limit
+    (False, {"flash_attention_fwd", "flash_attention_dkv"}),
+    # a demanded dbias: the ds output follows the dq kernel's grid
+    (True, {"flash_attention_fwd", "flash_attention_dq",
+            "flash_attention_dkv"}),
+], ids=["fused", "dbias_split"])
+def test_flash_backward_kernels_are_named(one_chip, no_compile_cache,
+                                          want_dbias, names):
     fa = _flash_module()
 
     def fwd_bwd(q, k, v, bias, g):
@@ -99,15 +114,13 @@ def test_flash_backward_kernels_are_named(one_chip, no_compile_cache):
                                   causal=True)
         return fa._fa_backward(q, k, v, bias, out, lse, g, D ** -0.5,
                                BLOCK_Q, BLOCK_K, layout="bshd",
-                               lse_wide=True, want_dbias=False,
-                               causal=True)[:3]
+                               lse_wide=True, want_dbias=want_dbias,
+                               causal=True)
 
     heads = _custom_call_heads(
         _compiled_text(fwd_bwd, one_chip, QKV, QKV, QKV, BIAS, QKV))
-    stems = {h.rsplit(".", 1)[0] if h.rsplit(".", 1)[-1].isdigit() else h
-             for h in heads}
-    assert stems == {"flash_attention_fwd", "flash_attention_dq",
-                     "flash_attention_dkv"}, heads
+    assert _stems(heads) == names, heads
+    assert len(heads) == len(names), heads
 
 
 @pytest.mark.parametrize("kernel", ["fused_adam", "fused_sgd"])
@@ -165,7 +178,8 @@ def test_flash_kernels_compile_at_latent_attention_widths(one_chip,
                                                          no_compile_cache):
     """q/k 192 wide, v 128 (32 heads, B=1 S=4096, the kanana2_s4096
     cell's attention): two heads share a 384- and a 256-lane block, and
-    Mosaic takes the three kernels under their names."""
+    Mosaic takes the forward and the fused backward under their names,
+    the backward with 12 MiB of resident dq over its default limit."""
     fa = _flash_module()
     qk = ((1, S, 32, 192), jnp.bfloat16)
     v = ((1, S, 32, 128), jnp.bfloat16)
@@ -179,10 +193,10 @@ def test_flash_kernels_compile_at_latent_attention_widths(one_chip,
                                causal=True)[:3]
 
     text = _compiled_text(fwd_bwd, one_chip, qk, qk, v, v)
-    stems = {h.rsplit(".", 1)[0] if h.rsplit(".", 1)[-1].isdigit() else h
-             for h in _custom_call_heads(text)}
-    assert stems == {"flash_attention_fwd", "flash_attention_dq",
-                     "flash_attention_dkv"}, stems
+    heads = _custom_call_heads(text)
+    assert _stems(heads) == {"flash_attention_fwd",
+                             "flash_attention_dkv"}, heads
+    assert len(heads) == 2, heads
     # v is not padded to the q/k width: no 32 x 192 = 6144-wide v, out
     # or dv anywhere
     assert "bf16[1,4096,4096]" in text
