@@ -11,3 +11,4 @@ from .flash_attention import flash_attention  # noqa: F401
 from .fused_optimizer import bucket_sweep, fused_adam, fused_sgd  # noqa: F401,E501
 from .quantized_matmul import quantized_matmul  # noqa: F401
 from . import grouped_matmul  # noqa: F401
+from . import sparse_index  # noqa: F401

@@ -160,6 +160,10 @@ def _seq_len(x, layout):
     return x.shape[1] if layout == "bshd" else x.shape[2]
 
 
+def _heads(x, layout):
+    return x.shape[2] if layout == "bshd" else x.shape[1]
+
+
 def _heads_per_block(H, D, Dv=None):
     """bshd lane packing: how many heads share one lane block. Aims for
     128 lanes (the Mosaic minimum for a strict lane block); interpret
@@ -185,11 +189,20 @@ class _Plan:
     bshd: grid (B, Hg, i, j);  rows [B, S, H*D];    hpb=128//D heads
           per lane block (Hg = H // hpb)
     `order` maps the q/k sequence grid axes for the active kernel
-    (dq-style grids put q before kv; dkv-style grids swap them)."""
+    (dq-style grids put q before kv; dkv-style grids swap them).
 
-    def __init__(self, layout, B, H, Sq, Sk, D, bq, bk, Dv=None):
+    Grouped queries: k and v may have Hkv < H heads, query head g
+    reading key head g // (H // Hkv). The grid stays the query heads';
+    only the k / v index maps change (`row_spec(shared=True)`), so k and
+    v are never expanded. One head a lane block then (hpb == 1,
+    `_kernel_ok`)."""
+
+    def __init__(self, layout, B, H, Sq, Sk, D, bq, bk, Dv=None,
+                 Hkv=None):
         self.layout = layout
         self.B, self.H, self.Sq, self.Sk, self.D = B, H, Sq, Sk, D
+        # query heads that share one key / value head
+        self.group = H // (Hkv or H)
         # q and k are D wide, v (and so out, do, dv) Dv wide
         self.Dv = D if Dv is None else Dv
         self.bq, self.bk = bq, bk
@@ -204,7 +217,7 @@ class _Plan:
         """HBM view handed to pallas_call."""
         if self.layout == "bshd":
             B, S = x.shape[0], x.shape[1]
-            return x.reshape(B, S, self.H * x.shape[3])
+            return x.reshape(B, S, x.shape[2] * x.shape[3])
         B, H, S, D = x.shape
         return x.reshape(B * H, S, D)
 
@@ -227,22 +240,25 @@ class _Plan:
                     + pl.program_id(1) * self.hpb + i)
         return pl.program_id(0)
 
-    def row_spec(self, blk, width_per_head, which_axis, idx=None):
+    def row_spec(self, blk, width_per_head, which_axis, idx=None,
+                 shared=False):
         """Spec for a q/k/v/out/do/lse tensor: [blk rows x
         hpb*width_per_head lanes]. which_axis = grid position of the
         sequence index; idx (callable(g) -> index) overrides it — the
         causal path clamps the masked-out tail of a sequential axis to
         its last live block, so Mosaic sees a repeated block index and
-        elides the DMA for skipped steps."""
+        elides the DMA for skipped steps. shared: a k / v INPUT, whose
+        head is the query head's over `group`."""
         get = (lambda g: g[which_axis]) if idx is None else idx
+        per = self.group if shared else 1
         if self.layout == "bshd":
             def index_map(*g):
-                return (g[0], get(g), g[1])
+                return (g[0], get(g), g[1] // per if per > 1 else g[1])
             return pl.BlockSpec(
                 (None, blk, self.hpb * width_per_head), index_map)
 
         def index_map(*g):
-            return (g[0], get(g), 0)
+            return (g[0] // per if per > 1 else g[0], get(g), 0)
         return pl.BlockSpec((None, blk, width_per_head), index_map)
 
     def wide_shape(self, S):
@@ -304,12 +320,16 @@ class _Plan:
         return br, factory, per_head, per_q
 
     def bias_tile(self, bias_ref, i):
-        """Per-local-head [bqs, bk] f32 tile from the bias ref."""
+        """Per-local-head [bqs, bk] tile from the bias ref: f32 to add
+        to the scores, or (an integer bias is a keep MASK) bool."""
         if bias_ref is None:
             return None
-        if bias_ref.ndim == 3:          # packed per-head [hpb, bqs, bk]
-            return bias_ref[i].astype(jnp.float32)
-        return bias_ref[...].astype(jnp.float32)
+        # packed per-head [hpb, bqs, bk], else [bqs, bk]
+        tile = bias_ref[i] if bias_ref.ndim == 3 else bias_ref[...]
+        if _is_mask(tile):
+            # the target has no i8 vector compare: widen first
+            return tile.astype(jnp.int32) != 0
+        return tile.astype(jnp.float32)
 
     def ds_shape(self):
         if self.layout == "bshd":
@@ -350,6 +370,21 @@ class _Plan:
 # kernel bodies (shared by both layouts via the plan's lane slicing)
 # ---------------------------------------------------------------------------
 
+def _is_mask(bias):
+    """An integer (or bool) bias is a keep mask: scores where it is 0
+    are masked out. A float bias is added to the scores."""
+    return not jnp.issubdtype(bias.dtype, jnp.floating)
+
+
+def _biased(s, bt):
+    """Scores s under a bias tile from `_Plan.bias_tile` (or None)."""
+    if bt is None:
+        return s
+    if bt.dtype == jnp.bool_:
+        return jnp.where(bt, s, _NEG_INF)
+    return s + bt
+
+
 def _causal_mask(s, q_idx, kv_idx, bq, bk):
     """Mask s to the causal triangle (absolute positions; fully-visible
     blocks get an all-true compare, masked-out blocks never run)."""
@@ -388,9 +423,7 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [bq, bk]
-            bt = plan.bias_tile(bias_ref, i)
-            if bt is not None:
-                s = s + bt
+            s = _biased(s, plan.bias_tile(bias_ref, i))
             if causal:
                 s = _causal_mask(s, q_idx, kv_idx, bq, bk)
 
@@ -458,9 +491,7 @@ def _bwd_tile(plan, i, q_idx, kv_idx, bh, seed_ref, q_ref, k_ref, v_ref,
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    bt = plan.bias_tile(bias_ref, i)
-    if bt is not None:
-        s = s + bt
+    s = _biased(s, plan.bias_tile(bias_ref, i))
     if causal:
         s = _causal_mask(s, q_idx, kv_idx, bq, bk)
     p = jnp.exp(s - lse)                            # [bq, bk]
@@ -632,7 +663,7 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
     bk = min(block_k, Sk)
     assert Sq % bq == 0 and Sk % bk == 0, (Sq, Sk, bq, bk)
     n_kv = Sk // bk
-    plan = _Plan(layout, B, H, Sq, Sk, D, bq, bk, Dv)
+    plan = _Plan(layout, B, H, Sq, Sk, D, bq, bk, Dv, _heads(k, layout))
     def _sds(shape, dtype):
         return _out_struct(shape, dtype, like=q)
 
@@ -652,8 +683,8 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
 
     in_specs = [
         plan.row_spec(bq, D, qa),
-        plan.row_spec(bk, D, ka, idx=k_idx),
-        plan.row_spec(bk, Dv, ka, idx=k_idx),
+        plan.row_spec(bk, D, ka, idx=k_idx, shared=True),
+        plan.row_spec(bk, Dv, ka, idx=k_idx, shared=True),
     ]
     args = [plan.rows(q), plan.rows(k), plan.rows(v)]
     if bias is not None:
@@ -788,7 +819,7 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     bk = min(block_k, Sk)
     n_q = Sq // bq
     n_kv = Sk // bk
-    plan = _Plan(layout, B, H, Sq, Sk, D, bq, bk, Dv)
+    plan = _Plan(layout, B, H, Sq, Sk, D, bq, bk, Dv, _heads(k, layout))
     lse_w = lse if lse_wide else _widen(lse.astype(jnp.float32), plan)
     args = [plan.rows(q), plan.rows(k), plan.rows(v), lse_w,
             plan.rows(out), plan.rows(g)]
@@ -806,7 +837,8 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     has_drop = seed is not None
     if has_drop:
         args.append(seed)
-    want_dbias = has_bias and (want_dbias is None or bool(want_dbias))
+    want_dbias = has_bias and not _is_mask(bias) \
+        and (want_dbias is None or bool(want_dbias))
     resident = _resident_dq_bytes(plan, q.dtype)
     fused = not want_dbias and resident <= _FUSED_DQ_VMEM_BUDGET
     _kreg.count("flash_attention", "fused_bwd" if fused else "split_bwd")
@@ -827,8 +859,8 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
         positions qa / ka; q_idx / k_idx clamp a sequential axis."""
         specs = [
             plan.row_spec(bq, D, qa, idx=q_idx),
-            plan.row_spec(bk, D, ka, idx=k_idx),
-            plan.row_spec(bk, Dv, ka, idx=k_idx),
+            plan.row_spec(bk, D, ka, idx=k_idx, shared=True),
+            plan.row_spec(bk, Dv, ka, idx=k_idx, shared=True),
             plan.wide_spec(bq, qa, idx=q_idx),
             plan.row_spec(bq, Dv, qa, idx=q_idx),
             plan.row_spec(bq, Dv, qa, idx=q_idx),
@@ -962,7 +994,21 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     dk, dv = res[:2]
     if fused:
         dq = res[2]
-    return _unrows(dq, Sq), _unrows(dk, Sk), _unrows(dv, Sk, Dv), dbias
+    return (_unrows(dq, Sq), _group_sum(_unrows(dk, Sk), plan),
+            _group_sum(_unrows(dv, Sk, Dv), plan), dbias)
+
+
+def _group_sum(dx, plan):
+    """dk or dv as the kernels write it, one slice a QUERY head, summed
+    over the query heads that share each key / value head (float32
+    accumulation): [B, S, H, D] -> [B, S, Hkv, D] (bshd; bhsd alike)."""
+    if plan.group == 1:
+        return dx
+    axis = 2 if plan.layout == "bshd" else 1
+    split = dx.shape[:axis] + (plan.H // plan.group, plan.group) \
+        + dx.shape[axis + 1:]
+    return jnp.sum(dx.reshape(split).astype(jnp.float32),
+                   axis=axis + 1).astype(dx.dtype)
 
 
 def _kernel_ok(q, k, block_q, block_k, layout="bhsd", v=None):
@@ -972,13 +1018,19 @@ def _kernel_ok(q, k, block_q, block_k, layout="bhsd", v=None):
     Sq, Sk = _seq_len(q, layout), _seq_len(k, layout)
     D = q.shape[3]
     Dv = D if v is None else v.shape[3]
+    H, Hkv = _heads(q, layout), _heads(k, layout)
+    if H % Hkv:
+        return False
     if layout == "bshd":
-        H = q.shape[2]
         hpb = _heads_per_block(H, D, Dv)
         # real Mosaic requires strict 128-lane (or full-minor) blocks;
         # the interpreter does not care, which lets CPU tests cover
         # small shapes
         if not _INTERPRET and ((hpb * D) % 128 or (hpb * Dv) % 128):
+            return False
+        # grouped queries: a lane block is ONE head, so that a query
+        # head's k / v block is simply its key head's
+        if Hkv != H and hpb != 1:
             return False
     return (Sq % min(block_q, Sq) == 0 and Sk % min(block_k, Sk) == 0
             and D % 8 == 0 and Dv % 8 == 0
@@ -1044,10 +1096,15 @@ def _attn_reference(q, k, v, bias, scale, layout="bhsd",
     lower triangle in ABSOLUTE positions (rows >= cols), matching the
     kernels' block mask."""
     eq = "bqhd,bkhd->bhqk" if layout == "bshd" else "bhqd,bhkd->bhqk"
+    group = _heads(q, layout) // _heads(k, layout)
+    if group > 1:       # grouped queries: each key / value head `group` times
+        axis = 2 if layout == "bshd" else 1
+        k, v = (jnp.repeat(x, group, axis=axis) for x in (k, v))
     s = jnp.einsum(eq, q, k,
                    preferred_element_type=jnp.float32) * scale
     if bias is not None:
-        s = s + bias.astype(jnp.float32)
+        s = jnp.where(bias != 0, s, _NEG_INF) if _is_mask(bias) \
+            else s + bias.astype(jnp.float32)
     if causal:
         s = _causal_mask_dense(s)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)

@@ -491,6 +491,40 @@ def _gmm_case(which):
                 "moe_grouped_matmul/%s/f32/4x128x256" % which, run)
 
 
+def _sparse_index_case(which):
+    """A learned sparse attention's index at S=256 in 128-wide tiles:
+    the score kernel against the `jax.numpy` lowering (f32, "highest"),
+    and the selection kernel against `lax.top_k`'s threshold on the same
+    scores, which must agree in every pair."""
+    def run():
+        import jax
+        from . import sparse_index as si
+        r = _rng(29)
+        b, s, heads, dim = 2, 256, 4, 32
+        q = jnp.asarray(r.standard_normal((b, s, heads, dim),
+                                          dtype=np.float32))
+        k = jnp.asarray(r.standard_normal((b, s, dim), dtype=np.float32))
+        w = jnp.asarray(r.standard_normal((b, s, heads), dtype=np.float32))
+        with jax.default_matmul_precision("highest"):
+            ref = si.index_scores(q, k, w, False)
+            if which == "scores":
+                got = si.index_scores(q, k, w, True, tile=128)
+                live = np.isfinite(np.asarray(ref))
+                return {"metric": "rel_vs_lowered", "tol": 1e-5,
+                        "value": rel_err(np.where(live, ref, 0),
+                                         np.where(live, got, 0))
+                        + float((np.isfinite(np.asarray(got))
+                                 != live).sum())}
+        got, counted = si.select_mask(ref, 48, True, rows=32, chunk=128)
+        want, summed = si.select_mask(ref, 48, False)
+        return {"metric": "mask_mismatch", "tol": 0,
+                "value": int((np.asarray(got) != np.asarray(want)).sum()
+                             + (np.asarray(counted)
+                                != np.asarray(summed)).sum())}
+    return Case("sparse_index_scores",
+                "sparse_index_%s/f32/2x256x4x32" % which, run)
+
+
 # shapes the fused optimizer blocks over their own layout
 # (fused_optimizer._native_block): one whole block; N off the 128 lanes
 # in a whole-row block; N over whole-row width and off the 512-column
@@ -507,7 +541,7 @@ def cases() -> List[Case]:
     # completeness is judged
     import importlib
     from . import fused_optimizer, grouped_matmul  # noqa: F401
-    from . import quantized_matmul  # noqa: F401
+    from . import quantized_matmul, sparse_index  # noqa: F401
     importlib.import_module("paddle_tpu.kernels.flash_attention")
     return [
         _adam_case((4096,)),        # rank 1: the flat view
@@ -525,6 +559,8 @@ def cases() -> List[Case]:
         _gmm_case("fwd"),
         _gmm_case("dx"),
         _gmm_case("dw"),
+        _sparse_index_case("scores"),
+        _sparse_index_case("select"),
     ]
 
 

@@ -1,7 +1,8 @@
 """Layers of a current decoder-only language model block (ops in
 ops/decoder.py): RMS normalisation, rotary positions, the gated
-feed-forward's activation, the sigmoid top-k router, and the expert
-layer that is told which experts this chip holds."""
+feed-forward's activation, the top-k router, the expert layer that is
+told which experts this chip holds, and a learned sparse attention's
+index."""
 from __future__ import annotations
 
 from ..initializer import Constant, Normal
@@ -9,7 +10,7 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
 __all__ = ["rms_norm", "rotary_embedding", "swiglu", "moe_router",
-           "moe_experts"]
+           "moe_experts", "sparse_attention_index"]
 
 
 def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
@@ -25,17 +26,19 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
 
 
 def rotary_embedding(input, theta=10000.0, rotary_dim=None,
-                     position_offset=0, name=None):
+                     position_offset=0, interleaved=True, name=None):
     """Rotary positions on input [B, S, H, D]: the trailing `rotary_dim`
-    channels of every head (all of them by default), adjacent pairs
-    rotated by pos * theta^(-2i / rotary_dim)."""
+    channels of every head (all of them by default), pair i rotated by
+    pos * theta^(-2i / rotary_dim). The pairs are adjacent channels, or
+    with `interleaved=False` channel i and channel i + rotary_dim / 2."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(input.dtype)
     helper.append_op("rotary_embedding", inputs={"X": input},
                      outputs={"Out": out},
                      attrs={"theta": float(theta),
                             "rotary_dim": int(rotary_dim or 0),
-                            "position_offset": int(position_offset)})
+                            "position_offset": int(position_offset),
+                            "interleaved": bool(interleaved)})
     return out
 
 
@@ -52,7 +55,8 @@ def moe_router(input, num_experts, top_k, experts_held=None, first_expert=0,
                scoring_func="sigmoid", norm_topk_prob=True,
                routed_scaling_factor=1.0, n_group=1, topk_group=1,
                param_attr=None, bias_attr=None, name=None):
-    """Sigmoid top-k router over `num_experts` (float32 under AMP).
+    """Top-k router over `num_experts`, scoring by `scoring_func`
+    ("sigmoid" or "softmax" over all experts; float32 under AMP).
     Returns (choice int32 [T, top_k], weight float32 [T, top_k], count
     of tokens for each of the `experts_held` experts from
     `first_expert`). `bias_attr` names the selection-only score
@@ -112,3 +116,22 @@ def moe_experts(input, choice, weight, num_experts, expert_width,
         attrs={"num_experts": int(num_experts), "experts_held": held,
                "first_expert": int(first_expert)})
     return out
+
+
+def sparse_attention_index(index_q, index_k, index_w, top_k, scale=1.0,
+                           name=None):
+    """The keep mask of a learned sparse attention from its indexer's
+    projections: index_q [B, S, heads, d], index_k [B, S, d], index_w
+    [B, S, heads]. Query t keeps the `top_k` keys s <= t of largest
+    sum_j scale * w[t, j] * relu(q[t, j] . k[s]) (all of them while
+    t < top_k). Returns (mask int8 [B, 1, S, S] for `fused_attention`'s
+    bias, pairs kept int32 [1]); neither takes a gradient."""
+    helper = LayerHelper("sparse_attention_index", name=name)
+    mask = helper.create_variable_for_type_inference("int8", True)
+    kept = helper.create_variable_for_type_inference("int32", True)
+    helper.append_op(
+        "sparse_attention_index",
+        inputs={"IndexQ": index_q, "IndexK": index_k, "IndexW": index_w},
+        outputs={"Mask": mask, "Kept": kept},
+        attrs={"top_k": int(top_k), "scale": float(scale)})
+    return mask, kept

@@ -946,7 +946,9 @@ def fused_attention(q, k, v, bias=None, scale=None, block_q=None,
     (paddle_tpu/kernels/flash_attention.py). q/k/v: [B, H, S, D]
     (layout="bhsd") or [B, S, H, D] (layout="bshd" — the free-reshape
     layout of a [B, S, H*D] projection, no head transposes);
-    bias: [B, 1|H, Sq|1, Sk] additive mask or None in either layout.
+    bias: [B, 1|H, Sq|1, Sk] additive mask (float), keep mask (integer:
+    0 masks the score out) or None in either layout. k and v may have
+    fewer heads than q: query head g reads key head g // (H / Hkv).
     causal=True masks rows >= cols IN the op (kernels skip fully-
     masked KV blocks) — pass a padding-only bias alongside instead of
     baking an O(S^2) causal bias feed."""

@@ -1,9 +1,30 @@
-"""Causal decoder-only language model: multi-head latent attention and a
-mixture of sigmoid-routed experts with shared experts (the block of the
-`deepseek_v3` model type), built with paddle_tpu.layers.
+"""Causal decoder-only language model with a mixture of routed experts,
+built with paddle_tpu.layers. ONE model file: which attention, which
+router and which feed-forwards a layer gets follow from the published
+`config.json` keys the configuration is given, not from a model's name.
 
-The configuration takes the published `config.json` keys as they are,
-plus three that say which share of each layer THIS chip holds under
+  attention   `kv_lora_rank` -> multi-head latent attention (the
+      `deepseek_v3` block: q/k of nope + rope channels, v of its own
+      width, one shared rope key); else grouped-query attention with
+      `num_key_value_heads` key / value heads of `head_dim`, q and k
+      normalised per head (RMS) before the rotary, and, with
+      `sa_config`, a learned sparse attention: an indexer picks the
+      `topk` keys each query attends (`sparse_attention_index`).
+  rotary      adjacent pairs with `rope_interleave` (latent attention's
+      default), half-split pairs (channel i with i + d/2) without; a
+      `rope_scaling` of type
+      "default" is plain rotary (text: the sections of a multimodal
+      rotary all carry the same position).
+  experts     `n_routed_experts` (that key family scores by
+      `scoring_func`, sigmoid with a selection-only bias unless given)
+      or `num_experts` (that family scores by softmax, no bias);
+      `n_shared_experts` shared SwiGLU experts beside them (0: none).
+  dense layers  the first `first_k_dense_replace`, those listed in
+      `mlp_only_layers`, and with `decoder_sparse_step` n every layer
+      whose number (from 1) is no multiple of n: a dense SwiGLU of
+      `intermediate_size` in place of the experts.
+
+Three more keys say which share of each layer THIS chip holds under
 expert and vocabulary parallelism:
 
   experts_held, first_expert   the routed experts whose weights live
@@ -14,21 +35,22 @@ expert and vocabulary parallelism:
   vocab_held   rows of the embedding and of the head held here (default:
       all); ids, logits and the loss are over that slice.
 
-Every layer: h += Attn(RMSNorm(h)); h += FFN(RMSNorm(h)). The first
-`first_k_dense_replace` layers have a dense SwiGLU feed-forward of
-`intermediate_size`, the rest `n_routed_experts` routed SwiGLU experts
-of `moe_intermediate_size` (top `num_experts_per_tok`) plus one shared
-SwiGLU of `n_shared_experts * moe_intermediate_size`.
+Every layer: h += Attn(RMSNorm(h)); h += FFN(RMSNorm(h)).
 
 Published keys that say nothing about the shapes built here
-(`max_position_embeddings`, `model_type`, `head_dim`, ...) are accepted
-and ignored, so a `config.json` can be passed whole.
+(`max_position_embeddings`, `model_type`, ...) are accepted and ignored,
+so a `config.json` can be passed whole.
 
 Every parameter has an explicit, stable name (`layer_<i>_attn_q.w_0`,
-`layer_<i>_experts_gate.w_0`, ...). The persistable int32
-`moe_expert_load` [MoE layers, experts held] accumulates, inside the
-step, the tokens the router sent to each held expert
-(observability/moe.py reads it).
+`layer_<i>_experts_gate.w_0`, ...). The indexer's weights
+(`layer_<i>_attn_index_*`) are buffers: not trainable, and the indexer
+reads the layer's input with no gradient (its own alignment loss is a
+training recipe's, not the language model's). Two persistable int32
+counters are written inside the step: `moe_expert_load` [MoE layers,
+experts held] ACCUMULATES the tokens the router sent to each held
+expert (observability/moe.py reads it); `sparse_attn_kept` [layers] is
+OVERWRITTEN with the (query, key) pairs each layer's selection kept
+(observability/sparse_attention.py).
 """
 from __future__ import annotations
 
@@ -36,36 +58,42 @@ from .. import layers
 from ..framework import name_scope
 from ..initializer import Constant, Normal
 from ..observability.moe import EXPERT_LOAD_VAR
+from ..observability.sparse_attention import KEPT_PAIRS_VAR
 from ..param_attr import ParamAttr
 
 
 class DecoderLMConfig:
     def __init__(self, vocab_size=32000, hidden_size=2048,
                  num_hidden_layers=4, num_attention_heads=32,
-                 kv_lora_rank=512, q_lora_rank=None, qk_nope_head_dim=128,
-                 qk_rope_head_dim=64, v_head_dim=128, rope_theta=10000.0,
-                 rope_interleave=True, rope_scaling=None,
-                 rms_norm_eps=1e-6, intermediate_size=6144,
-                 first_k_dense_replace=1, moe_layer_freq=1,
-                 n_routed_experts=128, num_experts_per_tok=6,
-                 n_shared_experts=2, moe_intermediate_size=768,
-                 routed_scaling_factor=1.0, norm_topk_prob=True,
-                 scoring_func="sigmoid", topk_method="noaux_tc", n_group=1,
-                 topk_group=1, hidden_act="silu", attention_bias=False,
+                 kv_lora_rank=None, q_lora_rank=None, qk_nope_head_dim=128,
+                 qk_rope_head_dim=64, v_head_dim=128,
+                 num_key_value_heads=None, head_dim=None, sa_config=None,
+                 rope_theta=10000.0, rope_interleave=None,
+                 rope_scaling=None, rms_norm_eps=1e-6,
+                 intermediate_size=6144, first_k_dense_replace=0,
+                 mlp_only_layers=(), decoder_sparse_step=1,
+                 moe_layer_freq=1, n_routed_experts=None, num_experts=None,
+                 num_experts_per_tok=6, n_shared_experts=0,
+                 moe_intermediate_size=768, routed_scaling_factor=1.0,
+                 norm_topk_prob=True, scoring_func=None,
+                 topk_method="noaux_tc", n_group=1, topk_group=1,
+                 hidden_act="silu", attention_bias=False,
                  tie_word_embeddings=False, initializer_range=0.02,
                  experts_held=None, first_expert=0, vocab_held=None,
                  **unused):
         if q_lora_rank is not None:
             raise NotImplementedError("query compression (q_lora_rank)")
-        if rope_scaling is not None:
-            raise NotImplementedError("rope scaling")
-        if not rope_interleave:
-            raise NotImplementedError("half-split rotary pairs")
+        scaling = rope_scaling or {}
+        scaling = scaling.get("rope_type", scaling.get("type", "default"))
+        if scaling != "default":
+            raise NotImplementedError(f"rope scaling {scaling!r}")
         if hidden_act != "silu" or attention_bias or tie_word_embeddings:
             raise NotImplementedError(
                 "silu, no attention bias, untied head only")
         if moe_layer_freq != 1:
             raise NotImplementedError("moe_layer_freq other than 1")
+        if (n_routed_experts is None) == (num_experts is None):
+            raise ValueError("one of n_routed_experts and num_experts")
         self.vocab_size = int(vocab_held or vocab_size)
         self.hidden_size = hidden_size
         self.num_hidden_layers = num_hidden_layers
@@ -75,27 +103,44 @@ class DecoderLMConfig:
         self.qk_rope_head_dim = qk_rope_head_dim
         self.qk_head_dim = qk_nope_head_dim + qk_rope_head_dim
         self.v_head_dim = v_head_dim
+        self.num_key_value_heads = num_key_value_heads \
+            or num_attention_heads
+        self.head_dim = head_dim or hidden_size // num_attention_heads
+        self.sa_config = dict(sa_config) if sa_config else None
+        if self.sa_config and self.sa_config["indexer_num_kv_heads"] != 1:
+            raise NotImplementedError("one index key head")
         self.rope_theta = float(rope_theta)
+        # absent: the latent block's own default (adjacent pairs), the
+        # half-split pairs of every other decoder
+        self.rope_interleave = bool(kv_lora_rank) \
+            if rope_interleave is None else bool(rope_interleave)
         self.rms_norm_eps = rms_norm_eps
         self.intermediate_size = intermediate_size
-        self.first_k_dense_replace = first_k_dense_replace
-        self.n_routed_experts = n_routed_experts
+        self.dense_layers = {
+            i for i in range(num_hidden_layers)
+            if i < first_k_dense_replace or i in set(mlp_only_layers)
+            or (i + 1) % decoder_sparse_step}
+        self.n_routed_experts = n_routed_experts or num_experts
         self.num_experts_per_tok = num_experts_per_tok
         self.n_shared_experts = n_shared_experts
         self.moe_intermediate_size = moe_intermediate_size
         self.routed_scaling_factor = routed_scaling_factor
         self.norm_topk_prob = norm_topk_prob
-        self.scoring_func = scoring_func
-        self.topk_method = topk_method
+        # the two key families' own modelling code: sigmoid scores with
+        # a selection-only correction bias, or softmax over all experts
+        self.scoring_func = scoring_func or (
+            "sigmoid" if n_routed_experts else "softmax")
+        self.router_bias = bool(n_routed_experts) \
+            and topk_method == "noaux_tc"
         self.n_group, self.topk_group = n_group, topk_group
         self.initializer_range = initializer_range
-        self.experts_held = int(experts_held or n_routed_experts)
+        self.experts_held = int(experts_held or self.n_routed_experts)
         self.first_expert = int(first_expert)
 
     @property
     def moe_layers(self):
         return [i for i in range(self.num_hidden_layers)
-                if i >= self.first_k_dense_replace]
+                if i not in self.dense_layers]
 
 
 def _w(name, cfg):
@@ -125,7 +170,8 @@ def latent_attention(x, cfg, name):
                       cfg.v_head_dim)
     q = _linear(x, h * (nope + rope), name + "_q", cfg)
     q = layers.reshape(q, [0, 0, h, nope + rope])
-    q = layers.rotary_embedding(q, theta=cfg.rope_theta, rotary_dim=rope)
+    q = layers.rotary_embedding(q, theta=cfg.rope_theta, rotary_dim=rope,
+                                interleaved=cfg.rope_interleave)
     ckv = _linear(x, cfg.kv_lora_rank + rope, name + "_kva", cfg)
     c, k_rope = layers.split(ckv, [cfg.kv_lora_rank, rope], dim=-1)
     c = _norm(c, name + "_kv_norm", cfg)
@@ -133,7 +179,8 @@ def latent_attention(x, cfg, name):
     kv = layers.reshape(kv, [0, 0, h, nope + dv])
     k_nope, v = layers.split(kv, [nope, dv], dim=-1)
     k_rope = layers.rotary_embedding(
-        layers.reshape(k_rope, [0, 0, 1, rope]), theta=cfg.rope_theta)
+        layers.reshape(k_rope, [0, 0, 1, rope]), theta=cfg.rope_theta,
+        interleaved=cfg.rope_interleave)
     k = layers.concat([k_nope, layers.expand(k_rope, [1, 1, h, 1])],
                       axis=3)
     ctx = layers.fused_attention(q, k, v, None,
@@ -143,6 +190,72 @@ def latent_attention(x, cfg, name):
     return _linear(ctx, cfg.hidden_size, name + "_o", cfg)
 
 
+def _buffer(name, cfg, init=None):
+    """A weight the optimizer never sees: drawn once, then held."""
+    return ParamAttr(name=name, trainable=False,
+                     initializer=init or Normal(0.0, cfg.initializer_range))
+
+
+def sparse_index(x, cfg, name):
+    """The learned sparse attention's indexer on the layer's normalised
+    input: `indexer_num_heads` index queries and ONE index key of
+    `indexer_head_dim` a token (the key layer-normalised, both rotated),
+    a weight a head, and from them the keep mask of the `topk` keys each
+    query attends. Returns (mask int8 [B, 1, S, S], pairs kept int32
+    [1]). Every weight is a buffer and nothing here takes a gradient."""
+    sa = cfg.sa_config
+    heads, dim = sa["indexer_num_heads"], sa["indexer_head_dim"]
+
+    def project(width, part):
+        return layers.fc(x, width, num_flatten_dims=2, bias_attr=False,
+                         param_attr=_buffer(f"{name}_{part}.w_0", cfg))
+
+    def rotate(t):
+        return layers.rotary_embedding(t, theta=cfg.rope_theta,
+                                       interleaved=cfg.rope_interleave)
+
+    q = rotate(layers.reshape(project(heads * dim, "q"), [0, 0, heads, dim]))
+    k = layers.layer_norm(
+        project(dim, "k"), begin_norm_axis=2, epsilon=1e-6,
+        param_attr=_buffer(name + "_k_norm.w_0", cfg, Constant(1.0)),
+        bias_attr=_buffer(name + "_k_norm.b_0", cfg, Constant(0.0)))
+    k = layers.reshape(rotate(layers.reshape(k, [0, 0, 1, dim])),
+                       [0, 0, dim])
+    return layers.sparse_attention_index(
+        q, k, project(heads, "w"), sa["topk"],
+        scale=heads ** -0.5 * dim ** -0.5)
+
+
+def grouped_query_attention(x, cfg, name):
+    """Causal attention of `num_attention_heads` query heads over
+    `num_key_value_heads` key / value heads of `head_dim` (k and v go to
+    the op at their own head count), q and k RMS-normalised per head and
+    then rotated; with `sa_config` over the keys the indexer keeps.
+    Returns (output, pairs kept int32 [1] or None)."""
+    h, hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
+        cfg.head_dim
+
+    def heads(part, n, normed):
+        t = layers.reshape(_linear(x, n * d, f"{name}_{part}", cfg),
+                           [0, 0, n, d])
+        if not normed:
+            return t
+        t = _norm(t, f"{name}_{part}_norm", cfg)
+        return layers.rotary_embedding(t, theta=cfg.rope_theta,
+                                       interleaved=cfg.rope_interleave)
+
+    q, k, v = heads("q", h, True), heads("k", hkv, True), \
+        heads("v", hkv, False)
+    mask = kept = None
+    if cfg.sa_config:
+        with name_scope("index"):
+            mask, kept = sparse_index(x, cfg, name + "_index")
+    ctx = layers.fused_attention(q, k, v, mask, scale=d ** -0.5,
+                                 layout="bshd", causal=True)
+    ctx = layers.reshape(ctx, [0, 0, h * d])
+    return _linear(ctx, cfg.hidden_size, name + "_o", cfg), kept
+
+
 def gated_ffn(x, width, cfg, name):
     hidden = layers.swiglu(_linear(x, width, name + "_gate", cfg),
                            _linear(x, width, name + "_up", cfg))
@@ -150,8 +263,9 @@ def gated_ffn(x, width, cfg, name):
 
 
 def moe_ffn(x, cfg, name):
-    """Routed experts held here plus the shared expert. Returns (output,
-    the router's count of tokens per held expert)."""
+    """Routed experts held here plus the shared expert where there is
+    one. Returns (output, the router's count of tokens per held
+    expert)."""
     choice, weight, counts = layers.moe_router(
         x, cfg.n_routed_experts, cfg.num_experts_per_tok,
         experts_held=cfg.experts_held, first_expert=cfg.first_expert,
@@ -160,7 +274,8 @@ def moe_ffn(x, cfg, name):
         n_group=cfg.n_group, topk_group=cfg.topk_group,
         param_attr=_w(name + "_router.w_0", cfg),
         bias_attr=ParamAttr(name=name + "_router.b_0",
-                            initializer=Constant(0.0)))
+                            initializer=Constant(0.0))
+        if cfg.router_bias else False)
     routed = layers.moe_experts(
         x, choice, weight, cfg.n_routed_experts,
         cfg.moe_intermediate_size, experts_held=cfg.experts_held,
@@ -189,15 +304,20 @@ def decoder_lm_train(cfg: DecoderLMConfig):
         h = layers.embedding(
             ids, size=[cfg.vocab_size, cfg.hidden_size],
             param_attr=_w("embed_tokens.w_0", cfg))
-    counts = []
+    counts, kept = [], []
     for i in range(cfg.num_hidden_layers):
         p = f"layer_{i}"
         with name_scope(p):
             with name_scope("attn"):
-                attn = latent_attention(_norm(h, p + "_attn_norm", cfg),
-                                        cfg, p + "_attn")
+                x = _norm(h, p + "_attn_norm", cfg)
+                if cfg.kv_lora_rank:
+                    attn = latent_attention(x, cfg, p + "_attn")
+                else:
+                    attn, n = grouped_query_attention(x, cfg, p + "_attn")
+                    if n is not None:
+                        kept.append(n)
                 h = layers.elementwise_add(h, attn)
-            if i < cfg.first_k_dense_replace:
+            if i in cfg.dense_layers:
                 with name_scope("mlp"):
                     ffn = gated_ffn(_norm(h, p + "_ffn_norm", cfg),
                                     cfg.intermediate_size, cfg, p + "_mlp")
@@ -213,6 +333,14 @@ def decoder_lm_train(cfg: DecoderLMConfig):
                 [len(counts), cfg.experts_held], 0, "int32",
                 persistable=True, name=EXPERT_LOAD_VAR)
             layers.sums([load, layers.stack(counts, axis=0)], out=load)
+    if kept:
+        # overwritten, not added to: 14.7 M pairs a layer a step at 8,192
+        # tokens would overflow an accumulating int32 within minutes
+        with name_scope("sparse_attn_kept"):
+            layers.assign(layers.concat(kept, axis=0),
+                          output=layers.create_global_var(
+                              [len(kept)], 0, "int32", persistable=True,
+                              name=KEPT_PAIRS_VAR))
     with name_scope("head"):
         logits = _linear(_norm(h, "final_norm", cfg), cfg.vocab_size,
                          "lm_head", cfg)

@@ -21,7 +21,9 @@ Three layers (docs/OBSERVABILITY.md):
   OOM/pressure postmortem dumps, and the leak sentinel
   (docs/MEMORY.md);
 * :mod:`.moe` — reader of the `moe_expert_load` counter a
-  mixture-of-experts step keeps (docs/TRACING.md).
+  mixture-of-experts step keeps (docs/TRACING.md);
+* :mod:`.sparse_attention` — reader of the `sparse_attn_kept` counter a
+  learned-sparse-attention step overwrites (docs/TRACING.md).
 
 Hot-path contract: one boolean (``metrics._HOT[0]``) gates all
 per-step telemetry work. The step's own profiler spans and clock stamps
@@ -30,7 +32,7 @@ feed a profiler session, the slow-step detector and, while ``_HOT``,
 this layer's record.
 """
 from . import metrics, recorder, export, tracing, attribution, \
-    memory, moe  # noqa: F401
+    memory, moe, sparse_attention  # noqa: F401
 from .metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, EngineCounters,
     default_registry, counter, gauge, histogram,

@@ -1,7 +1,8 @@
 """Ops of a current decoder-only language model block: RMS
 normalisation, rotary positions, the gated (SwiGLU) feed-forward's
-activation, the sigmoid top-k router and the expert layer of a mixture
-of experts of which this chip holds a share.
+activation, the top-k router (sigmoid or softmax scores), the expert
+layer of a mixture of experts of which this chip holds a share, and the
+index of a learned sparse attention: which keys each query attends.
 
 The expert layer is dropless and knows which experts it holds:
 `moe_experts` gathers the rows routed to experts `first_expert ..
@@ -16,7 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from ..core.amp import amp_cast
-from ..core.registry import register_op, override_grad_lowering
+from ..core.registry import (register_op, register_no_grad_op,
+                             override_grad_lowering)
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -36,38 +38,48 @@ def rms_norm(ctx):
     ctx.set_output("Y", y.astype(x.dtype))
 
 
-def _rotate_pairs(x, theta, offset):
-    """Rotate the adjacent pairs (x[2i], x[2i+1]) of the last axis of
-    x [B, S, H, D] by the angle pos * theta^(-2i/D), pos = offset + s."""
+def _rotate_pairs(x, theta, offset, interleaved):
+    """Rotate pair i of the last axis of x [B, S, H, D] by the angle
+    pos * theta^(-2i/D), pos = offset + s. The pairs are the adjacent
+    channels (x[2i], x[2i+1]) when `interleaved`, else the half-split
+    ones (x[i], x[i + D/2])."""
     d = x.shape[-1]
     pos = jnp.arange(x.shape[1], dtype=_F32) + offset
     freq = theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
     angle = pos[:, None] * freq[None, :]                    # [S, D/2]
     cos = jnp.cos(angle)[None, :, None, :]
     sin = jnp.sin(angle)[None, :, None, :]
-    pairs = x.astype(_F32).reshape(x.shape[:-1] + (d // 2, 2))
-    a, b = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    xf = x.astype(_F32)
+    if interleaved:
+        pairs = xf.reshape(x.shape[:-1] + (d // 2, 2))
+        a, b = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    else:
+        a, b = xf[..., :d // 2], xf[..., d // 2:]
+        out = jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
     return out.reshape(x.shape).astype(x.dtype)
 
 
 @register_op("rotary_embedding")
 def rotary_embedding(ctx):
     """Rotary positions on the trailing `rotary_dim` channels of
-    X [B, S, H, D] (all of D when 0), interleaved: the pairs are adjacent
-    channels. attrs: theta, rotary_dim, position_offset."""
+    X [B, S, H, D] (all of D when 0). attrs: theta, rotary_dim,
+    position_offset, interleaved (the pairs are adjacent channels; False:
+    channel i pairs with channel i + rotary_dim/2)."""
     x = ctx.input("X")
     theta = float(ctx.attr("theta", 10000.0))
     n = int(ctx.attr("rotary_dim", 0) or 0) or x.shape[-1]
     offset = float(ctx.attr("position_offset", 0))
+    interleaved = bool(ctx.attr("interleaved", True))
     if n % 2 or n > x.shape[-1]:
         raise ValueError(f"rotary_dim {n} of a head of {x.shape[-1]}")
     keep = x.shape[-1] - n
     if keep == 0:
-        ctx.set_output("Out", _rotate_pairs(x, theta, offset))
+        ctx.set_output("Out", _rotate_pairs(x, theta, offset, interleaved))
     else:
         ctx.set_output("Out", jnp.concatenate(
-            [x[..., :keep], _rotate_pairs(x[..., keep:], theta, offset)],
+            [x[..., :keep],
+             _rotate_pairs(x[..., keep:], theta, offset, interleaved)],
             axis=-1))
 
 
@@ -83,22 +95,28 @@ def swiglu(ctx):
 
 # ---------------------------------------------------------------- router
 
-def _router_scores(x, w):
-    """sigmoid(float32(x) . w^T), the matrix product in float32 on
-    every backend."""
+_SCORING = {"sigmoid": jax.nn.sigmoid,
+            "softmax": lambda logits: jax.nn.softmax(logits, axis=-1)}
+
+
+def _router_scores(x, w, scoring):
+    """scoring(float32(x) . w^T) over all experts, the matrix product in
+    float32 on every backend."""
     logits = jnp.einsum("td,ed->te", x.astype(_F32), w.astype(_F32),
                         precision=_HIGHEST)
-    return jax.nn.sigmoid(logits)
+    return _SCORING[scoring](logits)
 
 
 def _router(ctx, x, w, bias):
     k = int(ctx.attr("top_k", 1))
-    if ctx.attr("scoring_func", "sigmoid") != "sigmoid":
-        raise NotImplementedError("moe_router scores by sigmoid")
+    scoring = ctx.attr("scoring_func", "sigmoid")
+    if scoring not in _SCORING:
+        raise NotImplementedError(
+            f"moe_router scores by {sorted(_SCORING)}, not {scoring!r}")
     if int(ctx.attr("n_group", 1)) != 1 or \
             int(ctx.attr("topk_group", 1)) != 1:
         raise NotImplementedError("moe_router selects within one group")
-    s = _router_scores(x, w)
+    s = _router_scores(x, w, scoring)
     pick = s if bias is None else s + bias.astype(_F32)[None, :]
     _, choice = jax.lax.top_k(jax.lax.stop_gradient(pick), k)
     weight = jnp.take_along_axis(s, choice, axis=-1)
@@ -110,10 +128,11 @@ def _router(ctx, x, w, bias):
 
 @register_op("moe_router", no_grad_slots=("Bias",))
 def moe_router(ctx):
-    """X [T, D], Weight [num_experts, D], Bias [num_experts] (the
-    selection-only score correction; takes no gradient).
-    TopkIdx int32 [T, top_k]: the top_k of sigmoid(x.w^T) + bias over
-    all experts; TopkWeight float32 [T, top_k]: the chosen scores
+    """X [T, D], Weight [num_experts, D], optional Bias [num_experts]
+    (the selection-only score correction; takes no gradient).
+    TopkIdx int32 [T, top_k]: the top_k of s + bias over all experts,
+    s = sigmoid(x.w^T) or softmax(x.w^T) (attr scoring_func);
+    TopkWeight float32 [T, top_k]: the chosen scores
     WITHOUT the bias, normalised to sum 1 (norm_topk_prob) and scaled
     by routed_scaling_factor; Counts int32 [experts_held]: tokens routed
     to each expert held here (first_expert ..). float32 whatever AMP
@@ -283,3 +302,26 @@ def moe_experts_grad(ctx):
         if names and names[0]:
             primal = ctx.env[op.input(slot)[0]]
             ctx.env[names[0]] = grad.astype(primal.dtype)
+
+
+# ------------------------------------------------ sparse attention's index
+
+@register_no_grad_op("sparse_attention_index")
+def sparse_attention_index(ctx):
+    """Which keys each query of a learned sparse attention attends.
+    IndexQ [B, S, heads, d], IndexK [B, S, d] (one index key head),
+    IndexW [B, S, heads]; attrs top_k, scale (on IndexW).
+    I[t, s] = sum_j scale * w[t, j] * relu(q[t, j] . k[s]), float32 from
+    products in the inputs' type. Mask int8 [B, 1, S, S]: 1 where s <= t
+    and I[t, s] is among the top_k largest of I[t, :t + 1] (every key
+    while t < top_k; ties at the threshold all kept), the keep mask
+    `fused_attention` takes as BiasQK. Kept int32 [1]: the pairs kept.
+    No gradient: the selection is piecewise constant."""
+    from ..kernels import sparse_index
+    q, k = ctx.input("IndexQ"), ctx.input("IndexK")
+    w = ctx.input("IndexW").astype(_F32) * float(ctx.attr("scale", 1.0))
+    k = k.astype(q.dtype)
+    keep, kept = sparse_index.index_mask(
+        q, k, w, int(ctx.attr("top_k")), sparse_index.use_kernels(q, k))
+    ctx.set_output("Mask", keep[:, None])
+    ctx.set_output("Kept", kept)

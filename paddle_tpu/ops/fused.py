@@ -76,8 +76,13 @@ def _rows(x, layout):
 
 @register_op("fused_attention", intermediate_outputs=("SoftmaxLse",))
 def fused_attention(ctx):
-    """Q/K/V: [B, H, S, D] (layout "bhsd") or [B, S, H, D] ("bshd");
-    optional BiasQK [B, 1|H, Sq|1, Sk] additive.
+    """Q/K/V: [B, H, S, D] (layout "bhsd") or [B, S, H, D] ("bshd"); K
+    and V may have fewer heads than Q (grouped queries: query head g
+    reads key head g // (H / Hkv); dK and dV leave at K's and V's own
+    head count, and the kernels never expand K or V);
+    optional BiasQK [B, 1|H, Sq|1, Sk]: additive where float, a keep
+    MASK where integer (scores where it is 0 are masked out; it takes
+    no gradient; `sparse_attention_index` makes one inside the step).
     attrs: scale (default d^-0.5), block_q, block_k, layout,
     dropout_prob (attention-weights dropout, reference
     dist_transformer.py:1043-1044 — applied in BOTH regimes; the Pallas

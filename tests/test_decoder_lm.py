@@ -1,10 +1,13 @@
 """The decoder-only language model's ops, kernels and model file: RMS
-normalisation, rotary positions, the gated activation, the sigmoid top-k
-router, the dropless expert layer that holds a share of the experts
-(lowered and, under the Pallas interpreter, through the grouped-matmul
-kernels), the flash kernels with q/k and v of different widths, and the
-whole model through `Executor.run` against the benchmark's plain
-reference (benchmark/families/mla_moe_decoder_reference.py)."""
+normalisation, rotary positions (adjacent and half-split pairs), the
+gated activation, the top-k router (sigmoid and softmax), the dropless
+expert layer that holds a share of the experts (lowered and, under the
+Pallas interpreter, through the grouped-matmul kernels), a learned sparse
+attention's index (scores and exact top-k selection, lowered and through
+its kernels), the flash kernels with q/k and v of different widths, with
+fewer key heads than query heads and with a keep mask, and both whole
+models the file builds through `Executor.run` against the benchmark's
+plain references (benchmark/families/*_reference.py)."""
 import importlib
 
 import numpy as np
@@ -19,8 +22,11 @@ from paddle_tpu.core.scope import Scope
 from paddle_tpu.kernels import grouped_matmul as gm
 from paddle_tpu.kernels import registry as kreg
 
+from benchmark.families import gqa_dsa_moe_decoder as gqa_family
+from benchmark.families import gqa_dsa_moe_decoder_reference as gqa_ref
 from benchmark.families import mla_moe_decoder as family
 from benchmark.families import mla_moe_decoder_reference as ref
+from paddle_tpu.kernels import sparse_index
 
 fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
 
@@ -97,27 +103,36 @@ def test_rms_norm_forward_and_grad():
     _close(dw, gw)
 
 
+@pytest.mark.parametrize("interleaved", [True, False],
+                         ids=["adjacent_pairs", "half_split_pairs"])
 @pytest.mark.parametrize("rotary_dim", [None, 8])
-def test_rotary_embedding_forward_and_grad(rotary_dim):
+def test_rotary_embedding_forward_and_grad(rotary_dim, interleaved):
     x, cot = _r((2, 6, 3, 24), 3), _r((2, 6, 3, 24), 4)
     prog = _run(lambda x: layers.rotary_embedding(
-        x, theta=1e6, rotary_dim=rotary_dim), {"x": x}, ["x"])
+        x, theta=1e6, rotary_dim=rotary_dim, interleaved=interleaved),
+        {"x": x}, ["x"])
     out, (dx,) = _fetch(*prog[:3], {"x": x}, *prog[3:], cot)
     keep = 24 - (rotary_dim or 24)
+    rope = ref._rope if interleaved else gqa_ref._rope
 
     def f(x):
         return jnp.concatenate(
-            [x[..., :keep], ref._rope(x[..., keep:], 1e6)], -1)
+            [x[..., :keep], rope(x[..., keep:], 1e6)], -1)
     _close(out, f(x))
     _close(dx, jax.grad(lambda x: jnp.sum(f(x) * cot))(x))
-    # the pairs are adjacent channels, the angle pos * theta^(-2i/R)
+    # pair i is the adjacent channels (2i, 2i + 1) or the half-split
+    # ones (i, i + R/2); its angle pos * theta^(-2i/R) either way
     r = rotary_dim or 24
     pos, i = 5, 1
-    a, b = x[1, pos, 2, keep + 2 * i], x[1, pos, 2, keep + 2 * i + 1]
+    first, second = (2 * i, 2 * i + 1) if interleaved else (i, i + r // 2)
+    a, b = x[1, pos, 2, keep + first], x[1, pos, 2, keep + second]
     ang = pos * 1e6 ** (-2 * i / r)
     np.testing.assert_allclose(
-        out[1, pos, 2, keep + 2 * i],
+        out[1, pos, 2, keep + first],
         a * np.cos(ang) - b * np.sin(ang), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        out[1, pos, 2, keep + second],
+        a * np.sin(ang) + b * np.cos(ang), rtol=1e-5, atol=1e-6)
 
 
 def test_swiglu_forward_and_grad():
@@ -177,6 +192,45 @@ def test_moe_router_choices_weights_counts_and_grad():
     _close(dw, gw)
     # the buffer takes no gradient and no update op
     assert main.global_block().var("b_r").trainable is False
+
+
+def test_moe_router_scores_by_softmax_without_a_bias():
+    """Softmax over ALL the experts, the top-k of it renormalised, no
+    selection bias: choices, weights, counts and gradients against the
+    plain reference's router."""
+    t, d, e = 40, 16, 12
+    x, w_r, cot = _r((t, d), 8), _r((e, d), 9, 0.3), _r((t, 3), 10)
+
+    def build(x):
+        choice, weight, counts = layers.moe_router(
+            x, e, 3, experts_held=4, first_expert=4,
+            scoring_func="softmax", param_attr=fluid.ParamAttr(name="w_r"),
+            bias_attr=False)
+        build.extra = [choice, counts]
+        return weight
+    main, scope, exe, out, grads = _run(build, {"x": x}, ["x", "w_r"])
+    router, = [op for op in main.global_block().ops
+               if op.type == "moe_router"]
+    assert "Bias" not in router.input_slots() or not router.input("Bias")
+    with fluid.scope_guard(scope):
+        scope.find_var("w_r").set_value(jnp.asarray(w_r))
+        weight, choice, counts, dx, dw = [np.asarray(a) for a in exe.run(
+            main, feed={"x": x, "cot": cot},
+            fetch_list=[out] + build.extra + list(grads))]
+    sz = dict(num_experts_per_tok=3, norm_topk_prob=True)
+    r_choice, r_weight = gqa_ref.route(x, w_r, sz)
+    assert (choice == np.asarray(r_choice)).all()
+    _close(weight, r_weight)
+    np.testing.assert_allclose(weight.sum(-1), 1.0, rtol=1e-5)
+    # the weights are softmax probabilities over all 12, renormalised
+    p = np.asarray(jax.nn.softmax(x @ w_r.T, -1))
+    top = np.sort(p, -1)[:, -3:]
+    _close(np.sort(weight, -1), top / top.sum(-1, keepdims=True), 1e-5)
+    assert counts.tolist() == [(choice == 4 + j).sum() for j in range(4)]
+    gx, gw = jax.grad(lambda x, w: jnp.sum(
+        gqa_ref.route(x, w, sz)[1] * cot), (0, 1))(x, w_r)
+    _close(dx, gx)
+    _close(dw, gw)
 
 
 def _experts_case(t, d, f, held, choice, seed=0):
@@ -294,6 +348,128 @@ def test_the_shares_add_up_to_the_uncut_layer():
     _close(total, whole)
 
 
+def test_eight_softmax_shares_add_up_to_the_uncut_layer():
+    """16 softmax-routed experts, top-4, no shared expert, over 8 shares
+    of 2: the routed outputs of all eight shares add up to the uncut
+    reference layer (each share given the router's choices over all 16,
+    as every chip computes them alike)."""
+    t, d, f, e = 96, 16, 8, 16
+    sz = dict(num_experts_per_tok=4, norm_topk_prob=True, first_expert=0)
+    y = _r((t, d), 70)
+    p = {"router.w_0": _r((e, d), 71, 0.3),
+         "experts_gate.w_0": _r((e, d, f), 72, 0.3),
+         "experts_up.w_0": _r((e, d, f), 73, 0.3),
+         "experts_down.w_0": _r((e, f, d), 74, 0.3)}
+    ar = ref.base._Arithmetic("f32")
+    with jax.default_matmul_precision("highest"):
+        whole, choice = gqa_ref.moe_layer(ar, p, jnp.asarray(y), sz)
+        _, weight = gqa_ref.route(y, p["router.w_0"], sz)
+        total = np.zeros_like(y)
+        for share in range(8):
+            lo = 2 * share
+            c = dict(x=y, choice=np.asarray(choice),
+                     weight=np.asarray(weight), cot=np.zeros_like(y),
+                     wg=p["experts_gate.w_0"][lo:lo + 2],
+                     wu=p["experts_up.w_0"][lo:lo + 2],
+                     wd=p["experts_down.w_0"][lo:lo + 2])
+            out, _ = _experts_program(c, e, 2, lo)
+            total += out
+    _close(total, whole)
+    # every token's four choices lie in some share: nothing is left out
+    assert np.abs(np.asarray(whole)).sum(-1).min() > 0
+
+
+# ------------------------------------------- sparse attention's index
+
+def _index_case(b, s, heads, dim, seed=80):
+    return _r((b, s, heads, dim), seed), _r((b, s, dim), seed + 1), \
+        _r((b, s, heads), seed + 2)
+
+
+def _plain_index(q, k, w, top_k):
+    """(scores [B, S, S], keep bool [B, S, S]) by plain loops over rows:
+    the top_k best-scored keys not after the query, every such key while
+    there are at most top_k, ties at the threshold kept."""
+    q, k, w = (np.asarray(a, np.float64) for a in (q, k, w))
+    b, s = q.shape[:2]
+    scores = np.einsum("bqh,bhqk->bqk", w, np.maximum(
+        np.einsum("bqhd,bkd->bhqk", q, k), 0.0))
+    keep = np.zeros((b, s, s), bool)
+    for i in range(b):
+        for t in range(s):
+            row = scores[i, t, :t + 1]
+            kth = np.sort(row)[::-1][min(top_k, t + 1) - 1]
+            keep[i, t, :t + 1] = row >= kth
+    return scores, keep
+
+
+@pytest.mark.parametrize("path", ["lowered", "kernels"])
+def test_sparse_attention_index_against_a_plain_top_k(path, request):
+    if path == "kernels":
+        request.getfixturevalue("interp")
+    b, s, heads, dim, top_k = 2, 64, 4, 16, 12
+    q, k, w = _index_case(b, s, heads, dim)
+
+    def build(q, k, w):
+        mask, kept = layers.sparse_attention_index(q, k, w, top_k,
+                                                   scale=0.25)
+        build.extra = [mask, kept]
+        return layers.cast(kept, "float32")
+    main, scope, exe, out, _ = _run(build, {"q": q, "k": k, "w": w}, [])
+    with jax.default_matmul_precision("highest"), fluid.scope_guard(scope):
+        mask, kept = [np.asarray(a) for a in exe.run(
+            main, feed={"q": q, "k": k, "w": w,
+                        "cot": np.zeros((1,), np.float32)},
+            fetch_list=build.extra)]
+    _, want = _plain_index(q, k, w, top_k)
+    assert mask.shape == (b, 1, s, s) and mask.dtype == np.int8
+    assert (mask[:, 0].astype(bool) == want).all()
+    assert not np.triu(mask[0, 0], 1).any()            # causal bound
+    for t in range(top_k):                    # too few keys: keep them all
+        assert mask[1, 0, t, :t + 1].all()
+    # by hand: sum_t min(t + 1, top_k) a sequence, more only for ties
+    by_hand = b * sum(min(t + 1, top_k) for t in range(s))
+    assert kept.tolist() == [want.sum()] and want.sum() >= by_hand
+    assert by_hand == gqa_ref.kept_pairs_by_hand(b, s, top_k)
+    # the op takes no gradient and gives none
+    assert not [op for op in main.global_block().ops
+                if op.type.startswith("sparse_attention_index_grad")]
+    routed = kreg.dispatch_stats()["per_kernel"].get(
+        "sparse_index_scores", {})
+    assert bool(routed.get("custom")) == (path == "kernels")
+
+
+def test_sparse_index_kernels_equal_their_lowering(interp):
+    """Each kernel body under the interpreter against the `jax.numpy`
+    lowering: the scores tile by tile (several tiles a side, -inf above
+    the diagonal), then the selection on the SAME scores, bit for bit —
+    with ties (two index heads give exact zeros) and with every top_k
+    from 1 to more keys than there are."""
+    b, s = 2, 64
+    q, k, w = _index_case(b, s, 2, 8, seed=90)
+    with jax.default_matmul_precision("highest"):
+        got = sparse_index.index_scores(q, k, w, True, tile=16)
+        want = sparse_index.index_scores(q, k, w, False)
+    finite = np.isfinite(np.asarray(want))
+    assert (np.isfinite(np.asarray(got)) == finite).all()
+    assert (finite[0] == np.tril(np.ones((s, s), bool))).all()
+    _close(np.where(finite, got, 0), np.where(finite, want, 0), 1e-5)
+    plain, _ = _plain_index(q, k, w, 1)
+    _close(np.where(finite, want, 0), np.where(finite, plain, 0), 1e-5)
+    assert (np.asarray(want) == 0).sum() > 10          # ties to break
+    for top_k in (1, 7, 16, 63, 64, 200):
+        kernel, counted = sparse_index.select_mask(want, top_k, True,
+                                                   rows=8, chunk=32)
+        lowered, summed = sparse_index.select_mask(want, top_k, False)
+        assert kernel.dtype == lowered.dtype == jnp.int8
+        assert (np.asarray(kernel) == np.asarray(lowered)).all(), top_k
+        # each row's count, as the kernel's last accepted pass had it
+        assert counted.shape == summed.shape == (b, s, 1)
+        assert (np.asarray(counted)[..., 0]
+                == np.asarray(lowered).sum(-1)).all(), top_k
+        assert (np.asarray(counted) == np.asarray(summed)).all()
+
+
 def test_row_plan_holds_every_held_choice_once():
     rng = np.random.default_rng(50)
     local = rng.integers(-3, 9, 700).astype(np.int32)   # 0..3 are held
@@ -342,6 +518,89 @@ def test_flash_kernels_take_two_head_widths(d_qk, d_v, what, interp):
         _close(dv, rv, 1e-5)
 
 
+@pytest.mark.parametrize("backward", ["fused", "split"])
+def test_flash_kernels_take_fewer_key_heads_and_a_keep_mask(
+        backward, interp, monkeypatch):
+    """32 query heads over 4 key / value heads of 128 under a keep mask
+    the index made (int8 [B, 1, S, S]) and the causal bound: the forward
+    and dq / dk / dv, in the fused backward and in the split pair,
+    against the composed float32 vjp; k and v are never expanded and dk
+    and dv come back at 4 heads."""
+    if backward == "split":
+        monkeypatch.setattr(fa, "_FUSED_DQ_VMEM_BUDGET", 0)
+    b, s, h, hkv, d = 1, 64, 32, 4, 128
+    q, g = _r((b, s, h, d), 64), _r((b, s, h, d), 67)
+    k, v = _r((b, s, hkv, d), 65), _r((b, s, hkv, d), 66)
+    mask = sparse_index.index_mask(*_index_case(b, s, 4, 16, seed=68), 12,
+                                   False)[0][:, None]
+    scale = d ** -0.5
+    assert fa._kernel_ok(q, k, 32, 32, "bshd", v)
+    # fewer key heads need one head a lane block: two heads of 64 do not
+    assert not fa._kernel_ok(q[..., :64], k[..., :64], 32, 32, "bshd",
+                             v[..., :64])
+
+    def plain(q, k, v):
+        """Dense float32 attention over the kept pairs, k and v repeated
+        to the query heads."""
+        kk, vv = (jnp.repeat(t, h // hkv, axis=2) for t in (k, v))
+        sc = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
+        keep = (mask != 0) & (jnp.arange(s)[:, None]
+                              >= jnp.arange(s)[None, :])
+        p = jax.nn.softmax(jnp.where(keep, sc, -1e30), -1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, vv)
+
+    with jax.default_matmul_precision("highest"):
+        out, lse = fa._fa_forward(q, k, v, mask, scale, 32, 32,
+                                  return_lse=True, layout="bshd",
+                                  causal=True)
+        want, vjp = jax.vjp(plain, q, k, v)
+        _close(out, want, 1e-5)
+        _close(fa._attn_reference(q, k, v, mask, scale, layout="bshd",
+                                  causal=True), want, 1e-5)
+        kreg.reset_stats()
+        dq, dk, dv, dbias = fa._fa_backward(
+            q, k, v, mask, out, lse, g, scale, 32, 32, layout="bshd",
+            causal=True)
+        rq, rk, rv = vjp(g)
+    assert dbias is None and dk.shape == dv.shape == (b, s, hkv, d)
+    _close(dq, rq, 1e-5)
+    _close(dk, rk, 1e-5)
+    _close(dv, rv, 1e-5)
+    took = kreg.dispatch_stats()["per_kernel"]["flash_attention"]
+    assert took == {backward + "_bwd": 1}
+
+
+def test_fused_attention_op_takes_fewer_key_heads_and_a_mask(interp):
+    """The op through Executor.run: K and V at 4 heads, the mask as
+    BiasQK, gradients for Q, K and V at their own head counts and none
+    for the mask; `routing` says fused_bwd."""
+    b, s, h, hkv, d = 1, 64, 8, 2, 128
+    feeds = {"q": _r((b, s, h, d), 75), "k": _r((b, s, hkv, d), 76),
+             "v": _r((b, s, hkv, d), 77),
+             "mask": np.asarray(sparse_index.index_mask(
+                 *_index_case(b, s, 4, 16, seed=78), 12, False)[0]
+                 )[:, None]}
+    cot = _r((b, s, h, d), 79)
+    kreg.reset_stats()
+    prog = _run(lambda q, k, v, mask: layers.fused_attention(
+        q, k, v, mask, layout="bshd", causal=True, block_q=32, block_k=32),
+        feeds, ["q", "k", "v"])
+    with jax.default_matmul_precision("highest"):
+        out, (dq, dk, dv) = _fetch(*prog[:3], feeds, *prog[3:], cot)
+        want, vjp = jax.vjp(lambda q, k, v: fa._attn_reference(
+            q, k, v, feeds["mask"], d ** -0.5, layout="bshd", causal=True),
+            feeds["q"], feeds["k"], feeds["v"])
+        rq, rk, rv = vjp(cot)
+    _close(out, want, 1e-5)
+    for got, ref_g in ((dq, rq), (dk, rk), (dv, rv)):
+        _close(got, ref_g, 1e-5)
+    grad_op, = [op for op in prog[0].global_block().ops
+                if op.type == "fused_attention_grad"]
+    assert not (grad_op.output("BiasQK@GRAD") or [""])[0]
+    took = kreg.dispatch_stats()["per_kernel"]["flash_attention"]
+    assert took.get("fused_bwd") and not took.get("split_bwd")
+
+
 def test_heads_per_block_for_equal_widths_is_what_it_was():
     for d in (8, 16, 32, 64, 128, 256):
         old = max(1, 128 // d) if d < 128 else 1
@@ -362,13 +621,13 @@ def _model_sizes():
         return family.sizes(json.load(f), rehearsal=True)
 
 
-def _train(sz, tr, seed, amp, steps=3):
+def _train(sz, tr, seed, amp, steps=3, fam=family):
     fluid.framework.unique_name.reset()
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = 1
     from paddle_tpu import models
     with fluid.program_guard(main, startup):
-        cost, _, _ = models.decoder_lm_train(family.model_config(sz))
+        cost, _, _ = models.decoder_lm_train(fam.model_config(sz))
         opt = fluid.optimizer.AdamOptimizer(
             learning_rate=sz["learning_rate"], beta1=sz["adam_beta1"],
             beta2=sz["adam_beta2"], epsilon=sz["adam_epsilon"])
@@ -376,22 +635,25 @@ def _train(sz, tr, seed, amp, steps=3):
             opt = fluid.contrib.mixed_precision.decorate(opt)
         opt.minimize(cost)
     scope = Scope()
-    pool = family.make_pool(sz, tr, seed)
-    names = family.param_names(sz)
+    pool = fam.make_pool(sz, tr, seed)
+    names = fam.param_names(sz)
     with fluid.scope_guard(scope):
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(startup)
-        for n, a in family.init_params(sz, seed).items():
+        for n, a in fam.init_params(sz, seed).items():
             scope.find_var(n).set_value(a)
         get = lambda n: scope.find_var(n).get_value()
         losses = [float(np.asarray(exe.run(
             main, feed=pool[0], fetch_list=[cost])[0]))]
-        grads = family.read_first_gradient_norms(get, names, sz)
+        grads = fam.read_first_gradient_norms(get, names, sz)
+        kept = scope.find_var("sparse_attn_kept")
+        kept = None if kept is None else np.asarray(kept.get_value())
         losses += [float(np.asarray(exe.run(
             main, feed=pool[i], fetch_list=[cost])[0]))
             for i in range(1, steps)]
-        delta = family.read_delta_norms(get, names, sz, seed)
-    return {"losses": losses, "grad_norms": grads, "delta_norms": delta}
+        delta = fam.read_delta_norms(get, names, sz, seed)
+    return {"losses": losses, "grad_norms": grads, "delta_norms": delta,
+            "kept_after_one_step": kept, "state": get}
 
 
 @pytest.mark.parametrize("path", ["lowered", "kernels"])
@@ -434,6 +696,138 @@ def test_model_under_mixed_precision_keeps_the_router_in_float32():
     from paddle_tpu.core import amp
     assert "moe_router" in amp.BLACK_OPS and "rms_norm" in amp.NORM_OPS
     assert "moe_experts" not in amp.WHITE_OPS   # its weights stay f32
+
+
+def _gqa_sizes(**over):
+    import json
+    import os
+    from benchmark.lib import cells
+    with open(os.path.join(cells.BENCH, "configs",
+                           "keye_vl2_30b_a3b.json")) as f:
+        return dict(gqa_family.sizes(json.load(f), rehearsal=True), **over)
+
+
+@pytest.mark.parametrize("path", ["lowered", "kernels"])
+def test_sparse_attention_model_trains_like_its_reference(path, request):
+    """The same model file, told by its configuration's keys to build
+    grouped-query attention under a learned sparse index and a softmax
+    router: losses, every leaf's first gradient and every leaf's change
+    after three Adam steps against the plain reference; the indexer's
+    buffers as the seed drew them; the counter equal to the reference's
+    own count of kept pairs."""
+    over = {}
+    if path == "kernels":
+        request.getfixturevalue("interp")
+        over = dict(head_dim=128)      # one head a lane block
+    sz = _gqa_sizes(**over)
+    tr = gqa_family.traffic({"pool": 3, "reference_rows_per_block": 1},
+                            True)
+    fam = gqa_family
+    with jax.default_matmul_precision("highest"):
+        got = _train(sz, tr, 7, amp=False, fam=fam)
+        want = fam.run_reference(sz, tr, fam.make_pool(sz, tr, 7), 7, 3)
+    np.testing.assert_allclose(got["losses"], want["losses"], rtol=2e-6)
+    assert set(got["grad_norms"]) == set(want["grad_norms"]) \
+        == set(gqa_ref.trainable_names(sz))
+    for n, w in want["grad_norms"].items():
+        assert abs(got["grad_norms"][n] - w) <= 2e-4 * max(w, 1e-6), n
+    for n, w in want["delta_norms"].items():
+        assert abs(got["delta_norms"][n] - w) <= 5e-3 * w, n
+    # the indexer's buffers: in the program, not trained, not moved
+    buffers = [n for n in fam.param_names(sz) if gqa_ref.is_buffer(n)]
+    assert len(buffers) == 5 * sz["num_hidden_layers"]
+    seeded = fam.init_params(sz, 7)
+    for n in buffers:
+        assert (np.asarray(got["state"](n)) == np.asarray(seeded[n])).all()
+        assert n not in got["grad_norms"]
+    # the counter holds the LAST step's kept pairs a layer (overwritten,
+    # not added up): at most every causal pair, at least the count by
+    # hand; after one step it is the reference's own count
+    kept = fam.kept_pairs(sz)
+    causal = fam.causal_pairs(tr)
+    by_hand = gqa_ref.kept_pairs_by_hand(tr["batch"], tr["seq_len"],
+                                         sz["index_topk"])
+    assert kept.shape == (sz["num_hidden_layers"],)
+    assert ((by_hand <= kept) & (kept < causal)).all()
+    assert got["kept_after_one_step"].tolist() == \
+        np.asarray(want["first_kept"]).tolist()
+    assert fam.expert_load(sz).shape == (sz["num_hidden_layers"],
+                                         sz["experts_held"])
+    if path == "kernels":
+        stats = kreg.dispatch_stats()["per_kernel"]
+        assert stats["moe_grouped_matmul"].get("custom")
+        assert stats["sparse_index_scores"].get("custom")
+        assert stats["flash_attention"].get("custom")
+        assert stats["flash_attention"].get("fused_bwd")
+        assert not stats["flash_attention"].get("split_bwd")
+
+
+def test_the_two_models_are_chosen_by_their_published_keys():
+    """One model file: latent attention, a sigmoid router with its bias,
+    a shared expert and a dense first layer from the one key family;
+    grouped queries, the index, a softmax router without bias and
+    experts in every layer from the other. kanana's program op for op
+    as PR 32 built it."""
+    from paddle_tpu import models
+
+    def ops(cfg):
+        fluid.framework.unique_name.reset()
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            models.decoder_lm_train(cfg)
+        return main, [op.type for op in main.global_block().ops]
+
+    main, latent = ops(family.model_config(_model_sizes()))
+    layer = ["rms_norm", "mul", "reshape2", "rotary_embedding", "mul",
+             "split", "rms_norm", "mul", "reshape2", "split", "reshape2",
+             "rotary_embedding", "expand", "concat", "fused_attention",
+             "reshape2", "mul", "elementwise_add", "rms_norm"]
+    assert latent[:1 + len(layer)] == ["lookup_table"] + layer
+    assert latent.count("moe_router") == 2 and "layer_norm" not in latent
+    assert "sparse_attention_index" not in latent
+    router = [op for op in main.global_block().ops
+              if op.type == "moe_router"][0]
+    assert router.attr("scoring_func") == "sigmoid" and router.input("Bias")
+    assert "layer_0_mlp_gate.w_0" in {p.name for p in main.all_parameters()}
+
+    main, sparse = ops(gqa_family.model_config(_gqa_sizes()))
+    assert sparse.count("sparse_attention_index") == 2
+    assert sparse.count("moe_router") == 2 and "split" not in sparse
+    router = [op for op in main.global_block().ops
+              if op.type == "moe_router"][0]
+    assert router.attr("scoring_func") == "softmax"
+    assert not ("Bias" in router.input_slots() and router.input("Bias"))
+    names = {p.name for p in main.all_parameters()}
+    assert not [n for n in names if "_shared_" in n or "_mlp_" in n]
+    attn, = [op for op in main.global_block().ops
+             if op.type == "fused_attention"][:1]
+    block = main.global_block()
+    assert block.var(attn.input("K")[0]).shape[2] == 2       # 2 key heads
+    assert block.var(attn.input("Q")[0]).shape[2] == 4
+    from paddle_tpu.core.types import dtype_to_str
+    assert dtype_to_str(block.var(attn.input("BiasQK")[0]).dtype) == "int8"
+    rot = [op for op in block.ops if op.type == "rotary_embedding"]
+    assert rot and not any(op.attr("interleaved") for op in rot)
+    buffers = [p for p in main.all_parameters() if "_attn_index_" in p.name]
+    assert len(buffers) == 10 and not any(p.trainable for p in buffers)
+
+
+def test_kept_pairs_reader():
+    from paddle_tpu.observability import sparse_attention as sa
+    scope = Scope()
+    assert sa.kept_pairs(scope) is None
+    scope.var(sa.KEPT_PAIRS_VAR).set_value(
+        jnp.asarray([300, 330], jnp.int32))
+    kept = sa.kept_pairs(scope)
+    assert kept.dtype == np.int64 and kept.tolist() == [300, 330]
+    assert sa.causal_pairs(2, 20) == 420
+    assert sa.kept_share(kept, 2, 20) == pytest.approx(0.75)
+    assert sa.kept_share(np.zeros(2), 2, 20) is None
+    # 8,192 tokens, top-2,048: 43.75% of the causal pairs
+    by_hand = gqa_ref.kept_pairs_by_hand(1, 8192, 2048)
+    assert by_hand == 14_681_088
+    assert sa.kept_share(np.asarray([by_hand]), 1, 8192) == \
+        pytest.approx(0.4375, abs=1e-4)
 
 
 def test_expert_load_reader():

@@ -202,6 +202,75 @@ def test_flash_kernels_compile_at_latent_attention_widths(one_chip,
     assert "bf16[1,4096,4096]" in text
 
 
+def test_flash_kernels_compile_with_four_key_heads_and_a_keep_mask(
+        one_chip, no_compile_cache):
+    """32 query heads over 4 key / value heads of 128 under an int8 keep
+    mask, B=1 S=8192 (the keye2_s8192 cell's attention): Mosaic takes the
+    forward and the FUSED backward (8 MiB of resident dq) under their
+    names, k and v are never expanded to 32 heads, and dk / dv leave at 4
+    heads."""
+    fa = _flash_module()
+    s = 8192
+    q = ((1, s, 32, 128), jnp.bfloat16)
+    kv = ((1, s, 4, 128), jnp.bfloat16)
+    mask = ((1, 1, s, s), jnp.int8)
+    shapes = {}
+
+    def fwd_bwd(q, k, v, m, g):
+        out, lse = fa._fa_forward(q, k, v, m, 128 ** -0.5, BLOCK_Q,
+                                  BLOCK_K, return_lse=True,
+                                  layout="bshd", causal=True)
+        dq, dk, dv, _ = fa._fa_backward(q, k, v, m, out, lse, g,
+                                        128 ** -0.5, BLOCK_Q, BLOCK_K,
+                                        layout="bshd", causal=True)
+        shapes.update(dq=dq.shape, dk=dk.shape, dv=dv.shape)
+        return dq, dk, dv
+
+    from paddle_tpu.kernels import registry
+    registry.reset_stats()
+    text = _compiled_text(fwd_bwd, one_chip, q, kv, kv, mask, q)
+    heads = _custom_call_heads(text)
+    assert _stems(heads) == {"flash_attention_fwd",
+                             "flash_attention_dkv"}, heads
+    assert len(heads) == 2, heads
+    assert shapes == {"dq": (1, s, 32, 128), "dk": (1, s, 4, 128),
+                      "dv": (1, s, 4, 128)}
+    took = registry.dispatch_stats()["per_kernel"]["flash_attention"]
+    assert took == {"fused_bwd": 1}
+    # the kernels read k and v at 4 heads (512 lanes): the custom calls
+    # take bf16[1,8192,512] operands, and the mask as int8
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert all("bf16[1,8192,512]" in l and "s8[1,8192,8192]" in l
+               for l in calls), calls
+
+
+def test_sparse_index_kernels_compile_under_their_names(one_chip,
+                                                        no_compile_cache,
+                                                        monkeypatch):
+    """The index kernels at the keye2_s8192 cell's sizes: 16 index heads
+    of 64 over 8,192 tokens in [512, 512] tiles, then the exact top-2048
+    threshold 128 rows at a time with 14 MiB of VMEM over the default."""
+    from paddle_tpu.kernels import registry, sparse_index
+    monkeypatch.setattr(registry, "interpret", lambda: False)
+    s = 8192
+
+    def mask(q, k, w):
+        return sparse_index.index_mask(q, k, w, 2048, True)
+
+    text = _compiled_text(mask, one_chip,
+                          ((1, s, 16, 64), jnp.bfloat16),
+                          ((1, s, 64), jnp.bfloat16),
+                          ((1, s, 16), jnp.float32))
+    heads = _custom_call_heads(text)
+    assert [h.split(".")[0] for h in heads] == [
+        "sparse_index_scores", "sparse_index_select"], heads
+    # float32 scores between them, an int8 mask out beside each row's
+    # count (the mask is not read again to count it); never [16, S, S]
+    assert "f32[1,8192,8192]" in text and "s8[1,8192,8192]" in text
+    assert "s32[1,8192,1]" in text
+    assert "[1,16,8192,8192]" not in text and "[16,8192,8192]" not in text
+
+
 @pytest.mark.parametrize("which", ["fwd", "dx", "dw"])
 def test_grouped_matmul_kernels_are_named(one_chip, no_compile_cache,
                                           which, monkeypatch):
@@ -241,7 +310,8 @@ def test_no_other_kernel_reads_as_flash_or_adam():
     kernel's name may hold any of them."""
     from paddle_tpu.tuning import variants
     names = ["fused_sgd", "quantized_matmul", "moe_grouped_matmul_fwd",
-             "moe_grouped_matmul_dx", "moe_grouped_matmul_dw"] + [
+             "moe_grouped_matmul_dx", "moe_grouped_matmul_dw",
+             "sparse_index_scores", "sparse_index_select"] + [
         f"tuned_matmul_{v.epilogue}_{v.bm}x{v.bn}x{v.bk}"
         for v in variants.enumerate_variants()]
     for n in names:
