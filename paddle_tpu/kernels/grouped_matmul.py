@@ -23,6 +23,17 @@ sees a repeated block index and elides its DMA, and `pl.when` skips its
 body. Rows past the last tile in use are never written: whatever reads
 the buffer masks them (`valid`), it does not multiply them by zero.
 
+Prefixes (`prefix_rows`, `prefix_plan`, `prefix_index`). The kernels skip
+the tiles past `n_active`; what XLA does around them (the gathers into
+the buffer, the activation, the casts) cannot, its shapes are static. So
+the caller runs the whole layer over a static PREFIX of the buffer that
+holds every tile in use, picked on the device from `n_active` among a
+short ladder of sizes: a chip that holds `experts_held` of `num_experts`
+sees about that share of the choices, so the ladder is the buffer that
+share, twice and four times that share would need, then the worst case.
+The worst case stays: the layer is dropless whatever the router does,
+only slower.
+
 Routing. `select()`-governed like fused_adam (kernels/registry.py): off
 the CPU and when not denied the three kernels run; otherwise the
 `lowered` path computes the same three products over the same buffer
@@ -45,8 +56,9 @@ TILE_ROWS = 128
 # the dw kernel's output block [bk, n] f32 is held to this many bytes
 _DW_BLOCK_BYTES = 2 * 1024 * 1024
 
-__all__ = ["TILE_ROWS", "plan_rows", "buffer_rows", "gmm", "gmm_dx",
-           "gmm_dw", "use_kernels"]
+__all__ = ["TILE_ROWS", "plan_rows", "buffer_rows", "prefix_rows",
+           "prefix_plan", "prefix_index", "gmm", "gmm_dx", "gmm_dw",
+           "use_kernels"]
 
 
 # ---------------------------------------------------------------------------
@@ -111,6 +123,40 @@ def plan_rows(local_expert, experts_held: int, tile: int = TILE_ROWS):
             "choice_of_row": choice_of_row, "valid": valid,
             "tile_expert": tile_expert, "n_active": n_active,
             "sizes": sizes}
+
+
+def prefix_rows(n_choices: int, experts_held: int, num_experts: int,
+                tile: int = TILE_ROWS):
+    """The prefixes of the worst-case buffer a layer may run over, in
+    rows, ascending, the worst case last: the buffers of a share s, 2s
+    and 4s of the choices (those under all of them), s = experts_held /
+    num_experts being the share uniform routing sends here. One entry,
+    the worst case, where every expert is held."""
+    worst = buffer_rows(n_choices, experts_held, tile)
+    rows = {buffer_rows(-(-n_choices * experts_held * m // num_experts),
+                        experts_held, tile)
+            for m in (1, 2, 4) if experts_held * m < num_experts}
+    return sorted(r for r in rows if r < worst) + [worst]
+
+
+def prefix_plan(plan, rows: int, tile: int = TILE_ROWS):
+    """The plan over the buffer's first `rows` rows. It is the same
+    function of the routing wherever n_active * tile <= rows: the tiles
+    in use are the buffer's first, and `row_of_choice` of a held choice
+    lies among them."""
+    if rows == plan["valid"].shape[0]:
+        return plan
+    return dict(plan, choice_of_row=plan["choice_of_row"][:rows],
+                valid=plan["valid"][:rows],
+                tile_expert=plan["tile_expert"][:rows // tile])
+
+
+def prefix_index(plan, ladder, tile: int = TILE_ROWS):
+    """int32 scalar: the first prefix of `ladder` (`prefix_rows`) that
+    holds every tile in use, i.e. how many of the shorter ones n_active
+    exceeds."""
+    edges = jnp.asarray([r // tile for r in ladder[:-1]], jnp.int32)
+    return jnp.sum(plan["n_active"][0] > edges, dtype=jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -228,6 +274,15 @@ def _lowered_gmm(lhs, rhs, plan, transpose, tile):
 
 
 def _lowered_dw(lhs, dout, plan, experts_held, tile):
+    # the rows are the contraction here, and the dense dot a backend
+    # without a ragged one makes of it sums over all of them in an order
+    # that depends on how many there are: a prefix is padded back to the
+    # worst case (zero rows, as the worst case's own are past the tiles
+    # in use), so that its result is the worst case's to the bit
+    beyond = buffer_rows(plan["held"].shape[0], experts_held, tile) \
+        - lhs.shape[0]
+    if beyond:
+        lhs, dout = (jnp.pad(a, ((0, beyond), (0, 0))) for a in (lhs, dout))
     dims = lax.RaggedDotDimensionNumbers(
         dot_dimension_numbers=(((0,), (0,)), ((), ())),
         lhs_ragged_dimensions=[0], rhs_group_dimensions=[])

@@ -94,7 +94,9 @@ def moe_experts(input, choice, weight, num_experts, expert_width,
     experts_held - 1` of a layer of `num_experts`, dropless: three
     stacked parameters [experts_held, D, F], [experts_held, D, F],
     [experts_held, F, D]. A choice of an expert held elsewhere adds
-    nothing here."""
+    nothing here. The op's `RowsWorked` output (int32 [2]: rows of the
+    buffer the layer worked over, rows in use) is found through
+    `<result>.op`."""
     helper = LayerHelper("moe_experts", name=name)
     held = int(experts_held or num_experts)
     d, f = int(input.shape[-1]), int(expert_width)
@@ -108,11 +110,13 @@ def moe_experts(input, choice, weight, num_experts, expert_width,
     out = helper.create_variable_for_type_inference(input.dtype)
     gate = helper.create_variable_for_type_inference(input.dtype, True)
     up = helper.create_variable_for_type_inference(input.dtype, True)
+    worked = helper.create_variable_for_type_inference("int32", True)
     helper.append_op(
         "moe_experts",
         inputs={"X": input, "TopkIdx": choice, "TopkWeight": weight,
                 "WGate": w_gate, "WUp": w_up, "WDown": w_down},
-        outputs={"Out": out, "GateAct": gate, "UpAct": up},
+        outputs={"Out": out, "GateAct": gate, "UpAct": up,
+                 "RowsWorked": worked},
         attrs={"num_experts": int(num_experts), "experts_held": held,
                "first_expert": int(first_expert)})
     return out

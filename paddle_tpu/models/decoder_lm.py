@@ -45,10 +45,12 @@ Every parameter has an explicit, stable name (`layer_<i>_attn_q.w_0`,
 `layer_<i>_experts_gate.w_0`, ...). The indexer's weights
 (`layer_<i>_attn_index_*`) are buffers: not trainable, and the indexer
 reads the layer's input with no gradient (its own alignment loss is a
-training recipe's, not the language model's). Two persistable int32
+training recipe's, not the language model's). Three persistable int32
 counters are written inside the step: `moe_expert_load` [MoE layers,
 experts held] ACCUMULATES the tokens the router sent to each held
-expert (observability/moe.py reads it); `sparse_attn_kept` [layers] is
+expert and `moe_rows_worked` [MoE layers, 2] the rows of its row buffer
+each expert layer worked over and the rows of it in use
+(observability/moe.py reads both); `sparse_attn_kept` [layers] is
 OVERWRITTEN with the (query, key) pairs each layer's selection kept
 (observability/sparse_attention.py).
 """
@@ -57,7 +59,7 @@ from __future__ import annotations
 from .. import layers
 from ..framework import name_scope
 from ..initializer import Constant, Normal
-from ..observability.moe import EXPERT_LOAD_VAR
+from ..observability.moe import EXPERT_LOAD_VAR, ROWS_WORKED_VAR
 from ..observability.sparse_attention import KEPT_PAIRS_VAR
 from ..param_attr import ParamAttr
 
@@ -265,7 +267,7 @@ def gated_ffn(x, width, cfg, name):
 def moe_ffn(x, cfg, name):
     """Routed experts held here plus the shared expert where there is
     one. Returns (output, the router's count of tokens per held
-    expert)."""
+    expert, the expert layer's rows worked and in use)."""
     choice, weight, counts = layers.moe_router(
         x, cfg.n_routed_experts, cfg.num_experts_per_tok,
         experts_held=cfg.experts_held, first_expert=cfg.first_expert,
@@ -283,12 +285,13 @@ def moe_ffn(x, cfg, name):
         gate_attr=_w(name + "_experts_gate.w_0", cfg),
         up_attr=_w(name + "_experts_up.w_0", cfg),
         down_attr=_w(name + "_experts_down.w_0", cfg))
+    worked = routed.block.var(routed.op.output("RowsWorked")[0])
     if cfg.n_shared_experts:
         shared = gated_ffn(
             x, cfg.n_shared_experts * cfg.moe_intermediate_size, cfg,
             name + "_shared")
         routed = layers.elementwise_add(routed, shared)
-    return routed, counts
+    return routed, counts, worked
 
 
 def decoder_lm_train(cfg: DecoderLMConfig):
@@ -304,7 +307,7 @@ def decoder_lm_train(cfg: DecoderLMConfig):
         h = layers.embedding(
             ids, size=[cfg.vocab_size, cfg.hidden_size],
             param_attr=_w("embed_tokens.w_0", cfg))
-    counts, kept = [], []
+    counts, worked, kept = [], [], []
     for i in range(cfg.num_hidden_layers):
         p = f"layer_{i}"
         with name_scope(p):
@@ -324,8 +327,10 @@ def decoder_lm_train(cfg: DecoderLMConfig):
                     h = layers.elementwise_add(h, ffn)
             else:
                 with name_scope("moe"):
-                    ffn, c = moe_ffn(_norm(h, p + "_ffn_norm", cfg), cfg, p)
+                    ffn, c, w = moe_ffn(_norm(h, p + "_ffn_norm", cfg), cfg,
+                                        p)
                     counts.append(c)
+                    worked.append(w)
                     h = layers.elementwise_add(h, ffn)
     if counts:
         with name_scope("moe_expert_load"):
@@ -333,6 +338,11 @@ def decoder_lm_train(cfg: DecoderLMConfig):
                 [len(counts), cfg.experts_held], 0, "int32",
                 persistable=True, name=EXPERT_LOAD_VAR)
             layers.sums([load, layers.stack(counts, axis=0)], out=load)
+        with name_scope("moe_rows_worked"):
+            rows = layers.create_global_var(
+                [len(worked), 2], 0, "int32", persistable=True,
+                name=ROWS_WORKED_VAR)
+            layers.sums([rows, layers.stack(worked, axis=0)], out=rows)
     if kept:
         # overwritten, not added to: 14.7 M pairs a layer a step at 8,192
         # tokens would overflow an accumulating int32 within minutes

@@ -20,8 +20,8 @@ Three layers (docs/OBSERVABILITY.md):
   live-buffer census reconciled against ``jax.live_arrays()``,
   OOM/pressure postmortem dumps, and the leak sentinel
   (docs/MEMORY.md);
-* :mod:`.moe` — reader of the `moe_expert_load` counter a
-  mixture-of-experts step keeps (docs/TRACING.md);
+* :mod:`.moe` — readers of the `moe_expert_load` and `moe_rows_worked`
+  counters a mixture-of-experts step keeps (docs/TRACING.md);
 * :mod:`.sparse_attention` — reader of the `sparse_attn_kept` counter a
   learned-sparse-attention step overwrites (docs/TRACING.md).
 

@@ -7,11 +7,14 @@ index of a learned sparse attention: which keys each query attends.
 The expert layer is dropless and knows which experts it holds:
 `moe_experts` gathers the rows routed to experts `first_expert ..
 first_expert + experts_held - 1` into a worst-case row buffer and runs
-the three grouped matmuls of kernels/grouped_matmul.py over it; what the
-experts held elsewhere would add is left out (on one chip there is no
-exchange, and nothing stands in for the absent chips).
+the three grouped matmuls of kernels/grouped_matmul.py over the part of
+it that is in use; what the experts held elsewhere would add is left out
+(on one chip there is no exchange, and nothing stands in for the absent
+chips).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -191,10 +194,103 @@ def _combine(buf, plan, t, top_k):
     return jnp.sum(picked.reshape(t, top_k, -1).astype(_F32), axis=1)
 
 
+class _Prefix:
+    """The first `rows` rows of the row buffer, which hold every tile in
+    use: the plan cut to them, the rows gathered into them, each row's
+    routing weight, and the grouped matmuls over them."""
+
+    def __init__(self, x, weight, plan, rows, held, kernels):
+        from ..kernels import grouped_matmul as gm
+        self.gm, self.held, self.kernels = gm, held, kernels
+        self.top_k = weight.shape[-1]
+        self.plan = gm.prefix_plan(plan, rows)
+        self.valid = self.plan["valid"][:, None]
+        self.xs = _gather_rows(x, self.plan, self.top_k)
+        self.w_row = jnp.where(
+            self.plan["valid"],
+            weight.reshape(-1)[self.plan["choice_of_row"]], 0.0)
+
+    def gmm(self, lhs, rhs):
+        return self.gm.gmm(lhs, rhs, self.plan, self.kernels)
+
+    def gmm_dx(self, dout, rhs):
+        return self.gm.gmm_dx(dout, rhs, self.plan, self.kernels)
+
+    def gmm_dw(self, lhs, dout):
+        return self.gm.gmm_dw(lhs, dout, self.plan, self.held, self.kernels)
+
+
+def _experts_forward(x, weight, wg, wu, wd, plan, *, rows, held, kernels,
+                     out_dtype):
+    """`moe_experts` over the buffer's first `rows` rows. Returns (out
+    [T, D], GateAct, UpAct): the two at the whole buffer's length, their
+    rows past `rows` zero."""
+    p = _Prefix(x, weight, plan, rows, held, kernels)
+    gate, up = p.gmm(p.xs, wg), p.gmm(p.xs, wu)
+    hidden = _silu(gate.astype(_F32)) * up.astype(_F32)
+    # the routing weight goes in before the down projection (it is
+    # linear), so the backward needs no expert output kept or recomputed
+    hw = jnp.where(p.valid, hidden * p.w_row[:, None], 0)
+    out = _combine(p.gmm(hw.astype(x.dtype), wd), plan, x.shape[0], p.top_k)
+    beyond = plan["valid"].shape[0] - rows
+    if beyond:
+        gate, up = (jnp.pad(a, ((0, beyond), (0, 0))) for a in (gate, up))
+    return out.astype(out_dtype), gate, up
+
+
+def _experts_backward(x, weight, wg, wu, wd, plan, gate, up, dout, *, rows,
+                      held, kernels, shape):
+    """`moe_experts_grad` over the buffer's first `rows` rows, the rows
+    of GateAct and UpAct the forward wrote. Returns float32 (dX, dWeight,
+    dWGate, dWUp, dWDown)."""
+    p = _Prefix(x, weight, plan, rows, held, kernels)
+    dtype, valid = x.dtype, p.valid
+    if gate is None:        # a hand-built op desc without the two slots
+        gate, up = p.gmm(p.xs, wg), p.gmm(p.xs, wu)
+    elif rows < gate.shape[0]:
+        gate, up = gate[:rows], up[:rows]
+    dy = _gather_rows(dout.reshape(x.shape[0], -1).astype(dtype), p.plan,
+                      p.top_k)
+
+    gate32 = jnp.where(valid, gate.astype(_F32), 0)
+    up32 = jnp.where(valid, up.astype(_F32), 0)
+    sig = jax.nn.sigmoid(gate32)
+    act = gate32 * sig
+    hidden = act * up32
+    hw = (hidden * p.w_row[:, None]).astype(dtype)
+    dhw = jnp.where(valid, p.gmm_dx(dy, wd).astype(_F32), 0)
+    d_wd = p.gmm_dw(hw, dy)
+    dw_row = jnp.sum(dhw * hidden, axis=-1)
+    dh = dhw * p.w_row[:, None]
+    dgate = (dh * up32 * (sig + act * (1.0 - sig))).astype(dtype)
+    dup = (dh * act).astype(dtype)
+    dxs = p.gmm_dx(dgate, wg).astype(_F32) + p.gmm_dx(dup, wu).astype(_F32)
+    d_wg, d_wu = p.gmm_dw(p.xs, dgate), p.gmm_dw(p.xs, dup)
+    dx = _combine(dxs, plan, x.shape[0], p.top_k).reshape(shape)
+    dweight = jnp.where(plan["held"], dw_row[plan["row_of_choice"]],
+                        0.0).reshape(weight.shape)
+    return dx, dweight, d_wg, d_wu, d_wd
+
+
+# A layer's branches have the same shapes in every layer and in both
+# traces the engine makes of a step: jitted at module level, a body is
+# traced once a prefix and found again. Everything that reads a setting
+# of the process (AMP's casts, the kernel decision) stays outside them.
+_BODIES = {
+    _experts_forward: jax.jit(_experts_forward, static_argnames=(
+        "rows", "held", "kernels", "out_dtype")),
+    _experts_backward: jax.jit(_experts_backward, static_argnames=(
+        "rows", "held", "kernels", "shape")),
+}
+
+
 class _Experts:
     """What the forward and the grad op of `moe_experts` share: the
-    operands as the kernels take them, the row buffer's plan, the rows
-    gathered into it and each row's routing weight."""
+    operands as the kernels take them, the row buffer's plan, the kernel
+    decision, and the prefixes of the buffer the layer may run over
+    (`grouped_matmul.prefix_rows`): the share of the experts held here
+    sets them when the op is traced, the plan's `n_active` picks one
+    when it runs."""
 
     def __init__(self, ctx):
         from ..kernels import grouped_matmul as gm
@@ -211,25 +307,37 @@ class _Experts:
         self.wg, self.wu, self.wd = (w.astype(x.dtype) for w in (wg, wu, wd))
         self.held = int(ctx.attr("experts_held"))
         first = int(ctx.attr("first_expert", 0))
-        if first < 0 or first + self.held > int(ctx.attr("num_experts")):
+        num_experts = int(ctx.attr("num_experts"))
+        if first < 0 or first + self.held > num_experts:
             raise ValueError("experts held lie outside the layer's experts")
-        self.top_k = choice.shape[-1]
         self.plan = gm.plan_rows(choice.reshape(-1) - first, self.held)
         self.kernels = gm.use_kernels(x, self.wg)
-        self.valid = self.plan["valid"][:, None]
-        self.xs = _gather_rows(x, self.plan, self.top_k)
-        self.w_row = jnp.where(
-            self.plan["valid"],
-            self.weight.reshape(-1)[self.plan["choice_of_row"]], 0.0)
+        self.ladder = gm.prefix_rows(choice.size, self.held, num_experts)
 
-    def gmm(self, lhs, rhs):
-        return self.gm.gmm(lhs, rhs, self.plan, self.kernels)
+    def run(self, body, *more, **static):
+        """`body` over the shortest prefix that holds every tile in use.
+        One prefix (every expert held): the body itself, no branch."""
+        operands = (self.x, self.weight, self.wg, self.wu, self.wd,
+                    self.plan) + more
+        static.update(held=self.held, kernels=self.kernels)
+        if len(self.ladder) == 1:
+            return body(*operands, rows=self.ladder[0], **static)
+        return jax.lax.switch(
+            self.taken,
+            [functools.partial(_BODIES[body], rows=rows, **static)
+             for rows in self.ladder], *operands)
 
-    def gmm_dx(self, dout, rhs):
-        return self.gm.gmm_dx(dout, rhs, self.plan, self.kernels)
+    @functools.cached_property
+    def taken(self):
+        """int32 scalar: which prefix of the ladder this routing takes."""
+        return self.gm.prefix_index(self.plan, self.ladder)
 
-    def gmm_dw(self, lhs, dout):
-        return self.gm.gmm_dw(lhs, dout, self.plan, self.held, self.kernels)
+    def rows_worked(self):
+        """int32 [2]: rows of the prefix taken, rows in use (the tiles
+        in use, whole)."""
+        return jnp.stack([
+            jnp.asarray(self.ladder, jnp.int32)[self.taken],
+            self.plan["n_active"][0] * self.gm.TILE_ROWS])
 
 
 @register_op("moe_experts", no_grad_slots=("TopkIdx",),
@@ -241,25 +349,31 @@ def moe_experts(ctx):
     Out[t] = sum over k with TopkIdx[t, k] held here of TopkWeight[t, k]
     * WDown_e(silu(x_t WGate_e) * (x_t WUp_e)). GateAct and UpAct
     (intermediate, [buffer rows, F]) carry the two projections to the
-    grad op, so the backward runs no forward kernel again."""
-    res_t = jnp.result_type(ctx.input("X"))
+    grad op, so the backward runs no forward kernel again.
+
+    Where only a share of the experts is held, the whole layer runs over
+    a static prefix of the worst-case buffer that holds every tile in
+    use (`_Experts`); the worst case stays one of the prefixes, so no
+    routing drops a token. GateAct and UpAct keep the worst-case length:
+    a prefix's rows are written, the rows past it are zero, and nothing
+    reads them (the grad op takes the same prefix). RowsWorked (optional,
+    int32 [2]): rows of the prefix taken and rows in use, for the
+    `moe_rows_worked` counter (observability/moe.py)."""
     e = _Experts(ctx)
-    gate, up = e.gmm(e.xs, e.wg), e.gmm(e.xs, e.wu)
-    hidden = _silu(gate.astype(_F32)) * up.astype(_F32)
-    # the routing weight goes in before the down projection (it is
-    # linear), so the backward needs no expert output kept or recomputed
-    hw = jnp.where(e.valid, hidden * e.w_row[:, None], 0)
-    out = _combine(e.gmm(hw.astype(e.x.dtype), e.wd), e.plan,
-                   e.x.shape[0], e.top_k)
-    ctx.set_output("Out", out.astype(res_t).reshape(e.shape))
+    out, gate, up = e.run(_experts_forward,
+                          out_dtype=jnp.result_type(ctx.input("X")))
+    ctx.set_output("Out", out.reshape(e.shape))
     ctx.set_output("GateAct", gate)
     ctx.set_output("UpAct", up)
+    if ctx.has_output("RowsWorked"):
+        ctx.set_output("RowsWorked", e.rows_worked())
 
 
 @override_grad_lowering("moe_experts")
 def moe_experts_grad(ctx):
     """Hand-written: three dx and three dw grouped matmuls over the
-    forward's row buffer, reading the forward's GateAct and UpAct. With
+    forward's prefix of the row buffer (the same `n_active` picks it),
+    reading the forward's GateAct and UpAct. With
     hw = silu(gate) * up * w_row and y = hw WDown:
       d hw = dy WDown^T;  dWDown = hw^T dy;  dw_row = sum(d hw * hidden)
       d gate = d hw * w_row * up * silu'(gate);  d up = d hw * w_row *
@@ -267,37 +381,14 @@ def moe_experts_grad(ctx):
       d gate;  dWUp = x^T d up."""
     op = ctx.op
     e = _Experts(ctx)
-    dtype, valid = e.x.dtype, e.valid
+    gate = up = None
     if ctx.has_input("GateAct") and ctx.has_input("UpAct"):
         gate = ctx.env[op.input("GateAct")[0]]
         up = ctx.env[op.input("UpAct")[0]]
-    else:       # a hand-built op desc without the two slots
-        gate, up = e.gmm(e.xs, e.wg), e.gmm(e.xs, e.wu)
-    dout = ctx.env[op.input("Out@GRAD")[0]]
-    dy = _gather_rows(dout.reshape(e.x.shape[0], -1).astype(dtype), e.plan,
-                      e.top_k)
-
-    gate32 = jnp.where(valid, gate.astype(_F32), 0)
-    up32 = jnp.where(valid, up.astype(_F32), 0)
-    sig = jax.nn.sigmoid(gate32)
-    act = gate32 * sig
-    hidden = act * up32
-    hw = (hidden * e.w_row[:, None]).astype(dtype)
-    dhw = jnp.where(valid, e.gmm_dx(dy, e.wd).astype(_F32), 0)
-    d_wd = e.gmm_dw(hw, dy)
-    dw_row = jnp.sum(dhw * hidden, axis=-1)
-    dh = dhw * e.w_row[:, None]
-    dgate = (dh * up32 * (sig + act * (1.0 - sig))).astype(dtype)
-    dup = (dh * act).astype(dtype)
-    dxs = e.gmm_dx(dgate, e.wg).astype(_F32) \
-        + e.gmm_dx(dup, e.wu).astype(_F32)
-    d_wg, d_wu = e.gmm_dw(e.xs, dgate), e.gmm_dw(e.xs, dup)
-    dx = _combine(dxs, e.plan, e.x.shape[0], e.top_k).reshape(e.shape)
-    dweight = jnp.where(e.plan["held"], dw_row[e.plan["row_of_choice"]],
-                        0.0).reshape(e.weight.shape)
-
-    for slot, grad in (("X", dx), ("TopkWeight", dweight),
-                       ("WGate", d_wg), ("WUp", d_wu), ("WDown", d_wd)):
+    grads = e.run(_experts_backward, gate, up,
+                  ctx.env[op.input("Out@GRAD")[0]], shape=e.shape)
+    for slot, grad in zip(("X", "TopkWeight", "WGate", "WUp", "WDown"),
+                          grads):
         names = op.output(slot + "@GRAD")
         if names and names[0]:
             primal = ctx.env[op.input(slot)[0]]

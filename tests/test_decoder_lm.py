@@ -255,17 +255,21 @@ def _experts_reference(c, first):
     return out, grads
 
 
-def _experts_program(c, num_experts, held, first):
+def _experts_program(c, num_experts, held, first, slots=()):
+    """(Out, [the five gradients, then the op's outputs `slots`])."""
     f = c["wg"].shape[2]
-    prog = _run(lambda x, choice, weight: layers.moe_experts(
-        x, choice, weight, num_experts, f, experts_held=held,
-        first_expert=first, gate_attr=fluid.ParamAttr(name="wg"),
-        up_attr=fluid.ParamAttr(name="wu"),
-        down_attr=fluid.ParamAttr(name="wd")),
+    main, scope, exe, out, grads = _run(
+        lambda x, choice, weight: layers.moe_experts(
+            x, choice, weight, num_experts, f, experts_held=held,
+            first_expert=first, gate_attr=fluid.ParamAttr(name="wg"),
+            up_attr=fluid.ParamAttr(name="wu"),
+            down_attr=fluid.ParamAttr(name="wd")),
         {"x": c["x"], "choice": c["choice"], "weight": c["weight"]},
         ["x", "weight", "wg", "wu", "wd"])
-    return _fetch(*prog[:3], {"x": c["x"], "choice": c["choice"],
-                              "weight": c["weight"]}, *prog[3:], c["cot"],
+    more = [out.block.var(out.op.output(slot)[0]) for slot in slots]
+    return _fetch(main, scope, exe, {"x": c["x"], "choice": c["choice"],
+                                     "weight": c["weight"]}, out,
+                  list(grads) + more, c["cot"],
                   {"wg": c["wg"], "wu": c["wu"], "wd": c["wd"]})
 
 
@@ -313,6 +317,106 @@ def test_moe_experts_dropless_under_imbalance(case, path, request):
     if case == "none_held":
         out, _ = _experts_program(c, 12, 4, 4)
         assert not out.any()
+
+
+# 256 tokens x 4 choices, 4 of 16 experts held (4..7): prefixes of 6 and
+# 8 tiles (the buffers of a quarter and of half the 1,024 choices) under
+# the worst case's 12. A routing is the rows sent to each held expert.
+_PREFIX_ROUTINGS = {
+    "a_quarter_of_the_choices": ((70, 60, 66, 60), 768, 4),
+    "at_the_first_edge": ((384, 1, 1, 1), 768, 6),
+    "one_tile_past_the_first_edge": ((385, 1, 1, 1), 1024, 7),
+    "at_the_second_edge": ((300, 300, 100, 100), 1024, 8),
+    "every_choice_to_one_held_expert": ((0, 1024, 0, 0), 1536, 11),
+}
+
+
+def _routed(sizes, t=256, k=4, first=4, seed=60):
+    """int32 [t, k] choices that send sizes[e] rows to held expert e and
+    the rest to experts held elsewhere, shuffled."""
+    flat = np.concatenate(
+        [np.full(n, first + e) for e, n in enumerate(sizes)]
+        + [np.arange(t * k - sum(sizes)) % first])
+    return np.random.default_rng(seed).permutation(flat).reshape(t, k)
+
+
+@pytest.mark.parametrize("path", ["lowered", "kernels"])
+@pytest.mark.parametrize("routing", sorted(_PREFIX_ROUTINGS))
+def test_moe_experts_over_a_prefix_equals_the_worst_case_body(
+        routing, path, request, monkeypatch):
+    """Whatever prefix of the row buffer the routing picks, the worst
+    case included, Out and all five gradients are BIT-EQUAL to the one
+    body over the whole worst-case buffer; n_active at a prefix's edge
+    takes that prefix and one tile more takes the next."""
+    if path == "kernels":
+        request.getfixturevalue("interp")
+    sizes, prefix, tiles = _PREFIX_ROUTINGS[routing]
+    c = _experts_case(256, 16, 8, 4, _routed(sizes), seed=61)
+    assert gm.prefix_rows(1024, 4, 16) == [768, 1024, 1536]
+    out, got = _experts_program(c, 16, 4, 4, slots=["RowsWorked"])
+    assert got.pop().tolist() == [prefix, tiles * gm.TILE_ROWS]
+    monkeypatch.setattr(
+        gm, "prefix_rows", lambda n, held, num: [gm.buffer_rows(n, held)])
+    whole, want = _experts_program(c, 16, 4, 4, slots=["RowsWorked"])
+    assert want.pop().tolist() == [1536, tiles * gm.TILE_ROWS]
+    assert np.abs(whole).max() > 0
+    np.testing.assert_array_equal(out, whole)
+    for name, g, w in zip(("x", "weight", "wg", "wu", "wd"), got, want):
+        np.testing.assert_array_equal(g, w, err_msg=name)
+
+
+@pytest.mark.parametrize("shape,want", [
+    ((8192 * 8, 16, 128), [10240, 18432, 34816, 67584]),   # keye2_s8192
+    ((4096 * 6, 16, 128), [5120, 8192, 14336, 26624]),     # kanana2_s4096
+    ((4096 * 6, 32, 128), [10240, 16384, 28672]),         # a quarter held
+    ((4096 * 6, 128, 128), [40960]),                       # every expert
+    ((150 * 3, 12, 12), [2048]),
+], ids=["keye", "kanana", "a_quarter", "all_of_128", "all_of_12"])
+def test_prefix_ladder_by_hand(shape, want):
+    """The buffers of a share s = held / experts of the choices, of 2s
+    and of 4s (those under all the choices), then the worst case."""
+    assert gm.prefix_rows(*shape) == want
+    assert want[-1] == gm.buffer_rows(shape[0], shape[1])
+
+
+def _experts_jaxprs(num_experts, held):
+    """The jaxprs of the forward and the grad lowering of one
+    `moe_experts` op built by the layer."""
+    from paddle_tpu.core.registry import OPS, ExecContext
+    c = _experts_case(300, 16, 8, held,
+                      _random_choice(300, 2, num_experts, 3))
+    main = _run(lambda x, choice, weight: layers.moe_experts(
+        x, choice, weight, num_experts, 8, experts_held=held),
+        {"x": c["x"], "choice": c["choice"], "weight": c["weight"]},
+        ["x", "weight"])[0]
+    ops = {op.type: op for op in main.global_block().ops
+           if op.type.startswith("moe_experts")}
+
+    def lower(op_type, env):
+        env = dict(env)
+        OPS.get(op_type).lowering(ExecContext(ops[op_type], env))
+        return env
+
+    fwd = ops["moe_experts"]
+    env = {fwd.input("X")[0]: c["x"], fwd.input("TopkIdx")[0]: c["choice"],
+           fwd.input("TopkWeight")[0]: c["weight"],
+           fwd.input("WGate")[0]: c["wg"], fwd.input("WUp")[0]: c["wu"],
+           fwd.input("WDown")[0]: c["wd"]}
+    after = jax.eval_shape(lambda e: lower("moe_experts", e), env)
+    env_grad = {n: np.zeros(a.shape, a.dtype) for n, a in after.items()}
+    env_grad[ops["moe_experts_grad"].input("Out@GRAD")[0]] = c["cot"]
+    return (str(jax.make_jaxpr(lambda e: lower("moe_experts", e))(env)),
+            str(jax.make_jaxpr(
+                lambda e: lower("moe_experts_grad", e))(env_grad)))
+
+
+@pytest.mark.parametrize("held,branches", [(12, 0), (4, 1)],
+                         ids=["every_expert_held", "a_third_held"])
+def test_moe_experts_branches_only_where_a_share_is_held(held, branches):
+    """Every expert held: one prefix, the body itself, no `cond` in the
+    forward's or the grad op's jaxpr. A share held: one `cond` each."""
+    for jaxpr in _experts_jaxprs(12, held):
+        assert jaxpr.count("cond[") == branches, jaxpr[:2000]
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -842,3 +946,55 @@ def test_expert_load_reader():
     assert stats["held_rows_per_token"] == pytest.approx(0.75)
     assert stats["load_max_over_mean"] == pytest.approx(2.0)
     assert moe.load_stats(np.zeros((2, 4)), 80) is None
+
+
+def test_rows_worked_counter_adds_up_over_two_steps():
+    """The model's `moe_rows_worked` after each of two steps against the
+    same steps' `moe_expert_load`: a layer's tiles in use are its held
+    experts' rows in whole tiles (one for an expert nobody chose), and
+    the prefix it took is the ladder's first that holds them."""
+    from paddle_tpu import models
+    from paddle_tpu.observability import moe
+    sz = _model_sizes()
+    tr = family.traffic({"pool": 2, "reference_rows_per_block": 1}, True)
+    fluid.framework.unique_name.reset()
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 1
+    with fluid.program_guard(main, startup):
+        cost, _, _ = models.decoder_lm_train(family.model_config(sz))
+    scope, pool = Scope(), family.make_pool(sz, tr, 11)
+    choices = tr["batch"] * tr["seq_len"] * sz["num_experts_per_tok"]
+    ladder = gm.prefix_rows(choices, sz["experts_held"],
+                            sz["router_experts"])
+    assert len(ladder) == 3
+    want, load = np.zeros((2, 2), np.int64), 0
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        for step in range(2):
+            exe.run(main, feed=pool[step], fetch_list=[cost])
+            seen, load = moe.expert_load(scope) - load, moe.expert_load(scope)
+            tiles = np.maximum(1, -(-seen // gm.TILE_ROWS)).sum(axis=1)
+            want[:, 0] += [min(r for r in ladder if r >= n * gm.TILE_ROWS)
+                           for n in tiles]
+            want[:, 1] += tiles * gm.TILE_ROWS
+            worked = moe.rows_worked(scope)
+            assert worked.dtype == np.int64 and (worked == want).all()
+    stats = moe.rows_worked_stats(worked, 2, ladder[-1])
+    assert stats["worked_share_of_worst"] == pytest.approx(
+        (want[:, 0] / (2 * ladder[-1])).tolist())
+    assert max(stats["worked_share_of_worst"]) < 1
+
+
+def test_rows_worked_reader():
+    from paddle_tpu.observability import moe
+    scope = Scope()
+    assert moe.rows_worked(scope) is None       # a program without it
+    scope.var(moe.ROWS_WORKED_VAR).set_value(
+        jnp.asarray([[2048, 1024], [3072, 1536]], jnp.int32))
+    worked = moe.rows_worked(scope)
+    assert worked.dtype == np.int64 and worked.shape == (2, 2)
+    stats = moe.rows_worked_stats(worked, steps=2, worst_rows=2048)
+    assert stats["worked_share_of_worst"] == pytest.approx([0.5, 0.75])
+    assert stats["worked_over_in_use"] == pytest.approx([2.0, 2.0])
+    assert moe.rows_worked_stats(np.zeros((2, 2)), 2, 2048) is None
