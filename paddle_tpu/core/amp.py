@@ -61,6 +61,7 @@ GRAY_OPS = frozenset({
     "reshape2", "reshape", "transpose2", "transpose", "squeeze2",
     "squeeze", "unsqueeze2", "unsqueeze", "expand", "flatten2",
     "flatten", "add_position_encoding", "rotary_embedding", "swiglu",
+    "relu2",
 })
 
 # numerically sensitive: always f32 compute (extended per-config via the

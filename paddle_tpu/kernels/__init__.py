@@ -12,3 +12,4 @@ from .fused_optimizer import bucket_sweep, fused_adam, fused_sgd  # noqa: F401,E
 from .quantized_matmul import quantized_matmul  # noqa: F401
 from . import grouped_matmul  # noqa: F401
 from . import sparse_index  # noqa: F401
+from . import mamba2_ssd  # noqa: F401

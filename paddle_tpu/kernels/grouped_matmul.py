@@ -43,6 +43,7 @@ run. Decisions land in `dispatch_stats()` under `moe_grouped_matmul`.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +56,10 @@ from . import registry
 TILE_ROWS = 128
 # the dw kernel's output block [bk, n] f32 is held to this many bytes
 _DW_BLOCK_BYTES = 2 * 1024 * 1024
+# Mosaic's scoped VMEM unless a call asks for more; a call whose blocks,
+# double-buffered, do not fit asks for what they take and this much beside
+_VMEM_DEFAULT_LIMIT = 16 << 20
+_VMEM_MARGIN = 4 << 20
 
 __all__ = ["TILE_ROWS", "plan_rows", "buffer_rows", "prefix_rows",
            "prefix_plan", "prefix_index", "gmm", "gmm_dx", "gmm_dw",
@@ -179,6 +184,19 @@ def _gmm_kernel(te_ref, na_ref, lhs_ref, rhs_ref, out_ref, *, transpose):
             preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
+def _params(semantics, *blocks):
+    """Compiler parameters of a call whose double-buffered `blocks`
+    ((shape, dtype) each) may pass Mosaic's default VMEM: one expert's
+    [2688, 1856] matrix is 10 MB. A call that fits the default gets the
+    parameters it always had."""
+    need = 2 * sum(jnp.dtype(dtype).itemsize * math.prod(shape)
+                   for shape, dtype in blocks) + _VMEM_MARGIN
+    if need <= _VMEM_DEFAULT_LIMIT:
+        return pltpu.CompilerParams(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=int(need))
+
+
 def _gmm_call(name, lhs, rhs, plan, transpose, tile):
     rows, k = lhs.shape
     n_out = rhs.shape[1] if transpose else rhs.shape[2]
@@ -196,8 +214,9 @@ def _gmm_call(name, lhs, rhs, plan, transpose, tile):
         functools.partial(_gmm_kernel, transpose=transpose),
         name=name, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, n_out), lhs.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=_params(
+            ("arbitrary",), ((tile, k), lhs.dtype),
+            (rhs.shape[1:], rhs.dtype), ((tile, n_out), lhs.dtype)),
         interpret=registry.interpret(),
     )(plan["tile_expert"], plan["n_active"], lhs, rhs)
 
@@ -234,22 +253,35 @@ def _dw_block(k, n):
 def _dw_call(lhs, dout, plan, experts_held, tile):
     rows, k = lhs.shape
     n = dout.shape[1]
-    bk = _dw_block(k, n)
+    # the output is blocked along k, the lhs block's lanes; a k that is
+    # no multiple of 128 (an expert width of 1,856) stays whole there and
+    # the output is blocked along n instead
+    if k % 128 and n % 128 == 0:
+        bk, bn = k, _dw_block(n, k)
+    else:
+        bk, bn = _dw_block(k, n), n
+
+    def block(j):
+        """(k block, n block) of the grid's first axis."""
+        return (j, 0) if bn == n else (0, j)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(k // bk, rows // tile),
+        num_scalar_prefetch=2, grid=(k // bk * (n // bn), rows // tile),
         in_specs=[
             pl.BlockSpec((tile, bk),
-                         lambda j, t, te, na: (_in_use(t, na), j)),
-            pl.BlockSpec((tile, n), lambda j, t, te, na: (_in_use(t, na), 0)),
+                         lambda j, t, te, na: (_in_use(t, na), block(j)[0])),
+            pl.BlockSpec((tile, bn),
+                         lambda j, t, te, na: (_in_use(t, na), block(j)[1])),
         ],
         out_specs=pl.BlockSpec(
-            (None, bk, n), lambda j, t, te, na: (te[_in_use(t, na)], j, 0)))
+            (None, bk, bn),
+            lambda j, t, te, na: (te[_in_use(t, na)],) + block(j)))
     return pl.pallas_call(
         _dw_kernel, name="moe_grouped_matmul_dw", grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((experts_held, k, n), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_params(
+            ("parallel", "arbitrary"), ((tile, bk), lhs.dtype),
+            ((tile, bn), dout.dtype), ((bk, bn), jnp.float32)),
         interpret=registry.interpret(),
     )(plan["tile_expert"], plan["n_active"], lhs, dout)
 
@@ -334,12 +366,16 @@ def gmm_dw(lhs, dout, plan, experts_held, kernels, tile=TILE_ROWS):
 
 
 def _eligible(sig: registry.Signature) -> bool:
-    """bf16 or f32 rows whose widths Mosaic can tile: both matrix
-    dimensions multiples of 128 (the interpreter takes any)."""
+    """bf16 or f32 rows whose widths Mosaic can tile: one matrix
+    dimension a multiple of 128 and the other of 64 (an expert width of
+    1,856 = 29 x 64 stays whole in every block and Mosaic pads its
+    lanes; the interpreter takes any)."""
     (rows, k), (_, k2, n) = sig.shapes[0], sig.shapes[1]
     return (sig.dtypes[0] in ("bfloat16", "float32")
             and sig.dtypes[0] == sig.dtypes[1] and k == k2
-            and (registry._INTERPRET or (k % 128 == 0 and n % 128 == 0)))
+            and (registry._INTERPRET
+                 or (k % 64 == 0 and n % 64 == 0
+                     and (k % 128 == 0 or n % 128 == 0))))
 
 
 registry.register_kernel(

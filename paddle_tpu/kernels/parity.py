@@ -525,6 +525,34 @@ def _sparse_index_case(which):
                 "sparse_index_%s/f32/2x256x4x32" % which, run)
 
 
+def _ssd_case(which):
+    """Mamba-2's scan at 2 x 296 tokens (no multiple of the chunk), 8
+    heads of 16 in 2 groups, state 32: the forward kernel's y and
+    chunk-start states, and the backward kernel's six gradients, against
+    the `jax.numpy` chunked form and its `jax.vjp` (f32, "highest")."""
+    def run():
+        import jax
+        from . import mamba2_ssd as ssd
+        r = _rng(31)
+        b, t, h, p, g, n = 2, 296, 8, 16, 2, 32
+
+        def draw(*shape):
+            return jnp.asarray(r.standard_normal(shape, dtype=np.float32))
+        x, bm, cm, dy = draw(b, t, h, p), draw(b, t, g, n), \
+            draw(b, t, g, n), draw(b, t, h, p)
+        dt = jax.nn.softplus(draw(b, t, h) - 1.0)
+        a, d = -jnp.exp(draw(h) * 0.5), draw(h)
+        with jax.default_matmul_precision("highest"):
+            got, ref = (ssd.ssd(x, dt, a, bm, cm, d, kern)
+                        for kern in (True, False))
+            if which == "bwd":
+                got, ref = (ssd.ssd_grad(x, dt, a, bm, cm, d, ref[1], dy,
+                                         kern) for kern in (True, False))
+        return {"metric": "rel_vs_lowered", "tol": 1e-5,
+                "value": max(rel_err(w, v) for v, w in zip(got, ref))}
+    return Case("mamba2_ssd", "mamba2_ssd/%s/f32/2x296x8x16" % which, run)
+
+
 # shapes the fused optimizer blocks over their own layout
 # (fused_optimizer._native_block): one whole block; N off the 128 lanes
 # in a whole-row block; N over whole-row width and off the 512-column
@@ -542,6 +570,7 @@ def cases() -> List[Case]:
     import importlib
     from . import fused_optimizer, grouped_matmul  # noqa: F401
     from . import quantized_matmul, sparse_index  # noqa: F401
+    from . import mamba2_ssd  # noqa: F401
     importlib.import_module("paddle_tpu.kernels.flash_attention")
     return [
         _adam_case((4096,)),        # rank 1: the flat view
@@ -561,6 +590,8 @@ def cases() -> List[Case]:
         _gmm_case("dw"),
         _sparse_index_case("scores"),
         _sparse_index_case("select"),
+        _ssd_case("fwd"),
+        _ssd_case("bwd"),
     ]
 
 

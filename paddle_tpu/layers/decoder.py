@@ -1,16 +1,18 @@
 """Layers of a current decoder-only language model block (ops in
-ops/decoder.py): RMS normalisation, rotary positions, the gated
-feed-forward's activation, the top-k router, the expert layer that is
-told which experts this chip holds, and a learned sparse attention's
-index."""
+ops/decoder.py): RMS normalisation, rotary positions, the feed-forward's
+activation (gated, or a squared ReLU), the top-k router, the expert layer
+that is told which experts this chip holds, a learned sparse attention's
+index, and a Mamba-2 mixer's three ops: the short causal convolution,
+the state-space scan and the gated group-wise RMS norm."""
 from __future__ import annotations
 
 from ..initializer import Constant, Normal
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
-__all__ = ["rms_norm", "rotary_embedding", "swiglu", "moe_router",
-           "moe_experts", "sparse_attention_index"]
+__all__ = ["rms_norm", "rotary_embedding", "swiglu", "relu2", "moe_router",
+           "moe_experts", "sparse_attention_index", "causal_conv1d",
+           "gated_rms_norm", "mamba2_ssd"]
 
 
 def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
@@ -48,6 +50,14 @@ def swiglu(gate, up, name=None):
     out = helper.create_variable_for_type_inference(gate.dtype)
     helper.append_op("swiglu", inputs={"X": gate, "Y": up},
                      outputs={"Out": out})
+    return out
+
+
+def relu2(x, name=None):
+    """max(x, 0)^2."""
+    helper = LayerHelper("relu2", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("relu2", inputs={"X": x}, outputs={"Out": out})
     return out
 
 
@@ -89,36 +99,45 @@ def moe_router(input, num_experts, top_k, experts_held=None, first_expert=0,
 
 def moe_experts(input, choice, weight, num_experts, expert_width,
                 experts_held=None, first_expert=0, gate_attr=None,
-                up_attr=None, down_attr=None, name=None):
-    """The routed SwiGLU experts `first_expert .. first_expert +
-    experts_held - 1` of a layer of `num_experts`, dropless: three
-    stacked parameters [experts_held, D, F], [experts_held, D, F],
-    [experts_held, F, D]. A choice of an expert held elsewhere adds
-    nothing here. The op's `RowsWorked` output (int32 [2]: rows of the
-    buffer the layer worked over, rows in use) is found through
-    `<result>.op`."""
+                up_attr=None, down_attr=None, activation="swiglu",
+                name=None):
+    """The routed experts `first_expert .. first_expert + experts_held
+    - 1` of a layer of `num_experts`, dropless. `activation` "swiglu":
+    gated experts, down(silu(gate(x)) * up(x)), three stacked parameters
+    [experts_held, D, F], [experts_held, D, F], [experts_held, F, D];
+    "relu2": ungated experts, down(relu(up(x))^2), the last two alone. A
+    choice of an expert held elsewhere adds nothing here. The op's
+    `RowsWorked` output (int32 [2]: rows of the buffer the layer worked
+    over, rows in use) is found through `<result>.op`."""
+    if activation not in ("swiglu", "relu2"):
+        raise ValueError(f"moe_experts activation {activation!r}")
     helper = LayerHelper("moe_experts", name=name)
     held = int(experts_held or num_experts)
     d, f = int(input.shape[-1]), int(expert_width)
     init = Normal(0.0, 0.02)
-    w_gate = helper.create_parameter(gate_attr, [held, d, f], "float32",
-                                     default_initializer=init)
-    w_up = helper.create_parameter(up_attr, [held, d, f], "float32",
-                                   default_initializer=init)
-    w_down = helper.create_parameter(down_attr, [held, f, d], "float32",
-                                     default_initializer=init)
+    inputs = {"X": input, "TopkIdx": choice, "TopkWeight": weight}
     out = helper.create_variable_for_type_inference(input.dtype)
-    gate = helper.create_variable_for_type_inference(input.dtype, True)
-    up = helper.create_variable_for_type_inference(input.dtype, True)
-    worked = helper.create_variable_for_type_inference("int32", True)
-    helper.append_op(
-        "moe_experts",
-        inputs={"X": input, "TopkIdx": choice, "TopkWeight": weight,
-                "WGate": w_gate, "WUp": w_up, "WDown": w_down},
-        outputs={"Out": out, "GateAct": gate, "UpAct": up,
-                 "RowsWorked": worked},
-        attrs={"num_experts": int(num_experts), "experts_held": held,
-               "first_expert": int(first_expert)})
+    outputs = {"Out": out}
+    attrs = {"num_experts": int(num_experts), "experts_held": held,
+             "first_expert": int(first_expert)}
+    if activation == "swiglu":
+        inputs["WGate"] = helper.create_parameter(
+            gate_attr, [held, d, f], "float32", default_initializer=init)
+    else:
+        attrs["activation"] = activation
+    inputs["WUp"] = helper.create_parameter(
+        up_attr, [held, d, f], "float32", default_initializer=init)
+    inputs["WDown"] = helper.create_parameter(
+        down_attr, [held, f, d], "float32", default_initializer=init)
+    if activation == "swiglu":
+        outputs["GateAct"] = helper.create_variable_for_type_inference(
+            input.dtype, True)
+    outputs["UpAct"] = helper.create_variable_for_type_inference(
+        input.dtype, True)
+    outputs["RowsWorked"] = helper.create_variable_for_type_inference(
+        "int32", True)
+    helper.append_op("moe_experts", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
     return out
 
 
@@ -139,3 +158,67 @@ def sparse_attention_index(index_q, index_k, index_w, top_k, scale=1.0,
         outputs={"Mask": mask, "Kept": kept},
         attrs={"top_k": int(top_k), "scale": float(scale)})
     return mask, kept
+
+
+def causal_conv1d(input, kernel_size, act=None, param_attr=None,
+                  bias_attr=None, name=None):
+    """A depthwise convolution along the sequence of input [B, T, C]
+    that looks back only: out[t] = act(b + sum_j w[:, j] * input[t -
+    (kernel_size - 1) + j]), w [C, kernel_size], b [C] (`bias_attr`
+    False: none); `act` None or "silu"."""
+    helper = LayerHelper("causal_conv1d", name=name)
+    c = int(input.shape[-1])
+    inputs = {"X": input, "Weight": helper.create_parameter(
+        param_attr, [c, int(kernel_size)], "float32",
+        default_initializer=Normal(0.0, 0.02))}
+    if bias_attr is not False:
+        inputs["Bias"] = helper.create_parameter(
+            ParamAttr._to_attr(bias_attr), [c], "float32", is_bias=True)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("causal_conv1d", inputs=inputs, outputs={"Out": out},
+                     attrs={"activation": act or ""})
+    return out
+
+
+def gated_rms_norm(input, gate, groups=1, epsilon=1e-5, param_attr=None,
+                   name=None):
+    """RMSNorm(input * silu(gate)) * w, the mean square taken within
+    each of `groups` equal groups of the last axis; w [C] from ones."""
+    helper = LayerHelper("gated_rms_norm", name=name)
+    scale = helper.create_parameter(param_attr, [int(input.shape[-1])],
+                                    "float32",
+                                    default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("gated_rms_norm",
+                     inputs={"X": input, "Gate": gate, "Scale": scale},
+                     outputs={"Y": out},
+                     attrs={"groups": int(groups),
+                            "epsilon": float(epsilon)})
+    return out
+
+
+def mamba2_ssd(x, dt, b, c, chunk_size=128, dt_bias_attr=None,
+               a_log_attr=None, d_attr=None, name=None):
+    """Mamba-2's state-space scan over x [B, T, H, P] with step sizes
+    dt [B, T, H] (before their bias and softplus) and the groups' b, c
+    [B, T, G, N]; three parameters a head: the step's bias, log(-A) and
+    the skip weight D. Returns (y [B, T, H, P], tokens scanned int32
+    [1])."""
+    helper = LayerHelper("mamba2_ssd", name=name)
+    h = int(x.shape[2])
+
+    def per_head(attr, value):
+        return helper.create_parameter(attr, [h], "float32",
+                                       default_initializer=Constant(value))
+
+    inputs = {"X": x, "Dt": dt, "B": b, "C": c,
+              "DtBias": per_head(dt_bias_attr, 0.0),
+              "ALog": per_head(a_log_attr, 0.0),
+              "D": per_head(d_attr, 1.0)}
+    y = helper.create_variable_for_type_inference(x.dtype)
+    states = helper.create_variable_for_type_inference("float32", True)
+    tokens = helper.create_variable_for_type_inference("int32", True)
+    helper.append_op("mamba2_ssd", inputs=inputs,
+                     outputs={"Y": y, "States": states, "Tokens": tokens},
+                     attrs={"chunk_size": int(chunk_size)})
+    return y, tokens

@@ -23,6 +23,19 @@ router and which feed-forwards a layer gets follow from the published
       `mlp_only_layers`, and with `decoder_sparse_step` n every layer
       whose number (from 1) is no multiple of n: a dense SwiGLU of
       `intermediate_size` in place of the experts.
+  feed-forwards  gated SwiGLU, three matrices (`hidden_act` "silu"), or
+      with `mlp_hidden_act` "relu2" ungated, down(relu(up(x))^2), two:
+      the routed experts, the shared expert (of
+      `moe_shared_expert_intermediate_size` where that key is given)
+      and the dense layers alike.
+  hybrid      `hybrid_override_pattern` (the `nemotron_h` key): ONE part
+      a layer, one character each — `M` a Mamba-2 mixer
+      (`mamba_num_heads` heads of `mamba_head_dim`, `n_groups` groups of
+      B and C, state `ssm_state_size`, a causal depthwise convolution of
+      `conv_kernel` taps, scan chunks of `chunk_size`), `*` attention,
+      `E` the expert layer, `-` a dense feed-forward. Such a model's
+      attention has NO rotary and no q / k norm: its modelling code has
+      neither, the positions come from the mixers.
 
 Three more keys say which share of each layer THIS chip holds under
 expert and vocabulary parallelism:
@@ -35,11 +48,16 @@ expert and vocabulary parallelism:
   vocab_held   rows of the embedding and of the head held here (default:
       all); ids, logits and the loss are over that slice.
 
-Every layer: h += Attn(RMSNorm(h)); h += FFN(RMSNorm(h)).
+Every layer: h += Attn(RMSNorm(h)); h += FFN(RMSNorm(h)); with a
+pattern h += part(RMSNorm(h)), one part.
 
 Published keys that say nothing about the shapes built here
 (`max_position_embeddings`, `model_type`, ...) are accepted and ignored,
-so a `config.json` can be passed whole.
+so a `config.json` can be passed whole. Keys that change a layer's
+equations and are not built here raise NotImplementedError by name
+(`moe_latent_size`, `num_nextn_predict_layers`, `sliding_window`,
+`layer_types`, ...): a file that carries one is never built as some
+other model.
 
 Every parameter has an explicit, stable name (`layer_<i>_attn_q.w_0`,
 `layer_<i>_experts_gate.w_0`, ...). The indexer's weights
@@ -52,49 +70,119 @@ expert and `moe_rows_worked` [MoE layers, 2] the rows of its row buffer
 each expert layer worked over and the rows of it in use
 (observability/moe.py reads both); `sparse_attn_kept` [layers] is
 OVERWRITTEN with the (query, key) pairs each layer's selection kept
-(observability/sparse_attention.py).
+(observability/sparse_attention.py). A model with mixers keeps a fourth,
+`mamba_ssd_tokens` [mixer layers], OVERWRITTEN with the tokens each
+mixer's scan went over (observability/mamba.py).
+
+A mixer's parameters (`layer_<i>_mixer_*`): `in.w_0` [D, 2 H P + 2 G N
++ H] (the gate z, the convolution's input x | B | C, the step sizes dt),
+`conv.w_0` [H P + 2 G N, taps] and `conv.b_0`, `dt.b_0`, `a_log.w_0`,
+`d.w_0` [H], `norm.w_0` [H P], `out.w_0` [H P, D]. The startup program
+draws `a_log` as the log of values spread evenly over [1, 16] and
+`dt.b_0` as the inverse softplus of steps spread evenly in the log over
+[`time_step_min`, `time_step_max`] (the published initialiser draws both
+at random from those ranges), `d` at one, the convolution's weight and
+bias from U(-1 / sqrt(taps), 1 / sqrt(taps)) (a depthwise Conv1d's own
+default, which the published initialiser leaves as it is).
 """
 from __future__ import annotations
 
 from .. import layers
 from ..framework import name_scope
-from ..initializer import Constant, Normal
+import numpy as np
+
+from ..initializer import (Constant, Normal, NumpyArrayInitializer,
+                           Uniform)
+from ..observability.mamba import SSD_TOKENS_VAR
 from ..observability.moe import EXPERT_LOAD_VAR, ROWS_WORKED_VAR
 from ..observability.sparse_attention import KEPT_PAIRS_VAR
 from ..param_attr import ParamAttr
 
 
+# one character of `hybrid_override_pattern` -> the layer's one part (and
+# its op scope)
+PATTERN_PARTS = {"M": "mamba", "*": "attn", "E": "moe", "-": "mlp"}
+
+
+def parse_pattern(pattern):
+    """`hybrid_override_pattern` -> the parts of its layers, in order."""
+    bad = sorted(set(pattern) - set(PATTERN_PARTS))
+    if bad or not pattern:
+        raise ValueError(f"hybrid_override_pattern {pattern!r}: a layer is "
+                         f"one of {sorted(PATTERN_PARTS)}, not {bad}")
+    return [PATTERN_PARTS[ch] for ch in pattern]
+
+
 class DecoderLMConfig:
     def __init__(self, vocab_size=32000, hidden_size=2048,
-                 num_hidden_layers=4, num_attention_heads=32,
+                 num_hidden_layers=None, num_attention_heads=32,
                  kv_lora_rank=None, q_lora_rank=None, qk_nope_head_dim=128,
                  qk_rope_head_dim=64, v_head_dim=128,
                  num_key_value_heads=None, head_dim=None, sa_config=None,
                  rope_theta=10000.0, rope_interleave=None,
-                 rope_scaling=None, rms_norm_eps=1e-6,
+                 rope_scaling=None, rms_norm_eps=None,
+                 layer_norm_epsilon=None, norm_eps=None,
                  intermediate_size=6144, first_k_dense_replace=0,
                  mlp_only_layers=(), decoder_sparse_step=1,
                  moe_layer_freq=1, n_routed_experts=None, num_experts=None,
                  num_experts_per_tok=6, n_shared_experts=0,
-                 moe_intermediate_size=768, routed_scaling_factor=1.0,
+                 moe_intermediate_size=768,
+                 moe_shared_expert_intermediate_size=None,
+                 routed_scaling_factor=1.0,
                  norm_topk_prob=True, scoring_func=None,
                  topk_method="noaux_tc", n_group=1, topk_group=1,
-                 hidden_act="silu", attention_bias=False,
+                 hidden_act="silu", mlp_hidden_act=None,
+                 attention_bias=False,
                  tie_word_embeddings=False, initializer_range=0.02,
+                 hybrid_override_pattern=None, mamba_num_heads=None,
+                 mamba_head_dim=None, n_groups=1, ssm_state_size=128,
+                 conv_kernel=4, chunk_size=128, use_conv_bias=True,
+                 mamba_hidden_act="silu", mamba_proj_bias=False,
+                 mlp_bias=False, use_bias=False, time_step_min=0.001,
+                 time_step_max=0.1, time_step_floor=1e-4,
+                 time_step_limit=None, moe_latent_size=None,
+                 num_nextn_predict_layers=0, sliding_window=None,
+                 layer_types=None,
                  experts_held=None, first_expert=0, vocab_held=None,
                  **unused):
         if q_lora_rank is not None:
             raise NotImplementedError("query compression (q_lora_rank)")
+        # keys that change a layer's equations and are not built here: a
+        # file that carries one must not build as some other model
+        for key, given in (
+                ("moe_latent_size", moe_latent_size is not None),
+                ("num_nextn_predict_layers", bool(num_nextn_predict_layers)),
+                ("sliding_window", sliding_window is not None),
+                ("layer_types", layer_types is not None)):
+            if given:
+                raise NotImplementedError(
+                    f"{key}: the model file builds no such layer")
         scaling = rope_scaling or {}
         scaling = scaling.get("rope_type", scaling.get("type", "default"))
         if scaling != "default":
             raise NotImplementedError(f"rope scaling {scaling!r}")
-        if hidden_act != "silu" or attention_bias or tie_word_embeddings:
+        act = mlp_hidden_act or hidden_act
+        if act not in ("silu", "relu2") or attention_bias \
+                or tie_word_embeddings or mlp_bias or use_bias:
             raise NotImplementedError(
-                "silu, no attention bias, untied head only")
+                "silu (gated) or relu2 (ungated) feed-forwards, no bias, "
+                "untied head only")
         if moe_layer_freq != 1:
             raise NotImplementedError("moe_layer_freq other than 1")
-        if (n_routed_experts is None) == (num_experts is None):
+        self.parts = None if hybrid_override_pattern is None \
+            else parse_pattern(hybrid_override_pattern)
+        if self.parts is None:
+            num_hidden_layers = 4 if num_hidden_layers is None \
+                else num_hidden_layers
+        elif num_hidden_layers not in (None, len(self.parts)):
+            raise ValueError(
+                f"num_hidden_layers {num_hidden_layers} against a pattern "
+                f"of {len(self.parts)} layers")
+        else:
+            num_hidden_layers = len(self.parts)
+        has_experts = self.parts is None or "moe" in self.parts
+        if has_experts and \
+                (n_routed_experts is None) == (num_experts is None):
             raise ValueError("one of n_routed_experts and num_experts")
         self.vocab_size = int(vocab_held or vocab_size)
         self.hidden_size = hidden_size
@@ -116,7 +204,13 @@ class DecoderLMConfig:
         # half-split pairs of every other decoder
         self.rope_interleave = bool(kv_lora_rank) \
             if rope_interleave is None else bool(rope_interleave)
-        self.rms_norm_eps = rms_norm_eps
+        # a hybrid's attention: no rotary, no q / k norm
+        self.attention_positions = self.parts is None
+        eps = {e for e in (rms_norm_eps, layer_norm_epsilon, norm_eps)
+               if e is not None}
+        if len(eps) > 1:
+            raise ValueError(f"the norm's epsilon given as {sorted(eps)}")
+        self.rms_norm_eps = eps.pop() if eps else 1e-6
         self.intermediate_size = intermediate_size
         self.dense_layers = {
             i for i in range(num_hidden_layers)
@@ -126,6 +220,9 @@ class DecoderLMConfig:
         self.num_experts_per_tok = num_experts_per_tok
         self.n_shared_experts = n_shared_experts
         self.moe_intermediate_size = moe_intermediate_size
+        self.shared_expert_width = n_shared_experts * (
+            moe_shared_expert_intermediate_size or moe_intermediate_size)
+        self.gated_ffn = act == "silu"
         self.routed_scaling_factor = routed_scaling_factor
         self.norm_topk_prob = norm_topk_prob
         # the two key families' own modelling code: sigmoid scores with
@@ -136,11 +233,32 @@ class DecoderLMConfig:
             and topk_method == "noaux_tc"
         self.n_group, self.topk_group = n_group, topk_group
         self.initializer_range = initializer_range
-        self.experts_held = int(experts_held or self.n_routed_experts)
+        self.experts_held = int(experts_held or self.n_routed_experts or 0)
         self.first_expert = int(first_expert)
+        if self.parts and "mamba" in self.parts:
+            if mamba_hidden_act != "silu" or mamba_proj_bias:
+                raise NotImplementedError(
+                    "a mixer with silu and no projection bias only")
+            if time_step_limit is not None and (
+                    time_step_limit[0] or time_step_limit[1]
+                    not in (None, float("inf"))):
+                raise NotImplementedError("a clamp on the step sizes "
+                                          "(time_step_limit)")
+            if not mamba_num_heads or not mamba_head_dim \
+                    or mamba_num_heads % n_groups:
+                raise ValueError("mamba_num_heads and mamba_head_dim, the "
+                                 "heads a multiple of n_groups")
+        self.mamba_num_heads = mamba_num_heads
+        self.mamba_head_dim = mamba_head_dim
+        self.n_groups, self.ssm_state_size = n_groups, ssm_state_size
+        self.conv_kernel, self.chunk_size = conv_kernel, chunk_size
+        self.use_conv_bias = bool(use_conv_bias)
+        self.time_step = (time_step_min, time_step_max, time_step_floor)
 
     @property
     def moe_layers(self):
+        if self.parts is not None:
+            return [i for i, part in enumerate(self.parts) if part == "moe"]
         return [i for i in range(self.num_hidden_layers)
                 if i not in self.dense_layers]
 
@@ -232,7 +350,8 @@ def grouped_query_attention(x, cfg, name):
     """Causal attention of `num_attention_heads` query heads over
     `num_key_value_heads` key / value heads of `head_dim` (k and v go to
     the op at their own head count), q and k RMS-normalised per head and
-    then rotated; with `sa_config` over the keys the indexer keeps.
+    then rotated (neither in a hybrid: `attention_positions`); with
+    `sa_config` over the keys the indexer keeps.
     Returns (output, pairs kept int32 [1] or None)."""
     h, hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
         cfg.head_dim
@@ -240,7 +359,7 @@ def grouped_query_attention(x, cfg, name):
     def heads(part, n, normed):
         t = layers.reshape(_linear(x, n * d, f"{name}_{part}", cfg),
                            [0, 0, n, d])
-        if not normed:
+        if not (normed and cfg.attention_positions):
             return t
         t = _norm(t, f"{name}_{part}_norm", cfg)
         return layers.rotary_embedding(t, theta=cfg.rope_theta,
@@ -264,6 +383,15 @@ def gated_ffn(x, width, cfg, name):
     return _linear(hidden, cfg.hidden_size, name + "_down", cfg)
 
 
+def ffn(x, width, cfg, name):
+    """The configuration's feed-forward: gated SwiGLU, or ungated with a
+    squared ReLU."""
+    if cfg.gated_ffn:
+        return gated_ffn(x, width, cfg, name)
+    hidden = layers.relu2(_linear(x, width, name + "_up", cfg))
+    return _linear(hidden, cfg.hidden_size, name + "_down", cfg)
+
+
 def moe_ffn(x, cfg, name):
     """Routed experts held here plus the shared expert where there is
     one. Returns (output, the router's count of tokens per held
@@ -284,14 +412,124 @@ def moe_ffn(x, cfg, name):
         first_expert=cfg.first_expert,
         gate_attr=_w(name + "_experts_gate.w_0", cfg),
         up_attr=_w(name + "_experts_up.w_0", cfg),
-        down_attr=_w(name + "_experts_down.w_0", cfg))
+        down_attr=_w(name + "_experts_down.w_0", cfg),
+        activation="swiglu" if cfg.gated_ffn else "relu2")
     worked = routed.block.var(routed.op.output("RowsWorked")[0])
     if cfg.n_shared_experts:
-        shared = gated_ffn(
-            x, cfg.n_shared_experts * cfg.moe_intermediate_size, cfg,
-            name + "_shared")
+        shared = ffn(x, cfg.shared_expert_width, cfg, name + "_shared")
         routed = layers.elementwise_add(routed, shared)
     return routed, counts, worked
+
+
+def _per_head(name, values):
+    return ParamAttr(name=name, initializer=NumpyArrayInitializer(
+        np.asarray(values, np.float32)))
+
+
+def mamba_mixer(x, cfg, name):
+    """A Mamba-2 mixer on the layer's normalised input: [z | x B C | dt]
+    = x W_in; x B C through the causal depthwise convolution and SiLU;
+    the state-space scan; the group-wise RMS norm gated by z; W_out.
+    Returns (output, tokens scanned int32 [1])."""
+    h, p = cfg.mamba_num_heads, cfg.mamba_head_dim
+    g, n = cfg.n_groups, cfg.ssm_state_size
+    inner, groups = h * p, g * n
+    z, xbc, dt = layers.split(
+        _linear(x, 2 * inner + 2 * groups + h, name + "_in", cfg),
+        [inner, inner + 2 * groups, h], dim=-1)
+    taps = Uniform(-cfg.conv_kernel ** -0.5, cfg.conv_kernel ** -0.5)
+    xbc = layers.causal_conv1d(
+        xbc, cfg.conv_kernel, act="silu",
+        param_attr=ParamAttr(name=name + "_conv.w_0", initializer=taps),
+        bias_attr=ParamAttr(name=name + "_conv.b_0", initializer=taps)
+        if cfg.use_conv_bias else False)
+    xs, b, c = layers.split(xbc, [inner, groups, groups], dim=-1)
+    lo, hi, floor = cfg.time_step
+    step = np.maximum(np.exp(np.linspace(np.log(lo), np.log(hi), h)), floor)
+    y, tokens = layers.mamba2_ssd(
+        layers.reshape(xs, [0, 0, h, p]), dt,
+        layers.reshape(b, [0, 0, g, n]), layers.reshape(c, [0, 0, g, n]),
+        chunk_size=cfg.chunk_size,
+        # softplus^-1(step) = step + log(1 - exp(-step))
+        dt_bias_attr=_per_head(name + "_dt.b_0",
+                               step + np.log(-np.expm1(-step))),
+        a_log_attr=_per_head(name + "_a_log.w_0",
+                             np.log(np.linspace(1.0, 16.0, h))),
+        d_attr=ParamAttr(name=name + "_d.w_0", initializer=Constant(1.0)))
+    y = layers.gated_rms_norm(
+        layers.reshape(y, [0, 0, inner]), z, groups=g,
+        epsilon=cfg.rms_norm_eps,
+        param_attr=ParamAttr(name=name + "_norm.w_0",
+                             initializer=Constant(1.0)))
+    return _linear(y, cfg.hidden_size, name + "_out", cfg), tokens
+
+
+class _Counts:
+    """What the layers hand to the step's counters."""
+
+    def __init__(self):
+        self.load, self.worked, self.kept, self.scanned = [], [], [], []
+
+
+def _attention(x, cfg, name, counts):
+    if cfg.kv_lora_rank:
+        return latent_attention(x, cfg, name)
+    attn, n = grouped_query_attention(x, cfg, name)
+    if n is not None:
+        counts.kept.append(n)
+    return attn
+
+
+def _experts(x, cfg, p, counts):
+    out, c, w = moe_ffn(x, cfg, p)
+    counts.load.append(c)
+    counts.worked.append(w)
+    return out
+
+
+def _two_part_layer(h, i, cfg, counts):
+    """h += Attn(RMSNorm(h)); h += FFN(RMSNorm(h))."""
+    p = f"layer_{i}"
+    with name_scope("attn"):
+        attn = _attention(_norm(h, p + "_attn_norm", cfg), cfg, p + "_attn",
+                          counts)
+        h = layers.elementwise_add(h, attn)
+    if i in cfg.dense_layers:
+        with name_scope("mlp"):
+            out = gated_ffn(_norm(h, p + "_ffn_norm", cfg),
+                            cfg.intermediate_size, cfg, p + "_mlp")
+            return layers.elementwise_add(h, out)
+    with name_scope("moe"):
+        out = _experts(_norm(h, p + "_ffn_norm", cfg), cfg, p, counts)
+        return layers.elementwise_add(h, out)
+
+
+def _one_part_layer(h, i, part, cfg, counts):
+    """h += part(RMSNorm(h)), the part one of `PATTERN_PARTS`' names."""
+    p = f"layer_{i}"
+    with name_scope(part):
+        x = _norm(h, p + "_norm", cfg)
+        if part == "mamba":
+            out, n = mamba_mixer(x, cfg, p + "_mixer")
+            counts.scanned.append(n)
+        elif part == "attn":
+            out = _attention(x, cfg, p + "_attn", counts)
+        elif part == "moe":
+            out = _experts(x, cfg, p, counts)
+        else:
+            out = ffn(x, cfg.intermediate_size, cfg, p + "_mlp")
+        return layers.elementwise_add(h, out)
+
+
+def _overwritten_counter(name, parts):
+    """A persistable int32 [len(parts)] every step overwrites (a sum
+    would overflow an int32 within minutes: 14.7 M pairs a layer a step
+    at 8,192 tokens)."""
+    with name_scope(name):
+        layers.assign(layers.concat(parts, axis=0),
+                      output=layers.create_global_var(
+                          [len(parts)], 0, "int32", persistable=True,
+                          name=name))
 
 
 def decoder_lm_train(cfg: DecoderLMConfig):
@@ -307,50 +545,29 @@ def decoder_lm_train(cfg: DecoderLMConfig):
         h = layers.embedding(
             ids, size=[cfg.vocab_size, cfg.hidden_size],
             param_attr=_w("embed_tokens.w_0", cfg))
-    counts, worked, kept = [], [], []
+    counts = _Counts()
     for i in range(cfg.num_hidden_layers):
-        p = f"layer_{i}"
-        with name_scope(p):
-            with name_scope("attn"):
-                x = _norm(h, p + "_attn_norm", cfg)
-                if cfg.kv_lora_rank:
-                    attn = latent_attention(x, cfg, p + "_attn")
-                else:
-                    attn, n = grouped_query_attention(x, cfg, p + "_attn")
-                    if n is not None:
-                        kept.append(n)
-                h = layers.elementwise_add(h, attn)
-            if i in cfg.dense_layers:
-                with name_scope("mlp"):
-                    ffn = gated_ffn(_norm(h, p + "_ffn_norm", cfg),
-                                    cfg.intermediate_size, cfg, p + "_mlp")
-                    h = layers.elementwise_add(h, ffn)
+        with name_scope(f"layer_{i}"):
+            if cfg.parts is None:
+                h = _two_part_layer(h, i, cfg, counts)
             else:
-                with name_scope("moe"):
-                    ffn, c, w = moe_ffn(_norm(h, p + "_ffn_norm", cfg), cfg,
-                                        p)
-                    counts.append(c)
-                    worked.append(w)
-                    h = layers.elementwise_add(h, ffn)
-    if counts:
+                h = _one_part_layer(h, i, cfg.parts[i], cfg, counts)
+    if counts.load:
         with name_scope("moe_expert_load"):
             load = layers.create_global_var(
-                [len(counts), cfg.experts_held], 0, "int32",
+                [len(counts.load), cfg.experts_held], 0, "int32",
                 persistable=True, name=EXPERT_LOAD_VAR)
-            layers.sums([load, layers.stack(counts, axis=0)], out=load)
+            layers.sums([load, layers.stack(counts.load, axis=0)], out=load)
         with name_scope("moe_rows_worked"):
             rows = layers.create_global_var(
-                [len(worked), 2], 0, "int32", persistable=True,
+                [len(counts.worked), 2], 0, "int32", persistable=True,
                 name=ROWS_WORKED_VAR)
-            layers.sums([rows, layers.stack(worked, axis=0)], out=rows)
-    if kept:
-        # overwritten, not added to: 14.7 M pairs a layer a step at 8,192
-        # tokens would overflow an accumulating int32 within minutes
-        with name_scope("sparse_attn_kept"):
-            layers.assign(layers.concat(kept, axis=0),
-                          output=layers.create_global_var(
-                              [len(kept)], 0, "int32", persistable=True,
-                              name=KEPT_PAIRS_VAR))
+            layers.sums([rows, layers.stack(counts.worked, axis=0)],
+                        out=rows)
+    if counts.kept:
+        _overwritten_counter(KEPT_PAIRS_VAR, counts.kept)
+    if counts.scanned:
+        _overwritten_counter(SSD_TOKENS_VAR, counts.scanned)
     with name_scope("head"):
         logits = _linear(_norm(h, "final_norm", cfg), cfg.vocab_size,
                          "lm_head", cfg)

@@ -1,8 +1,10 @@
 """Ops of a current decoder-only language model block: RMS
-normalisation, rotary positions, the gated (SwiGLU) feed-forward's
-activation, the top-k router (sigmoid or softmax scores), the expert
-layer of a mixture of experts of which this chip holds a share, and the
-index of a learned sparse attention: which keys each query attends.
+normalisation, rotary positions, the feed-forward's activation (gated
+SwiGLU, or a squared ReLU), the top-k router (sigmoid or softmax
+scores), the expert layer of a mixture of experts of which this chip
+holds a share, the index of a learned sparse attention (which keys each
+query attends), and the three ops of a Mamba-2 mixer: the short causal
+convolution, the state-space scan and the gated group-wise RMS norm.
 
 The expert layer is dropless and knows which experts it holds:
 `moe_experts` gathers the rows routed to experts `first_expert ..
@@ -94,6 +96,14 @@ def swiglu(ctx):
     xf = x.astype(_F32)
     ctx.set_output("Out", (xf * jax.nn.sigmoid(xf) * y.astype(_F32)
                            ).astype(jnp.result_type(x, y)))
+
+
+@register_op("relu2")
+def relu2(ctx):
+    """Out = max(X, 0)^2, the ungated feed-forward's activation."""
+    x = ctx.input("X")
+    r = jnp.maximum(x.astype(_F32), 0.0)
+    ctx.set_output("Out", (r * r).astype(x.dtype))
 
 
 # ---------------------------------------------------------------- router
@@ -224,17 +234,24 @@ def _experts_forward(x, weight, wg, wu, wd, plan, *, rows, held, kernels,
                      out_dtype):
     """`moe_experts` over the buffer's first `rows` rows. Returns (out
     [T, D], GateAct, UpAct): the two at the whole buffer's length, their
-    rows past `rows` zero."""
+    rows past `rows` zero. Without a gate matrix (`wg` None) the expert
+    is the ungated one, down(relu(up(x))^2), and GateAct is None."""
     p = _Prefix(x, weight, plan, rows, held, kernels)
-    gate, up = p.gmm(p.xs, wg), p.gmm(p.xs, wu)
-    hidden = _silu(gate.astype(_F32)) * up.astype(_F32)
+    up = p.gmm(p.xs, wu)
+    if wg is None:
+        gate = None
+        hidden = jnp.square(jnp.maximum(up.astype(_F32), 0.0))
+    else:
+        gate = p.gmm(p.xs, wg)
+        hidden = _silu(gate.astype(_F32)) * up.astype(_F32)
     # the routing weight goes in before the down projection (it is
     # linear), so the backward needs no expert output kept or recomputed
     hw = jnp.where(p.valid, hidden * p.w_row[:, None], 0)
     out = _combine(p.gmm(hw.astype(x.dtype), wd), plan, x.shape[0], p.top_k)
     beyond = plan["valid"].shape[0] - rows
     if beyond:
-        gate, up = (jnp.pad(a, ((0, beyond), (0, 0))) for a in (gate, up))
+        gate, up = (None if a is None else jnp.pad(a, ((0, beyond), (0, 0)))
+                    for a in (gate, up))
     return out.astype(out_dtype), gate, up
 
 
@@ -242,30 +259,43 @@ def _experts_backward(x, weight, wg, wu, wd, plan, gate, up, dout, *, rows,
                       held, kernels, shape):
     """`moe_experts_grad` over the buffer's first `rows` rows, the rows
     of GateAct and UpAct the forward wrote. Returns float32 (dX, dWeight,
-    dWGate, dWUp, dWDown)."""
+    dWGate, dWUp, dWDown); dWGate is None for the ungated expert."""
     p = _Prefix(x, weight, plan, rows, held, kernels)
     dtype, valid = x.dtype, p.valid
-    if gate is None:        # a hand-built op desc without the two slots
-        gate, up = p.gmm(p.xs, wg), p.gmm(p.xs, wu)
-    elif rows < gate.shape[0]:
-        gate, up = gate[:rows], up[:rows]
+    if up is None:          # a hand-built op desc without the two slots
+        up = p.gmm(p.xs, wu)
+        gate = None if wg is None else p.gmm(p.xs, wg)
+    elif rows < up.shape[0]:
+        up = up[:rows]
+        gate = None if gate is None else gate[:rows]
     dy = _gather_rows(dout.reshape(x.shape[0], -1).astype(dtype), p.plan,
                       p.top_k)
 
-    gate32 = jnp.where(valid, gate.astype(_F32), 0)
     up32 = jnp.where(valid, up.astype(_F32), 0)
-    sig = jax.nn.sigmoid(gate32)
-    act = gate32 * sig
-    hidden = act * up32
+    if wg is None:
+        act = jnp.maximum(up32, 0.0)
+        hidden = act * act
+    else:
+        gate32 = jnp.where(valid, gate.astype(_F32), 0)
+        sig = jax.nn.sigmoid(gate32)
+        act = gate32 * sig
+        hidden = act * up32
     hw = (hidden * p.w_row[:, None]).astype(dtype)
     dhw = jnp.where(valid, p.gmm_dx(dy, wd).astype(_F32), 0)
     d_wd = p.gmm_dw(hw, dy)
     dw_row = jnp.sum(dhw * hidden, axis=-1)
     dh = dhw * p.w_row[:, None]
-    dgate = (dh * up32 * (sig + act * (1.0 - sig))).astype(dtype)
-    dup = (dh * act).astype(dtype)
-    dxs = p.gmm_dx(dgate, wg).astype(_F32) + p.gmm_dx(dup, wu).astype(_F32)
-    d_wg, d_wu = p.gmm_dw(p.xs, dgate), p.gmm_dw(p.xs, dup)
+    if wg is None:
+        dup = (dh * 2.0 * act).astype(dtype)
+        dxs = p.gmm_dx(dup, wu).astype(_F32)
+        d_wg = None
+    else:
+        dgate = (dh * up32 * (sig + act * (1.0 - sig))).astype(dtype)
+        dup = (dh * act).astype(dtype)
+        dxs = p.gmm_dx(dgate, wg).astype(_F32) \
+            + p.gmm_dx(dup, wu).astype(_F32)
+        d_wg = p.gmm_dw(p.xs, dgate)
+    d_wu = p.gmm_dw(p.xs, dup)
     dx = _combine(dxs, plan, x.shape[0], p.top_k).reshape(shape)
     dweight = jnp.where(plan["held"], dw_row[plan["row_of_choice"]],
                         0.0).reshape(weight.shape)
@@ -301,17 +331,25 @@ class _Experts:
         x = x.reshape(-1, x.shape[-1])
         # neither op is on an AMP list: cast here, so that both
         # differentiate the same function and the weights stay float32
-        x, wg, wu, wd = amp_cast("moe_experts", x, ctx.input("WGate"),
-                                 ctx.input("WUp"), ctx.input("WDown"))
+        activation = ctx.attr("activation", "swiglu")
+        gated = activation == "swiglu"
+        if activation not in ("swiglu", "relu2") \
+                or gated != ctx.has_input("WGate"):
+            raise ValueError("moe_experts is swiglu with a gate matrix or "
+                             "relu2 without one")
+        x, wg, wu, wd = amp_cast(
+            "moe_experts", x, ctx.input("WGate") if gated else None,
+            ctx.input("WUp"), ctx.input("WDown"))
         self.x = x
-        self.wg, self.wu, self.wd = (w.astype(x.dtype) for w in (wg, wu, wd))
+        self.wg, self.wu, self.wd = (
+            None if w is None else w.astype(x.dtype) for w in (wg, wu, wd))
         self.held = int(ctx.attr("experts_held"))
         first = int(ctx.attr("first_expert", 0))
         num_experts = int(ctx.attr("num_experts"))
         if first < 0 or first + self.held > num_experts:
             raise ValueError("experts held lie outside the layer's experts")
         self.plan = gm.plan_rows(choice.reshape(-1) - first, self.held)
-        self.kernels = gm.use_kernels(x, self.wg)
+        self.kernels = gm.use_kernels(x, self.wu)
         self.ladder = gm.prefix_rows(choice.size, self.held, num_experts)
 
     def run(self, body, *more, **static):
@@ -347,7 +385,9 @@ def moe_experts(ctx):
     [T, k] over all `num_experts`; TopkWeight [T, k]; WGate, WUp
     [experts_held, D, F]; WDown [experts_held, F, D].
     Out[t] = sum over k with TopkIdx[t, k] held here of TopkWeight[t, k]
-    * WDown_e(silu(x_t WGate_e) * (x_t WUp_e)). GateAct and UpAct
+    * WDown_e(silu(x_t WGate_e) * (x_t WUp_e)); with attr activation
+    "relu2" the expert is ungated, WDown_e(relu(x_t WUp_e)^2), and the op
+    has no WGate and no GateAct. GateAct and UpAct
     (intermediate, [buffer rows, F]) carry the two projections to the
     grad op, so the backward runs no forward kernel again.
 
@@ -363,7 +403,8 @@ def moe_experts(ctx):
     out, gate, up = e.run(_experts_forward,
                           out_dtype=jnp.result_type(ctx.input("X")))
     ctx.set_output("Out", out.reshape(e.shape))
-    ctx.set_output("GateAct", gate)
+    if gate is not None:
+        ctx.set_output("GateAct", gate)
     ctx.set_output("UpAct", up)
     if ctx.has_output("RowsWorked"):
         ctx.set_output("RowsWorked", e.rows_worked())
@@ -382,15 +423,17 @@ def moe_experts_grad(ctx):
     op = ctx.op
     e = _Experts(ctx)
     gate = up = None
-    if ctx.has_input("GateAct") and ctx.has_input("UpAct"):
-        gate = ctx.env[op.input("GateAct")[0]]
+    if ctx.has_input("UpAct") and (e.wg is None
+                                   or ctx.has_input("GateAct")):
         up = ctx.env[op.input("UpAct")[0]]
+        if e.wg is not None:
+            gate = ctx.env[op.input("GateAct")[0]]
     grads = e.run(_experts_backward, gate, up,
                   ctx.env[op.input("Out@GRAD")[0]], shape=e.shape)
     for slot, grad in zip(("X", "TopkWeight", "WGate", "WUp", "WDown"),
                           grads):
         names = op.output(slot + "@GRAD")
-        if names and names[0]:
+        if names and names[0] and grad is not None:
             primal = ctx.env[op.input(slot)[0]]
             ctx.env[names[0]] = grad.astype(primal.dtype)
 
@@ -416,3 +459,108 @@ def sparse_attention_index(ctx):
         q, k, w, int(ctx.attr("top_k")), sparse_index.use_kernels(q, k))
     ctx.set_output("Mask", keep[:, None])
     ctx.set_output("Kept", kept)
+
+
+# ------------------------------------------------------ the Mamba-2 mixer
+
+@register_op("causal_conv1d")
+def causal_conv1d(ctx):
+    """A short depthwise convolution along the sequence that looks back
+    only: X [B, T, C], Weight [C, K], optional Bias [C].
+    Out[t, c] = act(Bias[c] + sum_j Weight[c, j] X[t - (K - 1) + j, c]),
+    tokens before the first read as zero; attr activation "silu" or "".
+    Float32 inside, X's type out."""
+    x, w = ctx.input("X"), ctx.input("Weight").astype(_F32)
+    bias = ctx.input("Bias") if ctx.has_input("Bias") else None
+    k, t = w.shape[1], x.shape[1]
+    xp = jnp.pad(x.astype(_F32), ((0, 0), (k - 1, 0), (0, 0)))
+    y = sum(xp[:, j:j + t] * w[None, None, :, j] for j in range(k))
+    if bias is not None:
+        y = y + bias.astype(_F32)
+    act = ctx.attr("activation", "")
+    if act == "silu":
+        y = _silu(y)
+    elif act:
+        raise NotImplementedError(f"causal_conv1d activation {act!r}")
+    ctx.set_output("Out", y.astype(x.dtype))
+
+
+@register_op("gated_rms_norm")
+def gated_rms_norm(ctx):
+    """Y = RMSNorm(X * silu(Gate)) * Scale, the mean square taken within
+    each of `groups` equal groups of the last axis' channels (the gate
+    goes in BEFORE the norm). Float32 inside, X's type out."""
+    x, gate, scale = ctx.input("X"), ctx.input("Gate"), ctx.input("Scale")
+    groups, eps = int(ctx.attr("groups", 1)), ctx.attr("epsilon", 1e-5)
+    if x.shape[-1] % groups:
+        raise ValueError(f"{x.shape[-1]} channels in {groups} groups")
+    v = x.astype(_F32) * _silu(gate.astype(_F32))
+    g = v.reshape(v.shape[:-1] + (groups, -1))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    ctx.set_output("Y", (g.reshape(v.shape) * scale.astype(_F32)
+                         ).astype(x.dtype))
+
+
+class _Scan:
+    """What the forward and the grad op of `mamba2_ssd` share: the step
+    sizes dt = softplus(Dt + DtBias) and the decay rates A = -exp(ALog)
+    in float32, B and C in X's type, the kernel decision."""
+
+    def __init__(self, ctx):
+        from ..kernels import mamba2_ssd
+        self.ssd = mamba2_ssd
+        self.x = ctx.input("X")
+        self.b, self.c = (ctx.input(s).astype(self.x.dtype)
+                          for s in ("B", "C"))
+        self.raw = ctx.input("Dt").astype(_F32) \
+            + ctx.input("DtBias").astype(_F32)
+        self.dt = jax.nn.softplus(self.raw)
+        self.a = -jnp.exp(ctx.input("ALog").astype(_F32))
+        self.d = ctx.input("D").astype(_F32)
+        self.chunk = int(ctx.attr("chunk_size", mamba2_ssd.CHUNK))
+        self.kernels = mamba2_ssd.use_kernels(self.x, self.b)
+
+
+@register_op("mamba2_ssd", intermediate_outputs=("States",))
+def mamba2_ssd(ctx):
+    """Mamba-2's state-space scan. X [B, T, H, P]; Dt [B, T, H]; DtBias,
+    ALog, D [H]; B, C [B, T, G, N] (head h reads group h // (H / G)).
+    With dt = softplus(Dt + DtBias) and A = -exp(ALog), a head's state
+    S [P, N] from zero: S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t,
+    Y_t = S_t C_t + D x_t, computed in chunks of attr chunk_size tokens
+    (kernels/mamba2_ssd.py). States (intermediate, float32 [B, chunks,
+    H, P, N]) carries the state at each chunk's start to the grad op.
+    Tokens (optional, int32 [1]): the tokens scanned, for the
+    `mamba_ssd_tokens` counter (observability/mamba.py)."""
+    s = _Scan(ctx)
+    y, states = s.ssd.ssd(s.x, s.dt, s.a, s.b, s.c, s.d, s.kernels, s.chunk)
+    ctx.set_output("Y", y)
+    ctx.set_output("States", states)
+    if ctx.has_output("Tokens"):
+        ctx.set_output("Tokens", jnp.full(
+            (1,), s.x.shape[0] * s.x.shape[1], jnp.int32))
+
+
+@override_grad_lowering("mamba2_ssd")
+def mamba2_ssd_grad(ctx):
+    """Hand-written: the backward kernel reads the forward's States (a
+    forward recomputed here would be a second custom call); then
+    d Dt = d DtBias = d dt * sigmoid(Dt + DtBias), d ALog = dA * A."""
+    op = ctx.op
+    s = _Scan(ctx)
+    dy = ctx.env[op.input("Y@GRAD")[0]].astype(s.x.dtype)
+    if ctx.has_input("States"):
+        states = ctx.env[op.input("States")[0]]
+    else:
+        states = s.ssd.ssd(s.x, s.dt, s.a, s.b, s.c, s.d, s.kernels,
+                           s.chunk)[1]
+    dx, ddt, da, db, dc, dd = s.ssd.ssd_grad(
+        s.x, s.dt, s.a, s.b, s.c, s.d, states, dy, s.kernels, s.chunk)
+    draw = ddt * jax.nn.sigmoid(s.raw)
+    for slot, grad in (("X", dx), ("Dt", draw),
+                       ("DtBias", jnp.sum(draw, axis=(0, 1))),
+                       ("ALog", da * s.a), ("B", db), ("C", dc), ("D", dd)):
+        names = op.output(slot + "@GRAD")
+        if names and names[0]:
+            primal = ctx.env[op.input(slot)[0]]
+            ctx.env[names[0]] = grad.astype(primal.dtype)

@@ -5,9 +5,11 @@ expert layer that holds a share of the experts (lowered and, under the
 Pallas interpreter, through the grouped-matmul kernels), a learned sparse
 attention's index (scores and exact top-k selection, lowered and through
 its kernels), the flash kernels with q/k and v of different widths, with
-fewer key heads than query heads and with a keep mask, and both whole
-models the file builds through `Executor.run` against the benchmark's
-plain references (benchmark/families/*_reference.py)."""
+fewer key heads than query heads and with a keep mask, a Mamba-2 mixer's
+ops (the causal convolution, the state-space scan lowered and through
+its kernels, the gated group norm), the ungated squared-ReLU expert, and
+the three whole models the file builds through `Executor.run` against
+the benchmark's plain references (benchmark/families/*_reference.py)."""
 import importlib
 
 import numpy as np
@@ -24,6 +26,8 @@ from paddle_tpu.kernels import registry as kreg
 
 from benchmark.families import gqa_dsa_moe_decoder as gqa_family
 from benchmark.families import gqa_dsa_moe_decoder_reference as gqa_ref
+from benchmark.families import mamba_gqa_moe_decoder as hybrid_family
+from benchmark.families import mamba_gqa_moe_decoder_reference as hybrid_ref
 from benchmark.families import mla_moe_decoder as family
 from benchmark.families import mla_moe_decoder_reference as ref
 from paddle_tpu.kernels import sparse_index
@@ -255,22 +259,24 @@ def _experts_reference(c, first):
     return out, grads
 
 
-def _experts_program(c, num_experts, held, first, slots=()):
-    """(Out, [the five gradients, then the op's outputs `slots`])."""
-    f = c["wg"].shape[2]
+def _experts_program(c, num_experts, held, first, slots=(),
+                     activation="swiglu"):
+    """(Out, [the five gradients (four for the ungated expert, which has
+    no gate matrix), then the op's outputs `slots`])."""
+    f = c["wu"].shape[2]
+    weights = ["wg", "wu", "wd"] if activation == "swiglu" else ["wu", "wd"]
     main, scope, exe, out, grads = _run(
         lambda x, choice, weight: layers.moe_experts(
             x, choice, weight, num_experts, f, experts_held=held,
             first_expert=first, gate_attr=fluid.ParamAttr(name="wg"),
             up_attr=fluid.ParamAttr(name="wu"),
-            down_attr=fluid.ParamAttr(name="wd")),
+            down_attr=fluid.ParamAttr(name="wd"), activation=activation),
         {"x": c["x"], "choice": c["choice"], "weight": c["weight"]},
-        ["x", "weight", "wg", "wu", "wd"])
+        ["x", "weight"] + weights)
     more = [out.block.var(out.op.output(slot)[0]) for slot in slots]
     return _fetch(main, scope, exe, {"x": c["x"], "choice": c["choice"],
                                      "weight": c["weight"]}, out,
-                  list(grads) + more, c["cot"],
-                  {"wg": c["wg"], "wu": c["wu"], "wd": c["wd"]})
+                  list(grads) + more, c["cot"], {n: c[n] for n in weights})
 
 
 def _check_experts(c, num_experts, held, first, tol=2e-5):
@@ -340,28 +346,36 @@ def _routed(sizes, t=256, k=4, first=4, seed=60):
     return np.random.default_rng(seed).permutation(flat).reshape(t, k)
 
 
+@pytest.mark.parametrize("activation", ["swiglu", "relu2"])
 @pytest.mark.parametrize("path", ["lowered", "kernels"])
 @pytest.mark.parametrize("routing", sorted(_PREFIX_ROUTINGS))
 def test_moe_experts_over_a_prefix_equals_the_worst_case_body(
-        routing, path, request, monkeypatch):
+        routing, path, activation, request, monkeypatch):
     """Whatever prefix of the row buffer the routing picks, the worst
-    case included, Out and all five gradients are BIT-EQUAL to the one
-    body over the whole worst-case buffer; n_active at a prefix's edge
-    takes that prefix and one tile more takes the next."""
+    case included, Out and every gradient (five for the gated expert,
+    four for the ungated) are BIT-EQUAL to the one body over the whole
+    worst-case buffer; n_active at a prefix's edge takes that prefix and
+    one tile more takes the next."""
     if path == "kernels":
         request.getfixturevalue("interp")
     sizes, prefix, tiles = _PREFIX_ROUTINGS[routing]
     c = _experts_case(256, 16, 8, 4, _routed(sizes), seed=61)
     assert gm.prefix_rows(1024, 4, 16) == [768, 1024, 1536]
-    out, got = _experts_program(c, 16, 4, 4, slots=["RowsWorked"])
+    out, got = _experts_program(c, 16, 4, 4, slots=["RowsWorked"],
+                                activation=activation)
     assert got.pop().tolist() == [prefix, tiles * gm.TILE_ROWS]
     monkeypatch.setattr(
         gm, "prefix_rows", lambda n, held, num: [gm.buffer_rows(n, held)])
-    whole, want = _experts_program(c, 16, 4, 4, slots=["RowsWorked"])
+    whole, want = _experts_program(c, 16, 4, 4, slots=["RowsWorked"],
+                                   activation=activation)
     assert want.pop().tolist() == [1536, tiles * gm.TILE_ROWS]
     assert np.abs(whole).max() > 0
     np.testing.assert_array_equal(out, whole)
-    for name, g, w in zip(("x", "weight", "wg", "wu", "wd"), got, want):
+    names = ("x", "weight", "wg", "wu", "wd")
+    if activation == "relu2":
+        names = names[:2] + names[3:]
+    assert len(got) == len(names)
+    for name, g, w in zip(names, got, want):
         np.testing.assert_array_equal(g, w, err_msg=name)
 
 
@@ -998,3 +1012,424 @@ def test_rows_worked_reader():
     assert stats["worked_share_of_worst"] == pytest.approx([0.5, 0.75])
     assert stats["worked_over_in_use"] == pytest.approx([2.0, 2.0])
     assert moe.rows_worked_stats(np.zeros((2, 2)), 2, 2048) is None
+
+
+# ------------------------------------------- the ungated (relu^2) expert
+
+def _ungated_reference(c, first):
+    ar = ref.base._Arithmetic("f32")
+
+    def f(x, weight, wu, wd):
+        return hybrid_ref.routed_experts(ar, x, jnp.asarray(c["choice"]),
+                                         weight, wu, wd, first)
+    args = (c["x"], c["weight"], c["wu"], c["wd"])
+    return f(*args), jax.grad(lambda *a: jnp.sum(f(*a) * c["cot"]),
+                              (0, 1, 2, 3))(*args)
+
+
+@pytest.mark.parametrize("path", ["lowered", "kernels"])
+def test_ungated_experts_forward_and_grad(path, request):
+    """activation "relu2": down(relu(up(x))^2), two matrices, no gate
+    and no GateAct, against the plain loop over the held experts."""
+    kreg.reset_stats()
+    if path == "kernels":
+        request.getfixturevalue("interp")
+    c = _experts_case(150, 16, 8, 4, _random_choice(150, 3, 12, 21))
+    with jax.default_matmul_precision("highest"):
+        out, grads = _experts_program(c, 12, 4, 4, activation="relu2")
+        r_out, r_grads = _ungated_reference(c, 4)
+    _close(out, r_out)
+    assert len(grads) == 4
+    for got, want in zip(grads, r_grads):
+        _close(got, want)
+    routed = kreg.dispatch_stats()["per_kernel"].get(
+        "moe_grouped_matmul", {})
+    assert bool(routed.get("custom")) == (path == "kernels")
+
+
+def test_ungated_experts_op_has_no_gate_slots():
+    c = _experts_case(32, 16, 8, 4, _random_choice(32, 2, 8, 22))
+    main, *_ = _run(
+        lambda x, choice, weight: layers.moe_experts(
+            x, choice, weight, 8, 8, experts_held=4, activation="relu2",
+            up_attr=fluid.ParamAttr(name="wu"),
+            down_attr=fluid.ParamAttr(name="wd")),
+        {"x": c["x"], "choice": c["choice"], "weight": c["weight"]},
+        ["x", "wu", "wd"])
+    op, = [o for o in main.global_block().ops if o.type == "moe_experts"]
+    assert "WGate" not in op.input_slots()
+    assert "GateAct" not in op.output_slots()
+    assert op.attr("activation") == "relu2"
+    assert "wg" not in {p.name for p in main.all_parameters()}
+    with pytest.raises(ValueError):
+        layers.moe_experts(None, None, None, 8, 8, activation="gelu")
+
+
+def test_sixteen_ungated_shares_add_up_to_the_uncut_layer():
+    """32 sigmoid-routed ungated experts, top-6, over 16 shares of 2: the
+    routed outputs of all sixteen shares plus the shared expert counted
+    ONCE equal the uncut reference layer (each share given the router's
+    choices over all 32, as every chip computes them alike)."""
+    t, d, f, e = 96, 16, 8, 32
+    sz = dict(num_experts_per_tok=6, norm_topk_prob=True,
+              routed_scaling_factor=2.5, first_expert=0)
+    y = _r((t, d), 90)
+    p = {"router.w_0": _r((e, d), 91, 0.3),
+         "router.b_0": np.zeros((e,), np.float32),
+         "experts_up.w_0": _r((e, d, f), 92, 0.3),
+         "experts_down.w_0": _r((e, f, d), 93, 0.3),
+         "shared_up.w_0": _r((d, 2 * f), 94, 0.3),
+         "shared_down.w_0": _r((2 * f, d), 95, 0.3)}
+    ar = ref.base._Arithmetic("f32")
+    with jax.default_matmul_precision("highest"):
+        whole, choice = hybrid_ref.moe_layer(ar, p, jnp.asarray(y), sz)
+        _, weight = hybrid_ref.route(y, p["router.w_0"], p["router.b_0"],
+                                     sz)
+        total = np.asarray(hybrid_ref.relu2_ffn(
+            ar, jnp.asarray(y), p["shared_up.w_0"],
+            p["shared_down.w_0"])).copy()
+        for share in range(16):
+            lo = 2 * share
+            c = dict(x=y, choice=np.asarray(choice),
+                     weight=np.asarray(weight), cot=np.zeros_like(y),
+                     wu=p["experts_up.w_0"][lo:lo + 2],
+                     wd=p["experts_down.w_0"][lo:lo + 2])
+            out, _ = _experts_program(c, e, 2, lo, activation="relu2")
+            total += out
+    _close(total, whole)
+
+
+# ----------------------------------------------------- a Mamba-2 mixer's ops
+
+def test_relu2_forward_and_grad():
+    x, cot = _r((6, 10), 100), _r((6, 10), 101)
+    main, scope, exe, out, grads = _run(lambda x: layers.relu2(x),
+                                        {"x": x}, ["x"])
+    got, (dx,) = _fetch(main, scope, exe, {"x": x}, out, grads, cot)
+    _close(got, np.maximum(x, 0) ** 2)
+    _close(dx, 2 * np.maximum(x, 0) * cot)
+
+
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "no_bias"])
+def test_causal_conv1d_forward_and_grad(bias):
+    """Four taps, depthwise, looking back only: token t reads tokens
+    t - 3 .. t, the first tokens read zeros; against the reference's
+    convolution, and the output at a token does not move with a later
+    token."""
+    x, w, b = _r((2, 9, 6), 110), _r((6, 4), 111), _r((6,), 112)
+    cot = _r((2, 9, 6), 113)
+    main, scope, exe, out, grads = _run(
+        lambda x: layers.causal_conv1d(
+            x, 4, act="silu", param_attr=fluid.ParamAttr(name="w"),
+            bias_attr=fluid.ParamAttr(name="b") if bias else False),
+        {"x": x}, ["x", "w"] + (["b"] if bias else []))
+    params = {"w": w, **({"b": b} if bias else {})}
+    got, gs = _fetch(main, scope, exe, {"x": x}, out, grads, cot, params)
+
+    def f(x, w, b):
+        return jax.nn.silu(hybrid_ref.causal_conv(x, w, b))
+    zero = b if bias else np.zeros_like(b)
+    _close(got, f(x, w, zero))
+    want = jax.grad(lambda *a: jnp.sum(f(*a) * cot), (0, 1, 2))(x, w, zero)
+    for g, r in zip(gs, want):
+        _close(g, r)
+    later = x.copy()
+    later[:, 5:] += 1.0
+    moved, _ = _fetch(main, scope, exe, {"x": later}, out, grads, cot)
+    np.testing.assert_array_equal(moved[:, :5], got[:, :5])
+    assert np.abs(moved[:, 5:] - got[:, 5:]).min() > 0
+
+
+@pytest.mark.parametrize("groups", [1, 4])
+def test_gated_rms_norm_forward_and_grad(groups):
+    """The gate goes in before the norm and the mean square is taken
+    within each group: 4 groups differ from one over all channels."""
+    x, z, w = _r((3, 5, 16), 120), _r((3, 5, 16), 121), _r((16,), 122)
+    cot = _r((3, 5, 16), 123)
+    main, scope, exe, out, grads = _run(
+        lambda x, z: layers.gated_rms_norm(
+            x, z, groups=groups, epsilon=1e-5,
+            param_attr=fluid.ParamAttr(name="w")),
+        {"x": x, "z": z}, ["x", "z", "w"])
+    got, gs = _fetch(main, scope, exe, {"x": x, "z": z}, out, grads, cot,
+                     {"w": w})
+
+    def f(x, z, w):
+        return hybrid_ref.gated_group_norm(x, z, w, groups, 1e-5)
+    _close(got, f(x, z, w))
+    for g, r in zip(gs, jax.grad(lambda *a: jnp.sum(f(*a) * cot),
+                                 (0, 1, 2))(x, z, w)):
+        _close(g, r, 5e-5)
+    assert np.abs(np.asarray(f(x, z, w)) - np.asarray(
+        hybrid_ref.gated_group_norm(x, z, w, 5 - groups, 1e-5))).max() > 1e-3
+
+
+def _scan_case(b, t, h, p, g, n, seed=130):
+    return dict(x=_r((b, t, h, p), seed), dt=_r((b, t, h), seed + 1) - 1.0,
+                bm=_r((b, t, g, n), seed + 2), cm=_r((b, t, g, n), seed + 3),
+                dt_bias=_r((h,), seed + 4, 0.5),
+                a_log=np.log(np.random.default_rng(seed + 5).uniform(
+                    1, 8, (h,))).astype(np.float32),
+                d=_r((h,), seed + 6), cot=_r((b, t, h, p), seed + 7))
+
+
+@pytest.mark.parametrize("path", ["lowered", "kernels"])
+def test_mamba2_ssd_op_against_the_recurrence(path, request):
+    """The scan op through Executor.run — softplus and its bias, -exp(A),
+    chunks of 16 over 37 tokens (no multiple of the chunk) and B = 2 —
+    and all seven gradients against the token-by-token recurrence."""
+    kreg.reset_stats()
+    if path == "kernels":
+        request.getfixturevalue("interp")
+    c = _scan_case(2, 37, 4, 8, 2, 16)
+    feeds = {k: c[k] for k in ("x", "dt", "bm", "cm")}
+    main, scope, exe, out, grads = _run(
+        lambda x, dt, bm, cm: layers.mamba2_ssd(
+            x, dt, bm, cm, chunk_size=16,
+            dt_bias_attr=fluid.ParamAttr(name="dt_bias"),
+            a_log_attr=fluid.ParamAttr(name="a_log"),
+            d_attr=fluid.ParamAttr(name="d"))[0],
+        feeds, ["x", "dt", "bm", "cm", "dt_bias", "a_log", "d"])
+    params = {k: c[k] for k in ("dt_bias", "a_log", "d")}
+
+    def f(x, dt, bm, cm, dt_bias, a_log, d):
+        return hybrid_ref.recurrence(x, jax.nn.softplus(dt + dt_bias),
+                                     -jnp.exp(a_log), bm, cm, d, block=37)
+    args = [c[k] for k in ("x", "dt", "bm", "cm", "dt_bias", "a_log", "d")]
+    with jax.default_matmul_precision("highest"):
+        got, gs = _fetch(main, scope, exe, feeds, out, grads, c["cot"],
+                         params)
+        want = f(*args)
+        r_grads = jax.grad(lambda *a: jnp.sum(f(*a) * c["cot"]),
+                           tuple(range(7)))(*args)
+    _close(got, want)
+    for name, g, r in zip(("x", "dt", "b", "c", "dt_bias", "a_log", "d"),
+                          gs, r_grads):
+        assert np.abs(np.asarray(r)).max() > 0, name
+        _close(g, r, 5e-5)
+    routed = kreg.dispatch_stats()["per_kernel"].get("mamba2_ssd", {})
+    assert bool(routed.get("custom")) == (path == "kernels")
+    op, = [o for o in main.global_block().ops if o.type == "mamba2_ssd"]
+    tokens = out.block.var(op.output("Tokens")[0])
+    with fluid.scope_guard(scope):
+        n, = exe.run(main, feed={**feeds, "cot": c["cot"]},
+                     fetch_list=[tokens])
+    assert np.asarray(n).tolist() == [2 * 37]
+
+
+# ------------------------------------------------------ the hybrid model
+
+def _hybrid_sizes(**over):
+    import json
+    import os
+    from benchmark.lib import cells
+    with open(os.path.join(cells.BENCH, "configs",
+                           "nemotron_twotower_30b_a3b.json")) as f:
+        return dict(hybrid_family.sizes(json.load(f), rehearsal=True),
+                    **over)
+
+
+@pytest.mark.parametrize("path", ["lowered", "kernels"])
+def test_hybrid_model_trains_like_its_reference(path, request):
+    """The same model file, told by `hybrid_override_pattern` to build
+    one part a layer (a mixer, an expert layer, an attention without
+    positions, an expert layer): losses, EVERY leaf's first gradient and
+    every leaf's change after three Adam steps against the plain
+    reference, whose mixer is the token-by-token recurrence; 40 tokens
+    in chunks of 16, B = 2. The two counters as the model fills them."""
+    over = {}
+    if path == "kernels":
+        request.getfixturevalue("interp")
+        over = dict(head_dim=128)      # one head a lane block
+    sz = _hybrid_sizes(**over)
+    fam = hybrid_family
+    tr = fam.traffic({"pool": 3, "reference_rows_per_block": 1}, True)
+    with jax.default_matmul_precision("highest"):
+        got = _train(sz, tr, 7, amp=False, fam=fam)
+        want = fam.run_reference(sz, tr, fam.make_pool(sz, tr, 7), 7, 3)
+    np.testing.assert_allclose(got["losses"], want["losses"], rtol=2e-6)
+    assert set(got["grad_norms"]) == set(want["grad_norms"]) \
+        == set(hybrid_ref.trainable_names(sz))
+    for n, w in want["grad_norms"].items():
+        assert w > 0, n
+        assert abs(got["grad_norms"][n] - w) <= 2e-4 * max(w, 1e-6), n
+    for n, w in want["delta_norms"].items():
+        assert abs(got["delta_norms"][n] - w) <= 5e-3 * w, n
+    # the router's correction bias: zero, in the program, never moved
+    for n in fam.param_names(sz):
+        if hybrid_ref.is_buffer(n):
+            assert not np.asarray(got["state"](n)).any()
+    assert fam.scanned_tokens(sz).tolist() == [tr["batch"] * tr["seq_len"]]
+    assert fam.expert_load(sz).shape == (2, sz["experts_held"])
+    if path == "kernels":
+        stats = kreg.dispatch_stats()["per_kernel"]
+        assert stats["mamba2_ssd"].get("custom")
+        assert stats["moe_grouped_matmul"].get("custom")
+        assert stats["flash_attention"].get("custom")
+        assert not stats["mamba2_ssd"].get("lowered")
+
+
+def test_hybrid_model_under_mixed_precision():
+    sz = _hybrid_sizes()
+    fam = hybrid_family
+    tr = fam.traffic({"pool": 3, "reference_rows_per_block": 1}, True)
+    got = _train(sz, tr, 9, amp=True, fam=fam)
+    want = fam.run_reference(sz, tr, fam.make_pool(sz, tr, 9), 9, 3)
+    np.testing.assert_allclose(got["losses"], want["losses"], rtol=2e-3)
+    from paddle_tpu.core import amp
+    assert "relu2" in amp.GRAY_OPS
+    for op in ("mamba2_ssd", "gated_rms_norm", "causal_conv1d"):
+        assert amp.op_mode(op) is None       # float32 inside, by the op
+
+
+@pytest.mark.parametrize("char,part", [("M", "mamba"), ("E", "moe"),
+                                       ("*", "attn"), ("-", "mlp")])
+def test_pattern_parser_takes_each_part(char, part):
+    from paddle_tpu.models import decoder_lm
+    assert decoder_lm.parse_pattern(char) == [part]
+    assert decoder_lm.parse_pattern("M" + char + char) == ["mamba", part,
+                                                           part]
+
+
+@pytest.mark.parametrize("bad", ["MEX", "", "ME M", "m"])
+def test_pattern_parser_refuses_another_character(bad):
+    from paddle_tpu.models import decoder_lm
+    with pytest.raises(ValueError):
+        decoder_lm.parse_pattern(bad)
+
+
+def test_the_hybrid_is_built_one_part_a_layer():
+    """`MEMEM*E-`: the op scopes and types of each one-part layer; the
+    attention has no rotary and no q / k norm; the experts are ungated;
+    a pattern whose length is not num_hidden_layers raises."""
+    from paddle_tpu import models
+    sz = _hybrid_sizes(pattern="MEMEM*E-")
+    cfg = hybrid_family.model_config(sz)
+    assert cfg.num_hidden_layers == 8 and cfg.moe_layers == [1, 3, 6]
+    fluid.framework.unique_name.reset()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        models.decoder_lm_train(cfg)
+    ops = main.global_block().ops
+    types = [op.type for op in ops]
+    assert types.count("mamba2_ssd") == 3
+    assert types.count("causal_conv1d") == 3
+    assert types.count("gated_rms_norm") == 3
+    assert types.count("moe_experts") == 3
+    assert types.count("fused_attention") == 1
+    assert "rotary_embedding" not in types and "swiglu" not in types
+    assert types.count("rms_norm") == 8 + 1        # one a layer, one final
+    assert types.count("relu2") == 3 + 1           # shared experts, the MLP
+    scope_of = {op.type: op.attr("op_namescope", "") for op in ops}
+    assert scope_of["mamba2_ssd"].endswith("/mamba/")
+    assert scope_of["moe_experts"].endswith("layer_6/moe/")
+    assert scope_of["fused_attention"] == "layer_5/attn/"
+    assert scope_of["relu2"] == "layer_7/mlp/"
+    names = {p.name for p in main.all_parameters()}
+    assert "layer_0_mixer_a_log.w_0" in names
+    assert "layer_7_mlp_up.w_0" in names and "layer_7_mlp_gate.w_0" not in names
+    assert not [n for n in names if "_experts_gate" in n or "q_norm" in n]
+    with pytest.raises(ValueError):
+        models.DecoderLMConfig(hybrid_override_pattern="ME",
+                               num_hidden_layers=3, n_routed_experts=8,
+                               mamba_num_heads=4, mamba_head_dim=8)
+
+
+_PARENT_PROGRAMS = {
+    # sha256 over every op of main and startup (type, inputs, outputs,
+    # attributes with their name scopes) as the parent of PR 35 built
+    # them, with Adam: (configuration, rehearsal sizes) -> (ops, digest)
+    ("kanana2_30b_a3b", True): (435, "e3754e0f5d66484da5e01f0706160a6b9afb"
+                                "171f045f0b940c971864a445f543"),
+    ("kanana2_30b_a3b", False): (721, "12e306a7b1fa2ceaecf431db803618ca939b"
+                                 "ac00303b74d523c8ac895c690e1e"),
+    ("keye_vl2_30b_a3b", True): (298, "96dc911cc5d2af6876e3dad503bd3dc84735"
+                                 "553cd6aedcc4830ca0d607205aae"),
+    ("keye_vl2_30b_a3b", False): (556, "5c4db69f75a2558857703e110ef2ec83ffe0"
+                                  "e40f649c6c9aae0f9b2fefe8640e"),
+}
+
+
+@pytest.mark.parametrize("config,rehearsal", sorted(_PARENT_PROGRAMS))
+def test_accepted_decoder_programs_are_op_for_op_the_parents(config,
+                                                             rehearsal):
+    """The model file grew a second kind of layer; the two accepted
+    decoder configurations still build the parent's programs, op for op:
+    types, inputs, outputs and every attribute (name scopes among
+    them), at the rehearsal's sizes and at the cell's."""
+    import hashlib
+    import json
+    import os
+    from benchmark.lib import cells
+    from paddle_tpu import models
+    fam = family if config.startswith("kanana") else gqa_family
+    with open(os.path.join(cells.BENCH, "configs", config + ".json")) as f:
+        sz = fam.sizes(json.load(f), rehearsal=rehearsal)
+    fluid.framework.unique_name.reset()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        cost, _, _ = models.decoder_lm_train(fam.model_config(sz))
+        fluid.optimizer.AdamOptimizer(learning_rate=1e-3).minimize(cost)
+    lines = []
+    for prog in (main, startup):
+        for op in prog.global_block().ops:
+            attrs = {k: repr(v) for k, v in sorted(dict(
+                op._all_attrs()).items())
+                if k not in ("op_callstack", "op_uid")
+                and not k.startswith("__")}
+            lines.append(json.dumps(
+                [op.type,
+                 {s: op.input(s) for s in sorted(op.input_slots())},
+                 {s: op.output(s) for s in sorted(op.output_slots())},
+                 attrs], sort_keys=True))
+    assert (len(lines), hashlib.sha256("\n".join(lines).encode())
+            .hexdigest()) == _PARENT_PROGRAMS[(config, rehearsal)]
+
+
+@pytest.mark.parametrize("key,value", [
+    ("moe_latent_size", 1024), ("num_nextn_predict_layers", 1),
+    ("sliding_window", 4096), ("layer_types", ["full_attention"])])
+def test_keys_that_change_a_layers_equations_raise_by_name(key, value):
+    """`DecoderLMConfig` swallowed every key it did not know: a file
+    with experts in a compressed latent, multi-token heads, a window or
+    per-layer attention types would have built some other model."""
+    from paddle_tpu import models
+    with pytest.raises(NotImplementedError, match=key):
+        models.DecoderLMConfig(n_routed_experts=8, **{key: value})
+    # absent, null or zero: the model that was always built
+    quiet = {"sliding_window": None, "layer_types": None,
+             "moe_latent_size": None, "num_nextn_predict_layers": 0}
+    models.DecoderLMConfig(n_routed_experts=8, **{key: quiet[key]},
+                           max_position_embeddings=4096, model_type="any")
+
+
+@pytest.mark.parametrize("config,fam", [
+    ("kanana2_30b_a3b", family), ("keye_vl2_30b_a3b", gqa_family),
+    ("nemotron_twotower_30b_a3b", hybrid_family)])
+def test_configuration_files_load_whole(config, fam):
+    """Every published key of a configuration file handed to the model
+    file at once (what `**unused` is for), the harness's own groups
+    left out: none of the three raises, `sliding_window: null` and
+    `layer_types` absent among them."""
+    import json
+    import os
+    from benchmark.lib import cells
+    from paddle_tpu import models
+    with open(os.path.join(cells.BENCH, "configs", config + ".json")) as f:
+        raw = json.load(f)
+    keys = {k: v for k, v in raw.items()
+            if k not in ("name", "family", "source", "deployment",
+                         "reduced", "assumed", "time_step_limit")}
+    cfg = models.DecoderLMConfig(**keys)
+    assert cfg.hidden_size == raw["hidden_size"]
+    assert (cfg.parts is not None) == (config.startswith("nemotron"))
+
+
+def test_scanned_tokens_reader():
+    from paddle_tpu.observability import mamba
+    scope = Scope()
+    assert mamba.scanned_tokens(scope) is None
+    scope.var(mamba.SSD_TOKENS_VAR).set_value(
+        jnp.asarray([4096, 4096, 4096], jnp.int32))
+    got = mamba.scanned_tokens(scope)
+    assert got.dtype == np.int64 and got.tolist() == [4096] * 3

@@ -521,6 +521,10 @@ RECIPES = {
 # the structured inputs (LoD offsets, RNN state, anchors, ...) the
 # generic one-op builder here cannot: entry -> where the coverage lives.
 COVERED = {
+    "causal_conv1d": "tests/test_decoder_lm.py (forward and the three gradients against the reference's convolution, with and without bias; causality)",
+    "gated_rms_norm": "tests/test_decoder_lm.py (forward and the three gradients against the reference's group norm, one group and four)",
+    "mamba2_ssd": "tests/test_decoder_lm.py, tests/test_kernels.py (hand-written grad: y and all seven gradients against the token-by-token recurrence, lowered and through the Pallas kernels, a ragged tail, B > 1)",
+    "relu2": "tests/test_decoder_lm.py (forward and gradient by hand)",
     "moe_experts": "tests/test_decoder_lm.py (forward and every gradient against the plain reference, lowered and through the grouped-matmul kernels; the share test; dropless under imbalance)",
     "moe_router": "tests/test_decoder_lm.py (choices, weights, counts and gradients against the plain reference; the top-k choice is piecewise constant, so central differences straddle its jumps)",
     "add_position_encoding": "tests/test_nlp_ops.py (position encoding parity incl. grad via transformer training)",

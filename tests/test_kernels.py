@@ -658,3 +658,98 @@ def test_flash_attention_respects_deny(monkeypatch):
     assert not fa.use_kernel_path(q, k, 128, 128)
     monkeypatch.delenv("PT_KERNEL_DENY")
     assert fa.use_kernel_path(q, k, 128, 128)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2's state-space scan: kernels and lowering against the recurrence
+# ---------------------------------------------------------------------------
+
+def _ssd_case(b, t, h, p, g, n, seed, dtype=jnp.float32):
+    r = np.random.default_rng(seed)
+
+    def draw(*shape):
+        return jnp.asarray(r.standard_normal(shape), jnp.float32)
+    return dict(x=draw(b, t, h, p).astype(dtype),
+                dt=jax.nn.softplus(draw(b, t, h) - 1.0),
+                a=-jnp.asarray(r.uniform(0.5, 4.0, (h,)), jnp.float32),
+                b=draw(b, t, g, n).astype(dtype),
+                c=draw(b, t, g, n).astype(dtype), d=draw(h),
+                dy=draw(b, t, h, p).astype(dtype))
+
+
+def _recurrence(x, dt, a, b, c, d):
+    from benchmark.families import mamba_gqa_moe_decoder_reference as ref
+    return ref.recurrence(x.astype(jnp.float32), dt, a,
+                          b.astype(jnp.float32), c.astype(jnp.float32), d,
+                          block=x.shape[1])
+
+
+@pytest.mark.parametrize("path", ["lowered", "mamba2_ssd_fwd_bwd"])
+@pytest.mark.parametrize("tokens,chunk", [(37, 16), (64, 16), (9, 16)],
+                         ids=["ragged_tail", "whole_chunks", "one_chunk"])
+def test_ssd_against_the_token_by_token_recurrence(tokens, chunk, path):
+    """`mamba2_ssd_fwd` / `_bwd` under the interpreter and the lowered
+    chunked form: y and the six gradients against the recurrence, B = 2,
+    at a length that is no multiple of the chunk among them; the states
+    handed to the backward are those of the chunks' starts."""
+    from paddle_tpu.kernels import mamba2_ssd as ssd
+    kernels = path != "lowered"
+    c = _ssd_case(2, tokens, 4, 8, 2, 16, seed=tokens)
+    args = [c[k] for k in "x dt a b c d".split()]
+    with jax.default_matmul_precision("highest"):
+        want = _recurrence(*args)
+        r_grads = jax.vjp(_recurrence, *args)[1](c["dy"])
+        y, states = ssd.ssd(*args, kernels, chunk=chunk)
+        grads = ssd.ssd_grad(*args, states, c["dy"], kernels, chunk=chunk)
+    assert states.shape == (2, -(-tokens // chunk), 4, 8, 16)
+    assert not np.asarray(states[:, 0]).any()       # S_0 = 0
+    np.testing.assert_allclose(y, want, atol=3e-5, rtol=3e-5)
+    for name, g, w in zip("x dt a b c d".split(), grads, r_grads):
+        assert g.shape == w.shape, name
+        scale = float(jnp.max(jnp.abs(w)))
+        assert float(jnp.max(jnp.abs(g - w))) <= 3e-5 * max(scale, 1), name
+
+
+def test_ssd_kernels_equal_their_lowering_in_bfloat16():
+    """bf16 operands, float32 accumulation, dt and every exp float32 on
+    both paths: the kernels and the lowered form round the same products,
+    so they agree far inside bf16's own rounding of the result."""
+    from paddle_tpu.kernels import mamba2_ssd as ssd
+    c = _ssd_case(1, 48, 4, 8, 2, 16, seed=5, dtype=jnp.bfloat16)
+    args = [c[k] for k in "x dt a b c d".split()]
+    (y_k, st_k), (y_l, st_l) = (ssd.ssd(*args, kern, chunk=16)
+                                for kern in (True, False))
+    assert y_k.dtype == jnp.bfloat16 and st_k.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(y_k, np.float32),
+                               np.asarray(y_l, np.float32), atol=0.06,
+                               rtol=0.02)
+    np.testing.assert_allclose(st_k, st_l, atol=0.02, rtol=0.02)
+    g_k, g_l = (ssd.ssd_grad(*args, st_l, c["dy"], kern, chunk=16)
+                for kern in (True, False))
+    for name, a, b in zip("x dt a b c d".split(), g_k, g_l):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= 0.03 * max(np.abs(b).max(), 1), name
+
+
+def test_ssd_is_registered_and_routes_like_the_others(interp, monkeypatch):
+    from paddle_tpu.kernels import mamba2_ssd as ssd
+    assert "mamba2_ssd" in kreg.kernel_names()
+    x, b = jnp.zeros((1, 32, 4, 8)), jnp.zeros((1, 32, 2, 16))
+    kreg.reset_stats()
+    assert ssd.use_kernels(x, b)
+    monkeypatch.setenv("PT_KERNEL_DENY", "mamba2_ssd")
+    assert not ssd.use_kernels(x, b)
+    with kreg.auto_partitioned():
+        monkeypatch.delenv("PT_KERNEL_DENY")
+        assert not ssd.use_kernels(x, b)         # a mesh: lowered
+    assert kreg.dispatch_stats()["per_kernel"]["mamba2_ssd"] == {
+        "custom": 1, "denied": 1, "lowered": 1}
+    # off the interpreter the shapes must tile: a group's heads a sublane
+    # tile, their channels and the state whole lane blocks
+    monkeypatch.setattr(kreg, "_INTERPRET", False)
+    sig = lambda h, p, g, n: kreg.signature(  # noqa: E731
+        "mamba2_ssd", jnp.zeros((1, 8, h, p), jnp.bfloat16),
+        jnp.zeros((1, 8, g, n), jnp.bfloat16))
+    assert ssd._eligible(sig(64, 64, 8, 128))     # the published mixer
+    assert not ssd._eligible(sig(4, 8, 2, 16))
+    assert not ssd._eligible(sig(64, 64, 8, 64))

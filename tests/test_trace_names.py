@@ -304,6 +304,78 @@ def test_grouped_matmul_kernels_are_named(one_chip, no_compile_cache,
         h.startswith("moe_grouped_matmul_" + which) for h in heads), heads
 
 
+# the Mamba-2 mixer of the twotower_s4096 cell: 64 heads of 64 in 8 groups,
+# state 128, one sequence of 4,096 tokens in chunks of 128
+SSD_X = ((1, 4096, 64, 64), jnp.bfloat16)
+SSD_BC = ((1, 4096, 8, 128), jnp.bfloat16)
+SSD_DT, SSD_HEAD = ((1, 4096, 64), jnp.float32), ((64,), jnp.float32)
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_ssd_kernels_compile_under_their_names(one_chip, no_compile_cache,
+                                               which, monkeypatch):
+    """`mamba2_ssd_fwd` / `mamba2_ssd_bwd` at the cell's sizes: one
+    custom call each, the chunk-start states float32 [1, 32, 4096, 128]
+    between them, and never a state a token."""
+    from paddle_tpu.kernels import mamba2_ssd as ssd
+    from paddle_tpu.kernels import registry
+    monkeypatch.setattr(registry, "interpret", lambda: False)
+    shapes = (SSD_X, SSD_DT, SSD_HEAD, SSD_BC, SSD_BC, SSD_HEAD)
+    if which == "fwd":
+        def fn(*a):
+            return ssd.ssd(*a, True)
+    else:
+        def fn(x, dt, a, b, c, d, states, dy):
+            return ssd.ssd_grad(x, dt, a, b, c, d, states, dy, True)
+        shapes += (((1, 32, 64, 64, 128), jnp.float32), SSD_X)
+    text = _compiled_text(fn, one_chip, *shapes)
+    heads = _custom_call_heads(text)
+    assert [h.split(".")[0] for h in heads] == ["mamba2_ssd_" + which], heads
+    assert "f32[1,32,4096,128]" in text
+    assert "[1,4096,64,64,128]" not in text and "[4096,64,64,128]" not in text
+
+
+@pytest.mark.parametrize("which", ["fwd", "dx", "dw"])
+def test_grouped_matmul_kernels_take_an_expert_width_of_1856(
+        one_chip, no_compile_cache, which, monkeypatch):
+    """The same three kernels for the ungated expert of the
+    twotower_s4096 cell, two matrices of 2688 x 1856 (1856 = 29 x 64 is
+    no multiple of 128: it stays whole in every block, the dw kernel's
+    output is blocked along the 2688 side, and a call whose
+    double-buffered blocks pass Mosaic's 16 MiB asks for more)."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+    from paddle_tpu.kernels import registry
+    monkeypatch.setattr(registry, "interpret", lambda: False)
+    held, d, f = 8, 2688, 1856
+    rows = gm.prefix_rows(4096 * 6, held, 128)[0]
+    assert rows == 2560
+    assert gm._eligible(registry.signature(
+        "moe_experts", jnp.zeros((rows, d), jnp.bfloat16),
+        jnp.zeros((held, d, f), jnp.bfloat16)))
+    tiles = ((rows // gm.TILE_ROWS,), jnp.int32)
+    wide, narrow = ((rows, d), jnp.bfloat16), ((rows, f), jnp.bfloat16)
+    up, down = ((held, d, f), jnp.bfloat16), ((held, f, d), jnp.bfloat16)
+
+    def both(fn):
+        def run(te, na, a, b, a2, b2):
+            plan = {"tile_expert": te, "n_active": na}
+            return fn(a, b, plan), fn(a2, b2, plan)
+        return run
+    if which == "fwd":
+        fn = both(lambda x, w, plan: gm.gmm(x, w, plan, True))
+        shapes = (wide, up, narrow, down)
+    elif which == "dx":
+        fn = both(lambda dy, w, plan: gm.gmm_dx(dy, w, plan, True))
+        shapes = (narrow, up, wide, down)
+    else:
+        fn = both(lambda x, dy, plan: gm.gmm_dw(x, dy, plan, held, True))
+        shapes = (wide, narrow, narrow, wide)
+    heads = _custom_call_heads(_compiled_text(
+        fn, one_chip, tiles, ((1,), jnp.int32), *shapes))
+    assert len(heads) == 2 and all(
+        h.startswith("moe_grouped_matmul_" + which) for h in heads), heads
+
+
 def test_no_other_kernel_reads_as_flash_or_adam():
     """The accepted classifier maps a head holding `adam` to fused_adam
     and one holding `flash` or `kern` to flash attention: no other
@@ -311,7 +383,8 @@ def test_no_other_kernel_reads_as_flash_or_adam():
     from paddle_tpu.tuning import variants
     names = ["fused_sgd", "quantized_matmul", "moe_grouped_matmul_fwd",
              "moe_grouped_matmul_dx", "moe_grouped_matmul_dw",
-             "sparse_index_scores", "sparse_index_select"] + [
+             "sparse_index_scores", "sparse_index_select",
+             "mamba2_ssd_fwd", "mamba2_ssd_bwd"] + [
         f"tuned_matmul_{v.epilogue}_{v.bm}x{v.bn}x{v.bk}"
         for v in variants.enumerate_variants()]
     for n in names:
@@ -385,3 +458,54 @@ def test_name_scope_nests_and_closes():
     assert by_type["mul"] == "a/b/"
     assert by_type["relu"] == "a/"
     assert by_type["mean"] == ""
+
+
+def test_hybrid_step_carries_its_scopes_and_its_counter():
+    """A one-part-a-layer model: the op scopes `layer_<i>/mamba`, `/moe`
+    and `/attn` lead the role and the type in the compiled step's
+    metadata, and the step overwrites the persistable int32
+    `mamba_ssd_tokens` [mixer layers] with the tokens each mixer
+    scanned."""
+    import numpy as np
+    from paddle_tpu import models
+    from paddle_tpu.core.scope import Scope
+    from paddle_tpu.observability import mamba
+    cfg = models.DecoderLMConfig(
+        vocab_size=64, hidden_size=32, hybrid_override_pattern="ME*M",
+        num_attention_heads=2, num_key_value_heads=1, head_dim=16,
+        mamba_num_heads=4, mamba_head_dim=8, n_groups=2, ssm_state_size=8,
+        chunk_size=8, n_routed_experts=8, experts_held=4,
+        num_experts_per_tok=2, moe_intermediate_size=16, n_shared_experts=1,
+        moe_shared_expert_intermediate_size=24, mlp_hidden_act="relu2",
+        layer_norm_epsilon=1e-5)
+    fluid.framework.unique_name.reset()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        cost, _, _ = models.decoder_lm_train(cfg)
+        fluid.optimizer.AdamOptimizer(learning_rate=1e-3).minimize(cost)
+    ids = np.random.default_rng(0).integers(0, 64, (2, 13), dtype=np.int32)
+    feed = {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
+    scope = Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        assert mamba.scanned_tokens(scope).tolist() == [0, 0]
+        exe.run(main, feed=feed, fetch_list=[cost])
+        exe.run(main, feed=feed, fetch_list=[cost])
+        text = exe._engine.compiled_step(main, scope, feed,
+                                         [cost.name]).as_text()
+    # overwritten, not added up: two steps read one step's tokens
+    assert mamba.scanned_tokens(scope).tolist() == [2 * 12, 2 * 12]
+    var = main.global_block().var(mamba.SSD_TOKENS_VAR)
+    assert var.persistable and tuple(var.shape) == (2,)
+    names = set(re.findall(r'op_name="([^"]+)"', text))
+
+    def has(fragment):
+        return any(fragment in n for n in names)
+
+    assert has("layer_0/mamba/forward/mamba2_ssd")
+    assert has("layer_0/mamba/forward/causal_conv1d")
+    assert has("layer_3/mamba/backward/")
+    assert has("layer_1/moe/forward/moe_experts")
+    assert has("layer_2/attn/forward/fused_attention")
+    assert has("layer_0/mamba/optimize/adam")
