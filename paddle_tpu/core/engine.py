@@ -1935,9 +1935,6 @@ class Engine:
             outs = self._dispatch(
                 program, scope, entry.traced, arrays, donated, const,
                 return_numpy, updated_vars=entry.updated_vars)
-            with clock.phase(_profiler.P_RELEASE):
-                # the donated arrays' wrappers die with this dict
-                del donated, const
             self._finish_step(clock, entry.traced, arrays)
             if multi_step > 1:
                 return self._finish_multi(
@@ -2022,8 +2019,6 @@ class Engine:
         outs = self._dispatch(program, scope, traced, arrays,
                               donated_params, const_params,
                               return_numpy)
-        with clock.phase(_profiler.P_RELEASE):
-            del donated_params, const_params
         self._finish_step(clock, traced, arrays)
         if multi_step > 1:
             return self._finish_multi(outs, program, scope, place,
@@ -2242,6 +2237,13 @@ class Engine:
             # owns the HBM before unwinding (one dump per exception)
             _obs_memory.oom_postmortem(exc, where="engine_dispatch")
             raise
+        with clock.phase(_profiler.P_RELEASE):
+            # nothing below needs the arguments: drop them under the
+            # device's work, not after the fetch has waited for it. The
+            # scope's variables hold the donated arrays until the
+            # writeback replaces them, so their wrappers die there
+            donated_params.clear()
+            const_params.clear()
         async_defer = bool(FLAGS.async_dispatch) and not return_numpy
         guard_plan = getattr(traced, "guard_plan", None)
         reexec = False

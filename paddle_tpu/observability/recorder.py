@@ -43,8 +43,8 @@ __all__ = ["FlightRecorder", "flight_recorder", "record_step", "dump",
 # a step record's phase keys, in the order the phases run (the slots
 # of profiler.StepClock); `total_ms` and `lane_idle_ms` ride beside them
 PHASE_KEYS = ("executor_feed_ms", "feed_ms", "trace_ms", "args_ms",
-              "rng_ms", "dispatch_ms", "writeback_ms", "fetch_ms",
-              "release_ms")
+              "rng_ms", "dispatch_ms", "release_ms", "writeback_ms",
+              "fetch_ms")
 
 _ENABLED = [False]
 _FAULT = [False]

@@ -89,10 +89,10 @@ class RecordEvent:
 # another, never nested. Index = slot in StepClock.ns.
 PHASE_NAMES = ("pt.executor.feed", "pt.engine.feed", "pt.engine.trace",
                "pt.engine.args", "pt.engine.rng", "pt.engine.dispatch",
-               "pt.engine.writeback", "pt.engine.fetch",
-               "pt.engine.release")
+               "pt.engine.release", "pt.engine.writeback",
+               "pt.engine.fetch")
 (P_EXECUTOR_FEED, P_FEED, P_TRACE, P_ARGS, P_RNG, P_DISPATCH,
- P_WRITEBACK, P_FETCH, P_RELEASE) = range(len(PHASE_NAMES))
+ P_RELEASE, P_WRITEBACK, P_FETCH) = range(len(PHASE_NAMES))
 FIRST_DISPATCH = "pt.engine.first_dispatch"
 STEP_SPAN = "pt.step"
 _NO_STAMPS = (0,) * (2 * len(PHASE_NAMES))
