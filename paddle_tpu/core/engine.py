@@ -13,6 +13,7 @@ liveness replaces the eager-deletion GC.
 """
 from __future__ import annotations
 
+import functools
 import gc
 import os
 
@@ -72,7 +73,11 @@ class _TrackingDict(dict):
 
 
 class TracedStep:
-    """A compiled step: callable over (param_arrays, feed_arrays, key)."""
+    """A compiled step. ``fn(donated_params, const_params, feeds,
+    rng_state)`` takes the RAW rng state, splits it as a host-side
+    ``jax.random.split`` would and returns ``(fetches, updated,
+    nan_flags, info)``: ``info["rng_state"]`` is the state the scope
+    keeps for the next step."""
 
     def __init__(self, fn, donated_names, const_names, feed_names,
                  fetch_names, updated_names, fetch_lods, uses_rng,
@@ -89,8 +94,9 @@ class TracedStep:
         # executable: Engine._first_dispatch spans it, once
         self.dispatched = False
         # PT_MULTI_STEP: K > 1 means fn scans K stacked batches through
-        # one executable and returns (stacked_fetches, updated,
-        # nan_flags, ms_info) instead of the 3-tuple contract
+        # one executable, splitting the state once a substep: the
+        # fetches come back stacked and info["valid"] counts the
+        # substeps that took effect
         self.multi_step = 1
         # live reference to the trace's (op_type, var_name) label box, one
         # entry per all-finite flag when check_nan_inf is on. A reference,
@@ -394,6 +400,25 @@ def _lod_accum_slices(feed_sig, feed_lods, accum_k):
                 plan[n] = (s0, s1, None)
         plans.append(plan)
     return plans
+
+
+def _split_first(fn):
+    """``fn`` over a step key -> the step's contract over the RAW rng
+    state (:class:`TracedStep`): the state is split as the host's
+    ``jax.random.split`` would split it, ``fn`` runs under the first
+    half and the second is handed back as the next state. Inside the
+    jitted step the split is part of the executable, so a steady step
+    is ONE executable call; the eager, island and scheduler paths split
+    on the host as they always did."""
+
+    @functools.wraps(fn)
+    def stepped(donated_params, const_params, feeds, rng):
+        pair = jax.random.split(rng)
+        fetches, updated, nan_flags = fn(donated_params, const_params,
+                                         feeds, pair[0])
+        return fetches, updated, nan_flags, {"rng_state": pair[1]}
+
+    return stepped
 
 
 def _loop_fallback(fn, iterations):
@@ -880,8 +905,8 @@ def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
                 return step(params, feeds, key)
 
             ts = TracedStep(_multi_loop_fallback(eager_fn, multi_step)
-                            if multi_step > 1
-                            else _loop_fallback(eager_fn, iterations),
+                            if multi_step > 1 else _split_first(
+                                _loop_fallback(eager_fn, iterations)),
                             [], avail, sorted(feed_sig),
                             list(fetch_names), [], fetch_lod_box,
                             True, nan_check_labels=nan_labels_box)
@@ -928,8 +953,8 @@ def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
             return fetches, updated, nan_flags
 
         ts = TracedStep(_multi_loop_fallback(islands_fn, multi_step)
-                        if multi_step > 1
-                        else _loop_fallback(islands_fn, iterations),
+                        if multi_step > 1 else _split_first(
+                            _loop_fallback(islands_fn, iterations)),
                         [], avail, sorted(feed_sig),
                         list(fetch_names), [], fetch_lod_box, True,
                         nan_check_labels=nan_labels_box)
@@ -1015,8 +1040,8 @@ def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
         # executable, amortizing the per-step host dispatch cost the
         # bench measures at ~3x the device time. Three invariants:
         #   1. Bit-identity: the RNG state rides the carry and splits
-        #      per substep exactly like K sequential host dispatches
-        #      (_dispatch_inner's jax.random.split), and guard EMA /
+        #      per substep exactly like K sequential dispatches
+        #      (_split_first), and guard EMA /
         #      loss scale / integrity fingerprints chain through the
         #      donated carry just as they chain through the scope — so
         #      anomaly-free trajectories match K=1 bit-for-bit.
@@ -1082,6 +1107,8 @@ def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
             return tuple(fs), upd_out, nan_flags, ms_info
     else:
         step2 = step1
+    if multi_step == 1:
+        step2 = _split_first(step2)
 
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1128,7 +1155,7 @@ def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
         # fetches replicated; updated persistables keep their sharding
         out_shardings = (tuple(repl for _ in fetch_names),
                          {n: param_sh(n) for n in updated_names},
-                         repl)
+                         repl, repl)
         fn = jax.jit(step2, donate_argnums=(0,),
                      in_shardings=in_shardings,
                      out_shardings=out_shardings,
@@ -1934,7 +1961,8 @@ class Engine:
                 const = {n: _var_array(v) for n, v in entry.const_vars}
             outs = self._dispatch(
                 program, scope, entry.traced, arrays, donated, const,
-                return_numpy, updated_vars=entry.updated_vars)
+                return_numpy, entry.dev,
+                updated_vars=entry.updated_vars)
             self._finish_step(clock, entry.traced, arrays)
             if multi_step > 1:
                 return self._finish_multi(
@@ -1963,6 +1991,9 @@ class Engine:
             if use_program_cache:
                 self._cache[key] = traced
 
+        # the one device an un-meshed step's arguments are committed to
+        dev = place.jax_device() \
+            if place is not None and self.mesh is None else None
         with clock.phase(_profiler.P_ARGS):
             donated_params = {}
             const_params = {}
@@ -1970,15 +2001,14 @@ class Engine:
                 donated_params[n] = _scope_array(scope, n)
             for n in traced.const_names:
                 const_params[n] = _scope_array(scope, n)
-            if place is not None and self.mesh is None:
+            if dev is not None:
                 # a startup program has no committed input, so it
                 # leaves the params UNCOMMITTED; this step's outputs
                 # are committed (the feeds are). Commit what the step
                 # donates now, or the second dispatch sees other
                 # argument shardings than the first and XLA compiles
                 # the whole step a second time
-                donated_params = jax.device_put(donated_params,
-                                                place.jax_device())
+                donated_params = jax.device_put(donated_params, dev)
             if multihost:
                 # params already produced by a previous multihost step
                 # are global arrays; only host-local values need
@@ -2004,10 +2034,8 @@ class Engine:
                 # reconstruction, persistable re-walks, and no-op
                 # device_puts
                 entries = self._fast.setdefault(fast_key, [])
-                entry = _FastPathEntry(
-                    scope, place, place.jax_device()
-                    if place is not None and self.mesh is None
-                    else None, arrays, lods, traced)
+                entry = _FastPathEntry(scope, place, dev, arrays, lods,
+                                       traced)
                 entry.sig_hash = _sig_hash(feed_sig_key)
                 entries.append(entry)
                 if len(entries) > _MAX_FAST_ENTRIES:
@@ -2018,7 +2046,7 @@ class Engine:
             _obs_memory.track_scope(scope)
         outs = self._dispatch(program, scope, traced, arrays,
                               donated_params, const_params,
-                              return_numpy)
+                              return_numpy, dev)
         self._finish_step(clock, traced, arrays)
         if multi_step > 1:
             return self._finish_multi(outs, program, scope, place,
@@ -2115,7 +2143,7 @@ class Engine:
         return self.last_multi_fetches
 
     def _dispatch(self, program, scope, traced, arrays, donated_params,
-                  const_params, return_numpy, updated_vars=None):
+                  const_params, return_numpy, dev, updated_vars=None):
         """Watchdog wrapper over :meth:`_dispatch_inner`: with
         FLAGS_step_timeout_s > 0 the step runs armed, and a hang is
         converted into the watchdog's diagnosable EnforceNotMet (the
@@ -2125,13 +2153,13 @@ class Engine:
         if wd is None:
             return self._dispatch_inner(
                 program, scope, traced, arrays, donated_params,
-                const_params, return_numpy, updated_vars)
+                const_params, return_numpy, dev, updated_vars)
         try:
             try:
                 wd.arm()
                 return self._dispatch_inner(
                     program, scope, traced, arrays, donated_params,
-                    const_params, return_numpy, updated_vars)
+                    const_params, return_numpy, dev, updated_vars)
             finally:
                 wd.disarm()
         except KeyboardInterrupt:
@@ -2199,44 +2227,52 @@ class Engine:
 
     def _dispatch_inner(self, program, scope, traced, arrays,
                         donated_params, const_params, return_numpy,
-                        updated_vars=None, _guard_reexec=False):
-        """Shared dispatch tail of fast and slow paths: RNG split,
-        executable call, device-resident scope writeback, NaN-check
-        surfacing (inline or deferred), fetch wrapping. Under
-        FLAGS.async_dispatch nothing here forces a device sync — the
-        RNG split and persistable writebacks stay jax.Array futures and
-        the nan-flag host sync moves to the materialization point."""
+                        dev, updated_vars=None, _guard_reexec=False):
+        """Shared dispatch tail of fast and slow paths: the RNG state,
+        the ONE executable call (it splits the state itself,
+        :func:`_split_first`), then everything that needs neither the
+        step's result nor the next feed — the fetches' copies to the
+        host started, the argument dicts released, the device-resident
+        scope writeback — and only then the call that blocks on the
+        result: NaN-check surfacing (inline or deferred) and fetch
+        wrapping. EMPTIES ``donated_params`` and ``const_params``.
+        Under FLAGS.async_dispatch nothing here forces a device sync —
+        the persistable writebacks stay jax.Array futures and the
+        nan-flag host sync moves to the materialization point."""
         clock = _profiler.step_clock()
         multi_k = int(getattr(traced, "multi_step", 1) or 1)
+        async_defer = bool(FLAGS.async_dispatch) and not return_numpy
         with clock.phase(_profiler.P_RNG):
             rng_key = _get_rng_state(scope, program)
-            if multi_k > 1:
-                # multi-step (PT_MULTI_STEP): the scanned executable
-                # splits the rng PER SUBSTEP on device — bit-identical
-                # to K sequential host splits — so it takes the RAW
-                # state and returns the carried state in
-                # ms_info["rng_state"]
-                step_key, next_state = rng_key, None
-            else:
-                step_key, next_state = jax.random.split(rng_key)
+            if dev is not None and (arrays or donated_params) and not (
+                    getattr(rng_key, "committed", False)
+                    and _on_device(rng_key, dev)):
+                # the state a step hands back is committed like the
+                # step's other outputs; a startup program's, a fresh
+                # seed's or a user's own is not. Commit it beside the
+                # feeds and the donated arrays, or the next dispatch
+                # sees another argument signature and XLA compiles the
+                # whole step a second time
+                rng_key = jax.device_put(rng_key, dev)
         first = not traced.dispatched
         try:
             if first:
                 res = self._first_dispatch(
                     clock, program, traced, donated_params,
-                    const_params, arrays, step_key)
+                    const_params, arrays, rng_key)
             else:
                 with clock.phase(_profiler.P_DISPATCH):
                     # async dispatch: this is the enqueue span; device
                     # time lands in the fetch phase (sync) or at the
                     # materialization point
                     res = traced.fn(donated_params, const_params,
-                                    arrays, step_key)
+                                    arrays, rng_key)
         except Exception as exc:
             # RESOURCE_EXHAUSTED here = compile/alloc OOM: capture who
             # owns the HBM before unwinding (one dump per exception)
             _obs_memory.oom_postmortem(exc, where="engine_dispatch")
             raise
+        fetches, updated, nan_flags, info = res
         with clock.phase(_profiler.P_RELEASE):
             # nothing below needs the arguments: drop them under the
             # device's work, not after the fetch has waited for it. The
@@ -2244,17 +2280,10 @@ class Engine:
             # writeback replaces them, so their wrappers die there
             donated_params.clear()
             const_params.clear()
-        async_defer = bool(FLAGS.async_dispatch) and not return_numpy
         guard_plan = getattr(traced, "guard_plan", None)
         reexec = False
         with clock.phase(_profiler.P_WRITEBACK):
-            if multi_k > 1:
-                fetches, updated, nan_flags, ms_info = res
-                _set_rng_state(scope, ms_info["rng_state"])
-            else:
-                fetches, updated, nan_flags = res
-                ms_info = None
-                _set_rng_state(scope, next_state)
+            _set_rng_state(scope, info["rng_state"])
             comm_stats = getattr(traced, "comm_stats", None)
             if comm_stats:
                 c = self.counters
@@ -2306,7 +2335,7 @@ class Engine:
                 reexec = action == "reexecute"
             if not reexec:
                 self._after_writeback(program, scope, traced, updated,
-                                      guard_plan, ms_info, multi_k)
+                                      guard_plan, info, multi_k)
         if reexec:
             # the scope now holds the restored ghost (params, optimizer
             # state, loss scale, RNG); re-run THIS step from it —
@@ -2318,7 +2347,7 @@ class Engine:
                       for n in traced.const_names}
             return self._dispatch_inner(
                 program, scope, traced, arrays, donated2, const2,
-                return_numpy, updated_vars, _guard_reexec=True)
+                return_numpy, dev, updated_vars, _guard_reexec=True)
         with clock.phase(_profiler.P_FETCH):
             rec = None
             if traced.nan_check_labels:
@@ -2350,7 +2379,7 @@ class Engine:
         return out
 
     def _first_dispatch(self, clock, program, traced, donated_params,
-                        const_params, arrays, step_key):
+                        const_params, arrays, rng_key):
         """The first call of an executable: jit lowering plus XLA
         compile or persistent-cache load, once. A set-up span
         (`first_dispatch`, observability/tracing.py) beside the
@@ -2364,7 +2393,7 @@ class Engine:
                 clock.phase(_profiler.P_DISPATCH,
                             _profiler.FIRST_DISPATCH):
             res = traced.fn(donated_params, const_params, arrays,
-                            step_key)
+                            rng_key)
             hits, misses = _obs_tracing.compile_cache_events()
             if misses > misses0:
                 span.ann["cache"] = "miss"
@@ -2373,7 +2402,7 @@ class Engine:
         return res
 
     def _after_writeback(self, program, scope, traced, updated,
-                         guard_plan, ms_info, multi_k):
+                         guard_plan, info, multi_k):
         """Tail of the writeback phase: the integrity controller and
         the multi-step slab's accounting."""
         if getattr(traced, "integrity_plan", None) is not None:
@@ -2393,8 +2422,8 @@ class Engine:
             # construction (no sync); guard-on pays ONE scalar sync per
             # slab — amortized 1/K vs the per-step verdict sync of K=1
             valid = multi_k
-            if guard_plan is not None and ms_info is not None:
-                valid = int(np.asarray(ms_info["valid"]))
+            if guard_plan is not None:
+                valid = int(np.asarray(info["valid"]))
                 valid = max(1, min(valid, multi_k))
             self._last_multi = {"k": multi_k, "valid": valid}
             c = self.counters

@@ -943,7 +943,7 @@ def build_scheduled_step(program, block, params_sig, feed_sig,
     Never raises: any build/validation failure means "not schedulable",
     not "broken program" — the standard path will surface real errors.
     """
-    from .engine import TracedStep
+    from .engine import TracedStep, _split_first
     ops = list(block.ops)
     try:
         if any(_has_sub_block(op) for op in ops):
@@ -975,7 +975,8 @@ def build_scheduled_step(program, block, params_sig, feed_sig,
         sched.guard_plan = guard_plan
     except Exception:
         return None
-    ts = TracedStep(sched, [], list(avail), sorted(feed_sig),
+    ts = TracedStep(_split_first(sched.__call__), [], list(avail),
+                    sorted(feed_sig),
                     list(fetch_names), list(updated_names),
                     fetch_lod_box, uses_rng,
                     nan_check_labels=sched.labels)

@@ -311,8 +311,8 @@ class AnalysisPredictor:
             arrs = {n: a if isinstance(a, jax.Array)
                     else jnp.asarray(np.asarray(a))
                     for n, a in feed_arrays.items()}
-            fetches, updated, _ = fn(dict(d_params), c_params, arrs,
-                                     key)
+            fetches, updated, *_ = fn(dict(d_params), c_params, arrs,
+                                      key)
             # donated buffers are consumed by the executable; carry the
             # updated state forward so the next call has live arrays
             d_params.update(updated)

@@ -346,7 +346,7 @@ class FrozenServingModel:
                     arrs = {n: a if isinstance(a, jax.Array)
                             else jnp.asarray(np.asarray(a))
                             for n, a in feed_arrays.items()}
-                    fetches, updated, _ = traced.fn(
+                    fetches, updated, *_ = traced.fn(
                         dict(d_params), c_params, arrs, key)
                     d_params.update(updated)
                     return list(fetches)

@@ -55,15 +55,17 @@ def quiet():
     metrics._recompute_hot()
 
 
-def _pt_spans(trace_dir):
-    """{thread line: [(name, start_ns, end_ns)]} of the `pt.` spans."""
+def _pt_spans(trace_dir, also=()):
+    """{thread line: [(name, start_ns, end_ns)]} of the `pt.` spans (and
+    of the events whose names hold one of `also`)."""
     path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                       recursive=True)
     out = {}
     for plane in jax.profiler.ProfileData.from_file(path).planes:
         for line in plane.lines:
             spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
-                     for e in line.events if e.name.startswith("pt.")]
+                     for e in line.events if e.name.startswith("pt.")
+                     or any(part in e.name for part in also)]
             if spans:
                 out[f"{plane.name}/{line.name}"] = sorted(
                     spans, key=lambda s: s[1])
@@ -100,6 +102,42 @@ def test_steps_and_phases_in_a_profiler_session(quiet):
             assert b <= nxt[1]
     # every phase span lies in some step
     assert len(spans) == 3 * (1 + len(STEADY))
+
+
+def test_a_steady_step_is_one_executable_call(quiet):
+    """The rng state is split INSIDE the compiled step: between a
+    steady `pt.step`'s start and end the host calls one executable,
+    the step's own (no `_threefry_split`, no `_unstack`), dropout or
+    not."""
+    fluid.framework.unique_name.reset()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = layers.data(name="x", shape=[4], dtype="float32")
+        loss = layers.mean(layers.dropout(layers.fc(x, size=2), 0.5))
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    scope = Scope()
+    feed = {"x": np.ones((2, 4), np.float32)}
+    d = tempfile.mkdtemp(prefix="pt_spans_")
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        exe.run(main, feed=feed, fetch_list=[loss])     # cold: traces
+        jax.profiler.start_trace(d)
+        try:
+            for _ in range(3):
+                exe.run(main, feed=feed, fetch_list=[loss])
+        finally:
+            jax.profiler.stop_trace()
+    spans, = _pt_spans(d, also=("PjitFunction(",
+                                "Executable::Execute")).values()
+    steps = [s for s in spans if s[0] == "pt.step"]
+    assert len(steps) == 3
+    for _, a, b in steps:
+        inside = [s[0] for s in spans if a <= s[1] and s[2] <= b]
+        calls = {n for n in inside if n.startswith("PjitFunction(")}
+        assert calls == {"PjitFunction(step1)"}, calls
+        runs = [n for n in inside if n.endswith("Executable::Execute")]
+        assert len(runs) == 1, runs
 
 
 def test_cold_step_spans_trace_and_first_dispatch(quiet):
