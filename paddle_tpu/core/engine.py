@@ -2273,11 +2273,6 @@ class Engine:
             _obs_memory.oom_postmortem(exc, where="engine_dispatch")
             raise
         fetches, updated, nan_flags, info = res
-        if not async_defer:
-            # the copies the fetch phase will wait on, queued behind the
-            # step instead of requested after its last operation
-            self._start_host_copies(traced, fetches, nan_flags,
-                                    return_numpy, multi_k)
         with clock.phase(_profiler.P_RELEASE):
             # nothing below needs the arguments: drop them under the
             # device's work, not after the fetch has waited for it. The
@@ -2453,21 +2448,6 @@ class Engine:
                         "pt_multistep_early_exits_total",
                         "slabs cut short by a guard verdict "
                         "(carry freeze)").inc(1)
-
-    @staticmethod
-    def _start_host_copies(traced, fetches, nan_flags, return_numpy, k):
-        """Ask the runtime, at dispatch, for the device-to-host copies
-        that this call's fetch phase will materialise: the fetches
-        :meth:`_package` turns into numpy and the all-finite flags
-        FLAGS_check_nan_inf reads."""
-        lods = traced.fetch_lods
-        wanted = [v for n, v in zip(traced.fetch_names, fetches)
-                  if k > 1 or (return_numpy and not lods.get(n))]
-        if traced.nan_check_labels:
-            wanted.append(nan_flags)
-        for v in wanted:
-            if isinstance(v, jax.Array):
-                v.copy_to_host_async()
 
     def _package(self, traced, fetches, rec, program, async_defer,
                  return_numpy, k):
