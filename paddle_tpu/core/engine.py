@@ -2231,11 +2231,10 @@ class Engine:
         """Shared dispatch tail of fast and slow paths: the RNG state,
         the ONE executable call (it splits the state itself,
         :func:`_split_first`), then everything that needs neither the
-        step's result nor the next feed — the fetches' copies to the
-        host started, the argument dicts released, the device-resident
-        scope writeback — and only then the call that blocks on the
-        result: NaN-check surfacing (inline or deferred) and fetch
-        wrapping. EMPTIES ``donated_params`` and ``const_params``.
+        step's result nor the next feed — the argument dicts released,
+        the device-resident scope writeback — and only then the call
+        that blocks on the result: NaN-check surfacing (inline or
+        deferred) and fetch wrapping. EMPTIES ``donated_params`` and ``const_params``.
         Under FLAGS.async_dispatch nothing here forces a device sync —
         the persistable writebacks stay jax.Array futures and the
         nan-flag host sync moves to the materialization point."""

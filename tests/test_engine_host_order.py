@@ -1,10 +1,10 @@
 """What the engine does between the enqueue of a step and the call that
 blocks on its result (core/engine.py `_dispatch_inner`): the rng state
-is split inside the compiled step, the argument dicts are dropped and
-the fetches' copies to the host are started before the fetch waits.
-None of it may change a value: the stream of step keys, the scope's rng
-state and the losses are what host-side `jax.random.split`s of the seed
-give, and every fetch comes back in the form it always had.
+is split inside the compiled step and the argument dicts are dropped
+before the fetch waits. None of it may change a value: the stream of
+step keys, the scope's rng state and the losses are what host-side
+`jax.random.split`s of the seed give, and every fetch comes back in the
+form it always had.
 """
 import os
 import warnings
@@ -207,7 +207,7 @@ def test_one_executable_whoever_made_the_state():
 
 
 # ---------------------------------------------------------------------------
-# the fetch: its copy starts at dispatch, its form is what it was
+# the fetch: the last phase of the step, its form what it always was
 # ---------------------------------------------------------------------------
 
 def _fetch_program():
