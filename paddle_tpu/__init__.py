@@ -7,6 +7,9 @@ jax.sharding meshes (see parallel/).
 """
 from __future__ import annotations
 
+import time as _time
+_IMPORT_P0 = _time.perf_counter()     # the `import` set-up span's start
+
 from .core.compile_cache import configure_compile_cache as _cfg_cache
 _cfg_cache()
 
@@ -79,3 +82,9 @@ def is_compiled_with_cuda():
 
 
 __version__ = "0.1.0"
+
+# what a job pays for this file, first line to last, as a set-up span
+# (observability/tracing.py; docs/TRACING.md "Set-up")
+from .observability import tracing as _tracing  # noqa: E402
+with _tracing.setup_span("import", p0=_IMPORT_P0):
+    pass

@@ -16,6 +16,7 @@ from typing import Dict, List, Optional, Sequence, Set
 from . import framework
 from .core.registry import OPS, GRAD_SUFFIX, OP_UID_ATTR
 from .core.types import is_float_dtype
+from .observability import tracing as _obs_tracing
 
 __all__ = ["append_backward", "gradients"]
 
@@ -163,6 +164,12 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
 
     Returns list of (param, grad_var) tuples (reference backward.py:558).
     """
+    with _obs_tracing.setup_span("program_build.backward",
+                                 program=loss.block.program.fingerprint[0]):
+        return _append_backward(loss, parameter_list, no_grad_set)
+
+
+def _append_backward(loss, parameter_list, no_grad_set):
     block = loss.block
     program = block.program
     no_grad = set(no_grad_set or ())

@@ -16,6 +16,7 @@ import warnings
 import jax.numpy as jnp
 
 from ... import layers
+from ...observability import tracing as _obs_tracing
 from .fp16_lists import AutoMixedPrecisionLists
 
 __all__ = ["decorate", "OptimizerWithMixedPrecision"]
@@ -61,6 +62,18 @@ class OptimizerWithMixedPrecision:
 
     def backward(self, loss, startup_program=None, parameter_list=None,
                  no_grad_set=None, callbacks=None):
+        """The mixed-precision rewrite: the program's AMP lists set,
+        the loss scaled, the inner optimizer's backward (its own
+        `program_build.backward` inside this span), the gradients
+        unscaled."""
+        with _obs_tracing.setup_span(
+                "program_build.amp",
+                program=loss.block.program.fingerprint[0]):
+            return self._backward(loss, startup_program, parameter_list,
+                                  no_grad_set)
+
+    def _backward(self, loss, startup_program, parameter_list,
+                  no_grad_set):
         program = loss.block.program
         program._amp = {"dtype": self._dtype,
                         "black_ops": frozenset(self._amp_lists.black_list),

@@ -13,6 +13,7 @@ liveness replaces the eager-deletion GC.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import gc
 import os
@@ -512,7 +513,6 @@ def _kernel_scope(mesh):
     """Trace-time kernel-routing scope: a step XLA partitions over more
     than one device keeps every op on its lowered path (a Mosaic kernel
     cannot be partitioned automatically; kernels/registry.py)."""
-    import contextlib
     if mesh is None or getattr(mesh, "size", 1) <= 1:
         return contextlib.nullcontext()
     from ..kernels import registry as _kreg
@@ -525,7 +525,6 @@ def _activation_scope(mesh, strategy):
     the step body traces. Only live for multi-axis (fsdp/tp) meshes or
     explicit activation rules, so the long-standing dp path traces
     byte-identically."""
-    import contextlib
     if mesh is None or strategy is None:
         return contextlib.nullcontext()
     rules = getattr(strategy, "activation_rules", None)
@@ -1981,78 +1980,88 @@ class Engine:
         key = self._cache_key(program, block_idx, feed_sig_key,
                               fetch_names, iterations, multi_step)
         traced = self._cache.get(key) if use_program_cache else None
-        if traced is None:
-            self.counters["traces"] += 1
-            clock.traced = True
-            with clock.phase(_profiler.P_TRACE):
-                traced = self._trace(program, block_idx, arrays, lods,
-                                     fetch_names, scope, iterations,
-                                     multi_step)
-            if use_program_cache:
-                self._cache[key] = traced
+        if traced is not None and traced.dispatched:
+            cold = contextlib.nullcontext()
+        else:
+            # this call traces or first-dispatches: one set-up span
+            # over the whole of it, begun where Executor.run began the
+            # step, with `trace_step` and `first_dispatch` inside
+            cold = _obs_tracing.setup_span(
+                "cold_run", p0=clock.step_ns / 1e9,
+                program=program.fingerprint[0])
+        with cold:
+            if traced is None:
+                self.counters["traces"] += 1
+                clock.traced = True
+                with clock.phase(_profiler.P_TRACE):
+                    traced = self._trace(program, block_idx, arrays, lods,
+                                         fetch_names, scope, iterations,
+                                         multi_step)
+                if use_program_cache:
+                    self._cache[key] = traced
 
-        # the one device an un-meshed step's arguments are committed to
-        dev = place.jax_device() \
-            if place is not None and self.mesh is None else None
-        with clock.phase(_profiler.P_ARGS):
-            donated_params = {}
-            const_params = {}
-            for n in traced.donated_names:
-                donated_params[n] = _scope_array(scope, n)
-            for n in traced.const_names:
-                const_params[n] = _scope_array(scope, n)
-            if dev is not None:
-                # a startup program has no committed input, so it
-                # leaves the params UNCOMMITTED; this step's outputs
-                # are committed (the feeds are). Commit what the step
-                # donates now, or the second dispatch sees other
-                # argument shardings than the first and XLA compiles
-                # the whole step a second time
-                donated_params = jax.device_put(donated_params, dev)
-            if multihost:
-                # params already produced by a previous multihost step
-                # are global arrays; only host-local values need
-                # assembling — and globalized const params are written
-                # back to the scope so the transfer happens once, not
-                # per step
-                def _as_global(n, v, write_back):
-                    if isinstance(v, jax.Array) and \
-                            not v.is_fully_addressable:
-                        return v
-                    g = self._globalize_replicated({n: v})[n]
-                    if write_back:
-                        scope.var(n).set_value(g)
-                    return g
+            # the one device an un-meshed step's arguments are committed to
+            dev = place.jax_device() \
+                if place is not None and self.mesh is None else None
+            with clock.phase(_profiler.P_ARGS):
+                donated_params = {}
+                const_params = {}
+                for n in traced.donated_names:
+                    donated_params[n] = _scope_array(scope, n)
+                for n in traced.const_names:
+                    const_params[n] = _scope_array(scope, n)
+                if dev is not None:
+                    # a startup program has no committed input, so it
+                    # leaves the params UNCOMMITTED; this step's outputs
+                    # are committed (the feeds are). Commit what the step
+                    # donates now, or the second dispatch sees other
+                    # argument shardings than the first and XLA compiles
+                    # the whole step a second time
+                    donated_params = jax.device_put(donated_params, dev)
+                if multihost:
+                    # params already produced by a previous multihost step
+                    # are global arrays; only host-local values need
+                    # assembling — and globalized const params are written
+                    # back to the scope so the transfer happens once, not
+                    # per step
+                    def _as_global(n, v, write_back):
+                        if isinstance(v, jax.Array) and \
+                                not v.is_fully_addressable:
+                            return v
+                        g = self._globalize_replicated({n: v})[n]
+                        if write_back:
+                            scope.var(n).set_value(g)
+                        return g
 
-                donated_params = {n: _as_global(n, v, False)
-                                  for n, v in donated_params.items()}
-                const_params = {n: _as_global(n, v, True)
-                                for n, v in const_params.items()}
-            elif fast_key is not None:
-                # steady-state record: subsequent runs of this
-                # (program, feed-sig, fetch) tuple skip signature
-                # reconstruction, persistable re-walks, and no-op
-                # device_puts
-                entries = self._fast.setdefault(fast_key, [])
-                entry = _FastPathEntry(scope, place, dev, arrays, lods,
-                                       traced)
-                entry.sig_hash = _sig_hash(feed_sig_key)
-                entries.append(entry)
-                if len(entries) > _MAX_FAST_ENTRIES:
-                    entries.pop(0)
-            # cold path only: register the scope with the memory census
-            # (one weak-set add per trace, nothing per steady-state
-            # step)
-            _obs_memory.track_scope(scope)
-        outs = self._dispatch(program, scope, traced, arrays,
-                              donated_params, const_params,
-                              return_numpy, dev)
-        self._finish_step(clock, traced, arrays)
-        if multi_step > 1:
-            return self._finish_multi(outs, program, scope, place,
-                                      feed, fetch_names, block_idx,
-                                      return_numpy, multi_step)
-        return outs
+                    donated_params = {n: _as_global(n, v, False)
+                                      for n, v in donated_params.items()}
+                    const_params = {n: _as_global(n, v, True)
+                                    for n, v in const_params.items()}
+                elif fast_key is not None:
+                    # steady-state record: subsequent runs of this
+                    # (program, feed-sig, fetch) tuple skip signature
+                    # reconstruction, persistable re-walks, and no-op
+                    # device_puts
+                    entries = self._fast.setdefault(fast_key, [])
+                    entry = _FastPathEntry(scope, place, dev, arrays, lods,
+                                           traced)
+                    entry.sig_hash = _sig_hash(feed_sig_key)
+                    entries.append(entry)
+                    if len(entries) > _MAX_FAST_ENTRIES:
+                        entries.pop(0)
+                # cold path only: register the scope with the memory census
+                # (one weak-set add per trace, nothing per steady-state
+                # step)
+                _obs_memory.track_scope(scope)
+            outs = self._dispatch(program, scope, traced, arrays,
+                                  donated_params, const_params,
+                                  return_numpy, dev)
+            self._finish_step(clock, traced, arrays)
+            if multi_step > 1:
+                return self._finish_multi(outs, program, scope, place,
+                                          feed, fetch_names, block_idx,
+                                          return_numpy, multi_step)
+            return outs
 
     def _trace(self, program, block_idx, arrays, lods, fetch_names,
                scope, iterations, multi_step):
@@ -2379,11 +2388,14 @@ class Engine:
 
     def _first_dispatch(self, clock, program, traced, donated_params,
                         const_params, arrays, rng_key):
-        """The first call of an executable: jit lowering plus XLA
-        compile or persistent-cache load, once. A set-up span
-        (`first_dispatch`, observability/tracing.py) beside the
-        profiler's, annotated with what the compilation cache said
-        where `jax.monitoring` tells."""
+        """The first call of an executable: JAX's trace of the step
+        function, lowering, XLA compile or persistent-cache load, and
+        the first execution, once. A set-up span (`first_dispatch`,
+        observability/tracing.py) beside the profiler's; what
+        `jax.monitoring` times inside it become its children
+        (`first_dispatch.jit_trace` / `.lower` / `.compile` or
+        `.cache_load`), and it is annotated with what the compilation
+        cache said where `jax.monitoring` tells."""
         traced.dispatched = True
         hits0, misses0 = _obs_tracing.compile_cache_events()
         with _obs_tracing.setup_span(

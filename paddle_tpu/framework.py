@@ -16,6 +16,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import threading
+import time
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -27,6 +28,7 @@ from .proto import framework_pb2 as fpb
 from .core import types as core_types
 from .core.registry import OPS, ExecContext, OP_UID_ATTR, GRAD_SUFFIX
 from .core.types import convert_dtype, dtype_to_np, dtype_to_str
+from .observability import tracing as _obs_tracing
 
 __all__ = [
     "Program", "Block", "Operator", "Variable", "Parameter",
@@ -489,10 +491,14 @@ class Block:
         self.ops.append(op)
         self.program._bump_version()
         if infer_shape:
+            t0 = time.perf_counter()
             try:
                 self._infer_op_shapes(op)
             except NotImplementedError:
                 pass
+            # build-time shape inference has a counter, not a span: a
+            # program appends thousands of ops (tracing.build_totals)
+            _obs_tracing.note_infer_shapes(type, time.perf_counter() - t0)
         return op
 
     def _prepend_op(self, type: str, inputs=None, outputs=None, attrs=None):

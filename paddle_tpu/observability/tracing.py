@@ -49,6 +49,8 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+import jax
+
 from . import metrics as _metrics
 from . import recorder as _recorder
 
@@ -56,6 +58,8 @@ __all__ = ["worker_id", "set_worker", "default_worker", "new_span_id",
            "begin_step", "current_context", "span", "server_span",
            "record_span", "finish_step", "span_buffer",
            "setup_span", "setup_spans", "clear_setup_spans",
+           "build_totals", "compile_totals", "reset_setup_totals",
+           "note_infer_shapes", "compile_cache_events",
            "spans_snapshot", "clear_spans", "dump_spans",
            "read_span_dump", "find_span_dumps", "note_step_duration",
            "step_summary", "update_skew", "skew_snapshot",
@@ -73,13 +77,16 @@ def worker_id() -> str:
     """Stable identity of this process in the fleet: ``PT_WORKER`` env
     override, else ``trainer<PADDLE_TRAINER_ID>``, else ``pid<pid>``
     (standalone runs). Part of every trace id, so it must agree across
-    threads of one process."""
+    threads of one process. The ``pid`` fallback is nobody's choice and
+    is not kept: the `import` set-up span asks before a pserver has
+    called :func:`default_worker`."""
     if _WORKER[0] is None:
         w = os.environ.get("PT_WORKER")
         if not w:
             tid = os.environ.get("PADDLE_TRAINER_ID")
-            w = f"trainer{tid}" if tid not in (None, "") \
-                else f"pid{os.getpid()}"
+            if tid in (None, ""):
+                return f"pid{os.getpid()}"
+            w = f"trainer{tid}"
         _WORKER[0] = w
     return _WORKER[0]
 
@@ -360,17 +367,50 @@ def server_span(tctx: Optional[dict], name: str, kind: str = "rpc.server",
 # set-up spans: once per executable, recorded whether or not _HOT
 # ---------------------------------------------------------------------------
 
-# What a process spends before its first steady step — `trace_step` per
-# program (child `trace_step.op_walk`, the abstract walk over the ops'
-# lowerings that finds the updated persistables) and `first_dispatch`
-# per executable (jit lowering plus XLA compile or persistent-cache
-# load). They occur once per executable, never per
+# What a process spends before its first steady step (docs/TRACING.md,
+# "Set-up"): `import` (the package), `program_build.backward` /
+# `.optimize` / `.amp` (the build passes that have an entry point),
+# and per cold `Executor.run` one `cold_run` holding `trace_step`
+# (child `trace_step.op_walk`, the abstract walk over the ops'
+# lowerings that finds the updated persistables) and `first_dispatch`,
+# whose children `first_dispatch.jit_trace` / `.lower` / `.compile` or
+# `.cache_load` are what `jax.monitoring` timed inside it (the rest of
+# it is the first execution). They occur once per executable, never per
 # step, so they are kept without the telemetry switch, and apart from
 # the span ring: 4,096 step spans would push them out of it. With _HOT
 # set they are mirrored into the ring, so the dumps tools/timeline.py
 # reads show them.
 _SETUP_MAX = 512
 _SETUP: List[dict] = []
+# guards the process totals below; a listener call or an appended op
+# takes it once
+_TOTALS_LOCK = threading.Lock()
+FIRST_DISPATCH = "first_dispatch"
+_OUTSIDE = "outside"
+_JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+                 "/jax/compilation_cache/cache_misses": "cache_misses"}
+# a nested event starts no earlier than the one around it; JAX times
+# with time.time(), the arrival is read with perf_counter
+_NESTING_SLACK_S = 5e-4
+_JIT_PENDING_MAX = 4096
+
+
+def _no_compiles():
+    return {"jit_trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+            "cache_load_s": 0.0, "cache_retrieval_s": 0.0,
+            "cache_hits": 0, "cache_misses": 0}
+
+
+_COMPILES = {FIRST_DISPATCH: _no_compiles(), _OUTSIDE: _no_compiles()}
+_BUILD: Dict[str, list] = {}       # op type -> [calls, seconds]
+_INFERRED_S = [0.0]                # seconds over all op types
+_LISTENING = [False]
 
 
 def setup_spans() -> List[dict]:
@@ -383,59 +423,221 @@ def clear_setup_spans() -> None:
     del _SETUP[:]
 
 
-class _SetupSpan:
-    __slots__ = ("name", "ann", "sid", "parent", "t0", "_p0")
+class _SetupState(threading.local):
+    """A thread's set-up state: ``open``, its open set-up spans,
+    innermost last; ``jit``, the outermost jit events since the
+    innermost `first_dispatch` opened (or ever), newest last, each
+    ``[key, start, seconds, function, side]`` on the perf_counter
+    clock; ``retrieved``, set between a cache hit's retrieval event and
+    the backend-compile event that holds it."""
 
-    def __init__(self, name, parent, ann):
-        self.name, self.ann = name, ann
+    def __init__(self):
+        self.open, self.jit, self.retrieved = [], [], False
+
+
+_STATE = _SetupState()
+
+
+def _append_setup(rec):
+    _SETUP.append(rec)
+    if len(_SETUP) > _SETUP_MAX:
+        del _SETUP[0]
+    if _metrics._HOT[0]:
+        _ring_append(rec)
+
+
+class _SetupSpan:
+    __slots__ = ("name", "ann", "sid", "parent", "t0", "_p0", "_note",
+                 "_outer_jit", "_inferred")
+
+    def __init__(self, name, parent, p0, ann):
+        self.name, self.ann, self._p0 = name, ann, p0
         self.parent = parent.sid if parent is not None else None
         self.sid = new_span_id()
 
     def __enter__(self):
-        self.t0 = time.time()
-        self._p0 = time.perf_counter()
+        _listen()
+        st = _STATE
+        if st.open:
+            outer = st.open[-1]
+            if self.parent is None:
+                self.parent = outer.sid
+            if "program" in outer.ann:
+                self.ann.setdefault("program", outer.ann["program"])
+        st.open.append(self)
+        if self.name == FIRST_DISPATCH:
+            # the jit events from here to the end are this span's
+            self._outer_jit, st.jit = st.jit, []
+        self._inferred = _INFERRED_S[0]
+        now = time.perf_counter()
+        if self._p0 is None:
+            self._p0 = now
+        self.t0 = time.time() - (now - self._p0)
+        # on the profiler's clock too, beside the device's first work
+        # (from here on: a span backdated with p0 starts late there)
+        self._note = jax.profiler.TraceAnnotation("pt.setup." + self.name)
+        self._note.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        self._note.__exit__(exc_type, exc, tb)
+        end = time.perf_counter()
+        st = _STATE
+        if self in st.open:
+            del st.open[st.open.index(self):]
         if exc_type is not None:
             self.ann.setdefault("error", exc_type.__name__)
-        rec = _span_record(
-            self.name, self.t0, (time.perf_counter() - self._p0) * 1e3,
-            "setup", f"{worker_id()}-setup", self.sid, self.parent,
-            self.ann)
-        _SETUP.append(rec)
-        if len(_SETUP) > _SETUP_MAX:
-            del _SETUP[0]
-        if _metrics._HOT[0]:
-            _ring_append(rec)
+        inferred = _INFERRED_S[0] - self._inferred
+        if inferred:
+            # how much of a build pass was shape inference
+            self.ann["infer_shapes_s"] = round(inferred, 6)
+        if self.name == FIRST_DISPATCH:
+            events, st.jit = st.jit, self._outer_jit
+            self._record_jit_children(events, end)
+        _append_setup(_span_record(
+            self.name, self.t0, (end - self._p0) * 1e3, "setup",
+            f"{worker_id()}-setup", self.sid, self.parent, self.ann))
         return False
 
+    def _record_jit_children(self, events, end):
+        """What the listener heard while this `first_dispatch` was
+        open, as child records. It is told a duration and no start: the
+        start is the arrival less the duration, held inside the parent
+        (JAX times with another clock)."""
+        for key, p0, secs, fun, _ in events:
+            p0 = min(max(p0, self._p0), end)
+            _append_setup(_span_record(
+                f"{FIRST_DISPATCH}.{key}", self.t0 + (p0 - self._p0),
+                min(secs, end - p0) * 1e3, "setup",
+                f"{worker_id()}-setup", new_span_id(), self.sid,
+                {"program": self.ann.get("program"), "fun": fun}))
 
-def setup_span(name: str, parent: Optional[_SetupSpan] = None, **ann):
-    """``with setup_span("trace_step", program=fp) as sp: ...``; a child
-    passes ``parent=sp``. Annotations may be added to ``sp.ann`` until
-    the block ends."""
-    return _SetupSpan(name, parent, ann)
+
+def setup_span(name: str, parent: Optional[_SetupSpan] = None,
+               p0: Optional[float] = None, **ann):
+    """``with setup_span("trace_step", program=fp) as sp: ...``. The
+    parent is ``parent`` or else the set-up span open around it on this
+    thread, whose ``program`` annotation the span takes over unless it
+    has its own. ``p0`` backdates the start to that ``perf_counter``
+    reading (a span whose start is known only later: `import`,
+    `cold_run`). Annotations may be added to ``sp.ann`` until the block
+    ends."""
+    return _SetupSpan(name, parent, p0, ann)
 
 
-_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": 0,
-                 "/jax/compilation_cache/cache_misses": 0}
-_CACHE_LISTENING = [False]
+# ---------------------------------------------------------------------------
+# set-up counters: shape inference at build, what JAX compiled
+# ---------------------------------------------------------------------------
+
+def note_infer_shapes(op_type: str, seconds: float) -> None:
+    """One `_infer_op_shapes` call of ``Block.append_op``."""
+    with _TOTALS_LOCK:
+        rec = _BUILD.get(op_type)
+        if rec is None:
+            rec = _BUILD[op_type] = [0, 0.0]
+        rec[0] += 1
+        rec[1] += seconds
+        _INFERRED_S[0] += seconds
+
+
+def build_totals() -> Dict:
+    """Calls and seconds of build-time shape inference (`jax.eval_shape`
+    of an op's lowering, or its own `infer_shape`) in this process:
+    ``{"calls", "seconds", "by_op": {type: {"calls", "seconds"}}}``.
+    The forward layers are the user's calls and have no span; this is
+    what they, and the build passes, spend per appended op."""
+    with _TOTALS_LOCK:
+        seconds = _INFERRED_S[0]
+        by_op = {t: {"calls": c, "seconds": s}
+                 for t, (c, s) in _BUILD.items()}
+    return {"calls": sum(r["calls"] for r in by_op.values()),
+            "seconds": seconds, "by_op": by_op}
+
+
+def compile_totals() -> Dict[str, Dict]:
+    """What JAX traced, lowered and compiled in this process, as
+    ``jax.monitoring`` reported it from the first set-up span on:
+    seconds of jit trace, lowering, backend compile (``compile_s`` the
+    cache's misses and the requests it was not asked for,
+    ``cache_load_s`` its hits; ``cache_retrieval_s`` is the part of
+    the latter JAX spent reading and deserialising) and the persistent
+    cache's hits and misses, under ``"first_dispatch"`` (arrived while
+    one was open on the calling thread: the program's steps) and
+    ``"outside"`` (everything else the job compiles: eager helpers, the
+    caller's own jits). An event inside another's interval is not
+    counted: the seconds never exceed the wall's."""
+    _listen()
+    with _TOTALS_LOCK:
+        return {side: dict(t) for side, t in _COMPILES.items()}
+
+
+def reset_setup_totals() -> None:
+    with _TOTALS_LOCK:
+        _BUILD.clear()
+        _INFERRED_S[0] = 0.0
+        for side in _COMPILES:
+            _COMPILES[side] = _no_compiles()
 
 
 def compile_cache_events():
     """(hits, misses) of JAX's persistent compilation cache so far, as
-    ``jax.monitoring`` reports them; counted from the first call on."""
-    if not _CACHE_LISTENING[0]:
-        _CACHE_LISTENING[0] = True
-        import jax.monitoring
+    ``jax.monitoring`` reports them; counted from the first call or
+    set-up span on."""
+    totals = compile_totals()
+    return tuple(sum(t[k] for t in totals.values())
+                 for k in _CACHE_EVENTS.values())
 
-        def _count(event, **kw):
-            if event in _CACHE_EVENTS:
-                _CACHE_EVENTS[event] += 1
 
-        jax.monitoring.register_event_listener(_count)
-    return tuple(_CACHE_EVENTS.values())
+def _side(st):
+    return FIRST_DISPATCH if any(
+        s.name == FIRST_DISPATCH for s in st.open) else _OUTSIDE
+
+
+def _on_event(event, **kw):
+    key = _CACHE_EVENTS.get(event)
+    if key is not None:
+        side = _side(_STATE)
+        with _TOTALS_LOCK:
+            _COMPILES[side][key] += 1
+
+
+def _on_duration(event, seconds, **kw):
+    now = time.perf_counter()
+    st = _STATE
+    if event == _CACHE_RETRIEVAL:
+        # a hit; the backend-compile event around it arrives next
+        st.retrieved = True
+        with _TOTALS_LOCK:
+            _COMPILES[_side(st)]["cache_retrieval_s"] += seconds
+        return
+    key = _JIT_EVENTS.get(event)
+    if key is None:
+        return
+    if key == "compile" and st.retrieved:
+        key, st.retrieved = "cache_load", False
+    side, p0, jit = _side(st), now - seconds, st.jit
+    with _TOTALS_LOCK:
+        # a jitted function called inside another's trace, an eager
+        # helper compiled inside it: the outermost event stands for all
+        while jit and jit[-1][1] >= p0 - _NESTING_SLACK_S:
+            inner = jit.pop()
+            _COMPILES[inner[4]][inner[0] + "_s"] -= inner[2]
+        _COMPILES[side][key + "_s"] += seconds
+    jit.append([key, p0, seconds, kw.get("fun_name"), side])
+    if len(jit) > _JIT_PENDING_MAX:
+        del jit[:_JIT_PENDING_MAX // 2]
+
+
+def _listen():
+    """Register THE `jax.monitoring` listeners of the program (events:
+    the cache's hits and misses; durations: jit trace, lowering, backend
+    compile, cache retrieval), once."""
+    if _LISTENING[0]:
+        return
+    _LISTENING[0] = True
+    import jax.monitoring
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 # ---------------------------------------------------------------------------

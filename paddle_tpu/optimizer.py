@@ -24,6 +24,7 @@ from .initializer import Constant
 from .layer_helper import LayerHelper
 from .param_attr import ParamAttr
 from . import layers
+from .observability import tracing as _obs_tracing
 
 __all__ = [
     "SGD", "Momentum", "Adagrad", "Adam", "Adamax", "DecayedAdagrad",
@@ -275,19 +276,22 @@ class Optimizer:
         # grad clipping + regularization (reference optimizer.py:499-535)
         from .clip import append_gradient_clip_ops
         from .regularizer import append_regularization_ops
-        block = default_main_program().global_block()
+        program = default_main_program()
+        block = program.global_block()
         start = len(block.ops)
-        params_grads = append_gradient_clip_ops(params_grads)
-        params_grads = append_regularization_ops(params_grads,
-                                                 self.regularization)
-        ops = self._create_optimization_pass(params_grads)
-        # tag the whole optimize phase (clip + regularization + LR
-        # schedule + update rules) so the engine can split
-        # compute-vs-update for gradient accumulation
-        # (reference multi_batch_merge_pass works off the same role)
-        from .backward import OP_ROLE_ATTR
-        for op in block.ops[start:]:
-            op._attrs[OP_ROLE_ATTR] = "optimize"
+        with _obs_tracing.setup_span("program_build.optimize",
+                                     program=program.fingerprint[0]):
+            params_grads = append_gradient_clip_ops(params_grads)
+            params_grads = append_regularization_ops(
+                params_grads, self.regularization)
+            ops = self._create_optimization_pass(params_grads)
+            # tag the whole optimize phase (clip + regularization + LR
+            # schedule + update rules) so the engine can split
+            # compute-vs-update for gradient accumulation
+            # (reference multi_batch_merge_pass works off the same role)
+            from .backward import OP_ROLE_ATTR
+            for op in block.ops[start:]:
+                op._attrs[OP_ROLE_ATTR] = "optimize"
         return ops
 
     def apply_optimize(self, loss, startup_program, params_grads):

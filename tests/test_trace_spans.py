@@ -151,8 +151,13 @@ def test_cold_step_spans_trace_and_first_dispatch(quiet):
         jax.profiler.stop_trace()
     spans, = _pt_spans(d).values()
     names = [s[0] for s in spans]
+    # the set-up spans are in the session too, as `pt.setup.<name>`:
+    # `cold_run` from where the call is known to be cold to its end
     assert names == ["pt.step", "pt.executor.feed", "pt.engine.feed",
-                     "pt.engine.trace", "pt.engine.args", "pt.engine.rng",
+                     "pt.setup.cold_run", "pt.engine.trace",
+                     "pt.setup.trace_step", "pt.setup.trace_step.op_walk",
+                     "pt.engine.args", "pt.engine.rng",
+                     "pt.setup.first_dispatch",
                      "pt.engine.first_dispatch", "pt.engine.release",
                      "pt.engine.writeback", "pt.engine.fetch"]
 
@@ -217,7 +222,11 @@ def test_everything_off_builds_no_record(quiet, monkeypatch):
     assert not built
     assert tracing.span_buffer().total_appended == ring0
     assert recorder.flight_recorder().total_appended == flight0
-    names = [s["name"] for s in tracing.setup_spans()]
+    # (the list's other names, `cold_run`, `program_build.*` and
+    # `first_dispatch`'s children: tests/test_setup_spans.py)
+    names = [s["name"] for s in tracing.setup_spans()
+             if s["name"] in ("trace_step", "trace_step.op_walk",
+                              "first_dispatch")]
     assert sorted(names) == sorted(
         2 * ["trace_step", "trace_step.op_walk", "first_dispatch"])
     for s in tracing.setup_spans():
