@@ -2,12 +2,14 @@
 
 A mixture-of-experts layer sends each token to `top_k` of `num_experts`
 experts; this chip holds `experts_held` of them. The rows routed to held
-experts are gathered into ONE row buffer, expert by expert, and three
-kernels do every matrix product of the expert feed-forward over it:
+experts are gathered into ONE row buffer, expert by expert, three
+kernels do every matrix product of the expert feed-forward over it and
+a fourth sums the result back to the tokens:
 
   moe_grouped_matmul_fwd   out[r] = lhs[r] @ rhs[expert(r)]
   moe_grouped_matmul_dx    dlhs[r] = dout[r] @ rhs[expert(r)]^T
   moe_grouped_matmul_dw    drhs[e] = sum over rows r of e: lhs[r]^T dout[r]
+  moe_combine              out[token(r)] += buf[r]   (short prefixes)
 
 The row buffer (`plan_rows`). Shapes are static, token counts are not,
 and no token is ever dropped: the buffer has room for the worst case,
@@ -33,6 +35,19 @@ sees about that share of the choices, so the ladder is the buffer that
 share, twice and four times that share would need, then the worst case.
 The worst case stays: the layer is dropless whatever the router does,
 only slower.
+
+The combine (`combine`, `combine_by_rows`; kernel `moe_combine`). The
+layer's result is each token's held rows summed out of the buffer,
+`[rows, D] -> [T, D]` float32. Over a short prefix a fourth kernel does
+it from the ROWS: grid (D blocks, row tiles), the `[T, D block]` output
+resident in VMEM and zeroed at the first tile, each tile streamed once
+(the same clamp past `n_active`), each valid row added into its token's
+row, `out[tok] += buf[r]`, the token ids scalar-prefetched. It reads only
+tiles in use and moves `rows in use x D + T x D` where the gather over
+every choice of every token (ops/decoder.py `_combine`) moves `T x top_k
+x D` three times; its time grows with the rows, so the rule is static in
+the prefix's rows (`COMBINE_MAX_SHARE`) and the worst case keeps the
+gather. It belongs to the layer's one kernel decision, not a second one.
 
 Routing. `select()`-governed like fused_adam (kernels/registry.py): off
 the CPU and when not denied the three kernels run; otherwise the
@@ -60,10 +75,32 @@ _DW_BLOCK_BYTES = 2 * 1024 * 1024
 # double-buffered, do not fit asks for what they take and this much beside
 _VMEM_DEFAULT_LIMIT = 16 << 20
 _VMEM_MARGIN = 4 << 20
+# the combine kernel's resident [T, bd] f32 output block is held to this
+_COMBINE_BLOCK_BYTES = 16 << 20
+# The combine goes over a prefix's rows (`combine`) where they are at most
+# this share of all the choices, T * top_k, and over every choice (the
+# gather) past it. tools/bench_moe_combine.py on one v5e (PR 38; ms a
+# call, bf16 rows + float32 rows, kernel against gather), by prefix:
+# kanana2_s4096 0.42 / 0.43 / 0.57 against 2.35 / 2.36 / 2.52 at 0.21 /
+# 0.33 / 0.58 of the choices; keye2_s8192 0.75 / 1.03 / 1.70 against 2.67 /
+# 4.86 / 6.91 at 0.16 / 0.28 / 0.53; twotower_s4096 0.44 / 0.42 / 0.50
+# against 3.20 / 3.21 / 3.30 at 0.10 / 0.17 / 0.29. The kernel's time grows
+# with the rows in use (0.015-0.03 us each), the gather's hardly at all;
+# the kernel still read less with 0.67-0.83 of the choices in use (the
+# worst-case prefix: 0.92 against 3.14, 3.04 against 6.37, 1.13 against
+# 4.94), which is as far as it was measured: 0.75. The worst case, whose
+# rows pass T * top_k, and a layer that holds every expert keep the
+# gather, which is the needed work when every choice is held.
+COMBINE_MAX_SHARE = 0.75
+# the kernel's token table is scalar-prefetched: 4 bytes a row of the
+# chip's 1 MiB of SMEM (a described v5e refuses 262,144 rows)
+_COMBINE_MAX_ROWS = 196608
+# rows of a tile the combine kernel adds between two loop tests
+_COMBINE_UNROLL = 8
 
 __all__ = ["TILE_ROWS", "plan_rows", "buffer_rows", "prefix_rows",
            "prefix_plan", "prefix_index", "gmm", "gmm_dx", "gmm_dw",
-           "use_kernels"]
+           "combine", "combine_by_rows", "COMBINE_MAX_SHARE", "use_kernels"]
 
 
 # ---------------------------------------------------------------------------
@@ -238,14 +275,14 @@ def _dw_kernel(te_ref, na_ref, lhs_ref, dout_ref, out_ref):
             preferred_element_type=jnp.float32)
 
 
-def _dw_block(k, n):
+def _dw_block(k, n, limit=_DW_BLOCK_BYTES):
     """Largest 128-multiple divisor of k whose [bk, n] f32 block stays
-    within _DW_BLOCK_BYTES (k itself when k is no multiple of 128)."""
+    within `limit` bytes (k itself when k is no multiple of 128)."""
     if k % 128:
         return k
     best = 128
     for bk in range(128, k + 1, 128):
-        if k % bk == 0 and bk * n * 4 <= _DW_BLOCK_BYTES:
+        if k % bk == 0 and bk * n * 4 <= limit:
             best = bk
     return best
 
@@ -284,6 +321,74 @@ def _dw_call(lhs, dout, plan, experts_held, tile):
             ((tile, bn), dout.dtype), ((bk, bn), jnp.float32)),
         interpret=registry.interpret(),
     )(plan["tile_expert"], plan["n_active"], lhs, dout)
+
+
+def _combine_kernel(na_ref, count_ref, tok_ref, buf_ref, out_ref, *wide):
+    tile = buf_ref.shape[0]
+    t = pl.program_id(1)
+
+    @pl.when(t == 0)
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(t < na_ref[0])
+    def _run():
+        if wide:            # packed rows: widened once a tile, read by row
+            rows_ref, = wide
+            rows_ref[...] = buf_ref[...].astype(jnp.float32)
+        else:
+            rows_ref = buf_ref
+
+        def add(r):
+            tok = tok_ref[t * tile + r]
+            out_ref[pl.ds(tok, 1), :] += rows_ref[pl.ds(r, 1), :]
+
+        def several(c, carry):
+            for j in range(_COMBINE_UNROLL):
+                add(c * _COMBINE_UNROLL + j)
+            return carry
+
+        def one(r, carry):
+            add(r)
+            return carry
+        # a group's padding rows are its last, so a tile's valid rows are
+        # its first `count`; rows of one tile may share a token (a
+        # caller's choices need not be distinct), so they add in turn
+        count = count_ref[t]
+        whole = count // _COMBINE_UNROLL
+        lax.fori_loop(0, whole, several, 0)
+        lax.fori_loop(whole * _COMBINE_UNROLL, count, one, 0)
+
+
+def combine(buf, plan, t, top_k, tile=TILE_ROWS):
+    """[rows, d] -> float32 [t, d]: each token's rows summed, row r going
+    to token `choice_of_row[r] // top_k` (kernel `moe_combine`). Only
+    valid rows of tiles in use are read; the sum is float32 from `buf`'s
+    own type, a token's rows added in row (expert) order. The output is
+    blocked along d like the dw kernel's along k, a block of at most
+    _COMBINE_BLOCK_BYTES."""
+    rows, d = buf.shape
+    bd = _dw_block(d, t, _COMBINE_BLOCK_BYTES)
+    tok = plan["choice_of_row"] // top_k
+    count = jnp.sum(plan["valid"].reshape(rows // tile, tile), axis=1,
+                    dtype=jnp.int32)
+    wide = [((tile, bd), jnp.float32)] * (buf.dtype != jnp.float32)
+    # grid (D blocks, row tiles): the [t, bd] float32 output block stays
+    # in VMEM while the row tiles stream past it
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(d // bd, rows // tile),
+        in_specs=[pl.BlockSpec(
+            (tile, bd), lambda j, r, na, n, tok: (_in_use(r, na), j))],
+        out_specs=pl.BlockSpec((t, bd), lambda j, r, na, n, tok: (0, j)),
+        scratch_shapes=[pltpu.VMEM(*block) for block in wide])
+    return pl.pallas_call(
+        _combine_kernel, name="moe_combine", grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
+        compiler_params=_params(
+            ("parallel", "arbitrary"), ((tile, bd), buf.dtype),
+            ((t, bd), jnp.float32), *wide),
+        interpret=registry.interpret(),
+    )(plan["n_active"], count, tok, buf)
 
 
 # ---------------------------------------------------------------------------
@@ -363,6 +468,16 @@ def gmm_dw(lhs, dout, plan, experts_held, kernels, tile=TILE_ROWS):
     if kernels:
         return _dw_call(lhs, dout, plan, experts_held, tile)
     return _lowered_dw(lhs, dout, plan, experts_held, tile)
+
+
+def combine_by_rows(rows: int, n_choices: int) -> bool:
+    """Whether the combine out of a prefix of `rows` rows goes over its
+    rows (`combine`) or over all `n_choices` = T * top_k choices (the
+    gather, ops/decoder.py `_combine`). Static: the prefix's own length
+    against `COMBINE_MAX_SHARE` of the choices, so the worst-case prefix
+    and a layer that holds every expert (rows >= n_choices) never do,
+    nor does a prefix whose token table SMEM cannot hold."""
+    return rows <= min(COMBINE_MAX_SHARE * n_choices, _COMBINE_MAX_ROWS)
 
 
 def _eligible(sig: registry.Signature) -> bool:

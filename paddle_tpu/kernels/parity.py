@@ -491,6 +491,35 @@ def _gmm_case(which):
                 "moe_grouped_matmul/%s/f32/4x128x256" % which, run)
 
 
+def _combine_case(label, dtype):
+    """The expert layer's combine out of a prefix of the row buffer
+    (kernel `moe_combine`, part of the `moe_grouped_matmul` decision):
+    64 tokens x top-4 over 16 experts of which 0..3 are held, so a token
+    holds up to four rows; against the gather over every choice
+    (ops/decoder.py `_combine`), float32 sums of the same terms in
+    another order. Rows past the tiles in use are NaN."""
+    def run():
+        from . import grouped_matmul as gm
+        from ..ops.decoder import _combine
+        r = _rng(29)
+        t, top_k, held, d = 64, 4, 4, 256
+        choice = np.stack([r.choice(16, top_k, replace=False)
+                           for _ in range(t)]).astype(np.int32)
+        choice[:8] = np.arange(4)[::-1]     # eight tokens hold all four,
+        # chosen in the order the rows do not lie in
+        plan = gm.plan_rows(jnp.asarray(choice.reshape(-1)), held)
+        rows = gm.prefix_rows(t * top_k, held, 16)[0]
+        used = int(plan["n_active"][0]) * gm.TILE_ROWS
+        assert used <= rows
+        buf = jnp.asarray(r.standard_normal((rows, d), dtype=np.float32),
+                          dtype).at[used:].set(jnp.nan)
+        got = gm.combine(buf, gm.prefix_plan(plan, rows), t, top_k)
+        ref = _combine(buf, plan, t, top_k)
+        return {"metric": "rel_vs_gather", "tol": 1e-6,
+                "value": rel_err(ref, got)}
+    return Case("moe_grouped_matmul", "moe_combine/" + label, run)
+
+
 def _sparse_index_case(which):
     """A learned sparse attention's index at S=256 in 128-wide tiles:
     the score kernel against the `jax.numpy` lowering (f32, "highest"),
@@ -588,6 +617,8 @@ def cases() -> List[Case]:
         _gmm_case("fwd"),
         _gmm_case("dx"),
         _gmm_case("dw"),
+        _combine_case("bf16", jnp.bfloat16),
+        _combine_case("f32", jnp.float32),
         _sparse_index_case("scores"),
         _sparse_index_case("select"),
         _ssd_case("fwd"),

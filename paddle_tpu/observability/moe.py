@@ -27,6 +27,16 @@ prefix; read it and set it to zero before that.
 `worst` is the buffer's worst-case length
 (`kernels.grouped_matmul.buffer_rows`). A program without a counter
 gives None.
+
+The prefix a layer took in a step also says which combine it ran (the
+rule is static: `kernels.grouped_matmul.combine_by_rows`), so readings a
+step apart give how often the row-driven combine engages:
+
+    steps = np.diff(np.stack(readings), axis=0)        # [steps, layers, 2]
+    stats = observability.moe.combine_stats(steps, ladder, n_choices)
+
+`ladder` is `kernels.grouped_matmul.prefix_rows(...)`, `n_choices`
+tokens x top_k.
 """
 from __future__ import annotations
 
@@ -36,7 +46,8 @@ EXPERT_LOAD_VAR = "moe_expert_load"
 ROWS_WORKED_VAR = "moe_rows_worked"
 
 __all__ = ["EXPERT_LOAD_VAR", "ROWS_WORKED_VAR", "expert_load",
-           "load_stats", "rows_worked", "rows_worked_stats"]
+           "load_stats", "rows_worked", "rows_worked_stats",
+           "combine_stats"]
 
 
 def _counter(scope, name):
@@ -86,3 +97,33 @@ def rows_worked_stats(worked, steps, worst_rows):
     return {"worked_share_of_worst":
             (worked[:, 0] / (steps * worst_rows)).tolist(),
             "worked_over_in_use": (worked[:, 0] / worked[:, 1]).tolist()}
+
+
+def combine_stats(worked, ladder, n_choices):
+    """Which combine the expert layers ran, from the counter's increase
+    over single steps: `worked` int [steps, layers, 2] (or [layers, 2],
+    one step), each increase's first column the prefix that layer took
+    in that step, a rung of `ladder`. Where the layer's kernels run, a
+    rung under `COMBINE_MAX_SHARE` of the `n_choices` choices combines
+    over its rows (kernel `moe_combine`) and a longer one over every
+    choice. {"by_rows_share": the share of layer-steps that combined
+    over rows, "by_rows_share_per_layer": the same a layer,
+    "rows_over_choices": rows the combines went over (the rung's, or
+    `n_choices` where every choice was gathered) over layer-steps x
+    n_choices}; None for no step. An increase that is no rung (readings
+    more than a step apart) is a ValueError."""
+    from ..kernels.grouped_matmul import combine_by_rows
+    took = np.asarray(worked, np.int64)[..., 0]
+    took = took.reshape(-1, took.shape[-1])
+    if took.size == 0 or n_choices <= 0:
+        return None
+    if not np.isin(took, ladder).all():
+        raise ValueError(
+            f"rows worked {sorted(set(took.ravel()) - set(ladder))} are no "
+            f"rung of {list(ladder)}: readings one step apart?")
+    by_rows = np.isin(took, [rows for rows in ladder
+                             if combine_by_rows(rows, n_choices)])
+    went_over = np.where(by_rows, took, n_choices)
+    return {"by_rows_share": float(by_rows.mean()),
+            "by_rows_share_per_layer": by_rows.mean(axis=0).tolist(),
+            "rows_over_choices": float(went_over.mean() / n_choices)}

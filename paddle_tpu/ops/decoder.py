@@ -8,9 +8,10 @@ convolution, the state-space scan and the gated group-wise RMS norm.
 
 The expert layer is dropless and knows which experts it holds:
 `moe_experts` gathers the rows routed to experts `first_expert ..
-first_expert + experts_held - 1` into a worst-case row buffer and runs
+first_expert + experts_held - 1` into a worst-case row buffer, runs
 the three grouped matmuls of kernels/grouped_matmul.py over the part of
-it that is in use; what the experts held elsewhere would add is left out
+it that is in use and sums each token's rows back out of that part; what
+the experts held elsewhere would add is left out
 (on one chip there is no exchange, and nothing stands in for the absent
 chips).
 """
@@ -197,8 +198,11 @@ def _gather_rows(table, plan, top_k):
 
 
 def _combine(buf, plan, t, top_k):
-    """Sum each token's held choices out of the buffer: [rows, D] ->
-    [T, D]. Rows the kernels never wrote are masked, not multiplied."""
+    """Sum each token's held choices out of the buffer, choice by
+    choice: [rows, D] -> float32 [T, D]. Every one of the T * top_k
+    choices gathers a row, so this is the needed work where the rows are
+    as many (the worst-case prefix, a layer that holds every expert).
+    Rows the kernels never wrote are masked, not multiplied."""
     picked = buf[plan["row_of_choice"]]                  # [T*k, D]
     picked = jnp.where(plan["held"][:, None], picked, 0)
     return jnp.sum(picked.reshape(t, top_k, -1).astype(_F32), axis=1)
@@ -207,13 +211,15 @@ def _combine(buf, plan, t, top_k):
 class _Prefix:
     """The first `rows` rows of the row buffer, which hold every tile in
     use: the plan cut to them, the rows gathered into them, each row's
-    routing weight, and the grouped matmuls over them."""
+    routing weight, the grouped matmuls over them and the combine out
+    of them."""
 
     def __init__(self, x, weight, plan, rows, held, kernels):
         from ..kernels import grouped_matmul as gm
         self.gm, self.held, self.kernels = gm, held, kernels
-        self.top_k = weight.shape[-1]
+        self.tokens, self.top_k = weight.shape
         self.plan = gm.prefix_plan(plan, rows)
+        self.by_rows = kernels and gm.combine_by_rows(rows, weight.size)
         self.valid = self.plan["valid"][:, None]
         self.xs = _gather_rows(x, self.plan, self.top_k)
         self.w_row = jnp.where(
@@ -228,6 +234,25 @@ class _Prefix:
 
     def gmm_dw(self, lhs, dout):
         return self.gm.gmm_dw(lhs, dout, self.plan, self.held, self.kernels)
+
+    def combine(self, buf):
+        """[rows, D] -> float32 [T, D], each token's held rows summed in
+        float32 from the buffer's own type. Where the layer's kernels
+        run and the prefix is short (`grouped_matmul.combine_by_rows`)
+        the sum goes over the prefix's ROWS (kernel `moe_combine`: a
+        token's held rows added in row, that is expert, order), else
+        over every choice of every token (`_combine`, in choice order):
+        the two differ by the float32 reassociation of at most top_k
+        terms and are bit-equal where a token holds one choice or two.
+        Which one a traced body took is counted under `moe_combine`
+        (`prefix_rows` / `all_choices`) where the kernels run."""
+        from ..kernels import registry
+        if self.kernels:
+            registry.count("moe_combine",
+                           "prefix_rows" if self.by_rows else "all_choices")
+        if self.by_rows:
+            return self.gm.combine(buf, self.plan, self.tokens, self.top_k)
+        return _combine(buf, self.plan, self.tokens, self.top_k)
 
 
 def _experts_forward(x, weight, wg, wu, wd, plan, *, rows, held, kernels,
@@ -247,7 +272,7 @@ def _experts_forward(x, weight, wg, wu, wd, plan, *, rows, held, kernels,
     # the routing weight goes in before the down projection (it is
     # linear), so the backward needs no expert output kept or recomputed
     hw = jnp.where(p.valid, hidden * p.w_row[:, None], 0)
-    out = _combine(p.gmm(hw.astype(x.dtype), wd), plan, x.shape[0], p.top_k)
+    out = p.combine(p.gmm(hw.astype(x.dtype), wd))
     beyond = plan["valid"].shape[0] - rows
     if beyond:
         gate, up = (None if a is None else jnp.pad(a, ((0, beyond), (0, 0)))
@@ -296,7 +321,7 @@ def _experts_backward(x, weight, wg, wu, wd, plan, gate, up, dout, *, rows,
             + p.gmm_dx(dup, wu).astype(_F32)
         d_wg = p.gmm_dw(p.xs, dgate)
     d_wu = p.gmm_dw(p.xs, dup)
-    dx = _combine(dxs, plan, x.shape[0], p.top_k).reshape(shape)
+    dx = p.combine(dxs).reshape(shape)
     dweight = jnp.where(plan["held"], dw_row[plan["row_of_choice"]],
                         0.0).reshape(weight.shape)
     return dx, dweight, d_wg, d_wu, d_wd
@@ -394,7 +419,9 @@ def moe_experts(ctx):
     Where only a share of the experts is held, the whole layer runs over
     a static prefix of the worst-case buffer that holds every tile in
     use (`_Experts`); the worst case stays one of the prefixes, so no
-    routing drops a token. GateAct and UpAct keep the worst-case length:
+    routing drops a token; a short prefix also sums its rows back to the
+    tokens row by row (`_Prefix.combine`). GateAct and UpAct keep the
+    worst-case length:
     a prefix's rows are written, the rows past it are zero, and nothing
     reads them (the grad op takes the same prefix). RowsWorked (optional,
     int32 [2]): rows of the prefix taken and rows in use, for the

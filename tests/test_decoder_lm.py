@@ -355,7 +355,10 @@ def test_moe_experts_over_a_prefix_equals_the_worst_case_body(
     case included, Out and every gradient (five for the gated expert,
     four for the ungated) are BIT-EQUAL to the one body over the whole
     worst-case buffer; n_active at a prefix's edge takes that prefix and
-    one tile more takes the next."""
+    one tile more takes the next. One exception: through the kernels a
+    short prefix sums a token's held rows in row order (the combine
+    kernel, tests/test_moe_combine.py), so Out and dX, the two sums of
+    up to top_k rows, are equal to float32 reassociation there."""
     if path == "kernels":
         request.getfixturevalue("interp")
     sizes, prefix, tiles = _PREFIX_ROUTINGS[routing]
@@ -370,13 +373,19 @@ def test_moe_experts_over_a_prefix_equals_the_worst_case_body(
                                    activation=activation)
     assert want.pop().tolist() == [1536, tiles * gm.TILE_ROWS]
     assert np.abs(whole).max() > 0
-    np.testing.assert_array_equal(out, whole)
     names = ("x", "weight", "wg", "wu", "wd")
     if activation == "relu2":
         names = names[:2] + names[3:]
     assert len(got) == len(names)
-    for name, g, w in zip(names, got, want):
-        np.testing.assert_array_equal(g, w, err_msg=name)
+    by_rows = path == "kernels" and gm.combine_by_rows(prefix, 1024)
+    assert by_rows == (path == "kernels" and prefix == 768)
+    for name, g, w in zip(("out",) + names, [out] + got, [whole] + want):
+        if by_rows and name in ("out", "x"):
+            np.testing.assert_allclose(
+                g, w, rtol=0, atol=6 * 2.0 ** -24 * 4 * np.abs(w).max(),
+                err_msg=name)
+        else:
+            np.testing.assert_array_equal(g, w, err_msg=name)
 
 
 @pytest.mark.parametrize("shape,want", [

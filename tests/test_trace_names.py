@@ -376,19 +376,66 @@ def test_grouped_matmul_kernels_take_an_expert_width_of_1856(
         h.startswith("moe_grouped_matmul_" + which) for h in heads), heads
 
 
+@pytest.mark.parametrize("cell", ["kanana2_s4096", "twotower_s4096"])
+def test_expert_layer_compiles_with_the_combine_kernel(
+        one_chip, no_compile_cache, cell, monkeypatch):
+    """An expert layer's forward and backward over the first prefix of
+    its row buffer at two cells' sizes (D 2048, gated, and D 2688 = 21 x
+    128, ungated): the combine out of the prefix is the kernel
+    `moe_combine` (its `[T, D block]` float32 output resident in VMEM),
+    once forward on bf16 rows and once backward on float32 rows, beside
+    `moe_grouped_matmul_fwd` / `_dx` / `_dw`; no `[T * top_k, D]` array
+    is left in the layer."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+    from paddle_tpu.kernels import registry
+    from paddle_tpu.ops import decoder
+    monkeypatch.setattr(registry, "interpret", lambda: False)
+    t, k, experts = 4096, 6, 128
+    held, d, f, gated = {"kanana2_s4096": (16, 2048, 768, True),
+                         "twotower_s4096": (8, 2688, 1856, False)}[cell]
+    rows = gm.prefix_rows(t * k, held, experts)[0]
+    assert gm.combine_by_rows(rows, t * k)
+    bf16 = jnp.bfloat16
+
+    def layer(choice, x, weight, dout, wu, wd, wg=None):
+        plan = gm.plan_rows(choice, held)
+        static = dict(rows=rows, held=held, kernels=True)
+        out, gate, up = decoder._experts_forward(
+            x, weight, wg, wu, wd, plan, out_dtype=bf16, **static)
+        return out, decoder._experts_backward(
+            x, weight, wg, wu, wd, plan, gate, up, dout, shape=x.shape,
+            **static)
+    shapes = [((t * k,), jnp.int32), ((t, d), bf16), ((t, k), jnp.float32),
+              ((t, d), bf16), ((held, d, f), bf16), ((held, f, d), bf16)]
+    if gated:
+        shapes.append(((held, d, f), bf16))
+    text = _compiled_text(layer, one_chip, *shapes)
+    stems = sorted(h.split(".")[0] for h in _custom_call_heads(text))
+    n = 3 if gated else 2
+    assert stems == sorted(
+        ["moe_combine"] * 2 + ["moe_grouped_matmul_fwd"] * n
+        + ["moe_grouped_matmul_dx"] * n + ["moe_grouped_matmul_dw"] * n), \
+        stems
+    assert f"[{t * k},{d}]" not in text and f"[{t},{k},{d}]" not in text
+
+
 def test_no_other_kernel_reads_as_flash_or_adam():
     """The accepted classifier maps a head holding `adam` to fused_adam
     and one holding `flash` or `kern` to flash attention: no other
-    kernel's name may hold any of them."""
+    kernel's name may hold any of them. The expert layer's combine
+    kernel holds none of the families' hints at all, so the grouped
+    matmuls' metrics read the three matmul kernels alone."""
     from paddle_tpu.tuning import variants
     names = ["fused_sgd", "quantized_matmul", "moe_grouped_matmul_fwd",
              "moe_grouped_matmul_dx", "moe_grouped_matmul_dw",
              "sparse_index_scores", "sparse_index_select",
-             "mamba2_ssd_fwd", "mamba2_ssd_bwd"] + [
+             "mamba2_ssd_fwd", "mamba2_ssd_bwd", "moe_combine"] + [
         f"tuned_matmul_{v.epilogue}_{v.bm}x{v.bn}x{v.bk}"
         for v in variants.enumerate_variants()]
     for n in names:
         assert not any(h in n for h in ("adam", "flash", "kern")), n
+    assert not any(h in "moe_combine" for h in (
+        "moe_grouped_matmul", "mamba2_ssd", "sparse_index"))
     src = open(variants.__file__).read()
     assert 'name=f"tuned_matmul_{variant.epilogue}_{bm}x{bn}x{bk}"' in src
 
