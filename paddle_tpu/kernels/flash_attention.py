@@ -194,8 +194,13 @@ class _Plan:
     Grouped queries: k and v may have Hkv < H heads, query head g
     reading key head g // (H // Hkv). The grid stays the query heads';
     only the k / v index maps change (`row_spec(shared=True)`), so k and
-    v are never expanded. One head a lane block then (hpb == 1,
-    `_kernel_ok`)."""
+    v are never expanded. With one head a lane block (D >= 128) a query
+    block's k / v block is simply its key head's. With PACKED heads
+    (D < 128, hpb > 1) the group is a multiple of hpb (`_kernel_ok`), so
+    the hpb query heads of a lane block all read ONE key head, which is
+    one D-wide slice of a key lane block of hpb key heads: the index map
+    picks the key block (query block // group) and the kernels pick the
+    slice by the grid step (`shared_kv`)."""
 
     def __init__(self, layout, B, H, Sq, Sk, D, bq, bk, Dv=None,
                  Hkv=None):
@@ -212,6 +217,9 @@ class _Plan:
         else:
             self.hpb = 1
             self.Hg = None
+        # grouped queries with packed heads: a lane block's query heads
+        # share one key head, a slice of its key lane block
+        self.packed_shared = self.group > 1 and self.hpb > 1
 
     def rows(self, x):
         """HBM view handed to pallas_call."""
@@ -359,6 +367,27 @@ class _Plan:
             return ref[...]
         return ref[:, i * width:(i + 1) * width]
 
+    def kv_slot(self):
+        """Which slice of its key lane block this grid step's query
+        block reads (grouped queries with packed heads; None otherwise):
+        query block g1 holds heads g1 * hpb .., whose key head is g1 //
+        (group / hpb), the (that % hpb)-th of its lane block. Taken at
+        the kernel's top (a `pl.program_id` cannot sit inside a
+        `pl.when` body under the interpreter)."""
+        if not self.packed_shared:
+            return None
+        return (pl.program_id(1) // (self.group // self.hpb)) % self.hpb
+
+    def shared_kv(self, slot, ref, width):
+        """The one key (or value) tile every query head of this block
+        reads: the `slot`-th [rows, width] slice of the packed ref,
+        picked among the static lane slices."""
+        tile = ref[:, :width]
+        for h in range(1, self.hpb):
+            tile = jnp.where(slot == h, ref[:, h * width:(h + 1) * width],
+                             tile)
+        return tile
+
     def store_lanes(self, ref, i, width, val):
         if self.hpb == 1 and self.layout != "bshd":
             ref[...] = val
@@ -409,6 +438,7 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
     D, Dv, bq, bk = plan.D, plan.Dv, plan.bq, plan.bk
     bhs = [plan.bh(i) for i in range(plan.hpb)] \
         if drop_t is not None else None
+    slot = plan.kv_slot()
 
     @pl.when(kv_idx == 0)
     def _init():
@@ -417,9 +447,12 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def _body():
+        if slot is not None:
+            k_all = plan.shared_kv(slot, k_ref, D)
+            v_all = plan.shared_kv(slot, v_ref, Dv)
         for i in range(plan.hpb):
             q = plan.lanes(q_ref, i, D)                # [bq, D]
-            k = plan.lanes(k_ref, i, D)                # [bk, D]
+            k = plan.lanes(k_ref, i, D) if slot is None else k_all
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [bq, bk]
@@ -443,7 +476,8 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
                                   drop_t)
                 p_v = jnp.where(keep, p * (256.0 / drop_t), 0.0)
             acc_scr[i] = acc_scr[i] * corr + jax.lax.dot_general(
-                p_v.astype(v_ref.dtype), plan.lanes(v_ref, i, Dv),
+                p_v.astype(v_ref.dtype),
+                plan.lanes(v_ref, i, Dv) if slot is None else v_all,
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_scr[i] = jnp.broadcast_to(m_next, m_scr[i].shape)
@@ -474,14 +508,15 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
 
 def _bwd_tile(plan, i, q_idx, kv_idx, bh, seed_ref, q_ref, k_ref, v_ref,
               lse_ref, out_ref, do_ref, glse_ref, bias_ref, *, scale,
-              causal, drop_t):
+              causal, drop_t, slot=None):
     """Local head i's (q block, kv block) tile of the backward, built
     once for whichever products the calling kernel feeds from it:
     (q, k, p_v, ds) with p_v the DROPPED weights dv consumes
-    (out = p_drop @ v) and ds = p * (dp - di)."""
+    (out = p_drop @ v) and ds = p * (dp - di). `slot`: `_Plan.kv_slot`."""
     D, Dv, bq, bk = plan.D, plan.Dv, plan.bq, plan.bk
     q = plan.lanes(q_ref, i, D)                     # [bq, D]
-    k = plan.lanes(k_ref, i, D)                     # [bk, D]
+    k = plan.lanes(k_ref, i, D) if slot is None \
+        else plan.shared_kv(slot, k_ref, D)         # [bk, D]
     do = plan.lanes(do_ref, i, Dv)                  # [bq, Dv]
     lse = plan.lanes(lse_ref, i, 128)[:, :1]        # [bq, 1]
     di = jnp.sum(plan.lanes(out_ref, i, Dv).astype(jnp.float32)
@@ -499,7 +534,8 @@ def _bwd_tile(plan, i, q_idx, kv_idx, bh, seed_ref, q_ref, k_ref, v_ref,
     # a float32 copy of a bf16 dO is the same numbers in twice the
     # bytes
     dp = jax.lax.dot_general(
-        do, plan.lanes(v_ref, i, Dv), (((1,), (1,)), ((), ())),
+        do, plan.lanes(v_ref, i, Dv) if slot is None
+        else plan.shared_kv(slot, v_ref, Dv), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     p_v = p
     if drop_t is not None:
@@ -521,6 +557,7 @@ def _fa_bwd_dq_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
     D, bq, bk = plan.D, plan.bq, plan.bk
     bhs = [plan.bh(i) if drop_t is not None else None
            for i in range(plan.hpb)]
+    slot = plan.kv_slot()
 
     @pl.when(kv_idx == 0)
     def _init():
@@ -531,7 +568,7 @@ def _fa_bwd_dq_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
             _, k, _, ds = _bwd_tile(
                 plan, i, q_idx, kv_idx, bhs[i], seed_ref, q_ref, k_ref,
                 v_ref, lse_ref, out_ref, do_ref, glse_ref, bias_ref,
-                scale=scale, causal=causal, drop_t=drop_t)
+                scale=scale, causal=causal, drop_t=drop_t, slot=slot)
             if ds_ref is not None:
                 plan.ds_store(ds_ref, i, ds.astype(ds_ref.dtype))
             dq_scr[i] += scale * jax.lax.dot_general(
@@ -577,6 +614,7 @@ def _fa_bwd_dkv_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
     D, Dv, bq, bk = plan.D, plan.Dv, plan.bq, plan.bk
     bhs = [plan.bh(i) if drop_t is not None else None
            for i in range(plan.hpb)]
+    slot = plan.kv_slot()
 
     @pl.when(q_idx == 0)
     def _init():
@@ -596,7 +634,7 @@ def _fa_bwd_dkv_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
             q, k, p_v, ds = _bwd_tile(
                 plan, i, q_idx, kv_idx, bhs[i], seed_ref, q_ref, k_ref,
                 v_ref, lse_ref, out_ref, do_ref, glse_ref, bias_ref,
-                scale=scale, causal=causal, drop_t=drop_t)
+                scale=scale, causal=causal, drop_t=drop_t, slot=slot)
             dv_scr[i] += jax.lax.dot_general(
                 p_v.astype(do_ref.dtype), plan.lanes(do_ref, i, Dv),
                 (((0,), (0,)), ((), ())),
@@ -1029,8 +1067,10 @@ def _kernel_ok(q, k, block_q, block_k, layout="bhsd", v=None):
         if not _INTERPRET and ((hpb * D) % 128 or (hpb * Dv) % 128):
             return False
         # grouped queries: a lane block is ONE head, so that a query
-        # head's k / v block is simply its key head's
-        if Hkv != H and hpb != 1:
+        # head's k / v block is simply its key head's, or hpb packed
+        # heads that all read one key head (the group a multiple of
+        # hpb) out of a key lane block of hpb whole key heads
+        if Hkv != H and hpb != 1 and ((H // Hkv) % hpb or Hkv % hpb):
             return False
     return (Sq % min(block_q, Sq) == 0 and Sk % min(block_k, Sk) == 0
             and D % 8 == 0 and Dv % 8 == 0

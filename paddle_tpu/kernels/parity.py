@@ -582,6 +582,28 @@ def _ssd_case(which):
     return Case("mamba2_ssd", "mamba2_ssd/%s/f32/2x296x8x16" % which, run)
 
 
+def _short_conv_case(which):
+    """The gated short convolution at 2 x 296 tokens (no multiple of the
+    32-row blocks: the halo crosses nine boundaries), D 64, three taps:
+    the forward kernel's output, and the backward kernel's dX and
+    dWeight, against the `jax.numpy` lowering and its `jax.vjp` (f32)."""
+    def run():
+        from . import short_conv as sc
+        r = _rng(37)
+        x = jnp.asarray(r.standard_normal((2, 296, 192), dtype=np.float32))
+        w = jnp.asarray(r.standard_normal((64, 3), dtype=np.float32))
+        g = jnp.asarray(r.standard_normal((2, 296, 64), dtype=np.float32))
+        if which == "fwd":
+            got, ref = (sc._fwd_call(x, w, rows=32),), (sc._lowered(x, w),)
+        else:
+            got, ref = sc._bwd_call(x, w, g, rows=32), \
+                sc._lowered_grad(x, w, g)
+        return {"metric": "rel_vs_lowered", "tol": 1e-5,
+                "value": max(rel_err(v, u) for u, v in zip(got, ref))}
+    return Case("gated_short_conv",
+                "gated_short_conv/%s/f32/2x296x64x3" % which, run)
+
+
 # shapes the fused optimizer blocks over their own layout
 # (fused_optimizer._native_block): one whole block; N off the 128 lanes
 # in a whole-row block; N over whole-row width and off the 512-column
@@ -599,7 +621,7 @@ def cases() -> List[Case]:
     import importlib
     from . import fused_optimizer, grouped_matmul  # noqa: F401
     from . import quantized_matmul, sparse_index  # noqa: F401
-    from . import mamba2_ssd  # noqa: F401
+    from . import mamba2_ssd, short_conv  # noqa: F401
     importlib.import_module("paddle_tpu.kernels.flash_attention")
     return [
         _adam_case((4096,)),        # rank 1: the flat view
@@ -623,6 +645,8 @@ def cases() -> List[Case]:
         _sparse_index_case("select"),
         _ssd_case("fwd"),
         _ssd_case("bwd"),
+        _short_conv_case("fwd"),
+        _short_conv_case("bwd"),
     ]
 
 

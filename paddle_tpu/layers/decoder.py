@@ -2,17 +2,18 @@
 ops/decoder.py): RMS normalisation, rotary positions, the feed-forward's
 activation (gated, or a squared ReLU), the top-k router, the expert layer
 that is told which experts this chip holds, a learned sparse attention's
-index, and a Mamba-2 mixer's three ops: the short causal convolution,
-the state-space scan and the gated group-wise RMS norm."""
+index, a Mamba-2 mixer's three ops: the short causal convolution,
+the state-space scan and the gated group-wise RMS norm, and the gated
+short convolution that mixes tokens on its own."""
 from __future__ import annotations
 
-from ..initializer import Constant, Normal
+from ..initializer import Constant, Normal, Uniform
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
 __all__ = ["rms_norm", "rotary_embedding", "swiglu", "relu2", "moe_router",
            "moe_experts", "sparse_attention_index", "causal_conv1d",
-           "gated_rms_norm", "mamba2_ssd"]
+           "gated_rms_norm", "mamba2_ssd", "gated_short_conv"]
 
 
 def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
@@ -64,13 +65,16 @@ def relu2(x, name=None):
 def moe_router(input, num_experts, top_k, experts_held=None, first_expert=0,
                scoring_func="sigmoid", norm_topk_prob=True,
                routed_scaling_factor=1.0, n_group=1, topk_group=1,
-               param_attr=None, bias_attr=None, name=None):
+               param_attr=None, bias_attr=None, name=None,
+               norm_epsilon=None):
     """Top-k router over `num_experts`, scoring by `scoring_func`
     ("sigmoid" or "softmax" over all experts; float32 under AMP).
     Returns (choice int32 [T, top_k], weight float32 [T, top_k], count
     of tokens for each of the `experts_held` experts from
     `first_expert`). `bias_attr` names the selection-only score
-    correction, a parameter that takes no gradient (False: none)."""
+    correction, a parameter that takes no gradient (False: none).
+    `norm_epsilon` is what `norm_topk_prob` adds to the chosen scores'
+    sum (None: the op's own 1e-20)."""
     helper = LayerHelper("moe_router", name=name)
     d = int(input.shape[-1])
     weight = helper.create_parameter(param_attr, [num_experts, d],
@@ -85,15 +89,18 @@ def moe_router(input, num_experts, top_k, experts_held=None, first_expert=0,
     choice = helper.create_variable_for_type_inference("int32", True)
     probs = helper.create_variable_for_type_inference("float32")
     counts = helper.create_variable_for_type_inference("int32", True)
+    attrs = {"top_k": int(top_k), "scoring_func": scoring_func,
+             "norm_topk_prob": bool(norm_topk_prob),
+             "routed_scaling_factor": float(routed_scaling_factor),
+             "n_group": int(n_group), "topk_group": int(topk_group),
+             "experts_held": int(experts_held or num_experts),
+             "first_expert": int(first_expert)}
+    if norm_epsilon is not None:
+        attrs["norm_epsilon"] = float(norm_epsilon)
     helper.append_op(
         "moe_router", inputs=inputs,
         outputs={"TopkIdx": choice, "TopkWeight": probs, "Counts": counts},
-        attrs={"top_k": int(top_k), "scoring_func": scoring_func,
-               "norm_topk_prob": bool(norm_topk_prob),
-               "routed_scaling_factor": float(routed_scaling_factor),
-               "n_group": int(n_group), "topk_group": int(topk_group),
-               "experts_held": int(experts_held or num_experts),
-               "first_expert": int(first_expert)})
+        attrs=attrs)
     return choice, probs, counts
 
 
@@ -222,3 +229,22 @@ def mamba2_ssd(x, dt, b, c, chunk_size=128, dt_bias_attr=None,
                      outputs={"Y": y, "States": states, "Tokens": tokens},
                      attrs={"chunk_size": int(chunk_size)})
     return y, tokens
+
+
+def gated_short_conv(input, kernel_size, param_attr=None, name=None):
+    """The gated short convolution of input [B, T, 3 D] = [Bg | Cg | x]
+    (an in-projection's output): Cg * conv(Bg * x), conv depthwise and
+    causal over the last `kernel_size` tokens with w [D, kernel_size],
+    no bias, no activation. Returns (out [B, T, D], tokens convolved
+    int32 [1])."""
+    helper = LayerHelper("gated_short_conv", name=name)
+    d, k = int(input.shape[-1]) // 3, int(kernel_size)
+    weight = helper.create_parameter(
+        param_attr, [d, k], "float32",
+        default_initializer=Uniform(-k ** -0.5, k ** -0.5))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    tokens = helper.create_variable_for_type_inference("int32", True)
+    helper.append_op("gated_short_conv",
+                     inputs={"X": input, "Weight": weight},
+                     outputs={"Out": out, "Tokens": tokens})
+    return out, tokens

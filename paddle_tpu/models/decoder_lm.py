@@ -36,6 +36,24 @@ router and which feed-forwards a layer gets follow from the published
       `E` the expert layer, `-` a dense feed-forward. Such a model's
       attention has NO rotary and no q / k norm: its modelling code has
       neither, the positions come from the mixers.
+  conv layers  `layer_types` (the `lfm2_moe` key): a list of "conv" and
+      "full_attention", which token mixer each two-part layer has. A
+      "conv" layer's is the gated short convolution: [Bg | Cg | x] = x
+      W_in [D, 3 D]; Cg * conv(Bg * x), depthwise and causal over
+      `conv_L_cache` taps, no bias (`conv_bias` true raises), no
+      activation; W_out [D, D] (`gated_short_conv`). The first
+      `num_dense_layers` layers (the same count as
+      `first_k_dense_replace`) have the dense feed-forward. That key
+      family's router scores by sigmoid with a selection-only bias where
+      `use_expert_bias`, and adds `router_norm_epsilon` (1e-6, its
+      modelling code's) to the chosen scores' sum. Any other entry of
+      `layer_types` ("sliding_attention", "linear_attention", ...)
+      raises by name. `rope_parameters` {rope_theta, rope_type} is read
+      as `rope_theta` / `rope_scaling` are.
+  head        untied (`lm_head.w_0` [D, V]) unless `tie_word_embeddings`:
+      then the logits are the final norm's output times the embedding's
+      own matrix transposed — one parameter, one optimizer state, its
+      gradient the sum of both uses.
 
 Three more keys say which share of each layer THIS chip holds under
 expert and vocabulary parallelism:
@@ -55,8 +73,9 @@ Published keys that say nothing about the shapes built here
 (`max_position_embeddings`, `model_type`, ...) are accepted and ignored,
 so a `config.json` can be passed whole. Keys that change a layer's
 equations and are not built here raise NotImplementedError by name
-(`moe_latent_size`, `num_nextn_predict_layers`, `sliding_window`,
-`layer_types`, ...): a file that carries one is never built as some
+(`moe_latent_size`, `num_nextn_predict_layers`, `sliding_window`, a
+`layer_types` entry that is neither "conv" nor "full_attention",
+`conv_bias`, ...): a file that carries one is never built as some
 other model.
 
 Every parameter has an explicit, stable name (`layer_<i>_attn_q.w_0`,
@@ -72,7 +91,14 @@ each expert layer worked over and the rows of it in use
 OVERWRITTEN with the (query, key) pairs each layer's selection kept
 (observability/sparse_attention.py). A model with mixers keeps a fourth,
 `mamba_ssd_tokens` [mixer layers], OVERWRITTEN with the tokens each
-mixer's scan went over (observability/mamba.py).
+mixer's scan went over (observability/mamba.py); one with conv layers a
+fifth, `short_conv_tokens` [conv layers], OVERWRITTEN with the tokens
+each layer's convolution went over (observability/short_conv.py).
+
+A conv layer's parameters: `layer_<i>_conv_norm.w_0`, `layer_<i>_conv_in
+.w_0` [D, 3 D], `layer_<i>_conv.w_0` [D, taps] drawn from U(-1 /
+sqrt(taps), 1 / sqrt(taps)) (a depthwise Conv1d's own default),
+`layer_<i>_conv_out.w_0` [D, D].
 
 A mixer's parameters (`layer_<i>_mixer_*`): `in.w_0` [D, 2 H P + 2 G N
 + H] (the gate z, the convolution's input x | B | C, the step sizes dt),
@@ -95,6 +121,7 @@ from ..initializer import (Constant, Normal, NumpyArrayInitializer,
                            Uniform)
 from ..observability.mamba import SSD_TOKENS_VAR
 from ..observability.moe import EXPERT_LOAD_VAR, ROWS_WORKED_VAR
+from ..observability.short_conv import SHORT_CONV_TOKENS_VAR
 from ..observability.sparse_attention import KEPT_PAIRS_VAR
 from ..param_attr import ParamAttr
 
@@ -102,6 +129,9 @@ from ..param_attr import ParamAttr
 # one character of `hybrid_override_pattern` -> the layer's one part (and
 # its op scope)
 PATTERN_PARTS = {"M": "mamba", "*": "attn", "E": "moe", "-": "mlp"}
+# an entry of `layer_types` -> a two-part layer's token mixer (and its op
+# scope)
+LAYER_TYPES = {"conv": "conv", "full_attention": "attn"}
 
 
 def parse_pattern(pattern):
@@ -142,7 +172,9 @@ class DecoderLMConfig:
                  time_step_max=0.1, time_step_floor=1e-4,
                  time_step_limit=None, moe_latent_size=None,
                  num_nextn_predict_layers=0, sliding_window=None,
-                 layer_types=None,
+                 layer_types=None, num_dense_layers=None, conv_L_cache=3,
+                 conv_bias=False, use_expert_bias=None,
+                 router_norm_epsilon=None, rope_parameters=None,
                  experts_held=None, first_expert=0, vocab_held=None,
                  **unused):
         if q_lora_rank is not None:
@@ -153,20 +185,41 @@ class DecoderLMConfig:
                 ("moe_latent_size", moe_latent_size is not None),
                 ("num_nextn_predict_layers", bool(num_nextn_predict_layers)),
                 ("sliding_window", sliding_window is not None),
-                ("layer_types", layer_types is not None)):
+                ("conv_bias", bool(conv_bias))):
             if given:
                 raise NotImplementedError(
                     f"{key}: the model file builds no such layer")
+        if layer_types is not None:
+            bad = sorted(set(layer_types) - set(LAYER_TYPES))
+            if bad:
+                raise NotImplementedError(
+                    f"layer_types {bad}: the model file builds no such "
+                    f"layer (it builds {sorted(LAYER_TYPES)})")
+            if hybrid_override_pattern is not None or kv_lora_rank:
+                raise ValueError("layer_types beside a pattern or latent "
+                                 "attention")
+            if num_hidden_layers not in (None, len(layer_types)):
+                raise ValueError(
+                    f"num_hidden_layers {num_hidden_layers} against "
+                    f"{len(layer_types)} layer_types")
+            num_hidden_layers = len(layer_types)
+        if num_dense_layers is not None:
+            if first_k_dense_replace not in (0, num_dense_layers):
+                raise ValueError("num_dense_layers and first_k_dense_replace "
+                                 "differ")
+            first_k_dense_replace = num_dense_layers
+        if rope_parameters:
+            rope_theta = rope_parameters.get("rope_theta", rope_theta)
+            rope_scaling = rope_scaling or rope_parameters
         scaling = rope_scaling or {}
         scaling = scaling.get("rope_type", scaling.get("type", "default"))
         if scaling != "default":
             raise NotImplementedError(f"rope scaling {scaling!r}")
         act = mlp_hidden_act or hidden_act
         if act not in ("silu", "relu2") or attention_bias \
-                or tie_word_embeddings or mlp_bias or use_bias:
+                or mlp_bias or use_bias:
             raise NotImplementedError(
-                "silu (gated) or relu2 (ungated) feed-forwards, no bias, "
-                "untied head only")
+                "silu (gated) or relu2 (ungated) feed-forwards, no bias")
         if moe_layer_freq != 1:
             raise NotImplementedError("moe_layer_freq other than 1")
         self.parts = None if hybrid_override_pattern is None \
@@ -187,6 +240,11 @@ class DecoderLMConfig:
         self.vocab_size = int(vocab_held or vocab_size)
         self.hidden_size = hidden_size
         self.num_hidden_layers = num_hidden_layers
+        # a two-part layer's token mixer, by layer (None: attention)
+        self.mixers = None if layer_types is None \
+            else [LAYER_TYPES[t] for t in layer_types]
+        self.conv_taps = int(conv_L_cache)
+        self.tie_word_embeddings = bool(tie_word_embeddings)
         self.num_attention_heads = num_attention_heads
         self.kv_lora_rank = kv_lora_rank
         self.qk_nope_head_dim = qk_nope_head_dim
@@ -227,10 +285,16 @@ class DecoderLMConfig:
         self.norm_topk_prob = norm_topk_prob
         # the two key families' own modelling code: sigmoid scores with
         # a selection-only correction bias, or softmax over all experts
+        # ... or, where `use_expert_bias` is a key (`lfm2_moe`), sigmoid
+        # with that bias and 1e-6 under the chosen scores' sum
+        lfm = use_expert_bias is not None
         self.scoring_func = scoring_func or (
-            "sigmoid" if n_routed_experts else "softmax")
-        self.router_bias = bool(n_routed_experts) \
-            and topk_method == "noaux_tc"
+            "sigmoid" if n_routed_experts or lfm else "softmax")
+        self.router_bias = bool(use_expert_bias) if lfm else (
+            bool(n_routed_experts) and topk_method == "noaux_tc")
+        # None: the router op's own 1e-20
+        self.router_norm_epsilon = router_norm_epsilon if \
+            router_norm_epsilon is not None else (1e-6 if lfm else None)
         self.n_group, self.topk_group = n_group, topk_group
         self.initializer_range = initializer_range
         self.experts_held = int(experts_held or self.n_routed_experts or 0)
@@ -405,7 +469,8 @@ def moe_ffn(x, cfg, name):
         param_attr=_w(name + "_router.w_0", cfg),
         bias_attr=ParamAttr(name=name + "_router.b_0",
                             initializer=Constant(0.0))
-        if cfg.router_bias else False)
+        if cfg.router_bias else False,
+        norm_epsilon=cfg.router_norm_epsilon)
     routed = layers.moe_experts(
         x, choice, weight, cfg.n_routed_experts,
         cfg.moe_intermediate_size, experts_held=cfg.experts_held,
@@ -464,11 +529,23 @@ def mamba_mixer(x, cfg, name):
     return _linear(y, cfg.hidden_size, name + "_out", cfg), tokens
 
 
+def short_conv(x, cfg, name):
+    """The gated short convolution operator on the layer's normalised
+    input: [Bg | Cg | x] = x W_in; Cg * conv(Bg * x) over `conv_taps`
+    tokens back; W_out. Returns (output, tokens convolved int32 [1])."""
+    taps = Uniform(-cfg.conv_taps ** -0.5, cfg.conv_taps ** -0.5)
+    y, tokens = layers.gated_short_conv(
+        _linear(x, 3 * cfg.hidden_size, name + "_in", cfg), cfg.conv_taps,
+        param_attr=ParamAttr(name=name + ".w_0", initializer=taps))
+    return _linear(y, cfg.hidden_size, name + "_out", cfg), tokens
+
+
 class _Counts:
     """What the layers hand to the step's counters."""
 
     def __init__(self):
         self.load, self.worked, self.kept, self.scanned = [], [], [], []
+        self.convolved = []
 
 
 def _attention(x, cfg, name, counts):
@@ -488,12 +565,20 @@ def _experts(x, cfg, p, counts):
 
 
 def _two_part_layer(h, i, cfg, counts):
-    """h += Attn(RMSNorm(h)); h += FFN(RMSNorm(h))."""
+    """h += Mixer(RMSNorm(h)); h += FFN(RMSNorm(h)), the mixer attention
+    or, by `layer_types`, the gated short convolution."""
     p = f"layer_{i}"
-    with name_scope("attn"):
-        attn = _attention(_norm(h, p + "_attn_norm", cfg), cfg, p + "_attn",
-                          counts)
-        h = layers.elementwise_add(h, attn)
+    if cfg.mixers is not None and cfg.mixers[i] == "conv":
+        with name_scope("conv"):
+            out, n = short_conv(_norm(h, p + "_conv_norm", cfg), cfg,
+                                p + "_conv")
+            counts.convolved.append(n)
+            h = layers.elementwise_add(h, out)
+    else:
+        with name_scope("attn"):
+            attn = _attention(_norm(h, p + "_attn_norm", cfg), cfg,
+                              p + "_attn", counts)
+            h = layers.elementwise_add(h, attn)
     if i in cfg.dense_layers:
         with name_scope("mlp"):
             out = gated_ffn(_norm(h, p + "_ffn_norm", cfg),
@@ -568,9 +653,17 @@ def decoder_lm_train(cfg: DecoderLMConfig):
         _overwritten_counter(KEPT_PAIRS_VAR, counts.kept)
     if counts.scanned:
         _overwritten_counter(SSD_TOKENS_VAR, counts.scanned)
+    if counts.convolved:
+        _overwritten_counter(SHORT_CONV_TOKENS_VAR, counts.convolved)
     with name_scope("head"):
-        logits = _linear(_norm(h, "final_norm", cfg), cfg.vocab_size,
-                         "lm_head", cfg)
+        x = _norm(h, "final_norm", cfg)
+        if cfg.tie_word_embeddings:
+            # the embedding's own parameter: one state, and its gradient
+            # the sum of the lookup's and the head's
+            table = x.block.program.global_block().var("embed_tokens.w_0")
+            logits = layers.matmul(x, table, transpose_y=True)
+        else:
+            logits = _linear(x, cfg.vocab_size, "lm_head", cfg)
     with name_scope("loss"):
         cost = layers.softmax_with_cross_entropy(
             logits, layers.unsqueeze(labels, axes=[2]))

@@ -3,8 +3,9 @@ normalisation, rotary positions, the feed-forward's activation (gated
 SwiGLU, or a squared ReLU), the top-k router (sigmoid or softmax
 scores), the expert layer of a mixture of experts of which this chip
 holds a share, the index of a learned sparse attention (which keys each
-query attends), and the three ops of a Mamba-2 mixer: the short causal
-convolution, the state-space scan and the gated group-wise RMS norm.
+query attends), the three ops of a Mamba-2 mixer: the short causal
+convolution, the state-space scan and the gated group-wise RMS norm, and
+the gated short convolution that is a token mixer of its own.
 
 The expert layer is dropless and knows which experts it holds:
 `moe_experts` gathers the rows routed to experts `first_expert ..
@@ -135,7 +136,8 @@ def _router(ctx, x, w, bias):
     _, choice = jax.lax.top_k(jax.lax.stop_gradient(pick), k)
     weight = jnp.take_along_axis(s, choice, axis=-1)
     if ctx.attr("norm_topk_prob", True):
-        weight = weight / (jnp.sum(weight, -1, keepdims=True) + 1e-20)
+        weight = weight / (jnp.sum(weight, -1, keepdims=True)
+                           + float(ctx.attr("norm_epsilon", 1e-20)))
     return choice.astype(jnp.int32), \
         weight * float(ctx.attr("routed_scaling_factor", 1.0))
 
@@ -147,7 +149,8 @@ def moe_router(ctx):
     TopkIdx int32 [T, top_k]: the top_k of s + bias over all experts,
     s = sigmoid(x.w^T) or softmax(x.w^T) (attr scoring_func);
     TopkWeight float32 [T, top_k]: the chosen scores
-    WITHOUT the bias, normalised to sum 1 (norm_topk_prob) and scaled
+    WITHOUT the bias, normalised to sum 1 (norm_topk_prob: over their
+    sum + attr norm_epsilon, 1e-20 unless given) and scaled
     by routed_scaling_factor; Counts int32 [experts_held]: tokens routed
     to each expert held here (first_expert ..). float32 whatever AMP
     says (a BLACK op of core/amp.py)."""
@@ -591,3 +594,45 @@ def mamba2_ssd_grad(ctx):
         if names and names[0]:
             primal = ctx.env[op.input(slot)[0]]
             ctx.env[names[0]] = grad.astype(primal.dtype)
+
+
+# ------------------------------------------- the gated short convolution
+
+def _short_conv_operands(ctx):
+    from ..kernels import short_conv
+    x, w = ctx.input("X"), ctx.input("Weight")
+    if x.ndim != 3 or x.shape[-1] != 3 * w.shape[0]:
+        raise ValueError(f"gated_short_conv: X {x.shape} is not [B, T, 3 D] "
+                         f"of a filter {w.shape}")
+    return short_conv, x, w, short_conv.use_kernels(x, w)
+
+
+@register_op("gated_short_conv")
+def gated_short_conv(ctx):
+    """A gated short convolution, the token mixer between an in- and an
+    out-projection. X [B, T, 3 D] = [Bg | Cg | x], the in-projection's
+    output; Weight [D, K], a depthwise filter.
+    Out[t] = Cg[t] * sum_j Weight[:, j] * (Bg * x)[t - (K - 1) + j],
+    tokens before the first read as zero; no bias, no activation.
+    Float32 inside, X's type out (kernels/short_conv.py). Tokens
+    (optional, int32 [1]): the tokens convolved, for the
+    `short_conv_tokens` counter (observability/short_conv.py)."""
+    sc, x, w, kernels = _short_conv_operands(ctx)
+    ctx.set_output("Out", sc.conv(x, w, kernels))
+    if ctx.has_output("Tokens"):
+        ctx.set_output("Tokens", jnp.full(
+            (1,), x.shape[0] * x.shape[1], jnp.int32))
+
+
+@override_grad_lowering("gated_short_conv")
+def gated_short_conv_grad(ctx):
+    """Hand-written: one backward kernel gives dX (its three thirds are
+    d Bg = du * x, d Cg = dOut * c, dx = du * Bg, with du the filter run
+    the other way over dOut * Cg) and dWeight (float32)."""
+    op = ctx.op
+    sc, x, w, kernels = _short_conv_operands(ctx)
+    dx, dw = sc.conv_grad(x, w, ctx.env[op.input("Out@GRAD")[0]], kernels)
+    for slot, grad, like in (("X", dx, x), ("Weight", dw, w)):
+        names = op.output(slot + "@GRAD")
+        if names and names[0]:
+            ctx.env[names[0]] = grad.astype(like.dtype)

@@ -20,7 +20,7 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
 # divide by the chip's peak (no peak is looked up in a rehearsal) or
 # read events of the Pallas kernels, which route only off-CPU.
 _NEEDS_THE_CHIP = ("_roofline_pct", "step_mfu_pct", "flash_", "moe_gmm_",
-                   "dsa_index_", "ssd_ms_")
+                   "dsa_index_", "ssd_ms_", "short_conv_ms_")
 
 
 def _reads_on_cpu(name):

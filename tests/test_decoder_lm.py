@@ -24,6 +24,8 @@ from paddle_tpu.core.scope import Scope
 from paddle_tpu.kernels import grouped_matmul as gm
 from paddle_tpu.kernels import registry as kreg
 
+from benchmark.families import conv_gqa_moe_decoder as conv_family
+from benchmark.families import conv_gqa_moe_decoder_reference as conv_ref
 from benchmark.families import gqa_dsa_moe_decoder as gqa_family
 from benchmark.families import gqa_dsa_moe_decoder_reference as gqa_ref
 from benchmark.families import mamba_gqa_moe_decoder as hybrid_family
@@ -662,9 +664,16 @@ def test_flash_kernels_take_fewer_key_heads_and_a_keep_mask(
                                    False)[0][:, None]
     scale = d ** -0.5
     assert fa._kernel_ok(q, k, 32, 32, "bshd", v)
-    # fewer key heads need one head a lane block: two heads of 64 do not
-    assert not fa._kernel_ok(q[..., :64], k[..., :64], 32, 32, "bshd",
+    # at two heads of 64 a lane block the kernels take fewer key heads
+    # where the block's two query heads read ONE key head (PR 39: a group
+    # that is a multiple of two, key heads that fill lane blocks), and
+    # refuse a group of three and three key heads
+    assert fa._kernel_ok(q[..., :64], k[..., :64], 32, 32, "bshd",
+                         v[..., :64])
+    assert not fa._kernel_ok(q[:, :, :12, :64], k[..., :64], 32, 32, "bshd",
                              v[..., :64])
+    assert not fa._kernel_ok(q[:, :, :6, :64], k[:, :, :3, :64], 32, 32,
+                             "bshd", v[:, :, :3, :64])
 
     def plain(q, k, v):
         """Dense float32 attention over the kept pairs, k and v repeated
@@ -1356,22 +1365,29 @@ _PARENT_PROGRAMS = {
                                  "553cd6aedcc4830ca0d607205aae"),
     ("keye_vl2_30b_a3b", False): (556, "5c4db69f75a2558857703e110ef2ec83ffe0"
                                   "e40f649c6c9aae0f9b2fefe8640e"),
+    # as the parent of PR 39 built them
+    ("nemotron_twotower_30b_a3b", True): (
+        285, "4b5d44467e17f55735401eb7631b839131c3d459326b300a5cd6ecfad5168c35"),
+    ("nemotron_twotower_30b_a3b", False): (
+        502, "f7097b3dba5720c46ae765433eeae362b9ecf0e77f2776e30ad8a5096ca1922f"),
 }
 
 
 @pytest.mark.parametrize("config,rehearsal", sorted(_PARENT_PROGRAMS))
 def test_accepted_decoder_programs_are_op_for_op_the_parents(config,
                                                              rehearsal):
-    """The model file grew a second kind of layer; the two accepted
-    decoder configurations still build the parent's programs, op for op:
-    types, inputs, outputs and every attribute (name scopes among
-    them), at the rehearsal's sizes and at the cell's."""
+    """The model file grew a second kind of layer, then a third; the
+    three accepted decoder configurations still build the parent's
+    programs, op for op: types, inputs, outputs and every attribute
+    (name scopes among them; no router op gained a `norm_epsilon`), at
+    the rehearsal's sizes and at the cell's."""
     import hashlib
     import json
     import os
     from benchmark.lib import cells
     from paddle_tpu import models
-    fam = family if config.startswith("kanana") else gqa_family
+    fam = {"kanana2_30b_a3b": family, "keye_vl2_30b_a3b": gqa_family,
+           "nemotron_twotower_30b_a3b": hybrid_family}[config]
     with open(os.path.join(cells.BENCH, "configs", config + ".json")) as f:
         sz = fam.sizes(json.load(f), rehearsal=rehearsal)
     fluid.framework.unique_name.reset()
@@ -1397,24 +1413,30 @@ def test_accepted_decoder_programs_are_op_for_op_the_parents(config,
 
 @pytest.mark.parametrize("key,value", [
     ("moe_latent_size", 1024), ("num_nextn_predict_layers", 1),
-    ("sliding_window", 4096), ("layer_types", ["full_attention"])])
+    ("sliding_window", 4096),
+    ("layer_types", ["full_attention", "sliding_attention"]),
+    ("layer_types", ["conv", "linear_attention"]), ("conv_bias", True)])
 def test_keys_that_change_a_layers_equations_raise_by_name(key, value):
     """`DecoderLMConfig` swallowed every key it did not know: a file
-    with experts in a compressed latent, multi-token heads, a window or
-    per-layer attention types would have built some other model."""
+    with experts in a compressed latent, multi-token heads, a window, a
+    per-layer attention type the model file does not build (it builds
+    "conv" and "full_attention") or a biased convolution would have
+    built some other model."""
     from paddle_tpu import models
     with pytest.raises(NotImplementedError, match=key):
         models.DecoderLMConfig(n_routed_experts=8, **{key: value})
     # absent, null or zero: the model that was always built
     quiet = {"sliding_window": None, "layer_types": None,
-             "moe_latent_size": None, "num_nextn_predict_layers": 0}
+             "moe_latent_size": None, "num_nextn_predict_layers": 0,
+             "conv_bias": False}
     models.DecoderLMConfig(n_routed_experts=8, **{key: quiet[key]},
                            max_position_embeddings=4096, model_type="any")
 
 
 @pytest.mark.parametrize("config,fam", [
     ("kanana2_30b_a3b", family), ("keye_vl2_30b_a3b", gqa_family),
-    ("nemotron_twotower_30b_a3b", hybrid_family)])
+    ("nemotron_twotower_30b_a3b", hybrid_family),
+    ("lfm2_24b_a2b", conv_family)])
 def test_configuration_files_load_whole(config, fam):
     """Every published key of a configuration file handed to the model
     file at once (what `**unused` is for), the harness's own groups
@@ -1432,6 +1454,7 @@ def test_configuration_files_load_whole(config, fam):
     cfg = models.DecoderLMConfig(**keys)
     assert cfg.hidden_size == raw["hidden_size"]
     assert (cfg.parts is not None) == (config.startswith("nemotron"))
+    assert (cfg.mixers is not None) == (config.startswith("lfm2"))
 
 
 def test_scanned_tokens_reader():
@@ -1442,3 +1465,401 @@ def test_scanned_tokens_reader():
         jnp.asarray([4096, 4096, 4096], jnp.int32))
     got = mamba.scanned_tokens(scope)
     assert got.dtype == np.int64 and got.tolist() == [4096] * 3
+
+
+# ------------------------------------- gated short convolution, LFM2
+
+def test_gated_short_conv_op_forward_and_grad():
+    """Cg * conv3(Bg * x) through Executor.run against the reference's
+    own function and `jax.grad` of it: the output, dX over all three
+    thirds and dWeight; a token's output does not move with a later
+    token (2e-5: float32 on both sides, sums of three products)."""
+    d, k = 6, 3
+    x, w, cot = _r((2, 9, 3 * d), 140), _r((d, k), 141), _r((2, 9, d), 142)
+    main, scope, exe, out, grads = _run(
+        lambda x: layers.gated_short_conv(
+            x, k, param_attr=fluid.ParamAttr(name="w"))[0],
+        {"x": x}, ["x", "w"])
+    got, gs = _fetch(main, scope, exe, {"x": x}, out, grads, cot, {"w": w})
+    _close(got, conv_ref.gated_conv(x, w))
+    want = jax.grad(lambda x, w: jnp.sum(conv_ref.gated_conv(x, w) * cot),
+                    (0, 1))(x, w)
+    for g, r in zip(gs, want):
+        _close(g, r)
+    later = x.copy()
+    later[:, 5:] += 1.0
+    moved, _ = _fetch(main, scope, exe, {"x": later}, out, grads, cot)
+    np.testing.assert_array_equal(moved[:, :5], got[:, :5])
+    assert np.abs(moved[:, 5:] - got[:, 5:]).min() > 0
+    assert [op.type for op in main.global_block().ops].count(
+        "gated_short_conv_grad") == 1
+
+
+@pytest.mark.parametrize("shape", [(2, 296, 64, 3, 32), (1, 40, 128, 3, 256),
+                                   (2, 100, 32, 4, 16)],
+                         ids=["across_blocks", "one_short_block",
+                              "four_taps"])
+def test_short_conv_kernels_equal_their_lowering(shape):
+    """`gated_short_conv_fwd` / `_bwd` under the interpreter against the
+    `jax.numpy` lowering and its `jax.vjp`, and that against `jax.grad`
+    of the reference's function: sequences that are no multiple of the
+    block (296 and 100 tokens in blocks of 32 and 16: the halo crosses
+    nine and six block boundaries; 40 tokens in one padded block), float32
+    (5e-6 relative: the same products summed in another order)."""
+    from paddle_tpu.kernels import short_conv as sc
+    b, t, d, k, rows = shape
+    x, w, g = _r((b, t, 3 * d), 150), _r((d, k), 151), _r((b, t, d), 152)
+    low = sc._lowered(x, w)
+    _close(low, conv_ref.gated_conv(x, w), 2e-6)
+    _close(sc._fwd_call(x, w, rows=rows), low, 5e-6)
+    want = jax.grad(lambda x, w: jnp.sum(conv_ref.gated_conv(x, w) * g),
+                    (0, 1))(x, w)
+    for got in (sc._lowered_grad(x, w, g), sc._bwd_call(x, w, g, rows=rows)):
+        for a, r in zip(got, want):
+            _close(a, r, 5e-6)
+
+
+def test_short_conv_routes_by_the_registry(interp, monkeypatch):
+    from paddle_tpu.kernels import short_conv as sc
+    assert "gated_short_conv" in kreg.kernel_names()
+    x, w = jnp.zeros((1, 32, 3 * 8), jnp.bfloat16), jnp.zeros((8, 3))
+    assert sc.use_kernels(x, w)
+    assert not sc.use_kernels(x.astype(jnp.int32), w)
+    monkeypatch.setenv("PT_KERNEL_DENY", "gated_short_conv")
+    assert not sc.use_kernels(x, w)
+    assert kreg.dispatch_stats()["per_kernel"]["gated_short_conv"] == {
+        "custom": 1, "lowered": 1, "denied": 1}
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_flash_kernels_take_packed_heads_over_shared_key_heads(causal,
+                                                               interp):
+    """8 query heads over 2 key / value heads of 64: two query heads a
+    lane block, both reading ONE key head, a half of a key lane block
+    that the grid step picks. Forward and the fused backward against the
+    composed path (k and v repeated there); dk and dv leave at 2 heads
+    (2e-6 of the largest element: float32 both sides)."""
+    b, s, h, hkv, d = 2, 256, 8, 2, 64
+    q, k, v = _r((b, s, h, d), 160), _r((b, s, hkv, d), 161), \
+        _r((b, s, hkv, d), 162)
+    plan = fa._Plan("bshd", b, h, s, s, d, 128, 128, d, hkv)
+    assert plan.hpb == 2 and plan.group == 4 and plan.packed_shared
+    assert fa._kernel_ok(jnp.asarray(q), jnp.asarray(k), 128, 128, "bshd",
+                         jnp.asarray(v))
+    # three key heads under six query heads: a lane block would straddle
+    # two key heads' groups, which the kernels do not do
+    assert not fa._kernel_ok(jnp.zeros((b, s, 6, d)), jnp.zeros((b, s, 3, d)),
+                             128, 128, "bshd")
+    sc = d ** -0.5
+
+    def kernel(q, k, v):
+        return fa.flash_attention(q, k, v, None, sc, 128, 128, "bshd",
+                                  causal)
+
+    def composed(q, k, v):
+        return fa._attn_reference(q, k, v, None, sc, layout="bshd",
+                                  causal=causal)
+    with jax.default_matmul_precision("highest"):
+        _close(kernel(q, k, v), composed(q, k, v), 2e-6)
+        got = jax.grad(lambda *a: jnp.sum(kernel(*a) ** 2), (0, 1, 2))(
+            q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(composed(*a) ** 2), (0, 1, 2))(
+            q, k, v)
+    for a, r in zip(got, want):
+        assert a.shape == r.shape
+        _close(a, r, 5e-6)
+    assert kreg.dispatch_stats()["per_kernel"]["flash_attention"][
+        "fused_bwd"] >= 1
+
+
+@pytest.mark.parametrize("site,digest", [
+    ((8, 8, 64), "caf446f82563111e92a2b02a16c3d96deaafd1e6c0c89e5c5a79cbe1e"
+                 "02e9fbe"),
+    ((8, 2, 128), "17b62eef7b2c7227496372ff14bb78b2eee376a4eb0872da7f3bde13"
+                  "5a7b567e")], ids=["packed_ungrouped", "grouped_at_128"])
+def test_unchanged_flash_sites_trace_to_the_parents_kernels(site, digest,
+                                                            interp):
+    """The sites the accepted cells have — packed heads with as many key
+    heads (tbase_s4096), fewer key heads at one head a lane block
+    (keye2_s8192, twotower_s4096) — trace to the jaxpr the parent of
+    PR 39 traced, kernel bodies included (sha256 of the text, source
+    positions taken out): the same kernels, so bit-equal results."""
+    import hashlib
+    import re
+    h, hkv, d = site
+    q = jnp.zeros((1, 256, h, d), jnp.float32)
+    k = jnp.zeros((1, 256, hkv, d), jnp.float32)
+    assert not fa._Plan("bshd", 1, h, 256, 256, d, 128, 128, d,
+                        hkv).packed_shared
+
+    def f(q, k, v, g):
+        out, lse = fa._fa_forward(q, k, v, None, d ** -0.5, 128, 128,
+                                  return_lse=True, layout="bshd",
+                                  causal=True, raw_lse=True)
+        return fa._fa_backward(q, k, v, None, out, lse, g, d ** -0.5, 128,
+                               128, layout="bshd", lse_wide=True,
+                               causal=True)[:3]
+    text = re.sub(r" at \S+:\d+", "", str(jax.make_jaxpr(f)(q, k, k, q)))
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
+def _lfm2_sizes(**over):
+    import json
+    import os
+    from benchmark.lib import cells
+    with open(os.path.join(cells.BENCH, "configs",
+                           "lfm2_24b_a2b.json")) as f:
+        return dict(conv_family.sizes(json.load(f), rehearsal=True), **over)
+
+
+@pytest.mark.parametrize("path", ["lowered", "kernels"])
+def test_conv_attention_model_trains_like_its_reference(path, request):
+    """The same model file, told by `layer_types` to build convolution
+    and attention layers (conv, attention, conv, conv, conv; one leading
+    dense layer; a tied head), against the plain reference in float32:
+    losses (2e-6), EVERY leaf's first gradient (2e-4 of its norm) and
+    every leaf's change after three Adam steps (5e-3: Adam divides by
+    the gradient's own size, so a leaf's rounding is not damped). The
+    expert bias is a NONZERO seeded buffer (std 0.1 against sigmoid
+    scores that differ by less): it decides the choice and never the
+    weights, is in the program, and never moves. With the kernels: 8
+    query heads over 2 key heads of 64 (packed and shared), the
+    convolution kernels and the grouped matmuls, all interpreted."""
+    over = dict(expert_bias_std=0.1)
+    if path == "kernels":
+        request.getfixturevalue("interp")
+        over.update(num_attention_heads=8, num_key_value_heads=2,
+                    head_dim=64)
+    sz = _lfm2_sizes(**over)
+    fam = conv_family
+    tr = fam.traffic({"pool": 3, "reference_rows_per_block": 1}, True)
+    with jax.default_matmul_precision("highest"):
+        got = _train(sz, tr, 7, amp=False, fam=fam)
+        want = fam.run_reference(sz, tr, fam.make_pool(sz, tr, 7), 7, 3)
+        biased = fam.run_reference(sz, tr, fam.make_pool(sz, tr, 7), 7, 1,
+                                   fault="bias_in_weights")
+    np.testing.assert_allclose(got["losses"], want["losses"], rtol=2e-6)
+    assert set(got["grad_norms"]) == set(want["grad_norms"]) \
+        == set(conv_ref.trainable_names(sz))
+    assert "lm_head.w_0" not in got["grad_norms"]      # one tied table
+    for n, w in want["grad_norms"].items():
+        assert w > 0, n
+        assert abs(got["grad_norms"][n] - w) <= 2e-4 * max(w, 1e-6), n
+    for n, w in want["delta_norms"].items():
+        assert abs(got["delta_norms"][n] - w) <= 5e-3 * w, n
+    # a bias that weighed would show: the planted fault's loss is apart
+    assert abs(biased["losses"][0] - want["losses"][0]) \
+        > 1e-4 * want["losses"][0]
+    buffers = [n for n in fam.param_names(sz) if conv_ref.is_buffer(n)]
+    assert len(buffers) == 4
+    params = fam.init_params(sz, 7)
+    for n in buffers:
+        assert np.asarray(params[n]).any()
+        np.testing.assert_array_equal(got["state"](n), params[n])
+    tokens = tr["batch"] * tr["seq_len"]
+    assert fam.convolved_tokens(sz).tolist() == [tokens] * 4
+    assert fam.expert_load(sz).shape == (4, sz["experts_held"])
+    if path == "kernels":
+        stats = kreg.dispatch_stats()["per_kernel"]
+        assert stats["gated_short_conv"].get("custom")
+        assert not stats["gated_short_conv"].get("lowered")
+        assert stats["moe_grouped_matmul"].get("custom")
+        assert stats["flash_attention"].get("custom")
+        assert stats["flash_attention"].get("fused_bwd")
+
+
+def test_conv_attention_model_under_mixed_precision():
+    sz = _lfm2_sizes()
+    fam = conv_family
+    tr = fam.traffic({"pool": 3, "reference_rows_per_block": 1}, True)
+    got = _train(sz, tr, 9, amp=True, fam=fam)
+    want = fam.run_reference(sz, tr, fam.make_pool(sz, tr, 9), 9, 3)
+    np.testing.assert_allclose(got["losses"], want["losses"], rtol=2e-3)
+    from paddle_tpu.core import amp
+    assert amp.op_mode("gated_short_conv") is None   # float32 inside
+
+
+def test_the_tied_heads_gradient_is_the_sum_of_both_uses():
+    """One table under the lookup and, transposed, under the head: the
+    program has no `lm_head`, ONE Adam state for the table, and the
+    gradient the optimizer got is the lookup's plus the head's — read
+    apart from two programs that each stop one of the two paths."""
+    from paddle_tpu import models
+    sz = _lfm2_sizes(layers="C", num_dense_layers=1)
+    fam = conv_family
+    tr = fam.traffic({"pool": 1}, True)
+    batch = fam.make_pool(sz, tr, 3)[0]
+    params = fam.init_params(sz, 3)
+
+    def table_grad(stop):
+        fluid.framework.unique_name.reset()
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            cost, _, _ = models.decoder_lm_train(fam.model_config(sz))
+            block = main.global_block()
+            if stop:
+                op = [o for o in block.ops if o.type == stop][0]
+                block.var(op.output("Out")[0]).stop_gradient = True
+            table = block.var("embed_tokens.w_0")
+            grad, = fluid.backward.gradients(cost, [table])
+        names = {p.name for p in main.all_parameters()}
+        assert "lm_head.w_0" not in names
+        scope = Scope()
+        with fluid.scope_guard(scope):
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(startup)
+            for n in names:
+                scope.find_var(n).set_value(params[n])
+            return np.asarray(exe.run(main, feed=batch,
+                                      fetch_list=[grad])[0])
+    with jax.default_matmul_precision("highest"):
+        both = table_grad(None)
+        head_only = table_grad("lookup_table")
+    rest = both - head_only              # what reached it through the lookup
+    ids = np.unique(batch["input_ids"])
+    absent = np.setdiff1d(np.arange(sz["vocab_held"]), ids)
+    # the head reaches every row; the lookup only the rows that were fed
+    assert np.abs(head_only).sum(-1).min() > 0
+    assert np.abs(rest[ids]).sum(-1).min() > 0
+    np.testing.assert_allclose(rest[absent], 0, atol=1e-6)
+    # and Adam holds one state for it
+    fluid.framework.unique_name.reset()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        cost, _, _ = models.decoder_lm_train(fam.model_config(sz))
+        fluid.optimizer.AdamOptimizer(learning_rate=1e-3).minimize(cost)
+    moments = [v for v in main.global_block().vars
+               if v.startswith("embed_tokens.w_0_moment1")]
+    assert len(moments) == 1
+    assert [op.type for op in main.global_block().ops].count("adam") == len(
+        main.all_parameters())
+
+
+def test_eight_sigmoid_shares_with_a_bias_add_up_to_the_uncut_layer():
+    """16 sigmoid-routed experts, top-4 of score + bias, the weights
+    without the bias over their sum + 1e-6, NO shared expert, over 8
+    shares of 2: the routed outputs of all eight shares add up to the
+    uncut reference layer (nothing is counted twice: there is nothing
+    every chip computes alike)."""
+    t, d, f, e = 96, 16, 8, 16
+    sz = dict(num_experts_per_tok=4, norm_topk_prob=True, first_expert=0,
+              routed_scaling_factor=1.0, router_norm_epsilon=1e-6)
+    y = _r((t, d), 170)
+    p = {"router.w_0": _r((e, d), 171, 0.3), "router.b_0": _r((e,), 172, 0.2),
+         "experts_gate.w_0": _r((e, d, f), 173, 0.3),
+         "experts_up.w_0": _r((e, d, f), 174, 0.3),
+         "experts_down.w_0": _r((e, f, d), 175, 0.3)}
+    ar = ref.base._Arithmetic("f32")
+    with jax.default_matmul_precision("highest"):
+        whole, choice = conv_ref.moe_layer(ar, p, jnp.asarray(y), sz)
+        _, weight = conv_ref.route(y, p["router.w_0"], p["router.b_0"], sz)
+        plain, _ = conv_ref.route(y, p["router.w_0"], None, sz)
+        total = np.zeros_like(y)
+        for share in range(8):
+            lo = 2 * share
+            c = dict(x=y, choice=np.asarray(choice),
+                     weight=np.asarray(weight), cot=np.zeros_like(y),
+                     wg=p["experts_gate.w_0"][lo:lo + 2],
+                     wu=p["experts_up.w_0"][lo:lo + 2],
+                     wd=p["experts_down.w_0"][lo:lo + 2])
+            out, _ = _experts_program(c, e, 2, lo)
+            total += out
+    _close(total, whole)
+    assert (np.asarray(plain) != np.asarray(choice)).any()   # the bias chose
+    assert np.abs(np.asarray(whole)).sum(-1).min() > 0
+
+
+def test_router_takes_the_normalisers_epsilon_and_a_selecting_bias():
+    """`moe_router` with `norm_epsilon` 1e-6 and a nonzero bias against
+    the reference's router: the same choices, the same weights (which
+    sum to under one by the epsilon's share), and the default stays
+    1e-20 with no attribute on the op."""
+    t, d, e, k = 64, 16, 16, 4
+    sz = dict(num_experts_per_tok=k, norm_topk_prob=True,
+              routed_scaling_factor=1.0, router_norm_epsilon=1e-6)
+    x, w, b = _r((t, d), 180), _r((e, d), 181, 0.3), _r((e,), 182, 0.2)
+
+    def program(eps):
+        fluid.framework.unique_name.reset()
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            xv = layers.data("x", [t, d], append_batch_size=False)
+            choice, weight, _ = layers.moe_router(
+                xv, e, k, param_attr=fluid.ParamAttr(name="w"),
+                bias_attr=fluid.ParamAttr(name="b"), norm_epsilon=eps)
+        op = [o for o in main.global_block().ops
+              if o.type == "moe_router"][0]
+        scope = Scope()
+        with fluid.scope_guard(scope):
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(startup)
+            scope.find_var("w").set_value(jnp.asarray(w))
+            scope.find_var("b").set_value(jnp.asarray(b))
+            got = exe.run(main, feed={"x": x}, fetch_list=[choice, weight])
+        return op, np.asarray(got[0]), np.asarray(got[1])
+    with jax.default_matmul_precision("highest"):
+        op, choice, weight = program(1e-6)
+        want_c, want_w = conv_ref.route(x, w, b, sz)
+        op0, _, weight0 = program(None)
+    assert op.attr("norm_epsilon") == pytest.approx(1e-6)
+    assert not op0.has_attr("norm_epsilon")
+    np.testing.assert_array_equal(choice, want_c)
+    _close(weight, want_w, 1e-6)
+    assert (1.0 - weight.sum(-1)).min() > 1e-7
+    np.testing.assert_allclose(weight0.sum(-1), 1.0, atol=1e-6)
+
+
+def test_convolved_tokens_reader():
+    from paddle_tpu.observability import short_conv
+    scope = Scope()
+    assert short_conv.convolved_tokens(scope) is None
+    scope.var(short_conv.SHORT_CONV_TOKENS_VAR).set_value(
+        jnp.asarray([8192] * 4, jnp.int32))
+    got = short_conv.convolved_tokens(scope)
+    assert got.dtype == np.int64 and got.tolist() == [8192] * 4
+
+
+def test_the_conv_model_is_built_from_its_published_keys():
+    """`layer_types`, `num_dense_layers`, `conv_L_cache`,
+    `use_expert_bias`, `rope_parameters` and the tied head, read off the
+    configuration's own keys: op scopes `layer_<i>/conv`, one
+    `gated_short_conv` a conv layer, the router's epsilon and bias, the
+    rotary's theta, one `matmul` on the table for the head."""
+    from paddle_tpu import models
+    sz = _lfm2_sizes()
+    cfg = conv_family.model_config(sz)
+    assert cfg.mixers == ["conv", "attn", "conv", "conv", "conv"]
+    assert cfg.dense_layers == {0} and cfg.moe_layers == [1, 2, 3, 4]
+    assert cfg.scoring_func == "sigmoid" and cfg.router_bias
+    assert cfg.router_norm_epsilon == 1e-6 and cfg.rope_theta == 1e6
+    assert not cfg.rope_interleave and cfg.tie_word_embeddings
+    fluid.framework.unique_name.reset()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        models.decoder_lm_train(cfg)
+    ops = main.global_block().ops
+    types = [op.type for op in ops]
+    assert types.count("gated_short_conv") == 4
+    assert types.count("fused_attention") == 1
+    assert types.count("moe_experts") == 4 and types.count("swiglu") == 1
+    scopes = {op.attr("op_namescope", "") for op in ops
+              if op.type == "gated_short_conv"}
+    assert scopes == {f"layer_{i}/conv/" for i in (0, 2, 3, 4)}
+    router = [op for op in ops if op.type == "moe_router"][0]
+    assert router.attr("norm_epsilon") == pytest.approx(1e-6)
+    assert router.input("Bias")
+    rotary = [op for op in ops if op.type == "rotary_embedding"][0]
+    assert rotary.attr("theta") == 1e6 and not rotary.attr("interleaved")
+    head = [op for op in ops if op.type == "matmul"]
+    assert len(head) == 1 and head[0].input("Y") == ["embed_tokens.w_0"] \
+        and head[0].attr("transpose_Y")
+    names = {p.name for p in main.all_parameters()}
+    assert "layer_0_conv.w_0" in names and "layer_1_attn_q_norm.w_0" in names
+    assert "layer_0_mlp_gate.w_0" in names and "lm_head.w_0" not in names
+    assert main.global_block().var("short_conv_tokens").persistable
+    with pytest.raises(ValueError):
+        models.DecoderLMConfig(layer_types=["conv"], num_hidden_layers=2,
+                               num_experts=8)
+    with pytest.raises(ValueError):
+        models.DecoderLMConfig(layer_types=["conv"], num_experts=8,
+                               num_dense_layers=1, first_k_dense_replace=2)

@@ -522,6 +522,7 @@ RECIPES = {
 # generic one-op builder here cannot: entry -> where the coverage lives.
 COVERED = {
     "causal_conv1d": "tests/test_decoder_lm.py (forward and the three gradients against the reference's convolution, with and without bias; causality)",
+    "gated_short_conv": "tests/test_decoder_lm.py (hand-written grad: the output, dX over its three thirds and dWeight against jax.grad of the reference's function, lowered and through the two Pallas kernels across block boundaries; causality)",
     "gated_rms_norm": "tests/test_decoder_lm.py (forward and the three gradients against the reference's group norm, one group and four)",
     "mamba2_ssd": "tests/test_decoder_lm.py, tests/test_kernels.py (hand-written grad: y and all seven gradients against the token-by-token recurrence, lowered and through the Pallas kernels, a ragged tail, B > 1)",
     "relu2": "tests/test_decoder_lm.py (forward and gradient by hand)",

@@ -376,6 +376,108 @@ def test_grouped_matmul_kernels_take_an_expert_width_of_1856(
         h.startswith("moe_grouped_matmul_" + which) for h in heads), heads
 
 
+def test_flash_kernels_compile_with_packed_heads_over_shared_key_heads(
+        one_chip, no_compile_cache):
+    """32 query heads over 8 key / value heads of 64, B=1 S=8192 (the
+    lfm2_s8192 cell's attention): two query heads a 128-lane block, both
+    reading one key head, which is one half of a key lane block picked by
+    the grid step. Mosaic takes the forward and the FUSED backward under
+    their names, k and v are never expanded to 32 heads (the custom
+    calls read bf16[1,8192,512]), and dk / dv leave at 8 heads."""
+    fa = _flash_module()
+    s = 8192
+    q = ((1, s, 32, 64), jnp.bfloat16)
+    kv = ((1, s, 8, 64), jnp.bfloat16)
+    shapes = {}
+    plan = fa._Plan("bshd", 1, 32, s, s, 64, BLOCK_Q, BLOCK_K, 64, 8)
+    assert plan.hpb == 2 and plan.group == 4 and plan.packed_shared
+
+    def fwd_bwd(q, k, v, g):
+        out, lse = fa._fa_forward(q, k, v, None, 64 ** -0.5, BLOCK_Q,
+                                  BLOCK_K, return_lse=True,
+                                  layout="bshd", causal=True)
+        dq, dk, dv, _ = fa._fa_backward(q, k, v, None, out, lse, g,
+                                        64 ** -0.5, BLOCK_Q, BLOCK_K,
+                                        layout="bshd", causal=True)
+        shapes.update(dq=dq.shape, dk=dk.shape, dv=dv.shape)
+        return dq, dk, dv
+
+    from paddle_tpu.kernels import registry
+    registry.reset_stats()
+    text = _compiled_text(fwd_bwd, one_chip, q, kv, kv, q)
+    heads = _custom_call_heads(text)
+    assert _stems(heads) == {"flash_attention_fwd",
+                             "flash_attention_dkv"}, heads
+    assert len(heads) == 2, heads
+    assert shapes == {"dq": (1, s, 32, 64), "dk": (1, s, 8, 64),
+                      "dv": (1, s, 8, 64)}
+    took = registry.dispatch_stats()["per_kernel"]["flash_attention"]
+    assert took == {"fused_bwd": 1}
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert all("bf16[1,8192,512]" in l for l in calls), calls
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_short_conv_kernels_compile_under_their_names(
+        one_chip, no_compile_cache, which, monkeypatch):
+    """`gated_short_conv_fwd` / `_bwd` at the lfm2_s8192 cell's sizes (one
+    sequence of 8,192 tokens, D 2048, three taps, bfloat16): one custom
+    call each; the backward writes dX [1, 8192, 6144] whole (no three
+    thirds concatenated after it) and the filter's gradient in
+    float32."""
+    from paddle_tpu.kernels import registry
+    from paddle_tpu.kernels import short_conv as sc
+    monkeypatch.setattr(registry, "interpret", lambda: False)
+    x, w = ((1, 8192, 6144), jnp.bfloat16), ((2048, 3), jnp.float32)
+    assert sc._eligible(registry.signature(
+        "gated_short_conv", jnp.zeros(x[0], x[1]), jnp.zeros(w[0], w[1])))
+    if which == "fwd":
+        text = _compiled_text(lambda x, w: sc.conv(x, w, True), one_chip,
+                              x, w)
+    else:
+        text = _compiled_text(
+            lambda x, w, g: sc.conv_grad(x, w, g, True), one_chip, x, w,
+            ((1, 8192, 2048), jnp.bfloat16))
+        assert "concatenate" not in text
+    heads = _custom_call_heads(text)
+    assert [h.split(".")[0] for h in heads] == \
+        ["gated_short_conv_" + which], heads
+
+
+@pytest.mark.parametrize("which", ["fwd", "dx", "dw"])
+def test_grouped_matmul_kernels_take_an_expert_width_of_1536(
+        one_chip, no_compile_cache, which, monkeypatch):
+    """The three kernels at the lfm2_s8192 cell's expert: 8 held of 64,
+    top-4 over 8,192 tokens, matrices of 2048 x 1536 (12 x 128)."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+    from paddle_tpu.kernels import registry
+    monkeypatch.setattr(registry, "interpret", lambda: False)
+    held, d, f = 8, 2048, 1536
+    rows = gm.prefix_rows(8192 * 4, held, 64)[0]
+    assert gm._eligible(registry.signature(
+        "moe_experts", jnp.zeros((rows, d), jnp.bfloat16),
+        jnp.zeros((held, d, f), jnp.bfloat16)))
+    tiles = ((rows // gm.TILE_ROWS,), jnp.int32)
+    wide, narrow = ((rows, d), jnp.bfloat16), ((rows, f), jnp.bfloat16)
+    up = ((held, d, f), jnp.bfloat16)
+
+    def run(fn):
+        return lambda te, na, a, b: fn(a, b, {"tile_expert": te,
+                                              "n_active": na})
+    if which == "fwd":
+        fn, shapes = run(lambda x, w, p: gm.gmm(x, w, p, True)), (wide, up)
+    elif which == "dx":
+        fn = run(lambda dy, w, p: gm.gmm_dx(dy, w, p, True))
+        shapes = (narrow, up)
+    else:
+        fn = run(lambda x, dy, p: gm.gmm_dw(x, dy, p, held, True))
+        shapes = (wide, narrow)
+    heads = _custom_call_heads(_compiled_text(
+        fn, one_chip, tiles, ((1,), jnp.int32), *shapes))
+    assert len(heads) == 1 and heads[0].startswith(
+        "moe_grouped_matmul_" + which), heads
+
+
 @pytest.mark.parametrize("cell", ["kanana2_s4096", "twotower_s4096"])
 def test_expert_layer_compiles_with_the_combine_kernel(
         one_chip, no_compile_cache, cell, monkeypatch):
@@ -429,7 +531,8 @@ def test_no_other_kernel_reads_as_flash_or_adam():
     names = ["fused_sgd", "quantized_matmul", "moe_grouped_matmul_fwd",
              "moe_grouped_matmul_dx", "moe_grouped_matmul_dw",
              "sparse_index_scores", "sparse_index_select",
-             "mamba2_ssd_fwd", "mamba2_ssd_bwd", "moe_combine"] + [
+             "mamba2_ssd_fwd", "mamba2_ssd_bwd", "moe_combine",
+             "gated_short_conv_fwd", "gated_short_conv_bwd"] + [
         f"tuned_matmul_{v.epilogue}_{v.bm}x{v.bn}x{v.bk}"
         for v in variants.enumerate_variants()]
     for n in names:
