@@ -8,15 +8,15 @@ custom-kernel slot the reference's Xbyak JIT tier fills on x86
 
 * forward: online-softmax over KV blocks so the [Sq, Sk] score matrix
   never materializes in HBM — O(S) memory, QK^T and PV on the MXU from
-  VMEM tiles; optionally emits logsumexp (lane-broadcast to 128 wide,
-  the native TPU layout for per-row scalars).
+  VMEM tiles; optionally emits logsumexp, one float32 a row (lane-
+  broadcast only inside VMEM: `_Plan.lse_spec`).
 * backward: one fused kernel that consumes the saved (out, lse)
   residuals, recomputes the probability tile p = exp(s - lse) per block
   — the [Sq, Sk] matrix again never hits HBM — and writes dq, dk and dv
   from that single pass over the score tiles, the whole dq of one
   (batch, head group) resident in VMEM. di = sum(dO*O) is recomputed
-  per block from the out/do streams (VPU work) instead of a
-  lane-broadcast HBM tensor. A dq kernel and a dk/dv kernel run as a
+  per block from the out/do streams (VPU work) instead of a second
+  per-row HBM tensor. A dq kernel and a dk/dv kernel run as a
   split pair only where the fused one cannot (_fa_backward decides, by
   shape): with an additive bias that needs a gradient, where the dq
   kernel also emits the ds tile (dbias IS ds summed over broadcast
@@ -149,11 +149,9 @@ def _tile_keep(plan, seed_ref, bh, q_idx, kv_idx, t):
 
 
 def _dims(q, layout):
-    if layout == "bshd":
-        B, S, H, D = q.shape
-        return B, H, S, D
-    B, H, S, D = q.shape
-    return B, H, S, D
+    """(B, H, S, D) of a q / k / v in either layout."""
+    a, b, c, d = q.shape
+    return (a, c, b, d) if layout == "bshd" else (a, b, c, d)
 
 
 def _seq_len(x, layout):
@@ -211,6 +209,7 @@ class _Plan:
         # q and k are D wide, v (and so out, do, dv) Dv wide
         self.Dv = D if Dv is None else Dv
         self.bq, self.bk = bq, bk
+        self.bqp = -(-bq // 128) * 128     # a q block's lanes of an lse
         if layout == "bshd":
             self.hpb = _heads_per_block(H, D, self.Dv)
             self.Hg = H // self.hpb
@@ -250,7 +249,7 @@ class _Plan:
 
     def row_spec(self, blk, width_per_head, which_axis, idx=None,
                  shared=False):
-        """Spec for a q/k/v/out/do/lse tensor: [blk rows x
+        """Spec for a q/k/v/out/do tensor: [blk rows x
         hpb*width_per_head lanes]. which_axis = grid position of the
         sequence index; idx (callable(g) -> index) overrides it — the
         causal path clamps the masked-out tail of a sequential axis to
@@ -269,14 +268,29 @@ class _Plan:
             return (g[0] // per if per > 1 else g[0], get(g), 0)
         return pl.BlockSpec((None, blk, width_per_head), index_map)
 
-    def wide_shape(self, S):
-        """lse carrier: per-row f32 lane-broadcast to 128 per head."""
-        if self.layout == "bshd":
-            return (self.B, S, self.Hg * self.hpb * 128)
-        return (self.B * self.H, S, 128)
+    # A site's per-row float32 vectors (lse, its cotangent) cross the
+    # kernel boundary with ONE number a lane: [B*H/hpb, hpb, Sq], a free
+    # reshape of [B, H, Sq], in blocks of (hpb, bq) whose second-minor
+    # dimension is the array's whole one (Mosaic's (8, 128) block rule);
+    # a bq off the 128 lanes pads each q block to whole tiles (bqp).
 
-    def wide_spec(self, blk, which_axis, idx=None):
-        return self.row_spec(blk, 128, which_axis, idx=idx)
+    def lse_shape(self):
+        return (self.B * self.H // self.hpb, self.hpb,
+                self.Sq // self.bq * self.bqp)
+
+    def lse_rows(self, x):
+        """[B, H, Sq] -> the form handed to pallas_call."""
+        x = jnp.pad(x.astype(jnp.float32).reshape(-1, self.bq),
+                    ((0, 0), (0, self.bqp - self.bq)))
+        return x.reshape(self.lse_shape())
+
+    def lse_spec(self, which_axis, idx=None):
+        """The ref is [hpb, bqp]: local head i's numbers along row i."""
+        get = (lambda g: g[which_axis]) if idx is None else idx
+        bshd = self.layout == "bshd"
+        return pl.BlockSpec(
+            (None, self.hpb, self.bqp),
+            lambda *g: (g[0] * self.Hg + g[1] if bshd else g[0], 0, get(g)))
 
     def bias_info(self, bias):
         """Returns (reshaped_bias, spec_factory, per_head, per_q).
@@ -430,6 +444,39 @@ def _causal_mask_dense(s):
     return jnp.where(rows >= cols, s, _NEG_INF)
 
 
+def _eye(n):
+    """The first n rows of the [128, 128] diagonal mask: what keeps only
+    it, summed over lanes, is each row's own number; exact, the sum adds
+    zeros to one number (as `kernels/mamba2_ssd.py` `_to_col`)."""
+    return jax.lax.broadcasted_iota(jnp.int32, (n, 128), 0) \
+        == jax.lax.broadcasted_iota(jnp.int32, (n, 128), 1)
+
+
+def _to_lanes(ref, i, col):
+    """Store a [bq, 128] lane-broadcast per-row value as row i of a
+    `_Plan.lse_spec` ref. Every lane holds its row's number, so lanes
+    8j .. 8j+7 of a 128-row chunk's [8, 128] pick come from its j-th 8
+    rows, and sublane l % 8 of lane l is row l's (selects and one sum
+    with zeros: exact; lanes past a short chunk's rows are padding)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+    mine = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0) == lane % 8
+    group = lane // 8
+    for c in range(0, col.shape[0], 128):
+        pick = col[c:c + 8]
+        for r in range(c + 8, min(c + 128, col.shape[0]), 8):
+            pick = jnp.where(group == (r - c) // 8, col[r:r + 8], pick)
+        ref[i:i + 1, c:c + 128] = jnp.sum(jnp.where(mine, pick, 0.0),
+                                          axis=0, keepdims=True)
+
+
+def _to_rows(ref, i, bq):
+    """Row i of a `_Plan.lse_spec` ref as a [bq, 1] column."""
+    wide = jnp.concatenate(
+        [jnp.where(_eye(min(128, bq - c)), ref[i:i + 1, c:c + 128], 0.0)
+         for c in range(0, bq, 128)], axis=0)
+    return jnp.sum(wide, axis=1, keepdims=True)
+
+
 def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
                lse_ref, m_scr, l_scr, acc_scr, *, scale, n_kv,
                q_axis, kv_axis, causal, drop_t):
@@ -500,10 +547,8 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
             plan.store_lanes(o_ref, i, Dv,
                              (acc_scr[i] / denom).astype(o_ref.dtype))
             if lse_ref is not None:
-                plan.store_lanes(
-                    lse_ref, i, 128,
-                    (m_scr[i] + jnp.log(jnp.maximum(
-                        l_scr[i], 1e-30))).astype(lse_ref.dtype))
+                _to_lanes(lse_ref, i, m_scr[i] + jnp.log(
+                    jnp.maximum(l_scr[i], 1e-30)))
 
 
 def _bwd_tile(plan, i, q_idx, kv_idx, bh, seed_ref, q_ref, k_ref, v_ref,
@@ -518,11 +563,11 @@ def _bwd_tile(plan, i, q_idx, kv_idx, bh, seed_ref, q_ref, k_ref, v_ref,
     k = plan.lanes(k_ref, i, D) if slot is None \
         else plan.shared_kv(slot, k_ref, D)         # [bk, D]
     do = plan.lanes(do_ref, i, Dv)                  # [bq, Dv]
-    lse = plan.lanes(lse_ref, i, 128)[:, :1]        # [bq, 1]
+    lse = _to_rows(lse_ref, i, bq)                  # [bq, 1]
     di = jnp.sum(plan.lanes(out_ref, i, Dv).astype(jnp.float32)
                  * do.astype(jnp.float32), axis=-1, keepdims=True)
     if glse_ref is not None:
-        di = di - plan.lanes(glse_ref, i, 128)[:, :1]
+        di = di - _to_rows(glse_ref, i, bq)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
@@ -692,8 +737,8 @@ def _seed_i32(dropout):
 
 
 def _fa_forward(q, k, v, bias, scale, block_q, block_k,
-                return_lse=False, layout="bhsd", raw_lse=False,
-                causal=False, dropout=None):
+                return_lse=False, layout="bhsd", causal=False,
+                dropout=None):
     B, H, Sq, D = _dims(q, layout)
     Dv = v.shape[3]
     Sk = _seq_len(k, layout)
@@ -725,13 +770,11 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
         plan.row_spec(bk, Dv, ka, idx=k_idx, shared=True),
     ]
     args = [plan.rows(q), plan.rows(k), plan.rows(v)]
-    if bias is not None:
+    has_bias = bias is not None
+    if has_bias:
         br, bfac, _, _ = plan.bias_info(bias)
         in_specs.append(bfac(qa, ka, k_idx=k_idx))
         args.append(br)
-        has_bias = True
-    else:
-        has_bias = False
     if has_drop:
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         args.append(seed)
@@ -741,8 +784,8 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
     out_specs = [plan.row_spec(bq, Dv, qa)]
     out_shape = [_sds(out_rows, q.dtype)]
     if return_lse:
-        out_specs.append(plan.wide_spec(bq, qa))
-        out_shape.append(_sds(plan.wide_shape(Sq), jnp.float32))
+        out_specs.append(plan.lse_spec(qa))
+        out_shape.append(_sds(plan.lse_shape(), jnp.float32))
 
     def kern(*refs):
         i = 3
@@ -778,39 +821,17 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
         interpret=_INTERPRET,
     )(*args)
 
-    def _out(o):
-        if layout == "bshd":
-            return o.reshape(B, Sq, H, Dv)
-        return o.reshape(B, H, Sq, Dv)
-
+    shape = (B, Sq, H, Dv) if layout == "bshd" else (B, H, Sq, Dv)
     if return_lse:
-        out, lse_w = res
-        if raw_lse:
-            # wide carrier form, handed straight back to _fa_backward
-            # (skips a narrow->re-widen round trip)
-            return _out(out), lse_w
-        if layout == "bshd":
-            narrow = lse_w.reshape(B, Sq, H, 128)[..., 0]
-            return _out(out), jnp.moveaxis(narrow, 1, 2)   # [B,H,Sq]
-        return _out(out), lse_w[:, :, 0].reshape(B, H, Sq)
-    return _out(res)
+        # float32 [B, H, Sq] in either layout
+        return res[0].reshape(shape), res[1].reshape(
+            -1, plan.bqp)[:, :bq].reshape(B, H, Sq)
+    return res.reshape(shape)
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-
-def _widen(x_bhs, plan):
-    """Narrow [B,H,S] f32 -> the plan's wide lse carrier."""
-    B, H, Sq = plan.B, plan.H, plan.Sq
-    if plan.layout == "bshd":
-        x = jnp.moveaxis(x_bhs.reshape(B, H, Sq), 1, 2)   # [B,S,H]
-        return jnp.broadcast_to(
-            x[..., None], (B, Sq, H, 128)).reshape(
-                plan.wide_shape(Sq))
-    x = x_bhs.reshape(B * H, Sq)
-    return jnp.broadcast_to(x[..., None], (B * H, Sq, 128))
-
 
 # The fused backward holds one (batch, head group)'s whole dq in VMEM.
 # A v5e core has 128 MiB of it, of which Mosaic gives one kernel 16 MiB
@@ -830,8 +851,8 @@ def _resident_dq_bytes(plan, dtype):
 
 
 def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
-                 g_lse=None, layout="bhsd", lse_wide=False,
-                 want_dbias=None, causal=False, dropout=None):
+                 g_lse=None, layout="bhsd", want_dbias=None,
+                 causal=False, dropout=None):
     """Kernel-path backward: returns (dq, dk, dv, dbias?).
 
     One fused kernel builds each (q block, kv block) tile's s, p, dp and
@@ -839,11 +860,11 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     kernel over a (q, kv) grid, then the dk/dv kernel) runs only where
     the fused one cannot: a demanded dbias (the ds output follows the
     dq-style grid) or a dq too long to stay in VMEM. Which of the two a
-    call site took is counted as `fused_bwd` / `split_bwd`.
+    call site took is counted as `fused_bwd` / `split_bwd`, and every
+    call as `narrow_lse`.
 
-    lse arrives either in its wide carrier form straight from the
-    forward kernel (lse_wide=True) or narrow [B,H,Sq]. g_lse (per-row
-    lse cotangent, [B,H,Sq]) folds into the di term inside the kernels:
+    lse is the forward's float32 [B, H, Sq]; g_lse (per-row lse
+    cotangent, likewise) folds into the di term inside the kernels:
     ds = p*(dp - (di - g_lse)).
 
     want_dbias=False suppresses the ds OUTPUT while still adding the
@@ -858,13 +879,11 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     n_q = Sq // bq
     n_kv = Sk // bk
     plan = _Plan(layout, B, H, Sq, Sk, D, bq, bk, Dv, _heads(k, layout))
-    lse_w = lse if lse_wide else _widen(lse.astype(jnp.float32), plan)
-    args = [plan.rows(q), plan.rows(k), plan.rows(v), lse_w,
+    args = [plan.rows(q), plan.rows(k), plan.rows(v), plan.lse_rows(lse),
             plan.rows(out), plan.rows(g)]
     has_glse = g_lse is not None
     if has_glse:
-        args.append(_widen(
-            g_lse.reshape(B, H, Sq).astype(jnp.float32), plan))
+        args.append(plan.lse_rows(g_lse))
     has_bias = bias is not None
     if has_bias:
         # bias always feeds the score recompute; ds is emitted ONLY
@@ -880,6 +899,7 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     resident = _resident_dq_bytes(plan, q.dtype)
     fused = not want_dbias and resident <= _FUSED_DQ_VMEM_BUDGET
     _kreg.count("flash_attention", "fused_bwd" if fused else "split_bwd")
+    _kreg.count("flash_attention", "narrow_lse")
 
     def _sds(shape, dtype):
         return _out_struct(shape, dtype, like=q)
@@ -899,12 +919,12 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
             plan.row_spec(bq, D, qa, idx=q_idx),
             plan.row_spec(bk, D, ka, idx=k_idx, shared=True),
             plan.row_spec(bk, Dv, ka, idx=k_idx, shared=True),
-            plan.wide_spec(bq, qa, idx=q_idx),
+            plan.lse_spec(qa, idx=q_idx),
             plan.row_spec(bq, Dv, qa, idx=q_idx),
             plan.row_spec(bq, Dv, qa, idx=q_idx),
         ]
         if has_glse:
-            specs.append(plan.wide_spec(bq, qa, idx=q_idx))
+            specs.append(plan.lse_spec(qa, idx=q_idx))
         if has_bias:
             specs.append(bfac(qa, ka, q_idx=q_idx, k_idx=k_idx))
         if has_drop:
@@ -1202,11 +1222,9 @@ def flash_attention(q, k, v, bias=None, scale=1.0, block_q=128,
 def _fa_fwd(q, k, v, bias, scale, block_q, block_k, layout, causal,
             need_dbias):
     if _kernel_ok(q, k, block_q, block_k, layout, v):
-        # lse residual stays in the kernel's wide carrier layout;
-        # _kernel_ok is static, so _fa_bwd re-derives the same branch
         out, lse = _fa_forward(q, k, v, bias, scale, block_q, block_k,
                                return_lse=True, layout=layout,
-                               raw_lse=True, causal=causal)
+                               causal=causal)
     else:
         qb, kb, vb = q, k, v
         if layout == "bshd":
@@ -1226,8 +1244,7 @@ def _fa_bwd(scale, block_q, block_k, layout, causal, need_dbias, res,
     if use_kernel_path(q, k, block_q, block_k, layout, v):
         dq, dk, dv, dbias = _fa_backward(
             q, k, v, bias, out, lse, g, scale, block_q, block_k,
-            layout=layout, causal=causal, want_dbias=want_dbias,
-            lse_wide=_kernel_ok(q, k, block_q, block_k, layout, v))
+            layout=layout, causal=causal, want_dbias=want_dbias)
         return dq, dk, dv, dbias if want_dbias else None
 
     def f(q, k, v, bias):
@@ -1273,7 +1290,7 @@ def _fal_bwd(scale, block_q, block_k, res, g):
     if use_kernel_path(q, k, block_q, block_k):
         # the lse cotangent folds into the per-row correction term:
         # dlse/ds = p, so ds = p*(dp - di + g_lse) — the kernels
-        # subtract the widened g_lse from di
+        # subtract g_lse from di
         dq, dk, dv, dbias = _fa_backward(
             q, k, v, bias, out, lse, g_out, scale, block_q, block_k,
             g_lse=g_lse)

@@ -404,12 +404,12 @@ def dropout_mask_identity() -> Dict[str, Any]:
     @jax.jit
     def fwd(v, bias):
         return fa._fa_forward(z, z, v, bias, 1.0, bq, bk,
-                              return_lse=True, raw_lse=True, **drop)
+                              return_lse=True, **drop)
 
     @jax.jit
     def dv_of(out, lse, do):
         return fa._fa_backward(z, z, z, None, out, lse, do, 1.0, bq,
-                               bk, lse_wide=True, **drop)[2]
+                               bk, **drop)[2]
 
     with _fa_kernels_live(fa):
         m_fwd = np.zeros((H, S, S))
@@ -439,7 +439,7 @@ def dropout_mask_identity() -> Dict[str, Any]:
         out, lse = fwd(v, bias_h)
         _, _, _, dbias = fa._fa_backward(
             z, z, v, bias_h, out, lse, jnp.ones_like(v), 1.0, bq, bk,
-            lse_wide=True, want_dbias=True, **drop)
+            want_dbias=True, **drop)
     ds = np.asarray(dbias)[0]
     w = np.asarray(v.sum(-1))[0]
     di = np.asarray(out.sum(-1))[0]

@@ -253,7 +253,7 @@ def count(name: str, outcome: str) -> None:
     (eligibility or backend said no), ``denied`` (flag/deny list said
     no) — or what a kernel says of the call it then made (the fused
     optimizer's ``native_view`` / ``flat_view``, the flash backward's
-    ``fused_bwd`` / ``split_bwd``), which
+    ``fused_bwd`` / ``split_bwd`` and ``narrow_lse``), which
     :func:`dispatch_stats` lists per kernel and leaves out of
     ``decisions`` and ``hit_rate``.
     """

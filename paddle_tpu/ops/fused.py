@@ -66,14 +66,6 @@ def _attn_args(ctx):
     return q, k, v, bias, layout, scale, bq, bk, drop, causal
 
 
-def _rows(x, layout):
-    """[B, S, H, D] / [B, H, S, D] as the kernels' row form (a free
-    reshape): [B, S, H*D] / [B*H, S, D]."""
-    if layout == "bshd":
-        return x.reshape(x.shape[0], x.shape[1], -1)
-    return x.reshape(-1, *x.shape[2:])
-
-
 @register_op("fused_attention", intermediate_outputs=("SoftmaxLse",))
 def fused_attention(ctx):
     """Q/K/V: [B, H, S, D] (layout "bhsd") or [B, S, H, D] ("bshd"); K
@@ -91,9 +83,8 @@ def fused_attention(ctx):
     blocks and elide their DMA).
     SoftmaxLse (intermediate, float32 [B, H, Sq]): the softmax
     log-sum-exp the forward kernel wrote, carried to the grad op so the
-    backward kernels need no second forward. Narrow on purpose: the
-    kernel's lane-broadcast carrier is 128x this (64 MiB a site at B=4
-    S=4096) and would live from forward to backward. Only the kernel
+    backward kernels need no second forward. The kernels write and
+    read it in this form themselves (docs/KERNELS.md). Only the kernel
     path in training writes a real value; everywhere else it is zeros
     nothing reads."""
     from ..kernels.flash_attention import (
@@ -109,7 +100,7 @@ def fused_attention(ctx):
         # long-context regime: Pallas flash kernels, O(S) HBM
         if ctx.attr("is_test", False):
             # inference: no grad op will consume lse — skip the
-            # un-DCE-able wide-lse output entirely
+            # un-DCE-able lse output entirely
             out = _fa_forward(q, k, v, bias, scale, bq, bk,
                               layout=layout, causal=causal)
         else:
@@ -119,17 +110,6 @@ def fused_attention(ctx):
             out, lse = _fa_forward(q, k, v, bias, scale, bq, bk,
                                    return_lse=True, layout=layout,
                                    causal=causal, dropout=drop)
-            # Left alone, XLA fuses the narrowing slice into the
-            # backward's widening broadcast and keeps the kernel's wide
-            # carrier alive from forward to backward (+0.36 GiB peak at
-            # B=4 S=4096, 18 sites; PERF.md PR 27). Tied to out, the
-            # narrow value must exist before anything reads out, so the
-            # carrier dies here. out passes the barrier in the kernel's
-            # own row form: as [B, S, H, D] XLA gave it another layout
-            # and kept a second copy of out per site.
-            rows, lse = jax.lax.optimization_barrier(
-                (_rows(out, layout), lse))
-            out = rows.reshape(out.shape)
     else:
         # shape-bounded regime / CPU / odd shapes: XLA's fully-fused
         # composed formulation is faster while [Sq,Sk] fits (see the
@@ -181,20 +161,17 @@ def fused_attention_grad(ctx):
             # dtype, so casting back is exact
             out = ctx.env[op.input("Out")[0]].astype(q.dtype)
             lse = ctx.env[op.input("SoftmaxLse")[0]]
-            lse_wide = False
         else:
             # the one remaining recompute: SoftmaxLse unbound (a
             # program serialised before the slot existed, a hand-built
             # op desc) or a forward at is_test, which wrote no lse
             out, lse = _fa_forward(q, k, v, bias, scale, bq, bk,
                                    return_lse=True, layout=layout,
-                                   raw_lse=True, causal=causal,
-                                   dropout=drop)
-            lse_wide = True
+                                   causal=causal, dropout=drop)
         dq, dk, dv, dbias = _fa_backward(
             q, k, v, bias, out, lse, dout.astype(q.dtype), scale, bq,
-            bk, layout=layout, lse_wide=lse_wide,
-            want_dbias=_bound("BiasQK"), causal=causal, dropout=drop)
+            bk, layout=layout, want_dbias=_bound("BiasQK"),
+            causal=causal, dropout=drop)
     else:
         def f(q, k, v, bias):
             return _attn_reference(q, k, v, bias, scale,

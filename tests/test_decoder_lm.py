@@ -703,7 +703,7 @@ def test_flash_kernels_take_fewer_key_heads_and_a_keep_mask(
     _close(dk, rk, 1e-5)
     _close(dv, rv, 1e-5)
     took = kreg.dispatch_stats()["per_kernel"]["flash_attention"]
-    assert took == {backward + "_bwd": 1}
+    assert took == {backward + "_bwd": 1, "narrow_lse": 1}
 
 
 def test_fused_attention_op_takes_fewer_key_heads_and_a_mask(interp):
@@ -1573,17 +1573,21 @@ def test_flash_kernels_take_packed_heads_over_shared_key_heads(causal,
 
 
 @pytest.mark.parametrize("site,digest", [
-    ((8, 8, 64), "caf446f82563111e92a2b02a16c3d96deaafd1e6c0c89e5c5a79cbe1e"
-                 "02e9fbe"),
-    ((8, 2, 128), "17b62eef7b2c7227496372ff14bb78b2eee376a4eb0872da7f3bde13"
-                  "5a7b567e")], ids=["packed_ungrouped", "grouped_at_128"])
+    ((8, 8, 64), "8b2cfa4b881b60645d51fd6cff84208e3f6a5ddd04ae04503579ca841c"
+                 "b79cd9"),
+    ((8, 2, 128), "e04085923dd4c58719f6c43bdb5b7322c1ea485a175d6f7d89d85b92"
+                  "fe5b631c")], ids=["packed_ungrouped", "grouped_at_128"])
 def test_unchanged_flash_sites_trace_to_the_parents_kernels(site, digest,
                                                             interp):
     """The sites the accepted cells have — packed heads with as many key
     heads (tbase_s4096), fewer key heads at one head a lane block
-    (keye2_s8192, twotower_s4096) — trace to the jaxpr the parent of
-    PR 39 traced, kernel bodies included (sha256 of the text, source
-    positions taken out): the same kernels, so bit-equal results."""
+    (keye2_s8192, twotower_s4096) — trace to the jaxpr PR 40 left,
+    kernel bodies included (sha256 of the text, source positions taken
+    out). PR 40 changed the bodies (the lse crosses one number a row) and
+    renewed the digests on a record that Out, lse, dQ, dK and dV stayed
+    the same bits on the chip (PERF.md §6, PR 40): a PR that means to
+    leave these sites alone keeps the digests, one that changes them
+    brings such a record."""
     import hashlib
     import re
     h, hkv, d = site
@@ -1595,10 +1599,9 @@ def test_unchanged_flash_sites_trace_to_the_parents_kernels(site, digest,
     def f(q, k, v, g):
         out, lse = fa._fa_forward(q, k, v, None, d ** -0.5, 128, 128,
                                   return_lse=True, layout="bshd",
-                                  causal=True, raw_lse=True)
+                                  causal=True)
         return fa._fa_backward(q, k, v, None, out, lse, g, d ** -0.5, 128,
-                               128, layout="bshd", lse_wide=True,
-                               causal=True)[:3]
+                               128, layout="bshd", causal=True)[:3]
     text = re.sub(r" at \S+:\d+", "", str(jax.make_jaxpr(f)(q, k, k, q)))
     assert hashlib.sha256(text.encode()).hexdigest() == digest
 

@@ -278,11 +278,9 @@ def test_causal_dbias_zero_store():
     g = _rand(rng, B, H, S, D)
 
     out, lse = fa._fa_forward(q, k, v, bias, scale, 128, 128,
-                              return_lse=True, raw_lse=True,
-                              causal=True)
+                              return_lse=True, causal=True)
     dq, dk, dv, dbias = fa._fa_backward(
-        q, k, v, bias, out, lse, g, scale, 128, 128, lse_wide=True,
-        want_dbias=True, causal=True)
+        q, k, v, bias, out, lse, g, scale, 128, 128, want_dbias=True, causal=True)
 
     def ref(q, k, v, bias):
         return (fa._attn_reference(q, k, v, bias, scale, causal=True)
@@ -319,13 +317,12 @@ def test_dropout_kernel_fwd_bwd_consistent(layout, causal):
 
     q, k, v = to_layout(qb), to_layout(kb), to_layout(vb)
     out, lse = fa._fa_forward(q, k, v, None, scale, 128, 128,
-                              return_lse=True, raw_lse=True,
-                              layout=layout, causal=causal,
+                              return_lse=True, layout=layout, causal=causal,
                               dropout=(key, t))
     dq, dk, dv, _ = fa._fa_backward(
         q, k, v, None, out, lse, g if layout == "bhsd"
         else jnp.moveaxis(g, 1, 2), scale, 128, 128, layout=layout,
-        lse_wide=True, causal=causal, dropout=(key, t))
+        causal=causal, dropout=(key, t))
 
     def ref(q, k, v):
         s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
@@ -432,40 +429,64 @@ def test_dispatch_is_sequence_keyed(monkeypatch):
 
 _B, _H, _S, _D = 1, 2, 128, 16
 
+# (query heads, key heads, q / k width, v width): the accepted cells'
+# site forms at fewer heads
+_PLAIN = (_H, _H, _D, _D)
+_HEAD_FORMS = {
+    "packed_64x2": (2, 2, 64, 64),              # tbase_s4096
+    "one_128": (2, 2, 128, 128),
+    "mla_192_128": (2, 2, 192, 128),            # kanana2_s4096
+    "grouped_8_1": (8, 1, 128, 128),            # keye2_s8192 (32 / 4)
+    "grouped_packed_8_2x64": (8, 2, 64, 64),    # lfm2_s8192 (32 / 8)
+}
 
-def _attn_shape(layout, S=_S):
-    return (_B, S, _H, _D) if layout == "bshd" else (_B, _H, S, _D)
+
+def _attn_shape(layout, S=_S, heads=_H, width=_D):
+    return (_B, S, heads, width) if layout == "bshd" \
+        else (_B, heads, S, width)
 
 
-def _attn_program(layout, mode, n_sites=1, bias_grad=False):
+def _site_shapes(layout, names, form, S=_S):
+    H, Hkv, D, Dv = form
+    return {n: _attn_shape(layout, S, H if n == "q" else Hkv,
+                           Dv if n[0] == "v" else D) for n in names}
+
+
+def _attn_program(layout, mode, n_sites=1, bias_grad=False, form=_PLAIN,
+                  S=_S, block=128, dtype="float32"):
     """layers.fused_attention + append_backward over `n_sites` chained
-    attentions; returns (main, feed names, grad names)."""
+    attentions; returns (main, feed names, grad names). mode "mask": an
+    int8 keep mask [B, 1, S, S]."""
     import paddle_tpu as fluid
     from paddle_tpu import layers
 
     fluid.framework.unique_name.reset()
     main = fluid.Program()
     with fluid.program_guard(main, fluid.Program()):
-        def data(name, shape):
+        def data(name, shape, dtype=dtype):
             var = layers.data(name=name, shape=list(shape),
-                              dtype="float32", append_batch_size=False)
-            var.stop_gradient = False
+                              dtype=dtype, append_batch_size=False)
+            var.stop_gradient = dtype == "int8"
             return var
 
         # a leaf shared by two ops would need its grads summed, which
         # append_backward does only for parameters: K/V per site
         names = ["q"] + [f"{n}{i}" for i in range(n_sites) for n in "kv"]
-        q, *kv = (data(n, _attn_shape(layout)) for n in names)
+        shapes = _site_shapes(layout, names, form, S)
+        q, *kv = (data(n, shapes[n]) for n in names)
         bias = None
         if mode == "padding":
-            bias = data("bias", (_B, 1, 1, _S))
+            bias = data("bias", (_B, 1, 1, S), "float32")
             bias.stop_gradient = not bias_grad
+            names.append("bias")
+        elif mode == "mask":
+            bias = data("bias", (_B, 1, S, S), "int8")
             names.append("bias")
         x = q
         for i in range(n_sites):
             x = layers.fused_attention(
-                x, kv[2 * i], kv[2 * i + 1], bias, block_q=128,
-                block_k=128, layout=layout, causal=(mode == "causal"))
+                x, kv[2 * i], kv[2 * i + 1], bias, block_q=block,
+                block_k=block, layout=layout, causal=(mode == "causal"))
         loss = layers.reduce_sum(layers.square(x))
         fluid.backward.append_backward(loss)
     grads = [n + "@GRAD" for n in names
@@ -473,35 +494,66 @@ def _attn_program(layout, mode, n_sites=1, bias_grad=False):
     return main, names, grads
 
 
-def _attn_feed(names, layout, seed=0):
+def _attn_feed(names, layout, seed=0, form=_PLAIN, S=_S, mode="padding"):
     rng = np.random.default_rng(seed)
-    feed = {n: rng.standard_normal(_attn_shape(layout)).astype("float32")
-            for n in names if n != "bias"}
-    if "bias" in names:
-        pad = np.zeros((_B, 1, 1, _S), "float32")
-        pad[..., _S - 32:] = -1e9
+    shapes = _site_shapes(layout, [n for n in names if n != "bias"], form,
+                          S)
+    feed = {n: rng.standard_normal(shape).astype("float32")
+            for n, shape in shapes.items()}
+    if "bias" in names and mode == "mask":
+        keep = rng.random((_B, 1, S, S)) < 0.5
+        feed["bias"] = (keep | np.eye(S, dtype=bool)).astype("int8")
+    elif "bias" in names:
+        pad = np.zeros((_B, 1, 1, S), "float32")
+        pad[..., S - 32:] = -1e9
         feed["bias"] = pad
     return feed
 
 
-def _pallas_calls(jaxpr, acc=None):
-    """name -> count of pallas_call equations, sub-jaxprs included."""
-    acc = {} if acc is None else acc
+def _equations(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs included; a Pallas call's
+    kernel body (VMEM refs and tiles, nothing in HBM) is not entered."""
     for eqn in jaxpr.eqns:
+        yield eqn
         if eqn.primitive.name == "pallas_call":
-            name = eqn.params["name"]
-            acc[name] = acc.get(name, 0) + 1
+            continue
         for p in eqn.params.values():
             for sub in (p if isinstance(p, (list, tuple)) else (p,)):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _pallas_calls(sub, acc)
+                    yield from _equations(sub)
+
+
+def _pallas_calls(jaxpr):
+    """name -> count of pallas_call equations, sub-jaxprs included."""
+    acc = {}
+    for eqn in _equations(jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            acc[name] = acc.get(name, 0) + 1
     return acc
 
 
-def _engine_step(main, feed, fetch):
+def _lane_broadcast_arrays(jaxpr, B, H, S):
+    """The float32 arrays of a jaxpr in the shape of the carrier the
+    kernels spoke before PR 40 (128 copies of each row's number:
+    [B, S, H*128] or [B*H, S, 128]), and its optimization barriers."""
+    wide = {(B, S, H * 128), (B * H, S, 128)}
+    found = []
+    for eqn in _equations(jaxpr):
+        if eqn.primitive.name == "optimization_barrier":
+            found.append("optimization_barrier")
+        for var in list(eqn.invars) + list(eqn.outvars):
+            aval = getattr(var, "aval", None)
+            if getattr(aval, "dtype", None) == jnp.float32 \
+                    and tuple(aval.shape) in wide:
+                found.append((eqn.primitive.name, tuple(aval.shape)))
+    return found
+
+
+def _engine_step(main, feed, fetch, jaxpr=False):
     """Run one step through Executor.run; (fetched values, the step's
-    Pallas calls by name)."""
+    Pallas calls by name, or with `jaxpr` the step's jaxpr)."""
     import paddle_tpu as fluid
     from paddle_tpu.core.engine import _scope_array
     from paddle_tpu.core.scope import Scope
@@ -520,11 +572,12 @@ def _engine_step(main, feed, fetch):
             {n: sig(_scope_array(scope, n)) for n in traced.const_names},
             {n: sig(a) for n, a in feed.items()},
             jax.ShapeDtypeStruct((2,), jnp.uint32)).jaxpr
-    return vals, _pallas_calls(closed.jaxpr)
+    return vals, closed.jaxpr if jaxpr else _pallas_calls(closed.jaxpr)
 
 
 def _reference_grads(feed, layout, mode, n_sites, names):
-    scale = float(_D) ** -0.5
+    qn = feed["q"]
+    scale = float(qn.shape[-1]) ** -0.5
 
     def loss(*args):
         a = dict(zip(names, args))
@@ -535,8 +588,9 @@ def _reference_grads(feed, layout, mode, n_sites, names):
                                    causal=(mode == "causal"))
         return (x ** 2).sum()
 
-    return jax.grad(loss, tuple(range(len(names))))(
-        *(jnp.asarray(feed[n]) for n in names))
+    floats = tuple(i for i, n in enumerate(names)
+                   if feed[n].dtype == np.float32)
+    return jax.grad(loss, floats)(*(jnp.asarray(feed[n]) for n in names))
 
 
 @pytest.mark.parametrize("mode", ["plain", "causal", "padding"])
@@ -555,6 +609,123 @@ def test_engine_step_runs_each_flash_kernel_once_per_site(layout, mode):
     for got, want in zip(vals, ref):
         np.testing.assert_allclose(got, np.asarray(want),
                                    atol=5e-4, rtol=5e-4)
+
+
+def _check_lse_and_grads(vals, feed, layout, mode, names):
+    """One site's fetched (grads..., SoftmaxLse) against the composed
+    formulation: the lse float32 [B, H, Sq] to 1e-6, the grads its vjp's."""
+    q, k, v = (jnp.asarray(feed[n]) for n in ("q", "k0", "v0"))
+    if layout == "bshd":
+        q, k, v = (jnp.moveaxis(x, 1, 2) for x in (q, k, v))
+    k, v = (jnp.repeat(x, q.shape[1] // k.shape[1], axis=1) for x in (k, v))
+    bias = feed.get("bias")
+    if mode == "mask":
+        bias = np.where(bias != 0, 0.0, fa._NEG_INF).astype("float32")
+    _, lse = fa._attn_reference_lse(q, k, v, bias, q.shape[-1] ** -0.5,
+                                    causal=(mode == "causal"))
+    assert vals[-1].shape == lse.shape and vals[-1].dtype == np.float32
+    np.testing.assert_allclose(vals[-1], np.asarray(lse), atol=1e-6,
+                               rtol=1e-6)
+    ref = _reference_grads(feed, layout, mode, 1, names)
+    for got, want in zip(vals[:-1], ref):
+        np.testing.assert_allclose(got, np.asarray(want),
+                                   atol=5e-4, rtol=5e-4)
+
+
+@pytest.mark.parametrize("form", list(_HEAD_FORMS))
+@pytest.mark.parametrize("mode", ["plain", "causal", "padding", "mask"])
+@pytest.mark.parametrize("layout", ["bhsd", "bshd"])
+def test_site_hands_its_lse_over_narrow(layout, mode, form):
+    """PR 40: at every site form the accepted cells have, the engine's
+    step holds one forward and one backward call, no float32 array in
+    the lane-broadcast carrier's shape, no optimization barrier; the
+    SoftmaxLse it carries is the composed formulation's to 1e-6 and the
+    grads are the composed vjp's."""
+    heads = _HEAD_FORMS[form]
+    main, names, grads = _attn_program(layout, mode, form=heads)
+    feed = _attn_feed(names, layout, seed=7, form=heads, mode=mode)
+    fwd = next(op for op in main.global_block().ops
+               if op.type == "fused_attention")
+    lse_name, = fwd.output("SoftmaxLse")
+    vals, jaxpr = _engine_step(main, feed, grads + [lse_name], jaxpr=True)
+    assert _pallas_calls(jaxpr) == {"flash_attention_fwd": 1,
+                                    "flash_attention_dkv": 1}
+    if 128 in heads:
+        # float32 q, v and out of 128-wide heads ARE [B, S, H*128]: read
+        # the step's arrays where, as in the cells, the streams are bf16
+        half = _attn_program(layout, mode, form=heads, dtype="bfloat16")[0]
+        _, jaxpr = _engine_step(
+            half, {n: a.astype(jnp.bfloat16) if a.dtype == np.float32
+                   and n != "bias" else a for n, a in feed.items()},
+            grads, jaxpr=True)
+    assert _lane_broadcast_arrays(jaxpr, _B, heads[0], _S) == []
+    _check_lse_and_grads(vals, feed, layout, mode, names)
+
+
+@pytest.mark.parametrize("block", [64, 96, 192])
+@pytest.mark.parametrize("mode", ["plain", "causal"])
+@pytest.mark.parametrize("layout", ["bhsd", "bshd"])
+def test_sequence_of_192_stays_on_the_kernels(layout, mode, block):
+    """A q block that is no multiple of 128 rows pads its lanes of the
+    narrow lse in the wrapper (block 64: three padded segments; 96: two;
+    192: one block whose second chunk holds 64 rows) and takes the same
+    kernels, under the interpreter."""
+    S, heads = 192, (2, 2, 64, 64)
+    main, names, grads = _attn_program(layout, mode, form=heads, S=S,
+                                       block=block)
+    feed = _attn_feed(names, layout, seed=8, form=heads, S=S)
+    fwd = next(op for op in main.global_block().ops
+               if op.type == "fused_attention")
+    lse_name, = fwd.output("SoftmaxLse")
+    vals, calls = _engine_step(main, feed, grads + [lse_name])
+    assert calls == {"flash_attention_fwd": 1, "flash_attention_dkv": 1}
+    _check_lse_and_grads(vals, feed, layout, mode, names)
+
+
+def _residual_shapes(f, *args):
+    """Shapes of the float32 residuals `jax.vjp(f, *args)` keeps."""
+    _, vjp = jax.vjp(f, *args)
+    return [tuple(x.shape) for x in jax.tree_util.tree_leaves(vjp)
+            if x.dtype == jnp.float32]
+
+
+@pytest.mark.parametrize("entry,layout", [
+    ("flash_attention", "bhsd"), ("flash_attention", "bshd"),
+    # flash_attention_lse is [B, H, S, D] only
+    ("lse_out_only", "bhsd"), ("lse_with_cotangent", "bhsd")])
+def test_custom_vjp_residuals_are_narrow(entry, layout):
+    """D9: what `flash_attention` and `flash_attention_lse` save for
+    their backward is the float32 [B, H, Sq] lse, never 128 copies of
+    it, with and without an lse cotangent; nothing of that shape is in
+    the jaxpr of their grads either."""
+    B, H, S, D = 1, 2, 256, 64
+    rng = np.random.default_rng(13)
+    q, k, v = (jnp.asarray(rng.standard_normal(
+        _attn_shape(layout, S, H, D)), jnp.bfloat16) for _ in range(3))
+
+    if entry == "flash_attention":
+        def f(q, k, v):
+            return fa.flash_attention(q, k, v, None, 0.125, 128, 128,
+                                      layout, True)
+
+        def loss(q, k, v):
+            return (f(q, k, v).astype(jnp.float32) ** 2).sum()
+    else:
+        def f(q, k, v):
+            return fa.flash_attention_lse(q, k, v, None, 0.125, 128, 128)
+
+        def loss(q, k, v):
+            out, lse = f(q, k, v)
+            total = (out.astype(jnp.float32) ** 2).sum()
+            if entry == "lse_with_cotangent":
+                total = total + jnp.sin(lse).sum()
+            return total
+
+    # q, k, v and out are bf16: the one float32 residual is the lse
+    assert _residual_shapes(f, q, k, v) == [(B, H, S)]
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v).jaxpr
+    assert _lane_broadcast_arrays(jaxpr, B, H, S) == []
+    assert sum(_pallas_calls(jaxpr).values()) == 2
 
 
 def _lower_ops(block, env, skip_slot=None):
@@ -582,10 +753,11 @@ def _lower_ops(block, env, skip_slot=None):
 @pytest.mark.parametrize("layout", ["bhsd", "bshd"])
 def test_carried_lse_grads_bit_identical_to_recompute(layout, mode,
                                                       bias_grad):
-    """(b) Lane 0 of the carrier -> narrow -> _widen is exact: the grads
-    from the carried pair equal the unbound-slot fallback's bit for bit.
-    (c) What is carried is float32 [B, H, Sq]; nothing of the carrier's
-    wide shape enters the grad op."""
+    """(b) The grads from the carried pair equal the unbound-slot
+    fallback's bit for bit: both hand the backward kernels the forward
+    kernel's own float32 [B, H, Sq].
+    (c) What is carried is that; nothing 128 times its size enters the
+    grad op."""
     main, names, grads = _attn_program(layout, mode,
                                        bias_grad=bias_grad)
     block = main.global_block()
@@ -607,12 +779,11 @@ def test_carried_lse_grads_bit_identical_to_recompute(layout, mode,
     assert lse.dtype == jnp.float32 and lse.shape == (_B, _H, _S)
     assert tuple(block.var(lse_name).shape) == (_B, _H, _S)
     assert block.var(lse_name).stop_gradient
-    plan = fa._Plan(layout, _B, _H, _S, _S, _D, 128, 128)
-    wide = tuple(plan.wide_shape(_S))
     for slot in gop.input_slots():
         for n in gop.input(slot):
             if n:
-                assert tuple(carried[n].shape) != wide, (slot, n)
+                assert carried[n].size <= max(feed["q"].size, lse.size), \
+                    (slot, n)
     # the real thing, not the placeholder: matches the composed lse
     ref_q, ref_k = feed["q"], feed["k0"]
     if layout == "bshd":
@@ -741,23 +912,23 @@ def test_fused_backward_bit_equal_to_split(layout, causal, seqs, widths,
 
     q, k, v, g = (to_layout(x) for x in (qb, kb, vb, gb))
     out, lse = fa._fa_forward(q, k, v, None, scale, 128, 128,
-                              return_lse=True, raw_lse=True,
-                              layout=layout, causal=causal,
+                              return_lse=True, layout=layout, causal=causal,
                               dropout=dropout)
 
     def backward():
         return fa._fa_backward(q, k, v, None, out, lse, g, scale, 128,
                                128, g_lse=g_lse, layout=layout,
-                               lse_wide=True, causal=causal,
+                               causal=causal,
                                dropout=dropout)[:3]
 
     registry.reset_stats()
     fused = backward()
-    assert _flash_stats() == {"fused_bwd": 1}
+    assert _flash_stats() == {"fused_bwd": 1, "narrow_lse": 1}
     # nothing fits a budget of nothing: the split pair
     monkeypatch.setattr(fa, "_FUSED_DQ_VMEM_BUDGET", 0)
     split = backward()
-    assert _flash_stats() == {"fused_bwd": 1, "split_bwd": 1}
+    assert _flash_stats() == {"fused_bwd": 1, "split_bwd": 1,
+                              "narrow_lse": 2}
     for a, b in zip(fused, split):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -790,7 +961,10 @@ def _traced_backward_route(shape_qk, shape_v, dtype, bias=None,
             q, k, v, bias, out, lse, g, 0.125, 512, 1024, layout="bshd",
             want_dbias=want_dbias, causal=True),
         qk, qk, v, v, lse, v)
-    return _flash_stats()
+    took = _flash_stats()
+    # every backward call reads its lse narrow, whichever route
+    assert took.pop("narrow_lse") == 1
+    return took
 
 
 @pytest.mark.parametrize("shape_qk,shape_v,dtype,resident,route", [

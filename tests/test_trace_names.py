@@ -81,7 +81,7 @@ def test_flash_forward_is_named(one_chip, no_compile_cache):
     def fwd(q, k, v, bias):
         return fa._fa_forward(q, k, v, bias, D ** -0.5, BLOCK_Q, BLOCK_K,
                               return_lse=True, layout="bshd",
-                              raw_lse=True, causal=True)
+                              causal=True)
 
     heads = _custom_call_heads(
         _compiled_text(fwd, one_chip, QKV, QKV, QKV, BIAS))
@@ -110,11 +110,10 @@ def test_flash_backward_kernels_are_named(one_chip, no_compile_cache,
     def fwd_bwd(q, k, v, bias, g):
         out, lse = fa._fa_forward(q, k, v, bias, D ** -0.5, BLOCK_Q,
                                   BLOCK_K, return_lse=True,
-                                  layout="bshd", raw_lse=True,
-                                  causal=True)
+                                  layout="bshd", causal=True)
         return fa._fa_backward(q, k, v, bias, out, lse, g, D ** -0.5,
                                BLOCK_Q, BLOCK_K, layout="bshd",
-                               lse_wide=True, want_dbias=want_dbias,
+                               want_dbias=want_dbias,
                                causal=True)
 
     heads = _custom_call_heads(
@@ -236,7 +235,7 @@ def test_flash_kernels_compile_with_four_key_heads_and_a_keep_mask(
     assert shapes == {"dq": (1, s, 32, 128), "dk": (1, s, 4, 128),
                       "dv": (1, s, 4, 128)}
     took = registry.dispatch_stats()["per_kernel"]["flash_attention"]
-    assert took == {"fused_bwd": 1}
+    assert took == {"fused_bwd": 1, "narrow_lse": 1}
     # the kernels read k and v at 4 heads (512 lanes): the custom calls
     # take bf16[1,8192,512] operands, and the mask as int8
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
@@ -412,7 +411,7 @@ def test_flash_kernels_compile_with_packed_heads_over_shared_key_heads(
     assert shapes == {"dq": (1, s, 32, 64), "dk": (1, s, 8, 64),
                       "dv": (1, s, 8, 64)}
     took = registry.dispatch_stats()["per_kernel"]["flash_attention"]
-    assert took == {"fused_bwd": 1}
+    assert took == {"fused_bwd": 1, "narrow_lse": 1}
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
     assert all("bf16[1,8192,512]" in l for l in calls), calls
 
