@@ -409,6 +409,60 @@ class _Plan:
             ref[:, i * width:(i + 1) * width] = val
 
 
+class _Band:
+    """Where a sliding window's band lies on the block grid: query row r
+    admits the keys c with r - window < c <= r (absolute positions, as
+    the causal mask). The rows of query block i meet the keys
+    [i bq - window + 1, i bq + bq - 1], one contiguous run of key blocks
+    kv_first(i) .. kv_last(i), and key block j meets the query blocks
+    q_first(j) .. q_last(j). A banded grid axis has `kv_steps` (or
+    `q_steps`) steps, the most blocks any block meets: step t of query
+    block i is key block kv_first(i) + t, and the steps past kv_last(i)
+    repeat its index (Mosaic elides their DMA) and do no work. Every
+    block a query block's band meets holds at least one admitted pair;
+    a block it does not meet takes no grid step.
+
+    The methods take a grid index, traced inside a kernel or an index
+    map; `static` evaluates them on Python ints."""
+
+    def __init__(self, window, bq, bk, n_q, n_kv):
+        self.window, self.bq, self.bk = int(window), bq, bk
+        self.n_q, self.n_kv = n_q, n_kv
+        self.kv_steps = max(self.kv_last(i, min) - self.kv_first(i, max) + 1
+                            for i in range(n_q))
+        self.q_steps = max(self.q_last(j, min) - self.q_first(j) + 1
+                           for j in range(n_kv))
+
+    def kv_first(self, i, mx=jnp.maximum):
+        # clamp before dividing: the division stays on non-negative ints
+        return mx(i * self.bq - (self.window - 1), 0) // self.bk
+
+    def kv_last(self, i, mn=jnp.minimum):
+        return mn((i * self.bq + self.bq - 1) // self.bk, self.n_kv - 1)
+
+    def q_first(self, j):
+        return j * self.bk // self.bq
+
+    def q_last(self, j, mn=jnp.minimum):
+        return mn((j * self.bk + self.bk + self.window - 2) // self.bq,
+                  self.n_q - 1)
+
+    def kv_block(self, i, t):
+        """Key block of step t of query block i (index maps: clamped)."""
+        return jnp.minimum(self.kv_first(i) + t, self.kv_last(i))
+
+    def q_block(self, j, t):
+        """Query block of step t of key block j (index maps: clamped)."""
+        return jnp.minimum(self.q_first(j) + t, self.q_last(j))
+
+
+def admitted_pairs(Sq, Sk, window):
+    """(query, key) pairs a sliding window admits in one head of one
+    sequence: row r keeps keys max(0, r - window + 1) .. min(r, Sk - 1)."""
+    return sum(max(0, min(r, Sk - 1) - max(0, r - window + 1) + 1)
+               for r in range(Sq))
+
+
 # ---------------------------------------------------------------------------
 # kernel bodies (shared by both layouts via the plan's lane slicing)
 # ---------------------------------------------------------------------------
@@ -442,6 +496,22 @@ def _causal_mask_dense(s):
     rows = jnp.arange(s.shape[-2])[:, None]
     cols = jnp.arange(s.shape[-1])[None, :]
     return jnp.where(rows >= cols, s, _NEG_INF)
+
+
+def _band_mask(s, q_blk, kv_blk, bq, bk, window):
+    """Mask s to a sliding window's band, r - window < c <= r, for the
+    tile of query block q_blk and key block kv_blk (absolute positions;
+    a tile inside the band gets an all-true compare)."""
+    rows = q_blk * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = kv_blk * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    d = rows - cols
+    return jnp.where((d >= 0) & (d < window), s, _NEG_INF)
+
+
+def _band_mask_dense(s, window):
+    """Whole-matrix sibling of _band_mask for the composed paths."""
+    d = jnp.arange(s.shape[-2])[:, None] - jnp.arange(s.shape[-1])[None, :]
+    return jnp.where((d >= 0) & (d < window), s, _NEG_INF)
 
 
 def _eye(n):
@@ -479,13 +549,16 @@ def _to_rows(ref, i, bq):
 
 def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
                lse_ref, m_scr, l_scr, acc_scr, *, scale, n_kv,
-               q_axis, kv_axis, causal, drop_t):
+               q_axis, kv_axis, causal, drop_t, band=None):
+    """band (a `_Band`): the kv axis steps over the key blocks of the
+    query block's band, kv_idx counting steps, kv_blk the key block."""
     kv_idx = pl.program_id(kv_axis)
     q_idx = pl.program_id(q_axis)
     D, Dv, bq, bk = plan.D, plan.Dv, plan.bq, plan.bk
     bhs = [plan.bh(i) for i in range(plan.hpb)] \
         if drop_t is not None else None
     slot = plan.kv_slot()
+    kv_blk = kv_idx if band is None else band.kv_first(q_idx) + kv_idx
 
     @pl.when(kv_idx == 0)
     def _init():
@@ -504,7 +577,9 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [bq, bk]
             s = _biased(s, plan.bias_tile(bias_ref, i))
-            if causal:
+            if band is not None:
+                s = _band_mask(s, q_idx, kv_blk, bq, bk, band.window)
+            elif causal:
                 s = _causal_mask(s, q_idx, kv_idx, bq, bk)
 
             m_prev = m_scr[i][:, :1]                   # [bq, 1]
@@ -519,7 +594,7 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
             # dropout(softmax(s)) @ v semantics
             p_v = p
             if drop_t is not None:
-                keep = _tile_keep(plan, seed_ref, bhs[i], q_idx, kv_idx,
+                keep = _tile_keep(plan, seed_ref, bhs[i], q_idx, kv_blk,
                                   drop_t)
                 p_v = jnp.where(keep, p * (256.0 / drop_t), 0.0)
             acc_scr[i] = acc_scr[i] * corr + jax.lax.dot_general(
@@ -530,7 +605,13 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
             m_scr[i] = jnp.broadcast_to(m_next, m_scr[i].shape)
             l_scr[i] = jnp.broadcast_to(l_next, l_scr[i].shape)
 
-    if causal:
+    if band is not None:
+        # the steps past the band's last key block: no work, and the
+        # clamped index maps already elided their DMA
+        @pl.when(kv_blk <= band.kv_last(q_idx))
+        def _run_band():
+            _body()
+    elif causal:
         # skip fully-masked KV blocks (everything strictly above the
         # block diagonal): no MXU work, and the clamped index maps
         # already elided their DMA
@@ -553,11 +634,12 @@ def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
 
 def _bwd_tile(plan, i, q_idx, kv_idx, bh, seed_ref, q_ref, k_ref, v_ref,
               lse_ref, out_ref, do_ref, glse_ref, bias_ref, *, scale,
-              causal, drop_t, slot=None):
+              causal, drop_t, slot=None, window=None):
     """Local head i's (q block, kv block) tile of the backward, built
     once for whichever products the calling kernel feeds from it:
     (q, k, p_v, ds) with p_v the DROPPED weights dv consumes
-    (out = p_drop @ v) and ds = p * (dp - di). `slot`: `_Plan.kv_slot`."""
+    (out = p_drop @ v) and ds = p * (dp - di). `slot`: `_Plan.kv_slot`;
+    q_idx / kv_idx are the tile's blocks, `window` a band's width."""
     D, Dv, bq, bk = plan.D, plan.Dv, plan.bq, plan.bk
     q = plan.lanes(q_ref, i, D)                     # [bq, D]
     k = plan.lanes(k_ref, i, D) if slot is None \
@@ -572,7 +654,9 @@ def _bwd_tile(plan, i, q_idx, kv_idx, bh, seed_ref, q_ref, k_ref, v_ref,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     s = _biased(s, plan.bias_tile(bias_ref, i))
-    if causal:
+    if window is not None:
+        s = _band_mask(s, q_idx, kv_idx, bq, bk, window)
+    elif causal:
         s = _causal_mask(s, q_idx, kv_idx, bq, bk)
     p = jnp.exp(s - lse)                            # [bq, bk]
     # dO goes to the MXU in the stream's own dtype, as q, k and v do:
@@ -596,13 +680,15 @@ def _bwd_tile(plan, i, q_idx, kv_idx, bh, seed_ref, q_ref, k_ref, v_ref,
 def _fa_bwd_dq_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
                       out_ref, do_ref, glse_ref, bias_ref, dq_ref,
                       ds_ref, dq_scr, *, scale, n_kv, q_axis, kv_axis,
-                      causal, drop_t):
+                      causal, drop_t, band=None):
     kv_idx = pl.program_id(kv_axis)
     q_idx = pl.program_id(q_axis)
     D, bq, bk = plan.D, plan.bq, plan.bk
     bhs = [plan.bh(i) if drop_t is not None else None
            for i in range(plan.hpb)]
     slot = plan.kv_slot()
+    kv_blk = kv_idx if band is None else band.kv_first(q_idx) + kv_idx
+    window = None if band is None else band.window
 
     @pl.when(kv_idx == 0)
     def _init():
@@ -611,17 +697,19 @@ def _fa_bwd_dq_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
     def _body():
         for i in range(plan.hpb):
             _, k, _, ds = _bwd_tile(
-                plan, i, q_idx, kv_idx, bhs[i], seed_ref, q_ref, k_ref,
+                plan, i, q_idx, kv_blk, bhs[i], seed_ref, q_ref, k_ref,
                 v_ref, lse_ref, out_ref, do_ref, glse_ref, bias_ref,
-                scale=scale, causal=causal, drop_t=drop_t, slot=slot)
+                scale=scale, causal=causal, drop_t=drop_t, slot=slot,
+                window=window)
             if ds_ref is not None:
                 plan.ds_store(ds_ref, i, ds.astype(ds_ref.dtype))
             dq_scr[i] += scale * jax.lax.dot_general(
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    if causal:
-        run = q_idx * bq + bq > kv_idx * bk
+    if band is not None or causal:
+        run = kv_blk <= band.kv_last(q_idx) if band is not None \
+            else q_idx * bq + bq > kv_idx * bk
 
         @pl.when(run)
         def _run():
@@ -648,25 +736,41 @@ def _fa_bwd_dq_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
 def _fa_bwd_dkv_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
                        out_ref, do_ref, glse_ref, bias_ref, dk_ref,
                        dv_ref, dq_ref, dk_scr, dv_scr, dq_scr, *, scale,
-                       n_q, n_kv, q_axis, kv_axis, causal, drop_t):
+                       n_q, n_kv, q_axis, kv_axis, causal, drop_t,
+                       band=None):
     """dk and dv of one kv block, accumulated over the inner q axis.
     With dq_ref (the fused backward) the same ds also feeds dq: the
     whole [Sq, hpb*D] dq of this (batch, head group) stays in dq_scr
     over both sequence axes, each q block's rows accumulating over the
-    outer kv axis, ascending as in the dq kernel."""
+    outer kv axis, ascending as in the dq kernel. With a band the q axis
+    steps over the query blocks of the key block's band (q_idx counting
+    steps, q_blk the block), and a query block's dq rows start at the
+    first key block of its own band and end at its last."""
     q_idx = pl.program_id(q_axis)
     kv_idx = pl.program_id(kv_axis)
     D, Dv, bq, bk = plan.D, plan.Dv, plan.bq, plan.bk
     bhs = [plan.bh(i) if drop_t is not None else None
            for i in range(plan.hpb)]
     slot = plan.kv_slot()
+    q_blk, window = q_idx, None
+    if band is not None:
+        q_blk, window = band.q_first(kv_idx) + q_idx, band.window
+        live = q_blk <= band.q_last(kv_idx)
 
     @pl.when(q_idx == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    if dq_ref is not None:
+    if dq_ref is not None and band is not None:
+        rows = pl.ds(pl.multiple_of(
+            jnp.minimum(q_blk, band.q_last(kv_idx)) * bq, bq), bq)
+
+        @pl.when(live & (kv_idx == band.kv_first(q_blk)))
+        def _init_band_dq():
+            dq_scr[rows, :] = jnp.zeros((bq, dq_scr.shape[1]),
+                                        jnp.float32)
+    elif dq_ref is not None:
         rows = pl.ds(pl.multiple_of(q_idx * bq, bq), bq)
 
         @pl.when(kv_idx == 0)
@@ -677,9 +781,10 @@ def _fa_bwd_dkv_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
     def _body():
         for i in range(plan.hpb):
             q, k, p_v, ds = _bwd_tile(
-                plan, i, q_idx, kv_idx, bhs[i], seed_ref, q_ref, k_ref,
+                plan, i, q_blk, kv_idx, bhs[i], seed_ref, q_ref, k_ref,
                 v_ref, lse_ref, out_ref, do_ref, glse_ref, bias_ref,
-                scale=scale, causal=causal, drop_t=drop_t, slot=slot)
+                scale=scale, causal=causal, drop_t=drop_t, slot=slot,
+                window=window)
             dv_scr[i] += jax.lax.dot_general(
                 p_v.astype(do_ref.dtype), plan.lanes(do_ref, i, Dv),
                 (((0,), (0,)), ((), ())),
@@ -694,7 +799,11 @@ def _fa_bwd_dkv_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
                         ds, k, (((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)
 
-    if causal:
+    if band is not None:
+        @pl.when(live)
+        def _run_band():
+            _body()
+    elif causal:
         @pl.when(q_idx * bq + bq > kv_idx * bk)
         def _run():
             _body()
@@ -712,7 +821,8 @@ def _fa_bwd_dkv_kernel(plan, seed_ref, q_ref, k_ref, v_ref, lse_ref,
     if dq_ref is not None:
         # this q block has met its last kv block (a causally skipped
         # one adds nothing): its rows of the held output are final
-        @pl.when(kv_idx == n_kv - 1)
+        @pl.when(kv_idx == n_kv - 1 if band is None
+                 else live & (kv_idx == band.kv_last(q_blk)))
         def _finish_dq():
             dq_ref[rows, :] = dq_scr[rows, :].astype(dq_ref.dtype)
 
@@ -736,9 +846,22 @@ def _seed_i32(dropout):
         int(t)
 
 
+def _window_band(window, causal, bq, bk, Sq, Sk):
+    """The `_Band` of a windowed site (None without a window)."""
+    if window is None:
+        return None
+    if not causal or int(window) < 1:
+        raise ValueError(f"a sliding window ({window}) is causal and at "
+                         f"least one key wide")
+    return _Band(window, bq, bk, Sq // bq, Sk // bk)
+
+
 def _fa_forward(q, k, v, bias, scale, block_q, block_k,
                 return_lse=False, layout="bhsd", causal=False,
-                dropout=None):
+                dropout=None, window=None):
+    """window: a causal site's sliding window, r - window < c <= r; its
+    kv grid axis steps over the key blocks of each query block's band
+    only (`_Band`), under the kernel's own name."""
     B, H, Sq, D = _dims(q, layout)
     Dv = v.shape[3]
     Sk = _seq_len(k, layout)
@@ -747,6 +870,11 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
     assert Sq % bq == 0 and Sk % bk == 0, (Sq, Sk, bq, bk)
     n_kv = Sk // bk
     plan = _Plan(layout, B, H, Sq, Sk, D, bq, bk, Dv, _heads(k, layout))
+    band = _window_band(window, causal, bq, bk, Sq, Sk)
+    if band is not None:
+        _kreg.count("flash_attention", "window")
+        n_kv = band.kv_steps
+
     def _sds(shape, dtype):
         return _out_struct(shape, dtype, like=q)
 
@@ -757,7 +885,10 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
     has_drop = seed is not None
 
     k_idx = None
-    if causal:
+    if band is not None:
+        def k_idx(g):
+            return band.kv_block(g[qa], g[ka])
+    elif causal:
         # clamp the (sequential) kv axis to the diagonal block for
         # masked-out steps: repeated block index -> Mosaic elides the
         # k/v/bias DMA for the skipped upper triangle
@@ -801,11 +932,12 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
         return _fa_kernel(plan, seed_ref, refs[0], refs[1], refs[2],
                           b_ref, o_ref, lse_ref, m, l, a, scale=scale,
                           n_kv=n_kv, q_axis=qa, kv_axis=kv_axis,
-                          causal=causal, drop_t=drop_t)
+                          causal=causal, drop_t=drop_t, band=band)
 
     res = pl.pallas_call(
         kern,
-        name="flash_attention_fwd",
+        name="flash_attention_fwd" if band is None
+        else "flash_attention_window_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs if return_lse else out_specs[0],
@@ -852,7 +984,7 @@ def _resident_dq_bytes(plan, dtype):
 
 def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
                  g_lse=None, layout="bhsd", want_dbias=None,
-                 causal=False, dropout=None):
+                 causal=False, dropout=None, window=None):
     """Kernel-path backward: returns (dq, dk, dv, dbias?).
 
     One fused kernel builds each (q block, kv block) tile's s, p, dp and
@@ -870,7 +1002,12 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     want_dbias=False suppresses the ds OUTPUT while still adding the
     bias into the recomputed scores: ds is an O(B*H*Sq*Sk) f32 buffer a
     multi-output custom call cannot DCE (measured 2.1 GB/site at B=4
-    S=4096), and a padding/causal-mask bias never needs a gradient."""
+    S=4096), and a padding/causal-mask bias never needs a gradient.
+
+    window: as `_fa_forward`'s. The dk/dv kernel's q axis steps over
+    the query blocks of each key block's band, the dq kernel's kv axis
+    over the key blocks of each query block's, under the kernels' own
+    names (`flash_attention_window_bwd`, `_window_dq`)."""
     B, H, Sq, D = _dims(q, layout)
     Dv = v.shape[3]
     Sk = _seq_len(k, layout)
@@ -879,6 +1016,7 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     n_q = Sq // bq
     n_kv = Sk // bk
     plan = _Plan(layout, B, H, Sq, Sk, D, bq, bk, Dv, _heads(k, layout))
+    band = _window_band(window, causal, bq, bk, Sq, Sk)
     args = [plan.rows(q), plan.rows(k), plan.rows(v), plan.lse_rows(lse),
             plan.rows(out), plan.rows(g)]
     has_glse = g_lse is not None
@@ -896,6 +1034,8 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
         args.append(seed)
     want_dbias = has_bias and not _is_mask(bias) \
         and (want_dbias is None or bool(want_dbias))
+    if want_dbias and band is not None:
+        raise NotImplementedError("a bias gradient under a sliding window")
     resident = _resident_dq_bytes(plan, q.dtype)
     fused = not want_dbias and resident <= _FUSED_DQ_VMEM_BUDGET
     _kreg.count("flash_attention", "fused_bwd" if fused else "split_bwd")
@@ -945,12 +1085,15 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
     dq = dbias = None
     if not fused:
         # ---- dq (+ds when dbias is needed): reduction over kv --------
-        grid = plan.grid(n_q, n_kv)
+        grid = plan.grid(n_q, n_kv if band is None else band.kv_steps)
         qa, ka = plan.seq_axes(swap=False)
         kv_axis = len(grid) - 1
 
         k_idx = None
-        if causal:
+        if band is not None:
+            def k_idx(g):
+                return band.kv_block(g[qa], g[ka])
+        elif causal:
             def k_idx(g):
                 return jnp.minimum(g[ka], (g[qa] * bq + bq - 1) // bk)
 
@@ -965,13 +1108,15 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
                 split_refs(refs)
             return _fa_bwd_dq_kernel(plan, seed_r, *streams, gl_r, b_r,
                                      dq_r, ds_r[0] if ds_r else None,
-                                     scr, scale=scale, n_kv=n_kv,
+                                     scr, scale=scale, n_kv=grid[-1],
                                      q_axis=qa, kv_axis=kv_axis,
-                                     causal=causal, drop_t=drop_t)
+                                     causal=causal, drop_t=drop_t,
+                                     band=band)
 
         dq, *ds = pl.pallas_call(
             kern_dq,
-            name="flash_attention_dq",
+            name="flash_attention_dq" if band is None
+            else "flash_attention_window_dq",
             grid=grid,
             in_specs=in_specs(qa, ka, k_idx=k_idx),
             out_specs=out_specs,
@@ -991,12 +1136,16 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
             dbias = dbias.astype(bias.dtype)
 
     # ---- dk/dv (+dq when fused): reduction over q --------------------
-    grid = plan.grid(n_kv, n_q)
+    n_q_steps = n_q if band is None else band.q_steps
+    grid = plan.grid(n_kv, n_q_steps)
     qa, ka = plan.seq_axes(swap=True)
     q_axis = len(grid) - 1
 
     q_idx_f = None
-    if causal:
+    if band is not None:
+        def q_idx_f(g):
+            return band.q_block(g[ka], g[qa])
+    elif causal:
         # the q stream's masked-out HEAD (q blocks strictly above the
         # diagonal) clamps forward to the diagonal block
         def q_idx_f(g):
@@ -1027,9 +1176,9 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
             (dk_r, dv_r, ks, vs), dq_r, qs = rest, None, None
         return _fa_bwd_dkv_kernel(plan, seed_r, *streams, gl_r, b_r,
                                   dk_r, dv_r, dq_r, ks, vs, qs,
-                                  scale=scale, n_q=n_q, n_kv=n_kv,
+                                  scale=scale, n_q=n_q_steps, n_kv=n_kv,
                                   q_axis=q_axis, kv_axis=ka,
-                                  causal=causal, drop_t=drop_t)
+                                  causal=causal, drop_t=drop_t, band=band)
 
     res = pl.pallas_call(
         kern_dkv,
@@ -1038,7 +1187,8 @@ def _fa_backward(q, k, v, bias, out, lse, g, scale, block_q, block_k,
         # readers (mla_flash_roofline_pct sums the events named
         # flash_attention_fwd / _dq / _dkv) find a kernel by its name.
         # Under a new name its time would drop out of that sum.
-        name="flash_attention_dkv",
+        name="flash_attention_dkv" if band is None
+        else "flash_attention_window_bwd",
         grid=grid,
         in_specs=in_specs(qa, ka, q_idx=q_idx_f),
         out_specs=out_specs,
@@ -1149,12 +1299,13 @@ def use_kernel_path(q, k, block_q=128, block_k=128, layout="bhsd",
 
 
 def _attn_reference(q, k, v, bias, scale, layout="bhsd",
-                    dropout=None, causal=False):
+                    dropout=None, causal=False, window=None):
     """Composed attention. dropout = (key, t) applies u8-threshold
     attention-weights dropout with exact-realized-probability upscale
     (same contract as the dropout op, ops/nn.py). causal masks to the
     lower triangle in ABSOLUTE positions (rows >= cols), matching the
-    kernels' block mask."""
+    kernels' block mask; a `window` to the band rows - window < cols <=
+    rows."""
     eq = "bqhd,bkhd->bhqk" if layout == "bshd" else "bhqd,bhkd->bhqk"
     group = _heads(q, layout) // _heads(k, layout)
     if group > 1:       # grouped queries: each key / value head `group` times
@@ -1165,7 +1316,9 @@ def _attn_reference(q, k, v, bias, scale, layout="bhsd",
     if bias is not None:
         s = jnp.where(bias != 0, s, _NEG_INF) if _is_mask(bias) \
             else s + bias.astype(jnp.float32)
-    if causal:
+    if window is not None:
+        s = _band_mask_dense(s, window)
+    elif causal:
         s = _causal_mask_dense(s)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     if dropout is not None:
@@ -1198,58 +1351,68 @@ def _attn_reference_lse(q, k, v, bias, scale, causal=False):
     return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def flash_attention(q, k, v, bias=None, scale=1.0, block_q=128,
                     block_k=128, layout="bhsd", causal=False,
-                    need_dbias=None):
+                    need_dbias=None, window=None):
     """q [B,H,Sq,D] (bhsd) or [B,Sq,H,D] (bshd); k/v likewise;
     bias [B,1|H,Sq|1,Sk] additive in either layout; causal masks to
     rows >= cols and SKIPS fully-masked KV blocks in the kernels.
     need_dbias (static): False suppresses the ds/dbias backward output
     entirely — a multi-output Pallas call cannot DCE the ds tile, so
     callers that never read the bias gradient must say so here; None
-    (default) keeps the historical behavior (dbias iff bias given)."""
+    (default) keeps the historical behavior (dbias iff bias given).
+    window (static, causal only): a sliding window, rows - window <
+    cols <= rows; the kernels' grids cover the band's blocks alone."""
     if _kernel_ok(q, k, block_q, block_k, layout, v):
         return _fa_forward(q, k, v, bias, scale, block_q, block_k,
-                           layout=layout, causal=causal)
+                           layout=layout, causal=causal, window=window)
     qb, kb, vb = q, k, v
     if layout == "bshd":
         qb, kb, vb = (jnp.moveaxis(x, 2, 1) for x in (q, k, v))
-    out = _attn_reference(qb, kb, vb, bias, scale, causal=causal)
+    out = _attn_reference(qb, kb, vb, bias, scale, causal=causal,
+                          window=window)
     return jnp.moveaxis(out, 1, 2) if layout == "bshd" else out
 
 
 def _fa_fwd(q, k, v, bias, scale, block_q, block_k, layout, causal,
-            need_dbias):
+            need_dbias, window):
     if _kernel_ok(q, k, block_q, block_k, layout, v):
         out, lse = _fa_forward(q, k, v, bias, scale, block_q, block_k,
                                return_lse=True, layout=layout,
-                               causal=causal)
+                               causal=causal, window=window)
     else:
         qb, kb, vb = q, k, v
         if layout == "bshd":
             qb, kb, vb = (jnp.moveaxis(x, 2, 1) for x in (q, k, v))
-        out, lse = _attn_reference_lse(qb, kb, vb, bias, scale,
-                                       causal=causal)
+        if window is None:
+            out, lse = _attn_reference_lse(qb, kb, vb, bias, scale,
+                                           causal=causal)
+        else:
+            # the composed backward recomputes from (q, k, v): no lse
+            out, lse = _attn_reference(qb, kb, vb, bias, scale,
+                                       causal=causal, window=window), None
         if layout == "bshd":
             out = jnp.moveaxis(out, 1, 2)
     return out, (q, k, v, bias, out, lse)
 
 
-def _fa_bwd(scale, block_q, block_k, layout, causal, need_dbias, res,
-            g):
+def _fa_bwd(scale, block_q, block_k, layout, causal, need_dbias, window,
+            res, g):
     q, k, v, bias, out, lse = res
     want_dbias = (bias is not None) if need_dbias is None \
         else bool(need_dbias)
     if use_kernel_path(q, k, block_q, block_k, layout, v):
         dq, dk, dv, dbias = _fa_backward(
             q, k, v, bias, out, lse, g, scale, block_q, block_k,
-            layout=layout, causal=causal, want_dbias=want_dbias)
+            layout=layout, causal=causal, want_dbias=want_dbias,
+            window=window)
         return dq, dk, dv, dbias if want_dbias else None
 
     def f(q, k, v, bias):
         return _attn_reference(q, k, v, bias, scale, layout=layout,
-                               causal=causal)
+                               causal=causal, window=window)
     _, vjp = jax.vjp(f, q, k, v, bias)
     dq, dk, dv, dbias = vjp(g)
     return dq, dk, dv, dbias if want_dbias and bias is not None \

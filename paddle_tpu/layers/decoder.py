@@ -28,20 +28,30 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
     return out
 
 
+YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast",
+             "beta_slow", "attention_factor")
+
+
 def rotary_embedding(input, theta=10000.0, rotary_dim=None,
-                     position_offset=0, interleaved=True, name=None):
+                     position_offset=0, interleaved=True, name=None,
+                     yarn=None):
     """Rotary positions on input [B, S, H, D]: the trailing `rotary_dim`
     channels of every head (all of them by default), pair i rotated by
     pos * theta^(-2i / rotary_dim). The pairs are adjacent channels, or
-    with `interleaved=False` channel i and channel i + rotary_dim / 2."""
+    with `interleaved=False` channel i and channel i + rotary_dim / 2.
+    yarn: a dict of `YARN_KEYS` (the `rope_parameters` of a "yarn"
+    rotary): each frequency blended toward theta^(-2i/d) / factor over
+    the ramp beta_fast .. beta_slow turns of the original context, cos
+    and sin times the attention factor (ops/decoder.py `yarn_scale`)."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(input.dtype)
+    attrs = {"theta": float(theta), "rotary_dim": int(rotary_dim or 0),
+             "position_offset": int(position_offset),
+             "interleaved": bool(interleaved)}
+    if yarn is not None:
+        attrs["yarn"] = [float(yarn[k]) for k in YARN_KEYS]
     helper.append_op("rotary_embedding", inputs={"X": input},
-                     outputs={"Out": out},
-                     attrs={"theta": float(theta),
-                            "rotary_dim": int(rotary_dim or 0),
-                            "position_offset": int(position_offset),
-                            "interleaved": bool(interleaved)})
+                     outputs={"Out": out}, attrs=attrs)
     return out
 
 

@@ -941,7 +941,7 @@ def multiplex(inputs, index):
 
 def fused_attention(q, k, v, bias=None, scale=None, block_q=None,
                     block_k=None, layout="bhsd", dropout_prob=0.0,
-                    is_test=False, causal=False, name=None):
+                    is_test=False, causal=False, name=None, window=None):
     """Fused multi-head attention via the Pallas flash kernel
     (paddle_tpu/kernels/flash_attention.py). q/k/v: [B, H, S, D]
     (layout="bhsd") or [B, S, H, D] (layout="bshd" — the free-reshape
@@ -951,7 +951,11 @@ def fused_attention(q, k, v, bias=None, scale=None, block_q=None,
     fewer heads than q: query head g reads key head g // (H / Hkv).
     causal=True masks rows >= cols IN the op (kernels skip fully-
     masked KV blocks) — pass a padding-only bias alongside instead of
-    baking an O(S^2) causal bias feed."""
+    baking an O(S^2) causal bias feed. window (with causal): a sliding
+    window, rows - window < cols <= rows, which the kernels' grids
+    follow (no mask array); the op then also writes the pairs it
+    admits, int32 [1], to its `WindowPairs` output
+    (`out.op.output("WindowPairs")`)."""
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     # softmax log-sum-exp [B, H, Sq], kept for the grad op (as dropout
@@ -961,16 +965,24 @@ def fused_attention(q, k, v, bias=None, scale=None, block_q=None,
     inputs = {"Q": q, "K": k, "V": v}
     if bias is not None:
         inputs["BiasQK"] = bias
-    helper.append_op("fused_attention", inputs=inputs,
-                     outputs={"Out": out, "SoftmaxLse": lse},
-                     attrs={"scale": -1.0 if scale is None else
-                            float(scale),
-                            "block_q": int(block_q or 0),
-                            "block_k": int(block_k or 0),
-                            "layout": layout,
-                            "dropout_prob": float(dropout_prob),
-                            "is_test": bool(is_test),
-                            "causal": bool(causal)})
+    outputs = {"Out": out, "SoftmaxLse": lse}
+    attrs = {"scale": -1.0 if scale is None else float(scale),
+             "block_q": int(block_q or 0),
+             "block_k": int(block_k or 0),
+             "layout": layout,
+             "dropout_prob": float(dropout_prob),
+             "is_test": bool(is_test),
+             "causal": bool(causal)}
+    if window is not None:
+        # only a windowed site carries the attribute and the count: every
+        # other site's op is as it was
+        if not causal:
+            raise ValueError("a sliding window is causal")
+        attrs["window"] = int(window)
+        outputs["WindowPairs"] = helper.create_variable_for_type_inference(
+            "int32", True)
+    helper.append_op("fused_attention", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
     return out
 
 

@@ -10,11 +10,18 @@ router and which feed-forwards a layer gets follow from the published
       normalised per head (RMS) before the rotary, and, with
       `sa_config`, a learned sparse attention: an indexer picks the
       `topk` keys each query attends (`sparse_attention_index`).
+      `use_qk_norm` false: no q / k norm (absent: the norm, as always).
   rotary      adjacent pairs with `rope_interleave` (latent attention's
       default), half-split pairs (channel i with i + d/2) without; a
       `rope_scaling` of type
       "default" is plain rotary (text: the sections of a multimodal
-      rotary all carry the same position).
+      rotary all carry the same position), of type "yarn" the YaRN
+      blend of transformers' `_compute_yarn_parameters` (truncating):
+      `factor`, `original_max_position_embeddings`, `beta_fast`,
+      `beta_slow`, `attention_factor` (ops/decoder.py `yarn_scale`).
+      `rope_parameters` keyed by layer type ({"full_attention": {...},
+      "sliding_attention": {...}}) gives each attention layer its type's
+      own rotary; any other `rope_type` raises by name.
   experts     `n_routed_experts` (that key family scores by
       `scoring_func`, sigmoid with a selection-only bias unless given)
       or `num_experts` (that family scores by softmax, no bias);
@@ -47,9 +54,20 @@ router and which feed-forwards a layer gets follow from the published
       family's router scores by sigmoid with a selection-only bias where
       `use_expert_bias`, and adds `router_norm_epsilon` (1e-6, its
       modelling code's) to the chosen scores' sum. Any other entry of
-      `layer_types` ("sliding_attention", "linear_attention", ...)
-      raises by name. `rope_parameters` {rope_theta, rope_type} is read
-      as `rope_theta` / `rope_scaling` are.
+      `layer_types` ("linear_attention", ...) raises by name.
+      `rope_parameters` {rope_theta, rope_type} is read as `rope_theta` /
+      `rope_scaling` are.
+  window layers  a "sliding_attention" entry of `layer_types`: grouped-
+      query attention whose query at position r attends the keys r -
+      `sliding_window` < c <= r, the window an argument of the flash
+      kernels (their grids step over the band's blocks alone), op scope
+      `layer_<i>/swa`; "full_attention" the causal layer. `sliding_window`
+      is admitted where `layer_types` places it and raises without;
+      `use_sliding_window` / `max_window_layers` are accepted and ignored:
+      `layer_types` governs, as in transformers.
+  feed-forward by layer  `mlp_layer_types`, one entry a layer: "sparse"
+      the expert layer, "dense" the dense SwiGLU of `intermediate_size`;
+      any other entry raises by name.
   head        untied (`lm_head.w_0` [D, V]) unless `tie_word_embeddings`:
       then the logits are the final norm's output times the embedding's
       own matrix transposed — one parameter, one optimizer state, its
@@ -73,10 +91,10 @@ Published keys that say nothing about the shapes built here
 (`max_position_embeddings`, `model_type`, ...) are accepted and ignored,
 so a `config.json` can be passed whole. Keys that change a layer's
 equations and are not built here raise NotImplementedError by name
-(`moe_latent_size`, `num_nextn_predict_layers`, `sliding_window`, a
-`layer_types` entry that is neither "conv" nor "full_attention",
-`conv_bias`, ...): a file that carries one is never built as some
-other model.
+(`moe_latent_size`, `num_nextn_predict_layers`, a `sliding_window`
+that no `layer_types` places, a `layer_types` entry that is not "conv",
+"full_attention" or "sliding_attention", `conv_bias`, ...): a file that
+carries one is never built as some other model.
 
 Every parameter has an explicit, stable name (`layer_<i>_attn_q.w_0`,
 `layer_<i>_experts_gate.w_0`, ...). The indexer's weights
@@ -93,7 +111,10 @@ OVERWRITTEN with the (query, key) pairs each layer's selection kept
 `mamba_ssd_tokens` [mixer layers], OVERWRITTEN with the tokens each
 mixer's scan went over (observability/mamba.py); one with conv layers a
 fifth, `short_conv_tokens` [conv layers], OVERWRITTEN with the tokens
-each layer's convolution went over (observability/short_conv.py).
+each layer's convolution went over (observability/short_conv.py); one
+with window layers a sixth, `window_attn_pairs` [window layers],
+OVERWRITTEN with the (query, key) pairs each window layer's band
+admitted (observability/window_attention.py).
 
 A conv layer's parameters: `layer_<i>_conv_norm.w_0`, `layer_<i>_conv_in
 .w_0` [D, 3 D], `layer_<i>_conv.w_0` [D, taps] drawn from U(-1 /
@@ -115,6 +136,7 @@ from __future__ import annotations
 
 from .. import layers
 from ..framework import name_scope
+from ..layers.decoder import YARN_KEYS
 import numpy as np
 
 from ..initializer import (Constant, Normal, NumpyArrayInitializer,
@@ -123,6 +145,7 @@ from ..observability.mamba import SSD_TOKENS_VAR
 from ..observability.moe import EXPERT_LOAD_VAR, ROWS_WORKED_VAR
 from ..observability.short_conv import SHORT_CONV_TOKENS_VAR
 from ..observability.sparse_attention import KEPT_PAIRS_VAR
+from ..observability.window_attention import WINDOW_PAIRS_VAR
 from ..param_attr import ParamAttr
 
 
@@ -131,7 +154,29 @@ from ..param_attr import ParamAttr
 PATTERN_PARTS = {"M": "mamba", "*": "attn", "E": "moe", "-": "mlp"}
 # an entry of `layer_types` -> a two-part layer's token mixer (and its op
 # scope)
-LAYER_TYPES = {"conv": "conv", "full_attention": "attn"}
+LAYER_TYPES = {"conv": "conv", "full_attention": "attn",
+               "sliding_attention": "swa"}
+# an entry of `mlp_layer_types` -> whether the layer's feed-forward is dense
+MLP_LAYER_TYPES = {"sparse": False, "dense": True}
+_YARN_DEFAULTS = {"beta_fast": 32.0, "beta_slow": 1.0}
+
+
+def rotary_of(params, theta):
+    """(theta, YaRN dict or None) of one rotary's parameters (a
+    `rope_scaling` or an entry of `rope_parameters`); a `rope_type` that
+    is neither "default" nor "yarn" raises by name."""
+    params = params or {}
+    kind = params.get("rope_type", params.get("type", "default"))
+    theta = float(params.get("rope_theta", theta))
+    if kind == "default":
+        return theta, None
+    if kind != "yarn":
+        raise NotImplementedError(f"rope scaling {kind!r}")
+    yarn = dict(_YARN_DEFAULTS, **{k: params[k] for k in YARN_KEYS
+                                   if params.get(k) is not None})
+    yarn.setdefault("attention_factor",
+                    0.1 * np.log(float(yarn["factor"])) + 1.0)
+    return theta, {k: float(yarn[k]) for k in YARN_KEYS}
 
 
 def parse_pattern(pattern):
@@ -175,16 +220,19 @@ class DecoderLMConfig:
                  layer_types=None, num_dense_layers=None, conv_L_cache=3,
                  conv_bias=False, use_expert_bias=None,
                  router_norm_epsilon=None, rope_parameters=None,
+                 mlp_layer_types=None, use_qk_norm=None,
                  experts_held=None, first_expert=0, vocab_held=None,
                  **unused):
         if q_lora_rank is not None:
             raise NotImplementedError("query compression (q_lora_rank)")
         # keys that change a layer's equations and are not built here: a
         # file that carries one must not build as some other model
+        windowed = "sliding_attention" in (layer_types or ())
         for key, given in (
                 ("moe_latent_size", moe_latent_size is not None),
                 ("num_nextn_predict_layers", bool(num_nextn_predict_layers)),
-                ("sliding_window", sliding_window is not None),
+                ("sliding_window", sliding_window is not None
+                 and not windowed),
                 ("conv_bias", bool(conv_bias))):
             if given:
                 raise NotImplementedError(
@@ -203,18 +251,25 @@ class DecoderLMConfig:
                     f"num_hidden_layers {num_hidden_layers} against "
                     f"{len(layer_types)} layer_types")
             num_hidden_layers = len(layer_types)
+            if windowed and not sliding_window:
+                raise ValueError("sliding_attention layers without a "
+                                 "sliding_window")
         if num_dense_layers is not None:
             if first_k_dense_replace not in (0, num_dense_layers):
                 raise ValueError("num_dense_layers and first_k_dense_replace "
                                  "differ")
             first_k_dense_replace = num_dense_layers
-        if rope_parameters:
+        # `rope_parameters` keyed by layer type: each attention layer its
+        # type's own rotary
+        by_type = None
+        if rope_parameters and all(isinstance(v, dict)
+                                   for v in rope_parameters.values()):
+            by_type = {t: rotary_of(p, rope_theta)
+                       for t, p in rope_parameters.items()}
+        elif rope_parameters:
             rope_theta = rope_parameters.get("rope_theta", rope_theta)
             rope_scaling = rope_scaling or rope_parameters
-        scaling = rope_scaling or {}
-        scaling = scaling.get("rope_type", scaling.get("type", "default"))
-        if scaling != "default":
-            raise NotImplementedError(f"rope scaling {scaling!r}")
+        rope_theta, self.yarn = rotary_of(rope_scaling, rope_theta)
         act = mlp_hidden_act or hidden_act
         if act not in ("silu", "relu2") or attention_bias \
                 or mlp_bias or use_bias:
@@ -243,6 +298,13 @@ class DecoderLMConfig:
         # a two-part layer's token mixer, by layer (None: attention)
         self.mixers = None if layer_types is None \
             else [LAYER_TYPES[t] for t in layer_types]
+        self.sliding_window = int(sliding_window) if windowed else None
+        self.rotary_by_type = by_type
+        self.layer_types = list(layer_types) if layer_types else None
+        if by_type is not None and self.layer_types and any(
+                t not in by_type for t in self.layer_types if t != "conv"):
+            raise ValueError(f"rope_parameters {sorted(by_type)} against "
+                             f"layer_types {sorted(set(layer_types))}")
         self.conv_taps = int(conv_L_cache)
         self.tie_word_embeddings = bool(tie_word_embeddings)
         self.num_attention_heads = num_attention_heads
@@ -264,6 +326,8 @@ class DecoderLMConfig:
             if rope_interleave is None else bool(rope_interleave)
         # a hybrid's attention: no rotary, no q / k norm
         self.attention_positions = self.parts is None
+        self.qk_norm = self.attention_positions if use_qk_norm is None \
+            else bool(use_qk_norm) and self.attention_positions
         eps = {e for e in (rms_norm_eps, layer_norm_epsilon, norm_eps)
                if e is not None}
         if len(eps) > 1:
@@ -274,6 +338,17 @@ class DecoderLMConfig:
             i for i in range(num_hidden_layers)
             if i < first_k_dense_replace or i in set(mlp_only_layers)
             or (i + 1) % decoder_sparse_step}
+        if mlp_layer_types is not None:
+            bad = sorted(set(mlp_layer_types) - set(MLP_LAYER_TYPES))
+            if bad:
+                raise NotImplementedError(
+                    f"mlp_layer_types {bad}: the model file builds "
+                    f"{sorted(MLP_LAYER_TYPES)} feed-forwards")
+            if len(mlp_layer_types) != num_hidden_layers:
+                raise ValueError(f"{len(mlp_layer_types)} mlp_layer_types "
+                                 f"for {num_hidden_layers} layers")
+            self.dense_layers = {i for i, t in enumerate(mlp_layer_types)
+                                 if MLP_LAYER_TYPES[t]}
         self.n_routed_experts = n_routed_experts or num_experts
         self.num_experts_per_tok = num_experts_per_tok
         self.n_shared_experts = n_shared_experts
@@ -318,6 +393,18 @@ class DecoderLMConfig:
         self.conv_kernel, self.chunk_size = conv_kernel, chunk_size
         self.use_conv_bias = bool(use_conv_bias)
         self.time_step = (time_step_min, time_step_max, time_step_floor)
+
+    def rotary_at(self, i):
+        """(theta, YaRN dict or None) of layer i's attention."""
+        if self.rotary_by_type is not None:
+            return self.rotary_by_type[self.layer_types[i]]
+        return self.rope_theta, self.yarn
+
+    def window_at(self, i):
+        """Layer i's sliding window, or None (a causal layer)."""
+        if self.mixers is not None and self.mixers[i] == "swa":
+            return self.sliding_window
+        return None
 
     @property
     def moe_layers(self):
@@ -410,35 +497,43 @@ def sparse_index(x, cfg, name):
         scale=heads ** -0.5 * dim ** -0.5)
 
 
-def grouped_query_attention(x, cfg, name):
+def grouped_query_attention(x, cfg, name, layer=0):
     """Causal attention of `num_attention_heads` query heads over
     `num_key_value_heads` key / value heads of `head_dim` (k and v go to
-    the op at their own head count), q and k RMS-normalised per head and
-    then rotated (neither in a hybrid: `attention_positions`); with
-    `sa_config` over the keys the indexer keeps.
-    Returns (output, pairs kept int32 [1] or None)."""
+    the op at their own head count), q and k RMS-normalised per head
+    (`qk_norm`) and then rotated by the layer's rotary (neither in a
+    hybrid: `attention_positions`); with `sa_config` over the keys the
+    indexer keeps; in a window layer over the layer's band.
+    Returns (output, pairs kept int32 [1] or None, pairs the window
+    admitted int32 [1] or None)."""
     h, hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
         cfg.head_dim
+    theta, yarn = cfg.rotary_at(layer)
 
     def heads(part, n, normed):
         t = layers.reshape(_linear(x, n * d, f"{name}_{part}", cfg),
                            [0, 0, n, d])
         if not (normed and cfg.attention_positions):
             return t
-        t = _norm(t, f"{name}_{part}_norm", cfg)
-        return layers.rotary_embedding(t, theta=cfg.rope_theta,
-                                       interleaved=cfg.rope_interleave)
+        if cfg.qk_norm:
+            t = _norm(t, f"{name}_{part}_norm", cfg)
+        return layers.rotary_embedding(t, theta=theta,
+                                       interleaved=cfg.rope_interleave,
+                                       yarn=yarn)
 
     q, k, v = heads("q", h, True), heads("k", hkv, True), \
         heads("v", hkv, False)
-    mask = kept = None
+    mask = kept = admitted = None
     if cfg.sa_config:
         with name_scope("index"):
             mask, kept = sparse_index(x, cfg, name + "_index")
+    window = cfg.window_at(layer)
     ctx = layers.fused_attention(q, k, v, mask, scale=d ** -0.5,
-                                 layout="bshd", causal=True)
+                                 layout="bshd", causal=True, window=window)
+    if window is not None:
+        admitted = ctx.block.var(ctx.op.output("WindowPairs")[0])
     ctx = layers.reshape(ctx, [0, 0, h * d])
-    return _linear(ctx, cfg.hidden_size, name + "_o", cfg), kept
+    return _linear(ctx, cfg.hidden_size, name + "_o", cfg), kept, admitted
 
 
 def gated_ffn(x, width, cfg, name):
@@ -545,15 +640,17 @@ class _Counts:
 
     def __init__(self):
         self.load, self.worked, self.kept, self.scanned = [], [], [], []
-        self.convolved = []
+        self.convolved, self.admitted = [], []
 
 
-def _attention(x, cfg, name, counts):
+def _attention(x, cfg, name, counts, layer=0):
     if cfg.kv_lora_rank:
         return latent_attention(x, cfg, name)
-    attn, n = grouped_query_attention(x, cfg, name)
+    attn, n, admitted = grouped_query_attention(x, cfg, name, layer)
     if n is not None:
         counts.kept.append(n)
+    if admitted is not None:
+        counts.admitted.append(admitted)
     return attn
 
 
@@ -566,7 +663,8 @@ def _experts(x, cfg, p, counts):
 
 def _two_part_layer(h, i, cfg, counts):
     """h += Mixer(RMSNorm(h)); h += FFN(RMSNorm(h)), the mixer attention
-    or, by `layer_types`, the gated short convolution."""
+    (causal, or in a window layer over its band) or, by `layer_types`,
+    the gated short convolution."""
     p = f"layer_{i}"
     if cfg.mixers is not None and cfg.mixers[i] == "conv":
         with name_scope("conv"):
@@ -575,9 +673,9 @@ def _two_part_layer(h, i, cfg, counts):
             counts.convolved.append(n)
             h = layers.elementwise_add(h, out)
     else:
-        with name_scope("attn"):
+        with name_scope("attn" if cfg.window_at(i) is None else "swa"):
             attn = _attention(_norm(h, p + "_attn_norm", cfg), cfg,
-                              p + "_attn", counts)
+                              p + "_attn", counts, i)
             h = layers.elementwise_add(h, attn)
     if i in cfg.dense_layers:
         with name_scope("mlp"):
@@ -598,7 +696,7 @@ def _one_part_layer(h, i, part, cfg, counts):
             out, n = mamba_mixer(x, cfg, p + "_mixer")
             counts.scanned.append(n)
         elif part == "attn":
-            out = _attention(x, cfg, p + "_attn", counts)
+            out = _attention(x, cfg, p + "_attn", counts, i)
         elif part == "moe":
             out = _experts(x, cfg, p, counts)
         else:
@@ -655,6 +753,8 @@ def decoder_lm_train(cfg: DecoderLMConfig):
         _overwritten_counter(SSD_TOKENS_VAR, counts.scanned)
     if counts.convolved:
         _overwritten_counter(SHORT_CONV_TOKENS_VAR, counts.convolved)
+    if counts.admitted:
+        _overwritten_counter(WINDOW_PAIRS_VAR, counts.admitted)
     with name_scope("head"):
         x = _norm(h, "final_norm", cfg)
         if cfg.tie_word_embeddings:

@@ -27,7 +27,9 @@ Three layers (docs/OBSERVABILITY.md):
 * :mod:`.mamba` — reader of the `mamba_ssd_tokens` counter a step with
   Mamba-2 mixers overwrites (docs/TRACING.md);
 * :mod:`.short_conv` — reader of the `short_conv_tokens` counter a step
-  with gated short convolution layers overwrites (docs/TRACING.md).
+  with gated short convolution layers overwrites (docs/TRACING.md);
+* :mod:`.window_attention` — reader of the `window_attn_pairs` counter a
+  step with sliding-window attention layers overwrites (docs/TRACING.md).
 
 Hot-path contract: one boolean (``metrics._HOT[0]``) gates all
 per-step telemetry work. The step's own profiler spans and clock stamps
@@ -36,7 +38,8 @@ feed a profiler session, the slow-step detector and, while ``_HOT``,
 this layer's record.
 """
 from . import metrics, recorder, export, tracing, attribution, \
-    memory, moe, sparse_attention, mamba, short_conv  # noqa: F401
+    memory, moe, sparse_attention, mamba, short_conv, \
+    window_attention  # noqa: F401
 from .metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, EngineCounters,
     default_registry, counter, gauge, histogram,

@@ -19,9 +19,12 @@ chips).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+
+import numpy as np
 
 from ..core.amp import amp_cast
 from ..core.registry import (register_op, register_no_grad_op,
@@ -45,17 +48,41 @@ def rms_norm(ctx):
     ctx.set_output("Y", y.astype(x.dtype))
 
 
-def _rotate_pairs(x, theta, offset, interleaved):
+def yarn_scale(d, theta, factor, original, beta_fast, beta_slow):
+    """YaRN's factor on each of the d/2 rotary frequencies theta^(-2i/d),
+    as transformers' `_compute_yarn_parameters` makes it (truncating):
+    the pairs that turn fewer than `beta_slow` times over the `original`
+    context are interpolated (1 / factor), those that turn more than
+    `beta_fast` times kept (1), a linear ramp r_i between the two
+    (r_i / factor + 1 - r_i). float64 [d/2], a constant of the trace."""
+    def turning(rotations):      # the pair that turns so often over L0
+        return d * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(turning(beta_fast)), 0)
+    high = min(math.ceil(turning(beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    r = np.clip((np.arange(d // 2) - low) / (high - low), 0.0, 1.0)
+    return r / factor + 1.0 - r
+
+
+def _rotate_pairs(x, theta, offset, interleaved, yarn=None):
     """Rotate pair i of the last axis of x [B, S, H, D] by the angle
     pos * theta^(-2i/D), pos = offset + s. The pairs are the adjacent
     channels (x[2i], x[2i+1]) when `interleaved`, else the half-split
-    ones (x[i], x[i + D/2])."""
+    ones (x[i], x[i + D/2]). yarn (factor, original context, beta_fast,
+    beta_slow, attention factor): each frequency times `yarn_scale`,
+    cos and sin times the attention factor."""
     d = x.shape[-1]
     pos = jnp.arange(x.shape[1], dtype=_F32) + offset
     freq = theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
+    if yarn is not None:
+        freq = freq * jnp.asarray(yarn_scale(d, theta, *yarn[:4]), _F32)
     angle = pos[:, None] * freq[None, :]                    # [S, D/2]
     cos = jnp.cos(angle)[None, :, None, :]
     sin = jnp.sin(angle)[None, :, None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     xf = x.astype(_F32)
     if interleaved:
         pairs = xf.reshape(x.shape[:-1] + (d // 2, 2))
@@ -72,21 +99,26 @@ def rotary_embedding(ctx):
     """Rotary positions on the trailing `rotary_dim` channels of
     X [B, S, H, D] (all of D when 0). attrs: theta, rotary_dim,
     position_offset, interleaved (the pairs are adjacent channels; False:
-    channel i pairs with channel i + rotary_dim/2)."""
+    channel i pairs with channel i + rotary_dim/2), and where the
+    rotary is YaRN-scaled `yarn` = [factor, original context, beta_fast,
+    beta_slow, attention factor] (`_rotate_pairs`)."""
     x = ctx.input("X")
     theta = float(ctx.attr("theta", 10000.0))
     n = int(ctx.attr("rotary_dim", 0) or 0) or x.shape[-1]
     offset = float(ctx.attr("position_offset", 0))
     interleaved = bool(ctx.attr("interleaved", True))
+    yarn = ctx.attr("yarn", None) or None
     if n % 2 or n > x.shape[-1]:
         raise ValueError(f"rotary_dim {n} of a head of {x.shape[-1]}")
     keep = x.shape[-1] - n
     if keep == 0:
-        ctx.set_output("Out", _rotate_pairs(x, theta, offset, interleaved))
+        ctx.set_output("Out", _rotate_pairs(x, theta, offset, interleaved,
+                                            yarn))
     else:
         ctx.set_output("Out", jnp.concatenate(
             [x[..., :keep],
-             _rotate_pairs(x[..., keep:], theta, offset, interleaved)],
+             _rotate_pairs(x[..., keep:], theta, offset, interleaved,
+                           yarn)],
             axis=-1))
 
 

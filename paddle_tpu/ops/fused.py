@@ -55,6 +55,7 @@ def _attn_args(ctx):
         bk = _auto_block(Sk, 1024) if Sk >= 1024 else min(128, Sk)
     p_drop = float(ctx.attr("dropout_prob", 0.0) or 0.0)
     causal = bool(ctx.attr("causal", False))
+    window = int(ctx.attr("window", 0) or 0) or None
     drop = None
     if p_drop and not ctx.attr("is_test", False):
         # u8 keep-threshold, with BOTH edges handled exactly like the
@@ -63,7 +64,7 @@ def _attn_args(ctx):
         t = int(round((1.0 - p_drop) * 256.0))
         if t < 256:
             drop = (ctx.rng(), max(t, 0))
-    return q, k, v, bias, layout, scale, bq, bk, drop, causal
+    return q, k, v, bias, layout, scale, bq, bk, drop, causal, window
 
 
 @register_op("fused_attention", intermediate_outputs=("SoftmaxLse",))
@@ -80,7 +81,12 @@ def fused_attention(ctx):
     dist_transformer.py:1043-1044 — applied in BOTH regimes; the Pallas
     kernels regenerate the mask from the hardware PRNG per block),
     causal (mask rows >= cols; the kernels SKIP fully-masked KV
-    blocks and elide their DMA).
+    blocks and elide their DMA), window (a causal site's sliding
+    window: rows - window < cols <= rows; the kernels' grids step over
+    the band's blocks alone, `flash_attention_window_*`).
+    WindowPairs (optional, int32 [1]): the (query, key) pairs the
+    window admits, B x its pairs in one head, for the
+    `window_attn_pairs` counter (observability/window_attention.py).
     SoftmaxLse (intermediate, float32 [B, H, Sq]): the softmax
     log-sum-exp the forward kernel wrote, carried to the grad op so the
     backward kernels need no second forward. The kernels write and
@@ -88,9 +94,10 @@ def fused_attention(ctx):
     path in training writes a real value; everywhere else it is zeros
     nothing reads."""
     from ..kernels.flash_attention import (
-        _fa_forward, _attn_reference, use_kernel_path, _dims)
+        _fa_forward, _attn_reference, use_kernel_path, _dims,
+        admitted_pairs)
     res_t = jnp.result_type(ctx.input("Q"))
-    q, k, v, bias, layout, scale, bq, bk, drop, causal = \
+    q, k, v, bias, layout, scale, bq, bk, drop, causal, window = \
         _attn_args(ctx)
     lse = None
     if drop is not None and drop[1] == 0:
@@ -102,21 +109,27 @@ def fused_attention(ctx):
             # inference: no grad op will consume lse — skip the
             # un-DCE-able lse output entirely
             out = _fa_forward(q, k, v, bias, scale, bq, bk,
-                              layout=layout, causal=causal)
+                              layout=layout, causal=causal, window=window)
         else:
             # XLA does not merge two Mosaic custom calls, so the grad
             # op cannot get (out, lse) by repeating this call for free:
             # it reads Out and the narrow lse stored here
             out, lse = _fa_forward(q, k, v, bias, scale, bq, bk,
                                    return_lse=True, layout=layout,
-                                   causal=causal, dropout=drop)
+                                   causal=causal, dropout=drop,
+                                   window=window)
     else:
         # shape-bounded regime / CPU / odd shapes: XLA's fully-fused
         # composed formulation is faster while [Sq,Sk] fits (see the
         # measured dispatch table in kernels/flash_attention.py)
         out = _attn_reference(q, k, v, bias, scale, layout=layout,
-                              dropout=drop, causal=causal)
+                              dropout=drop, causal=causal, window=window)
     ctx.set_output("Out", out.astype(res_t))
+    if window is not None and ctx.has_output("WindowPairs"):
+        B, _, Sq, _ = _dims(q, layout)
+        ctx.set_output("WindowPairs", jnp.full(
+            (1,), B * admitted_pairs(Sq, _dims(k, layout)[2], window),
+            jnp.int32))
     if lse is None:
         # never read (the grad op takes this same branch), never
         # fetched: XLA removes it from the compiled step
@@ -139,7 +152,7 @@ def fused_attention_grad(ctx):
     from ..kernels.flash_attention import (
         _fa_forward, _fa_backward, _attn_reference, use_kernel_path)
     op = ctx.op
-    q, k, v, bias, layout, scale, bq, bk, drop, causal = \
+    q, k, v, bias, layout, scale, bq, bk, drop, causal, window = \
         _attn_args(ctx)
 
     g_names = op.input("Out@GRAD")
@@ -167,16 +180,17 @@ def fused_attention_grad(ctx):
             # op desc) or a forward at is_test, which wrote no lse
             out, lse = _fa_forward(q, k, v, bias, scale, bq, bk,
                                    return_lse=True, layout=layout,
-                                   causal=causal, dropout=drop)
+                                   causal=causal, dropout=drop,
+                                   window=window)
         dq, dk, dv, dbias = _fa_backward(
             q, k, v, bias, out, lse, dout.astype(q.dtype), scale, bq,
             bk, layout=layout, want_dbias=_bound("BiasQK"),
-            causal=causal, dropout=drop)
+            causal=causal, dropout=drop, window=window)
     else:
         def f(q, k, v, bias):
             return _attn_reference(q, k, v, bias, scale,
                                    layout=layout, dropout=drop,
-                                   causal=causal)
+                                   causal=causal, window=window)
 
         _, vjp = jax.vjp(f, q, k, v, bias)
         dq, dk, dv, dbias = vjp(dout.astype(q.dtype))

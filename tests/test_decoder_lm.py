@@ -477,13 +477,18 @@ def test_the_shares_add_up_to_the_uncut_layer():
     _close(total, whole)
 
 
-def test_eight_softmax_shares_add_up_to_the_uncut_layer():
-    """16 softmax-routed experts, top-4, no shared expert, over 8 shares
-    of 2: the routed outputs of all eight shares add up to the uncut
-    reference layer (each share given the router's choices over all 16,
-    as every chip computes them alike)."""
-    t, d, f, e = 96, 16, 8, 16
-    sz = dict(num_experts_per_tok=4, norm_topk_prob=True, first_expert=0)
+@pytest.mark.parametrize("e,top_k", [(16, 4), (64, 8)],
+                         ids=["16_top4", "64_top8_mellum2"])
+def test_eight_softmax_shares_add_up_to_the_uncut_layer(e, top_k):
+    """e softmax-routed experts, top-k, no shared expert, over 8 shares
+    of e / 8: the routed outputs of all eight shares add up to the uncut
+    reference layer (each share given the router's choices over all e,
+    as every chip computes them alike). 64 top-8 in shares of 8 is the
+    mellum2_s8192 cell's expert layer."""
+    t, d, f = 96, 16, 8
+    held = e // 8
+    sz = dict(num_experts_per_tok=top_k, norm_topk_prob=True,
+              first_expert=0)
     y = _r((t, d), 70)
     p = {"router.w_0": _r((e, d), 71, 0.3),
          "experts_gate.w_0": _r((e, d, f), 72, 0.3),
@@ -495,16 +500,16 @@ def test_eight_softmax_shares_add_up_to_the_uncut_layer():
         _, weight = gqa_ref.route(y, p["router.w_0"], sz)
         total = np.zeros_like(y)
         for share in range(8):
-            lo = 2 * share
+            lo = held * share
             c = dict(x=y, choice=np.asarray(choice),
                      weight=np.asarray(weight), cot=np.zeros_like(y),
-                     wg=p["experts_gate.w_0"][lo:lo + 2],
-                     wu=p["experts_up.w_0"][lo:lo + 2],
-                     wd=p["experts_down.w_0"][lo:lo + 2])
-            out, _ = _experts_program(c, e, 2, lo)
+                     wg=p["experts_gate.w_0"][lo:lo + held],
+                     wu=p["experts_up.w_0"][lo:lo + held],
+                     wd=p["experts_down.w_0"][lo:lo + held])
+            out, _ = _experts_program(c, e, held, lo)
             total += out
     _close(total, whole)
-    # every token's four choices lie in some share: nothing is left out
+    # every token's choices lie in some share: nothing is left out
     assert np.abs(np.asarray(whole)).sum(-1).min() > 0
 
 
@@ -1370,24 +1375,31 @@ _PARENT_PROGRAMS = {
         285, "4b5d44467e17f55735401eb7631b839131c3d459326b300a5cd6ecfad5168c35"),
     ("nemotron_twotower_30b_a3b", False): (
         502, "f7097b3dba5720c46ae765433eeae362b9ecf0e77f2776e30ad8a5096ca1922f"),
+    # as the parent of PR 41 built them
+    ("lfm2_24b_a2b", True): (
+        451, "469421ddaecf07b2aa66ceff28e40f202bbe52deca2618b8adc8f9fc9fdc40e5"),
+    ("lfm2_24b_a2b", False): (
+        451, "545bc4d6687dfa6d6f04e97c530d9afb7c2a81c90c386450043ccddf5cff2697"),
 }
 
 
 @pytest.mark.parametrize("config,rehearsal", sorted(_PARENT_PROGRAMS))
 def test_accepted_decoder_programs_are_op_for_op_the_parents(config,
                                                              rehearsal):
-    """The model file grew a second kind of layer, then a third; the
-    three accepted decoder configurations still build the parent's
-    programs, op for op: types, inputs, outputs and every attribute
-    (name scopes among them; no router op gained a `norm_epsilon`), at
-    the rehearsal's sizes and at the cell's."""
+    """The model file grew a second kind of layer, then a third, then
+    window layers and YaRN; the four accepted decoder configurations
+    still build the parent's programs, op for op: types, inputs, outputs
+    and every attribute (name scopes among them; no router op gained a
+    `norm_epsilon`, no attention a `window`, no rotary a `yarn`), at the
+    rehearsal's sizes and at the cell's."""
     import hashlib
     import json
     import os
     from benchmark.lib import cells
     from paddle_tpu import models
     fam = {"kanana2_30b_a3b": family, "keye_vl2_30b_a3b": gqa_family,
-           "nemotron_twotower_30b_a3b": hybrid_family}[config]
+           "nemotron_twotower_30b_a3b": hybrid_family,
+           "lfm2_24b_a2b": conv_family}[config]
     with open(os.path.join(cells.BENCH, "configs", config + ".json")) as f:
         sz = fam.sizes(json.load(f), rehearsal=rehearsal)
     fluid.framework.unique_name.reset()
@@ -1414,21 +1426,24 @@ def test_accepted_decoder_programs_are_op_for_op_the_parents(config,
 @pytest.mark.parametrize("key,value", [
     ("moe_latent_size", 1024), ("num_nextn_predict_layers", 1),
     ("sliding_window", 4096),
-    ("layer_types", ["full_attention", "sliding_attention"]),
-    ("layer_types", ["conv", "linear_attention"]), ("conv_bias", True)])
+    ("layer_types", ["full_attention", "chunked_attention"]),
+    ("layer_types", ["conv", "linear_attention"]), ("conv_bias", True),
+    ("mlp_layer_types", ["sparse", "sparse", "moe", "dense"])])
 def test_keys_that_change_a_layers_equations_raise_by_name(key, value):
     """`DecoderLMConfig` swallowed every key it did not know: a file
-    with experts in a compressed latent, multi-token heads, a window, a
-    per-layer attention type the model file does not build (it builds
-    "conv" and "full_attention") or a biased convolution would have
-    built some other model."""
+    with experts in a compressed latent, multi-token heads, a window no
+    `layer_types` places, a per-layer attention type the model file does
+    not build (it builds "conv", "full_attention" and
+    "sliding_attention"), a biased convolution or a feed-forward type it
+    does not build ("sparse" and "dense") would have built some other
+    model."""
     from paddle_tpu import models
     with pytest.raises(NotImplementedError, match=key):
         models.DecoderLMConfig(n_routed_experts=8, **{key: value})
     # absent, null or zero: the model that was always built
     quiet = {"sliding_window": None, "layer_types": None,
              "moe_latent_size": None, "num_nextn_predict_layers": 0,
-             "conv_bias": False}
+             "conv_bias": False, "mlp_layer_types": None}
     models.DecoderLMConfig(n_routed_experts=8, **{key: quiet[key]},
                            max_position_embeddings=4096, model_type="any")
 
@@ -1576,12 +1591,22 @@ def test_flash_kernels_take_packed_heads_over_shared_key_heads(causal,
     ((8, 8, 64), "8b2cfa4b881b60645d51fd6cff84208e3f6a5ddd04ae04503579ca841c"
                  "b79cd9"),
     ((8, 2, 128), "e04085923dd4c58719f6c43bdb5b7322c1ea485a175d6f7d89d85b92"
-                  "fe5b631c")], ids=["packed_ungrouped", "grouped_at_128"])
+                  "fe5b631c"),
+    # as the parent of PR 41 traced them
+    ((32, 4, 128), "44e1c77e7c3b01a35ed073b7061e4233eeac1616c3b0095190846f2e"
+                   "fb372109"),
+    ((8, 2, 64), "c9f48ca35b2591dc97450dd08b74a157751e5051b6ca51d31c261b092b"
+                 "421671")],
+    ids=["packed_ungrouped", "grouped_at_128", "grouped_32_over_4",
+         "packed_over_shared_key_heads"])
 def test_unchanged_flash_sites_trace_to_the_parents_kernels(site, digest,
                                                             interp):
     """The sites the accepted cells have — packed heads with as many key
     heads (tbase_s4096), fewer key heads at one head a lane block
-    (keye2_s8192, twotower_s4096) — trace to the jaxpr PR 40 left,
+    (keye2_s8192 at 32 over 4, twotower_s4096), packed heads over shared
+    key heads (lfm2_s8192) — trace to the jaxpr PR 40 left (the last two
+    as PR 41's parent traced them; PR 41 gave the kernels a window that
+    these sites do not take),
     kernel bodies included (sha256 of the text, source positions taken
     out). PR 40 changed the bodies (the lse crosses one number a row) and
     renewed the digests on a record that Out, lse, dQ, dK and dV stayed
@@ -1593,8 +1618,8 @@ def test_unchanged_flash_sites_trace_to_the_parents_kernels(site, digest,
     h, hkv, d = site
     q = jnp.zeros((1, 256, h, d), jnp.float32)
     k = jnp.zeros((1, 256, hkv, d), jnp.float32)
-    assert not fa._Plan("bshd", 1, h, 256, 256, d, 128, 128, d,
-                        hkv).packed_shared
+    assert fa._Plan("bshd", 1, h, 256, 256, d, 128, 128, d,
+                    hkv).packed_shared == (site == (8, 2, 64))
 
     def f(q, k, v, g):
         out, lse = fa._fa_forward(q, k, v, None, d ** -0.5, 128, 128,
@@ -1866,3 +1891,219 @@ def test_the_conv_model_is_built_from_its_published_keys():
     with pytest.raises(ValueError):
         models.DecoderLMConfig(layer_types=["conv"], num_experts=8,
                                num_dense_layers=1, first_k_dense_replace=2)
+
+
+# -------------------------- window and full layers, YaRN: Mellum2
+
+from benchmark.families import swa_gqa_moe_decoder as swa_family  # noqa: E402
+from benchmark.families import swa_gqa_moe_decoder_reference as swa_ref  # noqa: E402,E501
+
+
+def _mellum2_sizes(**over):
+    import json
+    import os
+    from benchmark.lib import cells
+    with open(os.path.join(cells.BENCH, "configs",
+                           "mellum2_12b_a2p5b.json")) as f:
+        return dict(swa_family.sizes(json.load(f), rehearsal=True), **over)
+
+
+@pytest.mark.parametrize("path", ["lowered", "kernels"])
+def test_window_model_trains_like_its_reference(path, request):
+    """The same model file, told by `layer_types` to build two window
+    layers and a full one (the band narrower than the sequence), by
+    `rope_parameters` plain rotary on the first and YaRN on the last,
+    softmax experts top-8 and an untied head, against the plain reference
+    in float32: losses (2e-6), EVERY leaf's first gradient (2e-4 of its
+    norm) and every leaf's change after three Adam steps (5e-3). The
+    window one key wider (the reference's `window_off_by_one`, which no
+    limit on the chip sees: it moves one key in 1,024) reads far outside
+    both: that is where a band off by one is caught. With the kernels:
+    4 query heads over 2 key heads of 128, 256 tokens in blocks of 128
+    and a window of 100 (the band's edges inside tiles, a block the band
+    never meets in the first window layer's grid), all interpreted."""
+    sz = _mellum2_sizes(layers="SSF")
+    tr = swa_family.traffic({"pool": 3, "reference_rows_per_block": 1},
+                            True)
+    if path == "kernels":
+        request.getfixturevalue("interp")
+        sz.update(num_attention_heads=4, num_key_value_heads=2,
+                  head_dim=128, sliding_window=100)
+        tr.update(seq_len=256, reference_query_rows=128)
+    fam = swa_family
+    with jax.default_matmul_precision("highest"):
+        got = _train(sz, tr, 7, amp=False, fam=fam)
+        pool = fam.make_pool(sz, tr, 7)
+        want = fam.run_reference(sz, tr, pool, 7, 3)
+        wider = fam.run_reference(sz, tr, pool, 7, 3,
+                                  fault="window_off_by_one")
+    np.testing.assert_allclose(got["losses"], want["losses"], rtol=2e-6)
+    assert set(got["grad_norms"]) == set(want["grad_norms"]) \
+        == set(swa_ref.trainable_names(sz))
+    worst = 0.0
+    for n, w in want["grad_norms"].items():
+        assert w > 0, n
+        assert abs(got["grad_norms"][n] - w) <= 2e-4 * max(w, 1e-6), n
+        worst = max(worst, abs(wider["grad_norms"][n] - w) / w)
+    for n, w in want["delta_norms"].items():
+        assert abs(got["delta_norms"][n] - w) <= 5e-3 * w, n
+    assert worst > 50 * 2e-4
+    assert abs(wider["losses"][0] - want["losses"][0]) \
+        > 10 * 2e-6 * want["losses"][0]
+    s, b, w = tr["seq_len"], tr["batch"], sz["sliding_window"]
+    assert fam.window_pairs(sz).tolist() == \
+        [b * fa.admitted_pairs(s, s, w)] * 2
+    assert fam.expert_load(sz).shape == (3, sz["experts_held"])
+    if path == "kernels":
+        stats = kreg.dispatch_stats()["per_kernel"]
+        assert stats["flash_attention"].get("custom")
+        assert stats["flash_attention"].get("window")
+        assert stats["flash_attention"].get("fused_bwd")
+        assert stats["moe_grouped_matmul"].get("custom")
+
+
+def test_window_model_under_mixed_precision():
+    sz = _mellum2_sizes()
+    tr = swa_family.traffic({"pool": 3, "reference_rows_per_block": 1},
+                            True)
+    got = _train(sz, tr, 9, amp=True, fam=swa_family)
+    want = swa_family.run_reference(sz, tr, swa_family.make_pool(sz, tr, 9),
+                                    9, 3)
+    np.testing.assert_allclose(got["losses"], want["losses"], rtol=2e-3)
+
+
+def test_yarn_table_matches_the_closed_form():
+    """At the configuration's numbers (d 128, theta 5e5, L0 8,192, beta
+    32 / 1, factor 16): the ramp runs from pair 18 to pair 35, the pairs
+    below it keep theta^(-2i/d), those above take a sixteenth of it, and
+    between them r / 16 + 1 - r; the program's table (ops/decoder.py),
+    the reference's (its own code) and the closed form agree, and the
+    attention factor the file carries is 0.1 ln 16 + 1."""
+    import math
+    from paddle_tpu.ops.decoder import yarn_scale
+    cfg = _mellum2_sizes()["yarn"]
+    assert swa_ref.yarn_bounds(128, 5e5, 8192, 32, 1) == (18, 35)
+    low = math.floor(128 * math.log(8192 / (32 * 2 * math.pi))
+                     / (2 * math.log(5e5)))
+    high = math.ceil(128 * math.log(8192 / (2 * math.pi))
+                     / (2 * math.log(5e5)))
+    assert (low, high) == (18, 35)
+    i = np.arange(64)
+    r = np.clip((i - 18) / 17, 0, 1)
+    closed = 5e5 ** (-2 * i / 128) * (r / 16 + 1 - r)
+    got = 5e5 ** (-2 * i / 128) * yarn_scale(128, 5e5, 16, 8192, 32, 1)
+    np.testing.assert_allclose(got, closed, rtol=1e-12)
+    np.testing.assert_allclose(
+        swa_ref.frequencies(128, 5e5, cfg), closed, rtol=1e-12)
+    assert np.all(got[:19] == 5e5 ** (-2 * i[:19] / 128))
+    np.testing.assert_allclose(got[35:], closed[35:], rtol=1e-12)
+    np.testing.assert_allclose(got[35:] * 16, 5e5 ** (-2 * i[35:] / 128),
+                               rtol=1e-12)
+    assert cfg["attention_factor"] == pytest.approx(
+        0.1 * math.log(16) + 1, abs=1e-15)
+
+
+def test_yarn_rotary_op_forward_and_grad():
+    """`rotary_embedding` with a YaRN table through Executor.run against
+    the reference's own rotary (its frequency table, cos and sin times the
+    attention factor): output and dX."""
+    yarn = {"factor": 16.0, "original_max_position_embeddings": 64.0,
+            "beta_fast": 32.0, "beta_slow": 1.0, "attention_factor": 1.27}
+    x, cot = _r((2, 40, 3, 32), 200), _r((2, 40, 3, 32), 201)
+    prog = _run(lambda x: layers.rotary_embedding(
+        x, theta=5e5, interleaved=False, yarn=yarn), {"x": x}, ["x"])
+    out, (dx,) = _fetch(*prog[:3], {"x": x}, *prog[3:], cot)
+    freq = swa_ref.frequencies(32, 5e5, yarn)
+
+    def f(x):
+        return swa_ref.rope(x, freq, 1.27)
+    _close(out, f(x))
+    _close(dx, jax.grad(lambda x: jnp.sum(f(x) * cot))(x))
+    # the blend binds at this context: some pairs are interpolated
+    assert freq[-1] < 5e5 ** (-30 / 32) / 8
+
+
+@pytest.mark.parametrize("where", ["rope_scaling", "rope_parameters",
+                                   "by_layer_type"])
+def test_rope_types_other_than_default_and_yarn_raise(where):
+    from paddle_tpu import models
+    bad = {"rope_type": "longrope", "rope_theta": 1e4}
+    kw = {"rope_scaling": {"rope_scaling": bad},
+          "rope_parameters": {"rope_parameters": bad},
+          "by_layer_type": {"rope_parameters": {
+              "full_attention": bad,
+              "sliding_attention": {"rope_type": "default"}},
+              "layer_types": ["sliding_attention", "full_attention"],
+              "sliding_window": 8}}[where]
+    with pytest.raises(NotImplementedError, match="longrope"):
+        models.DecoderLMConfig(num_experts=8, **kw)
+
+
+def test_the_window_model_is_built_from_its_published_keys():
+    """`layer_types`, `sliding_window`, `rope_parameters` by layer type,
+    `mlp_layer_types`, `use_qk_norm` false and the untied head, read off
+    the configuration's own keys at the cell's widths: op scopes
+    `layer_<i>/swa` and `layer_3/attn`, the window on three attention
+    ops and not the fourth, each writing its admitted pairs into the
+    `window_attn_pairs` counter; plain rotary on the window layers, YaRN
+    on the full one; no q / k norm; 64 softmax-routed outputs top-8."""
+    import json
+    import os
+    from benchmark.lib import cells
+    from paddle_tpu import models
+    with open(os.path.join(cells.BENCH, "configs",
+                           "mellum2_12b_a2p5b.json")) as f:
+        sz = swa_family.sizes(json.load(f))
+    cfg = swa_family.model_config(sz)
+    assert cfg.mixers == ["swa", "swa", "swa", "attn"]
+    assert [cfg.window_at(i) for i in range(4)] == [1024] * 3 + [None]
+    assert cfg.dense_layers == set() and cfg.moe_layers == [0, 1, 2, 3]
+    assert cfg.scoring_func == "softmax" and not cfg.router_bias
+    assert not cfg.qk_norm and not cfg.tie_word_embeddings
+    assert cfg.rotary_at(0) == (5e5, None)
+    theta, yarn = cfg.rotary_at(3)
+    assert theta == 5e5 and yarn["factor"] == 16
+    fluid.framework.unique_name.reset()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        models.decoder_lm_train(cfg)
+    ops = main.global_block().ops
+    attn = [op for op in ops if op.type == "fused_attention"]
+    assert [op.attr("op_namescope", "") for op in attn] == \
+        [f"layer_{i}/swa/" for i in range(3)] + ["layer_3/attn/"]
+    assert [op.attr("window", None) for op in attn] == [1024] * 3 + [None]
+    assert [bool(op.output("WindowPairs")) for op in attn] == \
+        [True] * 3 + [False]
+    rotary = [op for op in ops if op.type == "rotary_embedding"]
+    assert len(rotary) == 8
+    assert [op.attr("yarn", None) is not None for op in rotary] == \
+        [False] * 6 + [True] * 2
+    np.testing.assert_allclose(rotary[-1].attr("yarn"),
+                               [16, 8192, 32, 1, 1.2772588722239782],
+                               rtol=1e-7)
+    router = [op for op in ops if op.type == "moe_router"][0]
+    assert router.attr("top_k") == 8 and not router.input("Bias")
+    names = {p.name for p in main.all_parameters()}
+    assert not [n for n in names if "q_norm" in n or "k_norm" in n]
+    assert "lm_head.w_0" in names
+    assert main.global_block().var("window_attn_pairs").persistable
+    assert swa_family.trained_parameters(sz) == 340_349_184
+    with pytest.raises(ValueError, match="sliding_window"):
+        models.DecoderLMConfig(num_experts=8, layer_types=[
+            "sliding_attention", "full_attention"])
+    # `layer_types` governs: the windows are where it places them,
+    # whatever use_sliding_window / max_window_layers say
+    quiet = models.DecoderLMConfig(
+        num_experts=8, layer_types=["full_attention", "sliding_attention"],
+        sliding_window=16, use_sliding_window=False, max_window_layers=28)
+    assert [quiet.window_at(i) for i in range(2)] == [None, 16]
+
+
+def test_admitted_pairs_reader():
+    from paddle_tpu.observability import window_attention
+    scope = Scope()
+    assert window_attention.admitted_pairs(scope) is None
+    scope.var(window_attention.WINDOW_PAIRS_VAR).set_value(
+        jnp.asarray([7_864_832] * 3, jnp.int32))
+    got = window_attention.admitted_pairs(scope)
+    assert got.dtype == np.int64 and got.tolist() == [7_864_832] * 3

@@ -542,7 +542,7 @@ COVERED = {
     "dropout": "tests/test_loss_norm_ops.py TestDropout (mask determinism + scale; stochastic fwd excludes central differences)",
     "expand_to_rank_table_batch": "tests/test_rnn_control_flow.py (rank-table pipeline)",
     "fc": "composite of mul+elementwise_add, both swept here; tests/test_executor_mnist.py trains through it",
-    "fused_attention": "tests/test_flash_attention_bwd.py (kernel vs composed grads, both layouts)",
+    "fused_attention": "tests/test_flash_attention_bwd.py (kernel vs composed grads, both layouts); tests/test_flash_attention_window.py (a sliding window: the banded kernels' dQ / dK / dV, fused and split, vs the composed band)",
     "fused_elemwise_activation": "tests/test_elementwise_ops.py (compositions swept individually)",
     "fused_embedding_fc_lstm": "tests/test_rnn_control_flow.py (lstm family)",
     "fused_embedding_seq_pool": "tests/test_sequence_ops.py (embedding+pool composition)",

@@ -416,6 +416,73 @@ def test_flash_kernels_compile_with_packed_heads_over_shared_key_heads(
     assert all("bf16[1,8192,512]" in l for l in calls), calls
 
 
+def test_window_flash_kernels_compile_under_their_names(one_chip,
+                                                       no_compile_cache):
+    """32 query heads over 4 key / value heads of 128, B=1 S=8192 in 512
+    x 1024 blocks, a window of 1,024 (the mellum2_s8192 cell's window
+    layers): Mosaic takes the banded forward and the banded FUSED
+    backward under their own names, `flash_attention_window_fwd` /
+    `_bwd`, apart from the causal layer's; the routing counts the site's
+    `window` outcome beside `fused_bwd`; k and v go in at 4 heads."""
+    fa = _flash_module()
+    s = 8192
+    q = ((1, s, 32, 128), jnp.bfloat16)
+    kv = ((1, s, 4, 128), jnp.bfloat16)
+
+    def fwd_bwd(q, k, v, g):
+        out, lse = fa._fa_forward(q, k, v, None, 128 ** -0.5, BLOCK_Q,
+                                  BLOCK_K, return_lse=True, layout="bshd",
+                                  causal=True, window=1024)
+        return fa._fa_backward(q, k, v, None, out, lse, g, 128 ** -0.5,
+                               BLOCK_Q, BLOCK_K, layout="bshd",
+                               causal=True, window=1024)[:3]
+
+    from paddle_tpu.kernels import registry
+    registry.reset_stats()
+    text = _compiled_text(fwd_bwd, one_chip, q, kv, kv, q)
+    heads = _custom_call_heads(text)
+    assert _stems(heads) == {"flash_attention_window_fwd",
+                             "flash_attention_window_bwd"}, heads
+    assert len(heads) == 2, heads
+    took = registry.dispatch_stats()["per_kernel"]["flash_attention"]
+    assert took.get("window") == 1 and took.get("fused_bwd") == 1, took
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert all("bf16[1,8192,512]" in l for l in calls), calls
+
+
+def test_grouped_matmul_kernels_take_an_expert_width_of_896(monkeypatch,
+                                                           one_chip,
+                                                           no_compile_cache):
+    """The three kernels at the mellum2_s8192 cell's expert: 8 held of
+    64, top-8 over 8,192 tokens, matrices of 2304 x 896 (7 x 128, 18 x
+    128), under their names."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+    from paddle_tpu.kernels import registry
+    monkeypatch.setattr(registry, "interpret", lambda: False)
+    held, d, f = 8, 2304, 896
+    rows = gm.prefix_rows(8192 * 8, held, 64)[0]
+    assert gm._eligible(registry.signature(
+        "moe_experts", jnp.zeros((rows, d), jnp.bfloat16),
+        jnp.zeros((held, d, f), jnp.bfloat16)))
+    tiles = ((rows // gm.TILE_ROWS,), jnp.int32)
+    wide, narrow = ((rows, d), jnp.bfloat16), ((rows, f), jnp.bfloat16)
+    up = ((held, d, f), jnp.bfloat16)
+
+    def run(fn):
+        return lambda te, na, a, b: fn(a, b, {"tile_expert": te,
+                                              "n_active": na})
+    for which, fn, shapes in (
+            ("fwd", run(lambda x, w, p: gm.gmm(x, w, p, True)), (wide, up)),
+            ("dx", run(lambda dy, w, p: gm.gmm_dx(dy, w, p, True)),
+             (narrow, up)),
+            ("dw", run(lambda x, dy, p: gm.gmm_dw(x, dy, p, held, True)),
+             (wide, narrow))):
+        heads = _custom_call_heads(_compiled_text(
+            fn, one_chip, tiles, ((1,), jnp.int32), *shapes))
+        assert len(heads) == 1 and heads[0].startswith(
+            "moe_grouped_matmul_" + which), heads
+
+
 @pytest.mark.parametrize("which", ["fwd", "bwd"])
 def test_short_conv_kernels_compile_under_their_names(
         one_chip, no_compile_cache, which, monkeypatch):
