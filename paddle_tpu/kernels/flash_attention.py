@@ -341,17 +341,20 @@ class _Plan:
             return pl.BlockSpec((None, bqs, bk), index_map)
         return br, factory, per_head, per_q
 
-    def bias_tile(self, bias_ref, i):
-        """Per-local-head [bqs, bk] tile from the bias ref: f32 to add
-        to the scores, or (an integer bias is a keep MASK) bool."""
+    def bias_tile(self, bias_ref, i, transpose=False):
+        """Per-local-head [bqs, bk] tile from the bias ref ([bk, bqs]
+        transposed): f32 to add to the scores, or (an integer bias is a
+        keep MASK) bool."""
         if bias_ref is None:
             return None
         # packed per-head [hpb, bqs, bk], else [bqs, bk]
         tile = bias_ref[i] if bias_ref.ndim == 3 else bias_ref[...]
-        if _is_mask(tile):
-            # the target has no i8 vector compare: widen first
-            return tile.astype(jnp.int32) != 0
-        return tile.astype(jnp.float32)
+        mask = _is_mask(tile)
+        # the target has no i8 vector compare: widen first
+        tile = tile.astype(jnp.int32 if mask else jnp.float32)
+        if transpose:
+            tile = tile.T
+        return tile != 0 if mask else tile
 
     def ds_shape(self):
         if self.layout == "bshd":
@@ -548,10 +551,102 @@ def _to_rows(ref, i, bq):
 
 
 def _fa_kernel(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
-               lse_ref, m_scr, l_scr, acc_scr, *, scale, n_kv,
-               q_axis, kv_axis, causal, drop_t, band=None):
-    """band (a `_Band`): the kv axis steps over the key blocks of the
+               lse_ref, m_scr, l_scr, acc_scr, *, scale, n_kv, q_axis,
+               kv_axis, causal, drop_t, band=None):
+    """The forward on the TRANSPOSED tile, s^T = k q^T [bk, bq]: keys on
+    sublanes, queries on lanes. The softmax's max and sum run down the
+    sublanes (no cross-lane reduction), its running max, sum and
+    rescale are [1, bq] rows (bq / 128 vregs, not bq / 8), and the
+    accumulator is o^T = v^T p^T [Dv, bq], lane-dense at any head width
+    (Mosaic turns the [bk, Dv] v tile on the XLU); `_finish` turns o^T
+    back once a query block. A bias is a [1, bk] row here (a key's);
+    a [bq, bk] bias tile, or dropout, takes `_fa_kernel_rows`.
+
+    band (a `_Band`): the kv axis steps over the key blocks of the
     query block's band, kv_idx counting steps, kv_blk the key block."""
+    assert drop_t is None       # dropout takes `_fa_kernel_rows`
+    kv_idx = pl.program_id(kv_axis)
+    q_idx = pl.program_id(q_axis)
+    D, Dv, bq, bk = plan.D, plan.Dv, plan.bq, plan.bk
+    slot = plan.kv_slot()
+    kv_blk = kv_idx if band is None else band.kv_first(q_idx) + kv_idx
+    window = None if band is None else band.window
+
+    @pl.when(kv_idx == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def _body():
+        if slot is not None:
+            k_all = plan.shared_kv(slot, k_ref, D)
+            v_all = plan.shared_kv(slot, v_ref, Dv)
+
+        def scores(i):
+            st = jax.lax.dot_general(
+                plan.lanes(k_ref, i, D) if slot is None else k_all,
+                plan.lanes(q_ref, i, D), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [bk, bq]
+            st = _biased(st, plan.bias_tile(bias_ref, i, transpose=True))
+            if causal:
+                # query - key of each pair, absolute
+                d = q_idx * bq - kv_blk * bk + (
+                    jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+                    - jax.lax.broadcasted_iota(jnp.int32, st.shape, 0))
+                keep = d >= 0 if window is None else (d >= 0) & (d < window)
+                st = jnp.where(keep, st, _NEG_INF)
+            return st
+
+        # the heads of a lane block in turn, the next head's k q^T issued
+        # before this head's softmax: the MXU works under the VPU
+        s_next = scores(0)
+        for i in range(plan.hpb):
+            st = s_next
+            if i + 1 < plan.hpb:
+                s_next = scores(i + 1)
+            m_prev = m_scr[i][:1]                          # [1, bq]
+            m_next = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+            corr = jnp.exp(m_prev - m_next)
+            p = jnp.exp(st - m_next)                       # [bk, bq]
+            l_next = l_scr[i][:1] * corr + jnp.sum(p, axis=0, keepdims=True)
+            acc_scr[i] = acc_scr[i] * corr + jax.lax.dot_general(
+                plan.lanes(v_ref, i, Dv) if slot is None else v_all,
+                p.astype(v_ref.dtype), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)        # [Dv, bq]
+            m_scr[i] = jnp.broadcast_to(m_next, m_scr[i].shape)
+            l_scr[i] = jnp.broadcast_to(l_next, l_scr[i].shape)
+
+    if band is not None:
+        # the steps past the band's last key block: no work, and the
+        # clamped index maps already elided their DMA
+        pl.when(kv_blk <= band.kv_last(q_idx))(_body)
+    elif causal:
+        # skip fully-masked KV blocks (everything strictly above the
+        # block diagonal): no MXU work, and the clamped index maps
+        # already elided their DMA
+        pl.when(q_idx * bq + bq > kv_idx * bk)(_body)
+    else:
+        _body()
+
+    @pl.when(kv_idx == n_kv - 1)
+    def _finish():
+        for i in range(plan.hpb):
+            l = jnp.maximum(l_scr[i][:1], 1e-30)
+            plan.store_lanes(o_ref, i, Dv,
+                             (acc_scr[i] / l).T.astype(o_ref.dtype))
+            if lse_ref is not None:
+                lse_ref[i:i + 1, :bq] = m_scr[i][:1] + jnp.log(l)
+
+
+def _fa_kernel_rows(plan, seed_ref, q_ref, k_ref, v_ref, bias_ref,
+                    o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale, n_kv,
+                    q_axis, kv_axis, causal, drop_t, band=None):
+    """The forward on the [bq, bk] tile itself (keys on lanes), for a
+    site whose bias is a [bq, bk] tile a query block (a keep mask, a
+    float bias a query) or whose weights drop out: the tile and the keep
+    mask come in that form, and the backward draws the mask in it.
+    band: as `_fa_kernel`'s."""
     kv_idx = pl.program_id(kv_axis)
     q_idx = pl.program_id(q_axis)
     D, Dv, bq, bk = plan.D, plan.Dv, plan.bq, plan.bk
@@ -874,6 +969,13 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
     if band is not None:
         _kreg.count("flash_attention", "window")
         n_kv = band.kv_steps
+    # a [bq, bk] bias tile a query block, or dropout's keep mask, keeps
+    # the tile's own form (`_fa_kernel_rows`); every other site runs on
+    # the transposed tile, where a lane block of more than one head
+    # issues the next head's k q^T under this head's softmax
+    rows = dropout is not None or (bias is not None and bias.shape[2] != 1)
+    _kreg.count("flash_attention", "pipelined_fwd"
+                if plan.hpb > 1 and not rows else "single_fwd")
 
     def _sds(shape, dtype):
         return _out_struct(shape, dtype, like=q)
@@ -929,10 +1031,10 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
         lse_ref = refs[i] if return_lse else None
         i += return_lse
         m, l, a = refs[i:i + 3]
-        return _fa_kernel(plan, seed_ref, refs[0], refs[1], refs[2],
-                          b_ref, o_ref, lse_ref, m, l, a, scale=scale,
-                          n_kv=n_kv, q_axis=qa, kv_axis=kv_axis,
-                          causal=causal, drop_t=drop_t, band=band)
+        return (_fa_kernel_rows if rows else _fa_kernel)(
+            plan, seed_ref, refs[0], refs[1], refs[2], b_ref, o_ref,
+            lse_ref, m, l, a, scale=scale, n_kv=n_kv, q_axis=qa,
+            kv_axis=kv_axis, causal=causal, drop_t=drop_t, band=band)
 
     res = pl.pallas_call(
         kern,
@@ -942,10 +1044,16 @@ def _fa_forward(q, k, v, bias, scale, block_q, block_k,
         in_specs=in_specs,
         out_specs=out_specs if return_lse else out_specs[0],
         out_shape=out_shape if return_lse else out_shape[0],
+        # running max and sum ([bq, 128] lane-broadcast columns, or [1,
+        # bq] rows in 8 sublanes transposed) and the accumulator ([bq,
+        # Dv], or [Dv, bq] transposed)
         scratch_shapes=[
-            pltpu.VMEM((plan.hpb, bq, 128), jnp.float32),
-            pltpu.VMEM((plan.hpb, bq, 128), jnp.float32),
-            pltpu.VMEM((plan.hpb, bq, Dv), jnp.float32),
+            pltpu.VMEM((plan.hpb, bq, 128) if rows else (plan.hpb, 8, bq),
+                       jnp.float32),
+            pltpu.VMEM((plan.hpb, bq, 128) if rows else (plan.hpb, 8, bq),
+                       jnp.float32),
+            pltpu.VMEM((plan.hpb, bq, Dv) if rows else (plan.hpb, Dv, bq),
+                       jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * kv_axis
@@ -1333,14 +1441,21 @@ def _attn_reference(q, k, v, bias, scale, layout="bhsd",
     return jnp.einsum(eo, p, v)
 
 
-def _attn_reference_lse(q, k, v, bias, scale, causal=False):
+def _attn_reference_lse(q, k, v, bias, scale, causal=False, window=None):
     """Composed attention ([B,H,S,D] only) that also returns logsumexp
-    over keys — the CPU/odd-shape counterpart of return_lse mode."""
+    over keys — the CPU/odd-shape counterpart of return_lse mode. Bias,
+    grouped key heads and `window` as `_attn_reference`'s."""
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if bias is not None:
-        s = s + bias.astype(jnp.float32)
-    if causal:
+        s = jnp.where(bias != 0, s, _NEG_INF) if _is_mask(bias) \
+            else s + bias.astype(jnp.float32)
+    if window is not None:
+        s = _band_mask_dense(s, window)
+    elif causal:
         s = _causal_mask_dense(s)
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)

@@ -1588,15 +1588,15 @@ def test_flash_kernels_take_packed_heads_over_shared_key_heads(causal,
 
 
 @pytest.mark.parametrize("site,digest", [
-    ((8, 8, 64), "8b2cfa4b881b60645d51fd6cff84208e3f6a5ddd04ae04503579ca841c"
-                 "b79cd9"),
-    ((8, 2, 128), "e04085923dd4c58719f6c43bdb5b7322c1ea485a175d6f7d89d85b92"
-                  "fe5b631c"),
-    # as the parent of PR 41 traced them
-    ((32, 4, 128), "44e1c77e7c3b01a35ed073b7061e4233eeac1616c3b0095190846f2e"
-                   "fb372109"),
-    ((8, 2, 64), "c9f48ca35b2591dc97450dd08b74a157751e5051b6ca51d31c261b092b"
-                 "421671")],
+    # as PR 42 traced them (the forward on its transposed tile)
+    ((8, 8, 64), "2b5ea94e3c35a45878c99c8052f6733823adba8138dd24f1faed9fcdc3"
+                 "62d4de"),
+    ((8, 2, 128), "93adeafa43539e4ba0f80e9a920a8f92f9f88499bfe7deed43b14bc9"
+                  "a714611d"),
+    ((32, 4, 128), "38a38ac9068e225707fd2f23d1831e27d37112cbb7182ad63bc2227e"
+                   "70ffe786"),
+    ((8, 2, 64), "607fcb065b3b4ee23ca6b18ec5e650b4ebceb961b2fe506a9f7ac4324e"
+                 "9d4eb2")],
     ids=["packed_ungrouped", "grouped_at_128", "grouped_32_over_4",
          "packed_over_shared_key_heads"])
 def test_unchanged_flash_sites_trace_to_the_parents_kernels(site, digest,
@@ -1604,15 +1604,15 @@ def test_unchanged_flash_sites_trace_to_the_parents_kernels(site, digest,
     """The sites the accepted cells have — packed heads with as many key
     heads (tbase_s4096), fewer key heads at one head a lane block
     (keye2_s8192 at 32 over 4, twotower_s4096), packed heads over shared
-    key heads (lfm2_s8192) — trace to the jaxpr PR 40 left (the last two
-    as PR 41's parent traced them; PR 41 gave the kernels a window that
-    these sites do not take),
-    kernel bodies included (sha256 of the text, source positions taken
-    out). PR 40 changed the bodies (the lse crosses one number a row) and
-    renewed the digests on a record that Out, lse, dQ, dK and dV stayed
-    the same bits on the chip (PERF.md §6, PR 40): a PR that means to
-    leave these sites alone keeps the digests, one that changes them
-    brings such a record."""
+    key heads (lfm2_s8192) — trace to the jaxpr PR 42 left, kernel bodies
+    included (sha256 of the text, source positions taken out). PR 40
+    changed the bodies (the lse crosses one number a row) and renewed the
+    digests on a record that Out, lse, dQ, dK and dV stayed the same bits
+    on the chip (PERF.md §6, PR 40); PR 42 changed the forward's body (its
+    tile transposed: the same sums in another order) and renewed them on
+    a record of how far Out and lse moved on the chip (PERF.md §6, PR
+    42): a PR that means to leave these sites alone keeps the digests,
+    one that changes them brings such a record."""
     import hashlib
     import re
     h, hkv, d = site

@@ -235,7 +235,7 @@ def test_flash_kernels_compile_with_four_key_heads_and_a_keep_mask(
     assert shapes == {"dq": (1, s, 32, 128), "dk": (1, s, 4, 128),
                       "dv": (1, s, 4, 128)}
     took = registry.dispatch_stats()["per_kernel"]["flash_attention"]
-    assert took == {"fused_bwd": 1, "narrow_lse": 1}
+    assert took == {"single_fwd": 1, "fused_bwd": 1, "narrow_lse": 1}
     # the kernels read k and v at 4 heads (512 lanes): the custom calls
     # take bf16[1,8192,512] operands, and the mask as int8
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
@@ -411,7 +411,7 @@ def test_flash_kernels_compile_with_packed_heads_over_shared_key_heads(
     assert shapes == {"dq": (1, s, 32, 64), "dk": (1, s, 8, 64),
                       "dv": (1, s, 8, 64)}
     took = registry.dispatch_stats()["per_kernel"]["flash_attention"]
-    assert took == {"fused_bwd": 1, "narrow_lse": 1}
+    assert took == {"pipelined_fwd": 1, "fused_bwd": 1, "narrow_lse": 1}
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
     assert all("bf16[1,8192,512]" in l for l in calls), calls
 
