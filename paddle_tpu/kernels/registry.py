@@ -253,8 +253,9 @@ def count(name: str, outcome: str) -> None:
     (eligibility or backend said no), ``denied`` (flag/deny list said
     no) — or what a kernel says of the call it then made (the fused
     optimizer's ``native_view`` / ``flat_view``, the flash backward's
-    ``fused_bwd`` / ``split_bwd`` and ``narrow_lse``), which
-    :func:`dispatch_stats` lists per kernel and leaves out of
+    ``fused_bwd`` / ``split_bwd`` and ``narrow_lse``) or a lowering of
+    the form it took (``mul``'s ``in_rank``), which
+    :func:`dispatch_stats` lists per name and leaves out of
     ``decisions`` and ``hit_rate``.
     """
     with _STATS_LOCK:

@@ -37,23 +37,39 @@ def _acc_type(x, y):
 
 @register_op("mul")
 def mul(ctx):
+    """Out = X . Y over X's trailing ``rank - x_num_col_dims`` and Y's
+    leading ``y_num_col_dims`` dimensions.
+
+    One dot_general contracts X in its own rank: X's trailing dimensions
+    merge into one K (one already in every ``fc``: X ``[B, S, D]``
+    against Y ``[D, N]``) and its leading ones stay free dimensions of
+    the dot. The token dimensions are never merged into ``[B*S, D]`` and
+    split again, which XLA could only meet with a relayout of the
+    activation. Unit leading dimensions are dropped first, a bitcast: a
+    decoder's ``[1, S, D]`` contracts as ``[S, D]``. A routed kernel
+    takes the reference's 2-D operands.
+    """
     x, y = ctx.input("X"), ctx.input("Y")
     xn = ctx.attr("x_num_col_dims", 1)
     yn = ctx.attr("y_num_col_dims", 1)
     out_shape = tuple(x.shape[:xn]) + tuple(y.shape[yn:])
     res_t = jnp.result_type(x, y)
-    x2, y2 = _flat2d(x, xn), _flat2d(y, yn)
-    x2, y2 = amp_cast("mul", x2, y2)
+    x, y = amp_cast("mul", x, y)
+    y2 = _flat2d(y, yn)
     from ..kernels import registry as kreg
     sel = None
     if kreg.routable("mul"):
+        x2 = _flat2d(x, xn)
         sel = kreg.select("mul", kreg.signature("mul", x2, y2))
     if sel is not None:
         out = sel.run(x2, y2, out_dtype=res_t)
     else:
-        out = jnp.matmul(
-            x2, y2,
-            preferred_element_type=_acc_type(x2, y2) or res_t)
+        kreg.count("mul", "in_rank")
+        lead = tuple(d for d in x.shape[:xn] if d != 1)
+        x = x.reshape(lead + (y2.shape[0],))
+        out = lax.dot_general(
+            x, y2, (((len(lead),), (0,)), ((), ())),
+            preferred_element_type=_acc_type(x, y2) or res_t)
         out = out.astype(res_t)
     out = out.reshape(out_shape)
     # tp-sharded matmul: under an active multi-axis activation scope

@@ -587,6 +587,69 @@ def test_expert_layer_compiles_with_the_combine_kernel(
     assert f"[{t * k},{d}]" not in text and f"[{t},{k},{d}]" not in text
 
 
+def test_transformer_step_relays_out_no_projection(one_chip,
+                                                   no_compile_cache,
+                                                   monkeypatch):
+    """A reduced Transformer training step (one encoder and one decoder
+    layer at the base widths, d 512 in 8 heads, S=128, B=8, AMP and
+    Adam) compiled for a described v5e: the standalone `copy`s of
+    activations in its entry computation are four `[B, S, d]` a site of
+    the three attentions, q, k and v after their bias and one
+    projection's gradient, put S minor for the score dots (ROADMAP S3).
+    `mul` flattening X to `[B*S, d]` and back added 28 more. The bound,
+    24 such copies (25.2 MB), lies between the two readings it was set
+    from: 12 copies, 12.6 MB, with X contracted in its own rank, and
+    54.5 MB with the flatten."""
+    from paddle_tpu import models
+    from paddle_tpu.core import engine
+    from paddle_tpu.core.scope import Scope
+    b, s, d = 8, 128, 512
+    cfg = models.transformer.TransformerConfig(
+        src_vocab_size=1024, trg_vocab_size=1024, d_model=d,
+        d_inner=4 * d, n_head=d // 64, n_layer=1, dropout=0.0,
+        fuse_attention=True)
+    fluid.framework.unique_name.reset()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        cost, _, _ = models.transformer_train(cfg)
+        fluid.contrib.mixed_precision.decorate(
+            fluid.optimizer.AdamOptimizer(learning_rate=1e-3)).minimize(cost)
+    got = {}
+
+    class Compiled(Exception):
+        pass
+
+    def compile_for_the_chip(self, clock, program, traced, donated, const,
+                             arrays, key):
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
+                                           sharding=one_chip),
+            (donated, const, arrays, key))
+        got["text"] = traced.fn.lower(*args).compile().as_text()
+        raise Compiled()
+
+    with fluid.scope_guard(Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        monkeypatch.setattr(engine.Engine, "_first_dispatch",
+                            compile_for_the_chip)
+        with pytest.raises(Compiled):
+            exe.run(main, feed=models.transformer.make_batch(cfg, b, s, s),
+                    fetch_list=[cost])
+    text = got["text"]
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")]
+    width = {"bf16": 2, "f32": 4}
+    copies = [(dt, dims, math.prod(map(int, dims.split(",")))
+               * width.get(dt, 4))
+              for dt, dims in re.findall(
+                  r"= (\w+)\[([\d,]+)\]\{[^}]*\} copy\(", entry)]
+    # a copy of one head over every token or more is an activation's
+    activations = [c for c in copies if c[2] >= b * s * 64 * 2]
+    qkv = b * s * d * 2
+    assert sum(c[2] for c in activations) <= 24 * qkv, activations
+
+
 def test_no_other_kernel_reads_as_flash_or_adam():
     """The accepted classifier maps a head holding `adam` to fused_adam
     and one holding `flash` or `kern` to flash attention: no other
