@@ -1,8 +1,8 @@
 """Cross-path lowering conformance verifier (docs/STATIC_ANALYSIS.md).
 
-Every program lowers along one of four paths — engine whole-block jit,
-``FLAGS_op_scheduler`` islands, the transpiler-emitted explicit-
-collective program, and eager dygraph — and each path re-implements the
+Every program lowers along one of three paths — engine whole-block
+jit, the transpiler-emitted explicit-collective program, and eager
+dygraph — and each path re-implements the
 decisions that matter: kernel routing, gradient bucket planning,
 quantization, the stability-guard gate, loss scaling, sharding hints,
 and trace-cache keying.  This module extracts a canonical **lowering
@@ -18,8 +18,7 @@ execution), then diffs the traces pairwise against the declared
                                           cell's written justification.
 
 A tier-2 runtime hook (``crosscheck_traced``) additionally compares the
-static engine-path trace against the step the engine ACTUALLY traced,
-the same way PR 14's ``validate_traced`` re-proves the partition.
+static engine-path trace against the step the engine ACTUALLY traced.
 
 No jax import at module level: extraction is pure program/registry
 inspection so the CLI and tier-1 validation can afford it.
@@ -207,7 +206,7 @@ def _engine_keyed_names() -> Tuple[str, ...]:
                                    ast.AsyncFunctionDef)):
                 continue
             if fn.name not in ("_cache_key", "_fast_key",
-                               "_tuning_key_items"):
+                               "_trace_key_items"):
                 continue
             for node in ast.walk(fn):
                 if isinstance(node, ast.Attribute) and \
@@ -234,7 +233,7 @@ _KEYED_CACHE: Optional[Tuple[str, ...]] = None
 def _kernel_records(program, block_idx: int, cfg: TraceConfig):
     """(op_idx, op_type, kernel_name | None) for every op with at least
     one registered kernel candidate — the registry decision each path
-    would get, since all four paths execute ops through
+    would get, since all three paths execute ops through
     OPS.get(type).lowering(ctx) and one select() point."""
     from ..core.types import dtype_to_np
     from ..kernels import registry as kreg
@@ -353,9 +352,8 @@ def _guard_content(program, block_idx: int, cfg: TraceConfig,
     present = plan is not None
     return _pairs({
         "present": present,
-        # islands run the verdict+gate as a post-step jitted epilogue;
         # engine/transpiled gate inside the step trace itself
-        "in_trace": path != "scheduler",
+        "in_trace": True,
         "grads": grads,
         "policies": ("integrity", "nonfinite", "spike") if present
         else (),
@@ -390,9 +388,8 @@ def _shard_hint_records(program, block_idx: int):
 
 def _tier2_content(path: str):
     # what FLAGS_validate_tier>=2 re-verifies on each path: the traced
-    # partition (validate_traced) and the collective bucket plan
-    covered_partition = path != "dygraph"
-    return _pairs({"partition_verify": covered_partition,
+    # step's lowering (crosscheck_traced) and the collective bucket plan
+    return _pairs({"lowering_crosscheck": path != "dygraph",
                    "bucket_plan_verify": True})
 
 
@@ -454,12 +451,6 @@ def extract_trace(program, path: str, block_idx: int = 0,
                                      _STAGE_GLOBAL_VIEW),
                       note="emulated collective quantizes the "
                            "global-view reduced value")
-    elif path == "scheduler":
-        tr.record("collective_bucketing", False, (),
-                  note="island path requires mesh is None: no "
-                       "collectives ever apply")
-        tr.record("collective_quantization", False, (),
-                  note="no collectives on the island path")
     elif path == "transpiled":
         if transpiled_program is not None:
             buckets = _parsed_buckets(transpiled_program, cfg)
@@ -522,10 +513,6 @@ def extract_trace(program, path: str, block_idx: int = 0,
                   note="lax.scan over K stacked feed batches; a guard "
                        "verdict freezes the carry for early break-out "
                        "(core/engine.py trace_step)")
-    elif path == "scheduler":
-        tr.record("multi_step", False, (),
-                  note="scheduler_gate returns False for "
-                       "multi_step > 1 (core/scheduler.py)")
     elif path == "transpiled":
         tr.record("multi_step", False, (),
                   note="explicit-collective programs dispatch per "
@@ -548,10 +535,6 @@ def extract_trace(program, path: str, block_idx: int = 0,
                   note="frozen program exports through the predictor's "
                        "trace_step + __aot__ path with fixed bucketed "
                        "signatures (inference/serving/export.py)")
-    elif path == "scheduler":
-        tr.record("serving", False, (),
-                  note="island dispatch has no single serialized "
-                       "executable to export")
     elif path == "transpiled":
         tr.record("serving", False, (),
                   note="serving shards inside one traced executable "
@@ -575,11 +558,6 @@ def extract_trace(program, path: str, block_idx: int = 0,
                        "verify_stage_partition + the 1F1B slot-table "
                        "verifier gate engine construction "
                        "(parallel/auto_cut.py, analysis/races.py)")
-    elif path == "scheduler":
-        tr.record("pipeline", False, (),
-                  note="island lanes dispatch one whole program per "
-                       "step; no cross-lane handoff channel exists "
-                       "(core/scheduler.py scheduler_gate)")
     elif path == "transpiled":
         tr.record("pipeline", False, (),
                   note="no transpiler pass splits a block into stage "
@@ -724,7 +702,7 @@ def verify_conformance(program, block_idx: int = 0,
                        matrix: Optional[SupportMatrix] = None,
                        label: str = "",
                        note_stale: bool = False) -> List[Diagnostic]:
-    """Prove the four paths lower `program` the same way, modulo the
+    """Prove the three paths lower `program` the same way, modulo the
     declared support matrix.  Returns diagnostics; ERROR = undeclared
     drift.
 
@@ -789,15 +767,12 @@ def crosscheck_traced(program, block_idx: int, traced, mesh=None,
                       data_axis: str = "dp", strategy=None,
                       label: str = "traced step") -> None:
     """Compare the STATIC engine-path lowering trace against the step
-    the engine ACTUALLY traced (PR 14's ``validate_traced`` analog for
-    lowering decisions).  Raises EnforceNotMet on mismatch.
+    the engine ACTUALLY traced.  Raises EnforceNotMet on mismatch.
 
     Checks, under the LIVE flag/mesh state:
     * guard gate presence + gated grad set vs ``traced.guard_plan``;
     * the static bucket plan's count/bytes/quantized-count vs the
-      ``comm_stats`` attached to the traced step;
-    * the island-path gate: a step must not have been scheduled when
-      the static gate says islands are impossible.
+      ``comm_stats`` attached to the traced step.
     """
     from ..core.flags import FLAGS
     problems: List[str] = []
@@ -847,19 +822,6 @@ def crosscheck_traced(program, block_idx: int, traced, mesh=None,
                 problems.append(
                     f"collective plan {k}: static {expected[k]} vs "
                     f"traced {actual[k]}")
-
-    # island gate: never scheduled when statically impossible
-    is_scheduled = type(getattr(traced, "fn", None)).__name__ in (
-        "ScheduledStep", "PipelinedAccumStep")
-    if is_scheduled:
-        from ..core.scheduler import scheduler_gate
-        ok, reason = scheduler_gate(program, block_idx, mesh=mesh,
-                                    integrity_plan=None,
-                                    check_partition=False)
-        if not ok:
-            problems.append(
-                f"island path taken but the static gate forbids it: "
-                f"{reason}")
 
     if problems:
         from ..core.enforce import EnforceNotMet

@@ -5,14 +5,13 @@ graph — no tracing, no compilation.
 This is the substrate ROADMAP item 1's SPMD placement search consumes
 ("Synthesizing Optimal Parallelism Placement and Reduction Strategies
 on Hierarchical Systems" needs a per-op cost to score candidate
-placements without compiling each one), and the per-island aggregation
-lines up index-for-index with the scheduler partition so the model can
-be **calibrated** against measured per-island device time and against
-XLA's own analysis (``Engine.compiled_stats``'s flops).
+placements without compiling each one), and the model is
+**calibrated** against XLA's own analysis (``Engine.compiled_stats``'s
+flops).
 
 Cost formulas are deliberately simple closed forms (dense GEMM/conv
 arithmetic, element-wise/reduction byte counts, ring-allreduce 2N
-wire bytes): the model's job is *ranking* placements and islands, and
+wire bytes): the model's job is *ranking* placements, and
 the calibration report quantifies how well the ranking tracks
 reality instead of pretending the constants are exact.
 
@@ -30,7 +29,7 @@ import numpy as np
 
 from .diagnostics import Diagnostic, Severity
 
-__all__ = ["OpCost", "ProgramCost", "program_cost", "island_cost_rows"]
+__all__ = ["OpCost", "ProgramCost", "program_cost"]
 
 
 def _shape_of(block, name: str, dynamic_dim: int
@@ -240,31 +239,6 @@ def program_cost(program, block_idx: int = 0,
             flops = max(_all_numel(ins), _all_numel(outs))
         rows.append(OpCost(op_idx, op.type, flops, bytes_in, bytes_out))
     return ProgramCost(rows, block_idx, dynamic_dim)
-
-
-def island_cost_rows(program, cost: ProgramCost,
-                     info=None) -> List[Dict[str, Any]]:
-    """Aggregate per-op costs onto the scheduler partition's global
-    island indices, so a zip-by-index comparison against measured
-    per-island rows is valid."""
-    from ..core.scheduler import partition_metadata
-    if info is None:
-        try:
-            info = partition_metadata(program, cost.block_idx)
-        except Exception:
-            return []
-    if not info.eligible:
-        return []
-    by_idx = {r.op_idx: r for r in cost.rows}
-    rows: List[Dict[str, Any]] = []
-    for idx, pi, isl in info.islands():
-        flops = sum(by_idx[i].flops for i in isl.indices if i in by_idx)
-        byt = sum(by_idx[i].bytes_moved for i in isl.indices
-                  if i in by_idx)
-        rows.append({"island": idx, "phase": pi,
-                     "ops": len(isl.indices), "flops": flops,
-                     "bytes": byt})
-    return rows
 
 
 # -- the registered pass ----------------------------------------------------

@@ -16,14 +16,10 @@ Computes, *before anything compiles*, where a step's bytes go:
   snapshot (reported, but only added to the peak while a save is in
   flight — the plan records it separately).
 
-Per-island splits reuse the scheduler's own partition
-(``core.scheduler.partition_metadata``) so the rows line up one-to-one
-with the measured rows ``observability/attribution.island_memory_rows``
-reads from each island executable's ``memory_analysis()``.
-
 The plan is **calibrated**, not trusted: ``reconcile`` compares it
 against the measured owner census (``observability/memory.census``)
-and the compiled per-island attribution, and reports the error ratio.
+and the compiled step's ``memory_analysis()``, and reports the error
+ratio.
 A static plan cannot see XLA's fusion/rematerialization choices or
 allocator padding; the reconciliation quantifies exactly how much that
 costs in accuracy instead of letting the estimate drift silently.
@@ -73,7 +69,7 @@ class MemoryPlan:
     plain ints so ``to_dict`` is JSON-ready."""
 
     __slots__ = ("resident_bytes", "feed_bytes", "transient_peak_bytes",
-                 "overheads", "islands", "top_vars", "assumptions",
+                 "overheads", "top_vars", "assumptions",
                  "block_idx", "label")
 
     def __init__(self):
@@ -81,7 +77,6 @@ class MemoryPlan:
         self.feed_bytes = 0
         self.transient_peak_bytes = 0
         self.overheads: Dict[str, int] = {}
-        self.islands: List[Dict[str, Any]] = []
         self.top_vars: List[Dict[str, Any]] = []
         self.assumptions: Dict[str, Any] = {}
         self.block_idx = 0
@@ -104,7 +99,6 @@ class MemoryPlan:
             "feed_bytes": self.feed_bytes,
             "transient_peak_bytes": self.transient_peak_bytes,
             "overheads": dict(self.overheads),
-            "islands": [dict(r) for r in self.islands],
             "top_vars": [dict(r) for r in self.top_vars],
             "assumptions": dict(self.assumptions),
         }
@@ -120,10 +114,6 @@ class MemoryPlan:
         ]
         for k, v in sorted(self.overheads.items()):
             lines.append(f"  overhead {k}: {v / mb:.2f} MB")
-        for r in self.islands:
-            lines.append(
-                f"  island {r['island']} (phase {r['phase']}, "
-                f"{r['ops']} ops): peak {r['peak_bytes'] / mb:.2f} MB")
         return "\n".join(lines)
 
 
@@ -159,7 +149,7 @@ def plan_memory(program, block_idx: int = 0, feed_names=None,
     (pass the real batch size for calibration runs; the default of 1
     gives a per-sample lower bound and is recorded as an assumption).
     """
-    from ..core.scheduler import op_reads, op_writes, partition_metadata
+    from ..core.islands import op_reads, op_writes
     block = program.block(block_idx)
     ops = list(block.ops)
     plan = MemoryPlan()
@@ -233,26 +223,6 @@ def plan_memory(program, block_idx: int = 0, feed_names=None,
         {"name": n, "bytes": b,
          "resident": n in persistable} for b, n in contrib if b]
 
-    # -- per-island split (mirrors attribution.island_memory_rows) --------
-    try:
-        info = partition_metadata(program, block_idx,
-                                  fetch_names=fetch_names)
-    except Exception:
-        info = None
-    if info is not None and info.eligible:
-        for idx, pi, isl in info.islands():
-            arg = sum(bytes_of(n) for n in isl.in_names)
-            outb = sum(bytes_of(n) for n in isl.out_names)
-            internal = sum(
-                bytes_of(n) for i in isl.indices
-                for n in op_writes(ops[i])
-                if n not in isl.out_names and n not in persistable)
-            plan.islands.append({
-                "island": idx, "phase": pi, "ops": len(isl.indices),
-                "argument_bytes": arg, "output_bytes": outb,
-                "transient_bytes": internal,
-                "peak_bytes": arg + outb + internal})
-
     if include_overheads:
         plan.overheads = _flag_overheads(param_bytes, plan.feed_bytes)
     return plan
@@ -274,15 +244,12 @@ def configured_limit_bytes() -> Optional[int]:
 
 
 def reconcile(plan: MemoryPlan, census: Optional[Dict] = None,
-              island_rows: Optional[List[Dict]] = None,
               measured_step: Optional[Dict] = None) -> Dict[str, Any]:
     """Static-vs-measured reconciliation report.
 
     * ``census`` — ``observability.memory.census()`` output: its
       ``live_bytes`` is compared against the plan's steady-state
       residency (resident + feed + active overheads);
-    * ``island_rows`` — ``attribution.island_memory_rows`` output:
-      per-island measured peaks matched by island index;
     * ``measured_step`` — a compiled step's ``memory_analysis`` split
       (``argument_bytes``/``temp_bytes``): temp is compared against
       the plan's transient peak.
@@ -301,24 +268,6 @@ def reconcile(plan: MemoryPlan, census: Optional[Dict] = None,
         if measured > 0:
             out["resident_error_ratio"] = round(
                 abs(static_resident - measured) / measured, 4)
-    if island_rows:
-        by_idx = {r.get("island"): r for r in plan.islands}
-        rows = []
-        for m in island_rows:
-            s = by_idx.get(m.get("island"))
-            if s is None or not m.get("peak_bytes"):
-                continue
-            rows.append({
-                "island": m.get("island"),
-                "static_peak_bytes": s["peak_bytes"],
-                "measured_peak_bytes": m["peak_bytes"],
-                "error_ratio": round(
-                    abs(s["peak_bytes"] - m["peak_bytes"])
-                    / float(m["peak_bytes"]), 4)})
-        out["islands"] = rows
-        if rows:
-            out["island_mean_error_ratio"] = round(
-                sum(r["error_ratio"] for r in rows) / len(rows), 4)
     if measured_step:
         temp = float(measured_step.get("temp_bytes") or 0.0)
         if temp > 0:

@@ -1,45 +1,37 @@
-"""Island race / donation-hazard detection (the concurrency half of
-the program verifier).
+"""Engine-state, donation and bucket-plan hazard detection (the
+concurrency half of the program verifier).
 
-PR 7's op scheduler dispatches same-phase islands concurrently on
-thread-pool lanes, and the engine donates updated-persistable input
-buffers to XLA; both are safe only under invariants that used to live
-in the builders' heads:
+The engine compiles guard / loss-scale / fingerprint epilogues into the
+same trace as the program's ops, donates updated-persistable input
+buffers to XLA, and fuses gradient all-reduces into bucketed
+collectives; each is safe only under invariants this pass proves:
 
-* no two same-phase islands may touch a common name one of them
-  writes (write-write or read-write on scope vars) — lane timing
-  would otherwise pick the final value;
 * program ops must not read or write the engine's *in-trace* state
   (``@LOSS_SCALE@``, ``@GUARD_*@``, ``@INTEGRITY_*@``,
-  ``@RNG_STATE@``): the engine appends guard / loss-scale /
-  fingerprint epilogues to the same trace, so a user op racing them
-  is a same-trace conflict no scheduler barrier orders;
+  ``@RNG_STATE@``): a user op racing the epilogues is a same-trace
+  conflict nothing orders;
 * a donated / aliased buffer (an updated persistable's input) must
-  not be read by a concurrent island or held by a pending async
-  fetch when the next step's donation invalidates it;
+  not be held by a pending async fetch when the next step's donation
+  invalidates it;
 * a ``c_allreduce_fused`` bucket plan must tile the program's grad
   production order exactly — a dropped, duplicated, or reordered
   member changes the fused payload layout and silently mixes
   tensors (or hangs) on a real ring.
 
-The pass does NOT trust the scheduler's own interface bookkeeping: it
-re-derives each island's first-read and write sets from the op slots
-and proves the pairwise independence afresh, so a partitioner
-regression (union-find, capping, interface computation) surfaces here
-as an ERROR naming the islands, ops, and vars — before any executable
-is built.  `verify_partition` also accepts an externally supplied
-(possibly corrupted) `PartitionInfo`, which is how
-``tools/lint_program.py --check-races --inject ...`` demonstrates each
-defect class.
+The pipeline halves (``verify_stage_partition``,
+``verify_pipeline_schedule``) prove a stage cutting and a micro-batch
+slot table the same way, re-derived from the op slots.
 """
 from __future__ import annotations
 
 import re
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
+from ..core.islands import op_reads as _op_reads
+from ..core.islands import op_writes as _op_writes
 from .diagnostics import Diagnostic, Severity
 
-__all__ = ["verify_partition", "donation_plan", "ENGINE_STATE_RE",
+__all__ = ["donation_plan", "static_updated_names", "ENGINE_STATE_RE",
            "verify_stage_partition", "verify_pipeline_schedule"]
 
 # engine-managed in-trace state: fully-enclosed upper-case @NAME@ vars
@@ -50,111 +42,24 @@ __all__ = ["verify_partition", "donation_plan", "ENGINE_STATE_RE",
 ENGINE_STATE_RE = re.compile(r"^@[A-Z][A-Z0-9_]*@$")
 
 
-def _op_reads(op) -> List[str]:
-    return [n for slot in op.input_slots() for n in op.input(slot) if n]
-
-
-def _op_writes(op) -> List[str]:
-    return [n for slot in op.output_slots() for n in op.output(slot)
-            if n]
-
-
-def _island_sets(ops, isl) -> Tuple[Set[str], Set[str]]:
-    """(first_reads, writes) re-derived from the op slots — the proof
-    deliberately ignores ``isl.in_names``/``isl.writes`` so a stale or
-    buggy interface cannot vouch for itself."""
-    reads: Set[str] = set()
-    writes: Set[str] = set()
-    for i in isl.indices:
-        for n in _op_reads(ops[i]):
-            if n not in writes:
-                reads.add(n)
-        writes.update(_op_writes(ops[i]))
-    return reads, writes
-
-
-def _site_of(ops, indices, name, want_write: bool) -> Tuple[int, str]:
-    """(op_idx, op_type) of the first op in `indices` touching `name`
-    on the relevant side — makes the diagnostic actionable."""
-    for i in indices:
-        names = _op_writes(ops[i]) if want_write else _op_reads(ops[i])
-        if name in names:
-            return i, ops[i].type
-    return indices[0] if indices else -1, "?"
-
-
-def verify_partition(program, info, donated_names=None,
-                     label: Optional[str] = None) -> List[Diagnostic]:
-    """Prove every same-phase island pair of `info` conflict-free.
-
-    `info` is a ``core.scheduler.PartitionInfo`` — normally the one
-    ``partition_metadata`` recomputes from the program, at validation
-    tier 2 the engine's actual traced partition. `donated_names`
-    defaults to the partition's updated persistables (the engine's
-    static donation set); a read-write hazard on a donated name is
-    reported as a donation hazard, since the concurrent reader may
-    observe the donated/aliased buffer mid-update.
-    """
-    ops = info.ops
-    donated = set(donated_names) if donated_names is not None \
-        else set(info.updated_names)
-    diags: List[Diagnostic] = []
-    for phase in info.phases:
-        if len(phase) < 2:
-            continue
-        sets = [_island_sets(ops, isl) for isl in phase]
-        for a in range(len(phase)):
-            for b in range(a + 1, len(phase)):
-                ra, wa = sets[a]
-                rb, wb = sets[b]
-                ww = sorted(wa & wb)
-                for name in ww:
-                    ia, ta = _site_of(ops, phase[a].indices, name, True)
-                    ib, tb = _site_of(ops, phase[b].indices, name, True)
-                    diags.append(Diagnostic(
-                        Severity.ERROR, "island-race",
-                        f"write-write hazard: islands {a} and {b} of "
-                        f"phase {phase[a].phase} both write {name!r} "
-                        f"(op #{ia} {ta!r} vs op #{ib} {tb!r}) — "
-                        f"same-phase islands dispatch concurrently on "
-                        f"scheduler lanes, so the surviving value "
-                        f"depends on lane timing",
-                        op_type=ta, block_idx=info.block_idx,
-                        op_idx=ia, var_names=(name,),
-                        program_label=label))
-                for (ri, wi, i_r, i_w) in ((ra, wb, a, b),
-                                           (rb, wa, b, a)):
-                    for name in sorted((ri & wi) - set(ww)):
-                        ir, tr = _site_of(
-                            ops, phase[i_r].indices, name, False)
-                        iw, tw = _site_of(
-                            ops, phase[i_w].indices, name, True)
-                        if name in donated:
-                            msg = (
-                                f"donation hazard: island {i_r} reads "
-                                f"{name!r} (op #{ir} {tr!r}) while "
-                                f"island {i_w} updates it in place "
-                                f"(op #{iw} {tw!r}) in the same phase "
-                                f"— {name!r} is an updated persistable "
-                                f"whose input buffer the engine "
-                                f"donates, so the concurrent reader "
-                                f"may observe the donated/aliased "
-                                f"buffer mid-update")
-                        else:
-                            msg = (
-                                f"read-write hazard: island {i_r} "
-                                f"reads {name!r} (op #{ir} {tr!r}) "
-                                f"while island {i_w} writes it "
-                                f"(op #{iw} {tw!r}) in the same phase "
-                                f"— concurrent dispatch makes the "
-                                f"observed value depend on lane "
-                                f"timing")
-                        diags.append(Diagnostic(
-                            Severity.ERROR, "island-race", msg,
-                            op_type=tr, block_idx=info.block_idx,
-                            op_idx=ir, var_names=(name,),
-                            program_label=label))
-    return diags
+def static_updated_names(program, block_idx: int = 0) -> List[str]:
+    """Static approximation of the engine's traced ``updated_names``:
+    every persistable var the block writes (param updates, optimizer
+    state, BN running stats). The trace-time set can only be smaller
+    (an op may write a persistable a value identical to its input),
+    which errs conservative for hazard analysis."""
+    block = program.block(block_idx)
+    out: List[str] = []
+    seen: set = set()
+    for op in block.ops:
+        for n in _op_writes(op):
+            if n in seen:
+                continue
+            seen.add(n)
+            v = block._find_var_recursive(n)
+            if v is not None and getattr(v, "persistable", False):
+                out.append(n)
+    return out
 
 
 def donation_plan(program, block_idx: int = 0,
@@ -165,7 +70,6 @@ def donation_plan(program, block_idx: int = 0,
     from its phase-1 trace) and which of them a fetch could alias.
     Consumed by the island-race pass and by observability dashboards.
     """
-    from ..core.scheduler import static_updated_names
     if updated_names is None:
         updated_names = static_updated_names(program, block_idx)
     block = program.block(block_idx)
@@ -342,8 +246,7 @@ def verify_stage_partition(program, cut_vars, block_idx: int = 0,
       the per-stage grads and updates once, so there it is only a
       replication-cost WARNING.
 
-    Same re-derivation stance as ``verify_partition``: the stage
-    read/write sets come from the op slots via
+    The stage read/write sets are re-derived from the op slots via
     ``parallel/auto_cut.stage_partition``, not from any engine
     bookkeeping.
     """
@@ -416,7 +319,7 @@ def verify_stage_partition(program, cut_vars, block_idx: int = 0,
 def verify_pipeline_schedule(events, n_stages: int, n_micro: int,
                              label: Optional[str] = None
                              ) -> List[Diagnostic]:
-    """Prove a pipeline slot table (``core/scheduler.pipeline_schedule``
+    """Prove a pipeline slot table (``parallel/pipeline.pipeline_schedule``
     events ``(tick, device, kind, stage, micro)``) safe before anything
     dispatches: every F/B event exactly once, every event's pipeline
     dependencies strictly earlier (F(s,m) after F(s-1,m); B(s,m) after
@@ -494,18 +397,7 @@ from .passes import register_analysis_pass  # noqa: E402
 
 @register_analysis_pass("island-race")
 def island_race_pass(ctx) -> List[Diagnostic]:
-    """Recompute the scheduler's partition and prove it conflict-free;
-    plus the partition-independent hazards (engine-state conflicts,
-    donated-fetch aliasing, fused-bucket plan divergence)."""
-    from ..core.scheduler import partition_metadata
-    diags = _implicit_state_diags(ctx)
-    diags += _donated_fetch_diags(ctx)
-    diags += _bucket_plan_diags(ctx)
-    try:
-        info = partition_metadata(ctx.program, 0,
-                                  fetch_names=ctx.fetch_names)
-    except Exception:
-        return diags  # unpartitionable = never dispatched concurrently
-    if info.eligible:
-        diags += verify_partition(ctx.program, info, label=ctx.label)
-    return diags
+    """Engine-state conflicts, donated-fetch aliasing and fused-bucket
+    plan divergence."""
+    return (_implicit_state_diags(ctx) + _donated_fetch_diags(ctx)
+            + _bucket_plan_diags(ctx))

@@ -1,8 +1,8 @@
 """Declared feature × execution-path support matrix (docs/STATIC_ANALYSIS.md).
 
-The framework lowers every program along one of four paths — the engine
-whole-block jit trace, the ``FLAGS_op_scheduler`` island dispatch, the
-transpiler-emitted explicit-collective program, and eager dygraph — and
+The framework lowers every program along one of three paths — the
+engine whole-block jit trace, the transpiler-emitted explicit-collective
+program, and eager dygraph — and
 ROADMAP item 5 records that keeping those paths in agreement by hand is
 the dominant cost of every feature.  This module is the *contract* half
 of the conformance verifier (analysis/conformance.py): for every
@@ -34,7 +34,7 @@ __all__ = [
 
 # Execution paths, in reference order: "engine" is the semantics the
 # other paths are compared against.
-PATHS: Tuple[str, ...] = ("engine", "scheduler", "transpiled", "dygraph")
+PATHS: Tuple[str, ...] = ("engine", "transpiled", "dygraph")
 
 # Lowering decisions the conformance trace records per path.
 FEATURES: Tuple[str, ...] = (
@@ -139,37 +139,6 @@ def default_matrix() -> SupportMatrix:
     either fix or keep justified."""
     m = SupportMatrix()
 
-    # -- island scheduler: engine.trace_step takes the island path only
-    #    when `mesh is None` (core/engine.py), so a meshed program always
-    #    falls back to the whole-block jit and islands never see
-    #    multi-device features at all.
-    m.declare(
-        "collective_bucketing", "scheduler", UNSUPPORTED,
-        "engine.trace_step gates the island scheduler on `mesh is "
-        "None`: a meshed program takes the whole-block path, so "
-        "islands never plan or apply gradient buckets (core/engine.py "
-        "scheduler gate; core/scheduler.py).")
-    m.declare(
-        "collective_quantization", "scheduler", UNSUPPORTED,
-        "no collectives on the island path (see collective_bucketing/"
-        "scheduler): there is no bucket payload to quantize.")
-    m.declare(
-        "shard_hints", "scheduler", UNSUPPORTED,
-        "shard_hint() only binds inside a live parallel.strategy "
-        "activation_scope, which the engine opens on the mesh path; "
-        "the island gate requires `mesh is None`, so hints can never "
-        "be live on this path (core/registry.py shard_hint).")
-
-    # -- island scheduler: guard runs, but differently.
-    m.declare(
-        "stability_guard", "scheduler", DEGRADED,
-        "the verdict + update gate run as ONE cached jitted epilogue "
-        "AFTER the islands (ScheduledStep / GuardPlan.run_epilogue) "
-        "instead of inside the step trace; semantics match, but the "
-        "gate is a separate dispatch and donation is off on this "
-        "path, so rollback reads pre-step values from host copies "
-        "(core/scheduler.py, stability/guard.py).")
-
     # -- transpiled programs: engine semantics, except sharding hints.
     m.declare(
         "shard_hints", "transpiled", UNSUPPORTED,
@@ -216,9 +185,10 @@ def default_matrix() -> SupportMatrix:
         "tier2_verifier", "dygraph", DEGRADED,
         "tier-2 re-verification covers the collective bucket plan "
         "(analysis.validate.validate_collective_plan) but there is "
-        "no Program to run partition/race verification against.")
+        "no traced step to cross-check against the static lowering "
+        "(analysis.conformance.crosscheck_traced).")
 
-    # -- engine/scheduler in-trace collectives: emulated global view.
+    # -- engine in-trace collectives: emulated global view.
     m.declare(
         "collective_quantization", "engine", DEGRADED,
         "global-view in-trace collectives EMULATE the all-reduce, so "
@@ -242,11 +212,6 @@ def default_matrix() -> SupportMatrix:
         "with the whole-slab re-dispatch standing in for per-step "
         "re-execution (core/engine.py trace_step multi-step branch).")
     m.declare(
-        "multi_step", "scheduler", UNSUPPORTED,
-        "scheduler_gate returns False for multi_step > 1: island "
-        "lanes dispatch per step and cannot carry the cross-substep "
-        "scan carry (core/scheduler.py scheduler_gate).")
-    m.declare(
         "multi_step", "transpiled", UNSUPPORTED,
         "transpiled programs run process-level SPMD with explicit "
         "c_* collective ops executed per step; no jitted scan driver "
@@ -260,13 +225,6 @@ def default_matrix() -> SupportMatrix:
     #    engine whole-block trace can be frozen into the bucketed
     #    prefill/decode executables the continuous-batching engine
     #    dispatches.
-    m.declare(
-        "serving", "scheduler", UNSUPPORTED,
-        "serving.export freezes a program via trace_step's whole-"
-        "block path with fixed bucketed signatures; island dispatch "
-        "has no single serialized executable to export, and the "
-        "engine gates the scheduler off for inference programs "
-        "anyway (inference/serving/export.py).")
     m.declare(
         "serving", "transpiled", UNSUPPORTED,
         "transpiled programs are process-level SPMD training "
@@ -286,13 +244,6 @@ def default_matrix() -> SupportMatrix:
     #    (SPMD GPipe over the pp axis, MPMD 1F1B per-stage dispatch),
     #    both fed by the same automatic stage cutter.  No other path
     #    can host a cut program.
-    m.declare(
-        "pipeline", "scheduler", UNSUPPORTED,
-        "island lanes dispatch ONE whole program per step and have no "
-        "cross-lane handoff channel, so a stage-cut program cannot "
-        "ride them; the engine also gates islands on `mesh is None` "
-        "while a pp>1 mesh is exactly what pipeline needs "
-        "(core/scheduler.py scheduler_gate; parallel/pipeline.py).")
     m.declare(
         "pipeline", "transpiled", UNSUPPORTED,
         "the transpiler emits process-level SPMD programs with "

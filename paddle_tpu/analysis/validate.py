@@ -15,9 +15,8 @@ from ..core.enforce import EnforceNotMet
 from .diagnostics import Diagnostic, format_report, has_errors
 from .passes import analyze_program
 
-__all__ = ["validate_program", "validate_cached", "validate_traced",
-           "validate_transpiled", "validate_collective_plan",
-           "clear_validation_cache"]
+__all__ = ["validate_program", "validate_cached", "validate_transpiled",
+           "validate_collective_plan", "clear_validation_cache"]
 
 
 def validate_program(program, feed_names=None, fetch_names=(),
@@ -63,36 +62,6 @@ def validate_cached(program, feed_names=None, fetch_names=()) -> None:
 
 def clear_validation_cache() -> None:
     _VALIDATED.clear()
-
-
-def validate_traced(program, block_idx, updated_names, donated_names,
-                    fetch_names=(), label: str = "traced step") -> None:
-    """Validation tier 2: verify the step the engine ACTUALLY traced.
-
-    Tier 1 (``validate_cached``) analyzes the program with statically
-    inferred sets; this hook runs once per engine trace build with the
-    ground truth the trace discovered — the real ``updated_names``
-    (phase-1 abstract trace) and the real donation set — and re-proves
-    the scheduler partition conflict-free under them. Gated by
-    ``FLAGS_validate_program`` + ``FLAGS_validate_tier >= 2`` in
-    ``core/engine.py``; raises ``EnforceNotMet`` on any hazard, before
-    the step is compiled or dispatched."""
-    from ..core.scheduler import partition_metadata
-    from .races import verify_partition
-    info = partition_metadata(program, block_idx,
-                              fetch_names=fetch_names,
-                              updated_names=list(updated_names))
-    if not info.eligible:
-        return
-    diags = verify_partition(program, info,
-                             donated_names=donated_names, label=label)
-    if has_errors(diags):
-        first_err = next(d for d in diags if d.is_error)
-        raise EnforceNotMet(
-            format_report([d for d in diags if d.is_error],
-                          header="traced-step validation failed "
-                                 "(tier 2)"),
-            op_type=first_err.op_type)
 
 
 def validate_transpiled(program, fetch_names=(),

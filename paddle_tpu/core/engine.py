@@ -409,8 +409,8 @@ def _split_first(fn):
     ``jax.random.split`` would split it, ``fn`` runs under the first
     half and the second is handed back as the next state. Inside the
     jitted step the split is part of the executable, so a steady step
-    is ONE executable call; the eager, island and scheduler paths split
-    on the host as they always did."""
+    is ONE executable call; the eager and island fallbacks split on the
+    host."""
 
     @functools.wraps(fn)
     def stepped(donated_params, const_params, feeds, rng):
@@ -423,8 +423,8 @@ def _split_first(fn):
 
 
 def _loop_fallback(fn, iterations):
-    """num_iteration_per_run on the eager/islands paths: host loop with
-    state chained through the updated-persistables dict."""
+    """num_iteration_per_run on the eager and island fallbacks: host
+    loop with state chained through the updated-persistables dict."""
     if iterations <= 1:
         return fn
 
@@ -456,8 +456,8 @@ def _loop_fallback(fn, iterations):
 
 
 def _multi_loop_fallback(fn, k):
-    """PT_MULTI_STEP on the eager/islands paths: host loop over the K
-    stacked batches with the same split-per-substep RNG chain the
+    """PT_MULTI_STEP on the eager and island fallbacks: host loop over
+    the K stacked batches with the same split-per-substep RNG chain the
     compiled scan driver uses, so trajectories stay bit-identical to K
     sequential dispatches. The guard verdict is checked per substep
     (these paths are host-bound anyway) so an anomaly breaks out early
@@ -631,8 +631,8 @@ def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
     # stability guard (docs/STABILITY.md): the verdict + update gate
     # compile INTO the step, its persistent state (EMA, loss scale)
     # joins the donated inputs, and its outputs ride the updated dict —
-    # uniform across the whole-block, scheduler, islands and eager
-    # paths, so the host controller always reads one scope var
+    # uniform across the whole-block, island and eager paths, so the
+    # host controller always reads one scope var
     guard_plan = None
     if FLAGS.stability_guard:
         from ..stability import build_plan, ensure_state
@@ -969,31 +969,6 @@ def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
         ts.integrity_plan = None
         return ts
     updated_names = list(updated_box)
-    from .scheduler import scheduler_gate
-    if scheduler_gate(program, block_idx, fetch_names, mesh=mesh,
-                      iterations=iterations, feed_lods=feed_lods,
-                      integrity_plan=integrity_plan,
-                      multi_step=multi_step)[0]:
-        # programmable operator scheduler (core/scheduler.py,
-        # docs/SCHEDULING.md): data-independent islands dispatched on
-        # concurrent lanes (accum_k == 1) or a pipelined micro-batch
-        # grad-accumulation loop (accum_k > 1). The gate predicate is
-        # shared with the conformance verifier
-        # (analysis/conformance.py) so the static claim about when
-        # islands apply cannot drift from this call site. Returns None
-        # when the block is not schedulable (sub-blocks, single
-        # island, opaque state) — the whole-block jit below stays the
-        # fallback.
-        from .scheduler import build_scheduled_step
-        ts = build_scheduled_step(
-            program, block, params_sig, feed_sig, fetch_names, avail,
-            updated_names, amp_cfg, accum_k, check_nan, fetch_lod_box,
-            uses_rng=uses_rng_box[0], guard_plan=guard_plan)
-        if ts is not None:
-            ts.comm_stats = comm_stats
-            ts.guard_plan = guard_plan
-            ts.integrity_plan = None  # scheduler path: sentinel off
-            return ts
     donated = [n for n in avail if n in updated_names]
     const = [n for n in avail if n not in updated_names]
 
@@ -1323,12 +1298,6 @@ class Engine:
             "collective_bytes": 0, "collective_buckets": 0,
             "collective_quantized": 0, "grad_collectives_per_step": 0,
             "comm_overlap_frac": 0.0,
-            # op scheduler (core/scheduler.py, docs/SCHEDULING.md):
-            # steps through a scheduled TracedStep, max same-phase
-            # island width, grad-accum pipeline host duty cycle, and
-            # cumulative same-phase lane idle time
-            "scheduled_steps": 0, "islands_concurrent": 0,
-            "pipeline_fill_frac": 0.0, "lane_idle_ms": 0.0,
             # stability guard (paddle_tpu/stability,
             # docs/STABILITY.md): anomaly verdicts handled, ghost
             # snapshots captured + capture time, rollbacks performed,
@@ -1525,23 +1494,46 @@ class Engine:
                 for n, a in params.items()}
 
     @staticmethod
-    def _tuning_key_items():
-        """Trace-affecting inputs BOTH cache keys must carry beyond the
-        long-standing flag set: the applied-tuning token (an applied
-        config changes flag/env values the trace read — the token makes
-        pre/post-apply traces distinct even if a knob round-trips), and
-        the env knobs the key audit found missing (the scheduler lane
-        cap shapes the island partition; compiler options and recompute
-        types are baked into the compiled step). The audit test in
-        tests/test_tuning.py asserts every trace-affecting knob in the
-        tuning catalog moves both keys."""
+    def _trace_key_items(program):
+        """Every flag and environment read a trace depends on beyond the
+        call's own arguments, as ONE tuple both cache keys carry — a
+        knob keyed in one key and missed in the other served a stale
+        compiled step once. ``tools/lint_flags.py`` audits trace-time
+        reads against this function, and tests/test_tuning.py asserts
+        every trace-affecting knob of the tuning catalog moves both
+        keys."""
         from ..tuning import state as _tuning_state
-        return (_tuning_state.applied_token(),
-                os.environ.get("PT_SCHED_LANES", ""),
+        return (bool(FLAGS.check_nan_inf),
+                int(getattr(program, "_gradient_accumulation_steps", 1)
+                    or 1),
+                float(FLAGS.allreduce_bucket_mb),
+                str(FLAGS.quantized_allreduce),
+                bool(FLAGS.sharded_weight_update),
+                # the guard's gate (and its policy's damping, spike
+                # threshold, and EMA decay) is baked into the trace, as
+                # are the sentinel's fingerprints + shadow checksums
+                # (their bucket layout follows allreduce_bucket_mb)
+                bool(FLAGS.stability_guard),
+                bool(FLAGS.integrity_sentinel),
+                os.environ.get("PT_STABILITY_POLICY", ""),
+                os.environ.get("PT_GUARD_SPIKE_FACTOR", ""),
+                os.environ.get("PT_GUARD_EMA_BETA", ""),
+                # kernel-registry selection happens at trace time
+                bool(FLAGS.use_custom_kernels),
+                os.environ.get("PT_KERNEL_DENY", ""),
+                os.environ.get("PT_KERNEL_MIN_NUMEL", ""),
+                os.environ.get("PT_KERNEL_QUANT_MATMUL", ""),
+                # the applied-tuning token: an applied config changes
+                # flag/env values the trace read, and the token keeps
+                # pre/post-apply traces distinct even if a knob
+                # round-trips
+                _tuning_state.applied_token(),
+                # compiler options and recompute types are baked into
+                # the compiled step
                 os.environ.get("PT_COMPILER_OPTIONS", ""),
                 os.environ.get("PT_RECOMPUTE", ""),
                 # flash-attention A/B dispatch overrides pick the kernel
-                # at trace time (tools/lint_flags.py found these unkeyed)
+                # at trace time
                 os.environ.get("PT_FORCE_KERNEL", ""),
                 os.environ.get("PT_FORCE_COMPOSED", ""),
                 # multi-axis SPMD placement (analysis/placement.py):
@@ -1561,31 +1553,19 @@ class Engine:
                 os.environ.get("PT_MULTI_STEP", ""))
 
     @staticmethod
+    def _fast_key(program, block_idx, fetch_names, iterations=1,
+                  multi_step=1):
+        """The steady-state key: everything but the feed signature."""
+        return (program.fingerprint, block_idx, tuple(fetch_names),
+                int(iterations), int(multi_step),
+                *Engine._trace_key_items(program))
+
+    @staticmethod
     def _cache_key(program, block_idx, feed_sig_key, fetch_names,
                    iterations=1, multi_step=1):
-        return (program.fingerprint, block_idx, feed_sig_key,
-                tuple(fetch_names), bool(FLAGS.check_nan_inf),
-                int(getattr(program, "_gradient_accumulation_steps", 1)
-                    or 1), int(iterations), int(multi_step),
-                float(FLAGS.allreduce_bucket_mb),
-                str(FLAGS.quantized_allreduce),
-                bool(FLAGS.sharded_weight_update),
-                bool(FLAGS.op_scheduler),
-                bool(FLAGS.stability_guard),
-                # the sentinel's fingerprint + shadow checksums are
-                # compiled into the step (bucket layout follows
-                # allreduce_bucket_mb, already keyed above)
-                bool(FLAGS.integrity_sentinel),
-                os.environ.get("PT_STABILITY_POLICY", ""),
-                # GuardPlan bakes these into the compiled gate too
-                os.environ.get("PT_GUARD_SPIKE_FACTOR", ""),
-                os.environ.get("PT_GUARD_EMA_BETA", ""),
-                # kernel-registry selection happens at trace time
-                bool(FLAGS.use_custom_kernels),
-                os.environ.get("PT_KERNEL_DENY", ""),
-                os.environ.get("PT_KERNEL_MIN_NUMEL", ""),
-                os.environ.get("PT_KERNEL_QUANT_MATMUL", ""),
-                *Engine._tuning_key_items())
+        """The trace-cache key: the fast key plus the feed signature."""
+        return (feed_sig_key,) + Engine._fast_key(
+            program, block_idx, fetch_names, iterations, multi_step)
 
     def compiled_step(self, program, scope: Scope, feed, fetch_names,
                       block_idx: int = 0, iterations: int = 1,
@@ -1725,36 +1705,8 @@ class Engine:
             rows.append({
                 "donated": list(traced.donated_names),
                 "const_count": len(traced.const_names),
-                "updated": list(traced.updated_names),
-                "scheduled": getattr(traced, "op_sched", None)
-                is not None})
+                "updated": list(traced.updated_names)})
         return rows
-
-    def _fast_key(self, program, block_idx, fetch_names, iterations,
-                  multi_step=1):
-        return (program.fingerprint, block_idx, tuple(fetch_names),
-                int(iterations), int(multi_step),
-                bool(FLAGS.check_nan_inf),
-                int(getattr(program, "_gradient_accumulation_steps", 1)
-                    or 1),
-                float(FLAGS.allreduce_bucket_mb),
-                str(FLAGS.quantized_allreduce),
-                bool(FLAGS.sharded_weight_update),
-                bool(FLAGS.op_scheduler),
-                # the guard's gate (and its policy's damping, spike
-                # threshold, and EMA decay) is baked into the trace,
-                # as are the sentinel's fingerprints
-                bool(FLAGS.stability_guard),
-                bool(FLAGS.integrity_sentinel),
-                os.environ.get("PT_STABILITY_POLICY", ""),
-                os.environ.get("PT_GUARD_SPIKE_FACTOR", ""),
-                os.environ.get("PT_GUARD_EMA_BETA", ""),
-                # kernel-registry selection happens at trace time
-                bool(FLAGS.use_custom_kernels),
-                os.environ.get("PT_KERNEL_DENY", ""),
-                os.environ.get("PT_KERNEL_MIN_NUMEL", ""),
-                os.environ.get("PT_KERNEL_QUANT_MATMUL", ""),
-                *Engine._tuning_key_items())
 
     def _fast_feed_arrays(self, entry: _FastPathEntry, feed):
         """Feed dict -> device arrays through the cached signature: no
@@ -2076,19 +2028,10 @@ class Engine:
                             iterations=iterations,
                             multi_step=multi_step)
         if FLAGS.validate_program and int(FLAGS.validate_tier) >= 2:
-            # tier 2: re-verify the step we ACTUALLY traced — the
-            # partition the scheduler would dispatch, proven
-            # conflict-free under the ground-truth updated/donated
-            # sets phase 1 discovered (vs tier 1's static inference at
-            # the executor boundary). Runs once per trace build;
-            # raises before anything compiles.
-            from ..analysis.validate import validate_traced
-            validate_traced(program, block_idx, traced.updated_names,
-                            traced.donated_names, fetch_names)
-            # ... and cross-check the step's lowering decisions (guard
-            # gate, collective plan, island-gate choice) against the
-            # static conformance trace — same tier, same
-            # once-per-trace-build cost (analysis/conformance.py).
+            # tier 2: cross-check the step we ACTUALLY traced (guard
+            # gate, collective plan) against the static conformance
+            # trace; runs once per trace build, raises before anything
+            # compiles (analysis/conformance.py).
             from ..analysis.conformance import crosscheck_traced
             crosscheck_traced(program, block_idx, traced,
                               mesh=self.mesh, data_axis=self.data_axis,
@@ -2201,11 +2144,6 @@ class Engine:
         if comm_stats:
             obs["comm_plan"] = comm_stats.get("plan_id",
                                               comm_stats["buckets"])
-        sched = getattr(traced, "op_sched", None)
-        if sched is not None and sched.last_stats:
-            obs["lanes"] = sched.last_stats.get("spans")
-            obs["phases"]["lane_idle_ms"] = sched.last_stats.get(
-                "lane_idle_ms", 0.0)
         last = getattr(self._stability, "last", None)
         if last and last.get("step") == obs["step"]:
             obs["anomaly"] = dict(last)
@@ -2300,16 +2238,6 @@ class Engine:
                 c["collective_quantized"] += comm_stats["quantized"]
                 c["grad_collectives_per_step"] = comm_stats["buckets"]
                 c["comm_overlap_frac"] = comm_stats["overlap_frac"]
-            sched = getattr(traced, "op_sched", None)
-            if sched is not None and sched.last_stats:
-                st = sched.last_stats
-                c = self.counters
-                c["scheduled_steps"] += 1
-                if "islands_concurrent" in st:
-                    c["islands_concurrent"] = st["islands_concurrent"]
-                if "pipeline_fill_frac" in st:
-                    c["pipeline_fill_frac"] = st["pipeline_fill_frac"]
-                c["lane_idle_ms"] += st.get("lane_idle_ms", 0.0)
             for n, v in updated.items():
                 var = updated_vars.get(n) if updated_vars is not None \
                     else None

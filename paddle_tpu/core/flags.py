@@ -100,10 +100,10 @@ _define("validate_program", False, True,
 _define("validate_tier", 1, True,
         "validation depth when FLAGS_validate_program is on: tier 1 "
         "analyzes the program at the executor boundary with statically "
-        "inferred feed/update sets; tier 2 additionally re-verifies "
-        "each traced step inside the engine against the ground-truth "
-        "updated/donated sets the trace discovered (island races, "
-        "donation hazards) before it compiles — docs/STATIC_ANALYSIS.md")
+        "inferred feed/update sets; tier 2 additionally cross-checks "
+        "each traced step's lowering decisions (guard gate, collective "
+        "plan) against the static lowering trace before it compiles — "
+        "docs/STATIC_ANALYSIS.md")
 # fully-async communicator knobs (reference communicator.cc:29-41)
 _define("communicator_independent_recv_thread", True, True,
         "pull params on an independent thread (reference "
@@ -178,16 +178,6 @@ _define("telemetry", False, True,
         "collectors. Off (default) the step loop pays one boolean "
         "check; the flight recorder still arms itself under a fault "
         "plan or step watchdog so postmortems exist without telemetry")
-_define("op_scheduler", False, True,
-        "programmable operator scheduler (paddle_tpu/core/scheduler): "
-        "partition the block into data-independent islands by def-use "
-        "analysis, dispatch same-phase islands concurrently on dispatch "
-        "lanes, and pipeline the gradient-accumulation micro-batch loop "
-        "so slice k+1's feed/dispatch overlaps slice k's device work. "
-        "Numerically identical to the whole-block jit (per-op RNG keys "
-        "on op uids, not positions); programs it cannot schedule "
-        "(meshes, sub-blocks, LoD feeds, single-island blocks) fall "
-        "back to the standard path (docs/SCHEDULING.md)")
 _define("flight_recorder_steps", 64, True,
         "flight-recorder ring capacity: per-step span records retained "
         "for the postmortem dump (watchdog trip, PT_FAULT_PLAN, sticky "
@@ -198,7 +188,7 @@ _define("use_custom_kernels", True, True,
         "(paddle_tpu/kernels/registry.py): fused Adam/SGD update, "
         "quantized matmul, flash attention. Selection happens at trace "
         "time inside the op lowerings, so the whole-block trace, the "
-        "FLAGS_op_scheduler island path, and dygraph all dispatch from "
+        "eager-island fallback, and dygraph all dispatch from "
         "the same table; ops with no eligible kernel keep the lowered "
         "path bit-identically. Per-kernel denial: PT_KERNEL_DENY="
         "name[,name]; eligibility floor: PT_KERNEL_MIN_NUMEL. On CPU "

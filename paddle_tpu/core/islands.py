@@ -31,7 +31,26 @@ import jax
 import jax.numpy as jnp
 
 from .registry import _RngCtx
-from .scheduler import last_read_table
+
+
+def op_reads(op) -> List[str]:
+    return [n for slot in op.input_slots() for n in op.input(slot) if n]
+
+
+def op_writes(op) -> List[str]:
+    return [n for slot in op.output_slots() for n in op.output(slot)
+            if n]
+
+
+def last_read_table(ops: Sequence, reads_fn=op_reads) -> Dict[str, int]:
+    """name -> highest op index that READS it. One O(ops) pass answers
+    "is this name used at/after index i" without rescanning the op
+    suffix per segment."""
+    table: Dict[str, int] = {}
+    for i, op in enumerate(ops):
+        for n in reads_fn(op):
+            table[n] = i
+    return table
 
 
 def _sig_of(v, lod):
@@ -100,7 +119,7 @@ class IslandRunner:
         if first_dynamic_idx is not None:
             self.dynamic_idx.add(first_dynamic_idx)
         self._segments: Dict[Tuple[int, int], _Segment] = {}
-        # suffix read-set table (scheduler.last_read_table): one O(ops)
+        # suffix read-set table (last_read_table): one O(ops)
         # pass answers "read at/after index i" for every segment,
         # instead of rescanning ops[end:] per segment (O(n²))
         self._last_read = last_read_table(self.ops, self._op_reads)

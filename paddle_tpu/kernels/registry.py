@@ -4,8 +4,8 @@ Kernels (hand-written Pallas TPU programs) register themselves here with
 an op signature — the op types they can stand in for plus an eligibility
 predicate over the concrete operand dtypes/shapes.  Op lowerings in
 ``paddle_tpu/ops`` consult :func:`select` at trace time; because the
-engine whole-block trace, the ``FLAGS_op_scheduler`` island path, and
-dygraph all execute ops through ``OPS.get(op.type).lowering(ctx)``, one
+engine whole-block trace, the eager-island fallback, and dygraph all
+execute ops through ``OPS.get(op.type).lowering(ctx)``, one
 consultation point covers all three — no triple wiring.
 
 Gating, outermost first:

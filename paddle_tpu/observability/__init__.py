@@ -13,9 +13,8 @@ Three layers (docs/OBSERVABILITY.md):
 * :mod:`.tracing` — correlated cross-worker spans with deterministic
   per-step trace ids, RPC context propagation, and fleet skew
   detection (docs/TRACING.md);
-* :mod:`.attribution` — HLO cost/memory + measured device-time
-  attribution per op category and scheduler island, the measured-MFU
-  gauge, and the deep-profile merged-timeline trigger;
+* :mod:`.attribution` — the measured-MFU estimate and the
+  deep-profile merged-timeline trigger;
 * :mod:`.memory` — HBM memory observatory: owner-attributed
   live-buffer census reconciled against ``jax.live_arrays()``,
   OOM/pressure postmortem dumps, and the leak sentinel

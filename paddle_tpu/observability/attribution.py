@@ -1,10 +1,6 @@
 """What the observability layer reads off a compiled step, and the
 deep-profile trigger.
 
-* :func:`island_memory_rows` — per-island ``memory_analysis()`` of a
-  scheduler-split step (``pt_island_hbm_peak_bytes{island}``), pushed
-  to the memory observatory so postmortem dumps carry it
-  (``observability/memory.py``).
 * :func:`mfu_estimate` / :func:`peak_tflops` — FLOPs per step over
   seconds per step against the chip's dense bf16 peak. The caller
   brings both numbers; nothing here times a step or counts FLOPs
@@ -33,7 +29,7 @@ from . import recorder as _recorder
 from . import tracing as _tracing
 
 __all__ = ["mfu_estimate", "PEAK_TFLOPS", "peak_tflops",
-           "island_memory_rows", "request_deep_profile",
+           "request_deep_profile",
            "deep_profile_tick", "deep_profile_active"]
 
 # Dense bf16 matmul peak TFLOP/s per chip, keyed by the device_kind
@@ -78,75 +74,6 @@ def mfu_estimate(flops, seconds_per_step) -> Optional[float]:
     if not flops or not seconds_per_step:
         return None
     return float(flops) / float(seconds_per_step) / (peak * 1e12)
-
-
-def island_memory_rows(engine) -> List[Dict]:
-    """Per-island compiled-memory attribution: lower each scheduler
-    island's own executable against the signatures recorded by the
-    build pass and read its ``memory_analysis()`` —
-    argument/temp/output byte split plus the island peak (argument +
-    temp), exported as ``pt_island_hbm_peak_bytes{island}`` on the
-    scheduler's global island index. Rows are cached
-    on the scheduled step (island signatures are fixed after build, so
-    the lowering cost is paid once) and pushed to the memory
-    observatory so postmortem dumps carry them. Empty when no
-    scheduler-split trace exists."""
-    for traced in list(getattr(engine, "_cache", {}).values()):
-        sched = getattr(traced, "op_sched", None)
-        if sched is None or not getattr(sched, "phases", None):
-            continue
-        rows = getattr(sched, "_mem_rows", None)
-        if rows is None:
-            rows = _island_memory_rows(sched)
-            sched._mem_rows = rows
-        if not rows:
-            continue
-        for r in rows:
-            try:
-                _metrics.gauge("pt_island_hbm_peak_bytes").set(
-                    float(r["peak_bytes"]), island=str(r["island"]))
-            except Exception:
-                pass
-        try:
-            from . import memory as _memory
-            _memory.set_island_attribution(rows)
-        except Exception:
-            pass
-        return [dict(r) for r in rows]
-    return []
-
-
-def _island_memory_rows(sched) -> List[Dict]:
-    sig = getattr(sched, "_final_sig", None)
-    if not sig:
-        return []
-    try:
-        import jax
-        import jax.numpy as jnp
-        # same key signature convention as Engine._compiled_entry
-        key_sig = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    except Exception:
-        return []
-    rows: List[Dict] = []
-    idx = 0
-    for phase in sched.phases:
-        for isl in phase:
-            try:
-                ins_sig = {n: sig[n] for n in isl.in_names if n in sig}
-                ma = isl.jfn.lower(ins_sig, key_sig).compile() \
-                    .memory_analysis()
-                arg = float(getattr(ma, "argument_size_in_bytes", 0.0))
-                tmp = float(getattr(ma, "temp_size_in_bytes", 0.0))
-                outb = float(getattr(ma, "output_size_in_bytes", 0.0))
-                rows.append({
-                    "island": idx, "phase": isl.phase,
-                    "ops": len(isl.indices),
-                    "argument_bytes": arg, "temp_bytes": tmp,
-                    "output_bytes": outb, "peak_bytes": arg + tmp})
-            except Exception:
-                pass  # one un-lowerable island must not kill the rest
-            idx += 1
-    return rows
 
 
 # ---------------------------------------------------------------------------

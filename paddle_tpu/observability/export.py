@@ -317,9 +317,9 @@ def flight_to_chrome_trace(path: str) -> List[dict]:
 
 
 # one lane (tid) per span kind so the timeline groups step roots,
-# phases, scheduler islands, RPC pairs, fetch waits and ckpt writes
-_SPAN_LANES = {"step": 1, "phase": 2, "lane": 3, "rpc.client": 4,
-               "rpc.server": 5, "fetch": 6, "ckpt": 7}
+# phases, RPC pairs, fetch waits and ckpt writes
+_SPAN_LANES = {"step": 1, "phase": 2, "rpc.client": 3,
+               "rpc.server": 4, "fetch": 5, "ckpt": 6}
 
 
 def spans_to_chrome_trace(path: str) -> List[dict]:
@@ -355,9 +355,9 @@ def memdump_to_chrome_trace(path: str) -> List[dict]:
     docs/MEMORY.md) into chrome trace events rendered as a memory
     lane: a counter ('C') event per owner so the owner breakdown
     graphs as stacked area, one counter for live/tagged/orphan
-    totals, plus complete ('X') events for the top live buffers and
-    per-island peaks so the dump's heaviest allocations are
-    inspectable at the dump instant."""
+    totals, plus complete ('X') events for the top live buffers so
+    the dump's heaviest allocations are inspectable at the dump
+    instant."""
     from . import memory as _memory
     d = _memory.read_memdump(path)
     header = d.get("header") or {}
@@ -388,16 +388,6 @@ def memdump_to_chrome_trace(path: str) -> List[dict]:
             "args": {k: b.get(k)
                      for k in ("owner", "label", "bytes", "shape",
                                "dtype") if b.get(k) is not None}})
-    for i, r in enumerate(d.get("islands") or []):
-        events.append({
-            "name": f"island{r.get('island', i)}",
-            "cat": "memory.island", "ph": "X",
-            "ts": ts + i * 1e3, "dur": 1e3, "pid": pid, "tid": 2,
-            "args": {k: r.get(k)
-                     for k in ("island", "phase", "ops",
-                               "argument_bytes", "temp_bytes",
-                               "output_bytes", "peak_bytes")
-                     if r.get(k) is not None}})
     if d.get("donation"):
         events.append({
             "name": "donation", "cat": "memory", "ph": "I",

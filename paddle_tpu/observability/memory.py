@@ -1,8 +1,7 @@
 """HBM memory observatory: owner-attributed live-buffer census,
 OOM/pressure postmortems, and a leak sentinel.
 
-PR 10 attributed device *time* (per-island ms, measured MFU); this
-module attributes device *memory*. The framework holds device-resident
+This module attributes device *memory*. The framework holds device-resident
 state in at least seven places — Scope persistables, the engine
 fast-path caches, the ghost-snapshot ring (stability/), pending
 async-dispatch steps and fetch handles, checkpoint snapshot copies,
@@ -32,7 +31,7 @@ Design (same shape as recorder.py / tracing.py):
   to ``flight_*``/``spans_*`` files as
   ``memdump_<pid>_<reason>_<seq>.jsonl`` (reasons: ``oom``,
   ``watermark``, or caller-supplied), with census / top-buffer /
-  per-island / donation sections. ``PT_HBM_DUMP_THRESHOLD_FRAC`` arms
+  donation sections. ``PT_HBM_DUMP_THRESHOLD_FRAC`` arms
   a rising-edge-debounced dump *before* the crash, mirroring
   ``PT_SKEW_DUMP_THRESHOLD_S`` (tracing.check_skew).
 
@@ -65,8 +64,8 @@ __all__ = [
     "track_predictor", "note_host_bytes",
     "census", "census_active", "census_enabled", "enable", "step_tick",
     "stats", "reset", "LeakSentinel", "leak_sentinel",
-    "check_watermark", "device_limit_bytes", "set_island_attribution",
-    "island_attribution", "donation_stats", "dump", "read_memdump",
+    "check_watermark", "device_limit_bytes",
+    "donation_stats", "dump", "read_memdump",
     "find_memdumps", "is_oom_error", "oom_postmortem",
     "static_plan_report",
 ]
@@ -379,12 +378,10 @@ def last_census() -> Optional[Dict[str, Any]]:
 def static_plan_report(program, feed_names=None, fetch_names=(),
                        dynamic_dim: int = 1,
                        census_snapshot: Optional[Dict[str, Any]] = None,
-                       island_rows: Optional[List[Dict[str, Any]]] = None,
                        ) -> Dict[str, Any]:
     """Calibration hook: run the static HBM planner over ``program``
     and reconcile it against what the observatory actually measured —
-    the census (live resident bytes) and, when available, the
-    per-island compiled ``memory_analysis`` rows. Takes a fresh census
+    the census (live resident bytes). Takes a fresh census
     when the observatory is armed and no snapshot is passed; otherwise
     reuses ``last_census()``. Returns the plan dict plus the error
     ratios ``analysis.memplan.reconcile`` computes — the number the
@@ -396,10 +393,7 @@ def static_plan_report(program, feed_names=None, fetch_names=(),
                                dynamic_dim=dynamic_dim)
     if census_snapshot is None:
         census_snapshot = census() if census_active() else last_census()
-    if island_rows is None:
-        island_rows = island_attribution() or None
-    rec = memplan.reconcile(plan, census=census_snapshot,
-                            island_rows=island_rows)
+    rec = memplan.reconcile(plan, census=census_snapshot)
     out = {"plan": plan.to_dict(), "reconcile": rec}
     try:
         err = rec.get("resident_error_ratio")
@@ -554,29 +548,15 @@ def check_watermark(c: Dict[str, Any]) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# per-island attribution cache + donation effectiveness
+# donation effectiveness
 # ---------------------------------------------------------------------------
-
-_ISLAND_ROWS: List[List[Dict[str, Any]]] = [[]]
-
-
-def set_island_attribution(rows: List[Dict[str, Any]]) -> None:
-    """attribution.island_memory_rows pushes its latest per-island
-    memory split here so postmortem dumps carry it without
-    recompiling."""
-    _ISLAND_ROWS[0] = [dict(r) for r in (rows or [])]
-
-
-def island_attribution() -> List[Dict[str, Any]]:
-    return [dict(r) for r in _ISLAND_ROWS[0]]
-
 
 def donation_stats() -> Dict[str, Any]:
     """Donation effectiveness over live engines' compiled entries:
     ``alias_size_in_bytes`` (bytes XLA actually reused in-place) over
     ``argument_size_in_bytes``, plus donated/const name counts from
     the fast-path entries. Best-effort; zeros when nothing compiled
-    with ``.lower`` (e.g. scheduler-split steps)."""
+    with ``.lower`` (e.g. the eager fallbacks)."""
     out = {"compiled_entries": 0, "argument_bytes": 0, "aliased_bytes": 0,
            "donated_names": 0, "const_names": 0,
            "effectiveness_frac": None}
@@ -622,9 +602,8 @@ def dump(reason: str, census_snapshot: Optional[Dict[str, Any]] = None,
          directory: Optional[str] = None) -> Optional[str]:
     """Write ``memdump_<pid>_<reason>_<seq>.jsonl`` next to the flight
     dumps: one ``mem_header`` line, one ``census`` line, top-N
-    ``buffer`` lines, per-island ``island`` lines, one ``donation``
-    line. Never raises (postmortem paths are already failing);
-    returns the path or None."""
+    ``buffer`` lines, one ``donation`` line. Never raises (postmortem
+    paths are already failing); returns the path or None."""
     try:
         c = census_snapshot if census_snapshot is not None \
             else census(top_n=_TOP_N_DUMP)
@@ -638,18 +617,6 @@ def dump(reason: str, census_snapshot: Optional[Dict[str, Any]] = None,
                   "counters": _recorder._engine_counter_snapshot()}
         if extra:
             header.update(extra)
-        rows = _ISLAND_ROWS[0]
-        if not rows:
-            # best-effort refresh: cached on the scheduled step, so
-            # this only compiles if nothing attributed islands yet
-            try:
-                from . import attribution as _attr
-                for eng in list(getattr(_metrics, "_ENGINES", ()) or ()):
-                    rows = _attr.island_memory_rows(eng)
-                    if rows:
-                        break
-            except Exception:
-                rows = []
         with open(path, "w", encoding="utf-8") as f:
             def _w(rec):
                 f.write(json.dumps(rec, default=_recorder._json_fallback)
@@ -659,8 +626,6 @@ def dump(reason: str, census_snapshot: Optional[Dict[str, Any]] = None,
                 **{k: v for k, v in c.items() if k != "top_buffers"}})
             for b in c.get("top_buffers", []):
                 _w({"kind": "buffer", **b})
-            for r in rows or []:
-                _w({"kind": "island", **r})
             _w({"kind": "donation", **donation_stats()})
         _STATS["dumps"] += 1
         try:
@@ -684,9 +649,9 @@ def find_memdumps(directory: Optional[str] = None) -> List[str]:
 
 def read_memdump(path: str) -> Dict[str, Any]:
     """Parse one memdump into
-    ``{header, census, buffers[], islands[], donation}``."""
+    ``{header, census, buffers[], donation}``."""
     out: Dict[str, Any] = {"header": None, "census": None, "buffers": [],
-                           "islands": [], "donation": None}
+                           "donation": None}
     with open(path, "r", encoding="utf-8") as f:
         for line in f:
             line = line.strip()
@@ -703,8 +668,6 @@ def read_memdump(path: str) -> Dict[str, Any]:
                 out["census"] = rec
             elif kind == "buffer":
                 out["buffers"].append(rec)
-            elif kind == "island":
-                out["islands"].append(rec)
             elif kind == "donation":
                 out["donation"] = rec
     return out
@@ -802,7 +765,6 @@ def reset() -> None:
     _TICK[0] = 0
     _WM_ARMED[0] = False
     _SENTINEL[0] = None
-    _ISLAND_ROWS[0] = []
     _LAST_CENSUS[0] = None
     for k in _STATS:
         _STATS[k] = 0

@@ -317,8 +317,7 @@ class EngineCounters(dict):
 
 # engine counters that are point-in-time gauges, not monotonic totals
 _ENGINE_GAUGE_KEYS = frozenset({
-    "ckpt_inflight", "grad_collectives_per_step", "comm_overlap_frac",
-    "islands_concurrent", "pipeline_fill_frac"})
+    "ckpt_inflight", "grad_collectives_per_step", "comm_overlap_frac"})
 
 _ENGINES: "weakref.WeakSet" = weakref.WeakSet()
 
@@ -403,11 +402,6 @@ def _install_standard_families(reg: MetricsRegistry) -> None:
                   "synchronous fetch D2H per step (0-cost deferred "
                   "under FLAGS_async_dispatch)")
     reg.histogram("pt_step_total_seconds", "whole Engine.run() call")
-    reg.histogram("pt_step_lane_idle_seconds",
-                  "per-step dispatch-lane idle time under the op "
-                  "scheduler: sum over same-phase concurrent islands "
-                  "of (phase window - island dispatch span); 0 when "
-                  "FLAGS_op_scheduler is off (docs/SCHEDULING.md)")
     # checkpoint subsystem
     reg.histogram("pt_ckpt_save_seconds",
                   "background shard write + commit per save")
@@ -550,10 +544,6 @@ def _install_standard_families(reg: MetricsRegistry) -> None:
     reg.gauge("pt_hbm_live_bytes",
               "total non-deleted jax.live_arrays() bytes at the last "
               "census (the census denominator)")
-    reg.gauge("pt_island_hbm_peak_bytes",
-              "per-scheduler-island compiled HBM peak, labeled "
-              "{island}: memory_analysis temp + argument bytes of the "
-              "island's own executable")
     reg.gauge("pt_hbm_leak_suspect_bytes",
               "leak-sentinel verdict, labeled {owner}: window growth "
               "in bytes for owners whose census bytes rose "

@@ -41,7 +41,7 @@ __all__ = ["FlightRecorder", "flight_recorder", "record_step", "dump",
            "find_dumps", "summarize_dumps", "install_sigterm_hook"]
 
 # a step record's phase keys, in the order the phases run (the slots
-# of profiler.StepClock); `total_ms` and `lane_idle_ms` ride beside them
+# of profiler.StepClock); `total_ms` rides beside them
 PHASE_KEYS = ("executor_feed_ms", "feed_ms", "trace_ms", "args_ms",
               "rng_ms", "dispatch_ms", "release_ms", "writeback_ms",
               "fetch_ms")
@@ -196,9 +196,7 @@ def record_step(rec: dict) -> None:
                           ("trace_ms", "pt_step_trace_seconds"),
                           ("dispatch_ms", "pt_step_dispatch_seconds"),
                           ("fetch_ms", "pt_step_fetch_seconds"),
-                          ("total_ms", "pt_step_total_seconds"),
-                          ("lane_idle_ms",
-                           "pt_step_lane_idle_seconds")):
+                          ("total_ms", "pt_step_total_seconds")):
             v = phases.get(key)
             if v is not None:
                 h = reg.get(name)
@@ -294,7 +292,7 @@ def summarize_dumps(directory: Optional[str] = None,
         steps = [r.get("step") for r in recs
                  if r.get("step") is not None]
         phases: Dict[str, float] = {}
-        for key in PHASE_KEYS + ("total_ms", "lane_idle_ms"):
+        for key in PHASE_KEYS + ("total_ms",):
             vals = [r["phases"][key] for r in recs
                     if r.get("phases", {}).get(key) is not None]
             if vals:

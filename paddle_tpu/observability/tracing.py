@@ -12,7 +12,7 @@ cross-worker correlation. This module adds the correlation layer:
   id without any coordination or randomness.
 * **Spans.** One bounded ring of span dicts (``trace``/``span``/
   ``parent``/``name``/``kind``/``worker``/``t0``/``dur_ms`` + an
-  ``ann`` annotation dict). The engine derives step/phase/lane spans
+  ``ann`` annotation dict). The engine derives step/phase spans
   from the obs record it already builds (:func:`finish_step`), the RPC
   layer records client and server spans, async-dispatch fetch handles
   record their materialization waits, and the checkpoint manager its
@@ -641,15 +641,15 @@ def _listen():
 
 
 # ---------------------------------------------------------------------------
-# engine hook: derive step/phase/lane spans from the obs record
+# engine hook: derive step/phase spans from the obs record
 # ---------------------------------------------------------------------------
 
 
 
 def finish_step(obs: dict) -> None:
-    """Close out one step's trace: emit the root step span, one child
-    per measured phase, and one grandchild per scheduler-lane island
-    span — all derived from the stamps the engine's StepClock took
+    """Close out one step's trace: emit the root step span and one
+    child per measured phase — all derived from the stamps the engine's
+    StepClock took
     (``profiler.py``), so tracing adds no clocks to the hot path. A
     phase span starts where its stamp says: ``obs["phase_t0_ms"]``
     holds each phase's offset from the step's start (a record without
@@ -675,32 +675,16 @@ def finish_step(obs: dict) -> None:
                 span_id=root, parent=None, ann=ann)
     starts = obs.get("phase_t0_ms")
     off = 0.0
-    dispatch_t0, dispatch_sid = t0, root
     for key in _recorder.PHASE_KEYS:
         v = phases.get(key)
         if not v:
             continue
         if starts is not None:
             off = float(starts.get(key) or 0.0)
-        rec = record_span(key[:-3], t0 + off / 1e3, float(v),
-                          kind="phase", trace=trace, parent=root,
-                          ann={"step": step})
-        if key == "dispatch_ms" and rec is not None:
-            dispatch_t0, dispatch_sid = t0 + off / 1e3, rec["span"]
+        record_span(key[:-3], t0 + off / 1e3, float(v),
+                    kind="phase", trace=trace, parent=root,
+                    ann={"step": step})
         off += float(v)
-    for lane in obs.get("lanes") or ():
-        la = {"step": step, "phase": lane.get("phase"),
-              "ops": lane.get("ops"), "island": lane.get("i")}
-        if "micro_batch" in lane:
-            la["micro_batch"] = lane["micro_batch"]
-            name = f"micro_batch:{lane['micro_batch']}"
-        else:
-            la["lane"] = lane.get("lane")
-            name = f"island:{lane.get('i', lane.get('lane'))}"
-        record_span(name, dispatch_t0 + float(lane.get("t0_ms") or 0.0)
-                    / 1e3, float(lane.get("dur_ms") or 0.0),
-                    kind="lane", trace=trace, parent=dispatch_sid,
-                    ann=la)
     if total_ms:
         note_step_duration(total_ms / 1e3, step=step)
 

@@ -80,7 +80,7 @@ class MPMDPipelineEngine:
     structure still holds, only the overlap disappears).
 
     ``schedule`` picks the micro-batch dispatch order
-    (core/scheduler.pipeline_schedule): "1f1b" (default) drains each
+    (parallel/pipeline.pipeline_schedule): "1f1b" (default) drains each
     backward as soon as it is ready, capping the activation stash at
     the pipeline depth; "gpipe" is the legacy fill/drain. Both
     execute the same F/B events with the same fold_in keys, so the
@@ -300,7 +300,7 @@ class MPMDPipelineEngine:
         # (analysis/races.verify_pipeline_schedule) before anything
         # dispatches.
         import time
-        from ..core.scheduler import pipeline_schedule
+        from .pipeline import pipeline_schedule
         sched = pipeline_schedule(self.n_stages, n_micro,
                                   self.n_devices, kind=self.schedule)
         self._verify_schedule(sched)
@@ -430,7 +430,7 @@ class MPMDPipelineEngine:
 
     def _record_stats(self, sched, spans, dispatch_ms, window_ms,
                       stash_peak, xfer_bytes):
-        from ..core.scheduler import gpipe_bubble_fraction
+        from .pipeline import gpipe_bubble_fraction
         self.last_stats = {
             "schedule": self.schedule,
             "n_stages": self.n_stages,

@@ -42,7 +42,7 @@ stages.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -55,6 +55,124 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core.registry import OPS, ExecContext, _RngCtx
 from ..core.engine import run_block_ops, _collect_persistable_inputs
 from ..core.scope import LoDTensor, Scope
+
+
+# ---------------------------------------------------------------------------
+# pipeline micro-batch schedules (GPipe fill/drain vs interleaved 1F1B)
+# ---------------------------------------------------------------------------
+# The SPMD tick loop below IS the GPipe fill/drain; the MPMD engine
+# (parallel/mpmd_pipeline.py) dispatches forward/backward slots of many
+# per-stage executables, and the schedule below decides the slot ORDER.
+
+
+def gpipe_bubble_fraction(n_stages: int, n_micro: int) -> float:
+    """Analytic GPipe fill/drain bubble: (S-1)/(M+S-1)."""
+    s, m = int(n_stages), int(n_micro)
+    return (s - 1) / float(m + s - 1) if m + s > 1 else 0.0
+
+
+def interleaved_bubble_fraction(n_devices: int, n_micro: int,
+                                n_chunks: int) -> float:
+    """Analytic interleaved-1F1B bubble: (D-1)/(V*M + D-1) for D
+    devices each hosting V model chunks (Megatron-style virtual
+    stages). V=1 degenerates to the GPipe fraction."""
+    d, m, v = int(n_devices), int(n_micro), max(1, int(n_chunks))
+    return (d - 1) / float(v * m + d - 1) if v * m + d > 1 else 0.0
+
+
+def pipeline_schedule(n_stages: int, n_micro: int,
+                      n_devices: int = None,
+                      kind: str = "1f1b") -> Dict[str, Any]:
+    """Build a static pipeline micro-batch schedule as a slot table.
+
+    Stages are assigned round-robin to devices (``device = stage %
+    n_devices``), so ``n_stages > n_devices`` means each device hosts
+    ``V = n_stages / n_devices`` interleaved model chunks — the
+    Megatron-style virtual-stage layout that shrinks the 1F1B bubble
+    from (D-1)/(M+D-1) to (D-1)/(V*M+D-1).
+
+    The table is produced by a deterministic list-scheduling pass over
+    the F/B dependence DAG (F(s,m) needs F(s-1,m); B(s,m) needs F(s,m)
+    and B(s+1,m)), one unit-time slot per event per device tick:
+
+    * ``kind="gpipe"``  — forwards before backwards (fill/drain);
+    * ``kind="1f1b"``   — each device runs forwards only up to its
+      warmup quota of un-drained micro-batches (the Megatron warmup
+      count, ``2*(D-d-1) + (V-1)*D + 1``), then prefers the readiest
+      backward — highest chunk first, oldest micro first — which caps
+      the activation stash at the pipeline depth and reaches the
+      analytic interleaved bubble (D-1)/(V*M+D-1).
+
+    Returns ``{"events": [(tick, device, kind, stage, micro), ...] in
+    dispatch order, "makespan", "bubble_frac" (measured from the slot
+    table: idle device-slots / total device-slots), "stash_peak"
+    (max in-flight forward stashes), "kind", "n_chunks"}``.
+    """
+    S, M = int(n_stages), int(n_micro)
+    D = int(n_devices) if n_devices else S
+    if S < 1 or M < 1 or D < 1:
+        raise ValueError(f"pipeline_schedule: need n_stages/n_micro/"
+                         f"n_devices >= 1, got {S}/{M}/{D}")
+    if kind not in ("gpipe", "1f1b"):
+        raise ValueError(f"pipeline_schedule: unknown kind {kind!r}")
+    dev_of = [s % D for s in range(S)]
+    n_chunks = (S + D - 1) // D
+    done: set = set()          # completed events ("F"|"B", s, m)
+    pending = {("F", s, m) for s in range(S) for m in range(M)}
+    pending |= {("B", s, m) for s in range(S) for m in range(M)}
+
+    def _ready(ev):
+        k, s, m = ev
+        if k == "F":
+            return s == 0 or ("F", s - 1, m) in done
+        if ("F", s, m) not in done:
+            return False
+        return s == S - 1 or ("B", s + 1, m) in done
+
+    def _quota(d):
+        return 2 * (D - d - 1) + (n_chunks - 1) * D + 1
+
+    def _prio(ev, prefer_b):
+        k, s, m = ev
+        chunk = s // D
+        if k == "F":
+            return (1 if prefer_b else 0, m, chunk)
+        # backwards drain the HIGHEST chunk first (it unblocks the
+        # reverse wavefront of every lower chunk), oldest micro first
+        return (0 if prefer_b else 1, -chunk, m)
+
+    events: List[Tuple[int, int, str, int, int]] = []
+    dev_flight = [0] * D
+    stash_peak = 0
+    tick = 0
+    while pending:
+        fired = []
+        for d in range(D):
+            cand = [ev for ev in pending
+                    if dev_of[ev[1]] == d and _ready(ev)]
+            if not cand:
+                continue
+            prefer_b = (kind == "1f1b" and
+                        dev_flight[d] >= _quota(d))
+            fired.append(min(
+                cand, key=lambda ev: _prio(ev, prefer_b)))
+        if not fired:  # cannot happen on a well-formed DAG
+            raise RuntimeError("pipeline_schedule: deadlock")
+        for ev in fired:
+            pending.discard(ev)
+            events.append((tick, dev_of[ev[1]], ev[0], ev[1], ev[2]))
+        for ev in fired:
+            done.add(ev)
+            dev_flight[dev_of[ev[1]]] += 1 if ev[0] == "F" else -1
+        stash_peak = max(stash_peak, sum(dev_flight))
+        tick += 1
+    makespan = tick
+    busy = 2 * S * M
+    bubble = 1.0 - busy / float(D * makespan) if makespan else 0.0
+    return {"events": events, "makespan": makespan,
+            "bubble_frac": round(bubble, 6), "stash_peak": stash_peak,
+            "kind": kind, "n_chunks": n_chunks, "n_devices": D,
+            "n_stages": S, "n_micro": M}
 
 
 def _producer_index(ops, name):
@@ -290,7 +408,6 @@ class PipelineEngine:
         loop IS the GPipe fill/drain, so its bubble is the analytic
         (S-1)/(M+S-1); activation-exchange bytes count every ppermute
         tick's buffer."""
-        from ..core.scheduler import gpipe_bubble_fraction
         from .auto_cut import _var_bytes
         S, M = self.n_stages, self.n_micro
         block = self.program.block(0)

@@ -166,7 +166,7 @@ class GuardPlan:
         return sorted(set(self.input_state_names())
                       | set(self.output_names()))
 
-    # -- epilogue entry point (scheduler / islands paths) ---------------
+    # -- epilogue entry point (island fallback, apply_post) -------------
     def run_epilogue(self, env: dict, orig: dict,
                      fetch_names: Sequence[str],
                      gate_names: Sequence[str]) -> None:
@@ -311,7 +311,7 @@ def _verdict_math(plan: GuardPlan, loss_vals, grad_vals,
     """The fused verdict: finite-AND over every watched tensor, grad
     global norm vs its EMA, loss-scale bookkeeping. Pure jnp — runs
     inside the step trace (whole-block) or inside the cached epilogue
-    jit (scheduler/islands)."""
+    jit (island fallback)."""
     f32 = jnp.float32
     finite = jnp.asarray(True)
     for v in loss_vals:

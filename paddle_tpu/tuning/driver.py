@@ -86,12 +86,6 @@ def _attr_signals(engine, c0: Dict[str, float], steps: int
     c = engine.counters
     steps = max(1, int(steps))
     sig: Dict[str, float] = {}
-    # sched_lanes <- pt_step_lane_idle_seconds: lanes idling inside the
-    # scheduler's phase windows
-    lane = float(c.get("lane_idle_ms", 0.0)) - \
-        float(c0.get("lane_idle_ms", 0.0))
-    if lane > 0:
-        sig["lane_idle_ms"] = lane / steps
     # allreduce_bucket_mb <- comm-overlap fraction (only meaningful
     # when the trial actually moved collective bytes)
     if float(c.get("collective_bytes", 0.0)) > \
@@ -115,7 +109,6 @@ def _attr_score(wall_ms: float, sig: Dict[str, float]) -> float:
     time). With every signal absent this IS the wall objective."""
     cap = wall_ms * 0.5
     s = wall_ms
-    s += min(sig.get("lane_idle_ms", 0.0), cap)
     if "comm_overlap_frac" in sig:
         s += min((1.0 - sig["comm_overlap_frac"]) * wall_ms * 0.25,
                  cap)
@@ -226,7 +219,7 @@ def search_config(engine, program, scope, place, feed, fetch_names,
         # keeps the search off the training trajectory
         restore_scope(scope, scope_snap)
         c0 = {k: float(engine.counters.get(k, 0.0))
-              for k in ("lane_idle_ms", "collective_bytes",
+              for k in ("collective_bytes",
                         "multistep_substeps", "multistep_dispatches")}
         with knobs.applied(config):
             ms = _step_ms(engine, program, scope, place, feed,

@@ -1,8 +1,7 @@
 """Declarative registry of every tunable knob (docs/TUNING.md).
 
 Until this PR, the config surface ROADMAP item 3 calls "flag
-archaeology" was scattered: ``core/scheduler.py`` read
-``PT_SCHED_LANES`` at import time, ``kernels/registry.py`` parsed
+archaeology" was scattered: ``kernels/registry.py`` parsed
 ``PT_KERNEL_MIN_NUMEL``/``PT_KERNEL_DENY`` inline, the prefetcher
 depth had no knob at all, and nothing recorded which knobs change
 numerics or compiled-trace content. This module is the single catalog:
@@ -14,15 +13,15 @@ numerics or compiled-trace content. This module is the single catalog:
   ``PT_TUNE_ALLOW_LOSSY=1``;
 * ``trace_affecting`` marks knobs that change compiled-trace content —
   the audit test asserts every one of them shows up in BOTH engine
-  cache keys (``_cache_key`` and ``_fast_key``), the invariant PR 8's
-  review had to patch twice;
+  cache keys (``_cache_key`` and ``_fast_key``, built from one shared
+  tuple);
 * :func:`apply`/:func:`restore`/:func:`applied` snapshot the RAW
   backing state (env-var presence included) and put it back exactly,
   even when a trial raises mid-flight — tuning must never leak knob
   state into training.
 
-Runtime readers (scheduler lanes, kernel eligibility floor, prefetch
-depth, ghost cadence) call :func:`value` instead of ``os.getenv`` so a
+Runtime readers (kernel eligibility floor, prefetch depth, ghost
+cadence) call :func:`value` instead of ``os.getenv`` so a
 runtime change — ``set_flags``, ``os.environ``, or an applied tuning
 config — takes effect without re-import.
 """
@@ -126,11 +125,6 @@ def _def(name, kind, key, type_, default, candidates, *, lossy=False,
 
 # -- the catalog (docs/TUNING.md keeps the prose version) -------------------
 
-_def("sched_lanes", "env", "PT_SCHED_LANES", int, 4, (2, 4, 8),
-     trace_affecting=True,
-     help="op-scheduler dispatch lanes AND same-phase island cap "
-          "(core/scheduler.py); the cap shapes the island partition, "
-          "so the compiled scheduled step depends on it")
 _def("allreduce_bucket_mb", "flag", "FLAGS_allreduce_bucket_mb", float,
      32.0, (8.0, 32.0, 128.0), trace_affecting=True,
      help="comm-scheduler fused-allreduce bucket cap in MB "
@@ -140,10 +134,6 @@ _def("quantized_allreduce", "flag", "FLAGS_quantized_allreduce", str,
      "", ("", "bf16", "int8"), lossy=True, trace_affecting=True,
      help="on-the-wire bucket quantization; changes gradient numerics "
           "(docs/COLLECTIVES.md tolerance accounting)")
-_def("op_scheduler", "flag", "FLAGS_op_scheduler", bool, False,
-     (False, True), trace_affecting=True,
-     help="concurrent island dispatch; bit-identical to the "
-          "whole-block jit by construction (docs/SCHEDULING.md)")
 _def("kernel_min_numel", "env", "PT_KERNEL_MIN_NUMEL", int, 65536,
      (16384, 65536, 262144), trace_affecting=True,
      help="eligibility floor for size-gated custom kernels "

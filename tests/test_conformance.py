@@ -1,8 +1,7 @@
 """Cross-path lowering conformance verifier (docs/STATIC_ANALYSIS.md).
 
-The claim under test: the engine whole-block path, the island
-scheduler, the collective transpiler, and dygraph lower every book
-model IDENTICALLY, except where analysis/support_matrix.py declares
+The claim under test: the engine whole-block path, the collective
+transpiler, and dygraph lower every book model IDENTICALLY, except where analysis/support_matrix.py declares
 (and justifies) a gap. Undeclared divergence is an error; a supplied
 trace that disagrees with its own path's fresh extraction (the
 injected-drift self-test) is an error even when every cross-path cell
@@ -60,7 +59,7 @@ def _traces(name, with_shard=True):
 
 
 # ---------------------------------------------------------------------------
-# the headline invariant: book models × 4 paths, zero undeclared drift
+# the headline invariant: book models × 3 paths, zero undeclared drift
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("name", sorted(lint_program.MODELS))

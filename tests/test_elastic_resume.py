@@ -244,27 +244,33 @@ def _train_steps(exe, main, loss, rdr, total, start=0, kill_at=None,
 
 
 @pytest.mark.parametrize("kill_at,variant", [
-    (2, "plain"), (5, "scheduler"), (7, "async_dispatch"),
-], ids=["kill2_plain", "kill5_scheduler", "kill7_async"])
+    (2, "plain"), (5, "grad_accum"), (7, "async_dispatch"),
+], ids=["kill2_plain", "kill5_grad_accum", "kill7_async"])
 def test_kill_at_step_resume_is_bit_identical(tmp_path, kill_at,
                                               variant):
     """Exactly-once resume: kill the run at an arbitrary step, restart
     from the TrainState checkpoint (global step + reader cursors), and
     the stitched trajectory must be BIT-identical to an uninterrupted
-    run — no batch repeated, none skipped — on the plain, op-scheduler
-    and async-dispatch engine paths alike."""
+    run — no batch repeated, none skipped — on the plain,
+    gradient-accumulation and async-dispatch engine paths alike."""
     from paddle_tpu.checkpoint import (CheckpointManager,
                                        register_reader,
                                        unregister_reader)
-    flags = {"scheduler": {"FLAGS_op_scheduler": True},
-             "async_dispatch": {"FLAGS_async_dispatch": True}}.get(
-                 variant, {})
+    flags = {"async_dispatch": {"FLAGS_async_dispatch": True}}.get(
+        variant, {})
+
+    def build():
+        main, startup, loss = _build()
+        if variant == "grad_accum":
+            main._gradient_accumulation_steps = 2
+        return main, startup, loss
+
     total = 12
     ckpt = str(tmp_path / "ckpt")
     fluid.set_flags(flags)
     try:
         # uninterrupted reference run (snapshot the init for the rest)
-        main, startup, loss = _build()
+        main, startup, loss = build()
         scope = Scope()
         with fluid.scope_guard(scope):
             exe = fluid.Executor(fluid.CPUPlace())
@@ -278,7 +284,7 @@ def test_kill_at_step_resume_is_bit_identical(tmp_path, kill_at,
 
         # killed run: same init, TrainState-checkpoint every step,
         # stop cold at kill_at (scope + engine + reader all dropped)
-        main2, startup2, loss2 = _build()
+        main2, startup2, loss2 = build()
         scope_a = Scope()
         rdr_a = _pipeline()
         register_reader("train", rdr_a)
@@ -299,7 +305,7 @@ def test_kill_at_step_resume_is_bit_identical(tmp_path, kill_at,
 
         # relaunched incarnation: fresh everything; maybe_restore
         # delivers params + global step + the reader cursor
-        main3, startup3, loss3 = _build()
+        main3, startup3, loss3 = build()
         scope_b = Scope()
         rdr_b = _pipeline()
         register_reader("train", rdr_b)

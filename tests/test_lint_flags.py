@@ -17,11 +17,11 @@ import lint_flags  # noqa: E402  (tools/lint_flags.py)
 
 def test_keyed_names_cover_the_long_standing_set():
     keyed = lint_flags.keyed_names()
-    # spot-check both kinds: flags folded into _cache_key/_fast_key
-    # and env vars folded into _tuning_key_items
-    for name in ("FLAGS.check_nan_inf", "FLAGS.op_scheduler",
+    # spot-check both kinds: flags and env vars folded into the one
+    # tuple both keys carry (_trace_key_items)
+    for name in ("FLAGS.check_nan_inf", "FLAGS.stability_guard",
                  "FLAGS.use_custom_kernels", "PT_STABILITY_POLICY",
-                 "PT_SCHED_LANES", "PT_COMPILER_OPTIONS",
+                 "PT_RECOMPUTE", "PT_COMPILER_OPTIONS",
                  "PT_FORCE_KERNEL", "PT_FORCE_COMPOSED"):
         assert name in keyed, name
 
@@ -49,8 +49,8 @@ def test_planted_uncached_env_read_is_flagged(tmp_path, capsys):
                 return "wide"
             if FLAGS.check_nan_inf:     # keyed: must NOT be flagged
                 return "checked"
-            if getattr(FLAGS, "op_scheduler", False):  # keyed too
-                return "sched"
+            if getattr(FLAGS, "stability_guard", False):  # keyed too
+                return "guarded"
             return "narrow"
     """))
     rc = lint_flags.run([str(planted)])
@@ -58,7 +58,7 @@ def test_planted_uncached_env_read_is_flagged(tmp_path, capsys):
     assert rc == lint_flags.EXIT_FINDINGS
     assert "PT_BOGUS_TRACE_KNOB" in out
     assert "check_nan_inf" not in out
-    assert "op_scheduler" not in out
+    assert "stability_guard" not in out
 
 
 def test_planted_unkeyed_flag_read_is_flagged(tmp_path, capsys):

@@ -24,7 +24,7 @@ from paddle_tpu.stability.ghost import GhostRing  # noqa: E402
 
 NEW_FAMILIES = (
     "pt_hbm_owner_bytes", "pt_hbm_live_bytes",
-    "pt_island_hbm_peak_bytes", "pt_hbm_leak_suspect_bytes",
+    "pt_hbm_leak_suspect_bytes",
     "pt_memdumps_total", "pt_oom_postmortems_total")
 
 
@@ -291,10 +291,6 @@ class TestDumpsAndWatermark(unittest.TestCase):
     def test_dump_roundtrip_has_all_sections(self):
         scope = _device_scope({"w": np.ones((8, 8), np.float32)})
         memory.track_scope(scope)
-        memory.set_island_attribution(
-            [{"island": 0, "phase": "forward", "ops": 3,
-              "argument_bytes": 256, "temp_bytes": 64,
-              "output_bytes": 128, "peak_bytes": 320}])
         path = memory.dump("manual", extra={"note": "t"})
         self.assertIsNotNone(path)
         self.assertEqual(memory.find_memdumps(self.dir), [path])
@@ -305,7 +301,8 @@ class TestDumpsAndWatermark(unittest.TestCase):
         self.assertEqual(d["census"]["owners"]["scope"]["bytes"], 256)
         self.assertTrue(d["buffers"])
         self.assertEqual(d["buffers"][0]["kind"], "buffer")
-        self.assertEqual(d["islands"][0]["peak_bytes"], 320)
+        self.assertEqual(set(d), {"header", "census", "buffers",
+                                  "donation"})
         self.assertEqual(d["donation"]["kind"], "donation")
         self.assertIn("donated_names", d["donation"])
 
@@ -438,10 +435,6 @@ class TestTooling(unittest.TestCase):
     def test_memdump_to_chrome_trace(self):
         scope = _device_scope({"w": np.ones((8, 8), np.float32)})
         memory.track_scope(scope)
-        memory.set_island_attribution(
-            [{"island": 1, "phase": "backward", "ops": 5,
-              "argument_bytes": 512, "temp_bytes": 128,
-              "output_bytes": 64, "peak_bytes": 640}])
         path = memory.dump("manual")
         evs = export.memdump_to_chrome_trace(path)
         counters = [e for e in evs if e["ph"] == "C"]
@@ -451,8 +444,8 @@ class TestTooling(unittest.TestCase):
         self.assertEqual(owner_ctr["args"]["scope"], 256)
         bufs = [e for e in evs if e.get("cat") == "memory.buffer"]
         self.assertTrue(bufs)
-        isl = [e for e in evs if e.get("cat") == "memory.island"]
-        self.assertEqual(isl[0]["args"]["peak_bytes"], 640)
+        self.assertEqual(bufs[0]["args"]["owner"], "scope")
+        self.assertTrue(any(e["name"] == "donation" for e in evs))
 
     def test_timeline_merges_a_memdump_lane(self):
         scope = _device_scope({"w": np.ones((4, 4), np.float32)})

@@ -41,7 +41,6 @@ def _reset():
         else:
             os.environ[k] = v
     set_flags({"FLAGS_stability_guard": False,
-               "FLAGS_op_scheduler": False,
                "FLAGS_async_dispatch": False})
 
 

@@ -1,6 +1,6 @@
 """Pipeline parallelism as the fourth mesh axis (docs/PARALLELISM.md):
 ``pp`` on MeshSpec, automatic stage cutting (parallel/auto_cut), the
-interleaved 1F1B slot table (core/scheduler.pipeline_schedule), the
+interleaved 1F1B slot table (parallel/pipeline.pipeline_schedule), the
 cross-stage race verifier (analysis/races), and the joint
 (data, fsdp, tp, pp) placement search with its HBM gate + cache replay.
 """
@@ -9,7 +9,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import models
-from paddle_tpu.core.scheduler import pipeline_schedule
+from paddle_tpu.parallel.pipeline import pipeline_schedule
 from paddle_tpu.core.scope import Scope
 from paddle_tpu.analysis.races import (verify_pipeline_schedule,
                                        verify_stage_partition)

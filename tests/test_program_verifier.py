@@ -1,14 +1,7 @@
-"""Program verifier (PR 14): island-race / donation-hazard detection,
-the liveness-based static HBM planner, the static cost model, the
-tier-2 traced-step validator, and the lint CLI / calibration hooks
-that surface them.
-
-Race-defect injections corrupt the PARTITION, not the program: a
-correct partitioner can never produce a same-phase hazard from a
-well-formed program (the union-find merges every reader of a written
-name into the writer's island), so the defect class being detected is
-a partitioner regression — which is exactly what
-``verify_partition``'s re-derivation exists to catch.
+"""Program verifier: engine-state / donation / bucket-plan hazard
+detection (the island-race pass), the liveness-based static HBM
+planner, the static cost model, the tier-2 engine hook, and the lint
+CLI / calibration hooks that surface them.
 """
 import os
 import sys
@@ -20,14 +13,11 @@ import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu.analysis import (Severity, analyze_program,
                                  check_collective_ordering,
-                                 donation_plan, plan_memory, reconcile,
-                                 validate_traced, verify_partition)
+                                 donation_plan, plan_memory, reconcile)
 from paddle_tpu.analysis import cost as cost_model
-from paddle_tpu.analysis.races import ENGINE_STATE_RE
-from paddle_tpu.core.enforce import EnforceNotMet
-from paddle_tpu.core.flags import get_flags, set_flags
-from paddle_tpu.core.scheduler import (Island, partition_metadata,
+from paddle_tpu.analysis.races import (ENGINE_STATE_RE,
                                        static_updated_names)
+from paddle_tpu.core.flags import get_flags, set_flags
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(os.path.dirname(HERE), "tools"))
@@ -53,44 +43,9 @@ def _errors(diags):
     return [d for d in diags if d.is_error]
 
 
-def _split_largest_island(info):
-    """The lint CLI's island_conflict injection, inline."""
-    phase, isl = max(((p, i) for p in info.phases for i in p),
-                     key=lambda pi: len(pi[1].indices))
-    cut = len(isl.indices) // 2
-    tail = isl.indices[cut:]
-    del isl.indices[cut:]
-    phase.append(Island(tail, isl.phase))
-
-
 # ---------------------------------------------------------------------------
-# partition metadata (the analysis-facing scheduler view)
+# static updated / donated sets
 # ---------------------------------------------------------------------------
-
-def test_partition_metadata_mlp():
-    main, _, loss = _mlp_program()
-    info = partition_metadata(main, 0, fetch_names=[loss.name])
-    assert info.eligible, info.reason
-    assert len(info.phases) == 3          # forward / backward / optimize
-    assert info.island_count() >= 4
-    idxs = sorted(i for _, _, isl in info.islands() for i in isl.indices)
-    assert idxs == list(range(len(info.ops)))  # a true partition
-    d = info.to_dict()
-    assert d["eligible"]
-    assert sum(len(p) for p in d["phases"]) == info.island_count()
-
-
-def test_partition_metadata_forward_only_is_single_island():
-    fluid.framework.unique_name.reset()
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        x = layers.data("x", [8], dtype="float32")
-        y = layers.fc(x, 4)
-    info = partition_metadata(main, 0, fetch_names=[y.name])
-    # a pure dataflow chain with no phase cut is one island = whole-jit
-    assert not info.eligible
-    assert "single island" in info.reason
-
 
 def test_static_updated_names_are_the_params():
     main, _, _ = _mlp_program()
@@ -102,47 +57,6 @@ def test_static_updated_names_are_the_params():
 # ---------------------------------------------------------------------------
 # race verifier
 # ---------------------------------------------------------------------------
-
-def test_clean_partition_verifies_race_free():
-    main, _, loss = _mlp_program()
-    info = partition_metadata(main, 0, fetch_names=[loss.name])
-    assert verify_partition(main, info) == []
-
-
-def test_split_island_is_read_write_hazard():
-    main, _, loss = _mlp_program()
-    info = partition_metadata(main, 0, fetch_names=[loss.name])
-    _split_largest_island(info)
-    diags = verify_partition(main, info)
-    errs = _errors(diags)
-    assert errs, "a split dataflow chain must produce a hazard"
-    msg = errs[0].message
-    assert "hazard" in msg and "phase" in msg
-    # actionable: names both an op and a var
-    assert errs[0].op_idx >= 0 and errs[0].var_names
-
-
-def test_relocated_reader_is_donation_hazard():
-    main, _, loss = _mlp_program()
-    info = partition_metadata(main, 0, fetch_names=[loss.name])
-    donated = donation_plan(main)["donated"]
-    dset = set(donated)
-    moved = None
-    for phase in info.phases[:-1]:
-        for isl in phase:
-            if dset & set(isl.in_names):
-                phase.remove(isl)
-                info.phases[-1].append(isl)
-                moved = isl
-                break
-        if moved:
-            break
-    assert moved is not None
-    diags = verify_partition(main, info, donated_names=donated)
-    don = [d for d in _errors(diags) if "donation hazard" in d.message]
-    assert don, [d.message for d in diags]
-    assert "donate" in don[0].message
-
 
 def test_donation_plan_lists_updated_persistables():
     main, _, _ = _mlp_program()
@@ -272,10 +186,6 @@ def test_plan_memory_mlp_accounting():
     plan1 = plan_memory(main, feed_names=["img", "label"],
                         fetch_names=[loss.name], dynamic_dim=1)
     assert plan.feed_bytes == 64 * plan1.feed_bytes
-    # island rows line up with the scheduler partition
-    info = partition_metadata(main, 0, fetch_names=[loss.name])
-    assert [r["island"] for r in plan.islands] == \
-        list(range(info.island_count()))
     assert plan.top_vars == sorted(plan.top_vars,
                                    key=lambda r: -r["bytes"])
     d = plan.to_dict()
@@ -339,15 +249,10 @@ def test_reconcile_error_ratios():
     static_resident = float(plan.resident_bytes + plan.feed_bytes)
     rec = reconcile(plan,
                     census={"live_bytes": static_resident * 1.25},
-                    island_rows=[
-                        {"island": r["island"],
-                         "peak_bytes": r["peak_bytes"] * 2}
-                        for r in plan.islands],
                     measured_step={
-                        "temp_bytes": plan.transient_peak_bytes})
+                        "temp_bytes": plan.transient_peak_bytes * 2})
     assert rec["resident_error_ratio"] == pytest.approx(0.2)
-    assert rec["island_mean_error_ratio"] == pytest.approx(0.5)
-    assert rec["temp_error_ratio"] == pytest.approx(0.0)
+    assert rec["temp_error_ratio"] == pytest.approx(0.5)
 
 
 # ---------------------------------------------------------------------------
@@ -363,12 +268,7 @@ def test_program_cost_mlp():
     assert by_type["mul_grad"]["flops"] == 2 * by_type["mul"]["flops"]
     # the first GEMM dominates an MLP: 2*B*784*64 at B=64
     assert by_type["mul"]["flops"] >= 2 * 64 * 784 * 64
-    rows = cost_model.island_cost_rows(main, cost)
-    info = partition_metadata(main, 0)
-    assert [r["island"] for r in rows] == \
-        list(range(info.island_count()))
-    assert sum(r["flops"] for r in rows) == pytest.approx(
-        cost.total_flops, rel=0.05)  # feed/fetch-less ops all land
+    assert sum(r.flops for r in cost.rows) == cost.total_flops
 
 
 def test_cost_scales_with_batch():
@@ -396,20 +296,10 @@ def test_cost_model_pass_opt_in(monkeypatch):
 # tier-2 traced-step validation + engine integration
 # ---------------------------------------------------------------------------
 
-def test_validate_traced_clean_step():
-    main, _, loss = _mlp_program()
-    updated = static_updated_names(main)
-    donated = donation_plan(main)["donated"]
-    validate_traced(main, 0, updated, donated,
-                    fetch_names=[loss.name])  # must not raise
-
-
 def test_engine_tier2_runs_clean_step():
     main, startup, loss = _mlp_program()
-    old = get_flags(["validate_program", "validate_tier",
-                     "op_scheduler"])
-    set_flags({"validate_program": True, "validate_tier": 2,
-               "op_scheduler": True})
+    old = get_flags(["validate_program", "validate_tier"])
+    set_flags({"validate_program": True, "validate_tier": 2})
     try:
         exe = fluid.Executor(fluid.CPUPlace())
         scope = fluid.core.Scope()
@@ -424,24 +314,6 @@ def test_engine_tier2_runs_clean_step():
         assert rows and all("donated" in r for r in rows)
     finally:
         set_flags(old)
-
-
-def test_verify_partition_raise_path_via_validate():
-    # validate_traced recomputes the partition itself (can't be given a
-    # corrupted one) — so prove the raise plumbing via a program whose
-    # op writes engine state, caught at tier 1 by the same pass family
-    main, _, loss = _mlp_program()
-    block = main.global_block()
-    block.create_var(name="@GUARD_VERDICT@", shape=[1],
-                     dtype="float32", persistable=True)
-    block.append_op(type="scale", inputs={"X": [loss.name]},
-                    outputs={"Out": ["@GUARD_VERDICT@"]},
-                    attrs={"scale": 1.0}, infer_shape=False)
-    from paddle_tpu.analysis import validate_program
-    with pytest.raises(EnforceNotMet, match="engine-managed"):
-        validate_program(main, feed_names=["img", "label"],
-                         fetch_names=[loss.name],
-                         passes=["island-race"])
 
 
 # ---------------------------------------------------------------------------
@@ -498,24 +370,16 @@ def test_cli_check_races_clean():
     assert lint_program.main(["--model", "mlp", "--check-races"]) == 0
 
 
-def test_cli_island_conflict_detected(capsys):
-    rc = lint_program.main(["--model", "mlp", "--check-races",
-                            "--inject", "island_conflict"])
-    assert rc == 1
-    out = capsys.readouterr().out
-    assert "hazard" in out and "injected" in out
-
-
-def test_cli_donated_read_detected(capsys):
-    rc = lint_program.main(["--model", "mlp", "--check-races",
-                            "--inject", "donated_read"])
-    assert rc == 1
-    assert "donation hazard" in capsys.readouterr().out
-
-
 def test_cli_race_inject_requires_check_races():
+    # partition injections are gone with the partition: argparse
+    # refuses the name, and an injection without its check mode is
+    # still a usage error
+    with pytest.raises(SystemExit) as e:
+        lint_program.main(["--model", "mlp", "--check-races",
+                           "--inject", "island_conflict"])
+    assert e.value.code == 2
     rc = lint_program.main(["--model", "mlp",
-                            "--inject", "island_conflict"])
+                            "--inject", "cross_stage_hazard"])
     assert rc == 2
 
 

@@ -3,7 +3,8 @@ FLAGS_stability_guard; docs/STABILITY.md).
 
 The guard's contract has two halves. OFF-path: with no anomaly, the
 guard's in-trace verdict + elementwise gate must be bit-identical to a
-guard-off run — on the whole-block jit AND the op-scheduler path.
+guard-off run — on the whole-block jit, with and without gradient
+accumulation.
 ON-path: an injected NaN must be detected from ONE scalar fetch, the
 policy applied (gated skip / ghost rollback + re-execution), and
 training must continue without a process restart; the dumped replay
@@ -37,7 +38,6 @@ def _reset():
         else:
             os.environ[k] = v
     set_flags({"FLAGS_stability_guard": False,
-               "FLAGS_op_scheduler": False,
                "FLAGS_async_dispatch": False,
                "FLAGS_check_nan_inf": False})
 
@@ -65,11 +65,11 @@ def _feeds(steps, nan_at=None, seed=0):
     return feeds
 
 
-def _run(steps=4, guard=False, scheduler=False, async_dispatch=False,
+def _run(steps=4, guard=False, accum=1, async_dispatch=False,
          nan_at=None, seed=7, feeds=None):
-    """Fresh program/scope/engine; returns (losses, params, engine)."""
+    """Fresh program/scope/engine; returns (losses, params, engine).
+    ``accum`` > 1 runs every step as that many micro-batches."""
     set_flags({"FLAGS_stability_guard": guard,
-               "FLAGS_op_scheduler": scheduler,
                "FLAGS_async_dispatch": async_dispatch})
     fluid.framework.unique_name.reset()
     main, startup = fluid.Program(), fluid.Program()
@@ -77,6 +77,8 @@ def _run(steps=4, guard=False, scheduler=False, async_dispatch=False,
     startup.random_seed = seed
     with fluid.program_guard(main, startup):
         loss = _build_mlp()
+    if accum > 1:
+        main._gradient_accumulation_steps = accum
     scope = Scope()
     exe = fluid.Executor()
     losses = []
@@ -102,17 +104,16 @@ def _run(steps=4, guard=False, scheduler=False, async_dispatch=False,
 # parity: guard on, no anomaly == guard off, bit for bit
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("scheduler", [False, True],
-                         ids=["whole_block", "op_scheduler"])
-def test_guard_off_on_parity(scheduler):
-    l0, p0, _ = _run(guard=False, scheduler=scheduler)
-    l1, p1, eng = _run(guard=True, scheduler=scheduler)
+@pytest.mark.parametrize("accum", [1, 2],
+                         ids=["whole_block", "grad_accum"])
+def test_guard_off_on_parity(accum):
+    l0, p0, _ = _run(guard=False, accum=accum)
+    l1, p1, eng = _run(guard=True, accum=accum)
     assert l0 == l1
     assert sorted(p0) == sorted(p1)
     for n in p0:
         np.testing.assert_array_equal(p0[n], p1[n])
-    if scheduler:
-        assert eng.counters.get("scheduled_steps", 0) > 0
+    assert eng.counters["traces"] == 1
     assert eng.counters["anomalies"] == 0
 
 
@@ -120,20 +121,23 @@ def test_guard_off_on_parity(scheduler):
 # detection + recovery, across dispatch paths
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("scheduler,async_dispatch",
-                         [(False, False), (True, False),
-                          (False, True), (True, True)],
-                         ids=["plain", "sched", "async", "sched_async"])
-def test_nan_rollback_recovers(scheduler, async_dispatch, tmp_path):
+@pytest.mark.parametrize("accum,async_dispatch",
+                         [(1, False), (2, False), (1, True), (2, True)],
+                         ids=["plain", "accum", "async", "accum_async"])
+def test_nan_rollback_recovers(accum, async_dispatch, tmp_path):
     os.environ["PT_STABILITY_POLICY"] = "rollback"
     os.environ["PT_GHOST_EVERY"] = "1"
     os.environ["PT_REPLAY_DIR"] = str(tmp_path)
     feeds = _feeds(4, nan_at=2)
+    if accum > 1:
+        # poison the last row too: under accumulation the fetched loss
+        # is the last micro-batch's
+        feeds[2]["x"][-1, 0] = np.nan
     # reference: the same job with the poisoned step left out entirely
-    ref, rp, _ = _run(guard=True, scheduler=scheduler,
+    ref, rp, _ = _run(guard=True, accum=accum,
                       async_dispatch=async_dispatch,
                       feeds=feeds[:2] + feeds[3:])
-    bad, bp, eng = _run(guard=True, scheduler=scheduler,
+    bad, bp, eng = _run(guard=True, accum=accum,
                         async_dispatch=async_dispatch, feeds=feeds)
     # detected + rolled back + completed in-process; the poisoned feed
     # trips again on re-execution, so recovery lands as a gated skip
@@ -222,9 +226,9 @@ def test_replay_bundle_reproduces(tmp_path):
 @pytest.mark.parametrize("async_dispatch", [False, True],
                          ids=["sync", "async"])
 def test_scheduler_preserves_nan_check_labels(async_dispatch):
-    # FLAGS_check_nan_inf under FLAGS_op_scheduler: the sticky error
-    # must still name the op/var even though the step ran as islands
-    set_flags({"FLAGS_check_nan_inf": True, "FLAGS_op_scheduler": True,
+    # FLAGS_check_nan_inf on a block of two independent branches: the
+    # sticky error must still name the op/var, sync or async
+    set_flags({"FLAGS_check_nan_inf": True,
                "FLAGS_async_dispatch": async_dispatch})
     fluid.framework.unique_name.reset()
     main, startup = fluid.Program(), fluid.Program()
@@ -248,7 +252,6 @@ def test_scheduler_preserves_nan_check_labels(async_dispatch):
             for feed in feeds:
                 eng.run(main, scope, None, feed, [loss.name])
             eng.synchronize()
-        assert eng.counters.get("scheduled_steps", 0) > 0
 
 
 def test_bf16_dynamic_scaling_routes_through_guard():

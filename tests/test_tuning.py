@@ -123,16 +123,21 @@ def test_lossy_knobs_excluded_unless_opted_in():
 
 def test_apply_restore_exact_env_and_flag_state():
     os.environ.pop("PT_PREFETCH_DEPTH", None)   # absent, not ""
-    os.environ["PT_SCHED_LANES"] = "4"
-    before = knobs.snapshot()
-    with knobs.applied({"prefetch_depth": 4, "sched_lanes": 8,
-                        "allreduce_bucket_mb": 128.0}):
-        assert os.environ["PT_PREFETCH_DEPTH"] == "4"
-        assert os.environ["PT_SCHED_LANES"] == "8"
-        assert knobs.value("allreduce_bucket_mb") == 128.0
-    assert knobs.snapshot() == before
-    # absence restored as absence, not as an empty string
-    assert "PT_PREFETCH_DEPTH" not in os.environ
+    os.environ["PT_KERNEL_MIN_NUMEL"] = "65536"
+    try:
+        before = knobs.snapshot()
+        with knobs.applied({"prefetch_depth": 4,
+                            "kernel_min_numel": 16384,
+                            "allreduce_bucket_mb": 128.0}):
+            assert os.environ["PT_PREFETCH_DEPTH"] == "4"
+            assert os.environ["PT_KERNEL_MIN_NUMEL"] == "16384"
+            assert knobs.value("allreduce_bucket_mb") == 128.0
+        assert knobs.snapshot() == before
+        # absence restored as absence, not as an empty string
+        assert "PT_PREFETCH_DEPTH" not in os.environ
+        assert os.environ["PT_KERNEL_MIN_NUMEL"] == "65536"
+    finally:
+        os.environ.pop("PT_KERNEL_MIN_NUMEL", None)
 
 
 def test_apply_is_all_or_nothing():
@@ -142,7 +147,8 @@ def test_apply_is_all_or_nothing():
     assert knobs.snapshot() == before
     # failure mid-way (bad value after a good one) rolls back too
     with pytest.raises((TypeError, ValueError)):
-        knobs.apply({"prefetch_depth": 4, "sched_lanes": "not-an-int"})
+        knobs.apply({"prefetch_depth": 4,
+                     "kernel_min_numel": "not-an-int"})
     assert knobs.snapshot() == before
 
 
@@ -174,7 +180,7 @@ def test_cache_roundtrip(tmp_path):
     os.environ["PT_TUNING_CACHE_DIR"] = str(tmp_path)
     key = cache.cache_key("deadbeef")
     assert cache.lookup(key) is None
-    cfg = {"prefetch_depth": 4, "sched_lanes": 8}
+    cfg = {"prefetch_depth": 4, "kernel_min_numel": 16384}
     path = cache.store(key, cfg, objective_ms=1.25, trials=7,
                        extras={"default_ms": 1.5, "delta_ms": -0.25})
     assert os.path.exists(path)
@@ -221,11 +227,11 @@ def test_cache_corrupt_and_stale_read_as_miss(tmp_path):
 def test_cache_key_depends_on_knob_baseline(tmp_path):
     os.environ["PT_TUNING_CACHE_DIR"] = str(tmp_path)
     k0 = cache.cache_key("deadbeef")
-    os.environ["PT_SCHED_LANES"] = "8"
+    os.environ["PT_KERNEL_MIN_NUMEL"] = "16384"
     try:
         k1 = cache.cache_key("deadbeef")
     finally:
-        os.environ.pop("PT_SCHED_LANES", None)
+        os.environ.pop("PT_KERNEL_MIN_NUMEL", None)
     assert cache.key_digest(k0) != cache.key_digest(k1)
 
 

@@ -13,7 +13,7 @@ start from a warm tuning cache and pay ZERO trials::
 
     # include lossy knobs, custom search shape, machine-readable out
     python tools/autotune.py --allow-lossy --budgets 2,6 --rounds 2 \
-        --knobs sched_lanes,allreduce_bucket_mb --json
+        --knobs prefetch_depth,allreduce_bucket_mb --json
 
 A second invocation against the same cache dir reports the pure cache
 hit (``--force`` deletes the entry first to re-search). ``--variants``

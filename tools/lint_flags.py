@@ -1,12 +1,12 @@
 """lint_flags: meta-lint for trace-cache key completeness.
 
 The engine memoizes traced steps on ``Engine._cache_key`` /
-``Engine._fast_key`` (plus the shared ``_tuning_key_items`` tail). Any
-``FLAGS_*`` or ``PT_*`` environment read that happens while a step is
-being TRACED but is missing from both keys is a cache-poisoning bug:
-flip the flag, rerun, and the engine silently serves a step traced
-under the old value. PR 11's tuning work hit exactly this class twice
-(``PT_SCHED_LANES``, ``PT_COMPILER_OPTIONS``); this lint makes the
+``Engine._fast_key``, both built from the one tuple
+``Engine._trace_key_items`` returns. Any ``FLAGS_*`` or ``PT_*``
+environment read that happens while a step is being TRACED but is
+missing from that tuple is a cache-poisoning bug: flip the flag, rerun,
+and the engine silently serves a step traced under the old value
+(``PT_COMPILER_OPTIONS`` was once such a read); this lint makes the
 audit mechanical instead of archaeological.
 
 How it works — all static, no imports of the scanned code:
@@ -49,18 +49,17 @@ EXIT_FINDINGS = 1
 EXIT_USAGE = 2
 
 ENGINE_PATH = os.path.join(_REPO, "paddle_tpu", "core", "engine.py")
-KEY_FUNCTIONS = ("_cache_key", "_fast_key", "_tuning_key_items")
+KEY_FUNCTIONS = ("_cache_key", "_fast_key", "_trace_key_items")
 
 # Modules whose code executes while a step's lowering is DECIDED — for
-# the engine that is trace time (kernel selection, partitioning,
-# stability gates, bucket planning); for the transpiler it is emission
+# the engine that is trace time (kernel selection, stability gates,
+# bucket planning); for the transpiler it is emission
 # time (the c_allreduce_* plan is baked into the program); for dygraph
 # it is the per-call eager path whose fused-allreduce callable is
 # memoized per quantize mode. A flag read anywhere else happens at
 # dispatch/observation time and cannot poison a cached artifact.
 TRACE_MODULES = (
     "paddle_tpu/core/engine.py",
-    "paddle_tpu/core/scheduler.py",
     "paddle_tpu/kernels/",
     "paddle_tpu/stability/",
     "paddle_tpu/parallel/comm_scheduler.py",
@@ -153,7 +152,7 @@ def _read_name(node, os_names: Set[str] = frozenset(("os",))
 
 
 def keyed_names(engine_path: str = ENGINE_PATH) -> Set[str]:
-    """Everything ``_cache_key`` / ``_fast_key`` / ``_tuning_key_items``
+    """Everything ``_cache_key`` / ``_fast_key`` / ``_trace_key_items``
     fold into the trace key: FLAGS attrs read there, plus every PT_*
     string constant (the env reads)."""
     with open(engine_path, "r") as f:

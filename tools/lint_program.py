@@ -14,8 +14,6 @@ Usage:
   python tools/lint_program.py --model mlp --shards 2 \
       --inject shuffled_collectives
   python tools/lint_program.py --model mlp --check-races
-  python tools/lint_program.py --model mlp --check-races \
-      --inject island_conflict
   python tools/lint_program.py --model mlp --check-memory 2e9 --batch 64
   python tools/lint_program.py --model mlp --check-cost
   python tools/lint_program.py --model mlp --check-conformance
@@ -25,11 +23,7 @@ Usage:
 
 ``--inject`` corrupts the program before linting (dev aid + the CLI's
 own test fixture): dangling_read, dtype_mismatch, dead_output,
-shuffled_collectives (needs --shards >= 2). The race injections
-(island_conflict, donated_read; need --check-races) corrupt the
-*partition*, not the program — a correct partitioner cannot produce a
-same-phase hazard from a well-formed program, so the simulated defect
-is a partitioner regression.
+shuffled_collectives (needs --shards >= 2).
 """
 from __future__ import annotations
 
@@ -223,16 +217,12 @@ def _parser():
     p.add_argument("--inject", choices=["dangling_read", "dtype_mismatch",
                                         "dead_output",
                                         "shuffled_collectives",
-                                        "island_conflict",
-                                        "donated_read",
                                         "cross_stage_hazard",
                                         "dropped_bucket",
                                         "skipped_guard",
                                         "missing_shard_hint"],
                    help="corrupt the program before linting "
-                        "(island_conflict / donated_read corrupt the "
-                        "scheduler partition and need --check-races; "
-                        "cross_stage_hazard makes a later pipeline "
+                        "(cross_stage_hazard makes a later pipeline "
                         "stage rewrite a handoff activation and needs "
                         "--check-placement; dropped_bucket / "
                         "skipped_guard / missing_shard_hint corrupt "
@@ -265,10 +255,10 @@ def _parser():
                         "consistency, known knob names; exits non-zero "
                         "on invalid entries")
     p.add_argument("--check-races", action="store_true",
-                   help="verify the op scheduler's island partition is "
-                        "conflict-free (write-write / read-write / "
-                        "donation hazards across same-phase islands); "
-                        "exits non-zero on any hazard")
+                   help="run the island-race pass alone (program ops "
+                        "racing engine-managed state, donated fetch "
+                        "targets, fused-bucket plan divergence); exits "
+                        "non-zero on any hazard")
     p.add_argument("--check-memory", type=float, default=None,
                    metavar="BYTES",
                    help="build the liveness-based static HBM plan, "
@@ -276,7 +266,7 @@ def _parser():
                         "peak exceeds BYTES (0 = report only)")
     p.add_argument("--check-cost", action="store_true",
                    help="print the static per-op cost model (FLOPs / "
-                        "bytes moved, per-island aggregation)")
+                        "bytes moved)")
     p.add_argument("--check-placement", action="store_true",
                    help="multi-axis layout lint (docs/PARALLELISM.md): "
                         "every trainable parameter must resolve to "
@@ -287,8 +277,8 @@ def _parser():
     p.add_argument("--check-conformance", action="store_true",
                    help="cross-path lowering conformance (docs/"
                         "STATIC_ANALYSIS.md): extract the canonical "
-                        "lowering trace on the engine / scheduler / "
-                        "transpiled / dygraph paths and diff them "
+                        "lowering trace on the engine / transpiled / "
+                        "dygraph paths and diff them "
                         "against the declared support matrix; exits "
                         "non-zero on any undeclared divergence")
     p.add_argument("--batch", type=int, default=64, metavar="N",
@@ -296,8 +286,9 @@ def _parser():
                         "--check-memory/--check-cost plans (default 64)")
     p.add_argument("--all-models", action="store_true",
                    help="CI gate: run the full pass pipeline plus the "
-                        "race verifier over every named book model; "
-                        "exits non-zero if any model has an error")
+                        "placement and conformance checks over every "
+                        "named book model; exits non-zero if any model "
+                        "has an error")
     return p
 
 
@@ -353,73 +344,16 @@ def _check_tuning_cache(directory: str) -> int:
 # verifier modes (races / memory / cost)
 # ---------------------------------------------------------------------------
 
-def _split_island(info) -> str:
-    """Partition corruption #1: split the largest multi-op island into
-    two islands of the SAME phase. The halves share a dataflow chain,
-    so the verifier must see a read-write (or write-write) hazard —
-    exactly what a union-find regression in the partitioner would
-    produce."""
-    from paddle_tpu.core.scheduler import Island
-    best = None
-    for phase in info.phases:
-        for isl in phase:
-            if len(isl.indices) >= 2 and (
-                    best is None or
-                    len(isl.indices) > len(best[1].indices)):
-                best = (phase, isl)
-    if best is None:
-        raise ValueError("no multi-op island to split")
-    phase, isl = best
-    cut = len(isl.indices) // 2
-    tail = isl.indices[cut:]
-    del isl.indices[cut:]
-    phase.append(Island(tail, isl.phase))
-    return (f"split a {cut + len(tail)}-op island of phase {isl.phase} "
-            f"at op #{tail[0]} into two same-phase islands")
-
-
-def _move_reader_island(info, donated) -> str:
-    """Partition corruption #2: relocate an island that READS a donated
-    param into the final (optimize) phase, where another island updates
-    that param in place — the donated-buffer-read-mid-update hazard a
-    phase-cut regression would produce."""
-    if len(info.phases) < 2:
-        raise ValueError("need >= 2 phases to relocate an island")
-    dset = set(donated)
-    for phase in info.phases[:-1]:
-        for isl in phase:
-            hit = dset & set(isl.in_names)
-            if hit:
-                phase.remove(isl)
-                info.phases[-1].append(isl)
-                name = sorted(hit)[0]
-                return (f"moved the island reading donated "
-                        f"'{name}' into the optimize phase")
-    raise ValueError("no island reads a donated var")
-
-
-def _check_races(program, fetch_names, inject=None, label="") -> int:
-    """Island-race / donation-hazard verification over the scheduler's
-    own partition (docs/STATIC_ANALYSIS.md)."""
+def _check_races(program, feed_names, fetch_names, label="") -> int:
+    """The island-race pass alone (docs/STATIC_ANALYSIS.md)."""
     from paddle_tpu.analysis import (donation_plan, format_report,
-                                     has_errors, verify_partition)
-    from paddle_tpu.core.scheduler import partition_metadata
-    info = partition_metadata(program, 0, fetch_names=fetch_names or ())
+                                     has_errors)
+    diags = analyze_program(program, feed_names=feed_names,
+                            fetch_names=fetch_names or (),
+                            passes=["island-race"], label=label)
     donated = donation_plan(program)["donated"]
-    if not info.eligible:
-        print(f"check-races {label}: partition ineligible "
-              f"({info.reason}); nothing to verify")
-        return EXIT_CLEAN
-    if inject == "island_conflict":
-        print(f"injected: {_split_island(info)}")
-    elif inject == "donated_read":
-        print(f"injected: {_move_reader_island(info, donated)}")
-    diags = verify_partition(program, info, donated_names=donated,
-                             label=label)
     print(format_report(
-        diags, header=f"check-races {label}: {info.island_count()} "
-                      f"islands / {len(info.phases)} phases, "
-                      f"{len(donated)} donated"))
+        diags, header=f"check-races {label}: {len(donated)} donated"))
     return EXIT_ERRORS if has_errors(diags) else EXIT_CLEAN
 
 
@@ -456,9 +390,6 @@ def _check_cost(program, batch: int, label="") -> int:
           f"{d['total_bytes']:.3e} bytes moved (batch={batch})")
     for t, agg in d["by_type"].items():
         print(f"  {t:28s} x{agg['count']:<3d} {agg['flops']:.3e} FLOPs")
-    for r in cost_model.island_cost_rows(program, cost):
-        print(f"  island {r['island']} (phase {r['phase']}, "
-              f"{r['ops']} ops): {r['flops']:.3e} FLOPs")
     return EXIT_CLEAN
 
 
@@ -536,7 +467,7 @@ def _check_pipeline_cuts(model: str, rules, batch: int,
     from paddle_tpu.analysis import format_report, has_errors
     from paddle_tpu.analysis.races import (verify_pipeline_schedule,
                                            verify_stage_partition)
-    from paddle_tpu.core.scheduler import pipeline_schedule
+    from paddle_tpu.parallel.pipeline import pipeline_schedule
     from paddle_tpu.parallel.auto_cut import propose_cuts, validate_cuts
     from paddle_tpu.parallel.mesh import MeshSpec
 
@@ -610,8 +541,8 @@ def _check_conformance(model: str, batch: int, inject=None,
 
 
 def _all_models(batch: int) -> int:
-    """CI gate: every named book model must pass the full pipeline
-    (zero errors) AND verify race-free under the scheduler partition."""
+    """CI gate: every named book model must pass the full pipeline,
+    the placement lint and the conformance check with zero errors."""
     from paddle_tpu.analysis import format_report, has_errors
     rc = EXIT_CLEAN
     for name in sorted(MODELS):
@@ -620,8 +551,6 @@ def _all_models(batch: int) -> int:
                                 fetch_names=[loss.name], label=name)
         print(format_report(diags, header=f"lint {name}"))
         if has_errors(diags):
-            rc = EXIT_ERRORS
-        if _check_races(program, [loss.name], label=name) != EXIT_CLEAN:
             rc = EXIT_ERRORS
         if _check_placement(name, batch, label=name) != EXIT_CLEAN:
             rc = EXIT_ERRORS
@@ -649,12 +578,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     if ns.inject == "shuffled_collectives" and ns.shards < 2:
         print("lint_program: --inject shuffled_collectives requires "
               "--shards >= 2", file=sys.stderr)
-        return EXIT_USAGE
-    _partition_injects = ("island_conflict", "donated_read")
-    if ns.inject in _partition_injects and not ns.check_races:
-        print("lint_program: --inject island_conflict/donated_read "
-              "corrupts the scheduler partition and requires "
-              "--check-races", file=sys.stderr)
         return EXIT_USAGE
     if ns.inject == "cross_stage_hazard" and not ns.check_placement:
         print("lint_program: --inject cross_stage_hazard corrupts a "
@@ -704,9 +627,8 @@ def main(argv: Optional[List[str]] = None) -> int:
             or ns.check_placement or ns.check_conformance:
         rc = EXIT_CLEAN
         if ns.check_races:
-            inj = ns.inject if ns.inject in _partition_injects else None
-            rc = max(rc, _check_races(programs[0], fetch_names,
-                                      inject=inj, label=label))
+            rc = max(rc, _check_races(programs[0], feed_names,
+                                      fetch_names, label=label))
         if ns.check_memory is not None:
             rc = max(rc, _check_memory(programs[0], feed_names,
                                        fetch_names, ns.check_memory,

@@ -61,7 +61,7 @@ REQUIRED_FAMILIES = (
     "pt_tuning_trial_seconds",
     # HBM memory observatory (docs/MEMORY.md)
     "pt_hbm_owner_bytes", "pt_hbm_live_bytes",
-    "pt_island_hbm_peak_bytes", "pt_hbm_leak_suspect_bytes",
+    "pt_hbm_leak_suspect_bytes",
     "pt_memdumps_total", "pt_oom_postmortems_total",
     # integrity sentinel + exactly-once resume (docs/RESILIENCE.md)
     "pt_integrity_checks_total", "pt_integrity_mismatch_total",
