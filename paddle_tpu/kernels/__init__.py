@@ -13,4 +13,5 @@ from .quantized_matmul import quantized_matmul  # noqa: F401
 from . import grouped_matmul  # noqa: F401
 from . import sparse_index  # noqa: F401
 from . import mamba2_ssd  # noqa: F401
+from . import selective_scan  # noqa: F401
 from . import short_conv  # noqa: F401

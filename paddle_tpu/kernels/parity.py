@@ -582,6 +582,35 @@ def _ssd_case(which):
     return Case("mamba2_ssd", "mamba2_ssd/%s/f32/2x296x8x16" % which, run)
 
 
+def _selective_scan_case(which):
+    """Mamba-1's selective scan at 2 x 136 tokens (no multiple of the
+    128-token chunk), 256 channels, state 16: the forward kernel's y and
+    chunk-start states, and the backward kernel's six gradients, against
+    the `jax.numpy` recurrence and its `jax.vjp` (f32, "highest")."""
+    def run():
+        import jax
+        from . import selective_scan as ss
+        r = _rng(41)
+        b, t, c, n = 2, 136, 256, 16
+
+        def draw(*shape):
+            return jnp.asarray(r.standard_normal(shape, dtype=np.float32))
+        x, bm, cm, dy = draw(b, t, c), draw(b, t, n), draw(b, t, n), \
+            draw(b, t, c)
+        dt = jax.nn.softplus(draw(b, t, c) - 1.0)
+        a, d = -jnp.exp(draw(c, n) * 0.5), draw(c)
+        with jax.default_matmul_precision("highest"):
+            got, ref = (ss.scan(x, dt, a, bm, cm, d, kern)
+                        for kern in (True, False))
+            if which == "bwd":
+                got, ref = (ss.scan_grad(x, dt, a, bm, cm, d, ref[1], dy,
+                                         kern) for kern in (True, False))
+        return {"metric": "rel_vs_lowered", "tol": 1e-5,
+                "value": max(rel_err(w, v) for v, w in zip(got, ref))}
+    return Case("selective_scan",
+                "selective_scan/%s/f32/2x136x256x16" % which, run)
+
+
 def _short_conv_case(which):
     """The gated short convolution at 2 x 296 tokens (no multiple of the
     32-row blocks: the halo crosses nine boundaries), D 64, three taps:
@@ -621,7 +650,7 @@ def cases() -> List[Case]:
     import importlib
     from . import fused_optimizer, grouped_matmul  # noqa: F401
     from . import quantized_matmul, sparse_index  # noqa: F401
-    from . import mamba2_ssd, short_conv  # noqa: F401
+    from . import mamba2_ssd, selective_scan, short_conv  # noqa: F401
     importlib.import_module("paddle_tpu.kernels.flash_attention")
     return [
         _adam_case((4096,)),        # rank 1: the flat view
@@ -645,6 +674,8 @@ def cases() -> List[Case]:
         _sparse_index_case("select"),
         _ssd_case("fwd"),
         _ssd_case("bwd"),
+        _selective_scan_case("fwd"),
+        _selective_scan_case("bwd"),
         _short_conv_case("fwd"),
         _short_conv_case("bwd"),
     ]

@@ -3,8 +3,9 @@ ops/decoder.py): RMS normalisation, rotary positions, the feed-forward's
 activation (gated, or a squared ReLU), the top-k router, the expert layer
 that is told which experts this chip holds, a learned sparse attention's
 index, a Mamba-2 mixer's three ops: the short causal convolution,
-the state-space scan and the gated group-wise RMS norm, and the gated
-short convolution that mixes tokens on its own."""
+the state-space scan and the gated group-wise RMS norm, a Mamba-1
+mixer's selective scan, and the gated short convolution that mixes
+tokens on its own."""
 from __future__ import annotations
 
 from ..initializer import Constant, Normal, Uniform
@@ -13,7 +14,8 @@ from ..param_attr import ParamAttr
 
 __all__ = ["rms_norm", "rotary_embedding", "swiglu", "relu2", "moe_router",
            "moe_experts", "sparse_attention_index", "causal_conv1d",
-           "gated_rms_norm", "mamba2_ssd", "gated_short_conv"]
+           "gated_rms_norm", "mamba2_ssd", "selective_scan",
+           "gated_short_conv"]
 
 
 def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
@@ -238,6 +240,32 @@ def mamba2_ssd(x, dt, b, c, chunk_size=128, dt_bias_attr=None,
     helper.append_op("mamba2_ssd", inputs=inputs,
                      outputs={"Y": y, "States": states, "Tokens": tokens},
                      attrs={"chunk_size": int(chunk_size)})
+    return y, tokens
+
+
+def selective_scan(x, dt, b, c, state_size, dt_bias_attr=None,
+                   a_log_attr=None, d_attr=None, name=None):
+    """Mamba-1's selective scan over x [B, T, C] with step sizes dt
+    [B, T, C] (before their bias and softplus) and b, c [B, T, N] shared
+    by the channels; parameters the step's bias [C], log(-A) [C, N] and
+    the skip weight D [C]. Returns (y [B, T, C], tokens scanned int32
+    [1])."""
+    helper = LayerHelper("selective_scan", name=name)
+    ch, n = int(x.shape[-1]), int(state_size)
+
+    def param(attr, shape, value):
+        return helper.create_parameter(attr, shape, "float32",
+                                       default_initializer=Constant(value))
+
+    inputs = {"X": x, "Dt": dt, "B": b, "C": c,
+              "DtBias": param(dt_bias_attr, [ch], 0.0),
+              "ALog": param(a_log_attr, [ch, n], 0.0),
+              "D": param(d_attr, [ch], 1.0)}
+    y = helper.create_variable_for_type_inference(x.dtype)
+    states = helper.create_variable_for_type_inference("float32", True)
+    tokens = helper.create_variable_for_type_inference("int32", True)
+    helper.append_op("selective_scan", inputs=inputs,
+                     outputs={"Y": y, "States": states, "Tokens": tokens})
     return y, tokens
 
 

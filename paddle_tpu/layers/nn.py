@@ -23,6 +23,7 @@ __all__ = [
     "conv3d_transpose", "pool2d", "pool3d", "adaptive_pool2d", "batch_norm",
     "layer_norm", "group_norm", "instance_norm", "data_norm", "dropout",
     "softmax", "log_softmax", "matmul", "mul", "fused_attention",
+    "differential_attention",
     "dynamic_lstm", "dynamic_gru", "lstm_unit", "gru_unit",
     "relu", "relu6", "sigmoid",
     "tanh", "leaky_relu", "elu", "gelu", "swish", "prelu", "brelu",
@@ -983,6 +984,35 @@ def fused_attention(q, k, v, bias=None, scale=None, block_q=None,
             "int32", True)
     helper.append_op("fused_attention", inputs=inputs, outputs=outputs,
                      attrs=attrs)
+    return out
+
+
+def differential_attention(input, group, lambda_init, epsilon=1e-5,
+                           lambda_attrs=None, scale_attr=None, name=None):
+    """Differential attention's head outputs from `fused_attention`'s
+    input [B, S, 2 H, Dv], its query heads in (key pair, map, member)
+    order with `group` members a pair: (1 - lambda_init) * RMSNorm(a1 -
+    lambda a2) * w a head, lambda = exp(q1 . k1) - exp(q2 . k2) +
+    lambda_init of four learned vectors of the key width d (drawn N(0,
+    0.1); `lambda_attrs` their four ParamAttrs in the order q1, k1, q2,
+    k2), w [Dv] from ones. Returns [B, S, H Dv]."""
+    helper = LayerHelper("differential_attention", name=name)
+    attrs = lambda_attrs or [None] * 4
+    inputs = {"X": input}
+    for slot, attr in zip(("LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2"),
+                          attrs):
+        inputs[slot] = helper.create_parameter(
+            attr, [int(input.shape[-1]) // 2], "float32",
+            default_initializer=Normal(0.0, 0.1))
+    inputs["Scale"] = helper.create_parameter(
+        scale_attr, [int(input.shape[-1])], "float32",
+        default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("differential_attention", inputs=inputs,
+                     outputs={"Out": out},
+                     attrs={"group": int(group),
+                            "lambda_init": float(lambda_init),
+                            "epsilon": float(epsilon)})
     return out
 
 

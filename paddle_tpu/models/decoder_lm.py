@@ -68,6 +68,31 @@ router and which feed-forwards a layer gets follow from the published
   feed-forward by layer  `mlp_layer_types`, one entry a layer: "sparse"
       the expert layer, "dense" the dense SwiGLU of `intermediate_size`;
       any other entry raises by name.
+  decoder-hybrid-decoder  (SambaY, the `phi4flash` block) "mamba",
+      "gmu" and "cross_attention" entries of `layer_types`, or without a
+      list the layout `sambay_layout` derives from `num_hidden_layers`
+      and `mb_per_layer`. A "mamba" layer's mixer is Mamba-1: [x | z] = x
+      W_in [D, 2 I] (I = `mamba_expand` D); x = silu(conv(x) + b) over
+      `mamba_d_conv` taps; [delta | B | C] = x W_x (`mamba_dt_rank`,
+      `mamba_d_state`, `mamba_d_state`); dt = softplus(delta W_dt + b_dt);
+      the selective scan (`selective_scan`: A [I, N] a decay a channel and
+      a state); (y * silu(z)) W_out. Its y, before the gate, is the memory
+      a later "gmu" layer gates: (silu(x W_in) * m) W_out. A
+      "cross_attention" layer projects its queries alone and attends over
+      the keys and values of the last "full_attention" layer before it;
+      a reader with no producer before it raises by name. Such a model's
+      attention has no positions (no rotary, no q / k norm) and, without
+      expert keys, every layer the dense SwiGLU. `layer_norm_eps`:
+      LayerNorm with a shift (`.b_0`) in place of every RMS norm.
+      Its attention is differential attention, always (the key
+      `use_differential_attention`, where given, must say so): two
+      softmax maps a head, query heads (2i, 2i + 1) over key heads (2j,
+      2j + 1), the value heads read in pairs as heads of twice the
+      width, one flash call a site; lambda =
+      exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init, lambda_init = 0.8 -
+      0.6 exp(-0.3 l) at the layer's published index l
+      (`layers_held`); a head's output (1 - lambda_init) RMSNorm(a1 -
+      lambda a2) * w (`differential_attention`).
   head        untied (`lm_head.w_0` [D, V]) unless `tie_word_embeddings`:
       then the logits are the final norm's output times the embedding's
       own matrix transposed — one parameter, one optimizer state, its
@@ -83,9 +108,11 @@ expert and vocabulary parallelism:
       exchange and nothing stands in for the absent chips.
   vocab_held   rows of the embedding and of the head held here (default:
       all); ids, logits and the loss are over that slice.
+  layers_held  the published indices of the layers built here, one
+      pipeline stage (default: 0 .. num_hidden_layers - 1).
 
-Every layer: h += Attn(RMSNorm(h)); h += FFN(RMSNorm(h)); with a
-pattern h += part(RMSNorm(h)), one part.
+Every layer: h += Attn(Norm(h)); h += FFN(Norm(h)); with a pattern
+h += part(Norm(h)), one part.
 
 Published keys that say nothing about the shapes built here
 (`max_position_embeddings`, `model_type`, ...) are accepted and ignored,
@@ -93,8 +120,9 @@ so a `config.json` can be passed whole. Keys that change a layer's
 equations and are not built here raise NotImplementedError by name
 (`moe_latent_size`, `num_nextn_predict_layers`, a `sliding_window`
 that no `layer_types` places, a `layer_types` entry that is not "conv",
-"full_attention" or "sliding_attention", `conv_bias`, ...): a file that
-carries one is never built as some other model.
+"full_attention", "sliding_attention", "mamba", "gmu" or
+"cross_attention", `conv_bias`, ...): a file that carries one is never
+built as some other model.
 
 Every parameter has an explicit, stable name (`layer_<i>_attn_q.w_0`,
 `layer_<i>_experts_gate.w_0`, ...). The indexer's weights
@@ -114,7 +142,9 @@ fifth, `short_conv_tokens` [conv layers], OVERWRITTEN with the tokens
 each layer's convolution went over (observability/short_conv.py); one
 with window layers a sixth, `window_attn_pairs` [window layers],
 OVERWRITTEN with the (query, key) pairs each window layer's band
-admitted (observability/window_attention.py).
+admitted (observability/window_attention.py); one with Mamba-1 mixers
+`selective_scan_tokens` [Mamba layers], OVERWRITTEN with the tokens each
+selective scan went over (observability/selective_scan.py).
 
 A conv layer's parameters: `layer_<i>_conv_norm.w_0`, `layer_<i>_conv_in
 .w_0` [D, 3 D], `layer_<i>_conv.w_0` [D, taps] drawn from U(-1 /
@@ -143,6 +173,7 @@ from ..initializer import (Constant, Normal, NumpyArrayInitializer,
                            Uniform)
 from ..observability.mamba import SSD_TOKENS_VAR
 from ..observability.moe import EXPERT_LOAD_VAR, ROWS_WORKED_VAR
+from ..observability.selective_scan import SELECTIVE_SCAN_TOKENS_VAR
 from ..observability.short_conv import SHORT_CONV_TOKENS_VAR
 from ..observability.sparse_attention import KEPT_PAIRS_VAR
 from ..observability.window_attention import WINDOW_PAIRS_VAR
@@ -155,7 +186,11 @@ PATTERN_PARTS = {"M": "mamba", "*": "attn", "E": "moe", "-": "mlp"}
 # an entry of `layer_types` -> a two-part layer's token mixer (and its op
 # scope)
 LAYER_TYPES = {"conv": "conv", "full_attention": "attn",
-               "sliding_attention": "swa"}
+               "sliding_attention": "swa", "mamba": "mamba", "gmu": "gmu",
+               "cross_attention": "xattn"}
+# the mixers of a decoder-hybrid-decoder (SambaY): Mamba-1 mixers, and the
+# cross-decoder's layers that read what an earlier layer made
+SAMBAY_MIXERS = ("mamba", "gmu", "xattn")
 # an entry of `mlp_layer_types` -> whether the layer's feed-forward is dense
 MLP_LAYER_TYPES = {"sparse": False, "dense": True}
 _YARN_DEFAULTS = {"beta_fast": 32.0, "beta_slow": 1.0}
@@ -177,6 +212,27 @@ def rotary_of(params, theta):
     yarn.setdefault("attention_factor",
                     0.1 * np.log(float(yarn["factor"])) + 1.0)
     return theta, {k: float(yarn[k]) for k in YARN_KEYS}
+
+
+def sambay_layout(layers, mb_per_layer):
+    """SambaY's published layout of `layers` layers (`mb_per_layer` the
+    period of its Mamba layers): in the self-decoder (the first half) a
+    Mamba mixer at every index that is a multiple of the period and
+    sliding-window attention between; then the two layers that make the
+    memory and the shared keys and values (a Mamba mixer at the
+    multiple, the one full-attention layer beside it); in the
+    cross-decoder a gated memory unit at the multiple and cross-attention
+    between."""
+    half, out = layers // 2, []
+    for i in range(layers):
+        mamba = mb_per_layer > 0 and i % mb_per_layer == 0
+        if i < half:
+            out.append("mamba" if mamba else "sliding_attention")
+        elif i < half + 2:
+            out.append("mamba" if mamba else "full_attention")
+        else:
+            out.append("gmu" if mamba else "cross_attention")
+    return out
 
 
 def parse_pattern(pattern):
@@ -221,10 +277,17 @@ class DecoderLMConfig:
                  conv_bias=False, use_expert_bias=None,
                  router_norm_epsilon=None, rope_parameters=None,
                  mlp_layer_types=None, use_qk_norm=None,
+                 mb_per_layer=None, layer_norm_eps=None,
+                 use_differential_attention=None, mamba_d_state=16,
+                 mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=None,
                  experts_held=None, first_expert=0, vocab_held=None,
-                 **unused):
+                 layers_held=None, **unused):
         if q_lora_rank is not None:
             raise NotImplementedError("query compression (q_lora_rank)")
+        if layer_types is None and mb_per_layer is not None:
+            if num_hidden_layers is None:
+                raise ValueError("mb_per_layer without num_hidden_layers")
+            layer_types = sambay_layout(num_hidden_layers, mb_per_layer)
         # keys that change a layer's equations and are not built here: a
         # file that carries one must not build as some other model
         windowed = "sliding_attention" in (layer_types or ())
@@ -288,21 +351,30 @@ class DecoderLMConfig:
                 f"of {len(self.parts)} layers")
         else:
             num_hidden_layers = len(self.parts)
-        has_experts = self.parts is None or "moe" in self.parts
+        self.mixers = None if layer_types is None \
+            else [LAYER_TYPES[t] for t in layer_types]
+        # a decoder-hybrid-decoder: without expert keys every layer's
+        # feed-forward is the dense SwiGLU
+        self.sambay = any(m in SAMBAY_MIXERS for m in self.mixers or ())
+        dense_only = self.sambay and n_routed_experts is None \
+            and num_experts is None
+        if dense_only:
+            first_k_dense_replace = num_hidden_layers
+        has_experts = (self.parts is None and not dense_only) \
+            or "moe" in (self.parts or ())
         if has_experts and \
                 (n_routed_experts is None) == (num_experts is None):
             raise ValueError("one of n_routed_experts and num_experts")
         self.vocab_size = int(vocab_held or vocab_size)
         self.hidden_size = hidden_size
         self.num_hidden_layers = num_hidden_layers
-        # a two-part layer's token mixer, by layer (None: attention)
-        self.mixers = None if layer_types is None \
-            else [LAYER_TYPES[t] for t in layer_types]
+        self._check_readers()
         self.sliding_window = int(sliding_window) if windowed else None
         self.rotary_by_type = by_type
         self.layer_types = list(layer_types) if layer_types else None
         if by_type is not None and self.layer_types and any(
-                t not in by_type for t in self.layer_types if t != "conv"):
+                t not in by_type for t in self.layer_types
+                if LAYER_TYPES[t] in ("attn", "swa")):
             raise ValueError(f"rope_parameters {sorted(by_type)} against "
                              f"layer_types {sorted(set(layer_types))}")
         self.conv_taps = int(conv_L_cache)
@@ -324,15 +396,39 @@ class DecoderLMConfig:
         # half-split pairs of every other decoder
         self.rope_interleave = bool(kv_lora_rank) \
             if rope_interleave is None else bool(rope_interleave)
-        # a hybrid's attention: no rotary, no q / k norm
-        self.attention_positions = self.parts is None
+        # a hybrid's attention: no rotary, no q / k norm (the positions
+        # come from the mixers)
+        self.attention_positions = self.parts is None and not self.sambay
         self.qk_norm = self.attention_positions if use_qk_norm is None \
             else bool(use_qk_norm) and self.attention_positions
-        eps = {e for e in (rms_norm_eps, layer_norm_epsilon, norm_eps)
-               if e is not None}
+        eps = {e for e in (rms_norm_eps, layer_norm_epsilon, norm_eps,
+                           layer_norm_eps) if e is not None}
         if len(eps) > 1:
             raise ValueError(f"the norm's epsilon given as {sorted(eps)}")
         self.rms_norm_eps = eps.pop() if eps else 1e-6
+        # `layer_norm_eps` (the phi4flash key): LayerNorm, scale and shift
+        self.layer_norm = layer_norm_eps is not None
+        # differential attention is a decoder-hybrid-decoder's attention
+        # and no other model's: the key, where a file gives it, says so
+        if use_differential_attention is not None \
+                and bool(use_differential_attention) != self.sambay:
+            raise NotImplementedError(
+                f"use_differential_attention={use_differential_attention}: "
+                "the model file builds it in a decoder-hybrid-decoder's "
+                "attention layers, and there always")
+        if self.sambay and (self.num_key_value_heads % 2
+                            or num_attention_heads
+                            % self.num_key_value_heads):
+            raise ValueError("differential attention pairs the key heads")
+        self.layers_held = list(range(num_hidden_layers)) \
+            if layers_held is None else [int(i) for i in layers_held]
+        if len(self.layers_held) != num_hidden_layers:
+            raise ValueError(f"{len(self.layers_held)} layers_held for "
+                             f"{num_hidden_layers} layers")
+        self.mamba_d_state, self.mamba_d_conv = mamba_d_state, mamba_d_conv
+        self.mamba_inner = mamba_expand * hidden_size
+        self.mamba_dt_rank = -(-hidden_size // 16) \
+            if mamba_dt_rank in (None, "auto") else int(mamba_dt_rank)
         self.intermediate_size = intermediate_size
         self.dense_layers = {
             i for i in range(num_hidden_layers)
@@ -394,6 +490,25 @@ class DecoderLMConfig:
         self.use_conv_bias = bool(use_conv_bias)
         self.time_step = (time_step_min, time_step_max, time_step_floor)
 
+    def _check_readers(self):
+        """A gated memory unit reads the memory of a Mamba layer before
+        it, a cross-attention layer the keys and values of a full
+        attention layer before it: a layout without the producer raises
+        by name."""
+        reads = {"gmu": ("gmu", "mamba"),
+                 "xattn": ("cross_attention", "full_attention")}
+        made = set()
+        for i, m in enumerate(self.mixers or ()):
+            if m in reads and LAYER_TYPES[reads[m][1]] not in made:
+                raise ValueError(f"layer {i}: a {reads[m][0]} layer with no "
+                                 f"{reads[m][1]} layer before it")
+            made.add(m)
+
+    def lambda_init(self, i):
+        """Differential attention's lambda_init at layer i: 0.8 - 0.6
+        exp(-0.3 l), l the layer's PUBLISHED index (`layers_held`)."""
+        return 0.8 - 0.6 * float(np.exp(-0.3 * self.layers_held[i]))
+
     def rotary_at(self, i):
         """(theta, YaRN dict or None) of layer i's attention."""
         if self.rotary_by_type is not None:
@@ -425,10 +540,16 @@ def _linear(x, size, name, cfg):
 
 
 def _norm(x, name, cfg):
-    return layers.rms_norm(
-        x, epsilon=cfg.rms_norm_eps,
-        param_attr=ParamAttr(name=name + ".w_0",
-                             initializer=Constant(1.0)))
+    """The configuration's norm over the last axis: RMSNorm (`w_0`), or
+    with `layer_norm_eps` LayerNorm (scale `w_0`, shift `b_0`)."""
+    scale = ParamAttr(name=name + ".w_0", initializer=Constant(1.0))
+    if cfg.layer_norm:
+        return layers.layer_norm(
+            x, begin_norm_axis=len(x.shape) - 1, epsilon=cfg.rms_norm_eps,
+            param_attr=scale,
+            bias_attr=ParamAttr(name=name + ".b_0",
+                                initializer=Constant(0.0)))
+    return layers.rms_norm(x, epsilon=cfg.rms_norm_eps, param_attr=scale)
 
 
 def latent_attention(x, cfg, name):
@@ -624,6 +745,87 @@ def mamba_mixer(x, cfg, name):
     return _linear(y, cfg.hidden_size, name + "_out", cfg), tokens
 
 
+def mamba1_mixer(x, cfg, name):
+    """A Mamba-1 mixer on the layer's normalised input: [x | z] = x W_in;
+    x = silu(conv(x) + b), depthwise and causal; [delta | B | C] = x W_x;
+    dt = delta W_dt (its bias and softplus inside the scan); the
+    selective scan; out = (y * silu(z)) W_out. Returns (output, y — the
+    scan's readout before the gate, the memory a gated memory unit
+    reads —, tokens scanned int32 [1])."""
+    inner, n, rank = cfg.mamba_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
+    xs, z = layers.split(_linear(x, 2 * inner, name + "_in", cfg),
+                         [inner, inner], dim=-1)
+    taps = Uniform(-cfg.mamba_d_conv ** -0.5, cfg.mamba_d_conv ** -0.5)
+    xs = layers.causal_conv1d(
+        xs, cfg.mamba_d_conv, act="silu",
+        param_attr=ParamAttr(name=name + "_conv.w_0", initializer=taps),
+        bias_attr=ParamAttr(name=name + "_conv.b_0", initializer=taps))
+    delta, b, c = layers.split(_linear(xs, rank + 2 * n, name + "_x", cfg),
+                               [rank, n, n], dim=-1)
+    lo, hi, floor = cfg.time_step
+    step = np.maximum(np.exp(np.linspace(np.log(lo), np.log(hi), inner)),
+                      floor)
+    y, tokens = layers.selective_scan(
+        xs, _linear(delta, inner, name + "_dt", cfg), b, c, n,
+        # softplus^-1(step) = step + log(1 - exp(-step))
+        dt_bias_attr=_per_head(name + "_dt.b_0",
+                               step + np.log(-np.expm1(-step))),
+        a_log_attr=_per_head(name + "_a_log.w_0", np.log(np.tile(
+            np.arange(1.0, n + 1.0), (inner, 1)))),
+        d_attr=ParamAttr(name=name + "_d.w_0", initializer=Constant(1.0)))
+    out = _linear(layers.swiglu(z, y), cfg.hidden_size, name + "_out", cfg)
+    return out, y, tokens
+
+
+def gated_memory_unit(x, memory, cfg, name):
+    """SambaY's gated memory unit: (silu(x W_in) * memory) W_out, the
+    memory a Mamba layer's scan readout."""
+    gate = _linear(x, int(memory.shape[-1]), name + "_in", cfg)
+    return _linear(layers.swiglu(gate, memory), cfg.hidden_size,
+                   name + "_out", cfg)
+
+
+def sambay_attention(x, cfg, name, layer, kv=None):
+    """Differential attention without positions over the layer's own keys
+    and values or, in a cross-attention layer, over `kv` an earlier full
+    layer made (the layer projects its queries alone); causal, or over
+    the layer's window. Query heads (2i, 2i + 1) are the pair (q1_i,
+    q2_i), key heads (2j, 2j + 1) the pair (k1_j, k2_j) and the value
+    heads read in pairs as heads of twice the width; differential head i
+    reads key pair j = i // (H / Hkv), and ONE flash call makes both of
+    its softmax maps: the query heads go to the kernel in (j, map,
+    member) order, each key pair's value head twice. Returns (output,
+    the (k, v) attended, the window's admitted pairs int32 [1] or
+    None)."""
+    h, hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
+        cfg.head_dim
+    q = layers.reshape(_linear(x, h * d, name + "_q", cfg), [0, 0, h, d])
+    if kv is None:
+        k, v = (layers.reshape(_linear(x, hkv * d, f"{name}_{part}", cfg),
+                               [0, 0, hkv, d]) for part in ("k", "v"))
+        v = layers.reshape(layers.expand(
+            layers.reshape(v, [0, 0, hkv // 2, 1, 2 * d]),
+            [1, 1, 1, 2, 1]), [0, 0, hkv, 2 * d])
+        kv = (k, v)
+    group = h // hkv
+    q = layers.reshape(layers.transpose(
+        layers.reshape(q, [0, 0, hkv // 2, group, 2, d]),
+        [0, 1, 2, 4, 3, 5]), [0, 0, h, d])
+    window = cfg.window_at(layer)
+    ctx = layers.fused_attention(q, kv[0], kv[1], None, scale=d ** -0.5,
+                                 layout="bshd", causal=True, window=window)
+    pairs = None if window is None \
+        else ctx.block.var(ctx.op.output("WindowPairs")[0])
+    ctx = layers.differential_attention(
+        ctx, group, cfg.lambda_init(layer), epsilon=1e-5,
+        lambda_attrs=[ParamAttr(name=f"{name}_lambda_{part}.w_0",
+                                initializer=Normal(0.0, 0.1))
+                      for part in ("q1", "k1", "q2", "k2")],
+        scale_attr=ParamAttr(name=name + "_subln.w_0",
+                             initializer=Constant(1.0)))
+    return _linear(ctx, cfg.hidden_size, name + "_o", cfg), kv, pairs
+
+
 def short_conv(x, cfg, name):
     """The gated short convolution operator on the layer's normalised
     input: [Bg | Cg | x] = x W_in; Cg * conv(Bg * x) over `conv_taps`
@@ -641,6 +843,14 @@ class _Counts:
     def __init__(self):
         self.load, self.worked, self.kept, self.scanned = [], [], [], []
         self.convolved, self.admitted = [], []
+        self.selective = []
+
+
+class _Shared:
+    """What a decoder-hybrid-decoder's layers hand to later ones: the
+    last Mamba layer's scan readout, the last full layer's keys and
+    values."""
+    memory = kv = None
 
 
 def _attention(x, cfg, name, counts, layer=0):
@@ -661,12 +871,36 @@ def _experts(x, cfg, p, counts):
     return out
 
 
-def _two_part_layer(h, i, cfg, counts):
-    """h += Mixer(RMSNorm(h)); h += FFN(RMSNorm(h)), the mixer attention
-    (causal, or in a window layer over its band) or, by `layer_types`,
-    the gated short convolution."""
+def _sambay_mixer(h, i, kind, cfg, counts, shared):
+    """h += mixer(LN(h)) of a decoder-hybrid-decoder's layer."""
     p = f"layer_{i}"
-    if cfg.mixers is not None and cfg.mixers[i] == "conv":
+    with name_scope(kind):
+        if kind == "mamba":
+            out, shared.memory, n = mamba1_mixer(
+                _norm(h, p + "_mamba_norm", cfg), cfg, p + "_mamba")
+            counts.selective.append(n)
+        elif kind == "gmu":
+            out = gated_memory_unit(_norm(h, p + "_gmu_norm", cfg),
+                                    shared.memory, cfg, p + "_gmu")
+        else:
+            out, kv, n = sambay_attention(
+                _norm(h, p + "_attn_norm", cfg), cfg, p + "_attn", i,
+                shared.kv if kind == "xattn" else None)
+            if kind == "attn":
+                shared.kv = kv
+            if n is not None:
+                counts.admitted.append(n)
+        return layers.elementwise_add(h, out)
+
+
+def _two_part_layer(h, i, cfg, counts, shared):
+    """h += Mixer(Norm(h)); h += FFN(Norm(h)), the mixer attention
+    (causal, or in a window layer over its band) or, by `layer_types`,
+    the gated short convolution, or a decoder-hybrid-decoder's mixer."""
+    p = f"layer_{i}"
+    if cfg.sambay:
+        h = _sambay_mixer(h, i, cfg.mixers[i], cfg, counts, shared)
+    elif cfg.mixers is not None and cfg.mixers[i] == "conv":
         with name_scope("conv"):
             out, n = short_conv(_norm(h, p + "_conv_norm", cfg), cfg,
                                 p + "_conv")
@@ -728,11 +962,11 @@ def decoder_lm_train(cfg: DecoderLMConfig):
         h = layers.embedding(
             ids, size=[cfg.vocab_size, cfg.hidden_size],
             param_attr=_w("embed_tokens.w_0", cfg))
-    counts = _Counts()
+    counts, shared = _Counts(), _Shared()
     for i in range(cfg.num_hidden_layers):
         with name_scope(f"layer_{i}"):
             if cfg.parts is None:
-                h = _two_part_layer(h, i, cfg, counts)
+                h = _two_part_layer(h, i, cfg, counts, shared)
             else:
                 h = _one_part_layer(h, i, cfg.parts[i], cfg, counts)
     if counts.load:
@@ -755,6 +989,8 @@ def decoder_lm_train(cfg: DecoderLMConfig):
         _overwritten_counter(SHORT_CONV_TOKENS_VAR, counts.convolved)
     if counts.admitted:
         _overwritten_counter(WINDOW_PAIRS_VAR, counts.admitted)
+    if counts.selective:
+        _overwritten_counter(SELECTIVE_SCAN_TOKENS_VAR, counts.selective)
     with name_scope("head"):
         x = _norm(h, "final_norm", cfg)
         if cfg.tie_word_embeddings:

@@ -28,7 +28,9 @@ Three layers (docs/OBSERVABILITY.md):
 * :mod:`.short_conv` — reader of the `short_conv_tokens` counter a step
   with gated short convolution layers overwrites (docs/TRACING.md);
 * :mod:`.window_attention` — reader of the `window_attn_pairs` counter a
-  step with sliding-window attention layers overwrites (docs/TRACING.md).
+  step with sliding-window attention layers overwrites (docs/TRACING.md);
+* :mod:`.selective_scan` — reader of the `selective_scan_tokens` counter
+  a step with Mamba-1 mixers overwrites (docs/OBSERVABILITY.md).
 
 Hot-path contract: one boolean (``metrics._HOT[0]``) gates all
 per-step telemetry work. The step's own profiler spans and clock stamps
@@ -38,7 +40,7 @@ this layer's record.
 """
 from . import metrics, recorder, export, tracing, attribution, \
     memory, moe, sparse_attention, mamba, short_conv, \
-    window_attention  # noqa: F401
+    window_attention, selective_scan  # noqa: F401
 from .metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, EngineCounters,
     default_registry, counter, gauge, histogram,

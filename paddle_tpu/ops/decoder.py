@@ -4,8 +4,9 @@ SwiGLU, or a squared ReLU), the top-k router (sigmoid or softmax
 scores), the expert layer of a mixture of experts of which this chip
 holds a share, the index of a learned sparse attention (which keys each
 query attends), the three ops of a Mamba-2 mixer: the short causal
-convolution, the state-space scan and the gated group-wise RMS norm, and
-the gated short convolution that is a token mixer of its own.
+convolution, the state-space scan and the gated group-wise RMS norm, the
+selective scan of a Mamba-1 mixer, and the gated short convolution that
+is a token mixer of its own.
 
 The expert layer is dropless and knows which experts it holds:
 `moe_experts` gathers the rows routed to experts `first_expert ..
@@ -618,6 +619,68 @@ def mamba2_ssd_grad(ctx):
                            s.chunk)[1]
     dx, ddt, da, db, dc, dd = s.ssd.ssd_grad(
         s.x, s.dt, s.a, s.b, s.c, s.d, states, dy, s.kernels, s.chunk)
+    draw = ddt * jax.nn.sigmoid(s.raw)
+    for slot, grad in (("X", dx), ("Dt", draw),
+                       ("DtBias", jnp.sum(draw, axis=(0, 1))),
+                       ("ALog", da * s.a), ("B", db), ("C", dc), ("D", dd)):
+        names = op.output(slot + "@GRAD")
+        if names and names[0]:
+            primal = ctx.env[op.input(slot)[0]]
+            ctx.env[names[0]] = grad.astype(primal.dtype)
+
+
+# --------------------------------------- the Mamba-1 mixer's selective scan
+
+class _Selective:
+    """What the forward and the grad op of `selective_scan` share: the
+    step sizes dt = softplus(Dt + DtBias) and the decay rates A =
+    -exp(ALog) in float32, the kernel decision."""
+
+    def __init__(self, ctx):
+        from ..kernels import selective_scan
+        self.ss = selective_scan
+        self.x, self.b, self.c = (ctx.input(s) for s in ("X", "B", "C"))
+        self.raw = ctx.input("Dt").astype(_F32) \
+            + ctx.input("DtBias").astype(_F32)
+        self.dt = jax.nn.softplus(self.raw)
+        self.a = -jnp.exp(ctx.input("ALog").astype(_F32))
+        self.d = ctx.input("D").astype(_F32)
+        self.kernels = selective_scan.use_kernels(self.x, self.a)
+
+
+@register_op("selective_scan", intermediate_outputs=("States",))
+def selective_scan(ctx):
+    """Mamba-1's selective scan. X, Dt [B, T, C]; DtBias, D [C]; ALog
+    [C, N]; B, C [B, T, N] (shared by every channel). With dt =
+    softplus(Dt + DtBias) and A = -exp(ALog), a channel's state h [N]
+    from zero: h_t = exp(dt_t A) * h_{t-1} + dt_t x_t B_t, Y_t = h_t .
+    C_t + D x_t (kernels/selective_scan.py). States (intermediate,
+    float32 [B, chunks, N, C]) carries the state at the start of each
+    chunk of the kernels' CHUNK tokens to the grad op. Tokens (optional, int32 [1]): the tokens scanned, for
+    the `selective_scan_tokens` counter (observability/
+    selective_scan.py)."""
+    s = _Selective(ctx)
+    y, states = s.ss.scan(s.x, s.dt, s.a, s.b, s.c, s.d, s.kernels)
+    ctx.set_output("Y", y)
+    ctx.set_output("States", states)
+    if ctx.has_output("Tokens"):
+        ctx.set_output("Tokens", jnp.full(
+            (1,), s.x.shape[0] * s.x.shape[1], jnp.int32))
+
+
+@override_grad_lowering("selective_scan")
+def selective_scan_grad(ctx):
+    """Hand-written: the backward kernel reads the forward's States; then
+    d Dt = d DtBias = d dt * sigmoid(Dt + DtBias), d ALog = dA * A."""
+    op = ctx.op
+    s = _Selective(ctx)
+    dy = ctx.env[op.input("Y@GRAD")[0]].astype(s.x.dtype)
+    if ctx.has_input("States"):
+        states = ctx.env[op.input("States")[0]]
+    else:
+        states = s.ss.scan(s.x, s.dt, s.a, s.b, s.c, s.d, s.kernels)[1]
+    dx, ddt, da, db, dc, dd = s.ss.scan_grad(
+        s.x, s.dt, s.a, s.b, s.c, s.d, states, dy, s.kernels)
     draw = ddt * jax.nn.sigmoid(s.raw)
     for slot, grad in (("X", dx), ("Dt", draw),
                        ("DtBias", jnp.sum(draw, axis=(0, 1))),

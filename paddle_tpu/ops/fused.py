@@ -2,7 +2,8 @@
 fused_embedding_seq_pool, fusion_lstm/gru, ...). On TPU XLA fuses the
 elementwise families automatically, so the ops here are the ones that
 need a real kernel: fused multi-head attention via the Pallas flash
-kernel (kernels/flash_attention.py)."""
+kernel (kernels/flash_attention.py), and what differential attention
+makes of its two maps a head."""
 from __future__ import annotations
 
 import jax
@@ -207,6 +208,41 @@ def fused_attention_grad(ctx):
                     grad.dtype != primal.dtype:
                 grad = grad.astype(primal.dtype)
             ctx.env[names[0]] = grad
+
+
+@register_op("differential_attention")
+def differential_attention(ctx):
+    """The head outputs of differential attention from the two softmax
+    maps a pair of query heads made. X [B, S, 2 H, Dv]: the fused
+    attention's output with its query heads in (key pair j, map m,
+    member r) order, `group` members a pair, map 0 the one of q1 k1,
+    map 1 the one of q2 k2; LambdaQ1, LambdaK1, LambdaQ2, LambdaK2 [d];
+    Scale [Dv]; attrs lambda_init, epsilon.
+      lambda = exp(LambdaQ1 . LambdaK1) - exp(LambdaQ2 . LambdaK2)
+               + lambda_init
+      o_i = (1 - lambda_init) * RMSNorm(a1_i - lambda a2_i) * Scale,
+    head i = j * group + r, the norm over its Dv channels. Out [B, S,
+    H Dv], the heads in that order. Float32 inside, X's type out."""
+    x = ctx.input("X")
+    group = int(ctx.attr("group"))
+    init = float(ctx.attr("lambda_init"))
+    eps = float(ctx.attr("epsilon", 1e-5))
+    b, s, heads, dv = x.shape
+    if heads % (2 * group):
+        raise ValueError(f"{heads} attention heads are no pairs of "
+                         f"{group} members' two maps")
+
+    def dot(a, c):
+        return jnp.sum(ctx.input(a).astype(jnp.float32)
+                       * ctx.input(c).astype(jnp.float32))
+    lam = jnp.exp(dot("LambdaQ1", "LambdaK1")) \
+        - jnp.exp(dot("LambdaQ2", "LambdaK2")) + init
+    maps = x.astype(jnp.float32).reshape(b, s, heads // (2 * group), 2,
+                                         group, dv)
+    o = maps[:, :, :, 0] - lam * maps[:, :, :, 1]
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    o = o * ctx.input("Scale").astype(jnp.float32) * (1.0 - init)
+    ctx.set_output("Out", o.reshape(b, s, heads // 2 * dv).astype(x.dtype))
 
 
 @register_op("conv2d_inception_fusion")

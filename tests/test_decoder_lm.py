@@ -1380,6 +1380,11 @@ _PARENT_PROGRAMS = {
         451, "469421ddaecf07b2aa66ceff28e40f202bbe52deca2618b8adc8f9fc9fdc40e5"),
     ("lfm2_24b_a2b", False): (
         451, "545bc4d6687dfa6d6f04e97c530d9afb7c2a81c90c386450043ccddf5cff2697"),
+    # as the commit before the decoder-hybrid-decoder layers built them
+    ("mellum2_12b_a2p5b", True): (
+        432, "0778881731bce8c8b1e0fcd09318ee9e3028f2df7d2e11b75e4b2cd877600575"),
+    ("mellum2_12b_a2p5b", False): (
+        432, "bf2c6b18b958aee94e9a4eadf28d27376a06ec77cfbbaf7ade5d32955cba9551"),
 }
 
 
@@ -1387,11 +1392,13 @@ _PARENT_PROGRAMS = {
 def test_accepted_decoder_programs_are_op_for_op_the_parents(config,
                                                              rehearsal):
     """The model file grew a second kind of layer, then a third, then
-    window layers and YaRN; the four accepted decoder configurations
-    still build the parent's programs, op for op: types, inputs, outputs
-    and every attribute (name scopes among them; no router op gained a
-    `norm_epsilon`, no attention a `window`, no rotary a `yarn`), at the
-    rehearsal's sizes and at the cell's."""
+    window layers and YaRN, then a decoder-hybrid-decoder's layers,
+    LayerNorm and differential attention; the five accepted decoder
+    configurations still build the parent's programs, op for op: types,
+    inputs, outputs and every attribute (name scopes among them; no
+    router op gained a `norm_epsilon`, no attention a `window`, no
+    rotary a `yarn`, no norm became a LayerNorm), at
+    the rehearsal's sizes and at the cell's."""
     import hashlib
     import json
     import os
@@ -1399,7 +1406,8 @@ def test_accepted_decoder_programs_are_op_for_op_the_parents(config,
     from paddle_tpu import models
     fam = {"kanana2_30b_a3b": family, "keye_vl2_30b_a3b": gqa_family,
            "nemotron_twotower_30b_a3b": hybrid_family,
-           "lfm2_24b_a2b": conv_family}[config]
+           "lfm2_24b_a2b": conv_family,
+           "mellum2_12b_a2p5b": swa_family}[config]
     with open(os.path.join(cells.BENCH, "configs", config + ".json")) as f:
         sz = fam.sizes(json.load(f), rehearsal=rehearsal)
     fluid.framework.unique_name.reset()
@@ -1428,7 +1436,8 @@ def test_accepted_decoder_programs_are_op_for_op_the_parents(config,
     ("sliding_window", 4096),
     ("layer_types", ["full_attention", "chunked_attention"]),
     ("layer_types", ["conv", "linear_attention"]), ("conv_bias", True),
-    ("mlp_layer_types", ["sparse", "sparse", "moe", "dense"])])
+    ("mlp_layer_types", ["sparse", "sparse", "moe", "dense"]),
+    ("use_differential_attention", True)])
 def test_keys_that_change_a_layers_equations_raise_by_name(key, value):
     """`DecoderLMConfig` swallowed every key it did not know: a file
     with experts in a compressed latent, multi-token heads, a window no
@@ -1443,7 +1452,8 @@ def test_keys_that_change_a_layers_equations_raise_by_name(key, value):
     # absent, null or zero: the model that was always built
     quiet = {"sliding_window": None, "layer_types": None,
              "moe_latent_size": None, "num_nextn_predict_layers": 0,
-             "conv_bias": False, "mlp_layer_types": None}
+             "conv_bias": False, "mlp_layer_types": None,
+             "use_differential_attention": False}
     models.DecoderLMConfig(n_routed_experts=8, **{key: quiet[key]},
                            max_position_embeddings=4096, model_type="any")
 

@@ -525,6 +525,8 @@ COVERED = {
     "gated_short_conv": "tests/test_decoder_lm.py (hand-written grad: the output, dX over its three thirds and dWeight against jax.grad of the reference's function, lowered and through the two Pallas kernels across block boundaries; causality)",
     "gated_rms_norm": "tests/test_decoder_lm.py (forward and the three gradients against the reference's group norm, one group and four)",
     "mamba2_ssd": "tests/test_decoder_lm.py, tests/test_kernels.py (hand-written grad: y and all seven gradients against the token-by-token recurrence, lowered and through the Pallas kernels, a ragged tail, B > 1)",
+    "selective_scan": "tests/test_kernels.py, tests/test_sambay_decoder.py (hand-written grad: y and the six gradients against the token-by-token recurrence, lowered and through the Pallas kernels, a ragged tail, three channel blocks, B > 2; through the whole model against the plain reference, every parameter's gradient)",
+    "differential_attention": "tests/test_sambay_decoder.py (the output and the gradients of X, two lambda vectors and the sub-norm's scale against the equations written out; through the whole model against the plain reference)",
     "relu2": "tests/test_decoder_lm.py (forward and gradient by hand)",
     "moe_experts": "tests/test_decoder_lm.py (forward and every gradient against the plain reference, lowered and through the grouped-matmul kernels; the share test; dropless under imbalance)",
     "moe_router": "tests/test_decoder_lm.py (choices, weights, counts and gradients against the plain reference; the top-k choice is piecewise constant, so central differences straddle its jumps)",

@@ -753,3 +753,125 @@ def test_ssd_is_registered_and_routes_like_the_others(interp, monkeypatch):
     assert ssd._eligible(sig(64, 64, 8, 128))     # the published mixer
     assert not ssd._eligible(sig(4, 8, 2, 16))
     assert not ssd._eligible(sig(64, 64, 8, 64))
+
+
+# ---------------------------------------------------------------------------
+# Mamba-1's selective scan: kernels and lowering against the recurrence
+# ---------------------------------------------------------------------------
+
+def _selective_case(b, t, c, n, seed, dtype=jnp.float32):
+    r = np.random.default_rng(seed)
+
+    def draw(*shape):
+        return jnp.asarray(r.standard_normal(shape), jnp.float32)
+    return dict(x=draw(b, t, c).astype(dtype),
+                dt=jax.nn.softplus(draw(b, t, c) - 1.0),
+                a=-jnp.exp(draw(c, n) * 0.5),
+                b=draw(b, t, n).astype(dtype), c=draw(b, t, n).astype(dtype),
+                d=draw(c), dy=draw(b, t, c).astype(dtype))
+
+
+def _selective_recurrence(x, dt, a, b, c, d):
+    from benchmark.families import sambay_decoder_reference as ref
+    return ref.recurrence(x.astype(jnp.float32), dt, a,
+                          b.astype(jnp.float32), c.astype(jnp.float32), d,
+                          block=x.shape[1])
+
+
+@pytest.mark.parametrize("path", ["lowered", "selective_scan_fwd_bwd"])
+@pytest.mark.parametrize("tokens,chunk", [(37, 16), (64, 32), (9, 16)],
+                         ids=["ragged_tail", "whole_chunks", "one_chunk"])
+def test_selective_scan_against_the_token_by_token_recurrence(tokens, chunk,
+                                                              path):
+    """`selective_scan_fwd` / `_bwd` under the interpreter and the lowered
+    recurrence: y and the six gradients (x, dt, A, B, C, D) against the
+    token-by-token recurrence, B = 2, 384 channels (three blocks of 128,
+    so dB and dC are summed over blocks), at a length that is no
+    multiple of the chunk among them; the states handed to the backward
+    are those of the chunks' starts."""
+    from paddle_tpu.kernels import selective_scan as ss
+    kernels = path != "lowered"
+    c = _selective_case(2, tokens, 384, 16, seed=tokens)
+    args = [c[k] for k in "x dt a b c d".split()]
+    with jax.default_matmul_precision("highest"):
+        want = _selective_recurrence(*args)
+        r_grads = jax.vjp(_selective_recurrence, *args)[1](c["dy"])
+        y, states = ss.scan(*args, kernels, chunk=chunk)
+        grads = ss.scan_grad(*args, states, c["dy"], kernels, chunk=chunk)
+    assert states.shape == (2, -(-tokens // chunk), 16, 384)
+    assert not np.asarray(states[:, 0]).any()       # h_0 = 0
+    np.testing.assert_allclose(y, want, atol=3e-5, rtol=3e-5)
+    for name, g, w in zip("x dt a b c d".split(), grads, r_grads):
+        assert g.shape == w.shape, name
+        scale = float(jnp.max(jnp.abs(w)))
+        assert float(jnp.max(jnp.abs(g - w))) <= 3e-5 * max(scale, 1), name
+
+
+def test_selective_scan_kernels_equal_their_lowering_in_bfloat16():
+    """x, B and C in bf16, dt, A, the state and every exp float32 on both
+    paths: the kernels and the lowered recurrence agree far inside
+    bf16's own rounding of the result."""
+    from paddle_tpu.kernels import selective_scan as ss
+    c = _selective_case(1, 48, 256, 16, seed=5, dtype=jnp.bfloat16)
+    args = [c[k] for k in "x dt a b c d".split()]
+    (y_k, st_k), (y_l, st_l) = (ss.scan(*args, kern, chunk=16)
+                                for kern in (True, False))
+    assert y_k.dtype == jnp.bfloat16 and st_k.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(y_k, np.float32),
+                               np.asarray(y_l, np.float32), atol=0.06,
+                               rtol=0.02)
+    np.testing.assert_allclose(st_k, st_l, atol=1e-4, rtol=1e-4)
+    g_k, g_l = (ss.scan_grad(*args, st_l, c["dy"], kern, chunk=16)
+                for kern in (True, False))
+    for name, a, b in zip("x dt a b c d".split(), g_k, g_l):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= 0.03 * max(np.abs(b).max(), 1), name
+
+
+def test_selective_scan_is_registered_and_routes_like_the_others(
+        interp, monkeypatch):
+    from paddle_tpu.kernels import selective_scan as ss
+    assert "selective_scan" in kreg.kernel_names()
+    x, a = jnp.zeros((1, 32, 256)), jnp.zeros((256, 16))
+    kreg.reset_stats()
+    assert ss.use_kernels(x, a)
+    monkeypatch.setenv("PT_KERNEL_DENY", "selective_scan")
+    assert not ss.use_kernels(x, a)
+    with kreg.auto_partitioned():
+        monkeypatch.delenv("PT_KERNEL_DENY")
+        assert not ss.use_kernels(x, a)         # a mesh: lowered
+    assert kreg.dispatch_stats()["per_kernel"]["selective_scan"] == {
+        "custom": 1, "denied": 1, "lowered": 1}
+    # off the interpreter the shapes must tile: the channels whole lane
+    # blocks, the states a whole sublane tile
+    monkeypatch.setattr(kreg, "_INTERPRET", False)
+    sig = lambda ch, n: kreg.signature(  # noqa: E731
+        "selective_scan", jnp.zeros((1, 8, ch), jnp.bfloat16),
+        jnp.zeros((ch, n), jnp.float32))
+    assert ss._eligible(sig(5120, 16))           # the published mixer
+    assert not ss._eligible(sig(200, 16))
+    assert not ss._eligible(sig(5120, 12))
+    # the kernels step through a chunk a bf16 sublane tile at a time
+    with pytest.raises(ValueError, match="multiple of 16"):
+        ss.scan(x, x, a, x[..., :16], x[..., :16], x[0, 0], True, chunk=24)
+
+
+def test_selective_scan_tolerance_refuses_a_bfloat16_state_or_step():
+    """The benchmark cell's comparison cannot see the scan's state or
+    its step rounded to bfloat16 (the rest of the step's bfloat16
+    rounding is a hundred times larger there), so this float32 check
+    does: the recurrence with either rounded lies outside the 3e-5 the
+    kernels are held to above, by more than ten times."""
+    from benchmark.families import sambay_decoder_reference as ref
+    c = _selective_case(1, 64, 256, 16, seed=7)
+    args = [c[k] for k in "x dt a b c d".split()]
+    bits = jnp.finfo(jnp.bfloat16)
+    with jax.default_matmul_precision("highest"):
+        want = ref.recurrence(*args, block=64)
+        state = ref.recurrence(*args, block=64, state_bf16=True)
+        dt = args[1]
+        step = ref.recurrence(args[0], jax.lax.reduce_precision(
+            dt, bits.nexp, bits.nmant), *args[2:], block=64)
+    scale = float(jnp.max(jnp.abs(want)))
+    for got in (state, step):
+        assert float(jnp.max(jnp.abs(got - want))) > 10 * 3e-5 * scale

@@ -661,7 +661,8 @@ def test_no_other_kernel_reads_as_flash_or_adam():
              "moe_grouped_matmul_dx", "moe_grouped_matmul_dw",
              "sparse_index_scores", "sparse_index_select",
              "mamba2_ssd_fwd", "mamba2_ssd_bwd", "moe_combine",
-             "gated_short_conv_fwd", "gated_short_conv_bwd"] + [
+             "gated_short_conv_fwd", "gated_short_conv_bwd",
+             "selective_scan_fwd", "selective_scan_bwd"] + [
         f"tuned_matmul_{v.epilogue}_{v.bm}x{v.bn}x{v.bk}"
         for v in variants.enumerate_variants()]
     for n in names:
