@@ -31,6 +31,7 @@ import time
 from .enforce import EnforceNotMet, wrap_op_error
 from .flags import FLAGS
 from .registry import OPS, ExecContext, _RngCtx
+from .packing import Members, PackSlot
 from .scope import LoDTensor, Scope
 from .types import dtype_to_np
 from ..observability import metrics as _obs
@@ -82,10 +83,16 @@ class TracedStep:
 
     def __init__(self, fn, donated_names, const_names, feed_names,
                  fetch_names, updated_names, fetch_lods, uses_rng,
-                 nan_check_labels=()):
+                 nan_check_labels=(), packs=()):
         self.fn = fn
         self.donated_names = donated_names
         self.const_names = const_names
+        # core/packing.py: the packs fn takes in place of their members;
+        # the names fn takes one argument each are the rest
+        self.packs = tuple(packs)
+        packed = {n for lay in self.packs for n in lay.names}
+        self.donated_args = [n for n in donated_names if n not in packed]
+        self.const_args = [n for n in const_names if n not in packed]
         self.feed_names = feed_names
         self.fetch_names = fetch_names
         self.updated_names = updated_names
@@ -539,24 +546,26 @@ def trace_step(program, block_idx: int, feed_sig: Dict[str, Any],
                feed_lods: Dict[str, list], fetch_names: Sequence[str],
                scope: Scope, mesh=None, data_axis: str = "dp",
                strategy=None, iterations: int = 1,
-               multi_step: int = 1) -> TracedStep:
+               multi_step: int = 1, pack: bool = False) -> TracedStep:
     """:func:`_trace_step` under its set-up span (`trace_step`, with
     the child `trace_step.op_walk`: the abstract walk over every op's
     lowering that finds the updated persistables; the rest, building
     the jitted callable, is the parent's own time;
     observability/tracing.py): what a process spends tracing is kept
-    whether or not telemetry is on."""
+    whether or not telemetry is on. With ``pack`` an un-meshed step
+    takes its one-element persistables packed (core/packing.py); its
+    ``fn`` then takes the packs in place of their members."""
     with _obs_tracing.setup_span(
             "trace_step", program=program.fingerprint[0],
             ops=len(program.block(block_idx).ops)) as span:
         return _trace_step(program, block_idx, feed_sig, feed_lods,
                            fetch_names, scope, mesh, data_axis,
-                           strategy, iterations, multi_step, span)
+                           strategy, iterations, multi_step, span, pack)
 
 
 def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
                 scope, mesh, data_axis, strategy, iterations,
-                multi_step, span) -> TracedStep:
+                multi_step, span, pack=False) -> TracedStep:
     """Build + jit the step function for one (program, feed-sig) pair.
 
     With `mesh`, the step is compiled SPMD: feeds sharded on their batch
@@ -861,10 +870,13 @@ def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
 
     # --- phase 1: abstract trace to discover updated persistables ---------
     params_sig = {}
+    plain = set()
     opaque_state = False
     for n in avail:
         val = scope.find_var(n).get_value()
         arr = val.array if isinstance(val, LoDTensor) else val
+        if not (isinstance(val, LoDTensor) and val.lod()):
+            plain.add(n)
         try:
             params_sig[n] = jax.ShapeDtypeStruct(jnp.shape(arr),
                                                  jnp.result_type(arr))
@@ -880,7 +892,8 @@ def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
             raise NotImplementedError(
                 f"persistable {n!r} holds a host-side state object")
         with _obs_tracing.setup_span("trace_step.op_walk", parent=span):
-            jax.eval_shape(step, params_sig, feed_sig, key_sig)
+            walked, out_sig = jax.make_jaxpr(step, return_shape=True)(
+                params_sig, feed_sig, key_sig)
     except (NotImplementedError, jax.errors.JAXTypeError) as reason:
         # Block contains value-dependent-shape ops (edit_distance,
         # sequence_erase, save, ...) or host-state persistables: compile
@@ -971,6 +984,11 @@ def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
     updated_names = list(updated_box)
     donated = [n for n in avail if n in updated_names]
     const = [n for n in avail if n not in updated_names]
+    packs = []
+    if pack and mesh is None and strategy is None:
+        from . import packing as _packing
+        packs = _packing.plan(walked, params_sig, out_sig[1], donated,
+                              const, plain)
 
     # --- phase 2: jit with donation of updated persistables ---------------
     def step1(donated_params, const_params, feeds, key):
@@ -1083,6 +1101,20 @@ def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
         step2 = step1
     if multi_step == 1:
         step2 = _split_first(step2)
+    if packs:
+        # unpacked at the executable's entry and packed again at its
+        # exit: a scan carry (iterations, PT_MULTI_STEP) stays per name
+        donated_packs = [p for p in packs if p.donated]
+        const_packs = [p for p in packs if not p.donated]
+        per_name = step2
+
+        def step2(donated_params, const_params, feeds, key):
+            fetches, updated, nan_flags, info = per_name(
+                _packing.unpack_args(donated_params, donated_packs),
+                _packing.unpack_args(const_params, const_packs),
+                feeds, key)
+            return (fetches, _packing.repack(updated, donated_packs),
+                    nan_flags, info)
 
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1140,7 +1172,7 @@ def _trace_step(program, block_idx, feed_sig, feed_lods, fetch_names,
     ts = TracedStep(fn, donated, const, sorted(feed_sig),
                     list(fetch_names), updated_names,
                     fetch_lod_box, uses_rng_box[0],
-                    nan_check_labels=nan_labels_box)
+                    nan_check_labels=nan_labels_box, packs=packs)
     ts.comm_stats = comm_stats
     ts.guard_plan = guard_plan
     ts.integrity_plan = integrity_plan
@@ -1170,7 +1202,7 @@ class _FastPathEntry:
 
     __slots__ = ("scope", "place", "dev", "feed_names", "shapes",
                  "dtypes", "lods", "traced", "donated_vars",
-                 "const_vars", "updated_vars", "sig_hash")
+                 "const_vars", "packs", "updated_vars", "sig_hash")
 
     def __init__(self, scope, place, dev, arrays, lods, traced):
         self.scope = scope
@@ -1182,8 +1214,9 @@ class _FastPathEntry:
         self.lods = {n: [list(level) for level in lod]
                      for n, lod in lods.items()}
         self.traced = traced
-        self.donated_vars = scope.var_refs(traced.donated_names)
-        self.const_vars = scope.var_refs(traced.const_names)
+        self.donated_vars = scope.var_refs(traced.donated_args)
+        self.const_vars = scope.var_refs(traced.const_args)
+        self.packs = [PackSlot(lay, scope) for lay in traced.packs]
         # filled lazily by the writeback (eager fallbacks only discover
         # their updated set while running)
         self.updated_vars: Dict[str, Any] = {}
@@ -1331,7 +1364,11 @@ class Engine:
             # actually executed, slabs that broke out early on a guard
             # verdict, and frozen substeps replayed sequentially
             "multistep_dispatches": 0, "multistep_substeps": 0,
-            "multistep_early_exits": 0, "multistep_replays": 0})
+            "multistep_early_exits": 0, "multistep_replays": 0,
+            # the last compiled step's arguments (persistables, packs,
+            # feeds, rng state) and the persistables it takes packed
+            # (core/packing.py, docs/ASYNC_DISPATCH.md)
+            "step_args": 0, "packed_persistables": 0})
         _obs.register_engine(self)
         # lazily built per-engine stability controller
         # (FLAGS_stability_guard; paddle_tpu/stability/guard.py)
@@ -1617,15 +1654,19 @@ class Engine:
                                             jnp.result_type(a),
                                             sharding=_committed(a))
 
-            donated = {n: _sig(n) for n in traced.donated_names}
-            const = {n: _sig(n) for n in traced.const_names}
+            donated = {n: _sig(n) for n in traced.donated_args}
+            const = {n: _sig(n) for n in traced.const_args}
             multihost = self._is_multihost()
-            # un-meshed feeds were committed to the place's device —
-            # the device the step left its donated outputs on
+            # un-meshed feeds and packs were committed to the place's
+            # device — the device the step left its donated outputs on
             feed_sh = None
             if self.mesh is None:
                 feed_sh = next((s.sharding for s in donated.values()
                                 if s.sharding is not None), None)
+            for lay in traced.packs:
+                (donated if lay.donated else const)[lay.key] = \
+                    jax.ShapeDtypeStruct((lay.total,), lay.dtype,
+                                         sharding=feed_sh)
             feeds = {n: jax.ShapeDtypeStruct(
                          self._global_shape(n, a) if multihost
                          else a.shape, a.dtype, sharding=feed_sh)
@@ -1697,15 +1738,19 @@ class Engine:
         """Per-trace donation metadata for the verifier and the memory
         observatory: which buffers each cached step donates to XLA
         (updated persistables, aliased in-place) and which it keeps
-        const. The static analyzer's ``analysis.donation_plan``
-        predicts this set pre-trace; this is the ground truth to
-        reconcile against."""
+        const, and which of them it takes packed. The static
+        analyzer's ``analysis.donation_plan`` predicts this set
+        pre-trace; this is the ground truth to reconcile against."""
         rows: List[Dict[str, Any]] = []
         for traced in list(self._cache.values()):
             rows.append({
                 "donated": list(traced.donated_names),
                 "const_count": len(traced.const_names),
-                "updated": list(traced.updated_names)})
+                "updated": list(traced.updated_names),
+                # the packs the step takes in place of these members
+                # (core/packing.py); an updated pack is donated
+                "packed": {lay.key: list(lay.names)
+                           for lay in traced.packs}})
         return rows
 
     def _fast_feed_arrays(self, entry: _FastPathEntry, feed):
@@ -1880,7 +1925,8 @@ class Engine:
                                 if place is not None
                                 and self.mesh is None else None)):
                         arrays = self._fast_feed_arrays(e, feed)
-                        if arrays is not None:
+                        if arrays is not None and not any(
+                                s.stale() for s in e.packs):
                             entry = e
                             break
             if entry is None:
@@ -1910,10 +1956,12 @@ class Engine:
                 donated = {n: _var_array(v)
                            for n, v in entry.donated_vars}
                 const = {n: _var_array(v) for n, v in entry.const_vars}
+                packs = _take_packs(entry.packs, donated, const,
+                                    entry.dev)
             outs = self._dispatch(
                 program, scope, entry.traced, arrays, donated, const,
                 return_numpy, entry.dev,
-                updated_vars=entry.updated_vars)
+                updated_vars=entry.updated_vars, packs=packs)
             self._finish_step(clock, entry.traced, arrays)
             if multi_step > 1:
                 return self._finish_multi(
@@ -1932,6 +1980,15 @@ class Engine:
         key = self._cache_key(program, block_idx, feed_sig_key,
                               fetch_names, iterations, multi_step)
         traced = self._cache.get(key) if use_program_cache else None
+        if traced is not None and any(PackSlot(lay, scope).stale()
+                                      for lay in traced.packs):
+            # a host write reshaped a packed persistable: trace again,
+            # and drop the steady-state records of the old trace
+            if fast_key is not None:
+                self._fast[fast_key] = [
+                    e for e in self._fast.get(fast_key, ())
+                    if e.traced is not traced]
+            traced = None
         if traced is not None and traced.dispatched:
             cold = contextlib.nullcontext()
         else:
@@ -1956,12 +2013,28 @@ class Engine:
             dev = place.jax_device() \
                 if place is not None and self.mesh is None else None
             with clock.phase(_profiler.P_ARGS):
-                donated_params = {}
-                const_params = {}
-                for n in traced.donated_names:
-                    donated_params[n] = _scope_array(scope, n)
-                for n in traced.const_names:
-                    const_params[n] = _scope_array(scope, n)
+                slots = None
+                if fast_key is not None and not multihost:
+                    # steady-state record: subsequent runs of this
+                    # (program, feed-sig, fetch) tuple skip signature
+                    # reconstruction, persistable re-walks, and no-op
+                    # device_puts
+                    entries = self._fast.setdefault(fast_key, [])
+                    entry = _FastPathEntry(scope, place, dev, arrays, lods,
+                                           traced)
+                    entry.sig_hash = _sig_hash(feed_sig_key)
+                    entries.append(entry)
+                    if len(entries) > _MAX_FAST_ENTRIES:
+                        entries.pop(0)
+                    slots = entry.packs
+                donated_params = {n: _scope_array(scope, n)
+                                  for n in traced.donated_args}
+                const_params = {n: _scope_array(scope, n)
+                                for n in traced.const_args}
+                packs = _take_packs(
+                    slots if slots is not None else
+                    [PackSlot(lay, scope) for lay in traced.packs],
+                    donated_params, const_params, dev)
                 if dev is not None:
                     # a startup program has no committed input, so it
                     # leaves the params UNCOMMITTED; this step's outputs
@@ -1989,25 +2062,13 @@ class Engine:
                                       for n, v in donated_params.items()}
                     const_params = {n: _as_global(n, v, True)
                                     for n, v in const_params.items()}
-                elif fast_key is not None:
-                    # steady-state record: subsequent runs of this
-                    # (program, feed-sig, fetch) tuple skip signature
-                    # reconstruction, persistable re-walks, and no-op
-                    # device_puts
-                    entries = self._fast.setdefault(fast_key, [])
-                    entry = _FastPathEntry(scope, place, dev, arrays, lods,
-                                           traced)
-                    entry.sig_hash = _sig_hash(feed_sig_key)
-                    entries.append(entry)
-                    if len(entries) > _MAX_FAST_ENTRIES:
-                        entries.pop(0)
                 # cold path only: register the scope with the memory census
                 # (one weak-set add per trace, nothing per steady-state
                 # step)
                 _obs_memory.track_scope(scope)
             outs = self._dispatch(program, scope, traced, arrays,
                                   donated_params, const_params,
-                                  return_numpy, dev)
+                                  return_numpy, dev, packs=packs)
             self._finish_step(clock, traced, arrays)
             if multi_step > 1:
                 return self._finish_multi(outs, program, scope, place,
@@ -2026,7 +2087,14 @@ class Engine:
                             data_axis=self.data_axis,
                             strategy=self.strategy,
                             iterations=iterations,
-                            multi_step=multi_step)
+                            multi_step=multi_step, pack=True)
+        if hasattr(traced.fn, "lower"):
+            c = self.counters
+            c["step_args"] = (len(traced.donated_args)
+                              + len(traced.const_args)
+                              + len(traced.packs) + len(arrays) + 1)
+            c["packed_persistables"] = sum(len(lay.names)
+                                           for lay in traced.packs)
         if FLAGS.validate_program and int(FLAGS.validate_tier) >= 2:
             # tier 2: cross-check the step we ACTUALLY traced (guard
             # gate, collective plan) against the static conformance
@@ -2095,7 +2163,8 @@ class Engine:
         return self.last_multi_fetches
 
     def _dispatch(self, program, scope, traced, arrays, donated_params,
-                  const_params, return_numpy, dev, updated_vars=None):
+                  const_params, return_numpy, dev, updated_vars=None,
+                  packs=None):
         """Watchdog wrapper over :meth:`_dispatch_inner`: with
         FLAGS_step_timeout_s > 0 the step runs armed, and a hang is
         converted into the watchdog's diagnosable EnforceNotMet (the
@@ -2105,13 +2174,13 @@ class Engine:
         if wd is None:
             return self._dispatch_inner(
                 program, scope, traced, arrays, donated_params,
-                const_params, return_numpy, dev, updated_vars)
+                const_params, return_numpy, dev, updated_vars, packs)
         try:
             try:
                 wd.arm()
                 return self._dispatch_inner(
                     program, scope, traced, arrays, donated_params,
-                    const_params, return_numpy, dev, updated_vars)
+                    const_params, return_numpy, dev, updated_vars, packs)
             finally:
                 wd.disarm()
         except KeyboardInterrupt:
@@ -2174,13 +2243,15 @@ class Engine:
 
     def _dispatch_inner(self, program, scope, traced, arrays,
                         donated_params, const_params, return_numpy,
-                        dev, updated_vars=None, _guard_reexec=False):
+                        dev, updated_vars=None, packs=None,
+                        _guard_reexec=False):
         """Shared dispatch tail of fast and slow paths: the RNG state,
         the ONE executable call (it splits the state itself,
         :func:`_split_first`), then everything that needs neither the
         step's result nor the next feed — the argument dicts released,
-        the device-resident scope writeback — and only then the call
-        that blocks on the result: NaN-check surfacing (inline or
+        the device-resident scope writeback (one value per updated
+        pack, into the ``packs`` its members own) — and only then the
+        call that blocks on the result: NaN-check surfacing (inline or
         deferred) and fetch wrapping. EMPTIES ``donated_params`` and ``const_params``.
         Under FLAGS.async_dispatch nothing here forces a device sync —
         the persistable writebacks stay jax.Array futures and the
@@ -2239,6 +2310,9 @@ class Engine:
                 c["grad_collectives_per_step"] = comm_stats["buckets"]
                 c["comm_overlap_frac"] = comm_stats["overlap_frac"]
             for n, v in updated.items():
+                if packs and n in packs:
+                    packs[n].update(v)
+                    continue
                 var = updated_vars.get(n) if updated_vars is not None \
                     else None
                 if var is None:
@@ -2250,6 +2324,10 @@ class Engine:
             # persistables are the step's full dependency cone (same
             # arrays the scope holds — no extra live buffers)
             self._last_updated = tuple(updated.values())
+            if packs and (guard_plan is not None or getattr(
+                    traced, "integrity_plan", None) is not None):
+                # the controllers read the step's state by name
+                updated = Members(updated, packs)
             if guard_plan is not None:
                 _g0 = time.perf_counter()
                 ctl = self._stability
@@ -2278,12 +2356,16 @@ class Engine:
             # recursion depth is bounded to one by the controller's
             # reexec handling
             donated2 = {n: _scope_array(scope, n)
-                        for n in traced.donated_names}
+                        for n in traced.donated_args}
             const2 = {n: _scope_array(scope, n)
-                      for n in traced.const_names}
+                      for n in traced.const_args}
+            packs2 = _take_packs(
+                [PackSlot(lay, scope) for lay in traced.packs],
+                donated2, const2, dev)
             return self._dispatch_inner(
                 program, scope, traced, arrays, donated2, const2,
-                return_numpy, dev, updated_vars, _guard_reexec=True)
+                return_numpy, dev, updated_vars, packs2,
+                _guard_reexec=True)
         with clock.phase(_profiler.P_FETCH):
             rec = None
             if traced.nan_check_labels:
@@ -2474,6 +2556,18 @@ class Engine:
                     f"{exc}")
                 err.__cause__ = exc
                 raise err
+
+
+def _take_packs(slots, donated, const, dev):
+    """Put each pack of a step in its argument dict; returns the packs
+    the step updates, by key, for the writeback."""
+    owned = {}
+    for slot in slots:
+        lay = slot.layout
+        p = slot.take(donated if lay.donated else const, dev)
+        if p is not None:
+            owned[lay.key] = p
+    return owned
 
 
 def _scope_array(scope: Scope, name: str):

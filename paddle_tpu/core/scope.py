@@ -141,23 +141,72 @@ class LoDRankTable:
         return self.items[0][1] if self.items else 0
 
 
-class Variable:
-    """Type-erased runtime variable (reference variable.h:26)."""
+class Pack:
+    """Several persistables of one dtype held end to end in ONE device
+    array, which a compiled step takes and returns as one argument
+    (core/packing.py). Each member Variable reads its value as a view
+    of the array; a host write to a member releases it from the pack
+    (``intact`` turns False), and the engine packs the members again
+    before the next step."""
 
-    __slots__ = ("name", "_value")
+    __slots__ = ("array", "layout", "intact", "_views")
+
+    def __init__(self, array, layout, variables):
+        self.array = array
+        self.layout = layout
+        self.intact = True
+        self._views = None
+        for i, v in enumerate(variables):
+            old = v._pack
+            if old is not None and old is not self:
+                old.intact = False
+            v._pack, v._slot, v._value = self, i, None
+
+    def view(self, i):
+        """Member ``i``'s value; all members are split out at once, on
+        the first read after the array changed."""
+        views = self._views
+        if views is None:
+            views = self._views = self.layout.split(self.array)
+        return views[i]
+
+    def update(self, array):
+        self.array = array
+        self._views = None
+
+
+class Variable:
+    """Type-erased runtime variable (reference variable.h:26). A packed
+    variable's value lives in its :class:`Pack`."""
+
+    __slots__ = ("name", "_value", "_pack", "_slot")
 
     def __init__(self, name: str):
         self.name = name
         self._value = None
+        self._pack = None
+        self._slot = 0
+
+    @property
+    def pack(self) -> Optional[Pack]:
+        return self._pack
 
     def get_value(self):
+        if self._pack is not None:
+            return self._pack.view(self._slot)
         return self._value
 
     def set_value(self, v):
+        if self._pack is not None:
+            self._pack.intact = False
+            self._pack = None
         self._value = v
 
     # fluid calls this get_tensor(); returns the LoDTensor view
     def get_tensor(self) -> LoDTensor:
+        if self._pack is not None:
+            # the caller may write through the tensor: it is the value
+            self.set_value(self.get_value())
         if isinstance(self._value, LoDTensor):
             return self._value
         t = LoDTensor(self._value)
@@ -165,6 +214,8 @@ class Variable:
         return t
 
     def is_initialized(self):
+        if self._pack is not None:
+            return True
         v = self._value
         if isinstance(v, LoDTensor):
             return v.array is not None

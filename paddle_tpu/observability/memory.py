@@ -218,7 +218,10 @@ def _iter_owned() -> Iterator[Tuple[str, str, Any]]:
                 v = scope.find_var(n)
                 if v is None or not v.is_initialized():
                     continue
-                t = v.get_value()
+                # a packed member is claimed as its pack, once (the
+                # dedupe by identity), without splitting it
+                p = v.pack
+                t = p.array if p is not None else v.get_value()
             except Exception:
                 continue
             yield "scope", n, getattr(t, "array", t)
